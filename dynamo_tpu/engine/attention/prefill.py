@@ -2,8 +2,7 @@
 
 The XLA prefill path materializes f32 scores ``[KVH, T, G, ctx+T]`` per
 layer plus a gathered copy of the cached context — at 2K tokens that is
-GBs of HBM traffic per layer and caps prefill at ~15% MFU (measured on
-v5e, BENCH_r03). This kernel is the role FlashAttention plays inside the
+GBs of HBM traffic per layer. This kernel is the role FlashAttention plays inside the
 reference's engines (SURVEY.md §1 L5; anchor
 /root/reference/docs/benchmarks/pre_deployment_profiling.md:54): blocked
 K/V with an online softmax, scores never leave VMEM.

@@ -26,6 +26,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 
 def ragged_chunk_attention(
@@ -60,9 +61,14 @@ def ragged_chunk_attention(
             merge_attention_pieces,
         )
 
-        out2, m2, l2 = flash_chunk_attention(
-            q, k_new, v_new, valid_len, num_kv_heads=kvh, interpret=interpret
+        from dynamo_tpu.engine.sharding import HEADS, over_tp
+
+        # Under a tp mesh the kernel runs per shard over its local heads.
+        flash = over_tp(
+            flash_chunk_attention, kvh, (HEADS, HEADS, HEADS, P()), (HEADS, HEADS, HEADS),
+            interpret=interpret,
         )
+        out2, m2, l2 = flash(q, k_new, v_new, valid_len)
         if not has_prefix:
             return out2
         # Cached-prefix partial (online-softmax state), merged with the

@@ -162,6 +162,9 @@ class TpuEngine:
         draft_params=None,
         kv_event_sink: Optional[Callable[[KvEvent], None]] = None,
     ) -> "TpuEngine":
+        from dynamo_tpu.engine.compile_cache import enable_compile_cache
+
+        enable_compile_cache()
         mc = args.model_config or get_config(args.model)
         if args.kv_cache_dtype != "auto":
             mc = mc.replace(kv_cache_dtype=args.kv_cache_dtype)

@@ -42,7 +42,7 @@ PHASES = ("prefill", "decode", "mixed", "wave", "spec")
 # window between a dispatch returning and the next dispatch being issued —
 # far finer-grained than step durations. Overlapped steady state should sit
 # in the lowest buckets; sync-path steps pay the full
-# readback+bookkeeping+upload gap (ms to tens of ms on tunneled devices).
+# readback+bookkeeping+upload gap.
 GAP_BUCKETS: Tuple[float, ...] = (
     0.0001, 0.00025, 0.0005, 0.001, 0.0025, 0.005, 0.01, 0.025, 0.05, 0.1, 0.25
 )
@@ -84,33 +84,42 @@ class _PhaseHist:
         return self.buckets[-1]
 
 
-# Peak hardware numbers for the live MFU / HBM-roofline gauges, keyed by a
-# lowercase substring of jax's device_kind. Sources: published TPU specs
-# (bf16 FLOPs, HBM bandwidth). CPU gets a nominal floor so the gauges stay
-# defined (their absolute value is meaningless off-accelerator; the bench
-# anchors are the real numbers).
-_PEAKS: Tuple[Tuple[str, float, float], ...] = (
-    ("v5e", 197e12, 819e9),
-    ("v5p", 459e12, 2765e9),
-    ("v5", 197e12, 819e9),
-    ("v4", 275e12, 1228e9),
-    ("v6", 918e12, 1640e9),
-)
+# Peak hardware numbers for the live MFU / HBM-roofline gauges, keyed by the
+# ``device_kind`` JAX reports (both spellings jax's own tpu_info accepts).
+# Source: Google Cloud TPU documentation, per-chip bf16 FLOP/s and HBM
+# bytes/s. An accelerator that is not in the table is an error, not a
+# default. The CPU keeps a nominal value only so that tests run: the gauges'
+# absolute value is meaningless off-accelerator.
+CHIP_PEAKS: Dict[str, Tuple[float, float]] = {
+    "TPU v4": (275e12, 1228e9),
+    "TPU v5 lite": (197e12, 819e9),
+    "TPU v5e": (197e12, 819e9),
+    "TPU v5": (459e12, 2765e9),
+    "TPU v5p": (459e12, 2765e9),
+    "TPU v6 lite": (918e12, 1640e9),
+    "TPU v6e": (918e12, 1640e9),
+}
 _CPU_PEAKS = (1e12, 100e9)
 
 
-def detect_peaks() -> Tuple[float, float]:
-    """(peak FLOPs/s, peak HBM bytes/s) for the local accelerator."""
-    try:
-        import jax
-
-        kind = jax.devices()[0].device_kind.lower()
-    except Exception:  # noqa: BLE001 — no backend is a valid state
+def peaks_for(platform: str, device_kind: str) -> Tuple[float, float]:
+    """(peak FLOPs/s, peak HBM bytes/s) of one device as JAX reports it."""
+    if platform == "cpu":
         return _CPU_PEAKS
-    for sub, flops, bw in _PEAKS:
-        if sub in kind:
-            return flops, bw
-    return _CPU_PEAKS
+    if device_kind not in CHIP_PEAKS:
+        raise ValueError(
+            f"no peak FLOP/s and HBM bytes/s on record for accelerator "
+            f"{device_kind!r} (platform {platform!r}): add its row to CHIP_PEAKS"
+        )
+    return CHIP_PEAKS[device_kind]
+
+
+def detect_peaks() -> Tuple[float, float]:
+    """(peak FLOPs/s, peak HBM bytes/s) for the local device."""
+    import jax
+
+    dev = jax.devices()[0]
+    return peaks_for(dev.platform, dev.device_kind)
 
 
 class StepCostModel:

@@ -20,10 +20,17 @@ one all-reduce per block):
 - wo, w_down:            shard input dim over tp (row-parallel).
 - KV cache:              shard kv_heads over tp.
 - embed/lm_head:         shard vocab over tp.
+
+The one thing GSPMD cannot partition is a Pallas (Mosaic) kernel: the
+attention kernels run under ``jax.shard_map`` over ``tp`` (``over_tp``) —
+heads are independent, each shard sees its local KV heads, no collective is
+added. The engine's mesh reaches the model's trace through ``bind_mesh``.
 """
 
 from __future__ import annotations
 
+import contextvars
+import functools
 from dataclasses import dataclass
 from typing import Optional, Sequence
 
@@ -137,3 +144,86 @@ def replicated(mesh: Mesh) -> NamedSharding:
 
 def batch_sharding(mesh: Mesh) -> NamedSharding:
     return NamedSharding(mesh, P("dp"))
+
+
+# --- the engine's mesh, visible to the model while a step is traced ---------
+
+_STEP_MESH: contextvars.ContextVar = contextvars.ContextVar("dynamo_tpu_step_mesh", default=None)
+
+
+def step_mesh() -> Optional[Mesh]:
+    """The mesh of the engine whose model step is being traced (None for a
+    one-device engine or a direct model call)."""
+    return _STEP_MESH.get()
+
+
+class _MeshBound:
+    """A model module whose functions run — i.e. are traced, inside the
+    scheduler's jits — with ``mesh`` as the step mesh."""
+
+    def __init__(self, module, mesh: Mesh):
+        self._module = module
+        self._mesh = mesh
+
+    def __getattr__(self, name):
+        attr = getattr(self._module, name)
+        if not callable(attr):
+            return attr
+
+        @functools.wraps(attr)
+        def call(*args, **kwargs):
+            token = _STEP_MESH.set(self._mesh)
+            try:
+                return attr(*args, **kwargs)
+            finally:
+                _STEP_MESH.reset(token)
+
+        return call
+
+
+def bind_mesh(module, mesh: Optional[Mesh]):
+    """``module`` itself without a mesh; with one, a view of it whose calls
+    see ``step_mesh() == mesh``. Two engines in one process (a tp=4 engine
+    beside a one-device one) each trace under their own."""
+    return module if mesh is None else _MeshBound(module, mesh)
+
+
+def tp_size(mesh: Optional[Mesh]) -> int:
+    return 1 if mesh is None else int(mesh.shape.get("tp", 1))
+
+
+def kernel_shards(num_kv_heads: int) -> int:
+    """How many ``tp`` shards a per-head Pallas kernel of the step being
+    traced splits into: 1 without a tp mesh, ``tp`` when the KV heads divide
+    by it, and 0 when they do not — the cache is then replicated
+    (``kv_cache_spec``), the kernels cannot partition, and the callers take
+    their XLA paths."""
+    tp = tp_size(step_mesh())
+    if tp == 1:
+        return 1
+    return tp if num_kv_heads % tp == 0 else 0
+
+
+# Specs of the attention kernels' operands under ``over_tp``.
+HEADS = P(None, "tp", None)  # [rows, heads, HD] — q/k/v rows, outputs, (m, l)
+PAGES = P(None, None, "tp", None)  # [pages, BS, KVH, HD] — both members of a QuantKv
+
+
+def over_tp(kernel, num_kv_heads: int, in_specs, out_specs, **static):
+    """``kernel(*arrays, num_kv_heads=<local count>, **static)`` — a Pallas
+    attention call over per-head arrays — partitioned by hand over the step
+    mesh's ``tp`` axis: heads are independent, so each shard runs the kernel
+    on its local heads and no collective is added. Without a tp mesh it is
+    the plain kernel. Axes the specs do not name are replicated."""
+    tp = kernel_shards(num_kv_heads)
+    if tp == 0:
+        raise ValueError(
+            f"{num_kv_heads} KV heads do not divide by tp={tp_size(step_mesh())}: "
+            "resolve_attention_impl / resolve_prefill_impl choose the XLA paths there"
+        )
+    fn = functools.partial(kernel, num_kv_heads=num_kv_heads // tp, **static)
+    if tp == 1:
+        return fn
+    return jax.shard_map(
+        fn, mesh=step_mesh(), in_specs=in_specs, out_specs=out_specs, check_vma=False
+    )

@@ -49,10 +49,31 @@ async def make_engine(out_spec: str, args, drt):
             model=out_spec,
             dtype=args.dtype,
             checkpoint_path=args.checkpoint,
+            seed=args.seed,
             scheduler=SchedulerConfig(num_blocks=args.num_blocks),
         )
     )
     return engine, None
+
+
+async def serve_http(engine, tokenizer, pipeline, model_name: str, *, host: str, port: int) -> HttpService:
+    """The ``in=http`` frontend over ``engine``, started: chat completions,
+    plus embeddings when the engine is local. The caller stops it."""
+    manager = ModelManager()
+    manager.add_model("chat", model_name, pipeline)
+    if isinstance(engine, TpuEngine):
+        from dynamo_tpu.engine.embeddings import EmbeddingEngine
+        from dynamo_tpu.llm.entrypoint import build_embeddings_pipeline
+
+        sched = engine.scheduler
+        manager.add_model(
+            "embeddings",
+            model_name,
+            build_embeddings_pipeline(tokenizer, EmbeddingEngine(sched.mc, sched.params)),
+        )
+    service = HttpService(manager, host=host, port=port)
+    await service.start()
+    return service
 
 
 async def amain(args) -> None:
@@ -63,20 +84,9 @@ async def amain(args) -> None:
     model_name = args.model_name or args.out
 
     if args.mode == "http":
-        manager = ModelManager()
-        manager.add_model("chat", model_name, pipeline)
-        if isinstance(engine, TpuEngine):
-            from dynamo_tpu.engine.embeddings import EmbeddingEngine
-            from dynamo_tpu.llm.entrypoint import build_embeddings_pipeline
-
-            sched = engine.scheduler
-            manager.add_model(
-                "embeddings",
-                model_name,
-                build_embeddings_pipeline(tokenizer, EmbeddingEngine(sched.mc, sched.params)),
-            )
-        service = HttpService(manager, host="0.0.0.0", port=args.http_port)
-        await service.start()
+        service = await serve_http(
+            engine, tokenizer, pipeline, model_name, host="0.0.0.0", port=args.http_port
+        )
         print(f"serving {model_name} on :{service.port} (POST /v1/chat/completions)", flush=True)
         drt.runtime.install_signal_handlers()
         await drt.runtime.cancellation.cancelled()
@@ -137,6 +147,7 @@ def main() -> None:
     p.add_argument("--max-tokens", type=int, default=128)
     p.add_argument("--output", default=None)
     p.add_argument("--timeout", type=float, default=30.0)
+    p.set_defaults(seed=0)  # EngineArgs.seed (random-weight init); no flag — chip_smoke.py sets it
     args = p.parse_args()
     spec = {}
     for part in args.io:

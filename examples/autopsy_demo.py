@@ -30,7 +30,8 @@ import tempfile
 import aiohttp
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-sys.path.insert(0, os.path.join(REPO, "tools"))
+# Run as a file from a bare checkout: the package and tools/ sit under REPO.
+sys.path[:0] = [REPO, os.path.join(REPO, "tools")]
 
 
 async def main():

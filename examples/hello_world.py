@@ -6,6 +6,11 @@ Run: python examples/hello_world.py
 """
 
 import asyncio
+import os
+import sys
+
+# Run as a file from a bare checkout: the package sits one directory up.
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 from dynamo_tpu.runtime import DistributedRuntime, PushRouter
 from dynamo_tpu.runtime.health import SystemHealth, SystemStatusServer, HEALTHY

@@ -11,8 +11,13 @@ whatever the (random) weights emit.
 
 import asyncio
 import json
+import os
+import sys
 
 import aiohttp
+
+# Run as a file from a bare checkout: the package sits one directory up.
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 from dynamo_tpu.engine.engine import EngineArgs, TpuEngine
 from dynamo_tpu.engine.scheduler import SchedulerConfig

@@ -93,3 +93,65 @@ def test_tp4_with_dp2_mesh_compiles():
         sp, cache.k, cache.v
     )
     assert logits.shape == (B, cfg.vocab_size)
+
+
+@pytest.mark.parametrize(
+    "attention_impl,use_flash,kv_dtype",
+    [("megakernel", False, "auto"), ("megakernel", False, "int8"),
+     ("gather", True, "auto"), ("paged", False, "auto")],
+)
+def test_tp_pallas_kernels_partition_over_heads(attention_impl, use_flash, kv_dtype):
+    """The Pallas attention kernels (interpreted here) run per tp shard under
+    ``jax.shard_map`` when the model is traced under an engine's mesh
+    (``bind_mesh``): prefill + one decode step match the one-device run of
+    the same kernels."""
+    from dynamo_tpu.engine.sharding import bind_mesh
+
+    cfg = CFG.replace(attention_impl=attention_impl, kv_cache_dtype=kv_dtype)
+    mesh = build_mesh(ParallelConfig(tp=2))
+    model = bind_mesh(llama, mesh)
+    params = llama.init_params(cfg, jax.random.PRNGKey(0), dtype=jnp.float32)
+    T = 20
+    padded = jnp.array(list(range(40, 40 + T)) + [0] * (32 - T), dtype=jnp.int32)
+    block_table = jnp.array([1, 2, 3, 0], dtype=jnp.int32)
+    toks = jnp.array([7, 0], dtype=jnp.int32)
+    positions = jnp.array([T, 0], dtype=jnp.int32)
+    tables = jnp.zeros((2, 4), dtype=jnp.int32).at[0].set(block_table)
+    active = jnp.array([True, False])
+
+    def run(m, p, k, v):
+        step = jax.jit(
+            lambda p, k, v: m.prefill(
+                p, cfg, k, v, padded, jnp.int32(T), jnp.int32(0), block_table,
+                use_flash=use_flash, has_prefix=False,
+            )
+        )
+        logits, k, v = step(p, k, v)
+        dec, _, _ = jax.jit(
+            lambda p, k, v: m.decode(p, cfg, k, v, toks, positions, tables, active)
+        )(p, k, v)
+        return np.asarray(logits), np.asarray(dec[0])
+
+    cache = KvCacheArrays.create(cfg, 16, dtype=jnp.float32)
+    ref = run(llama, params, cache.k, cache.v)
+    sh_cache = KvCacheArrays.create(
+        cfg, 16, dtype=jnp.float32,
+        sharding=NamedSharding(mesh, kv_cache_spec(cfg.num_kv_heads, 2)),
+    )
+    got = run(model, shard_params(params, mesh, cfg.tie_word_embeddings), sh_cache.k, sh_cache.v)
+    for a, b in zip(got, ref):
+        np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-4)
+
+
+def test_tp_indivisible_kv_heads_take_xla_paths():
+    """KV heads that do not divide by tp replicate the cache; the kernels
+    cannot partition, so the resolution — observed from the mesh — is the
+    XLA gather and XLA prefill, not an error at first compile."""
+    from dynamo_tpu.engine.sharding import bind_mesh
+
+    cfg = CFG.replace(attention_impl="megakernel", prefill_impl="flash")
+    model = bind_mesh(llama, build_mesh(ParallelConfig(tp=4)))  # tiny: 2 KV heads
+    assert model.resolve_attention_impl(cfg, None) == "gather"
+    assert model.resolve_prefill_impl(cfg) == "xla"
+    assert llama.resolve_attention_impl(cfg, None) == "megakernel"
+    assert llama.resolve_prefill_impl(cfg) == "flash"

@@ -1,0 +1,270 @@
+"""AOT compiles for a described (not attached) v5e: the rehearsal that costs no
+chip time. The TPU compiler installed with JAX compiles the main path's
+kernels and whole step programs at Llama-3.2-1B widths for a ``v5e:2x2``
+topology — what it refuses here it would refuse on the chip (unaligned
+slices, VMEM, a Mosaic kernel that cannot be partitioned). Nothing runs: a
+pass here is not a chip run and is never reported as one.
+
+Rules this file keeps (``on-chip-measurement`` guide, section 2): the
+topology is described inside a module-scoped fixture — never at import, never
+in conftest, not autouse — because only one process may load libtpu; every
+compile happens in the test's own process; all such tests live in this one
+file. ``llama._on_tpu`` is steered by monkeypatch here, not by an option of
+the program.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSharding
+
+from dynamo_tpu.engine.config import get_config
+from dynamo_tpu.engine.kv_cache import QuantKv
+from dynamo_tpu.engine.models import llama
+from dynamo_tpu.engine.sharding import bind_mesh, kv_cache_spec, param_specs
+
+CFG = get_config("llama-3.2-1b")  # 16 layers, hidden 2048, 32/8 heads, HD 64, vocab 128256
+H, KVH, HD, BS = CFG.num_heads, CFG.num_kv_heads, CFG.head_dim, CFG.block_size
+NUM_BLOCKS = 512  # run.py's default --num-blocks
+BF16 = jnp.bfloat16
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")  # or the compiler logs under /tmp
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        t = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — any failure to describe is a skip
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # A described device's executable is written to the persistent cache but
+    # cannot be read back without a chip: keep the cache off around these.
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield t
+    jax.config.update("jax_enable_compilation_cache", was)
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def tp4(topo):
+    import numpy as np
+
+    return Mesh(np.array(topo.devices).reshape(1, 1, 1, 1, 4), ("dp", "pp", "sp", "ep", "tp"))
+
+
+@pytest.fixture
+def on_tpu(monkeypatch):
+    """The program's one backend question answers "tpu": kernels compile
+    (never interpret) and "auto" resolves as it would on the chip."""
+    monkeypatch.setattr(llama, "_on_tpu", lambda: True)
+
+
+def _sds(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _pages(sh, quant=False, n=16 * NUM_BLOCKS):
+    if quant:
+        return QuantKv(_sds((n, BS, KVH, HD), jnp.int8, sh), _sds((n, BS, KVH, 1), jnp.float32, sh))
+    return _sds((n, BS, KVH, HD), BF16, sh)
+
+
+def _model_args(param_sh, cache_sh):
+    """(params, k_cache, v_cache) as shapes: ``param_sh`` maps a param's path
+    spec to a sharding, the cache takes ``cache_sh``."""
+    shapes = jax.eval_shape(lambda: llama.init_params(CFG, jax.random.PRNGKey(0), dtype=BF16))
+    params = jax.tree.map(lambda s, sh: _sds(s.shape, s.dtype, sh), shapes, param_sh(shapes))
+    cache = _sds((CFG.num_layers, NUM_BLOCKS, BS, KVH, HD), BF16, cache_sh)
+    return params, cache, cache
+
+
+def _one_chip_args(one_chip):
+    return _model_args(lambda shapes: jax.tree.map(lambda _: one_chip, shapes), one_chip)
+
+
+# --- kernels ----------------------------------------------------------------
+
+
+@pytest.mark.parametrize(
+    "nq,ck,rows,width,quant",
+    [(8, 8, 8, 64, False), (520, 520, 9, 64, False), (8, 8, 8, 64, True)],
+    ids=["decode-b8", "mixed-chunk512+b8", "decode-b8-int8kv"],
+)
+def test_ragged_paged_attention_compiles(one_chip, nq, ck, rows, width, quant):
+    from dynamo_tpu.engine.attention.megakernel import ragged_paged_attention
+
+    i32 = jnp.int32
+    compiled = ragged_paged_attention.lower(
+        _sds((nq, H, HD), BF16, one_chip),
+        _sds((ck, KVH, HD), BF16, one_chip), _sds((ck, KVH, HD), BF16, one_chip),
+        _pages(one_chip, quant), _pages(one_chip, quant),
+        _sds((rows, width), i32, one_chip), _sds((5, nq), i32, one_chip),
+        num_kv_heads=KVH, block_size=BS, interpret=False,
+    ).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("T", [512, 2048])
+def test_flash_chunk_attention_compiles(one_chip, T):
+    from dynamo_tpu.engine.attention.prefill import flash_chunk_attention
+
+    compiled = flash_chunk_attention.lower(
+        _sds((T, H, HD), BF16, one_chip),
+        _sds((T, KVH, HD), BF16, one_chip), _sds((T, KVH, HD), BF16, one_chip),
+        _sds((), jnp.int32, one_chip),
+        num_kv_heads=KVH, interpret=False,
+    ).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+# --- whole step programs of the 16-layer 1B on one chip -----------------------
+
+B, W = 8, 64  # decode batch bucket, block-table width (1024 tokens)
+
+
+def _decode_io(sh):
+    i32 = jnp.int32
+    return dict(
+        tokens=_sds((B,), i32, sh), positions=_sds((B,), i32, sh),
+        tables=_sds((B, W), i32, sh), active=_sds((B,), jnp.bool_, sh),
+        temps=_sds((B,), jnp.float32, sh), top_ks=_sds((B,), i32, sh),
+        top_ps=_sds((B,), jnp.float32, sh), key=_sds((2,), jnp.uint32, sh),
+    )
+
+
+def _compile(fn, *args):
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()  # the Pallas kernel is in, not a fallback
+    return compiled
+
+
+def test_decode_step_compiles(one_chip, on_tpu):
+    p, k, v = _one_chip_args(one_chip)
+    io = _decode_io(one_chip)
+    assert llama.resolve_attention_impl(CFG, k) == "megakernel"
+    _compile(
+        lambda p, k, v, t, pos, bt, act: llama.decode(p, CFG, k, v, t, pos, bt, act),
+        p, k, v, io["tokens"], io["positions"], io["tables"], io["active"],
+    )
+
+
+@pytest.mark.parametrize("attention_impl", ["auto", "gather"], ids=["megakernel-rows", "flash-kernel"])
+def test_prefill_step_compiles(one_chip, on_tpu, attention_impl):
+    """T 512 fresh prefill as the scheduler jits it (``use_flash=True``): under
+    the megakernel the chunk is one ragged row of that kernel; with the gather
+    the chunk runs the flash kernel."""
+    cfg = CFG.replace(attention_impl=attention_impl)
+    assert llama.resolve_prefill_impl(cfg) == "flash"
+    p, k, v = _one_chip_args(one_chip)
+    i32 = jnp.int32
+    _compile(
+        lambda p, k, v, t, vl, cl, bt: llama.prefill(
+            p, cfg, k, v, t, vl, cl, bt, use_flash=True, has_prefix=False
+        ),
+        p, k, v, _sds((512,), i32, one_chip), _sds((), i32, one_chip),
+        _sds((), i32, one_chip), _sds((32,), i32, one_chip),
+    )
+
+
+def test_mixed_step_compiles(one_chip, on_tpu):
+    p, k, v = _one_chip_args(one_chip)
+    io = _decode_io(one_chip)
+    i32 = jnp.int32
+    _compile(
+        lambda p, k, v, pt, pv, cl, ptab, dt, dpos, dtab, dact: llama.mixed_step(
+            p, CFG, k, v, pt, pv, cl, ptab, dt, dpos, dtab, dact,
+            use_flash=True, has_prefix=False,
+        ),
+        p, k, v, _sds((512,), i32, one_chip), _sds((), i32, one_chip),
+        _sds((), i32, one_chip), _sds((32,), i32, one_chip),
+        io["tokens"], io["positions"], io["tables"], io["active"],
+    )
+
+
+def test_decode_multi_window32_compiles(one_chip, on_tpu):
+    p, k, v = _one_chip_args(one_chip)
+    io = _decode_io(one_chip)
+    _compile(
+        lambda p, k, v, t, pos, bt, act, te, tk, tp, key: llama.decode_multi(
+            p, CFG, k, v, t, pos, bt, act, te, tk, tp, key, 32
+        ),
+        p, k, v, io["tokens"], io["positions"], io["tables"], io["active"],
+        io["temps"], io["top_ks"], io["top_ps"], io["key"],
+    )
+
+
+def test_decode_sample_compiles(one_chip, on_tpu):
+    p, k, v = _one_chip_args(one_chip)
+    io = _decode_io(one_chip)
+    _compile(
+        lambda p, k, v, tpa, bt, te, tk, tp, key: llama.decode_sample(
+            p, CFG, k, v, tpa, bt, te, tk, tp, key
+        ),
+        p, k, v, _sds((3, B), jnp.int32, one_chip), io["tables"],
+        io["temps"], io["top_ks"], io["top_ps"], io["key"],
+    )
+
+
+# --- tp=4 over the described 2x2: the kernels must partition -------------------
+
+
+def _tp4_args(mesh):
+    specs = param_specs(CFG.tie_word_embeddings)
+    return _model_args(
+        lambda shapes: jax.tree.map(
+            lambda _, s: NamedSharding(mesh, s), shapes, specs,
+        ),
+        NamedSharding(mesh, kv_cache_spec(KVH, 4)),
+    )
+
+
+def _assert_partitioned(compiled, per_device_limit):
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text, "the Pallas kernel is not in the tp=4 program"
+    assert "all-reduce" in text, "no tp all-reduce: the program is not partitioned"
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes < per_device_limit, mem.argument_size_in_bytes
+
+
+def test_tp4_decode_step_partitions(tp4, on_tpu):
+    """``--tp 4`` on a real host: ``llama.decode`` traced under the engine's
+    mesh compiles with the megakernel inside a shard_map (8/2 heads per
+    shard) — GSPMD alone refuses a Mosaic kernel."""
+    model = bind_mesh(llama, tp4)
+    p, k, v = _tp4_args(tp4)
+    io = _decode_io(NamedSharding(tp4, P()))
+    assert model.resolve_attention_impl(CFG, k) == "megakernel"
+    compiled = jax.jit(
+        lambda p, k, v, t, pos, bt, act: model.decode(p, CFG, k, v, t, pos, bt, act)
+    ).lower(p, k, v, io["tokens"], io["positions"], io["tables"], io["active"]).compile()
+    # 2.47 GB of bf16 weights + 0.27 GB cache on one chip → about a quarter each.
+    _assert_partitioned(compiled, per_device_limit=1 << 30)
+
+
+@pytest.mark.parametrize("attention_impl", ["auto", "gather"], ids=["megakernel-rows", "flash-kernel"])
+def test_tp4_prefill_step_partitions(tp4, on_tpu, attention_impl):
+    cfg = CFG.replace(attention_impl=attention_impl)
+    model = bind_mesh(llama, tp4)
+    assert model.resolve_prefill_impl(cfg) == "flash"
+    p, k, v = _tp4_args(tp4)
+    rep = NamedSharding(tp4, P())
+    i32 = jnp.int32
+    compiled = jax.jit(
+        lambda p, k, v, t, vl, cl, bt: model.prefill(
+            p, cfg, k, v, t, vl, cl, bt, use_flash=True, has_prefix=False
+        )
+    ).lower(
+        p, k, v, _sds((512,), i32, rep), _sds((), i32, rep), _sds((), i32, rep),
+        _sds((32,), i32, rep),
+    ).compile()
+    _assert_partitioned(compiled, per_device_limit=1 << 30)

@@ -31,8 +31,12 @@ from __future__ import annotations
 
 import argparse
 import json
+import os
 import sys
 from typing import Dict, List, Optional, Tuple
+
+# Run as a file from a bare checkout: the package sits one directory up.
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 from dynamo_tpu.runtime.incidents import BUNDLE_SCHEMA
 from dynamo_tpu.runtime.telemetry import LatencyDigest

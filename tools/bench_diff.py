@@ -6,7 +6,7 @@ only a number until it's placed against the previous one — and eyeballing
 two 2000-char JSON blobs is how a 15% decode regression ships. This tool
 makes the comparison mechanical:
 
-    python tools/bench_diff.py BENCH_r04.json BENCH_r05.json
+    python tools/bench_diff.py BENCH_r05.json new.json
     python tools/bench_diff.py --latest            # two newest rounds in repo
     python tools/bench_diff.py old.json new.json --strict   # rc=1 on regression
 

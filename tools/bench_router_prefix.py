@@ -33,9 +33,13 @@ Usage: python tools/bench_router_prefix.py [--quick]
 
 import asyncio
 import json
+import os
 import random
 import sys
 import time
+
+# Run as a file from a bare checkout: the package sits one directory up.
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 from dynamo_tpu.llm.kv_router import (
     KvEventPublisher,

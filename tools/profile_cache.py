@@ -12,12 +12,16 @@ Variants (all with the real weights scan + lm_head):
 from __future__ import annotations
 
 import os
+import sys
 import time
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+
+# Run as a file from a bare checkout: the package sits one directory up.
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 from dynamo_tpu.engine.config import get_config
 from dynamo_tpu.engine.models import llama

@@ -11,9 +11,13 @@ Usage: python tools/profile_serving.py [n_requests] [concurrency]
 import asyncio
 import cProfile
 import io
+import os
 import pstats
 import sys
 import time
+
+# Run as a file from a bare checkout: the package sits one directory up.
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 import bench
 

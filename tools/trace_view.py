@@ -32,9 +32,13 @@ from __future__ import annotations
 
 import argparse
 import json
+import os
 import sys
 from collections import defaultdict
 from typing import Dict, List
+
+# Run as a file from a bare checkout: the package sits one directory up.
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 from dynamo_tpu.runtime.tracing import chrome_trace, read_trace_file
 from dynamo_tpu.runtime.telemetry import LatencyDigest

@@ -46,10 +46,15 @@ import argparse
 import asyncio
 import json
 import math
+import os
 import random
+import sys
 import time
 from dataclasses import asdict, dataclass, field
 from typing import Any, Callable, Dict, List, Optional
+
+# Run as a file from a bare checkout: the package sits one directory up.
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 from dynamo_tpu.runtime.logging import get_logger
 
