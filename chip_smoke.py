@@ -576,4 +576,10 @@ def main() -> int:
 
 
 if __name__ == "__main__":
-    sys.exit(main())
+    code = main()
+    sys.stdout.flush()
+    sys.stderr.flush()
+    # No interpreter finalization: a daemon thread of the program (profiler capture, trace writer) that
+    # returns from native code while Python tears down is unwound by force and aborts the process with
+    # "FATAL: exception not rethrown" after the result line (seen once in six-worker test runs).
+    os._exit(code)

@@ -18,6 +18,7 @@ never stalls.
 from __future__ import annotations
 
 import asyncio
+import time
 from dataclasses import dataclass, field
 from typing import Any, AsyncIterator, Callable, List, Optional
 
@@ -326,20 +327,20 @@ class TpuEngine:
                     else:
                         await self._wake.wait()
                     continue
-                for rid, tokens, sampling, stop, queue, extras in self._staged_adds:
-                    try:
-                        seq = self.scheduler.add_request(rid, tokens, sampling, stop, **extras)
-                        seq.out_queue = queue
-                    except ValueError as e:
-                        queue.put_nowait(StepOutput(token_id=-1, finished=True, finish_reason=f"error:{e}"))
-                self._staged_adds.clear()
-                for rid in self._staged_aborts:
-                    self.scheduler.abort(rid)
-                self._staged_aborts.clear()
-
-                outputs = await asyncio.to_thread(self.scheduler.step)
-                for seq, out in outputs:
-                    seq.out_queue.put_nowait(out)
+                # One iteration with work: ``engine.loop`` is the parent of the
+                # staging, the scheduler's step (on the step thread) and the
+                # delivery; all carry the step's sequence number.
+                log = self.scheduler.flight.log
+                step = log.step + 1
+                with log.span("engine.loop", step=step):
+                    if self._staged_adds or self._staged_aborts:
+                        with log.span("engine.stage", step=step, adds=len(self._staged_adds),
+                                      aborts=len(self._staged_aborts)):
+                            self._stage()
+                    outputs = await asyncio.to_thread(self.scheduler.step)
+                    with log.span("engine.deliver", step=step, outputs=len(outputs)):
+                        for seq, out in outputs:
+                            seq.out_queue.put_nowait(out)
         except Exception:
             logger.exception("engine step loop crashed")
             # Engine death: fail all in-flight requests so streams end and the
@@ -349,8 +350,23 @@ class TpuEngine:
                 seq.out_queue.put_nowait(StepOutput(token_id=-1, finished=True, finish_reason="error:engine_dead"))
             raise
 
+    def _stage(self) -> None:
+        """Hand the requests and aborts that arrived while the last step ran
+        to the scheduler (only this task mutates it)."""
+        for rid, tokens, sampling, stop, queue, extras in self._staged_adds:
+            try:
+                seq = self.scheduler.add_request(rid, tokens, sampling, stop, **extras)
+                seq.out_queue = queue
+            except ValueError as e:
+                queue.put_nowait(StepOutput(token_id=-1, finished=True, finish_reason=f"error:{e}"))
+        self._staged_adds.clear()
+        for rid in self._staged_aborts:
+            self.scheduler.abort(rid)
+        self._staged_aborts.clear()
+
     # --- AsyncEngine --------------------------------------------------------
     async def generate(self, request: Any, context: Context) -> AsyncIterator[dict]:
+        enqueued_ts = time.monotonic()  # the staged wait (until add_request) starts here
         self.start()
         rid = context.id
         sampling_d = request.get("sampling_options") or {}
@@ -384,6 +400,7 @@ class TpuEngine:
             # Capacity-ledger attribution (runtime/ledger.py): resolved by
             # the frontend, billed by the scheduler.
             "tenant": request.get("tenant") or "anon",
+            "enqueued_ts": enqueued_ts,
         }
         guided = request.get("guided_decoding")
         if guided is not None:

@@ -28,6 +28,7 @@ from collections import deque
 from typing import Dict, List, Optional, Set, Tuple
 
 from dynamo_tpu.runtime.logging import get_logger
+from dynamo_tpu.runtime.tracing import StepLog
 
 logger = get_logger(__name__)
 
@@ -37,6 +38,12 @@ STEP_BUCKETS: Tuple[float, ...] = (
 )
 
 PHASES = ("prefill", "decode", "mixed", "wave", "spec")
+
+# Name of the step-log entry ``record_step`` writes: one per dispatch, its
+# interval the dispatch's timed part, ``attrs`` its phase and tokens — the
+# /debug/state and incident-bundle ``recent_steps`` timeline is read from it.
+STEP_RECORD = "flight.step"
+RECENT_STEPS = 64
 
 # Host-gap buckets: the decode pipeline's subject is the SUB-millisecond
 # window between a dispatch returning and the next dispatch being issued —
@@ -263,9 +270,12 @@ class FlightRecorder:
         # record_step cost-free for schedulers that never attach one.
         self.cost_model: Optional[StepCostModel] = None
         self._roofline: Dict[str, _PhaseRoofline] = {}
-        # Stall watchdog reference point + /debug/state step timeline.
+        # The engine's step log (runtime/tracing.py): every span of the
+        # served path and every finished request, and — through record_step's
+        # own entries — the /debug/state step timeline.
+        self.log = StepLog()
+        # Stall watchdog reference point.
         self.last_step_ts: Optional[float] = None
-        self.recent_steps: deque = deque(maxlen=64)  # (ts, phase, dur_s, tokens)
         # Decode host gap: time from a decode dispatch RETURNING (device
         # launched, host free) to the NEXT decode dispatch being issued —
         # the bubble the overlap pipeline exists to close. Only consecutive
@@ -380,10 +390,7 @@ class FlightRecorder:
         if h is None:
             h = self._hists.setdefault(phase, _PhaseHist())
         h.observe(dur_s, tokens)
-        self.last_step_phase = phase
-        self.last_step_s = dur_s
-        self.last_step_ts = time.monotonic()
-        self.recent_steps.append((self.last_step_ts, phase, round(dur_s, 6), tokens))
+        self._log_step(phase, dur_s, tokens)
         if self.telemetry is not None:
             self.telemetry.observe(f"{phase}_step", dur_s)
         if self.cost_model is not None:
@@ -391,6 +398,25 @@ class FlightRecorder:
                 tokens, kv_read_tokens, param_passes
             )
             self._record_roofline(phase, flops, bytes_moved, dur_s)
+
+    def _log_step(self, phase: str, dur_s: float, tokens: int) -> None:
+        now = time.monotonic_ns()
+        self.last_step_phase = phase
+        self.last_step_s = dur_s
+        self.last_step_ts = now / 1e9  # time.monotonic()'s clock
+        self.log.spans.append(
+            (STEP_RECORD, now - int(dur_s * 1e9), now, self.log.step, {"phase": phase, "tokens": tokens})
+        )
+
+    def recent_steps(self, n: int = RECENT_STEPS) -> List[dict]:
+        """The newest ``n`` dispatches, oldest first, in the shape
+        /debug/state, incident bundles and ``tools/autopsy.py`` read."""
+        now = time.monotonic_ns()
+        return [
+            {"age_s": round((now - t1) / 1e9, 3), "phase": a["phase"],
+             "dur_s": round((t1 - t0) / 1e9, 6), "tokens": a["tokens"]}
+            for _, t0, t1, _, a in self.log.named(STEP_RECORD, n)
+        ]
 
     def _record_roofline(
         self, phase: str, flops: float, bytes_moved: float, dur_s: float
@@ -420,12 +446,7 @@ class FlightRecorder:
         bytes-bound, so a 50/50 token split is NOT a 50/50 time split)."""
         h = self._hists["mixed"]
         h.observe(dur_s, prefill_tokens + decode_tokens)
-        self.last_step_phase = "mixed"
-        self.last_step_s = dur_s
-        self.last_step_ts = time.monotonic()
-        self.recent_steps.append(
-            (self.last_step_ts, "mixed", round(dur_s, 6), prefill_tokens + decode_tokens)
-        )
+        self._log_step("mixed", dur_s, prefill_tokens + decode_tokens)
         if self.telemetry is not None:
             self.telemetry.observe("mixed_step", dur_s)
         if self.cost_model is None:
@@ -578,10 +599,7 @@ class FlightRecorder:
         the seconds before the trigger" without the live process."""
         now = time.monotonic()
         return {
-            "recent_steps": [
-                {"age_s": round(now - ts, 3), "phase": ph, "dur_s": d, "tokens": t}
-                for ts, ph, d, t in list(self.recent_steps)
-            ],
+            "recent_steps": self.recent_steps(),
             "last_step_phase": self.last_step_phase,
             "last_step_age_s": (
                 round(now - self.last_step_ts, 3) if self.last_step_ts is not None else None
@@ -596,18 +614,3 @@ class FlightRecorder:
             "compiles_after_warmup_total": self.compiles_after_warmup_total,
             "post_warmup_keys": [str(k) for k in self.post_warmup_keys[-16:]],
         }
-
-
-class StepTimer:
-    """Tiny context helper: ``with StepTimer() as t: ...; flight.record_step
-    (phase, t.dur, n)`` without try/finally noise at each dispatch site."""
-
-    __slots__ = ("t0", "dur")
-
-    def __enter__(self) -> "StepTimer":
-        self.t0 = time.perf_counter()
-        self.dur = 0.0
-        return self
-
-    def __exit__(self, exc_type, exc, tb) -> None:
-        self.dur = time.perf_counter() - self.t0

@@ -40,14 +40,14 @@ import jax.numpy as jnp
 import numpy as np
 
 from dynamo_tpu.engine.config import ModelConfig
-from dynamo_tpu.engine.flight_recorder import FlightRecorder, StepCostModel, StepTimer
+from dynamo_tpu.engine.flight_recorder import FlightRecorder, StepCostModel
 from dynamo_tpu.engine.kv_cache import BlockAllocator, KvCacheArrays, KvEvent, OutOfBlocksError
 from dynamo_tpu.runtime.ledger import RequestBill, TenantLedger
 from dynamo_tpu.runtime.telemetry import SloConfig, SloJudge, Telemetry
 from dynamo_tpu.engine.sampling import SamplingParams, guided_sample_batch, sample_batch
 from dynamo_tpu.llm.tokens import extend_block_hashes
 from dynamo_tpu.runtime.logging import get_logger
-from dynamo_tpu.runtime.tracing import get_tracer
+from dynamo_tpu.runtime.tracing import StepSpan, get_tracer
 
 logger = get_logger(__name__)
 
@@ -147,9 +147,17 @@ class Sequence:
     num_cached_blocks: int = 0  # prefix blocks reused from cache
     cached_tokens: int = 0  # prompt tokens skipped by the prefix cache
     out_queue: "asyncio.Queue[Optional[StepOutput]]" = field(default_factory=asyncio.Queue)
+    # When TpuEngine.generate took the request (time.monotonic); it then sits
+    # staged until the running dispatch returns and add_request stamps
+    # arrival_ts. None for requests added to a bare Scheduler.
+    enqueued_ts: Optional[float] = None
     arrival_ts: float = field(default_factory=time.monotonic)
     admitted_ts: Optional[float] = None  # first engine work (queue-time end)
     first_token_ts: Optional[float] = None
+    # Request record (step log): dispatches that carried prompt tokens, and the
+    # scheduler iteration of the first engine work.
+    prefill_chunks: int = 0
+    first_step: Optional[int] = None
     aborted: bool = False
     abort_reason: str = "cancelled"
     # Absolute eviction deadline (arrival + stop.deadline_ms); None = no
@@ -513,34 +521,36 @@ class Scheduler:
         self._aux_lock = threading.Lock()
         # llama-only kwargs (MLA's forward has its own signature).
         stats_kw = {"moe_stats": True} if self._moe_stats else {}
+        # Every step program is a NAMED function: the profiler's "XLA
+        # Modules" line then reads jit_<kind>(...) (the flight recorder's
+        # kind, with the window rung where there is one), so a program's
+        # device time is found by name, without host marks.
         if self._use_flash_prefill:
-            self._prefill_jit = jax.jit(
-                lambda p, k, v, t, vl, cl, bt, hp: model.prefill(
-                    p, self.mc, k, v, t, vl, cl, bt, use_flash=True, has_prefix=hp,
-                    **stats_kw,
-                ),
-                donate_argnums=(1, 2),
-                static_argnums=(7,),
-            )
+
+            def prefill(p, k, v, t, vl, cl, bt, hp):
+                return model.prefill(
+                    p, self.mc, k, v, t, vl, cl, bt, use_flash=True, has_prefix=hp, **stats_kw
+                )
+
+            self._prefill_jit = jax.jit(prefill, donate_argnums=(1, 2), static_argnums=(7,))
         else:
             # ``hp`` rides as a TRACED (unused) arg here: the XLA path's
             # masks cover prefix and fresh prefills alike, and a static arg
             # would compile two byte-identical executables per bucket.
-            self._prefill_jit = jax.jit(
-                lambda p, k, v, t, vl, cl, bt, hp: model.prefill(
-                    p, self.mc, k, v, t, vl, cl, bt, **stats_kw
-                ),
-                donate_argnums=(1, 2),
-            )
+            def prefill(p, k, v, t, vl, cl, bt, hp):
+                return model.prefill(p, self.mc, k, v, t, vl, cl, bt, **stats_kw)
+
+            self._prefill_jit = jax.jit(prefill, donate_argnums=(1, 2))
+
         # tokens/positions/active ride ONE packed [3, bucket] i32 upload and
         # split in-jit — three small per-step H2D transfers collapsed into
         # one.
-        self._decode_jit = jax.jit(
-            lambda p, k, v, tpa, bt: model.decode(
+        def decode(p, k, v, tpa, bt):
+            return model.decode(
                 p, self.mc, k, v, tpa[0], tpa[1], bt, tpa[2].astype(bool), **stats_kw
-            ),
-            donate_argnums=(1, 2),
-        )
+            )
+
+        self._decode_jit = jax.jit(decode, donate_argnums=(1, 2))
         self._sample_jit = jax.jit(sample_batch)
         # Logprobs folded into the sampling dispatch (one executable, one
         # readback) — the separate compute_logprobs op cost an extra device
@@ -565,15 +575,20 @@ class Scheduler:
         # when a table actually changes.
         self._supports_overlap = hasattr(model, "decode_sample")
         if self._supports_overlap:
-            self._decode_sample_jit = jax.jit(
-                lambda p, k, v, tpa, bt, te, tk, tp, key: model.decode_sample(
+
+            def decode_sample(p, k, v, tpa, bt, te, tk, tp, key):
+                return model.decode_sample(
                     p, self.mc, k, v, tpa, bt, te, tk, tp, key, **stats_kw
-                ),
-                donate_argnums=(1, 2),
-            )
+                )
+
+            self._decode_sample_jit = jax.jit(decode_sample, donate_argnums=(1, 2))
         self._pipe: Optional[dict] = None
         self._tables_cache: Optional[tuple] = None
-        self._last_decode_dispatch_t: Optional[float] = None
+        # Step-phase spans (runtime/tracing.py): the iteration in progress
+        # and its open plan phase, which crosses from step() into whichever
+        # dispatch path forms the batch.
+        self._step_span: Optional[StepSpan] = None
+        self._plan_span: Optional[StepSpan] = None
         self.overlap_steps_total = 0
         self.overlap_flushes_total = 0
         # Deferred-retirement KV rollback: zero the slot the speculative
@@ -586,10 +601,10 @@ class Scheduler:
                 return QuantKv(c.q.at[:, b, o].set(0), c.scale.at[:, b, o].set(0))
             return c.at[:, b, o].set(jnp.zeros((), c.dtype))
 
-        self._kv_zero_jit = jax.jit(
-            lambda k, v, b, o: (_zero_slot(k, b, o), _zero_slot(v, b, o)),
-            donate_argnums=(0, 1),
-        )
+        def kv_rollback(k, v, b, o):
+            return _zero_slot(k, b, o), _zero_slot(v, b, o)
+
+        self._kv_zero_jit = jax.jit(kv_rollback, donate_argnums=(0, 1))
         # Prefix-cache copy-on-write: duplicate one block's contents into a
         # private block (full-cover hits recompute only the LAST prompt
         # token, whose KV write would otherwise land in a block other
@@ -601,10 +616,10 @@ class Scheduler:
                 return QuantKv(c.q.at[:, dst].set(c.q[:, src]), c.scale.at[:, dst].set(c.scale[:, src]))
             return c.at[:, dst].set(c[:, src])
 
-        self._kv_copy_jit = jax.jit(
-            lambda k, v, s, d: (_copy_block_arr(k, s, d), _copy_block_arr(v, s, d)),
-            donate_argnums=(0, 1),
-        )
+        def kv_block_copy(k, v, s, d):
+            return _copy_block_arr(k, s, d), _copy_block_arr(v, s, d)
+
+        self._kv_copy_jit = jax.jit(kv_block_copy, donate_argnums=(0, 1))
         # Prefix-cache accounting: reuse is only "automatic" if it is
         # visible — cached_tokens flows request-level (StepOutput → usage)
         # and these totals flow through stats → aggregator → Grafana.
@@ -647,13 +662,14 @@ class Scheduler:
             # 32-step window wastes half the dispatch). _decode_multi picks
             # the smallest rung covering the batch's remaining budget.
             def mk_multi(steps: int):
-                return jax.jit(
-                    lambda p, k, v, t, pos, bt, act, te, tk, tp, key: model.decode_multi(
+                def decode_multi(p, k, v, t, pos, bt, act, te, tk, tp, key):
+                    return model.decode_multi(
                         p, self.mc, k, v, t, pos, bt, act, te, tk, tp, key,
                         steps, **stats_kw,
-                    ),
-                    donate_argnums=(1, 2),
-                )
+                    )
+
+                decode_multi.__name__ = f"decode_multi_w{steps}"
+                return jax.jit(decode_multi, donate_argnums=(1, 2))
 
             self._window_rungs = sorted(
                 {w for w in (8, 16, self.sc.num_scheduler_steps) if w <= self.sc.num_scheduler_steps}
@@ -691,34 +707,36 @@ class Scheduler:
             # distributions, so one executable covers mixed batches).
             def mk_fused(steps: int, sampled: bool, guided: bool):
                 if not sampled and not guided:
-                    return jax.jit(
-                        lambda p, k, v, t, pos, bt, act: model.decode_multi_fused(
+
+                    def decode_fused(p, k, v, t, pos, bt, act):
+                        return model.decode_multi_fused(
                             p, self.mc, k, v, t, pos, bt, act, steps
-                        ),
-                        donate_argnums=(1, 2),
-                    )
+                        )
+
+                    decode_fused.__name__ = f"decode_fused_w{steps}"
+                    return jax.jit(decode_fused, donate_argnums=(1, 2))
                 if not guided:
-                    return jax.jit(
-                        lambda p, k, v, t, pos, bt, act, te, tk, tp, u: (
-                            model.decode_multi_fused(
-                                p, self.mc, k, v, t, pos, bt, act, steps,
-                                temps=te, top_ks=tk, top_ps=tp, uniforms=u,
-                                sampled=True,
-                            )
-                        ),
-                        donate_argnums=(1, 2),
-                    )
-                return jax.jit(
-                    lambda p, k, v, t, pos, bt, act, te, tk, tp, u, rows, mp, xp: (
-                        model.decode_multi_fused(
+
+                    def decode_fused_sampled(p, k, v, t, pos, bt, act, te, tk, tp, u):
+                        return model.decode_multi_fused(
                             p, self.mc, k, v, t, pos, bt, act, steps,
                             temps=te, top_ks=tk, top_ps=tp, uniforms=u,
-                            guided_rows=rows, mask_pool=mp, next_pool=xp,
-                            sampled=True, guided=True,
+                            sampled=True,
                         )
-                    ),
-                    donate_argnums=(1, 2),
-                )
+
+                    decode_fused_sampled.__name__ = f"decode_fused_sampled_w{steps}"
+                    return jax.jit(decode_fused_sampled, donate_argnums=(1, 2))
+
+                def decode_fused_guided(p, k, v, t, pos, bt, act, te, tk, tp, u, rows, mp, xp):
+                    return model.decode_multi_fused(
+                        p, self.mc, k, v, t, pos, bt, act, steps,
+                        temps=te, top_ks=tk, top_ps=tp, uniforms=u,
+                        guided_rows=rows, mask_pool=mp, next_pool=xp,
+                        sampled=True, guided=True,
+                    )
+
+                decode_fused_guided.__name__ = f"decode_fused_guided_w{steps}"
+                return jax.jit(decode_fused_guided, donate_argnums=(1, 2))
 
             self._decode_fused_jits = {
                 (w, s, g): mk_fused(w, s, g)
@@ -769,12 +787,12 @@ class Scheduler:
         self.spec_stats = SpecDecodeStats()
         dc = draft_config
         model = self._model  # llama-family (checked above), under this engine's mesh
-        self._d_prefill_jit = jax.jit(
-            lambda p, k, v, t, vl, cl, bt: model.prefill(p, dc, k, v, t, vl, cl, bt),
-            donate_argnums=(1, 2),
-        )
+        def draft_prefill(p, k, v, t, vl, cl, bt):
+            return model.prefill(p, dc, k, v, t, vl, cl, bt)
 
-        def d_chunk_sample(p, k, v, t, pos, val, bt, te, tk, tp, key):
+        self._d_prefill_jit = jax.jit(draft_prefill, donate_argnums=(1, 2))
+
+        def spec_draft_chunk(p, k, v, t, pos, val, bt, te, tk, tp, key):
             # Draft catch-up chunk + FIRST proposal sampled from the row's
             # last valid position with its own sampling params (greedy rows
             # reduce to argmax). Returns the dist too — spec_verify needs it.
@@ -785,14 +803,15 @@ class Scheduler:
             tok = sample_batch(last, te, tk, tp, key)
             return tok.astype(jnp.int32), last, k, v
 
-        self._d_chunk_sample_jit = jax.jit(d_chunk_sample, donate_argnums=(1, 2))
+        self._d_chunk_sample_jit = jax.jit(spec_draft_chunk, donate_argnums=(1, 2))
         t_stats_kw = {"moe_stats": True} if self._moe_stats else {}
-        self._t_chunk_jit = jax.jit(
-            lambda p, k, v, t, pos, val, bt: model.chunk_decode(
+
+        def spec_target_chunk(p, k, v, t, pos, val, bt):
+            return model.chunk_decode(
                 p, self.mc, k, v, t, pos, val, bt, all_logits=True, **t_stats_kw
-            ),
-            donate_argnums=(1, 2),
-        )
+            )
+
+        self._t_chunk_jit = jax.jit(spec_target_chunk, donate_argnums=(1, 2))
         from dynamo_tpu.engine.spec_decode import spec_verify
 
         self._spec_verify_jit = jax.jit(spec_verify)
@@ -800,13 +819,14 @@ class Scheduler:
             # On-device window for proposals 2..γ: one dispatch + one sync
             # instead of γ-1 round-trips; samples with the rows' REAL
             # params and returns per-step logits for rejection sampling.
-            self._d_multi_jit = jax.jit(
-                lambda p, k, v, t, pos, bt, act, te, tk, tp, key: model.decode_multi(
+            def spec_draft_multi(p, k, v, t, pos, bt, act, te, tk, tp, key):
+                return model.decode_multi(
                     p, dc, k, v, t, pos, bt, act, te, tk, tp, key, gamma - 1,
                     return_logits=True,
-                ),
-                donate_argnums=(1, 2),
-            )
+                )
+
+            spec_draft_multi.__name__ = f"spec_draft_multi_w{gamma - 1}"
+            self._d_multi_jit = jax.jit(spec_draft_multi, donate_argnums=(1, 2))
         # Fused speculative window: R whole draft+verify rounds in ONE
         # pallas launch (megakernel.fused_spec_window) — both models'
         # weights and caches VMEM-resident, accepted bursts advancing the
@@ -839,16 +859,15 @@ class Scheduler:
             # worst-case token span equal to the plain fused window's.
             self._spec_rounds = max(1, self.sc.num_scheduler_steps // (gamma + 1))
             rounds = self._spec_rounds
-            self._spec_fused_jit = jax.jit(
-                lambda p, dp, kt, vt, kd, vd, t, xp, pos, bt, act, te, tk, tp, u: (
-                    model.decode_spec_fused(
-                        p, self.mc, dp, dc, kt, vt, kd, vd, t, xp, pos,
-                        bt, bt, act, te, tk, tp, u,
-                        rounds=rounds, gamma=gamma,
-                    )
-                ),
-                donate_argnums=(2, 3, 4, 5),
-            )
+
+            def spec_fused(p, dp, kt, vt, kd, vd, t, xp, pos, bt, act, te, tk, tp, u):
+                return model.decode_spec_fused(
+                    p, self.mc, dp, dc, kt, vt, kd, vd, t, xp, pos,
+                    bt, bt, act, te, tk, tp, u,
+                    rounds=rounds, gamma=gamma,
+                )
+
+            self._spec_fused_jit = jax.jit(spec_fused, donate_argnums=(2, 3, 4, 5))
 
     def attach_guided(self, tokenizer) -> None:
         """Enable grammar-constrained decoding: grammars lift to token FSMs
@@ -895,6 +914,7 @@ class Scheduler:
         trace: Optional[tuple] = None,
         guided: Optional[dict] = None,
         tenant: str = "anon",
+        enqueued_ts: Optional[float] = None,
     ) -> Sequence:
         if not token_ids:
             raise ValueError("empty prompt")
@@ -921,6 +941,7 @@ class Scheduler:
             mm_features=mm_features,
             trace=trace,
             tenant=tenant or "anon",
+            enqueued_ts=enqueued_ts,
         )
         if guided is not None:
             seq.guided = self.guided.open(guided)  # ValueError on a bad spec
@@ -1059,10 +1080,7 @@ class Scheduler:
                 "compiles_total": f.compiles_total,
                 "compiles_after_warmup_total": f.compiles_after_warmup_total,
                 "post_warmup_keys": [str(k) for k in f.post_warmup_keys[-8:]],
-                "recent_steps": [
-                    {"age_s": round(now - ts, 3), "phase": ph, "dur_s": d, "tokens": t}
-                    for ts, ph, d, t in list(f.recent_steps)
-                ],
+                "recent_steps": f.recent_steps(),
                 "utilization": {
                     ph: {"mfu": round(m, 6), "hbm_frac": round(h, 6)}
                     for ph, (m, h) in f.utilization().items()
@@ -1161,8 +1179,26 @@ class Scheduler:
         iteration instead dispatches step N+1 from the previous step's
         on-device sampled tokens and retires step N while the device runs —
         unless a composition change (waiting work, aborts, block growth,
-        finish) forces a flush back to this sync path."""
+        finish) forces a flush back to this sync path.
+
+        The iteration is one ``sched.step`` span whose phases (plan, upload,
+        launch, sync, sample, emit, account — runtime/tracing.py) partition
+        it: ``sched.plan`` opens here and each dispatch path closes it where
+        its uploads begin, then reopens it after its accounting."""
         outputs: List[tuple] = []
+        log = self.flight.log
+        log.step += 1
+        with log.span("sched.step") as span:
+            self._step_span = span
+            self._begin_plan()
+            try:
+                self._step(outputs)
+            finally:
+                self._end_plan()
+                self._step_span = None
+        return outputs
+
+    def _step(self, outputs: List[tuple]) -> None:
         # Deadline sweep runs before the overlap fast path too: an expired
         # row marks itself aborted, which forces the pipeline flush below
         # (otherwise a pure-decode window could outlive the deadline).
@@ -1170,16 +1206,56 @@ class Scheduler:
         if self._pipe is not None:
             if self._overlap_should_continue():
                 self._overlap_step(outputs)
-                return outputs
+                return
             self._overlap_flush(outputs)
         self._reap_aborted(outputs)
         cand = self._mixed_candidate()
         if cand is not None and not self._wave_preferred() and self._mixed_step(cand, outputs):
-            return outputs
+            return
         if self.running:
             outputs.extend(self._decode_step())
         self._admit(outputs)
-        return outputs
+
+    # --- step-phase spans (runtime/tracing.py) -------------------------------
+    def _span(self, name: str, **attrs) -> StepSpan:
+        return self.flight.log.span(name, **attrs)
+
+    def _begin_plan(self) -> None:
+        self._plan_span = self.flight.log.span("sched.plan").begin()
+
+    def _end_plan(self) -> None:
+        if self._plan_span is not None:
+            self._plan_span.end()
+            self._plan_span = None
+
+    def _note_step(self, kind: str, key: tuple, batch=(), prefill: int = 0, decode: int = 0) -> None:
+        """What the iteration dispatched, on the open ``sched.step``: kind
+        and shape key (as registered with ``flight.record_exec``), rows and
+        context tokens of the batch, prefill and decode tokens. A second
+        dispatch of one iteration (decode, then a prefill) only counts
+        itself."""
+        span = self._step_span
+        if span is None:
+            return
+        if span.attrs is None:
+            span.set(kind=kind, key=key, rows=len(batch), ctx=sum(s.total_len for s in batch),
+                     prefill=prefill, decode=decode)
+        else:
+            span.set(dispatches=span.attrs.get("dispatches", 1) + 1)
+
+    def _launch(self, kind: str, decode: bool = False) -> StepSpan:
+        """The ``sched.launch`` span of one program. A decode-family launch
+        first books the decode host gap (see _record_host_gap)."""
+        if decode:
+            self._record_host_gap()
+            return self.flight.log.span("sched.launch", kind=kind, decode=True)
+        return self.flight.log.span("sched.launch", kind=kind)
+
+    def _since(self, span: StepSpan) -> float:
+        """Seconds since ``span`` began: a dispatch's timed part runs from
+        the start of its ``sched.upload`` to the end of its ``sched.emit``
+        (what the flight recorder, the bills and the itl digest are fed)."""
+        return (time.monotonic_ns() - span.t0) / 1e9
 
     def _mixed_candidate(self) -> Optional[Sequence]:
         """Head-of-queue sequence eligible to ride a mixed step, or None.
@@ -1237,22 +1313,22 @@ class Scheduler:
             model = self._model
             stats_kw = {"moe_stats": True} if self._moe_stats else {}
             if self._use_flash_prefill:
-                self._mixed_jits[key] = jax.jit(
-                    lambda p, k, v, pt, pv, cl, ptab, dt, dpos, dtab, dact, hp: model.mixed_step(
+
+                def mixed_step(p, k, v, pt, pv, cl, ptab, dt, dpos, dtab, dact, hp):
+                    return model.mixed_step(
                         p, self.mc, k, v, pt, pv, cl, ptab, dt, dpos, dtab, dact,
                         use_flash=True, has_prefix=hp, **stats_kw,
-                    ),
-                    donate_argnums=(1, 2),
-                    static_argnums=(11,),
-                )
+                    )
+
+                self._mixed_jits[key] = jax.jit(mixed_step, donate_argnums=(1, 2), static_argnums=(11,))
             else:
-                self._mixed_jits[key] = jax.jit(
-                    lambda p, k, v, pt, pv, cl, ptab, dt, dpos, dtab, dact, hp: model.mixed_step(
-                        p, self.mc, k, v, pt, pv, cl, ptab, dt, dpos, dtab, dact,
-                        **stats_kw,
-                    ),
-                    donate_argnums=(1, 2),
-                )
+
+                def mixed_step(p, k, v, pt, pv, cl, ptab, dt, dpos, dtab, dact, hp):
+                    return model.mixed_step(
+                        p, self.mc, k, v, pt, pv, cl, ptab, dt, dpos, dtab, dact, **stats_kw
+                    )
+
+                self._mixed_jits[key] = jax.jit(mixed_step, donate_argnums=(1, 2))
         return self._mixed_jits[key]
 
     def _mixed_step(self, seq: Sequence, outputs: List[tuple]) -> bool:
@@ -1284,7 +1360,6 @@ class Scheduler:
         chunk_tokens = pf_tokens[seq.num_computed : seq.num_computed + chunk]
         p_tok = np.zeros((s_bucket,), dtype=np.int32)
         p_tok[: len(chunk_tokens)] = chunk_tokens
-        p_table = self._prefill_table(seq)
         has_prefix = seq.num_computed > 0
 
         # Decode batch formation — identical to _decode_step (see there for
@@ -1300,64 +1375,83 @@ class Scheduler:
             tokens[i] = s.all_ids[-1]
             positions[i] = s.total_len - 1
             active[i] = True
-        tables = self._decode_tables(batch, d_bucket, width)
 
-        mixed_key = (s_bucket, int(p_table.shape[0]), d_bucket, width)
-        self.flight.record_exec(
-            "mixed", mixed_key + ((has_prefix,) if self._use_flash_prefill else ())
-        )
-        self._break_decode_gap()
-        with StepTimer() as timer:
+        self._end_plan()
+        with self._span("sched.upload") as upload:
+            p_table = self._prefill_table(seq)
+            tables = self._decode_tables(batch, d_bucket, width)
+            p_tok_d, p_len_d, p_start_d = (
+                jnp.asarray(p_tok), jnp.int32(len(chunk_tokens)), jnp.int32(seq.num_computed)
+            )
+            tokens_d, positions_d, active_d = (
+                jnp.asarray(tokens), jnp.asarray(positions), jnp.asarray(active)
+            )
+            mixed_key = (s_bucket, int(p_table.shape[0]), d_bucket, width)
+            exec_key = mixed_key + ((has_prefix,) if self._use_flash_prefill else ())
+            self.flight.record_exec("mixed", exec_key)
+            self._note_step("mixed", exec_key, batch, prefill=len(chunk_tokens), decode=n)
+        with self._launch("mixed"):
             res = self._get_mixed_jit(mixed_key)(
                 self.params, self.cache.k, self.cache.v,
-                jnp.asarray(p_tok), jnp.int32(len(chunk_tokens)), jnp.int32(seq.num_computed),
-                p_table, jnp.asarray(tokens), jnp.asarray(positions), tables,
-                jnp.asarray(active), has_prefix,
+                p_tok_d, p_len_d, p_start_d,
+                p_table, tokens_d, positions_d, tables,
+                active_d, has_prefix,
             )
             logits, self.cache.k, self.cache.v = self._consume_aux(res)
-            self.mixed_steps_total += 1
-            self.mixed_prefill_tokens_total += len(chunk_tokens)
-            self.mixed_decode_tokens_total += n
+            decode_logits = logits[1:]
+        self.mixed_steps_total += 1
+        self.mixed_prefill_tokens_total += len(chunk_tokens)
+        self.mixed_decode_tokens_total += n
+        seq.prefill_chunks += 1
 
-            # Decode rows first (output-order parity with the phase-separated
-            # decode-then-admit iteration), then the chunk's progress.
-            self._finish_decode_rows(batch, d_bucket, logits[1:], outputs)
-        # Mixed-step roofline split: the chunk's FLOPs/bytes land in the
-        # PREFILL bucket and the decode rows' in DECODE, so mfu_prefill /
-        # hbm_frac_decode stay truthful when one fused launch serves both
-        # phases (the step histogram itself stays under "mixed").
-        self.flight.record_mixed_step(
-            timer.dur, len(chunk_tokens), n,
-            kv_read_prefill=seq.num_computed,
-            kv_read_decode=sum(s.total_len for s in batch),
-        )
-        self._bill_step(
-            timer.dur,
-            [(seq, "prefill", len(chunk_tokens), seq.num_computed)]
-            + [(s, "decode", 1, s.total_len) for s in batch],
-        )
-        self.telemetry.observe("itl", timer.dur)
-        self._trace_event(
-            seq, "mixed_ride", chunk_tokens=len(chunk_tokens), decode_rows=n,
-            dur_s=round(timer.dur, 6),
-        )
+        # Decode rows first (output-order parity with the phase-separated
+        # decode-then-admit iteration), then the chunk's progress.
+        self._finish_decode_rows(batch, d_bucket, decode_logits, outputs)
+        dur = self._since(upload)
+        with self._span("sched.account"):
+            # Mixed-step roofline split: the chunk's FLOPs/bytes land in the
+            # PREFILL bucket and the decode rows' in DECODE, so mfu_prefill /
+            # hbm_frac_decode stay truthful when one fused launch serves both
+            # phases (the step histogram itself stays under "mixed").
+            self.flight.record_mixed_step(
+                dur, len(chunk_tokens), n,
+                kv_read_prefill=seq.num_computed,
+                kv_read_decode=sum(s.total_len for s in batch),
+            )
+            self._bill_step(
+                dur,
+                [(seq, "prefill", len(chunk_tokens), seq.num_computed)]
+                + [(s, "decode", 1, s.total_len) for s in batch],
+            )
+            self.telemetry.observe("itl", dur)
+            self._trace_event(
+                seq, "mixed_ride", chunk_tokens=len(chunk_tokens), decode_rows=n,
+                dur_s=round(dur, 6),
+            )
 
-        seq.num_computed += len(chunk_tokens)
-        self._register_full_blocks(seq)  # chunk's completed blocks go live
-        if seq.num_computed < len(pf_tokens):
+        with self._span("sched.emit"):
+            seq.num_computed += len(chunk_tokens)
+            self._register_full_blocks(seq)  # chunk's completed blocks go live
+            done = seq.num_computed >= len(pf_tokens)
+            if done:
+                self.waiting.remove(seq)
+                seq.state = SeqState.RUNNING
+                self.running.append(seq)
+                self._register_full_blocks(seq)
+        if not done:
+            self._begin_plan()
             return True  # more chunks ride later steps
-        self.waiting.remove(seq)
-        seq.state = SeqState.RUNNING
-        self.running.append(seq)
-        self._register_full_blocks(seq)
         if resuming:
             # KV restored through the last generated token; the final token
             # re-enters via decode — nothing to sample or emit.
             seq.resume_tokens = None
         else:
-            token = self._sample_one(seq, logits[0])
-            seq.first_token_ts = time.monotonic()
-            self._append_token(seq, token, outputs)
+            with self._span("sched.sample"):
+                token = self._sample_one(seq, logits[0])
+            with self._span("sched.emit"):
+                seq.first_token_ts = time.monotonic()
+                self._append_token(seq, token, outputs)
+        self._begin_plan()
         return True
 
     def _reap_aborted(self, outputs: List[tuple]) -> None:
@@ -1372,6 +1466,7 @@ class Scheduler:
                 # any mid-prefill KV hold) — a timeout storm in the queue is
                 # exactly what tenant attribution must see.
                 self._emit_bill(seq, seq.abort_reason)
+                self._log_request(seq, seq.abort_reason)
                 # Mid-prefill cancellations already hold blocks — release them.
                 self.allocator.release(seq.block_ids)
                 seq.block_ids = []
@@ -1441,13 +1536,14 @@ class Scheduler:
         shared by _admit_wave and warmup so both compile the same thing."""
         if key not in self._admit_jits:
             model = self._model
-            self._admit_jits[key] = jax.jit(
-                lambda p, k, v, t, p0, vl, bt: model.chunk_decode(
-                    p, self.mc, k, v, t, p0, vl, bt, last_logits=True,
-                    **({"moe_stats": True} if self._moe_stats else {}),
-                ),
-                donate_argnums=(1, 2),
-            )
+            stats_kw = {"moe_stats": True} if self._moe_stats else {}
+
+            def admit_wave(p, k, v, t, p0, vl, bt):
+                return model.chunk_decode(
+                    p, self.mc, k, v, t, p0, vl, bt, last_logits=True, **stats_kw
+                )
+
+            self._admit_jits[key] = jax.jit(admit_wave, donate_argnums=(1, 2))
         return self._admit_jits[key]
 
     def _wave_eligible(self, seq: Sequence) -> bool:
@@ -1538,38 +1634,49 @@ class Scheduler:
             valid[i] = len(chunk)
             tables[i, : len(seq.block_ids)] = seq.block_ids
 
-        self.flight.record_exec("admit", (b_bucket, s_bucket, width))
-        self._break_decode_gap()
-        with StepTimer() as timer:
-            res = self._get_admit_jit((b_bucket, s_bucket, width))(
+        self._end_plan()
+        with self._span("sched.upload") as upload:
+            tokens_d, pos0_d, valid_d, tables_d = (
+                jnp.asarray(tokens), jnp.asarray(pos0), jnp.asarray(valid), jnp.asarray(tables)
+            )
+            wave_key = (b_bucket, s_bucket, width)
+            self.flight.record_exec("admit", wave_key)
+            self._note_step("admit", wave_key, admitted, prefill=int(valid.sum()))
+        with self._launch("admit"):
+            res = self._get_admit_jit(wave_key)(
                 self.params, self.cache.k, self.cache.v,
-                jnp.asarray(tokens), jnp.asarray(pos0), jnp.asarray(valid), jnp.asarray(tables),
+                tokens_d, pos0_d, valid_d, tables_d,
             )
             lg, self.cache.k, self.cache.v = self._consume_aux(res)
+        with self._span("sched.sample"):
             self._step_counter += 1
             skey = jax.random.fold_in(self._rng, self._step_counter)
-            sampled = np.asarray(
-                self._sample_jit(
-                    lg, jnp.asarray(temps), jnp.asarray(top_ks), jnp.asarray(top_ps), skey, None
-                )
-            )  # the wave's ONE host sync
-
+            res = self._sample_jit(
+                lg, jnp.asarray(temps), jnp.asarray(top_ks), jnp.asarray(top_ps), skey, None
+            )
+            with self._span("sched.sync"):
+                sampled = np.asarray(res)  # the wave's ONE host sync
+        with self._span("sched.emit"):
             for i, seq in enumerate(admitted):
                 self.waiting.remove(seq)
                 seq.num_computed = len(seq.prompt)
+                seq.prefill_chunks += 1
                 seq.first_token_ts = time.monotonic()
                 seq.state = SeqState.RUNNING
                 self.running.append(seq)
                 self._register_full_blocks(seq)
                 self._append_token(seq, int(sampled[i]), outputs)
-        self.flight.record_step(
-            "wave", timer.dur, int(valid.sum()) + len(admitted),
-            kv_read_tokens=int(pos0.sum()),
-        )
-        self._bill_step(
-            timer.dur,
-            [(seq, "prefill", int(valid[i]) + 1, int(pos0[i])) for i, seq in enumerate(admitted)],
-        )
+        dur = self._since(upload)
+        with self._span("sched.account"):
+            self.flight.record_step(
+                "wave", dur, int(valid.sum()) + len(admitted),
+                kv_read_tokens=int(pos0.sum()),
+            )
+            self._bill_step(
+                dur,
+                [(seq, "prefill", int(valid[i]) + 1, int(pos0[i])) for i, seq in enumerate(admitted)],
+            )
+        self._begin_plan()
         return True
 
     def _first_touch(self, seq: Sequence, pf_tokens: List[int], total_tokens: int) -> None:
@@ -1628,6 +1735,7 @@ class Scheduler:
         seq.state = SeqState.PREFILL
         if seq.admitted_ts is None:
             seq.admitted_ts = time.monotonic()
+            seq.first_step = self.flight.log.step
             self._trace_event(
                 seq, "admitted",
                 queue_s=round(seq.admitted_ts - seq.arrival_ts, 6),
@@ -1660,84 +1768,90 @@ class Scheduler:
         tokens = pf_tokens[seq.num_computed : seq.num_computed + chunk]
         padded = np.zeros((bucket,), dtype=np.int32)
         padded[: len(tokens)] = tokens
-        table = self._prefill_table(seq)
+        has_prefix = seq.num_computed > 0
+        mm_args = ()
+        if seq.mm_features is not None:
+            feats = seq.mm_features
+            fb = 16
+            while fb < feats.shape[0]:
+                fb *= 2
+            padded_f = np.zeros((fb, feats.shape[1]), dtype=np.float32)
+            padded_f[: feats.shape[0]] = feats
 
-        self._break_decode_gap()
         t0 = time.monotonic() if self.sc.itl_budget_ms else None
-        with StepTimer() as timer:
+        self._end_plan()
+        with self._span("sched.upload") as upload:
+            table = self._prefill_table(seq)
+            padded_d, len_d, start_d = (
+                jnp.asarray(padded), jnp.int32(len(tokens)), jnp.int32(seq.num_computed)
+            )
             if seq.mm_features is not None:
-                feats = seq.mm_features
-                fb = 16
-                while fb < feats.shape[0]:
-                    fb *= 2
-                padded_f = np.zeros((fb, feats.shape[1]), dtype=np.float32)
-                padded_f[: feats.shape[0]] = feats
-                self.flight.record_exec(
-                    "prefill_mm", (bucket, int(table.shape[0]), fb, seq.num_computed > 0)
-                )
-                res = self._prefill_mm_jit()(
-                    self.params, self.cache.k, self.cache.v,
-                    jnp.asarray(padded), jnp.int32(len(tokens)), jnp.int32(seq.num_computed),
-                    table, seq.num_computed > 0,
-                    jnp.asarray(padded_f), jnp.int32(feats.shape[0]),
-                )
+                mm_args = (jnp.asarray(padded_f), jnp.int32(feats.shape[0]))
+                kind, fn = "prefill_mm", self._prefill_mm_jit()
+                key = (bucket, int(table.shape[0]), fb, has_prefix)
+                self.flight.record_exec("prefill_mm", key)
             else:
                 # Shape key mirrors warmup(): on the XLA path has_prefix is a
                 # traced no-op arg (one executable serves both values).
-                hp_key = (seq.num_computed > 0) if self._use_flash_prefill else False
-                self.flight.record_exec("prefill", (bucket, int(table.shape[0]), hp_key))
-                res = self._prefill_jit(
-                    self.params,
-                    self.cache.k,
-                    self.cache.v,
-                    jnp.asarray(padded),
-                    jnp.int32(len(tokens)),
-                    jnp.int32(seq.num_computed),
-                    table,
-                    seq.num_computed > 0,
-                )
+                kind, fn = "prefill", self._prefill_jit
+                key = (bucket, int(table.shape[0]), has_prefix if self._use_flash_prefill else False)
+                self.flight.record_exec("prefill", key)
+            self._note_step(kind, key, (seq,), prefill=len(tokens))
+        with self._launch(kind):
+            res = fn(
+                self.params, self.cache.k, self.cache.v,
+                padded_d, len_d, start_d,
+                table, has_prefix, *mm_args,
+            )
             logits, self.cache.k, self.cache.v = self._consume_aux(res)
-        self.flight.record_step(
-            "prefill", timer.dur, len(tokens), kv_read_tokens=seq.num_computed
-        )
-        self._bill_step(timer.dur, [(seq, "prefill", len(tokens), seq.num_computed)])
-        self._trace_event(
-            seq, "prefill_chunk", tokens=len(tokens), bucket=bucket,
-            computed=seq.num_computed + len(tokens), dur_s=round(timer.dur, 6),
-            resume=resuming,
-        )
+        dur = self._since(upload)
+        seq.prefill_chunks += 1
+        with self._span("sched.account"):
+            self.flight.record_step(
+                "prefill", dur, len(tokens), kv_read_tokens=seq.num_computed
+            )
+            self._bill_step(dur, [(seq, "prefill", len(tokens), seq.num_computed)])
+            self._trace_event(
+                seq, "prefill_chunk", tokens=len(tokens), bucket=bucket,
+                computed=seq.num_computed + len(tokens), dur_s=round(dur, 6),
+                resume=resuming,
+            )
         if t0 is not None:
             # Sync to learn the chunk rate (feeds _chunk_budget's EMA).
-            logits.block_until_ready()
+            with self._span("sched.sync"):
+                logits.block_until_ready()
             dt = max(time.monotonic() - t0, 1e-6)
             rate = len(tokens) / dt
             self._prefill_tok_s = rate if self._prefill_tok_s is None else (
                 0.7 * self._prefill_tok_s + 0.3 * rate
             )
-        seq.num_computed += len(tokens)
-        self._register_full_blocks(seq)  # chunk's completed blocks go live
-        self._draft_catchup_prefill(seq, pf_tokens)
+        with self._span("sched.emit"):
+            seq.num_computed += len(tokens)
+            self._register_full_blocks(seq)  # chunk's completed blocks go live
+            self._draft_catchup_prefill(seq, pf_tokens)
+            done = seq.num_computed >= len(pf_tokens)
+            if done and resuming:
+                # KV restored through the last generated token; the final token
+                # re-enters via the decode step — nothing to sample or emit.
+                seq.resume_tokens = None
+                seq.state = SeqState.RUNNING
+                self.running.append(seq)
+                self._register_full_blocks(seq)
+                self._trace_event(seq, "resume", total_len=seq.total_len)
+        if not done or resuming:
+            self._begin_plan()
+            return done  # False: more chunks to go
 
-        if seq.num_computed < len(pf_tokens):
-            return False  # more chunks to go
-
-        if resuming:
-            # KV restored through the last generated token; the final token
-            # re-enters via the decode step — nothing to sample or emit.
-            seq.resume_tokens = None
+        # Prompt fully computed: sample the first token.
+        with self._span("sched.sample"):
+            token = self._sample_one(seq, logits)
+        with self._span("sched.emit"):
+            seq.first_token_ts = time.monotonic()
             seq.state = SeqState.RUNNING
             self.running.append(seq)
             self._register_full_blocks(seq)
-            self._trace_event(seq, "resume", total_len=seq.total_len)
-            return True
-
-        # Prompt fully computed: sample the first token.
-        token = self._sample_one(seq, logits)
-        seq.first_token_ts = time.monotonic()
-        seq.state = SeqState.RUNNING
-        self.running.append(seq)
-        self._register_full_blocks(seq)
-        self._append_token(seq, token, outputs)
+            self._append_token(seq, token, outputs)
+        self._begin_plan()
         return True
 
     def _chunk_budget(self) -> int:
@@ -2160,11 +2274,12 @@ class Scheduler:
             toks = tokens[start : start + chunk]
             padded = np.zeros((bucket,), dtype=np.int32)
             padded[: len(toks)] = toks
-            _, self.draft_cache.k, self.draft_cache.v = self._d_prefill_jit(
-                self.draft_params, self.draft_cache.k, self.draft_cache.v,
-                jnp.asarray(padded), jnp.int32(len(toks)), jnp.int32(start),
-                self._prefill_table(seq),
-            )
+            with self._launch("draft_prefill"):
+                _, self.draft_cache.k, self.draft_cache.v = self._d_prefill_jit(
+                    self.draft_params, self.draft_cache.k, self.draft_cache.v,
+                    jnp.asarray(padded), jnp.int32(len(toks)), jnp.int32(start),
+                    self._prefill_table(seq),
+                )
             seq.d_n += len(toks)
 
     def _draft_catchup_prefill(self, seq: Sequence, pf_tokens: List[int]) -> None:
@@ -2196,20 +2311,14 @@ class Scheduler:
 
     def _record_host_gap(self) -> None:
         """Host-gap accounting, called right BEFORE a decode-family dispatch:
-        the interval since the previous decode dispatch RETURNED is the
-        bubble the device spent waiting on Python."""
-        if self._last_decode_dispatch_t is not None:
-            self.flight.record_host_gap(time.perf_counter() - self._last_decode_dispatch_t)
-
-    def _note_decode_dispatch(self) -> None:
-        """Called right after a decode-family dispatch call returns (device
-        launched, host free again)."""
-        self._last_decode_dispatch_t = time.perf_counter()
-
-    def _break_decode_gap(self) -> None:
-        """A non-decode dispatch intervened — the next interval is not a
-        decode host gap."""
-        self._last_decode_dispatch_t = None
+        the interval since the previous decode dispatch RETURNED (the end of
+        its ``sched.launch`` span in the step log) is the bubble the device
+        spent waiting on Python. Any other program launched in between (a
+        mixed step, a prefill, a wave, a block copy) is the newest launch in
+        the log instead, so that interval is not a decode host gap."""
+        prev = self.flight.log.last("sched.launch")
+        if prev is not None and prev[4].get("decode"):
+            self.flight.record_host_gap((time.monotonic_ns() - prev[2]) / 1e9)
 
     def _overlap_row_ok(self, seq: Sequence) -> bool:
         """Rows needing host work between steps can't ride the pipeline:
@@ -2260,16 +2369,17 @@ class Scheduler:
     def _dispatch_overlap(self, pipe: dict, tpa_dev) -> None:
         """Issue one fused decode+sample dispatch (async — returns as soon as
         the device has the work) and stage its outputs in the pipe."""
-        self._step_counter += 1
-        key = jax.random.fold_in(self._rng, self._step_counter)
-        self.flight.record_exec("decode_sample", (pipe["bucket"], pipe["width"]))
-        self._record_host_gap()
-        res = self._decode_sample_jit(
-            self.params, self.cache.k, self.cache.v, tpa_dev, pipe["tables"],
-            pipe["temps"], pipe["tks"], pipe["tps"], key,
-        )
-        sampled, next_tpa, self.cache.k, self.cache.v = self._consume_aux(res)
-        self._note_decode_dispatch()
+        with self._launch("decode_sample", decode=True):
+            self._step_counter += 1
+            key = jax.random.fold_in(self._rng, self._step_counter)
+            exec_key = (pipe["bucket"], pipe["width"])
+            self.flight.record_exec("decode_sample", exec_key)
+            self._note_step("decode_sample", exec_key, pipe["batch"], decode=len(pipe["batch"]))
+            res = self._decode_sample_jit(
+                self.params, self.cache.k, self.cache.v, tpa_dev, pipe["tables"],
+                pipe["temps"], pipe["tks"], pipe["tps"], key,
+            )
+            sampled, next_tpa, self.cache.k, self.cache.v = self._consume_aux(res)
         pipe["sampled"] = sampled
         pipe["next_tpa"] = next_tpa
         self.overlap_steps_total += 1
@@ -2289,15 +2399,19 @@ class Scheduler:
             tpa[0, i] = seq.all_ids[-1]
             tpa[1, i] = positions[i]
             tpa[2, i] = 1
-        pipe = {
-            "batch": batch, "bucket": bucket, "width": width,
-            "tables": self._decode_tables(batch, bucket, width),
-            "temps": jnp.asarray(temps), "tks": jnp.asarray(top_ks),
-            "tps": jnp.asarray(top_ps),
-        }
-        self._dispatch_overlap(pipe, jnp.asarray(tpa))
+        self._end_plan()
+        with self._span("sched.upload"):
+            pipe = {
+                "batch": batch, "bucket": bucket, "width": width,
+                "tables": self._decode_tables(batch, bucket, width),
+                "temps": jnp.asarray(temps), "tks": jnp.asarray(top_ks),
+                "tps": jnp.asarray(top_ps),
+            }
+            tpa_d = jnp.asarray(tpa)
+        self._dispatch_overlap(pipe, tpa_d)
         pipe["positions"] = [p + 1 for p in positions]
         self._pipe = pipe
+        self._begin_plan()
         return True
 
     def _overlap_step(self, outputs: List[tuple]) -> None:
@@ -2314,22 +2428,28 @@ class Scheduler:
         # the N+1 dispatch writes each row's last-appended token's KV at
         # the row's pre-retire total_len.
         rollback = self._rollback_targets(pipe["batch"])
-        with StepTimer() as timer:
-            self._dispatch_overlap(pipe, pipe["next_tpa"])
-            pipe["positions"] = [p + 1 for p in pipe["positions"]]
-            # Retire step N while N+1 runs on device.
+        self._end_plan()
+        t0 = time.monotonic_ns()
+        self._dispatch_overlap(pipe, pipe["next_tpa"])
+        pipe["positions"] = [p + 1 for p in pipe["positions"]]
+        # Retire step N while N+1 runs on device.
+        with self._span("sched.sync"):
             sampled_h = np.asarray(prev_sampled)  # the step's one blocking sync
+        with self._span("sched.emit"):
             finished = False
             for i, seq in enumerate(pipe["batch"]):
                 self._append_token(seq, int(sampled_h[i]), outputs)
                 if seq.state != SeqState.RUNNING:
                     finished = True
-        self.flight.record_step(
-            "decode", timer.dur, len(pipe["batch"]),
-            kv_read_tokens=sum(s.total_len for s in pipe["batch"]),
-        )
-        self._bill_step(timer.dur, [(s, "decode", 1, s.total_len) for s in pipe["batch"]])
-        self.telemetry.observe("itl", timer.dur)
+        dur = (time.monotonic_ns() - t0) / 1e9
+        with self._span("sched.account"):
+            self.flight.record_step(
+                "decode", dur, len(pipe["batch"]),
+                kv_read_tokens=sum(s.total_len for s in pipe["batch"]),
+            )
+            self._bill_step(dur, [(s, "decode", 1, s.total_len) for s in pipe["batch"]])
+            self.telemetry.observe("itl", dur)
+        self._begin_plan()
         if finished:
             self._overlap_flush(outputs, rollback=rollback)
 
@@ -2355,7 +2475,8 @@ class Scheduler:
         row is still running and nothing rolls back."""
         pipe, self._pipe = self._pipe, None
         self.overlap_flushes_total += 1
-        sampled_h = np.asarray(pipe["sampled"])
+        with self._span("sched.sync"):
+            sampled_h = np.asarray(pipe["sampled"])
         for i, seq in enumerate(pipe["batch"]):
             if seq.state != SeqState.RUNNING:
                 # Rollback applies ONLY to rows that finished at the previous
@@ -2368,9 +2489,10 @@ class Scheduler:
                 ):
                     blk, off = rollback[i]
                     self.flight.record_exec("kv_rollback", ())
-                    self.cache.k, self.cache.v = self._kv_zero_jit(
-                        self.cache.k, self.cache.v, jnp.int32(blk), jnp.int32(off)
-                    )
+                    with self._launch("kv_rollback"):
+                        self.cache.k, self.cache.v = self._kv_zero_jit(
+                            self.cache.k, self.cache.v, jnp.int32(blk), jnp.int32(off)
+                        )
                 continue
             if seq.aborted:
                 continue  # _reap_aborted finishes it without the extra token
@@ -2472,23 +2594,27 @@ class Scheduler:
             tpa[0, i] = seq.all_ids[-1]
             tpa[1, i] = seq.total_len - 1  # write slot of the current token
             tpa[2, i] = 1
-        tables = self._decode_tables(batch, bucket, width)
 
-        self.flight.record_exec("decode", (bucket, width))
-        with StepTimer() as timer:
-            self._record_host_gap()
-            res = self._decode_jit(
-                self.params, self.cache.k, self.cache.v, jnp.asarray(tpa), tables
-            )
-            self._note_decode_dispatch()
+        self._end_plan()
+        with self._span("sched.upload") as upload:
+            tables = self._decode_tables(batch, bucket, width)
+            tpa_d = jnp.asarray(tpa)
+            exec_key = (bucket, width)
+            self.flight.record_exec("decode", exec_key)
+            self._note_step("decode", exec_key, batch, decode=len(batch))
+        with self._launch("decode", decode=True):
+            res = self._decode_jit(self.params, self.cache.k, self.cache.v, tpa_d, tables)
             logits, self.cache.k, self.cache.v = self._consume_aux(res)
-            self._finish_decode_rows(batch, bucket, logits, outputs)
-        self.flight.record_step(
-            "decode", timer.dur, len(outputs),
-            kv_read_tokens=sum(s.total_len for s in batch),
-        )
-        self._bill_step(timer.dur, [(s, "decode", 1, s.total_len) for s in batch])
-        self.telemetry.observe("itl", timer.dur)
+        self._finish_decode_rows(batch, bucket, logits, outputs)
+        dur = self._since(upload)
+        with self._span("sched.account"):
+            self.flight.record_step(
+                "decode", dur, len(outputs),
+                kv_read_tokens=sum(s.total_len for s in batch),
+            )
+            self._bill_step(dur, [(s, "decode", 1, s.total_len) for s in batch])
+            self.telemetry.observe("itl", dur)
+        self._begin_plan()
         return outputs
 
     def _finish_decode_rows(
@@ -2501,126 +2627,116 @@ class Scheduler:
         logits a plain decode step produces."""
         from dynamo_tpu.engine.sampling import pack_param_rows
 
-        # Frequency/presence penalties: one batched device op for the whole
-        # step (per-row output-token counts via scatter-add — sampling.py).
-        # Penalty-free batches skip it entirely.
-        if any(seq.sampling.has_penalties for seq in batch):
-            logits = self._apply_penalties(batch, bucket, logits)
-        # Per-request logits processors (dynamo_tpu.logits_processing): the
-        # host path — ONLY the rows that carry processors cross to host
-        # (device gather → [n_proc, V] transfer → device scatter), so one
-        # logit_bias row no longer drags the whole batch's [B, V] logits
-        # over the wire, and processor-free batches stay on the fast path.
-        if any(seq.sampling.logits_processors for seq in batch):
-            from dynamo_tpu.logits_processing import apply_chain
+        with self._span("sched.sample"):
+            # Frequency/presence penalties: one batched device op for the whole
+            # step (per-row output-token counts via scatter-add — sampling.py).
+            # Penalty-free batches skip it entirely.
+            if any(seq.sampling.has_penalties for seq in batch):
+                logits = self._apply_penalties(batch, bucket, logits)
+            # Per-request logits processors (dynamo_tpu.logits_processing): the
+            # host path — ONLY the rows that carry processors cross to host
+            # (device gather → [n_proc, V] transfer → device scatter), so one
+            # logit_bias row no longer drags the whole batch's [B, V] logits
+            # over the wire, and processor-free batches stay on the fast path.
+            if any(seq.sampling.logits_processors for seq in batch):
+                from dynamo_tpu.logits_processing import apply_chain
 
-            proc_rows = [i for i, seq in enumerate(batch) if seq.sampling.logits_processors]
-            sel = jnp.asarray(np.asarray(proc_rows, dtype=np.int32))
-            sub = np.array(logits[sel])  # [n_proc, V] writable host copy
-            for j, i in enumerate(proc_rows):
-                sub[j] = np.asarray(
-                    apply_chain(batch[i].sampling.logits_processors, batch[i].output_ids, jnp.asarray(sub[j]))
-                )
-            logits = logits.at[sel].set(jnp.asarray(sub))
-        self._step_counter += 1
-        key = jax.random.fold_in(self._rng, self._step_counter)
-        row_keys = None
-        if any(seq.sampling.seed is not None for seq in batch):
-            from dynamo_tpu.engine.sampling import make_row_keys
+                proc_rows = [i for i, seq in enumerate(batch) if seq.sampling.logits_processors]
+                sel = jnp.asarray(np.asarray(proc_rows, dtype=np.int32))
+                sub = np.array(logits[sel])  # [n_proc, V] writable host copy
+                for j, i in enumerate(proc_rows):
+                    sub[j] = np.asarray(
+                        apply_chain(batch[i].sampling.logits_processors, batch[i].output_ids, jnp.asarray(sub[j]))
+                    )
+                logits = logits.at[sel].set(jnp.asarray(sub))
+            self._step_counter += 1
+            key = jax.random.fold_in(self._rng, self._step_counter)
+            row_keys = None
+            if any(seq.sampling.seed is not None for seq in batch):
+                from dynamo_tpu.engine.sampling import make_row_keys
 
-            seeds = np.zeros((bucket,), dtype=np.int32)
-            poss_out = np.zeros((bucket,), dtype=np.int32)
-            has_seed = np.zeros((bucket,), dtype=bool)
-            for i, seq in enumerate(batch):
-                if seq.sampling.seed is not None:
-                    seeds[i] = seq.sampling.seed
-                    poss_out[i] = len(seq.output_ids)
-                    has_seed[i] = True
-            row_keys = make_row_keys(
-                key, jnp.asarray(seeds), jnp.asarray(poss_out), jnp.asarray(has_seed)
-            )
-        temps, top_ks, top_ps = pack_param_rows([s.sampling for s in batch], bucket)
-        # Logprobs fold into the SAME sampling dispatch when any row wants
-        # them (sampling.sample_batch_logprobs): one executable, one
-        # readback — previously a separate compute_logprobs device op plus
-        # its own sync per step. A top_logprobs row widens the dispatch to
-        # the top-k variant (static candidate cap — one executable for any
-        # requested k); the chosen-token logprob rides along either way.
-        want_tlp = any(seq.sampling.top_logprobs for seq in batch)
-        want_lp = want_tlp or any(seq.sampling.logprobs for seq in batch)
-        logprobs_np = None
-        top_ids_np = top_lps_np = None
-        if any(seq.guided is not None for seq in batch):
-            # Guided rows: gather each row's FSM-state mask from the shared
-            # device pool inside the fused mask+sample dispatch. Unguided
-            # rows point at the reserved allow-all row 0, so the mixed batch
-            # shares one executable.
-            pool = self.guided.pool.device()
-            k_rows = np.zeros((2, bucket), dtype=np.int32)
-            k_rows[0] = top_ks
-            for i, seq in enumerate(batch):
-                if seq.guided is not None:
-                    k_rows[1, i] = seq.guided.row_id
-            self.flight.record_exec("guided_sample", (bucket, int(pool.shape[0])))
-            if want_tlp:
-                sampled, logprobs_np, top_ids_np, top_lps_np = jax.device_get(
-                    self._guided_sample_tlp_jit(
-                        logits, pool, jnp.asarray(k_rows),
-                        jnp.asarray(temps), jnp.asarray(top_ps), key, row_keys,
-                    )
+                seeds = np.zeros((bucket,), dtype=np.int32)
+                poss_out = np.zeros((bucket,), dtype=np.int32)
+                has_seed = np.zeros((bucket,), dtype=bool)
+                for i, seq in enumerate(batch):
+                    if seq.sampling.seed is not None:
+                        seeds[i] = seq.sampling.seed
+                        poss_out[i] = len(seq.output_ids)
+                        has_seed[i] = True
+                row_keys = make_row_keys(
+                    key, jnp.asarray(seeds), jnp.asarray(poss_out), jnp.asarray(has_seed)
                 )
-            elif want_lp:
-                sampled, logprobs_np = jax.device_get(
-                    self._guided_sample_lp_jit(
-                        logits, pool, jnp.asarray(k_rows),
-                        jnp.asarray(temps), jnp.asarray(top_ps), key, row_keys,
-                    )
+            temps, top_ks, top_ps = pack_param_rows([s.sampling for s in batch], bucket)
+            # Logprobs fold into the SAME sampling dispatch when any row wants
+            # them (sampling.sample_batch_logprobs): one executable, one
+            # readback — previously a separate compute_logprobs device op plus
+            # its own sync per step. A top_logprobs row widens the dispatch to
+            # the top-k variant (static candidate cap — one executable for any
+            # requested k); the chosen-token logprob rides along either way.
+            want_tlp = any(seq.sampling.top_logprobs for seq in batch)
+            want_lp = want_tlp or any(seq.sampling.logprobs for seq in batch)
+            logprobs_np = None
+            top_ids_np = top_lps_np = None
+            if any(seq.guided is not None for seq in batch):
+                # Guided rows: gather each row's FSM-state mask from the shared
+                # device pool inside the fused mask+sample dispatch. Unguided
+                # rows point at the reserved allow-all row 0, so the mixed batch
+                # shares one executable.
+                pool = self.guided.pool.device()
+                k_rows = np.zeros((2, bucket), dtype=np.int32)
+                k_rows[0] = top_ks
+                for i, seq in enumerate(batch):
+                    if seq.guided is not None:
+                        k_rows[1, i] = seq.guided.row_id
+                self.flight.record_exec("guided_sample", (bucket, int(pool.shape[0])))
+                guided_jit = (
+                    self._guided_sample_tlp_jit if want_tlp
+                    else self._guided_sample_lp_jit if want_lp
+                    else self._guided_sample_jit
+                )
+                res = guided_jit(
+                    logits, pool, jnp.asarray(k_rows),
+                    jnp.asarray(temps), jnp.asarray(top_ps), key, row_keys,
                 )
             else:
-                sampled = np.asarray(
-                    self._guided_sample_jit(
-                        logits, pool, jnp.asarray(k_rows),
-                        jnp.asarray(temps), jnp.asarray(top_ps), key, row_keys,
-                    )
+                sample_jit = (
+                    self._sample_tlp_jit if want_tlp
+                    else self._sample_lp_jit if want_lp
+                    else self._sample_jit
                 )
-        elif want_tlp:
-            sampled, logprobs_np, top_ids_np, top_lps_np = jax.device_get(
-                self._sample_tlp_jit(
+                res = sample_jit(
                     logits, jnp.asarray(temps), jnp.asarray(top_ks), jnp.asarray(top_ps), key, row_keys
                 )
-            )
-        elif want_lp:
-            sampled, logprobs_np = jax.device_get(
-                self._sample_lp_jit(
-                    logits, jnp.asarray(temps), jnp.asarray(top_ks), jnp.asarray(top_ps), key, row_keys
-                )
-            )
-        else:
-            sampled = np.asarray(
-                self._sample_jit(
-                    logits, jnp.asarray(temps), jnp.asarray(top_ks), jnp.asarray(top_ps), key, row_keys
-                )
-            )
+            # The step's blocking read-back: the device runs the step program and
+            # the sampler while the host waits here.
+            with self._span("sched.sync"):
+                if want_tlp:
+                    sampled, logprobs_np, top_ids_np, top_lps_np = jax.device_get(res)
+                elif want_lp:
+                    sampled, logprobs_np = jax.device_get(res)
+                else:
+                    sampled = np.asarray(res)
 
-        for i, seq in enumerate(batch):
-            if seq.state != SeqState.RUNNING:
-                continue  # preempted while growing an earlier row this step
-            self._ensure_block_capacity(seq)
-            if seq.state != SeqState.RUNNING:
-                continue  # itself preempted (no candidate to evict)
-            lp = (
-                float(logprobs_np[i])
-                if logprobs_np is not None
-                and (seq.sampling.logprobs or seq.sampling.top_logprobs)
-                else None
-            )
-            tlp = None
-            if top_ids_np is not None and seq.sampling.top_logprobs:
-                k = min(seq.sampling.top_logprobs, top_ids_np.shape[1])
-                tlp = [
-                    (int(top_ids_np[i, j]), float(top_lps_np[i, j])) for j in range(k)
-                ]
-            self._append_token(seq, int(sampled[i]), outputs, logprob=lp, top_logprobs=tlp)
+        with self._span("sched.emit"):
+            for i, seq in enumerate(batch):
+                if seq.state != SeqState.RUNNING:
+                    continue  # preempted while growing an earlier row this step
+                self._ensure_block_capacity(seq)
+                if seq.state != SeqState.RUNNING:
+                    continue  # itself preempted (no candidate to evict)
+                lp = (
+                    float(logprobs_np[i])
+                    if logprobs_np is not None
+                    and (seq.sampling.logprobs or seq.sampling.top_logprobs)
+                    else None
+                )
+                tlp = None
+                if top_ids_np is not None and seq.sampling.top_logprobs:
+                    k = min(seq.sampling.top_logprobs, top_ids_np.shape[1])
+                    tlp = [
+                        (int(top_ids_np[i, j]), float(top_lps_np[i, j])) for j in range(k)
+                    ]
+                self._append_token(seq, int(sampled[i]), outputs, logprob=lp, top_logprobs=tlp)
 
     def _decode_multi(self, batch: List[Sequence], bucket: int, outputs: List[tuple]) -> bool:
         """Multi-step decode window: N steps in one dispatch, one host sync.
@@ -2683,7 +2799,6 @@ class Scheduler:
             tokens[i] = seq.all_ids[-1]
             positions[i] = seq.total_len - 1
             active[i] = True
-        tables = self._decode_tables(batch, bucket, width)
 
         # Fused megakernel window: any batch with no per-row HOST extras
         # dispatches the whole N-step window as ONE pallas launch (grid =
@@ -2722,10 +2837,12 @@ class Scheduler:
             else:
                 kind, key_t = "decode_fused", (steps, bucket, width)
             new_exec = self.flight.record_exec(kind, key_t)
+            self._note_step(kind, key_t, batch, decode=steps * len(batch))
             launches0 = _mk.trace_launch_count() if new_exec else 0
             n0 = len(outputs)
-            with StepTimer() as timer:
-                self._record_host_gap()
+            self._end_plan()
+            with self._span("sched.upload") as upload:
+                tables = self._decode_tables(batch, bucket, width)
                 args = [
                     self.params, self.cache.k, self.cache.v,
                     jnp.asarray(tokens), jnp.asarray(positions), tables,
@@ -2764,63 +2881,80 @@ class Scheduler:
                         jnp.asarray(rows0), self.guided.pool.device(),
                         self.guided.pool.next_device(),
                     ]
-                fjit = self._decode_fused_jits[(steps, use_sampled, any_guided)]
+            fjit = self._decode_fused_jits[(steps, use_sampled, any_guided)]
+            with self._launch(kind, decode=True):
                 toks_out, self.cache.k, self.cache.v = fjit(*args)
-                self._note_decode_dispatch()
+            with self._span("sched.sync"):
                 sampled = np.asarray(toks_out)  # the one host sync per window
-
+            with self._span("sched.emit"):
                 for i, seq in enumerate(batch):
                     for s in range(steps):
                         if seq.state != SeqState.RUNNING:
                             break
                         self._append_token(seq, int(sampled[s, i]), outputs)
-            if new_exec:
-                # Launch sites traced into this window executable — the
-                # amortization invariant (== 1) CI asserts.
-                self.flight.record_window_launches(_mk.trace_launch_count() - launches0)
-            self.flight.fused_windows_total += 1
-            if use_sampled:
-                self.flight.fused_sampled_windows_total += 1
-            self.flight.record_step(
-                "decode", timer.dur, len(outputs) - n0,
-                # VMEM-resident window: weights and prefix stream from HBM
-                # once per window, not once per step.
-                kv_read_tokens=sum(s.total_len for s in batch),
-                param_passes=1.0,
-            )
-            self._bill_step(timer.dur, [(s, "decode", steps, s.total_len) for s in batch])
-            self.telemetry.observe("itl", timer.dur / max(steps, 1))
+            dur = self._since(upload)
+            with self._span("sched.account"):
+                if new_exec:
+                    # Launch sites traced into this window executable — the
+                    # amortization invariant (== 1) CI asserts.
+                    self.flight.record_window_launches(_mk.trace_launch_count() - launches0)
+                self.flight.fused_windows_total += 1
+                if use_sampled:
+                    self.flight.fused_sampled_windows_total += 1
+                self.flight.record_step(
+                    "decode", dur, len(outputs) - n0,
+                    # VMEM-resident window: weights and prefix stream from HBM
+                    # once per window, not once per step.
+                    kv_read_tokens=sum(s.total_len for s in batch),
+                    param_passes=1.0,
+                )
+                self._bill_step(dur, [(s, "decode", steps, s.total_len) for s in batch])
+                self.telemetry.observe("itl", dur / max(steps, 1))
+            self._begin_plan()
             return True
 
-        self._step_counter += 1
-        key = jax.random.fold_in(self._rng, self._step_counter)
-        self.flight.record_exec("decode_multi", (steps, bucket, width))
+        exec_key = (steps, bucket, width)
+        self.flight.record_exec("decode_multi", exec_key)
+        self._note_step("decode_multi", exec_key, batch, decode=steps * len(batch))
         n0 = len(outputs)
-        with StepTimer() as timer:
-            self._record_host_gap()
+        self._end_plan()
+        with self._span("sched.upload") as upload:
+            self._step_counter += 1
+            key = jax.random.fold_in(self._rng, self._step_counter)
+            tables = self._decode_tables(batch, bucket, width)
+            tokens_d, positions_d, active_d = (
+                jnp.asarray(tokens), jnp.asarray(positions), jnp.asarray(active)
+            )
+            temps_d, top_ks_d, top_ps_d = (
+                jnp.asarray(temps), jnp.asarray(top_ks), jnp.asarray(top_ps)
+            )
+        with self._launch("decode_multi", decode=True):
             res = self._decode_multi_jits[steps](
                 self.params, self.cache.k, self.cache.v,
-                jnp.asarray(tokens), jnp.asarray(positions), tables,
-                jnp.asarray(active), jnp.asarray(temps), jnp.asarray(top_ks),
-                jnp.asarray(top_ps), key,
+                tokens_d, positions_d, tables,
+                active_d, temps_d, top_ks_d,
+                top_ps_d, key,
             )
-            self._note_decode_dispatch()
             toks_out, self.cache.k, self.cache.v = self._consume_aux(res)
+        with self._span("sched.sync"):
             sampled = np.asarray(toks_out)  # [steps, bucket] — the one host sync
-
+        with self._span("sched.emit"):
             for i, seq in enumerate(batch):
                 for s in range(steps):
                     if seq.state != SeqState.RUNNING:
                         break  # stopped mid-window; later tokens are trimmed
                     self._append_token(seq, int(sampled[s, i]), outputs)
-        self.flight.record_step(
-            "decode", timer.dur, len(outputs) - n0,
-            kv_read_tokens=steps * sum(s.total_len for s in batch),
-            # The fori_loop window re-streams the parameter set every step.
-            param_passes=float(steps),
-        )
-        self._bill_step(timer.dur, [(s, "decode", steps, steps * s.total_len) for s in batch])
-        self.telemetry.observe("itl", timer.dur / max(steps, 1))
+        dur = self._since(upload)
+        with self._span("sched.account"):
+            self.flight.record_step(
+                "decode", dur, len(outputs) - n0,
+                kv_read_tokens=steps * sum(s.total_len for s in batch),
+                # The fori_loop window re-streams the parameter set every step.
+                param_passes=float(steps),
+            )
+            self._bill_step(dur, [(s, "decode", steps, steps * s.total_len) for s in batch])
+            self.telemetry.observe("itl", dur / max(steps, 1))
+        self._begin_plan()
         return True
 
     def _decode_spec_fused(self, batch: List[Sequence], bucket: int, outputs: List[tuple]) -> bool:
@@ -2859,9 +2993,10 @@ class Scheduler:
         width = self._width_bucket(max(len(seq.block_ids) for seq in batch))
         from dynamo_tpu.engine.attention import megakernel as _mk
 
-        new_exec = self.flight.record_exec("spec_fused", (R, gamma, B, width))
+        exec_key = (R, gamma, B, width)
+        new_exec = self.flight.record_exec("spec_fused", exec_key)
+        self._note_step("spec_fused", exec_key, batch, decode=span * len(batch))
         launches0 = _mk.trace_launch_count() if new_exec else 0
-        self._break_decode_gap()
         n0 = len(outputs)
         t_round = time.perf_counter()
         tables = np.zeros((B, width), dtype=np.int32)
@@ -2883,19 +3018,21 @@ class Scheduler:
         ukey = jax.random.fold_in(self._rng, self._step_counter)
         uniforms = jax.random.uniform(ukey, (R, B, 2 * gamma + 1))
 
-        toks_out, accepted, self.cache.k, self.cache.v, self.draft_cache.k, self.draft_cache.v = (
-            self._spec_fused_jit(
-                self.params, self.draft_params,
-                self.cache.k, self.cache.v,
-                self.draft_cache.k, self.draft_cache.v,
-                jnp.asarray(tok0), jnp.asarray(xprev0), jnp.asarray(pos0),
-                jnp.asarray(tables), jnp.asarray(act),
-                jnp.asarray(temps), jnp.asarray(top_ks), jnp.asarray(top_ps),
-                uniforms,
+        with self._launch("spec_fused"):
+            toks_out, accepted, self.cache.k, self.cache.v, self.draft_cache.k, self.draft_cache.v = (
+                self._spec_fused_jit(
+                    self.params, self.draft_params,
+                    self.cache.k, self.cache.v,
+                    self.draft_cache.k, self.draft_cache.v,
+                    jnp.asarray(tok0), jnp.asarray(xprev0), jnp.asarray(pos0),
+                    jnp.asarray(tables), jnp.asarray(act),
+                    jnp.asarray(temps), jnp.asarray(top_ks), jnp.asarray(top_ps),
+                    uniforms,
+                )
             )
-        )
-        toks_h = np.asarray(toks_out)  # [R, B, γ+1] — the one sync
-        acc_h = np.asarray(accepted)  # [R, B]
+        with self._span("sched.sync"):
+            toks_h = np.asarray(toks_out)  # [R, B, γ+1] — the one sync
+            acc_h = np.asarray(accepted)  # [R, B]
         if new_exec:
             self.flight.record_window_launches(_mk.trace_launch_count() - launches0)
 
@@ -2965,8 +3102,9 @@ class Scheduler:
 
         B = bucket
         width = self._width_bucket(max(len(seq.block_ids) for seq in batch))
-        self.flight.record_exec("spec", (gamma, B, width))
-        self._break_decode_gap()
+        exec_key = (gamma, B, width)
+        self.flight.record_exec("spec", exec_key)
+        self._note_step("spec", exec_key, batch, decode=S * len(batch))
         n0 = len(outputs)
         t_round = time.perf_counter()
         tables = np.zeros((B, width), dtype=np.int32)
@@ -2987,12 +3125,14 @@ class Scheduler:
         # γ-1 sampled window steps with per-step logits.
         self._step_counter += 1
         key = jax.random.fold_in(self._rng, self._step_counter)
-        tok1, lg1, self.draft_cache.k, self.draft_cache.v = self._d_chunk_sample_jit(
-            self.draft_params, self.draft_cache.k, self.draft_cache.v,
-            jnp.asarray(d_toks), jnp.asarray(d_pos0), jnp.asarray(d_valid), tables_j,
-            temps_j, tks_j, tps_j, key,
-        )
-        tok1_h = np.asarray(tok1)
+        with self._launch("spec_draft_chunk"):
+            tok1, lg1, self.draft_cache.k, self.draft_cache.v = self._d_chunk_sample_jit(
+                self.draft_params, self.draft_cache.k, self.draft_cache.v,
+                jnp.asarray(d_toks), jnp.asarray(d_pos0), jnp.asarray(d_valid), tables_j,
+                temps_j, tks_j, tps_j, key,
+            )
+        with self._span("sched.sync"):
+            tok1_h = np.asarray(tok1)
         proposals = np.zeros((B, gamma), dtype=np.int32)
         poss = np.zeros((B,), dtype=np.int32)
         act = np.zeros((B,), dtype=bool)
@@ -3003,12 +3143,14 @@ class Scheduler:
         if gamma > 1:
             self._step_counter += 1
             key2 = jax.random.fold_in(self._rng, self._step_counter)
-            toks_out, lg_steps, self.draft_cache.k, self.draft_cache.v = self._d_multi_jit(
-                self.draft_params, self.draft_cache.k, self.draft_cache.v,
-                tok1, jnp.asarray(poss), tables_j, jnp.asarray(act),
-                temps_j, tks_j, tps_j, key2,
-            )
-            proposals[:, 1:] = np.asarray(toks_out).T
+            with self._launch("spec_draft_multi"):
+                toks_out, lg_steps, self.draft_cache.k, self.draft_cache.v = self._d_multi_jit(
+                    self.draft_params, self.draft_cache.k, self.draft_cache.v,
+                    tok1, jnp.asarray(poss), tables_j, jnp.asarray(act),
+                    temps_j, tks_j, tps_j, key2,
+                )
+            with self._span("sched.sync"):
+                proposals[:, 1:] = np.asarray(toks_out).T
             draft_logits = jnp.concatenate(
                 [lg1[:, None], jnp.transpose(lg_steps, (1, 0, 2))], axis=1
             )  # [B, γ, V]
@@ -3024,21 +3166,24 @@ class Scheduler:
             t_toks[i, 1:] = proposals[i]
             t_pos0[i] = seq.total_len - 1
             t_valid[i] = S
-        t_logits, self.cache.k, self.cache.v = self._consume_aux(
-            self._t_chunk_jit(
-                self.params, self.cache.k, self.cache.v,
-                jnp.asarray(t_toks), jnp.asarray(t_pos0), jnp.asarray(t_valid), tables_j,
+        with self._launch("spec_target_chunk"):
+            t_logits, self.cache.k, self.cache.v = self._consume_aux(
+                self._t_chunk_jit(
+                    self.params, self.cache.k, self.cache.v,
+                    jnp.asarray(t_toks), jnp.asarray(t_pos0), jnp.asarray(t_valid), tables_j,
+                )
             )
-        )
 
         # Rejection-sampling verification (greedy rows: exact argmax check).
         self._step_counter += 1
         vkey = jax.random.fold_in(self._rng, self._step_counter)
-        accepted, next_tok = self._spec_verify_jit(
-            draft_logits, t_logits, jnp.asarray(proposals), temps_j, tks_j, tps_j, vkey
-        )
-        accepted_h = np.asarray(accepted)
-        next_h = np.asarray(next_tok)
+        with self._launch("spec_verify"):
+            accepted, next_tok = self._spec_verify_jit(
+                draft_logits, t_logits, jnp.asarray(proposals), temps_j, tks_j, tps_j, vkey
+            )
+        with self._span("sched.sync"):
+            accepted_h = np.asarray(accepted)
+            next_h = np.asarray(next_tok)
 
         st = self.spec_stats
         st.num_rounds += 1
@@ -3092,6 +3237,7 @@ class Scheduler:
         seq.num_computed = n_pref
         if seq.admitted_ts is None:
             seq.admitted_ts = time.monotonic()
+            seq.first_step = self.flight.log.step
         # Spec decode: the draft cache has nothing for remotely-prefilled KV —
         # compute the draft's own prompt KV before the row joins spec rounds.
         self._draft_catchup_prefill(seq, seq.prompt)
@@ -3187,9 +3333,10 @@ class Scheduler:
         """Device-side block duplication (the COW copy). One warmed
         executable; src/dst ride as traced scalars."""
         self.flight.record_exec("kv_block_copy", ())
-        self.cache.k, self.cache.v = self._kv_copy_jit(
-            self.cache.k, self.cache.v, jnp.int32(src), jnp.int32(dst)
-        )
+        with self._launch("kv_block_copy"):
+            self.cache.k, self.cache.v = self._kv_copy_jit(
+                self.cache.k, self.cache.v, jnp.int32(src), jnp.int32(dst)
+            )
 
     def _match_prefix_tiers(self, seq: Sequence) -> List[int]:
         """G1 match, extended through G2/G3 onboarding when KVBM is attached.
@@ -3241,15 +3388,15 @@ class Scheduler:
             model = self._model
             uf = self._use_flash_prefill
 
-            self._mm_jit = jax.jit(
-                lambda p, k, v, t, vl, cl, bt, hp, mf, ml: model.prefill(
+
+            def prefill_mm(p, k, v, t, vl, cl, bt, hp, mf, ml):
+                return model.prefill(
                     p, self.mc, k, v, t, vl, cl, bt,
                     use_flash=uf, has_prefix=hp, mm_feats=mf, mm_len=ml,
                     moe_stats=self._moe_stats,
-                ),
-                donate_argnums=(1, 2),
-                static_argnums=(7,),
-            )
+                )
+
+            self._mm_jit = jax.jit(prefill_mm, donate_argnums=(1, 2), static_argnums=(7,))
         return self._mm_jit
 
     def _prefill_table(self, seq: Sequence) -> jnp.ndarray:
@@ -3379,7 +3526,8 @@ class Scheduler:
                 jnp.asarray([s.top_p], dtype=jnp.float32),
                 self._row_key(seq),
             )
-        token = int(np.asarray(tok)[0])
+        with self._span("sched.sync"):
+            token = int(np.asarray(tok)[0])
         if s.top_logprobs:
             # First token's alternatives: same op group as the batched
             # top-k path (guided rows already applied their mask above via
@@ -3578,6 +3726,31 @@ class Scheduler:
             tpot_s=tpot_s,
         ))
 
+    def _log_request(self, seq: Sequence, reason: str) -> None:
+        """One record per finished request in the step log, always on (PR 2's
+        sampled trace events are beside it): the stamps of its life on
+        ``time.monotonic()`` — ``enqueued`` (TpuEngine.generate took it),
+        ``arrival`` (add_request, after the staged wait), ``admitted``,
+        ``first_token``, ``finished`` — its token counts, and the scheduler
+        iterations (``sched.step`` numbers) of its first and last dispatch."""
+        log = self.flight.log
+        log.requests.append({
+            "request_id": seq.request_id,
+            "reason": reason,
+            "enqueued": seq.enqueued_ts,
+            "arrival": seq.arrival_ts,
+            "admitted": seq.admitted_ts,
+            "first_token": seq.first_token_ts,
+            "finished": time.monotonic(),
+            "prompt_tokens": len(seq.prompt),
+            "cached_tokens": seq.cached_tokens,
+            "output_tokens": len(seq.output_ids),
+            "prefill_chunks": seq.prefill_chunks,
+            "preemptions": seq.preemptions,
+            "first_step": seq.first_step,
+            "last_step": log.step,
+        })
+
     def _finish(self, seq: Sequence, reason: str, outputs: List[tuple], emit: bool = True) -> None:
         if seq in self.running:
             self.running.remove(seq)
@@ -3597,6 +3770,7 @@ class Scheduler:
         # Tenant ledger: the request's capacity bill, emitted while blocks
         # are still held so the KV accrual closes at the true release point.
         self._emit_bill(seq, reason, ttft_s=ttft_s, tpot_s=tpot_s)
+        self._log_request(seq, reason)
         self._trace_event(
             seq, "finish", reason=reason, output_tokens=len(seq.output_ids),
             preemptions=seq.preemptions,

@@ -18,6 +18,7 @@ from dynamo_tpu.llm.protocols.common import LLMEngineOutput
 from dynamo_tpu.llm.tokenizer import DecodeStream, Tokenizer
 from dynamo_tpu.runtime.engine import Annotated, Context
 from dynamo_tpu.runtime.pipeline import Operator
+from dynamo_tpu.runtime.tracing import get_step_log
 
 
 class StopStringJail:
@@ -116,6 +117,8 @@ class Backend(Operator):
                 reasoning=reasoning,
             )
 
+        frames = get_step_log()
+
         async def gen():
             stopped = False
             async for item in stream:
@@ -142,8 +145,11 @@ class Backend(Operator):
                         yield Annotated(data=LLMEngineOutput(finish_reason="stop", index=out.index).to_wire())
                         return
                     continue
-                delta = decoder.step(out.token_ids) if out.token_ids else ""
-                emit_text, hit = jail.feed(delta) if delta else (None, False)
+                # One frame through detokenisation, on the event loop (the GIL
+                # the engine's step thread needs): a ``backend.frame`` span.
+                with frames.span("backend.frame", tokens=len(out.token_ids or ())):
+                    delta = decoder.step(out.token_ids) if out.token_ids else ""
+                    emit_text, hit = jail.feed(delta) if delta else (None, False)
                 if hit:
                     stopped = True
                     yield Annotated(data=finalize(out, emit_text, "stop", include_tail=False).to_wire())

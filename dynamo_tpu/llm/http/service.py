@@ -27,7 +27,7 @@ from dynamo_tpu.llm.protocols.common import LLMEngineOutput, as_engine_output
 from dynamo_tpu.runtime.engine import Annotated, Context, StreamDisconnect
 from dynamo_tpu.runtime.logging import TraceParent, get_logger
 from dynamo_tpu.runtime.push_router import NoInstancesError
-from dynamo_tpu.runtime.tracing import NULL_SPAN, get_tracer
+from dynamo_tpu.runtime.tracing import NULL_SPAN, get_step_log, get_tracer
 from dynamo_tpu.runtime.metrics import (
     DURATION_BUCKETS,
     FRONTEND_PREFIX,
@@ -1218,7 +1218,9 @@ def _trace_headers(ctx: Context) -> dict:
 
 
 async def _sse(resp: web.StreamResponse, obj: dict) -> None:
-    await resp.write(b"data: " + json.dumps(obj, ensure_ascii=False).encode() + b"\n\n")
+    # One SSE frame encoded and written: an ``http.frame`` span (event loop).
+    with get_step_log().span("http.frame"):
+        await resp.write(b"data: " + json.dumps(obj, ensure_ascii=False).encode() + b"\n\n")
 
 
 async def _sse_event(resp: web.StreamResponse, event: str, comment: Optional[str]) -> None:

@@ -310,6 +310,141 @@ class Tracer:
         self.enabled = False
 
 
+# --- step-phase spans ---------------------------------------------------------
+#
+# Request traces above answer "what happened to this request"; the spans below
+# answer "what was the engine doing": one entry per layer boundary of the
+# served path (engine loop, scheduler phases, frontend frames), always on.
+# One call site writes to two places:
+#
+# - the profiler's own trace (``jax.profiler.TraceAnnotation``, names prefixed
+#   ``dyn:``) whenever ANY profiler session is open, so host spans and device
+#   operations share a clock;
+# - a bounded in-memory ``StepLog`` on ``time.monotonic_ns()`` (the clock of
+#   ``Sequence.arrival_ts`` and ``FlightRecorder.last_step_ts``).
+#
+# With no session open a span costs one ``is_enabled()`` branch, two clock
+# reads and one tuple: attributes travel as keyword arguments and are only
+# formatted (by the profiler, in C++) while a session records them.
+
+STEP_LOG_SIZE = 16384  # >= 60 s of a saturated engine: ~600 dispatches x <= 16 entries
+REQUEST_LOG_SIZE = 4096
+PROFILER_PREFIX = "dyn:"
+
+_profiler = None  # (is_enabled, TraceAnnotation) of jax.profiler, resolved at the first span
+
+
+def _no_session() -> bool:
+    return False
+
+
+def _resolve_profiler():
+    global _profiler
+    try:
+        from jax.profiler import TraceAnnotation
+
+        _profiler = (TraceAnnotation.is_enabled, TraceAnnotation)
+    except (ImportError, AttributeError):  # a frontend process without JAX
+        _profiler = (_no_session, None)
+    return _profiler
+
+
+class StepSpan:
+    """One interval of the served path. ``with log.span(name, **attrs)`` (or
+    explicit ``begin()``/``end()`` where the interval crosses functions);
+    ``t0``/``t1`` are ``time.monotonic_ns()`` stamps and ``dur`` seconds."""
+
+    __slots__ = ("log", "name", "step", "attrs", "t0", "t1", "_ann")
+
+    def __init__(self, log: "StepLog", name: str, step: int, attrs: Optional[dict]):
+        self.log = log
+        self.name = name
+        self.step = step
+        self.attrs = attrs
+        self.t0 = self.t1 = 0
+        self._ann = None
+
+    def set(self, **attrs: Any) -> None:
+        """Attributes learned while the span is open (a step's kind and
+        shape are only known once its batch is formed)."""
+        if self.attrs:
+            self.attrs.update(attrs)
+        else:
+            self.attrs = attrs
+        if self._ann is not None:
+            self._ann.set_metadata(**attrs)
+
+    def begin(self) -> "StepSpan":
+        is_enabled, annotation = _profiler or _resolve_profiler()
+        if is_enabled():
+            self._ann = annotation(PROFILER_PREFIX + self.name, step=self.step, **(self.attrs or {}))
+            self._ann.__enter__()
+        self.t0 = time.monotonic_ns()
+        return self
+
+    def end(self) -> None:
+        self.t1 = time.monotonic_ns()
+        self.log.spans.append((self.name, self.t0, self.t1, self.step, self.attrs))
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+            self._ann = None
+
+    @property
+    def dur(self) -> float:
+        return (self.t1 - self.t0) / 1e9
+
+    __enter__ = begin
+
+    def __exit__(self, exc_type, exc, tb) -> None:
+        self.end()
+
+
+class StepLog:
+    """Bounded in-memory log of step-phase spans and finished requests.
+
+    ``spans`` holds ``(name, t0_ns, t1_ns, step, attrs | None)`` tuples in
+    order of their END; ``requests`` one dict per finished request; ``step``
+    is the sequence number of the scheduler iteration in progress (spans
+    carry it, request records name their first and last). Appended from the
+    step thread and the event loop (``deque.append`` is atomic); readers take
+    ``list(...)`` snapshots."""
+
+    def __init__(self, maxlen: int = STEP_LOG_SIZE, request_maxlen: int = REQUEST_LOG_SIZE):
+        self.spans: "deque[tuple]" = deque(maxlen=maxlen)
+        self.requests: "deque[dict]" = deque(maxlen=request_maxlen)
+        self.step = 0
+
+    def span(self, name: str, step: Optional[int] = None, **attrs: Any) -> StepSpan:
+        return StepSpan(self, name, self.step if step is None else step, attrs or None)
+
+    def last(self, name: str) -> Optional[tuple]:
+        """Newest finished span called ``name`` (a short backwards scan)."""
+        for entry in reversed(self.spans):
+            if entry[0] == name:
+                return entry
+        return None
+
+    def named(self, name: str, limit: Optional[int] = None) -> List[tuple]:
+        """Finished spans called ``name``, oldest first, at most the newest ``limit``."""
+        out: List[tuple] = []
+        for entry in reversed(list(self.spans)):
+            if entry[0] == name:
+                out.append(entry)
+                if len(out) == limit:
+                    break
+        return out[::-1]
+
+
+_STEP_LOG = StepLog()
+
+
+def get_step_log() -> StepLog:
+    """The process's own log: frontend spans (``backend.frame``,
+    ``http.frame``) that belong to no one engine. Each scheduler's flight
+    recorder owns the log of its engine's spans."""
+    return _STEP_LOG
+
+
 # --- process-global tracer ---------------------------------------------------
 
 _TRACER = Tracer(path=None, sample=0.0)
