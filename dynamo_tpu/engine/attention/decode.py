@@ -36,7 +36,7 @@ into bench.py's ``decode_attention`` section):
   lanes hit zeros) in one MXU-shaped ``[KVH*G, 512]×[512, BS]`` matmul.
   The ×KVH FLOP overhead is immaterial — decode attention has ~100×
   MXU headroom; bytes are the budget. The lanes-vs-lanes contraction
-  (cache pages are token-major ``[BS, KVH, HD]``) costs an in-kernel
+  (cache pages are token-major ``[BS, KVH*HD]``) costs an in-kernel
   transpose that would matter in a compute-bound kernel and does not
   here.
 - Returns UNnormalized online-softmax partials ``(m, l, acc)`` in the
@@ -91,7 +91,7 @@ def _paged_kernel(
     def _compute():
         wq = wq_ref[0]  # [KVG, KVHD]
         rows, merged = wq.shape
-        k = k_ref[0]  # [BS, KVH*HD] — merged lanes, reshaped by the caller
+        k = k_ref[0]  # [BS, KVH*HD] — merged lanes, as the pool stores them
         v = v_ref[0]
         s = (
             lax.dot_general(
@@ -120,7 +120,7 @@ def _paged_kernel(
 )
 def paged_decode_partials(
     q: jax.Array,  # [B, H, HD] post-rope current-token queries
-    k_pages: jax.Array,  # [NP, BS, KVH, HD] layer-flat page pool
+    k_pages: jax.Array,  # [NP, BS, KVH*HD] layer-flat page pool
     v_pages: jax.Array,
     tables: jax.Array,  # [B, W] i32 — page ids, layer-offset, padded slots → 0
     lengths: jax.Array,  # [B] i32 — true prefix length (0 = inactive row)
@@ -148,13 +148,11 @@ def paged_decode_partials(
     eye = jnp.eye(KVH, dtype=q.dtype)[:, None, :, None]  # [KVH, 1, KVH, 1]
     wq = (q_r[:, :, :, None, :] * eye[None]).reshape(B, KVG, KVHD)
 
-    # Merge the (KVH, HD) trailing dims into lanes OUTSIDE the kernel —
-    # contiguous, so XLA reshapes metadata only; Mosaic cannot shape-cast
-    # [BS, KVH, HD] → [BS, KVH*HD] in-kernel.
-    NP = k_pages.shape[0]
+    # The pool is stored with (KVH, HD) merged into lanes — the page the
+    # BlockSpec reads (the layout contract: ``KvCacheArrays``) — so the
+    # pages go to the kernel untouched.
     BS = k_pages.shape[1]
-    k2 = k_pages.reshape(NP, BS, KVHD)
-    v2 = v_pages.reshape(NP, BS, KVHD)
+    assert k_pages.shape[2] == KVHD, (k_pages.shape, KVH, HD)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
@@ -181,7 +179,7 @@ def paged_decode_partials(
         ),
         grid_spec=grid_spec,
         interpret=interpret,
-    )(tables.astype(jnp.int32), lengths.astype(jnp.int32), wq, k2, v2)
+    )(tables.astype(jnp.int32), lengths.astype(jnp.int32), wq, k_pages, v_pages)
 
     m = m.reshape(B, KVH, G)
     l = l.reshape(B, KVH, G)

@@ -218,7 +218,7 @@ def ragged_paged_attention(
     q: jax.Array,  # [NQ, H, HD] post-rope queries (chunk rows then decode rows)
     k_extra: jax.Array,  # [CK, KVH, HD] in-flight keys (chunk K, window rows, current tokens)
     v_extra: jax.Array,
-    k_pages,  # [NP, BS, KVH, HD] layer-flat page pool, or QuantKv
+    k_pages,  # [NP, BS, KVH*HD] layer-flat page pool, or QuantKv (scales [NP, BS, KVH])
     v_pages,
     tables: jax.Array,  # [R, W] i32 — per-sequence-row page ids (layer-offset)
     meta: jax.Array,  # [5, NQ] i32 — build_meta
@@ -234,6 +234,9 @@ def ragged_paged_attention(
     ever materialized in HBM.
 
     Dead queries (``meta`` active = 0) return zeros and read nothing.
+
+    The pages reach ``pallas_call`` untouched: the pool is stored in the
+    layout the page ``BlockSpec`` reads (the contract: ``KvCacheArrays``).
     """
     from dynamo_tpu.engine.kv_cache import QuantKv
 
@@ -256,14 +259,8 @@ def ragged_paged_attention(
     ke = k_extra.reshape(CK, KVHD)
     ve = v_extra.reshape(CK, KVHD)
 
-    if quant:
-        NP, BS = k_pages.q.shape[0], k_pages.q.shape[1]
-        k2, v2 = k_pages.q.reshape(NP, BS, KVHD), v_pages.q.reshape(NP, BS, KVHD)
-        ks = k_pages.scale.reshape(NP, BS, KVH).astype(jnp.float32)
-        vs = v_pages.scale.reshape(NP, BS, KVH).astype(jnp.float32)
-    else:
-        NP, BS = k_pages.shape[0], k_pages.shape[1]
-        k2, v2 = k_pages.reshape(NP, BS, KVHD), v_pages.reshape(NP, BS, KVHD)
+    BS = k_pages.shape[1]
+    assert k_pages.shape[2] == KVHD, (k_pages.shape, KVH, HD)
 
     def page_idx(nq, w, t, mt):
         return (t[mt[0, nq], jnp.minimum(w, W - 1)], 0, 0)
@@ -275,13 +272,14 @@ def ragged_paged_attention(
         pl.BlockSpec((1, BS, KVHD), page_idx),
         pl.BlockSpec((1, BS, KVHD), page_idx),
     ]
-    args = [wq, ke, ve, k2, v2]
     if quant:
         in_specs += [
             pl.BlockSpec((1, BS, KVH), page_idx),
             pl.BlockSpec((1, BS, KVH), page_idx),
         ]
-        args += [ks, vs]
+        args = [wq, ke, ve, k_pages.q, v_pages.q, k_pages.scale, v_pages.scale]
+    else:
+        args = [wq, ke, ve, k_pages, v_pages]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
@@ -399,11 +397,11 @@ def _fused_window_kernel(
         mask_ref, next_ref = rest[r : r + 2]  # [P, ceil(V/32)] u32, [P, V] i32
         r += 2
     (
-        k_in_ref,  # [L, N, BS, KVH, HD] (aliased to k_out off-interpret)
+        k_in_ref,  # [L, N, BS, KVH*HD] (aliased to k_out off-interpret)
         v_in_ref,
         # outputs
         tok_out_ref,  # [NSTEPS, B] i32
-        k_out_ref,  # [L, N, BS, KVH, HD]
+        k_out_ref,  # [L, N, BS, KVH*HD]
         v_out_ref,
         # scratch
         h_ref,  # VMEM [B, D] wdtype — the inter-layer residual carry
@@ -460,15 +458,15 @@ def _fused_window_kernel(
         slot = jnp.where(live, pos_b, 0)
         blk = jnp.where(live, tables_ref[b, slot // bs], 0)
         off = slot % bs
-        k_out_ref[l, blk, off] = k[b].astype(k_out_ref.dtype)
-        v_out_ref[l, blk, off] = v[b].astype(v_out_ref.dtype)
+        k_out_ref[l, blk, off] = k[b].reshape(KVH * HD).astype(k_out_ref.dtype)
+        v_out_ref[l, blk, off] = v[b].reshape(KVH * HD).astype(v_out_ref.dtype)
 
     attn_rows = []
     for b in range(B):
         pages_k = [k_out_ref[l, tables_ref[b, w]] for w in range(W)]
         pages_v = [v_out_ref[l, tables_ref[b, w]] for w in range(W)]
-        kb = jnp.concatenate(pages_k, axis=0).astype(x.dtype)  # [W*BS, KVH, HD]
-        vb = jnp.concatenate(pages_v, axis=0).astype(x.dtype)
+        kb = jnp.concatenate(pages_k, axis=0).astype(x.dtype).reshape(W * bs, KVH, HD)
+        vb = jnp.concatenate(pages_v, axis=0).astype(x.dtype).reshape(W * bs, KVH, HD)
         qg = q[b].reshape(KVH, G, HD)
         s = jnp.einsum("kgd,skd->kgs", qg, kb).astype(jnp.float32) * scale
         kpos = lax.iota(jnp.int32, W * bs)
@@ -540,7 +538,7 @@ def fused_decode_window(
     w_gate: jax.Array,
     w_up: jax.Array,
     w_down: jax.Array,
-    k_cache: jax.Array,  # [L, N, BS, KVH, HD]
+    k_cache: jax.Array,  # [L, N, BS, KVH*HD]
     v_cache: jax.Array,
     tokens: jax.Array,  # [B] i32
     positions: jax.Array,  # [B] i32
@@ -580,7 +578,7 @@ def fused_decode_window(
     allow-bitmask pool, and the FSM advances ON-CHIP between steps through
     the next-state row pool, so guided rows no longer flush the window.
     """
-    L, N, BS, KVH, HD = k_cache.shape
+    L = k_cache.shape[0]
     B = tokens.shape[0]
     V, D = embed.shape
 
@@ -662,7 +660,7 @@ def _one_token_forward(
     positions,  # [B] i32 — write slot / attention frontier per row
     act_ref,  # SMEM [B] i32
     tables_ref,  # SMEM [B, W] i32
-    k_ref,  # [L, N, BS, KVH, HD] output-aliased cache ref
+    k_ref,  # [L, N, BS, KVH*HD] output-aliased cache ref
     v_ref,
     w,  # 12-tuple of weight refs (embed..w_down, fused-window layout)
     *,
@@ -701,16 +699,16 @@ def _one_token_forward(
             slot = jnp.where(live, jnp.maximum(positions[b], 0), 0)
             blk = jnp.where(live, tables_ref[b, slot // bs], 0)
             off = slot % bs
-            k_ref[l, blk, off] = k[b].astype(k_ref.dtype)
-            v_ref[l, blk, off] = v[b].astype(v_ref.dtype)
+            k_ref[l, blk, off] = k[b].reshape(KVH * HD).astype(k_ref.dtype)
+            v_ref[l, blk, off] = v[b].reshape(KVH * HD).astype(v_ref.dtype)
         attn_rows = []
         for b in range(B):
             kb = jnp.concatenate(
                 [k_ref[l, tables_ref[b, wi]] for wi in range(W)], axis=0
-            ).astype(x.dtype)  # [W*BS, KVH, HD]
+            ).astype(x.dtype).reshape(W * bs, KVH, HD)
             vb = jnp.concatenate(
                 [v_ref[l, tables_ref[b, wi]] for wi in range(W)], axis=0
-            ).astype(x.dtype)
+            ).astype(x.dtype).reshape(W * bs, KVH, HD)
             qg = q[b].reshape(KVH, G, HD)
             s = jnp.einsum("kgd,skd->kgs", qg, kb).astype(jnp.float32) * scale
             kpos = lax.iota(jnp.int32, W * bs)
@@ -777,16 +775,16 @@ def _chunk_forward(
                 slot = jnp.where(live, jnp.maximum(positions[b, s], 0), 0)
                 blk = jnp.where(live, tables_ref[b, slot // bs], 0)
                 off = slot % bs
-                k_ref[l, blk, off] = k[b, s].astype(k_ref.dtype)
-                v_ref[l, blk, off] = v[b, s].astype(v_ref.dtype)
+                k_ref[l, blk, off] = k[b, s].reshape(KVH * HD).astype(k_ref.dtype)
+                v_ref[l, blk, off] = v[b, s].reshape(KVH * HD).astype(v_ref.dtype)
         attn_rows = []
         for b in range(B):
             kb = jnp.concatenate(
                 [k_ref[l, tables_ref[b, wi]] for wi in range(W)], axis=0
-            ).astype(x.dtype)  # [T, KVH, HD]
+            ).astype(x.dtype).reshape(W * bs, KVH, HD)  # [T, KVH, HD]
             vb = jnp.concatenate(
                 [v_ref[l, tables_ref[b, wi]] for wi in range(W)], axis=0
-            ).astype(x.dtype)
+            ).astype(x.dtype).reshape(W * bs, KVH, HD)
             qg = q[b].reshape(S, KVH, G, HD)
             s_sc = jnp.einsum("skgd,tkd->skgt", qg, kb).astype(jnp.float32) * scale
             kpos = lax.iota(jnp.int32, W * bs)
@@ -970,7 +968,7 @@ def fused_spec_window(
     # draft weights
     d_embed, d_head, d_fnorm, d_anorm, d_mnorm,
     d_wq, d_wk, d_wv, d_wo, d_wg, d_wu, d_wd,
-    k_t: jax.Array,  # [Lt, N, BS, KVHt, HDt] target cache
+    k_t: jax.Array,  # [Lt, N, BS, KVHt*HDt] target cache
     v_t: jax.Array,
     k_d: jax.Array,  # draft cache
     v_d: jax.Array,

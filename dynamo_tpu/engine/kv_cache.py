@@ -2,8 +2,9 @@
 caching and KV event emission.
 
 The device cache is a global block pool: ``k``/``v`` arrays of shape
-``[layers, num_blocks, block_size, kv_heads, head_dim]``. Sequences own
-*block tables* (lists of block indices); attention gathers through them.
+``[layers, num_blocks, block_size, kv_heads * head_dim]`` (the layout
+contract is in ``KvCacheArrays``). Sequences own *block tables* (lists of
+block indices); attention gathers through them.
 This is the TPU-native equivalent of vLLM's paged KV plus the engine-side
 part of the reference's KVBM G1 tier (lib/llm/src/block_manager — device
 pool, sequence-hash reuse in block/registry.rs:478, pool/managed.rs
@@ -38,8 +39,8 @@ class QuantKv(NamedTuple):
     like a plain array — model code dispatches on the type at gather/scatter
     points (``dequantize_kv`` / ``quantize_kv_rows``)."""
 
-    q: jax.Array  # int8, [L, N, BS, KVH, HD]
-    scale: jax.Array  # f32, [L, N, BS, KVH, 1]
+    q: jax.Array  # int8, [L, N, BS, KVH*HD]
+    scale: jax.Array  # f32, [L, N, BS, KVH]
 
     @property
     def shape(self):
@@ -49,24 +50,40 @@ class QuantKv(NamedTuple):
     def dtype(self):
         return self.q.dtype
 
-    def reshape(self, *shape) -> "QuantKv":
-        # Layer-flat views ([L*N, ...]) reshape both members coherently.
-        return QuantKv(self.q.reshape(*shape), self.scale.reshape(*shape[:-1], 1))
-
 
 def quantize_kv_rows(rows: jax.Array) -> QuantKv:
-    """Symmetric int8 quantization over the trailing (head_dim) axis."""
+    """Symmetric int8 quantization of rows ``[..., KVH, HD]`` over the
+    head_dim axis, returned in the pool's layout: codes ``[..., KVH*HD]``,
+    scales ``[..., KVH]``."""
     amax = jnp.max(jnp.abs(rows.astype(jnp.float32)), axis=-1, keepdims=True)
     scale = jnp.where(amax > 0, amax / 127.0, 1.0)
     q = jnp.clip(jnp.round(rows.astype(jnp.float32) / scale), -127, 127).astype(jnp.int8)
-    return QuantKv(q, scale)
+    return QuantKv(merge_heads(q), scale[..., 0])
 
 
-def dequantize_kv(x, dtype=jnp.bfloat16):
-    """QuantKv → real-valued rows; plain arrays pass through."""
-    if isinstance(x, QuantKv):
-        return (x.q.astype(jnp.float32) * x.scale).astype(dtype)
-    return x
+def dequantize_kv(x: QuantKv, dtype=jnp.bfloat16) -> jax.Array:
+    """QuantKv in the pool's layout → real-valued rows ``[..., KVH, HD]``."""
+    rows = split_heads(x.q, x.scale.shape[-1]).astype(jnp.float32) * x.scale[..., None]
+    return rows.astype(dtype)
+
+
+def merge_heads(rows: jax.Array) -> jax.Array:
+    """``[..., KVH, HD]`` → ``[..., KVH*HD]``: rows enter the pool's layout.
+    For the rows a step writes or a block in transit, never for the pool."""
+    return rows.reshape(*rows.shape[:-2], rows.shape[-2] * rows.shape[-1])
+
+
+def split_heads(rows: jax.Array, num_kv_heads: int) -> jax.Array:
+    """``[..., KVH*HD]`` → ``[..., KVH, HD]``: what was gathered from the
+    pool leaves its layout. Its cost is the context gathered."""
+    return rows.reshape(*rows.shape[:-1], num_kv_heads, rows.shape[-1] // num_kv_heads)
+
+
+def layer_flat(cache):
+    """``[L, N, ...]`` → ``[L*N, ...]`` for an array or a QuantKv: the one
+    reshape of the pool a step program may hold. It merges leading
+    dimensions only, so no element changes tile and XLA emits a bitcast."""
+    return jax.tree.map(lambda a: a.reshape(a.shape[0] * a.shape[1], *a.shape[2:]), cache)
 
 
 def ragged_scatter_targets(
@@ -89,10 +106,27 @@ def ragged_scatter_targets(
 class KvCacheArrays:
     """Device-side block pool (one array pair covering all layers). With
     ``config.kv_cache_dtype == "int8"`` the members are :class:`QuantKv`
-    pytrees instead of plain arrays."""
+    pytrees instead of plain arrays.
 
-    k: Any  # jax.Array | QuantKv — [L, N, BS, KVH, HD]
+    **Layout contract.** ``k``/``v`` are ``[L, N, BS, KVH*HD]``: a token's
+    heads are merged into one lane axis, which is the page
+    ``(1, BS, KVH*HD)`` that the attention kernels' ``BlockSpec`` reads
+    (attention/megakernel.py, attention/decode.py). A QuantKv's codes take
+    the same shape and its scales are ``[L, N, BS, KVH]``; MLA's one latent
+    row per token is ``[L, N, BS, width]`` by the same rule (``kv_heads``
+    1). The pool is allocated in this layout and keeps it for life: un-
+    merging ``(KVH, HD)`` moves every element to another (8, 128) tile, so
+    on a chip it is a copy of the whole pool, and no step program may hold
+    one. Writers merge the rows they write (``merge_heads``), gathering
+    readers split what they gathered (``split_heads``), transfers reshape
+    one block at the boundary; ``layer_flat`` is the only reshape applied
+    to the pool itself. ``tests/test_kv_layout.py`` holds the step
+    programs to this. Sharding over ``tp`` is on axis 3: a contiguous
+    ``KVH*HD/tp`` slice is ``KVH/tp`` whole heads."""
+
+    k: Any  # jax.Array | QuantKv — [L, N, BS, KVH*HD]
     v: Any
+    kv_heads: int = 1  # KVH of the merged axis (1 for MLA's latent row)
 
     @classmethod
     def create(
@@ -108,33 +142,22 @@ class KvCacheArrays:
             # from the latent — models/mla.py). int8 quantizes the latent
             # row with one per-token scale (the row is rms-normed latent ‖
             # rope'd keys — O(1) ranges, one scale holds within a code step).
-            width = config.kv_lora_rank + config.qk_rope_head_dim
-            shape = (config.num_layers, num_blocks, config.block_size, 1, width)
-            if config.kv_cache_dtype == "int8":
-                q = jnp.zeros(shape, dtype=jnp.int8)
-                scale = jnp.zeros((*shape[:-1], 1), dtype=jnp.float32)
-                if sharding is not None:
-                    q = jax.device_put(q, sharding)
-                    scale = jax.device_put(scale, sharding)
-                return cls(k=QuantKv(q, scale), v=jnp.zeros((config.num_layers, 1, 1, 1, 1), dtype=dtype))
-            k = jnp.zeros(shape, dtype=dtype)
-            if sharding is not None:
-                k = jax.device_put(k, sharding)
-            return cls(k=k, v=jnp.zeros((config.num_layers, 1, 1, 1, 1), dtype=dtype))
-        shape = (config.num_layers, num_blocks, config.block_size, config.num_kv_heads, config.head_dim)
+            kv_heads, lanes = 1, config.kv_lora_rank + config.qk_rope_head_dim
+        else:
+            kv_heads, lanes = config.num_kv_heads, config.num_kv_heads * config.head_dim
+        rows = (config.num_layers, num_blocks, config.block_size)
+
+        def zeros(shape, dt):
+            init = jnp.zeros(shape, dtype=dt)
+            return jax.device_put(init, sharding) if sharding is not None else init
 
         def mk():
             if config.kv_cache_dtype == "int8":
-                q = jnp.zeros(shape, dtype=jnp.int8)
-                scale = jnp.zeros((*shape[:-1], 1), dtype=jnp.float32)
-                if sharding is not None:
-                    q = jax.device_put(q, sharding)
-                    scale = jax.device_put(scale, sharding)
-                return QuantKv(q, scale)
-            init = jnp.zeros(shape, dtype=dtype)
-            return jax.device_put(init, sharding) if sharding is not None else init
+                return QuantKv(zeros((*rows, lanes), jnp.int8), zeros((*rows, kv_heads), jnp.float32))
+            return zeros((*rows, lanes), dtype)
 
-        return cls(k=mk(), v=mk())
+        v = jnp.zeros((config.num_layers, 1, 1, 1), dtype=dtype) if config.architecture == "mla" else mk()
+        return cls(k=mk(), v=v, kv_heads=kv_heads)
 
 
 class OutOfBlocksError(Exception):

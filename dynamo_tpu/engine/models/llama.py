@@ -28,7 +28,14 @@ from jax import lax
 from jax.sharding import PartitionSpec as P
 
 from dynamo_tpu.engine.config import ModelConfig
-from dynamo_tpu.engine.kv_cache import QuantKv, quantize_kv_rows, ragged_scatter_targets
+from dynamo_tpu.engine.kv_cache import (
+    QuantKv,
+    layer_flat,
+    merge_heads,
+    quantize_kv_rows,
+    ragged_scatter_targets,
+    split_heads,
+)
 from dynamo_tpu.engine.quant import dequant_layer
 from dynamo_tpu.engine.sharding import HEADS, PAGES, kernel_shards, over_tp, step_mesh, tp_size
 
@@ -71,22 +78,31 @@ def _gather_kv(flat, idx, dtype):
     does not run faster than bf16 (measured: parity at b8, slower at wide
     batch — the gather widens byte elements internally), so int8 KV is a
     CAPACITY feature (double the blocks per HBM byte — longer contexts,
-    bigger batches before preemption), not a decode-latency one."""
+    bigger batches before preemption), not a decode-latency one.
+
+    ``flat`` is the layer-flat pool ``[L*N, BS, KVH*HD]``; the result is
+    what ``idx`` selected, ``[*idx.shape, BS, KVH*HD]`` (int8:
+    ``[*idx.shape, BS, KVH, HD]``), which every caller reshapes to
+    ``[..., ctx, KVH, HD]`` — a reshape of the context gathered, never of
+    the pool (the layout contract: ``KvCacheArrays``)."""
     if isinstance(flat, QuantKv):
-        return flat.q[idx].astype(dtype) * flat.scale[idx].astype(dtype)
+        scale = flat.scale[idx]
+        q = split_heads(flat.q[idx], scale.shape[-1])
+        return q.astype(dtype) * scale[..., None].astype(dtype)
     return flat[idx]
 
 
 def _scatter_kv(cache, layer_idx, blocks, offs, rows):
-    """Scatter fresh KV rows into the cache; int8 caches quantize on the way
-    in (requantization is stable to within one code step)."""
+    """Scatter fresh KV rows ``[..., KVH, HD]`` into the cache, merged into
+    the pool's lane layout on the way in; int8 caches also quantize
+    (requantization is stable to within one code step)."""
     if isinstance(cache, QuantKv):
         qk = quantize_kv_rows(rows)
         return QuantKv(
             cache.q.at[layer_idx, blocks, offs].set(qk.q),
             cache.scale.at[layer_idx, blocks, offs].set(qk.scale),
         )
-    return cache.at[layer_idx, blocks, offs].set(rows)
+    return cache.at[layer_idx, blocks, offs].set(merge_heads(rows))
 
 
 # ---------------------------------------------------------------------------
@@ -430,7 +446,7 @@ def _mega_attend_rows(
     q: jax.Array,  # [NQ, H, HD]
     k_extra: jax.Array,  # [CK, KVH, HD]
     v_extra: jax.Array,
-    k_flat,  # [L*N, BS, KVH, HD] layer-flat pages (QuantKv ok)
+    k_flat,  # [L*N, BS, KVH*HD] layer-flat pages (QuantKv ok)
     v_flat,
     tables: jax.Array,  # [R, W] layer-offset page tables
     meta: jax.Array,  # [5, NQ] megakernel.build_meta
@@ -510,7 +526,7 @@ def _attend(q: jax.Array, k: jax.Array, v: jax.Array, mask: jax.Array, config: M
 def prefill(
     params: Params,
     config: ModelConfig,
-    k_cache: jax.Array,  # [L, N, BS, KVH, HD]
+    k_cache: jax.Array,  # [L, N, BS, KVH*HD]
     v_cache: jax.Array,
     tokens: jax.Array,  # [T] bucket-padded token ids
     valid_len: jax.Array,  # scalar: actual new tokens
@@ -570,8 +586,8 @@ def prefill(
     # scratch sink because offset tables map 0 → l*N, layer l's own block 0.
     L = c.num_layers
     N = k_cache.shape[1]
-    k_flat = k_cache.reshape(L * N, bs, c.num_kv_heads, c.head_dim)
-    v_flat = v_cache.reshape(L * N, bs, c.num_kv_heads, c.head_dim)
+    k_flat = layer_flat(k_cache)
+    v_flat = layer_flat(v_cache)
 
     use_mega = _use_megakernel(c, k_cache)
     if use_mega:
@@ -676,7 +692,7 @@ def prefill(
 def decode_multi(
     params: Params,
     config: ModelConfig,
-    k_cache: jax.Array,  # [L, N, BS, KVH, HD]
+    k_cache: jax.Array,  # [L, N, BS, KVH*HD]
     v_cache: jax.Array,
     tokens: jax.Array,  # [B] current token per sequence
     positions: jax.Array,  # [B] write slot of the current token
@@ -750,8 +766,8 @@ def decode_multi(
         and not _use_megakernel(c, k_cache)
         and hoist_bytes <= _hoist_gather_budget()
     ):
-        k_flat = k_cache.reshape(L * N, bs, KVH, HD)
-        v_flat = v_cache.reshape(L * N, bs, KVH, HD)
+        k_flat = layer_flat(k_cache)
+        v_flat = layer_flat(v_cache)
         tables_all = block_tables[None] + (jnp.arange(L, dtype=jnp.int32) * N)[:, None, None]
         k_ctx_all = _gather_kv(k_flat, tables_all, wdtype).reshape(L, B, ctx_w, KVH, HD)
         v_ctx_all = _gather_kv(v_flat, tables_all, wdtype).reshape(L, B, ctx_w, KVH, HD)
@@ -821,7 +837,7 @@ def decode_multi(
 def decode_multi_fused(
     params: Params,
     config: ModelConfig,
-    k_cache: jax.Array,  # [L, N, BS, KVH, HD]
+    k_cache: jax.Array,  # [L, N, BS, KVH*HD]
     v_cache: jax.Array,
     tokens: jax.Array,  # [B] current token per sequence
     positions: jax.Array,  # [B] write slot of the current token
@@ -937,7 +953,7 @@ def decode_spec_fused(
 def _decode_layer_scan_window(
     layers: Dict[str, jax.Array],
     c: ModelConfig,
-    k_cache: jax.Array,  # [L, N, BS, KVH, HD] — read-only throughout
+    k_cache: jax.Array,  # [L, N, BS, KVH*HD] — read-only throughout
     v_cache: jax.Array,
     h: jax.Array,  # [B, D]
     positions: jax.Array,  # [B] true position of the current token
@@ -973,8 +989,8 @@ def _decode_layer_scan_window(
     # layer-offset tables instead of slicing the cache per layer.
     L = k_cache.shape[0]
     N = k_cache.shape[1]
-    k_flat = k_cache.reshape(L * N, bs, kvh, hd)
-    v_flat = v_cache.reshape(L * N, bs, kvh, hd)
+    k_flat = layer_flat(k_cache)
+    v_flat = layer_flat(v_cache)
     # Small-piece mask: window rows j < step, then the current token (always).
     small_mask = jnp.concatenate(
         [
@@ -1077,7 +1093,7 @@ def _decode_layer_scan_window(
 def chunk_decode(
     params: Params,
     config: ModelConfig,
-    k_cache: jax.Array,  # [L, N, BS, KVH, HD]
+    k_cache: jax.Array,  # [L, N, BS, KVH*HD]
     v_cache: jax.Array,
     tokens: jax.Array,  # [B, S] per-row token chunks (padded)
     positions0: jax.Array,  # [B] position of tokens[:, 0]
@@ -1113,8 +1129,8 @@ def chunk_decode(
     active = valid > 0
 
     N = k_cache.shape[1]
-    k_flat = k_cache.reshape(L * N, bs, kvh, hd)
-    v_flat = v_cache.reshape(L * N, bs, kvh, hd)
+    k_flat = layer_flat(k_cache)
+    v_flat = layer_flat(v_cache)
 
     h = params["embed"].at[tokens].get(mode="clip")  # [B, S, D]
     positions = positions0[:, None] + jnp.arange(S, dtype=jnp.int32)[None, :]  # [B, S]
@@ -1234,7 +1250,7 @@ def chunk_decode(
 def mixed_step(
     params: Params,
     config: ModelConfig,
-    k_cache: jax.Array,  # [L, N, BS, KVH, HD]
+    k_cache: jax.Array,  # [L, N, BS, KVH*HD]
     v_cache: jax.Array,
     p_tokens: jax.Array,  # [S] prefill-chunk token ids (bucket-padded)
     p_valid: jax.Array,  # scalar i32: actual chunk tokens (the row's ``len``)
@@ -1275,8 +1291,8 @@ def mixed_step(
     interp = not _on_tpu()
 
     N = k_cache.shape[1]
-    k_flat = k_cache.reshape(L * N, bs, kvh, hd)
-    v_flat = v_cache.reshape(L * N, bs, kvh, hd)
+    k_flat = layer_flat(k_cache)
+    v_flat = layer_flat(v_cache)
 
     p_positions = p_cache_len + jnp.arange(S, dtype=jnp.int32)
     p_valid_q = jnp.arange(S, dtype=jnp.int32) < p_valid
@@ -1420,7 +1436,7 @@ def mixed_step(
 def decode_sample(
     params: Params,
     config: ModelConfig,
-    k_cache: jax.Array,  # [L, N, BS, KVH, HD]
+    k_cache: jax.Array,  # [L, N, BS, KVH*HD]
     v_cache: jax.Array,
     tpa: jax.Array,  # [3, B] i32 — rows: (tokens, positions, active)
     block_tables: jax.Array,  # [B, max_blocks]
@@ -1529,7 +1545,7 @@ def decode_targets(
 def decode_layer_scan(
     layers: Dict[str, jax.Array],
     c: ModelConfig,
-    k_cache: jax.Array,  # [L', N, BS, KVH, HD] — full stack or a pipeline stage's slice
+    k_cache: jax.Array,  # [L', N, BS, KVH*HD] — full stack or a pipeline stage's slice
     v_cache: jax.Array,
     h: jax.Array,  # [B, D] embedded inputs (or activations from the previous pp stage)
     positions: jax.Array,  # [B]
@@ -1556,8 +1572,8 @@ def decode_layer_scan(
     # scan — gathers index [L'*N, ...] with layer-offset tables instead.
     Lp = k_cache.shape[0]
     N = k_cache.shape[1]
-    k_flat = k_cache.reshape(Lp * N, bs, c.num_kv_heads, c.head_dim)
-    v_flat = v_cache.reshape(Lp * N, bs, c.num_kv_heads, c.head_dim)
+    k_flat = layer_flat(k_cache)
+    v_flat = layer_flat(v_cache)
 
     kvh, G, hd = c.num_kv_heads, c.num_heads // c.num_kv_heads, c.head_dim
     scale = hd**-0.5
@@ -1627,7 +1643,7 @@ def decode_layer_scan(
 
 
 def scatter_kv_rows(
-    k_cache: jax.Array,  # [L', N, BS, KVH, HD]
+    k_cache: jax.Array,  # [L', N, BS, KVH*HD]
     v_cache: jax.Array,
     k_rows: jax.Array,  # [L', B, KVH, HD] from decode_layer_scan
     v_rows: jax.Array,
@@ -1645,7 +1661,7 @@ def scatter_kv_rows(
 def decode(
     params: Params,
     config: ModelConfig,
-    k_cache: jax.Array,  # [L, N, BS, KVH, HD]
+    k_cache: jax.Array,  # [L, N, BS, KVH*HD]
     v_cache: jax.Array,
     tokens: jax.Array,  # [B] current token per sequence
     positions: jax.Array,  # [B] position of each token (its write slot)

@@ -16,9 +16,9 @@ TPU-first design:
   sink), so the scheduler, prefix cache, KVBM and disaggregation move MLA
   blocks with zero special-casing.
 
-Cache layout: k_cache [L, N, BS, 1, R] with R = kv_lora_rank +
-qk_rope_head_dim (the "1" keeps the [L, N, BS, heads, dim] rank the rest of
-the stack expects); v_cache is unused (shape [L, 1, 1, 1, 1]).
+Cache layout: k_cache [L, N, BS, R] with R = kv_lora_rank +
+qk_rope_head_dim — one "head" of width R in the pool's merged-lane layout
+(``KvCacheArrays``); v_cache is unused (shape [L, 1, 1, 1]).
 """
 
 from __future__ import annotations
@@ -30,6 +30,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from dynamo_tpu.engine.config import ModelConfig
+from dynamo_tpu.engine.kv_cache import layer_flat
 from dynamo_tpu.engine.models.llama import _gather_kv, _scatter_kv, _mlp, apply_rope, rms_norm
 
 Params = Dict[str, jax.Array]
@@ -128,7 +129,7 @@ def _attend_latent(
 def prefill(
     params: Params,
     config: ModelConfig,
-    k_cache: jax.Array,  # [L, N, BS, 1, R]
+    k_cache: jax.Array,  # [L, N, BS, R]
     v_cache: jax.Array,  # unused
     tokens: jax.Array,  # [T]
     valid_len: jax.Array,
@@ -159,7 +160,7 @@ def prefill(
     chunk_mask = (chunk_q[None, :] <= chunk_q[:, None]) & valid_q[None, :]
     mask = jnp.concatenate([prefix_mask, chunk_mask], axis=1)  # [T, ctx+T]
     N = k_cache.shape[1]
-    k_flat = k_cache.reshape(c.num_layers * N, bs, 1, latent_width(c))
+    k_flat = layer_flat(k_cache)
 
     def layer_fn(h, xs):
         lp, l = xs
@@ -193,7 +194,7 @@ def prefill(
 def decode(
     params: Params,
     config: ModelConfig,
-    k_cache: jax.Array,  # [L, N, BS, 1, R]
+    k_cache: jax.Array,  # [L, N, BS, R]
     v_cache: jax.Array,  # unused
     tokens: jax.Array,  # [B]
     positions: jax.Array,  # [B]
@@ -217,7 +218,7 @@ def decode(
     mask_full = jnp.concatenate([mask, jnp.ones((B, 1), dtype=bool)], axis=1)
     # Layer-flat view: no per-layer cache slice in the scan (see prefill).
     N = k_cache.shape[1]
-    k_flat = k_cache.reshape(c.num_layers * N, bs, 1, R)
+    k_flat = layer_flat(k_cache)
 
     def layer_fn(h, xs):
         lp, l = xs
@@ -253,7 +254,7 @@ def decode(
 def decode_multi(
     params: Params,
     config: ModelConfig,
-    k_cache: jax.Array,  # [L, N, BS, 1, R]
+    k_cache: jax.Array,  # [L, N, BS, R]
     v_cache: jax.Array,  # unused
     tokens: jax.Array,  # [B]
     positions: jax.Array,  # [B]
@@ -281,7 +282,7 @@ def decode_multi(
     R = latent_width(c)
     N = k_cache.shape[1]
     ctx = block_tables.shape[1] * bs
-    k_flat = k_cache.reshape(L * N, bs, 1, R)
+    k_flat = layer_flat(k_cache)
     key_pos = jnp.arange(ctx, dtype=jnp.int32)
     mask0 = key_pos[None, :] < positions[:, None]  # fixed: cache not written in-window
 

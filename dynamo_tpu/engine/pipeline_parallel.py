@@ -43,7 +43,7 @@ from dynamo_tpu.engine.models.llama import (
 def pipelined_decode(
     params,
     config: ModelConfig,
-    k_cache: jax.Array,  # [L, N, BS, KVH, HD], layer axis sharded over pp
+    k_cache: jax.Array,  # [L, N, BS, KVH*HD], layer axis sharded over pp
     v_cache: jax.Array,
     tokens: jax.Array,  # [B]
     positions: jax.Array,  # [B]

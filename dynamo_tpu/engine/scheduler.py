@@ -495,6 +495,15 @@ class Scheduler:
             model_config.architecture == "llama"
             and model.resolve_prefill_impl(model_config) == "flash"
         )
+        # ``has_prefix`` is a STATIC argument of the prefill and mixed-step
+        # programs only where it changes them: on the flash path's own
+        # chunk attention, which skips the prefix piece of a fresh chunk.
+        # Under the megakernel (one ragged launch serves every row) the
+        # step programs never read it, and a static argument would key two
+        # byte-identical executables — the second one re-traced, lowered
+        # and fetched from the persistent cache in the middle of traffic
+        # (0.05-0.6 s of a stalled step thread per key, PERF.md §6 PR 26).
+        self._hp_static = self._use_flash_prefill and self._attn_impl != "megakernel"
         # Capacity-dispatch MoE exports drop counters (wide-EP observability;
         # ref: SURVEY.md §2e / trtllm_utils.py:37-39 wide-EP surface).
         self._moe_stats = (
@@ -525,7 +534,7 @@ class Scheduler:
         # Modules" line then reads jit_<kind>(...) (the flight recorder's
         # kind, with the window rung where there is one), so a program's
         # device time is found by name, without host marks.
-        if self._use_flash_prefill:
+        if self._hp_static:
 
             def prefill(p, k, v, t, vl, cl, bt, hp):
                 return model.prefill(
@@ -535,8 +544,9 @@ class Scheduler:
             self._prefill_jit = jax.jit(prefill, donate_argnums=(1, 2), static_argnums=(7,))
         else:
             # ``hp`` rides as a TRACED (unused) arg here: the XLA path's
-            # masks cover prefix and fresh prefills alike, and a static arg
-            # would compile two byte-identical executables per bucket.
+            # masks and the megakernel's ragged rows cover prefix and fresh
+            # prefills alike, and a static arg would compile two
+            # byte-identical executables per bucket.
             def prefill(p, k, v, t, vl, cl, bt, hp):
                 return model.prefill(p, self.mc, k, v, t, vl, cl, bt, **stats_kw)
 
@@ -1307,12 +1317,13 @@ class Scheduler:
     def _get_mixed_jit(self, key):
         """Mixed-step executable for (s_bucket, p_width, d_bucket, d_width)
         — shared by _mixed_step and warmup so both compile the same thing.
-        ``hp`` follows the prefill convention: static on the flash path
-        (the kernel skips the prefix piece), traced no-op on XLA."""
+        ``hp`` follows the prefill convention (``_hp_static``): static on
+        the flash path's own chunk attention (the kernel skips the prefix
+        piece), a traced no-op on XLA and under the megakernel."""
         if key not in self._mixed_jits:
             model = self._model
             stats_kw = {"moe_stats": True} if self._moe_stats else {}
-            if self._use_flash_prefill:
+            if self._hp_static:
 
                 def mixed_step(p, k, v, pt, pv, cl, ptab, dt, dpos, dtab, dact, hp):
                     return model.mixed_step(
@@ -1387,7 +1398,7 @@ class Scheduler:
                 jnp.asarray(tokens), jnp.asarray(positions), jnp.asarray(active)
             )
             mixed_key = (s_bucket, int(p_table.shape[0]), d_bucket, width)
-            exec_key = mixed_key + ((has_prefix,) if self._use_flash_prefill else ())
+            exec_key = mixed_key + ((has_prefix,) if self._hp_static else ())
             self.flight.record_exec("mixed", exec_key)
             self._note_step("mixed", exec_key, batch, prefill=len(chunk_tokens), decode=n)
         with self._launch("mixed"):
@@ -1794,7 +1805,7 @@ class Scheduler:
                 # Shape key mirrors warmup(): on the XLA path has_prefix is a
                 # traced no-op arg (one executable serves both values).
                 kind, fn = "prefill", self._prefill_jit
-                key = (bucket, int(table.shape[0]), has_prefix if self._use_flash_prefill else False)
+                key = (bucket, int(table.shape[0]), has_prefix if self._hp_static else False)
                 self.flight.record_exec("prefill", key)
             self._note_step(kind, key, (seq,), prefill=len(tokens))
         with self._launch(kind):
@@ -2118,7 +2129,7 @@ class Scheduler:
                 # no-op arg, so the second call is a cache hit.)
                 for hp in (False, True):
                     self.flight.record_exec(
-                        "prefill", (bucket, width, hp if self._use_flash_prefill else False)
+                        "prefill", (bucket, width, hp if self._hp_static else False)
                     )
                     _, self.cache.k, self.cache.v = self._consume_aux(
                         self._prefill_jit(
@@ -2191,7 +2202,7 @@ class Scheduler:
                         self.flight.record_exec(
                             "mixed",
                             (s_b, p_w, bucket, width)
-                            + ((False,) if self._use_flash_prefill else ()),
+                            + ((False,) if self._hp_static else ()),
                         )
                         res = self._get_mixed_jit((s_b, p_w, bucket, width))(
                             self.params, self.cache.k, self.cache.v,

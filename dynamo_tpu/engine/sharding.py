@@ -18,7 +18,7 @@ Weight layout (megatron-style column→row pairs so each layer needs exactly
 one all-reduce per block):
 - wq/wk/wv, w_gate/w_up: shard output dim over tp (column-parallel).
 - wo, w_down:            shard input dim over tp (row-parallel).
-- KV cache:              shard kv_heads over tp.
+- KV cache:              shard kv_heads (the merged KVH*HD axis) over tp.
 - embed/lm_head:         shard vocab over tp.
 
 The one thing GSPMD cannot partition is a Pallas (Mosaic) kernel: the
@@ -102,14 +102,16 @@ def param_specs(tie_word_embeddings: bool, num_experts: int = 0, pp: bool = Fals
 
 
 def kv_cache_spec(num_kv_heads: int = 0, tp_size: int = 1, pp: bool = False) -> P:
-    """[L, N, BS, KVH, HD] — shard kv heads over tp when divisible; when
-    tp > kv_heads (e.g. 70B kv_heads=8 on tp=16) the cache replicates and the
-    duplicated-KV-head handling lives in the attention partitioning. With
-    ``pp=True`` the layer axis shards over pp alongside the layer stack."""
+    """[L, N, BS, KVH*HD] (a QuantKv's scales: [L, N, BS, KVH]) — shard the
+    merged head axis over tp when the kv heads divide: a contiguous 1/tp
+    slice of it is KVH/tp whole heads. When tp > kv_heads (e.g. 70B
+    kv_heads=8 on tp=16) the cache replicates and the duplicated-KV-head
+    handling lives in the attention partitioning. With ``pp=True`` the layer
+    axis shards over pp alongside the layer stack."""
     lax_ = "pp" if pp else None
     if tp_size > 1 and num_kv_heads % tp_size == 0:
-        return P(lax_, None, None, "tp", None)
-    return P(lax_, None, None, None, None)
+        return P(lax_, None, None, "tp")
+    return P(lax_, None, None, None)
 
 
 def shard_params(params, mesh: Mesh, tie_word_embeddings: bool, num_experts: int = 0, pp: bool = False):
@@ -206,7 +208,7 @@ def kernel_shards(num_kv_heads: int) -> int:
 
 # Specs of the attention kernels' operands under ``over_tp``.
 HEADS = P(None, "tp", None)  # [rows, heads, HD] — q/k/v rows, outputs, (m, l)
-PAGES = P(None, None, "tp", None)  # [pages, BS, KVH, HD] — both members of a QuantKv
+PAGES = P(None, None, "tp")  # [pages, BS, KVH*HD] — and a QuantKv's scales [pages, BS, KVH]
 
 
 def over_tp(kernel, num_kv_heads: int, in_specs, out_specs, **static):

@@ -5,6 +5,11 @@ kernels) + block/transfer/cuda.rs. On TPU the same job is a jitted XLA
 gather/scatter (XLA emits the optimal DMA) + ``jax.device_get/put`` across
 PCIe. Jitted once per cache shape; block id is a traced scalar so every block
 reuses the same executable.
+
+A block in transit keeps its heads apart — ``[L, BS, KVH, HD]`` (stacks:
+``[L, n, BS, KVH, HD]``) — whatever the pool's layout: the pool merges them
+into lanes (``KvCacheArrays``), and every function here reshapes the block
+it moves, at the boundary, never the pool.
 """
 
 from __future__ import annotations
@@ -16,23 +21,19 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from dynamo_tpu.engine.kv_cache import KvCacheArrays, QuantKv, quantize_kv_rows
-
-
-@jax.jit
-def _gather(k_cache: jax.Array, v_cache: jax.Array, block_id: jax.Array) -> Tuple[jax.Array, jax.Array]:
-    """[L, N, BS, KVH, HD] → block [L, BS, KVH, HD]."""
-    return k_cache[:, block_id], v_cache[:, block_id]
-
-
-@partial(jax.jit, donate_argnums=(0, 1))
-def _scatter(k_cache: jax.Array, v_cache: jax.Array, block_id: jax.Array, k: jax.Array, v: jax.Array):
-    return k_cache.at[:, block_id].set(k), v_cache.at[:, block_id].set(v)
+from dynamo_tpu.engine.kv_cache import (
+    KvCacheArrays,
+    QuantKv,
+    dequantize_kv,
+    merge_heads,
+    quantize_kv_rows,
+    split_heads,
+)
 
 
 def _has_v(cache: KvCacheArrays) -> bool:
     # MLA caches carry everything in the latent ``k`` array; ``v`` is a
-    # [L,1,1,1,1] placeholder that must not be block-indexed.
+    # [L,1,1,1] placeholder that must not be block-indexed.
     return cache.v.shape[1:] == cache.k.shape[1:]
 
 
@@ -45,27 +46,31 @@ def _has_v(cache: KvCacheArrays) -> bool:
 # int8 code step.
 
 
-@jax.jit
-def _gather_one_quant(qkv: QuantKv, block_id: jax.Array) -> jax.Array:
-    return (qkv.q[:, block_id].astype(jnp.float32) * qkv.scale[:, block_id]).astype(jnp.float32)
+@partial(jax.jit, static_argnames=("kv_heads", "dtype"))
+def _gather(pool, ids: jax.Array, *, kv_heads: int, dtype=None) -> jax.Array:
+    """Pool [L, N, BS, KVH*HD] × one id or ``[n]`` ids → the block
+    ``[L, BS, KVH, HD]`` or the stack ``[L, n, BS, KVH, HD]``; a quantized
+    pool dequantizes to ``dtype``."""
+    if isinstance(pool, QuantKv):
+        return dequantize_kv(QuantKv(pool.q[:, ids], pool.scale[:, ids]), dtype)
+    return split_heads(pool[:, ids], kv_heads)
 
 
 @partial(jax.jit, donate_argnums=(0,))
-def _scatter_one_quant(qkv: QuantKv, block_id: jax.Array, rows: jax.Array) -> QuantKv:
-    qk = quantize_kv_rows(rows)
-    return QuantKv(qkv.q.at[:, block_id].set(qk.q), qkv.scale.at[:, block_id].set(qk.scale))
+def _scatter(pool, ids: jax.Array, blocks: jax.Array):
+    """The inverse of ``_gather``: blocks with their heads apart go back
+    into the pool's merged lanes (requantized for a quantized pool)."""
+    if isinstance(pool, QuantKv):
+        qk = quantize_kv_rows(blocks)
+        return QuantKv(pool.q.at[:, ids].set(qk.q), pool.scale.at[:, ids].set(qk.scale))
+    return pool.at[:, ids].set(merge_heads(blocks))
 
 
 def gather_blocks(cache: KvCacheArrays, block_id: int) -> Tuple[np.ndarray, np.ndarray]:
     """Device block → host numpy (device_get performs the DMA)."""
-    if isinstance(cache.k, QuantKv):
-        k_dev = _gather_one_quant(cache.k, jnp.int32(block_id))
-        v_dev = _gather_one_quant(cache.v, jnp.int32(block_id))
-        return np.asarray(jax.device_get(k_dev)), np.asarray(jax.device_get(v_dev))
-    if not _has_v(cache):
-        k_dev = _gather_k(cache.k, jnp.int32(block_id))
+    k_dev, v_dev = gather_blocks_async(cache, block_id)
+    if v_dev is None:
         return np.asarray(jax.device_get(k_dev)), np.zeros((0,), dtype=cache.k.dtype)
-    k_dev, v_dev = _gather(cache.k, cache.v, jnp.int32(block_id))
     return np.asarray(jax.device_get(k_dev)), np.asarray(jax.device_get(v_dev))
 
 
@@ -75,64 +80,26 @@ def gather_blocks_async(cache: KvCacheArrays, block_id: int):
     stream), so the returned device arrays are a consistent copy even
     though the caller reuses the block immediately; the host transfer
     happens when the offload queue drains (KvbmManager.flush_pending)."""
-    if isinstance(cache.k, QuantKv):
-        return _gather_one_quant(cache.k, jnp.int32(block_id)), _gather_one_quant(
-            cache.v, jnp.int32(block_id)
-        )
-    if not _has_v(cache):
-        return _gather_k(cache.k, jnp.int32(block_id)), None
-    return _gather(cache.k, cache.v, jnp.int32(block_id))
+    bid = jnp.int32(block_id)
+    k = _gather(cache.k, bid, kv_heads=cache.kv_heads, dtype=jnp.float32)
+    v = _gather(cache.v, bid, kv_heads=cache.kv_heads, dtype=jnp.float32) if _has_v(cache) else None
+    return k, v
 
 
 def scatter_blocks(cache: KvCacheArrays, block_id: int, k: np.ndarray, v: np.ndarray) -> None:
     """Host numpy → device block (in-place on the cache handle)."""
-    if isinstance(cache.k, QuantKv):
-        cache.k = _scatter_one_quant(cache.k, jnp.int32(block_id), jnp.asarray(k, dtype=jnp.float32))
-        cache.v = _scatter_one_quant(cache.v, jnp.int32(block_id), jnp.asarray(v, dtype=jnp.float32))
-        return
-    if not _has_v(cache):
-        cache.k = _scatter_k(cache.k, jnp.int32(block_id), jnp.asarray(k))
-        return
-    cache.k, cache.v = _scatter(cache.k, cache.v, jnp.int32(block_id), jnp.asarray(k), jnp.asarray(v))
-
-
-@jax.jit
-def _gather_k(k_cache: jax.Array, block_id: jax.Array) -> jax.Array:
-    return k_cache[:, block_id]
-
-
-@partial(jax.jit, donate_argnums=(0,))
-def _scatter_k(k_cache: jax.Array, block_id: jax.Array, k: jax.Array) -> jax.Array:
-    return k_cache.at[:, block_id].set(k)
+    dtype = jnp.float32 if isinstance(cache.k, QuantKv) else None  # a quantized pool requantizes real values
+    bid = jnp.int32(block_id)
+    cache.k = _scatter(cache.k, bid, jnp.asarray(k, dtype=dtype))
+    if _has_v(cache):
+        cache.v = _scatter(cache.v, bid, jnp.asarray(v, dtype=dtype))
 
 
 # ---------------------------------------------------------------------------
 # Device-native block movement (the NIXL data-plane role): blocks never
-# leave the accelerator. Stacked layout [L, n, BS, KVH, HD] matches the
-# cache's own, so gather/scatter are single XLA ops (one fused DMA each).
+# leave the accelerator. A stack is [L, n, BS, KVH, HD]: the same gather and
+# scatter as one block, over ``[n]`` ids (one fused DMA each).
 # ---------------------------------------------------------------------------
-
-
-@jax.jit
-def _gather_many(cache: jax.Array, block_ids: jax.Array) -> jax.Array:
-    """[L, N, BS, ...] × [n] → [L, n, BS, ...]."""
-    return cache[:, block_ids]
-
-
-@partial(jax.jit, donate_argnums=(0,))
-def _scatter_many(cache: jax.Array, block_ids: jax.Array, blocks: jax.Array) -> jax.Array:
-    return cache.at[:, block_ids].set(blocks)
-
-
-@jax.jit
-def _gather_many_quant(qkv: QuantKv, block_ids: jax.Array) -> jax.Array:
-    return (qkv.q[:, block_ids].astype(jnp.float32) * qkv.scale[:, block_ids]).astype(jnp.bfloat16)
-
-
-@partial(jax.jit, donate_argnums=(0,))
-def _scatter_many_quant(qkv: QuantKv, block_ids: jax.Array, blocks: jax.Array) -> QuantKv:
-    qk = quantize_kv_rows(blocks)
-    return QuantKv(qkv.q.at[:, block_ids].set(qk.q), qkv.scale.at[:, block_ids].set(qk.scale))
 
 
 def gather_blocks_device(cache: KvCacheArrays, block_ids) -> Tuple[jax.Array, Optional[jax.Array]]:
@@ -140,29 +107,27 @@ def gather_blocks_device(cache: KvCacheArrays, block_ids) -> Tuple[jax.Array, Op
     is independent of the cache, so the source blocks may be released
     immediately while the stack awaits a remote pull."""
     bids = jnp.asarray(list(block_ids), dtype=jnp.int32)
-    if isinstance(cache.k, QuantKv):
-        return _gather_many_quant(cache.k, bids), _gather_many_quant(cache.v, bids)
-    k = _gather_many(cache.k, bids)
-    v = _gather_many(cache.v, bids) if _has_v(cache) else None
+    k = _gather(cache.k, bids, kv_heads=cache.kv_heads, dtype=jnp.bfloat16)
+    v = _gather(cache.v, bids, kv_heads=cache.kv_heads, dtype=jnp.bfloat16) if _has_v(cache) else None
     return k, v
 
 
 def scatter_blocks_device(cache: KvCacheArrays, block_ids, k_stack: jax.Array, v_stack) -> None:
     """Write stacked device blocks into the cache (in-place on the handle)."""
     bids = jnp.asarray(list(block_ids), dtype=jnp.int32)
-    if isinstance(cache.k, QuantKv):
-        cache.k = _scatter_many_quant(cache.k, bids, k_stack)
-        if v_stack is not None:
-            cache.v = _scatter_many_quant(cache.v, bids, v_stack)
-        return
-    cache.k = _scatter_many(cache.k, bids, k_stack)
+    cache.k = _scatter(cache.k, bids, k_stack)
     if v_stack is not None and _has_v(cache):
-        cache.v = _scatter_many(cache.v, bids, v_stack)
+        cache.v = _scatter(cache.v, bids, v_stack)
 
 
 @jax.jit
 def _copy_between(src_k, src_v, dst_k, dst_v, src_ids, dst_ids):
     return dst_k.at[:, dst_ids].set(src_k[:, src_ids]), dst_v.at[:, dst_ids].set(src_v[:, src_ids])
+
+
+@jax.jit
+def _copy_one(src_k, dst_k, src_ids, dst_ids):
+    return dst_k.at[:, dst_ids].set(src_k[:, src_ids])
 
 
 def copy_blocks_between(src: KvCacheArrays, src_ids, dst: KvCacheArrays, dst_ids) -> None:
@@ -185,4 +150,4 @@ def copy_blocks_between(src: KvCacheArrays, src_ids, dst: KvCacheArrays, dst_ids
     if _has_v(src):
         dst.k, dst.v = _copy_between(src.k, src.v, dst.k, dst.v, s, d)
     else:
-        dst.k = _scatter_many(dst.k, d, _gather_many(src.k, s))
+        dst.k = _copy_one(src.k, dst.k, s, d)

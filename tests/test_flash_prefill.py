@@ -118,3 +118,37 @@ def test_scheduler_flash_prefill_e2e():
         return seq.output_ids
 
     assert run("flash") == run("xla")
+
+
+@pytest.mark.parametrize("attention_impl,static", [("megakernel", False), ("gather", True)])
+def test_has_prefix_keys_an_executable_only_where_it_changes_the_program(attention_impl, static):
+    """``has_prefix`` is a static argument of the prefill and mixed-step
+    programs on the flash path's own chunk attention; under the megakernel
+    the programs never read it, so a chunk with a cached prefix reuses the
+    executable the fresh chunk traced: nothing is built mid-traffic."""
+    from dynamo_tpu.engine.sampling import SamplingParams
+    from dynamo_tpu.engine.scheduler import Scheduler, SchedulerConfig, StopConditions
+
+    c = get_config("tiny").replace(attention_impl=attention_impl, prefill_impl="flash")
+    params = llama.init_params(c, jax.random.PRNGKey(0), dtype=jnp.float32)
+    sched = Scheduler(c, params, SchedulerConfig(
+        num_blocks=64, max_running=4, prefill_buckets=[32], decode_buckets=[4],
+        max_prefill_chunk=32, mixed_prefill_budget=32, num_scheduler_steps=1,
+        enable_prefix_caching=False, enable_overlap_decode=False,
+    ), dtype=jnp.float32)
+    assert sched._use_flash_prefill and sched._hp_static is static
+    stop = StopConditions(max_tokens=6, ignore_eos=True)
+    sched.add_request("a", [int(t) for t in _tokens(20, 256, seed=3)], SamplingParams(temperature=0.0), stop)
+    sched.step()  # "a" prefills alone and decodes from here on
+    # Two chunks: the second has a cached prefix, and both ride mixed steps beside "a".
+    sched.add_request("b", [int(t) for t in _tokens(50, 256, seed=4)], SamplingParams(temperature=0.0), stop)
+    for _ in range(40):
+        if not sched.has_work():
+            break
+        sched.step()
+    assert sched.mixed_steps_total >= 2
+    assert sched._mixed_jits
+    for fn in sched._mixed_jits.values():
+        assert fn._cache_size() == (2 if static else 1)
+    mixed_keys = [k for k in sched.flight._exec_keys if k[0] == "mixed"]
+    assert {len(k) for k in mixed_keys} == {6 if static else 5}  # ("mixed", s, p_w, b, w[, has_prefix])

@@ -167,8 +167,9 @@ def test_dead_queries_return_zeros():
     rng = np.random.default_rng(4)
     q = jnp.asarray(rng.standard_normal((3, H, hd)).astype(np.float32))
     ke = jnp.asarray(rng.standard_normal((3, kvh, hd)).astype(np.float32))
-    k_pages = jnp.asarray(rng.standard_normal((6, bs, kvh, hd)).astype(np.float32))
-    v_pages = jnp.asarray(rng.standard_normal((6, bs, kvh, hd)).astype(np.float32))
+    # Pages in the pool's layout: heads merged into lanes (KvCacheArrays).
+    k_pages = jnp.asarray(rng.standard_normal((6, bs, kvh * hd)).astype(np.float32))
+    v_pages = jnp.asarray(rng.standard_normal((6, bs, kvh * hd)).astype(np.float32))
     tables = jnp.asarray(np.array([[1, 2], [3, 4], [0, 0]], np.int32))
     meta = mk.build_meta(
         jnp.asarray(np.array([0, 1, 2], np.int32)),
@@ -551,7 +552,7 @@ def test_scheduler_guided_fused_parity():
 
 
 def _cache_rows(cache, tables, upto):
-    """Gather per-position KV rows [B, upto, KVH, HD] (layer-stacked) from a
+    """Gather per-position KV rows [B, upto, KVH*HD] (layer-stacked) from a
     paged cache given each row's block table and confirmed length."""
     L, N, BS = cache.shape[0], cache.shape[1], cache.shape[2]
     out = []
@@ -560,7 +561,7 @@ def _cache_rows(cache, tables, upto):
         for p in range(upto[b]):
             blk = int(tables[b, p // BS])
             rows.append(np.asarray(cache[:, blk, p % BS]))
-        out.append(np.stack(rows, axis=1))  # [L, upto, KVH, HD]
+        out.append(np.stack(rows, axis=1))  # [L, upto, KVH*HD]
     return out
 
 

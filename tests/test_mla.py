@@ -24,9 +24,11 @@ def test_dispatch():
 
 def test_latent_cache_shape():
     cache = KvCacheArrays.create(CFG, num_blocks=8, dtype=jnp.float32)
-    # One latent row per token: kv_lora_rank + rope dim, single "head".
-    assert cache.k.shape == (2, 8, 16, 1, 40)
-    assert cache.v.shape == (2, 1, 1, 1, 1)
+    # One latent row per token: kv_lora_rank + rope dim, a single "head" in
+    # the pool's merged-lane layout.
+    assert cache.k.shape == (2, 8, 16, 40)
+    assert cache.kv_heads == 1
+    assert cache.v.shape == (2, 1, 1, 1)
 
 
 def test_decode_matches_prefill_logits():

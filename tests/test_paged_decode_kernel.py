@@ -30,7 +30,7 @@ def test_kernel_matches_gather_partials():
     H = KVH * G
     NP_, W = 40, 6
     key = jax.random.PRNGKey(0)
-    kp = jax.random.normal(key, (NP_, BS, KVH, HD), jnp.float32)
+    kp = jax.random.normal(key, (NP_, BS, KVH * HD), jnp.float32)  # pages as the pool stores them
     vp = kp * 0.5 + 1
     q = jax.random.normal(jax.random.PRNGKey(1), (B, H, HD), jnp.float32)
     tables = jnp.array(
@@ -57,7 +57,7 @@ def test_empty_piece_drops_out_of_merge():
     B, BS, KVH, HD, G = 2, 16, 2, 32, 2
     H = KVH * G
     key = jax.random.PRNGKey(2)
-    kp = jax.random.normal(key, (8, BS, KVH, HD), jnp.float32)
+    kp = jax.random.normal(key, (8, BS, KVH * HD), jnp.float32)
     vp = kp + 1
     q = jax.random.normal(jax.random.PRNGKey(3), (B, H, HD), jnp.float32)
     tables = jnp.zeros((B, 4), jnp.int32)

@@ -75,8 +75,8 @@ def _sds(shape, dtype, sharding):
 
 def _pages(sh, quant=False, n=16 * NUM_BLOCKS):
     if quant:
-        return QuantKv(_sds((n, BS, KVH, HD), jnp.int8, sh), _sds((n, BS, KVH, 1), jnp.float32, sh))
-    return _sds((n, BS, KVH, HD), BF16, sh)
+        return QuantKv(_sds((n, BS, KVH * HD), jnp.int8, sh), _sds((n, BS, KVH), jnp.float32, sh))
+    return _sds((n, BS, KVH * HD), BF16, sh)
 
 
 def _model_args(param_sh, cache_sh):
@@ -84,7 +84,7 @@ def _model_args(param_sh, cache_sh):
     spec to a sharding, the cache takes ``cache_sh``."""
     shapes = jax.eval_shape(lambda: llama.init_params(CFG, jax.random.PRNGKey(0), dtype=BF16))
     params = jax.tree.map(lambda s, sh: _sds(s.shape, s.dtype, sh), shapes, param_sh(shapes))
-    cache = _sds((CFG.num_layers, NUM_BLOCKS, BS, KVH, HD), BF16, cache_sh)
+    cache = _sds((CFG.num_layers, NUM_BLOCKS, BS, KVH * HD), BF16, cache_sh)
     return params, cache, cache
 
 

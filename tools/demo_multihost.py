@@ -75,7 +75,7 @@ def _worker() -> None:
         B, blocks, width = 4, 16, 8
         kv_sh = NamedSharding(mesh, kv_cache_spec(cfg.num_kv_heads, par.tp))
         bt_sh = NamedSharding(mesh, P("dp"))
-        shape = (cfg.num_layers, blocks, cfg.block_size, cfg.num_kv_heads, cfg.head_dim)
+        shape = (cfg.num_layers, blocks, cfg.block_size, cfg.num_kv_heads * cfg.head_dim)
         k0, v0, toks, pos, tables, active = jax.jit(
             lambda: (
                 jnp.zeros(shape, jnp.float32),
