@@ -1,11 +1,13 @@
 """The reductions that metric files name.
 
 A metric is one file ``metrics/<name>.json``: ``{"name", "unit", "better",
-"source", "layer", "moves", "workloads", "reader", "args"}``. ``reader`` names
-a function here (or, for a metric that needs new code, a file
-``metrics/<name>.py`` with ``read(run, **args)`` beside the JSON). Each takes
-the finished run and returns a number, or ``None`` where it finds nothing to
-read; the runner then leaves the metric out of the line.
+"source", "layer", "moves", "reader", "args"}``. Which cells report it is the
+manifest's to say (``workloads`` of the entry of the same name in
+``BENCHMARK.json``), never the file's. ``reader`` names a function here (or,
+for a metric that needs new code, a file ``metrics/<name>.py`` with
+``read(run, **args)`` beside the JSON). Each takes the finished run and
+returns a number, or ``None`` where it finds nothing to read; the runner then
+leaves the metric out of the line.
 """
 
 from __future__ import annotations
@@ -188,8 +190,8 @@ def decode_step_ms(run):
 def step_roofline_pct(run):
     """Least time the chip could take for the decode steps of the traced
     window (the larger of bytes over peak bandwidth and FLOPs over peak
-    compute, per step, contexts growing by one token a step) over their
-    device time."""
+    compute, per step, contexts growing by one token a step; what a step
+    needs is counted by the run's family) over their device time."""
     steps = _decode_steps(run)
     if not steps:
         return None
@@ -197,7 +199,7 @@ def step_roofline_pct(run):
     for s in steps:
         w = s["n_steps"]
         ctx_mid = s["ctx"] + s["rows"] * (w - 1) / 2.0
-        cost = roofline.decode_step_cost(run.cfg, run.weight_dtype, s["rows"], ctx_mid)
+        cost = run.family.decode_step_cost(run.cfg, run.weight_dtype, s["rows"], ctx_mid)
         least += w * roofline.min_seconds(cost, run.device["kind"])["seconds"]
     return 100.0 * least / sum(s["device_s"] for s in steps)
 

@@ -6,8 +6,10 @@
 A cell is an entry of ``BENCHMARK.json``'s ``workloads``: a configuration
 (``benchmark/configs/<config>.json``) under a traffic mix
 (``benchmark/traffic/<mix>.json``). Its metrics are files under
-``benchmark/metrics/``. Nothing in this file names a cell, a configuration, a
-mix or a metric.
+``benchmark/metrics/``; which cells report a metric is the manifest's to say.
+The configuration names its family, and everything that depends on the
+architecture comes from ``benchmark/families/<family>.py``. Nothing in this
+file names a cell, a configuration, a mix, a metric or a model.
 
 One process holds the chip: it makes the weights from ``--seed`` on the
 device, checks the program's logits against the plain reference, builds the
@@ -79,35 +81,6 @@ def resolve_cell(workload: str, rehearse: bool):
     if rehearse:
         cfg, mix = overlay(cfg, cfg.get("rehearsal", {})), overlay(mix, mix.get("rehearsal", {}))
     return manifest, cell, cfg, mix
-
-
-def model_config(cfg: dict, name: str):
-    """The program's ``ModelConfig`` from the configuration file's Hugging
-    Face keys, as run."""
-    from dynamo_tpu.engine.config import ModelConfig
-
-    heads = cfg["num_attention_heads"]
-    eng = cfg["engine"]
-    return ModelConfig(
-        name=name,
-        vocab_size=cfg["vocab_size"],
-        hidden_size=cfg["hidden_size"],
-        num_layers=cfg["num_hidden_layers"],
-        num_heads=heads,
-        num_kv_heads=cfg["num_key_value_heads"],
-        head_dim=cfg.get("head_dim") or cfg["hidden_size"] // heads,
-        intermediate_size=cfg["intermediate_size"],
-        rope_theta=float(cfg["rope_theta"]),
-        rms_norm_eps=float(cfg["rms_norm_eps"]),
-        max_seq_len=int(min(eng.get("max_seq_len", cfg["max_position_embeddings"]), cfg["max_position_embeddings"])),
-        tie_word_embeddings=bool(cfg.get("tie_word_embeddings", False)),
-        dtype=eng.get("dtype", "bfloat16"),
-        num_experts=int(cfg.get("num_local_experts", 0)),
-        num_experts_per_tok=int(cfg.get("num_experts_per_tok", 0)),
-        weight_dtype=eng.get("weight_dtype", "auto"),
-        kv_cache_dtype=eng.get("kv_cache_dtype", "auto"),
-        block_size=int(eng.get("block_size", 16)),
-    )
 
 
 def open_device(chips: int, rehearse: bool) -> dict:
@@ -274,7 +247,7 @@ async def measured_pass(url, model, mix, vocab, seed, seconds, hooks, trace_dir,
     return reqs, t_open, client
 
 
-async def serve(args, cell, cfg, mix, device, meter, params, mc, parts):
+async def serve(args, cell, cfg, mix, device, meter, params, family, mc, parts):
     from dynamo_tpu import run as dynamo_run
     from dynamo_tpu.engine.engine import EngineArgs, TpuEngine
     from dynamo_tpu.engine.scheduler import SchedulerConfig
@@ -318,7 +291,7 @@ async def serve(args, cell, cfg, mix, device, meter, params, mc, parts):
         reqs, t_open, client = await measured_pass(
             url, cell["config"], mix, mc.vocab_size, args.seed, args.seconds, hooks, trace_dir)
         run = Run(client=client, window=(t_open, t_open + args.seconds), setup_s=t_open - T_START,
-                  hooks=hooks, meter=meter, cfg=cfg, mix=mix, weight_dtype=mc.weight_dtype, device=device,
+                  hooks=hooks, meter=meter, cfg=cfg, mix=mix, family=family, weight_dtype=mc.weight_dtype, device=device,
                   prompt_keys={r.rid: spans.prompt_key([0] + r.word_ids) for r in reqs},
                   trace_steps=[], trace_busy=None, trace_rows=None, offered=traffic.offered(reqs))
         return run
@@ -375,20 +348,27 @@ def emit_check(check: dict) -> None:
           "number": "mismatched_windows", "value": 0 if check["sampled_is_argmax"] else 1, "limit": 0})
 
 
-def parity_study(args, cell, cfg, mc) -> int:
+def finish(result: dict, compared: dict) -> None:
+    """The result line, what was compared last on it, and the same as the last lines of standard error."""
+    emit(dict(result, compared={k: {"value": v, "limit": lim} for k, (v, lim) in compared.items()}))
+    for k, (v, lim) in compared.items():
+        print(f"compared {k} {v!r} limit {lim!r}", file=sys.stderr)
+
+
+def parity_study(args, cell, cfg, family, mc) -> int:
     """The study behind the output check's limits: ``--parity-study N`` seeds
     of the program against the reference and, on the first few of them, the
     configuration's controls and the faulty program. One process, no engine."""
-    from benchmark import parity, weights
+    from benchmark import parity
 
     spec = cfg["parity"]
     rows = []
     for i in range(args.parity_study):
         seed = args.seed + i * 7919
         t = time.monotonic()
-        params = weights.make_params(mc, seed)
+        params = family.make_params(mc, seed)
         ctl = i < STUDY_CONTROL_SEEDS
-        r = parity.check(params, mc, seed, spec, controls=spec["controls"] if ctl else (), fault=ctl,
+        r = parity.check(family, params, mc, seed, spec, controls=spec["controls"] if ctl else (), fault=ctl,
                          per_position=True)
         del params
         row = {"phase": "parity_study", "seed": seed, **{k: r.get(k) for k in (
@@ -437,26 +417,27 @@ def main() -> int:
         args.seconds = float(manifest["run_seconds"])
     device = open_device(int(cell["chips"]), args.rehearse)
 
-    from benchmark import parity, readers, spans, weights
+    from benchmark import families, parity, readers, spans
 
     meter = spans.CompileMeter()
-    mc = model_config(cfg, cell["config"])
+    family = families.load(cfg["family"])
+    mc = family.model_config(cfg, cell["config"])
     if args.parity_study:
-        return parity_study(args, cell, cfg, mc)
+        return parity_study(args, cell, cfg, family, mc)
 
     parts = {}
     t = time.monotonic()
-    params = weights.make_params(mc, args.seed)
+    params = family.make_params(mc, args.seed)
     import jax
 
     jax.block_until_ready(params)
     parts["weights_s"] = time.monotonic() - t
     t = time.monotonic()
-    check = parity.check(params, mc, args.seed, cfg["parity"])
+    check = parity.check(family, params, mc, args.seed, cfg["parity"])
     parts["parity_s"] = time.monotonic() - t
     emit_check(check)
 
-    run = asyncio.run(serve(args, cell, cfg, mix, device, meter, params, mc, parts))
+    run = asyncio.run(serve(args, cell, cfg, mix, device, meter, params, family, mc, parts))
     if run is None:
         return 0
     run.cell = cell["name"]
@@ -467,28 +448,33 @@ def main() -> int:
               "usage": r["usage"], "tokens_received": sum(f[1] for f in r["frames"]), "max_tokens": r["max_tokens"]})
     emit({"phase": "correct", "compared": "requests due in the window: SSE framing, finish_reason, token counts",
           "number": "failed", "value": len(failed), "limit": 0})
+    # Each number compared beside its limit: last on the result line, and as the last lines of standard error.
+    compared = {"rel_err": [check["rel_err"], check["limit_rel_err"]],
+                "group_rel_err": [check["group_rel_err"], check["limit_group_rel_err"]],
+                "mismatched_windows": [0 if check["sampled_is_argmax"] else 1, 0], "failed": [len(failed), 0]}
     if args.trace:
         t = time.monotonic()
         reduce_trace(run, os.path.join(STATE, "trace"))
         parts["trace_reduce_s"] = time.monotonic() - t
 
     kind = "per_layer" if args.trace else "end_to_end"
-    wanted = [m for m in manifest[kind] if cell["name"] in m.get("workloads", [cell["name"]])]
+    reports = lambda m: cell["name"] in m.get("workloads", [cell["name"]])  # noqa: E731 - no key: every cell
+    wanted = [m for m in manifest[kind] if reports(m)]
     metrics = {}
     for m in wanted:
         value = readers.read_metric(m["name"], run)
         if value is not None:
             metrics[m["name"]] = {"value": value, "unit": m["unit"]}
-    # Beside the cell's own metrics: every metric file of this cell that needs no trace, as candidates
-    # (the driver ignores other keys; the spread study reads them).
+    # Beside the cell's own metrics: every other metric the manifest gives this cell that needs no trace, as
+    # candidates (the driver ignores other keys; the spread study reads them). A metric file without a manifest
+    # entry is a candidate of no cell.
     extra = {}
     if not args.trace:
-        for f in sorted(os.listdir(os.path.join(HERE, "metrics"))):
-            spec = load_json(HERE, "metrics", f) if f.endswith(".json") else None
-            if spec and cell["name"] in spec.get("workloads", [cell["name"]]) and spec["source"] != "device_trace" and spec["name"] not in metrics:
-                value = readers.read_metric(spec["name"], run)
+        for m in sorted(manifest["end_to_end"] + manifest["per_layer"], key=lambda m: m["name"]):
+            if reports(m) and m["source"] != "device_trace" and m["name"] not in metrics:
+                value = readers.read_metric(m["name"], run)
                 if value is not None:
-                    extra[spec["name"]] = value
+                    extra[m["name"]] = value
     in_win = meter.in_window(*run.window)
     hooks_keys = run.hooks.engine.scheduler.flight.post_warmup_keys
     emit({"phase": "setup", "setup_s": run.setup_s, **parts, "ramp_s": mix.get("ramp_s", 0.0), **meter.snapshot(),
@@ -501,7 +487,7 @@ def main() -> int:
         result.update(metrics={}, device=dev, rehearsal=True, metric_names=sorted(metrics),
                       counts={"compiles_in_window": in_win["builds"], "trace_steps": len(run.trace_steps),
                               "tokens_received": sum(f[1] for r in run.client["requests"] for f in r["frames"])})
-        emit(result)
+        finish(result, compared)
         return 0
     if args.trace:
         from benchmark import trace as tr
@@ -513,7 +499,7 @@ def main() -> int:
     result.update(metrics=metrics, device=dev)
     if extra:
         result["candidates"] = extra
-    emit(result)
+    finish(result, compared)
     return 0
 
 

@@ -52,7 +52,7 @@ def write_tokenizer(vocab_size: int, directory: str) -> str:
     fixed path inside the checkout) and return the file's path."""
     os.makedirs(directory, exist_ok=True)
     path = os.path.join(directory, "tokenizer.json")
-    tmp = path + ".tmp"
+    tmp = f"{path}.{os.getpid()}.tmp"  # two runs in one checkout write the same bytes, each through a file of its own
     with open(tmp, "w") as f:
         json.dump(tokenizer_json(vocab_size), f)
     os.replace(tmp, path)

@@ -12,7 +12,7 @@ ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
-from benchmark import readers, traffic  # noqa: E402
+from benchmark import families, readers, traffic  # noqa: E402
 
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 WIDTH = re.compile(r"(hidden|intermediate|latent|state|proj).*size|_dim$|_rank$|head_dim|expand|experts_per_tok")
@@ -46,10 +46,10 @@ def test_configuration_file_is_the_source_as_run(cfg):
     assert not any(WIDTH.search(k) for k in cfg["reduced"])  # no width is ever cut
     assert body["engine"]["continuous_profiling"] is False
     assert body["assumed"] and set(body["reduced_why"]) == set(cfg["reduced"])
-    from benchmark import reference
-
+    family = families.load(body["family"])  # the configuration names its family; the family names its controls
+    assert all(hasattr(family, name) for name in families.SEAM)
     controls = body["parity"]["controls"]
-    assert controls and set(controls) <= set(reference.WEIGHT_CONTROLS + reference.ACT_CONTROLS)
+    assert controls and set(controls) <= set(family.CONTROLS)
     assert 0 < body["parity"]["limit_rel_err"] <= body["parity"]["limit_group_rel_err"] < 0.5 and body["parity"]["chunk"] == body["scheduler"]["prefill_buckets"][-1]
     assert body["parity"]["window"] == body["scheduler"]["num_scheduler_steps"]  # the check runs the served shapes
     assert body["parity"]["decode_bucket"] in body["scheduler"]["decode_buckets"]
@@ -71,8 +71,9 @@ def test_cell_names_files_that_exist_and_reports_enough(cell):
 @pytest.mark.parametrize("metric", METRICS, ids=lambda m: m["name"])
 def test_metric_has_a_file_with_a_reader(metric):
     spec = readers.load_metric(metric["name"])
-    for key in ("name", "unit", "better", "source", "workloads"):
-        assert spec.get(key) == metric.get(key), key  # no ``workloads``: every cell, those of later PRs too
+    for key in ("name", "unit", "better", "source"):
+        assert spec.get(key) == metric.get(key), key
+    assert "workloads" not in spec  # which cells report a metric is the manifest's to say: a new cell edits no metric file
     own = os.path.join(ROOT, "benchmark", "metrics", metric["name"] + ".py")
     assert spec["reader"] in readers.READERS or os.path.exists(own)
     assert NAME.match(metric["name"]) and re.match(r"^[A-Za-z0-9_/%.\-]{1,16}$", metric["unit"])

@@ -6,7 +6,6 @@ program's `dyn:` spans and named step programs in it
 
 import json
 import os
-import subprocess
 import sys
 import types
 
@@ -175,19 +174,15 @@ def test_recorded_trace_reduces_to_numbers_that_agree_with_the_outside_in_ones(r
     assert fe is not None and 0 < fe < 100
 
 
-def test_rehearsal_lists_the_new_metrics_that_need_no_device():
+def test_rehearsal_lists_the_new_metrics_that_need_no_device(rehearsed):
     """A CPU trace has no device plane (no "XLA Ops", no "XLA Modules"), so a
     rehearsal lists the metrics read from the step log and from the host
     spans; the idle split, the program times and programs per dispatch are
-    checked on the recorded chip trace above, as `decode_step_ms` is."""
-    env = dict(os.environ, JAX_PLATFORMS="cpu", DYN_LOG="ERROR")
-    env.pop("XLA_FLAGS", None)
-    p = subprocess.run(
-        [sys.executable, os.path.join(ROOT, "benchmark", "run.py"), "--workload", "mistral-7b-w8.chat",
-         "--seed", str(2**31 + 29), "--seconds", "4", "--trace", "1", "--rehearse"],
-        cwd=ROOT, env=env, capture_output=True, text=True, timeout=600)
-    assert p.returncode == 0, p.stderr[-3000:]
-    last = json.loads(p.stdout.splitlines()[-1])
+    checked on the recorded chip trace above, as `decode_step_ms` is. The
+    rehearsal is the session's one (`conftest.py::rehearsed`): this test used
+    to launch a second, which collided with the first in `.bench_state/`."""
+    assert rehearsed["returncode"] == 0, rehearsed["stderr"][-3000:]
+    last = json.loads(rehearsed["stdout"].splitlines()[-1])
     assert last["correct"] is True and last["metrics"] == {} and last["rehearsal"] is True
     assert {"staged_wait_p50_ms.chat", "sched_host_ms.chat", "frontend_busy_pct.chat"} <= set(last["metric_names"])
     device_only = {"idle_pre_launch_pct.chat", "idle_post_sync_pct.chat", "idle_loop_pct.chat", "decode_program_ms.chat",

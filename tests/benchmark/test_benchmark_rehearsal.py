@@ -26,10 +26,9 @@ def test_without_a_chip_the_runner_fails_and_prints_no_result():
         assert "metrics" not in json.loads(line)
 
 
-def test_rehearsal_runs_the_cell_end_to_end_with_the_tracer_on():
-    p = run_cell("--trace", "1", "--rehearse")
-    assert p.returncode == 0, p.stderr[-3000:]
-    lines = [json.loads(line) for line in p.stdout.splitlines()]  # every line is one JSON object
+def test_rehearsal_runs_the_cell_end_to_end_with_the_tracer_on(rehearsed):
+    assert rehearsed["returncode"] == 0, rehearsed["stderr"][-3000:]
+    lines = [json.loads(line) for line in rehearsed["stdout"].splitlines()]  # every line is one JSON object
     last = lines[-1]
     assert {"correct", "attempted", "failed", "metrics", "device"} <= set(last)
     assert last["correct"] is True and last["failed"] == 0 and last["attempted"] == 16
