@@ -120,6 +120,27 @@ class ModelConfig:
     # Embed/lm_head stay in compute dtype (per-step re-dequant of a
     # vocab-size matrix would add ~1 GB/token of traffic at 8B).
     weight_dtype: str = "auto"
+    # Attention kind of the llama-family layer: "causal" (every earlier key,
+    # one cache row a token for ever) or "eva" (EVA chunked linear attention:
+    # exact softmax over the query's own ``window_size`` window, joined in one
+    # softmax with one learned, softmax-pooled (key, value) summary per
+    # ``chunk_size`` chunk of every earlier window). A summary has the shape
+    # of a cached row, so the paged pool holds ``window_size // chunk_size``
+    # summary rows for each completed window in front of the current
+    # window's exact rows: ``kv_cache.cache_rows`` maps a position to its
+    # row, and the scheduler rolls a completed window (``llama.eva_roll``).
+    attention_kind: str = "causal"
+    window_size: int = 0
+    chunk_size: int = 0
+    # RMSNorm multiplies by ``1 + weight`` (weights stored around zero).
+    norm_unit_offset: bool = False
+    # The residual stream is carried and added in float32; matmul inputs
+    # stay in the compute dtype.
+    residual_fp32: bool = False
+    # Prediction heads of ``lm_head`` ([hidden, vocab * num_pred_heads]):
+    # head 0 (columns [0, vocab)) gives the next-token logits, the only ones
+    # served; the others' weights are held, not multiplied.
+    num_pred_heads: int = 1
 
     def __post_init__(self):
         if self.attention_impl not in ("auto", "gather", "paged", "megakernel"):
@@ -146,6 +167,20 @@ class ModelConfig:
                 "weight_dtype='int8' is llama-family only (MLA layer scans "
                 "do not dequantize yet)"
             )
+        if self.attention_kind not in ("causal", "eva"):
+            raise ValueError(f"attention_kind must be causal|eva, got {self.attention_kind!r}")
+        if self.attention_kind == "eva":
+            if self.architecture != "llama":
+                raise ValueError("attention_kind='eva' is served by the llama-family step programs only")
+            if self.chunk_size <= 0 or self.window_size <= 0 or self.window_size % self.chunk_size:
+                raise ValueError(
+                    "attention_kind='eva' needs window_size and chunk_size > 0 with chunk_size dividing "
+                    f"window_size, got {self.window_size} / {self.chunk_size}"
+                )
+            if self.kv_cache_dtype == "int8":
+                raise ValueError("attention_kind='eva' has no int8 KV path (summaries are pooled from real rows)")
+        if self.num_pred_heads < 1 or (self.num_pred_heads > 1 and self.tie_word_embeddings):
+            raise ValueError("num_pred_heads >= 1, and > 1 only with an untied lm_head")
         if self.weight_dtype == "int8" and self.num_experts > 0:
             raise ValueError(
                 "weight_dtype='int8' does not cover MoE expert stacks "
@@ -159,6 +194,15 @@ class ModelConfig:
     @property
     def kv_size(self) -> int:
         return self.num_kv_heads * self.head_dim
+
+    @property
+    def is_eva(self) -> bool:
+        return self.attention_kind == "eva"
+
+    @property
+    def summaries_per_window(self) -> int:
+        """Cache rows a completed window leaves behind (eva)."""
+        return self.window_size // self.chunk_size
 
     def replace(self, **kwargs) -> "ModelConfig":
         return dataclasses.replace(self, **kwargs)
@@ -223,6 +267,28 @@ PRESETS = {
         max_seq_len=131072,
         num_experts=128,
         num_experts_per_tok=4,
+    ),
+    # Tiny EVA config (chunked linear attention over the paged pool) for unit
+    # tests: 8 summary rows a window of 32, MHA, float32 residual, two
+    # prediction heads.
+    "tiny-eva": ModelConfig(
+        name="tiny-eva",
+        vocab_size=320,
+        hidden_size=64,
+        num_layers=2,
+        num_heads=4,
+        num_kv_heads=4,
+        head_dim=16,
+        intermediate_size=128,
+        max_seq_len=256,
+        block_size=8,
+        rope_theta=100000.0,
+        attention_kind="eva",
+        window_size=32,
+        chunk_size=4,
+        norm_unit_offset=True,
+        residual_fp32=True,
+        num_pred_heads=2,
     ),
     # Tiny MLA config (DeepSeek-style latent attention) for unit tests.
     "tiny-mla": ModelConfig(

@@ -213,7 +213,8 @@ class StepCostModel:
         win the gauge must show)."""
         flops = self.flops_per_token * tokens
         bytes_moved = (
-            self.param_bytes * max(param_passes, 1.0)
+            # (0 passes: a program that reads no weights, as a roll of KV rows)
+            self.param_bytes * (max(param_passes, 1.0) if param_passes else 0.0)
             + kv_read_tokens * self.kv_bytes_per_token * self.kv_read_factor
             + tokens * self.kv_bytes_per_token  # written KV rows
         )

@@ -86,9 +86,30 @@ def layer_flat(cache):
     return jax.tree.map(lambda a: a.reshape(a.shape[0] * a.shape[1], *a.shape[2:]), cache)
 
 
+def cache_rows(config: ModelConfig, positions):
+    """Row of its sequence's block table that holds position ``t``: THE place
+    that relates a token's position to its cache row. Works alike on Python
+    ints (the scheduler's block accounting) and on traced arrays (the step
+    programs' scatter targets and prefix lengths).
+
+    A causal model keeps one row a token for ever: the row *is* the position.
+    An eva model keeps ``M = window_size // chunk_size`` summary rows for each
+    completed window in front of the current window's exact rows, so
+    ``row(t) = M * (t // W) + t % W``: a query at ``t`` attends exactly the
+    rows below its own (summaries of every earlier window, then the earlier
+    keys of its own window), which is what the prefix length of every
+    attention path means. The table of a sequence of ``n`` tokens is
+    ``cache_rows(config, n - 1) + 1`` rows once its completed windows are
+    rolled (``llama.eva_roll``)."""
+    if not config.is_eva:
+        return positions
+    W = config.window_size
+    return config.summaries_per_window * (positions // W) + positions % W
+
+
 def ragged_scatter_targets(
     block_table: jax.Array,  # [W] block ids for one sequence (0 = scratch)
-    positions: jax.Array,  # [T] absolute write slot per token row
+    positions: jax.Array,  # [T] cache row per token row (``cache_rows``)
     live: jax.Array,  # [T] bool — dead rows (bucket padding) sink to block 0
     block_size: int,
 ):

@@ -30,6 +30,7 @@ from jax.sharding import PartitionSpec as P
 from dynamo_tpu.engine.config import ModelConfig
 from dynamo_tpu.engine.kv_cache import (
     QuantKv,
+    cache_rows,
     layer_flat,
     merge_heads,
     quantize_kv_rows,
@@ -68,6 +69,14 @@ def _hoist_gather_budget() -> int:
     return _HOIST_GATHER_MAX_BYTES
 
 
+# Widest cache row XLA:TPU's gather of whole blocks is trusted with: at 4096
+# lanes it first slices the POOL in halves of its lane axis, a copy of the
+# pool in every layer (3-4.5 GB of temporaries at 177 blocks; compiled for a
+# described v5e, PERF.md §6, PR 28). Past it one sequence's table is read by
+# a dynamic slice a block.
+_GATHER_MAX_LANES = 2048
+
+
 def _gather_kv(flat, idx, dtype):
     """Gather KV rows through a block-table index; int8 caches dequantize on
     the way out (per-token-per-head symmetric scale).
@@ -89,6 +98,8 @@ def _gather_kv(flat, idx, dtype):
         scale = flat.scale[idx]
         q = split_heads(flat.q[idx], scale.shape[-1])
         return q.astype(dtype) * scale[..., None].astype(dtype)
+    if idx.ndim == 1 and flat.shape[-1] > _GATHER_MAX_LANES:
+        return jnp.stack([lax.dynamic_index_in_dim(flat, idx[i], axis=0, keepdims=False) for i in range(idx.shape[0])])
     return flat[idx]
 
 
@@ -149,8 +160,19 @@ def init_params(config: ModelConfig, key: jax.Array, dtype=jnp.bfloat16) -> Para
         "final_norm": jnp.ones((c.hidden_size,), dtype=dtype),
         "layers": layers,
     }
+    if c.norm_unit_offset:
+        # Norm weights are stored around zero and applied as 1 + g.
+        for name in ("attn_norm", "mlp_norm"):
+            layers[name] = jnp.zeros_like(layers[name])
+        params["final_norm"] = jnp.zeros_like(params["final_norm"])
+    if c.is_eva:
+        # Pooling queries of the chunk summaries, one per head and layer
+        # (eva_roll): mu weights the key pooling, phi the value pooling.
+        k_mu, k_phi = jax.random.split(jax.random.fold_in(k_layers, 1))
+        layers["eva_mu"] = dense(k_mu, (L, c.num_kv_heads, c.head_dim), scale=1.0)
+        layers["eva_phi"] = dense(k_phi, (L, c.num_kv_heads, c.head_dim), scale=1.0)
     if not c.tie_word_embeddings:
-        params["lm_head"] = dense(k_head, (c.hidden_size, c.vocab_size), scale=0.02)
+        params["lm_head"] = dense(k_head, (c.hidden_size, c.vocab_size * c.num_pred_heads), scale=0.02)
     return params
 
 
@@ -159,10 +181,42 @@ def init_params(config: ModelConfig, key: jax.Array, dtype=jnp.bfloat16) -> Para
 # ---------------------------------------------------------------------------
 
 
-def rms_norm(x: jax.Array, weight: jax.Array, eps: float) -> jax.Array:
+def rms_norm(x: jax.Array, weight: jax.Array, eps: float, unit_offset: bool = False, out_dtype=None) -> jax.Array:
+    """RMS norm in float32; ``unit_offset`` multiplies by ``1 + weight``;
+    the result is ``out_dtype`` (default: the input's)."""
     xf = x.astype(jnp.float32)
     norm = xf * lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
-    return (norm * weight.astype(jnp.float32)).astype(x.dtype)
+    w = weight.astype(jnp.float32)
+    return (norm * (1.0 + w if unit_offset else w)).astype(x.dtype if out_dtype is None else out_dtype)
+
+
+def _norm(c: ModelConfig, x: jax.Array, weight: jax.Array, wdtype) -> jax.Array:
+    """The configuration's norm of the residual stream, as a matmul's input:
+    in the compute dtype ``wdtype`` even where the stream is float32."""
+    return rms_norm(x, weight, c.rms_norm_eps, c.norm_unit_offset, wdtype)
+
+
+def _embed_rows(c: ModelConfig, params: Params, tokens: jax.Array):
+    """Embedding rows that open the residual stream, and the compute dtype
+    ``wdtype`` of every matmul input after them. The stream itself is float32
+    where the configuration says so (each residual add then promotes)."""
+    h = params["embed"].at[tokens].get(mode="clip")
+    return (h.astype(jnp.float32) if c.residual_fp32 else h), h.dtype
+
+
+def _logits(c: ModelConfig, params: Params, h: jax.Array, wdtype) -> jax.Array:
+    """Final norm and head of the picked rows -> float32 next-token logits.
+    Of a head with several prediction heads only the first ``vocab_size``
+    columns are multiplied; with a float32 residual the product also leaves
+    the MXU in float32."""
+    x = _norm(c, h, params["final_norm"], wdtype)
+    head = params.get("lm_head")
+    head = head if head is not None else params["embed"].T
+    if c.num_pred_heads > 1:
+        head = head[:, : c.vocab_size]
+    if c.residual_fp32:
+        return jnp.dot(x, head, preferred_element_type=jnp.float32)
+    return (x @ head).astype(jnp.float32)
 
 
 def rope_frequencies(head_dim: int, theta: float) -> jax.Array:
@@ -335,6 +389,13 @@ def _mlp(
         return (out, jnp.int32(0)) if stats else out
     out, dropped = _moe_capacity(x, lp, config, valid)
     return (out, dropped) if stats else out
+
+
+def _refuse_eva(c: ModelConfig, what: str) -> None:
+    """Programs that equate a token's position with its cache row are not
+    built for ``attention_kind='eva'``: refuse, never serve wrong rows."""
+    if c.is_eva:
+        raise NotImplementedError(f"{what} is not built for attention_kind='eva' (model {c.name!r})")
 
 
 def _on_tpu() -> bool:
@@ -554,8 +615,13 @@ def prefill(
     T = tokens.shape[0]
     ctx = block_table.shape[0] * bs
 
-    h = params["embed"].at[tokens].get(mode="clip")  # [T, D]
+    h, wdtype = _embed_rows(c, params, tokens)  # [T, D]
     positions = cache_len + jnp.arange(T, dtype=jnp.int32)
+    # Rope turns by position; the cache is addressed by row (cache_rows). A
+    # chunk never straddles a window boundary (the scheduler cuts it there),
+    # so its rows are consecutive and the rows below its first are its prefix.
+    kv_rows = cache_rows(c, positions)
+    prefix_rows = cache_rows(c, cache_len)
     valid_q = jnp.arange(T, dtype=jnp.int32) < valid_len
     if mm_feats is not None:
         # Multimodal early fusion: positions [0, mm_len) are image-feature
@@ -564,10 +630,10 @@ def prefill(
         # the encode worker hands features to prefill).
         inject = (positions < mm_len) & valid_q
         rows = mm_feats.at[jnp.clip(positions, 0, mm_feats.shape[0] - 1)].get(mode="clip")
-        h = jnp.where(inject[:, None], rows.astype(h.dtype), h)
+        h = jnp.where(inject[:, None], rows.astype(wdtype), h)
 
     # Scatter targets for the new tokens; padded positions sink to block 0.
-    tgt_blocks, tgt_offs = ragged_scatter_targets(block_table, positions, valid_q, bs)
+    tgt_blocks, tgt_offs = ragged_scatter_targets(block_table, kv_rows, valid_q, bs)
 
     # The cache is READ-ONLY inside the layer scan (slices ride the scan xs);
     # each layer's fresh chunk K/V is attended in-register and stacked into
@@ -600,7 +666,7 @@ def prefill(
         t_iq = jnp.arange(T, dtype=jnp.int32)
         mega_meta = build_meta(
             jnp.zeros((T,), jnp.int32),
-            jnp.full((T,), cache_len, jnp.int32),
+            jnp.full((T,), prefix_rows, jnp.int32),
             jnp.zeros((T,), jnp.int32),
             t_iq + 1,
             (t_iq < valid_len).astype(jnp.int32),
@@ -608,8 +674,8 @@ def prefill(
 
     def layer_fn(h, xs):
         lp, l = xs  # l: scalar layer index
-        lp = dequant_layer(lp, h.dtype)  # int8 weight-only storage
-        x = rms_norm(h, lp["attn_norm"], c.rms_norm_eps)
+        lp = dequant_layer(lp, wdtype)  # int8 weight-only storage
+        x = _norm(c, h, lp["attn_norm"], wdtype)
         q = (x @ lp["wq"]).reshape(T, c.num_heads, c.head_dim)
         k = (x @ lp["wk"]).reshape(T, c.num_kv_heads, c.head_dim)
         v = (x @ lp["wv"]).reshape(T, c.num_kv_heads, c.head_dim)
@@ -620,9 +686,9 @@ def prefill(
             attn = _mega_attend_rows(
                 c, q, k, v, k_flat, v_flat,
                 (block_table + l * N)[None, :], mega_meta,
-            ).astype(h.dtype)
+            ).astype(wdtype)
             h = h + attn.reshape(T, c.q_size) @ lp["wo"]
-            x = rms_norm(h, lp["mlp_norm"], c.rms_norm_eps)
+            x = _norm(c, h, lp["mlp_norm"], wdtype)
             if moe_stats:
                 mlp_out, drops = _mlp(x, lp, c, valid=valid_q, stats=True)
                 h = h + mlp_out
@@ -640,16 +706,16 @@ def prefill(
             k_ctx = v_ctx = None
         else:
             table_l = block_table + l * N
-            k_ctx = _gather_kv(k_flat, table_l, h.dtype).reshape(ctx, kvh, c.head_dim)
-            v_ctx = _gather_kv(v_flat, table_l, h.dtype).reshape(ctx, kvh, c.head_dim)
+            k_ctx = _gather_kv(k_flat, table_l, wdtype).reshape(ctx, kvh, c.head_dim)
+            v_ctx = _gather_kv(v_flat, table_l, wdtype).reshape(ctx, kvh, c.head_dim)
         attn = ragged_chunk_attention(
-            q, k, v, k_ctx, v_ctx, valid_len, cache_len,
+            q, k, v, k_ctx, v_ctx, valid_len, prefix_rows,
             num_kv_heads=kvh, use_flash=use_flash, has_prefix=has_prefix,
             interpret=interp,
         )
         h = h + attn.reshape(T, c.q_size) @ lp["wo"]
 
-        x = rms_norm(h, lp["mlp_norm"], c.rms_norm_eps)
+        x = _norm(c, h, lp["mlp_norm"], wdtype)
         if moe_stats:
             mlp_out, drops = _mlp(x, lp, c, valid=valid_q, stats=True)
             h = h + mlp_out
@@ -676,17 +742,10 @@ def prefill(
     k_new = _scatter_kv(k_cache, layer_idx, tgt_blocks[None, :], tgt_offs[None, :], k_rows)
     v_new = _scatter_kv(v_cache, layer_idx, tgt_blocks[None, :], tgt_offs[None, :], v_rows)
 
-    head = params.get("lm_head")
-    if all_logits:
-        h_all = rms_norm(h, params["final_norm"], c.rms_norm_eps)
-        logits = h_all @ (head if head is not None else params["embed"].T)
-    else:
-        last = jnp.maximum(valid_len - 1, 0)
-        h_last = rms_norm(h[last], params["final_norm"], c.rms_norm_eps)
-        logits = h_last @ (head if head is not None else params["embed"].T)
+    logits = _logits(c, params, h if all_logits else h[jnp.maximum(valid_len - 1, 0)], wdtype)
     if moe_stats:
-        return logits.astype(jnp.float32), k_new, v_new, aux
-    return logits.astype(jnp.float32), k_new, v_new
+        return logits, k_new, v_new, aux
+    return logits, k_new, v_new
 
 
 def decode_multi(
@@ -746,7 +805,8 @@ def decode_multi(
 
     # Cached-prefix mask is fixed for the whole window (the cache is not
     # written during it); window rows carry the in-flight tokens.
-    _, _, mask0 = decode_targets(positions, block_tables, active, bs)
+    rows0 = cache_rows(c, positions)  # cache row of each sequence's first step
+    _, _, mask0 = decode_targets(rows0, block_tables, active, bs)
 
     # Hoist the cached-prefix gather out of the window loop: the prefix is
     # read-only for the whole window, so gathering it per step pays the
@@ -775,17 +835,15 @@ def decode_multi(
     def body(i, state):
         toks, k_win, v_win, out, lg_out, key, drops = state
         poss = positions + i
-        h = params["embed"].at[toks].get(mode="clip")  # [B, D]
+        h, _ = _embed_rows(c, params, toks)  # [B, D]
         h, k_rows, v_rows, step_drops = _decode_layer_scan_window(
             params["layers"], c, k_cache, v_cache, h, poss, block_tables,
             mask0, k_win, v_win, i, active, moe_stats=moe_stats,
-            k_ctx_all=k_ctx_all, v_ctx_all=v_ctx_all,
+            k_ctx_all=k_ctx_all, v_ctx_all=v_ctx_all, prefix_rows=rows0, wdtype=wdtype,
         )
         k_win = k_win.at[:, i].set(k_rows)
         v_win = v_win.at[:, i].set(v_rows)
-        h = rms_norm(h, params["final_norm"], c.rms_norm_eps)
-        head = params.get("lm_head")
-        logits = (h @ (head if head is not None else params["embed"].T)).astype(jnp.float32)
+        logits = _logits(c, params, h, wdtype)
         key, sub = jax.random.split(key)
         if uniforms is not None:
             nxt = sample_from_uniforms(
@@ -812,11 +870,18 @@ def decode_multi(
         0, num_steps, body, (tokens, k_win0, v_win0, out0, lg0, rng_key, jnp.int32(0))
     )
 
-    # One fused scatter for the whole window: row (l, j, b) → slot pos_b + j.
+    # One fused scatter for the whole window: row (l, j, b) → cache row
+    # rows0_b + j (a window's rows are consecutive).
     steps_i = jnp.arange(num_steps, dtype=jnp.int32)
-    slots = jnp.where(active[None, :], positions[None, :] + steps_i[:, None], 0)  # [w, B]
+    live = active[None, :]
+    if c.is_eva:
+        # A sequence stops at its window boundary: the steps past it wait
+        # for the roll (the scheduler takes none of their tokens), so their
+        # rows, which would overwrite the completed window, sink to scratch.
+        live = live & ((positions % c.window_size)[None, :] + steps_i[:, None] < c.window_size)
+    slots = jnp.where(live, rows0[None, :] + steps_i[:, None], 0)  # [w, B]
     tgt_blocks = jnp.where(
-        active[None, :], block_tables[jnp.arange(B)[None, :], slots // bs], 0
+        live, block_tables[jnp.arange(B)[None, :], slots // bs], 0
     )  # [w, B] — inactive rows sink to scratch block 0
     tgt_offs = slots % bs
     layer_idx = jnp.broadcast_to(jnp.arange(L, dtype=jnp.int32)[:, None, None], (L, num_steps, B))
@@ -832,6 +897,78 @@ def decode_multi(
     if return_logits:
         return out, lg_steps, k_new, v_new
     return out, k_new, v_new
+
+
+def eva_roll_blocks(c: ModelConfig) -> int:
+    """Blocks ``eva_roll`` is handed: those that hold a window's rows from
+    the block of its first row on. One more than the window's own where the
+    summaries of a window do not fill whole blocks, so that a window may
+    begin inside a block."""
+    bs, W = c.block_size, c.window_size
+    aligned = c.summaries_per_window % bs == 0 and W % bs == 0
+    return -(-W // bs) + (0 if aligned else 1)
+
+
+def eva_roll(
+    params: Params,
+    config: ModelConfig,
+    k_cache: jax.Array,  # [L, N, BS, KVH*HD]
+    v_cache: jax.Array,
+    table: jax.Array,  # [eva_roll_blocks] block ids from the block that holds ``row0`` on
+    row0: jax.Array,  # scalar i32: table row of the window's first key
+) -> Tuple[jax.Array, jax.Array]:
+    """Roll one completed window of one sequence: pool each chunk of its
+    ``window_size`` exact (key, value) rows into one summary row and write
+    the ``M = window_size // chunk_size`` summaries over the window's first
+    ``M`` rows, in every layer. Returns (k_cache, v_cache); the caller then
+    releases the blocks past the summaries.
+
+    Per head ``a`` and chunk ``c`` (float32; ``mu``, ``phi`` learned per
+    head and layer)::
+
+        kbar[c, a] = sum_i softmax_i(mu_a . k[i, a]) k[i, a]
+        vbar[c, a] = sum_i softmax_i(phi_a . k[i, a]) v[i, a]
+
+    Keys are pooled as cached, i.e. after rope. The window's blocks are
+    gathered through the layer-flat pool (no slice of a layer, no copy of
+    the pool) and the summaries scattered in one all-layer write into the
+    donated buffers: the bytes moved are the window read and ``M`` rows
+    written per layer."""
+    c = config
+    bs, W, C, M = c.block_size, c.window_size, c.chunk_size, c.summaries_per_window
+    L, KVH, HD = c.num_layers, c.num_kv_heads, c.head_dim
+    N = k_cache.shape[1]
+    nb = table.shape[0]
+    k_flat, v_flat = layer_flat(k_cache), layer_flat(v_cache)
+    off0 = row0 % bs
+
+    def window_rows(flat, l):
+        rows = _gather_kv(flat, table + l * N, flat.dtype).reshape(nb * bs, KVH * HD)
+        if nb * bs != W:
+            rows = lax.dynamic_slice_in_dim(rows, off0, W, axis=0)
+        return split_heads(rows, KVH).astype(jnp.float32).reshape(M, C, KVH, HD)
+
+    def pool(by, x, query):
+        """Softmax-pooled ``x`` over each chunk, weighted by ``query . by``."""
+        w = jax.nn.softmax(jnp.sum(by * query.astype(jnp.float32), axis=-1), axis=1)  # [M, C, KVH]
+        return jnp.sum(w[..., None] * x, axis=1)  # [M, KVH, HD]
+
+    def layer_fn(_, xs):
+        mu, phi, l = xs
+        k, v = window_rows(k_flat, l), window_rows(v_flat, l)
+        return None, (pool(k, k, mu).astype(k_cache.dtype), pool(k, v, phi).astype(v_cache.dtype))
+
+    layers = params["layers"]
+    _, (k_bar, v_bar) = lax.scan(
+        layer_fn, None, (layers["eva_mu"], layers["eva_phi"], jnp.arange(L, dtype=jnp.int32))
+    )
+    tgt = off0 + jnp.arange(M, dtype=jnp.int32)
+    layer_idx = jnp.broadcast_to(jnp.arange(L, dtype=jnp.int32)[:, None], (L, M))
+    blocks, offs = table[tgt // bs][None, :], (tgt % bs)[None, :]
+    return (
+        _scatter_kv(k_cache, layer_idx, blocks, offs, k_bar),
+        _scatter_kv(v_cache, layer_idx, blocks, offs, v_bar),
+    )
 
 
 def decode_multi_fused(
@@ -873,6 +1010,7 @@ def decode_multi_fused(
     from dynamo_tpu.engine.attention.megakernel import fused_decode_window
 
     c = config
+    _refuse_eva(c, "decode_multi_fused (the fused window keeps one row a token)")
     lp = params["layers"]
     head = params.get("lm_head")
     if head is None:
@@ -922,6 +1060,8 @@ def decode_spec_fused(
     from dynamo_tpu.engine.attention.megakernel import fused_spec_window
 
     tc, dc = target_config, draft_config
+    _refuse_eva(tc, "decode_spec_fused (speculative decoding)")
+    _refuse_eva(dc, "decode_spec_fused (speculative decoding)")
 
     def _w(p):
         head = p.get("lm_head")
@@ -966,6 +1106,8 @@ def _decode_layer_scan_window(
     moe_stats: bool = False,
     k_ctx_all: Optional[jax.Array] = None,  # [L, B, ctx, KVH, HD] pre-gathered
     v_ctx_all: Optional[jax.Array] = None,
+    prefix_rows: Optional[jax.Array] = None,  # [B] cache rows below the window's first (default: its position)
+    wdtype=None,  # compute dtype of the matmul inputs (default: h's)
 ) -> Tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
     """Decode layer scan attending [cached prefix ; window rows ; current].
     Same math as ``decode_layer_scan`` — the window rows are exactly the
@@ -983,6 +1125,7 @@ def _decode_layer_scan_window(
     bs = c.block_size
     ctx = block_tables.shape[1] * bs
     w = k_win.shape[1]
+    wdtype = h.dtype if wdtype is None else wdtype
     kvh, G, hd = c.num_kv_heads, c.num_heads // c.num_kv_heads, c.head_dim
     scale = hd**-0.5
     # Layer-flat cache views (see prefill): the scan gathers with
@@ -1005,7 +1148,9 @@ def _decode_layer_scan_window(
     use_mega = not hoisted and _use_megakernel(c, k_cache)
     # Prefix length is fixed for the whole window (mask0 semantics): the
     # window rows live in the carry, not the cache.
-    win_prefix_lens = jnp.minimum(positions - step, ctx).astype(jnp.int32)
+    if prefix_rows is None:
+        prefix_rows = positions - step
+    win_prefix_lens = jnp.minimum(prefix_rows, ctx).astype(jnp.int32)
     if use_mega:
         # Megakernel row metadata: each decode query's fresh keys are its
         # row's slice of [current ; window rows] — a contiguous [start,
@@ -1024,8 +1169,8 @@ def _decode_layer_scan_window(
             lp, l, kwl, vwl, k_ctx, v_ctx = xs
         else:
             lp, l, kwl, vwl = xs  # kwl/vwl: [w, B, KVH, HD] this layer's window rows
-        lp = dequant_layer(lp, h.dtype)  # int8 weight-only storage
-        x = rms_norm(h, lp["attn_norm"], c.rms_norm_eps)
+        lp = dequant_layer(lp, wdtype)  # int8 weight-only storage
+        x = _norm(c, h, lp["attn_norm"], wdtype)
         q = (x @ lp["wq"]).reshape(B, 1, c.num_heads, c.head_dim)
         k = (x @ lp["wk"]).reshape(B, 1, c.num_kv_heads, c.head_dim)
         v = (x @ lp["wv"]).reshape(B, 1, c.num_kv_heads, c.head_dim)
@@ -1046,9 +1191,9 @@ def _decode_layer_scan_window(
             attn = _mega_attend_rows(
                 c, q, k_extra, v_extra, k_flat, v_flat,
                 block_tables + l * N, mega_meta,
-            ).astype(h.dtype)
+            ).astype(wdtype)
             h = h + attn.reshape(B, c.q_size) @ lp["wo"]
-            x = rms_norm(h, lp["mlp_norm"], c.rms_norm_eps)
+            x = _norm(c, h, lp["mlp_norm"], wdtype)
             if moe_stats:
                 mlp_out, drops = _mlp(x, lp, c, valid=active, stats=True)
                 return h + mlp_out, (k, v, drops)
@@ -1063,17 +1208,17 @@ def _decode_layer_scan_window(
                 tables_l = block_tables + l * N
                 # Piece 1: cached prefix via the width-bucketed gather (two-
                 # piece online-softmax — no concat re-materialization).
-                k_ctx = _gather_kv(k_flat, tables_l, h.dtype).reshape(B, ctx, kvh, hd)
-                v_ctx = _gather_kv(v_flat, tables_l, h.dtype).reshape(B, ctx, kvh, hd)
+                k_ctx = _gather_kv(k_flat, tables_l, wdtype).reshape(B, ctx, kvh, hd)
+                v_ctx = _gather_kv(v_flat, tables_l, wdtype).reshape(B, ctx, kvh, hd)
             m1, l1, acc1 = _attend_piece(qg, k_ctx, v_ctx, mask0, scale)
         # Piece 2: in-register rows [window ; current] — never round-trip HBM.
         k_small = jnp.concatenate([jnp.swapaxes(kwl, 0, 1), k[:, None]], axis=1)  # [B, w+1, ...]
         v_small = jnp.concatenate([jnp.swapaxes(vwl, 0, 1), v[:, None]], axis=1)
         m2, l2, acc2 = _attend_piece(qg, k_small, v_small, small_mask, scale)
-        attn = _merge_pieces(m1, l1, acc1, m2, l2, acc2).astype(h.dtype)
+        attn = _merge_pieces(m1, l1, acc1, m2, l2, acc2).astype(wdtype)
 
         h = h + attn.reshape(B, c.q_size) @ lp["wo"]
-        x = rms_norm(h, lp["mlp_norm"], c.rms_norm_eps)
+        x = _norm(c, h, lp["mlp_norm"], wdtype)
         if moe_stats:
             mlp_out, drops = _mlp(x, lp, c, valid=active, stats=True)
             return h + mlp_out, (k, v, drops)
@@ -1120,6 +1265,7 @@ def chunk_decode(
     at that position overwrites them — write-before-attend, monotone
     positions)."""
     c = config
+    _refuse_eva(c, "chunk_decode (wave admission, speculative verification)")
     bs = c.block_size
     B, S = tokens.shape
     L, KVH, HD = c.num_layers, c.num_kv_heads, c.head_dim
@@ -1133,6 +1279,7 @@ def chunk_decode(
     v_flat = layer_flat(v_cache)
 
     h = params["embed"].at[tokens].get(mode="clip")  # [B, S, D]
+    wdtype = h.dtype
     positions = positions0[:, None] + jnp.arange(S, dtype=jnp.int32)[None, :]  # [B, S]
 
     # Prefix mask: cached keys strictly before the chunk. Chunk mask: causal
@@ -1161,8 +1308,8 @@ def chunk_decode(
 
     def layer_fn(h, xs):
         lp, l = xs
-        lp = dequant_layer(lp, h.dtype)  # int8 weight-only storage
-        x = rms_norm(h, lp["attn_norm"], c.rms_norm_eps)
+        lp = dequant_layer(lp, wdtype)  # int8 weight-only storage
+        x = _norm(c, h, lp["attn_norm"], wdtype)
         q = (x @ lp["wq"]).reshape(B, S, c.num_heads, hd)
         k = (x @ lp["wk"]).reshape(B, S, kvh, hd)
         v = (x @ lp["wv"]).reshape(B, S, kvh, hd)
@@ -1171,8 +1318,8 @@ def chunk_decode(
         qg = q.reshape(B, S, kvh, G, hd)
 
         tables_l = block_tables + l * N
-        k_ctx = _gather_kv(k_flat, tables_l, h.dtype).reshape(B, ctx, kvh, hd)
-        v_ctx = _gather_kv(v_flat, tables_l, h.dtype).reshape(B, ctx, kvh, hd)
+        k_ctx = _gather_kv(k_flat, tables_l, wdtype).reshape(B, ctx, kvh, hd)
+        v_ctx = _gather_kv(v_flat, tables_l, wdtype).reshape(B, ctx, kvh, hd)
         m1, l1, acc1 = piece(qg, k_ctx, v_ctx, prefix_mask)
         m2, l2, acc2 = piece(qg, k, v, chunk_mask)
         m_t = jnp.maximum(m1, m2)
@@ -1180,11 +1327,11 @@ def chunk_decode(
         a2 = jnp.exp(m2 - m_t)
         l_t = l1 * a1 + l2 * a2
         acc = acc1 * a1[..., None] + acc2 * a2[..., None]
-        attn = (acc / jnp.maximum(l_t, 1e-30)[..., None]).astype(h.dtype)  # [B,KVH,G,S,hd]
+        attn = (acc / jnp.maximum(l_t, 1e-30)[..., None]).astype(wdtype)  # [B,KVH,G,S,hd]
         attn = jnp.transpose(attn, (0, 3, 1, 2, 4)).reshape(B, S, c.q_size)
 
         h = h + attn @ lp["wo"]
-        x = rms_norm(h, lp["mlp_norm"], c.rms_norm_eps)
+        x = _norm(c, h, lp["mlp_norm"], wdtype)
         valid_flat = (s_i[None, :] < valid[:, None]).reshape(B * S)
         if moe_stats:
             mlp_out, drops = _mlp(x.reshape(B * S, -1), lp, c, valid=valid_flat, stats=True)
@@ -1298,14 +1445,17 @@ def mixed_step(
     p_valid_q = jnp.arange(S, dtype=jnp.int32) < p_valid
     positions_all = jnp.concatenate([p_positions, d_positions])
     valid_all = jnp.concatenate([p_valid_q, d_active])
-    h = params["embed"].at[jnp.concatenate([p_tokens, d_tokens])].get(mode="clip")  # [S+B, D]
+    h, wdtype = _embed_rows(c, params, jnp.concatenate([p_tokens, d_tokens]))  # [S+B, D]
+    # Positions turn the rope; the cache is addressed by row (cache_rows).
+    p_prefix_rows = cache_rows(c, p_cache_len)
+    d_rows = cache_rows(c, d_positions)
 
     ctx_p = p_table.shape[0] * bs
     ctx_d = d_tables.shape[1] * bs
-    d_tgt_blocks, d_tgt_offs, d_mask = decode_targets(d_positions, d_tables, d_active, bs)
+    d_tgt_blocks, d_tgt_offs, d_mask = decode_targets(d_rows, d_tables, d_active, bs)
     use_paged = _use_paged_decode(c, k_cache)
     use_mega = _use_megakernel(c, k_cache)
-    d_prefix_lens = jnp.minimum(d_positions, ctx_d).astype(jnp.int32)
+    d_prefix_lens = jnp.minimum(d_rows, ctx_d).astype(jnp.int32)
     if use_mega:
         # Megakernel packing: the WHOLE mixed step's attention — the chunk's
         # (start, len) queries AND the B length-1 decode rows — is one
@@ -1324,7 +1474,7 @@ def mixed_step(
         d_iq = jnp.arange(B, dtype=jnp.int32)
         mega_meta = build_meta(
             jnp.concatenate([jnp.zeros((S,), jnp.int32), 1 + d_iq]),
-            jnp.concatenate([jnp.full((S,), p_cache_len, jnp.int32), d_prefix_lens]),
+            jnp.concatenate([jnp.full((S,), p_prefix_rows, jnp.int32), d_prefix_lens]),
             jnp.concatenate([jnp.zeros((S,), jnp.int32), S + d_iq]),
             jnp.concatenate([s_iq + 1, S + d_iq + 1]),
             jnp.concatenate(
@@ -1336,8 +1486,8 @@ def mixed_step(
 
     def layer_fn(h, xs):
         lp, l = xs
-        lp = dequant_layer(lp, h.dtype)  # int8 weight-only storage
-        x = rms_norm(h, lp["attn_norm"], c.rms_norm_eps)
+        lp = dequant_layer(lp, wdtype)  # int8 weight-only storage
+        x = _norm(c, h, lp["attn_norm"], wdtype)
         q = (x @ lp["wq"]).reshape(S + B, c.num_heads, hd)
         k = (x @ lp["wk"]).reshape(S + B, kvh, hd)
         v = (x @ lp["wv"]).reshape(S + B, kvh, hd)
@@ -1349,9 +1499,9 @@ def mixed_step(
             # is the packed [chunk K ; decode K] projection output itself.
             attn = _mega_attend_rows(
                 c, q, k, v, k_flat, v_flat, mega_tbl + l * N, mega_meta
-            ).astype(h.dtype).reshape(S + B, c.q_size)
+            ).astype(wdtype).reshape(S + B, c.q_size)
             h = h + attn @ lp["wo"]
-            x = rms_norm(h, lp["mlp_norm"], c.rms_norm_eps)
+            x = _norm(c, h, lp["mlp_norm"], wdtype)
             if moe_stats:
                 mlp_out, drops = _mlp(x, lp, c, valid=valid_all, stats=True)
                 return h + mlp_out, (k, v, drops)
@@ -1363,10 +1513,10 @@ def mixed_step(
             kp_ctx = vp_ctx = None
         else:
             table_pl = p_table + l * N
-            kp_ctx = _gather_kv(k_flat, table_pl, h.dtype).reshape(ctx_p, kvh, hd)
-            vp_ctx = _gather_kv(v_flat, table_pl, h.dtype).reshape(ctx_p, kvh, hd)
+            kp_ctx = _gather_kv(k_flat, table_pl, wdtype).reshape(ctx_p, kvh, hd)
+            vp_ctx = _gather_kv(v_flat, table_pl, wdtype).reshape(ctx_p, kvh, hd)
         attn_p = ragged_chunk_attention(
-            q[:S], k[:S], v[:S], kp_ctx, vp_ctx, p_valid, p_cache_len,
+            q[:S], k[:S], v[:S], kp_ctx, vp_ctx, p_valid, p_prefix_rows,
             num_kv_heads=kvh, use_flash=use_flash, has_prefix=has_prefix,
             interpret=interp,
         )
@@ -1380,19 +1530,19 @@ def mixed_step(
             )
         else:
             tables_dl = d_tables + l * N
-            kd_ctx = _gather_kv(k_flat, tables_dl, h.dtype).reshape(B, ctx_d, kvh, hd)
-            vd_ctx = _gather_kv(v_flat, tables_dl, h.dtype).reshape(B, ctx_d, kvh, hd)
+            kd_ctx = _gather_kv(k_flat, tables_dl, wdtype).reshape(B, ctx_d, kvh, hd)
+            vd_ctx = _gather_kv(v_flat, tables_dl, wdtype).reshape(B, ctx_d, kvh, hd)
             m1, l1, acc1 = _attend_piece(qg_d, kd_ctx, vd_ctx, d_mask, scale)
         m2, l2, acc2 = _attend_piece(
             qg_d, k[S:, None], v[S:, None], jnp.ones((B, 1), dtype=bool), scale
         )
-        attn_d = _merge_pieces(m1, l1, acc1, m2, l2, acc2).astype(h.dtype)
+        attn_d = _merge_pieces(m1, l1, acc1, m2, l2, acc2).astype(wdtype)
 
         attn = jnp.concatenate(
             [attn_p.reshape(S, c.q_size), attn_d.reshape(B, c.q_size)], axis=0
         )
         h = h + attn @ lp["wo"]
-        x = rms_norm(h, lp["mlp_norm"], c.rms_norm_eps)
+        x = _norm(c, h, lp["mlp_norm"], wdtype)
         if moe_stats:
             mlp_out, drops = _mlp(x, lp, c, valid=valid_all, stats=True)
             return h + mlp_out, (k, v, drops)
@@ -1414,7 +1564,7 @@ def mixed_step(
         )
 
     # ONE fused ragged scatter for chunk rows + decode rows together.
-    p_tgt_blocks, p_tgt_offs = ragged_scatter_targets(p_table, p_positions, p_valid_q, bs)
+    p_tgt_blocks, p_tgt_offs = ragged_scatter_targets(p_table, cache_rows(c, p_positions), p_valid_q, bs)
     tgt_blocks = jnp.concatenate([p_tgt_blocks, d_tgt_blocks])
     tgt_offs = jnp.concatenate([p_tgt_offs, d_tgt_offs])
     layer_idx = jnp.broadcast_to(jnp.arange(L, dtype=jnp.int32)[:, None], (L, S + B))
@@ -1424,10 +1574,7 @@ def mixed_step(
     # lm_head only at each sequence's LAST row: the chunk's last valid
     # position + every decode row — [1+B, D] picked rows, never [S+B, V].
     last_p = jnp.maximum(p_valid - 1, 0)
-    h_rows = jnp.concatenate([h[last_p][None], h[S:]], axis=0)
-    h_rows = rms_norm(h_rows, params["final_norm"], c.rms_norm_eps)
-    head = params.get("lm_head")
-    logits = (h_rows @ (head if head is not None else params["embed"].T)).astype(jnp.float32)
+    logits = _logits(c, params, jnp.concatenate([h[last_p][None], h[S:]], axis=0), wdtype)
     if moe_stats:
         return logits, k_new, v_new, aux
     return logits, k_new, v_new
@@ -1487,21 +1634,23 @@ def embed(
     pool over the final hidden states → [hidden_size] f32, L2-normalized.
     (Serving path for /v1/embeddings — ref: http/service/openai.rs:369.)"""
     c = config
+    _refuse_eva(c, "embed (sequence embeddings)")
     T = tokens.shape[0]
     h = params["embed"].at[tokens].get(mode="clip")  # [T, D]
+    wdtype = h.dtype
     positions = jnp.arange(T, dtype=jnp.int32)
     valid = positions < valid_len
     mask = (positions[None, :] <= positions[:, None]) & valid[None, :]
 
     def layer_fn(h, lp):
-        lp = dequant_layer(lp, h.dtype)  # int8 weight-only storage
-        x = rms_norm(h, lp["attn_norm"], c.rms_norm_eps)
+        lp = dequant_layer(lp, wdtype)  # int8 weight-only storage
+        x = _norm(c, h, lp["attn_norm"], wdtype)
         q = apply_rope((x @ lp["wq"]).reshape(T, c.num_heads, c.head_dim), positions, c.rope_theta)
         k = apply_rope((x @ lp["wk"]).reshape(T, c.num_kv_heads, c.head_dim), positions, c.rope_theta)
         v = (x @ lp["wv"]).reshape(T, c.num_kv_heads, c.head_dim)
         attn = _attend(q, k, v, mask, c)
         h = h + attn.reshape(T, c.q_size) @ lp["wo"]
-        x = rms_norm(h, lp["mlp_norm"], c.rms_norm_eps)
+        x = _norm(c, h, lp["mlp_norm"], wdtype)
         h = h + _mlp(x, lp, c, valid=valid)
         return h, None
 
@@ -1518,7 +1667,7 @@ def embed(
 
 
 def decode_targets(
-    positions: jax.Array,  # [B]
+    positions: jax.Array,  # [B] cache row of each current token (kv_cache.cache_rows)
     block_tables: jax.Array,  # [B, max_blocks]
     active: jax.Array,  # [B] bool
     block_size: int,
@@ -1553,6 +1702,7 @@ def decode_layer_scan(
     mask: jax.Array,  # [B, ctx] bool — cached prefix only (decode_targets)
     active: Optional[jax.Array] = None,  # [B] bool — live lanes (MoE dispatch mask)
     moe_stats: bool = False,  # also return summed capacity drops
+    wdtype=None,  # compute dtype of the matmul inputs (default: h's)
 ):
     """Scan the decode layer body over a stacked layer group. Factored out of
     ``decode`` so pipeline parallelism (pipeline_parallel.py) can run the
@@ -1579,7 +1729,8 @@ def decode_layer_scan(
     scale = hd**-0.5
     use_paged = _use_paged_decode(c, k_cache)
     use_mega = _use_megakernel(c, k_cache)
-    prefix_lens = jnp.minimum(positions, ctx).astype(jnp.int32)
+    wdtype = h.dtype if wdtype is None else wdtype
+    prefix_lens = jnp.minimum(cache_rows(c, positions), ctx).astype(jnp.int32)
     if use_mega:
         from dynamo_tpu.engine.attention.megakernel import build_meta
 
@@ -1590,8 +1741,8 @@ def decode_layer_scan(
 
     def layer_fn(h, xs):
         lp, l = xs  # l: scalar layer index within this stack
-        lp = dequant_layer(lp, h.dtype)  # int8 weight-only storage
-        x = rms_norm(h, lp["attn_norm"], c.rms_norm_eps)
+        lp = dequant_layer(lp, wdtype)  # int8 weight-only storage
+        x = _norm(c, h, lp["attn_norm"], wdtype)
         q = (x @ lp["wq"]).reshape(B, 1, c.num_heads, c.head_dim)
         k = (x @ lp["wk"]).reshape(B, 1, c.num_kv_heads, c.head_dim)
         v = (x @ lp["wv"]).reshape(B, 1, c.num_kv_heads, c.head_dim)
@@ -1607,7 +1758,7 @@ def decode_layer_scan(
             # external piece merge (attention/megakernel.py).
             attn = _mega_attend_rows(
                 c, q, k, v, k_flat, v_flat, tables_l, mega_meta
-            ).astype(h.dtype)
+            ).astype(wdtype)
         else:
             # Two online-softmax pieces: cached prefix + current token
             # in-register. Prefix: Pallas paged flash kernel (pages stream
@@ -1615,16 +1766,16 @@ def decode_layer_scan(
             if use_paged:
                 m1, l1, acc1 = _paged_prefix_partials(c, q, k_flat, v_flat, tables_l, prefix_lens)
             else:
-                k_ctx = _gather_kv(k_flat, tables_l, h.dtype).reshape(B, ctx, kvh, hd)
-                v_ctx = _gather_kv(v_flat, tables_l, h.dtype).reshape(B, ctx, kvh, hd)
+                k_ctx = _gather_kv(k_flat, tables_l, wdtype).reshape(B, ctx, kvh, hd)
+                v_ctx = _gather_kv(v_flat, tables_l, wdtype).reshape(B, ctx, kvh, hd)
                 m1, l1, acc1 = _attend_piece(qg, k_ctx, v_ctx, mask, scale)
             m2, l2, acc2 = _attend_piece(
                 qg, k[:, None], v[:, None], jnp.ones((B, 1), dtype=bool), scale
             )
-            attn = _merge_pieces(m1, l1, acc1, m2, l2, acc2).astype(h.dtype)
+            attn = _merge_pieces(m1, l1, acc1, m2, l2, acc2).astype(wdtype)
         h = h + attn.reshape(B, c.q_size) @ lp["wo"]
 
-        x = rms_norm(h, lp["mlp_norm"], c.rms_norm_eps)
+        x = _norm(c, h, lp["mlp_norm"], wdtype)
         if moe_stats:
             mlp_out, drops = _mlp(x, lp, c, valid=active, stats=True)
             return h + mlp_out, (k, v, drops)
@@ -1674,9 +1825,9 @@ def decode(
     c = config
     bs = c.block_size
 
-    h = params["embed"].at[tokens].get(mode="clip")  # [B, D]
+    h, wdtype = _embed_rows(c, params, tokens)  # [B, D]
 
-    tgt_blocks, tgt_offs, mask = decode_targets(positions, block_tables, active, bs)
+    tgt_blocks, tgt_offs, mask = decode_targets(cache_rows(c, positions), block_tables, active, bs)
 
     # Decode attention: the ragged megakernel (one launch per layer, TPU
     # auto) or the width-bucketed XLA gather with a two-piece online-
@@ -1684,23 +1835,21 @@ def decode(
     if moe_stats:
         h, k_rows, v_rows, drops = decode_layer_scan(
             params["layers"], c, k_cache, v_cache, h, positions,
-            block_tables, mask, active=active, moe_stats=True,
+            block_tables, mask, active=active, moe_stats=True, wdtype=wdtype,
         )
     else:
         h, k_rows, v_rows = decode_layer_scan(
             params["layers"], c, k_cache, v_cache, h, positions,
-            block_tables, mask, active=active,
+            block_tables, mask, active=active, wdtype=wdtype,
         )
     k_new, v_new = scatter_kv_rows(k_cache, v_cache, k_rows, v_rows, tgt_blocks, tgt_offs)
 
-    h = rms_norm(h, params["final_norm"], c.rms_norm_eps)
-    head = params.get("lm_head")
-    logits = h @ (head if head is not None else params["embed"].T)
+    logits = _logits(c, params, h, wdtype)
     if moe_stats:
         aux = {
             "moe_dropped": drops,
             "moe_assignments": jnp.sum(active).astype(jnp.int32)
             * jnp.int32(max(c.num_experts_per_tok, 1) * c.num_layers),
         }
-        return logits.astype(jnp.float32), k_new, v_new, aux
-    return logits.astype(jnp.float32), k_new, v_new
+        return logits, k_new, v_new, aux
+    return logits, k_new, v_new

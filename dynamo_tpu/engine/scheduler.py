@@ -41,7 +41,7 @@ import numpy as np
 
 from dynamo_tpu.engine.config import ModelConfig
 from dynamo_tpu.engine.flight_recorder import FlightRecorder, StepCostModel
-from dynamo_tpu.engine.kv_cache import BlockAllocator, KvCacheArrays, KvEvent, OutOfBlocksError
+from dynamo_tpu.engine.kv_cache import BlockAllocator, KvCacheArrays, KvEvent, OutOfBlocksError, cache_rows
 from dynamo_tpu.runtime.ledger import RequestBill, TenantLedger
 from dynamo_tpu.runtime.telemetry import SloConfig, SloJudge, Telemetry
 from dynamo_tpu.engine.sampling import SamplingParams, guided_sample_batch, sample_batch
@@ -143,6 +143,9 @@ class Sequence:
     output_ids: List[int] = field(default_factory=list)
     block_ids: List[int] = field(default_factory=list)
     num_computed: int = 0  # prompt tokens whose KV is in cache
+    # attention_kind "eva": completed windows whose rows are summaries by now
+    # (Scheduler._roll); the table is then [summary rows ; current window].
+    rolls: int = 0
     block_hashes: List[int] = field(default_factory=list)
     num_cached_blocks: int = 0  # prefix blocks reused from cache
     cached_tokens: int = 0  # prompt tokens skipped by the prefix cache
@@ -387,12 +390,22 @@ class Scheduler:
     ):
         from dynamo_tpu.engine.config import resolve_moe_dispatch
 
+        self.mc = model_config
+        if model_config.is_eva and mesh is not None:
+            self._refuse_eva("sharded serving (a mesh)")
         ep = parallel.ep if parallel is not None else (mesh.shape.get("ep", 1) if mesh else 1)
         model_config = resolve_moe_dispatch(model_config, ep)
         self.mc = model_config
         self.sc = scheduler_config or SchedulerConfig()
         self.mesh = mesh
         self.parallel = parallel
+        # attention_kind "eva": a position and its cache row differ
+        # (kv_cache.cache_rows), completed windows are rolled into summaries
+        # (_roll_windows), and what equates the two is refused (_refuse_eva).
+        self._eva = model_config.is_eva
+        if self._eva and self.sc.enable_prefix_caching:
+            logger.info("attention_kind='eva': prefix-block reuse is not built for it, prefix caching is off")
+            self.sc.enable_prefix_caching = False
         self.allocator = BlockAllocator(self.sc.num_blocks, on_event=on_kv_event)
         # Reserve block 0 as the scratch sink for padded scatter positions.
         self.allocator._free.remove(0)
@@ -411,7 +424,14 @@ class Scheduler:
         else:
             self.cache = KvCacheArrays.create(model_config, self.sc.num_blocks, dtype=dtype)
         self.params = params
-        self.max_blocks_per_seq = (model_config.max_seq_len + model_config.block_size - 1) // model_config.block_size
+        # Widest table a sequence can hold: every position's row, or for eva the
+        # summaries of every completed window and one whole window.
+        most_rows = model_config.max_seq_len
+        if self._eva:
+            W = model_config.window_size
+            last_window = (most_rows - 1) // W * W  # first position of the last window a sequence can open
+            most_rows = cache_rows(model_config, last_window) + min(W, most_rows)
+        self.max_blocks_per_seq = (most_rows + model_config.block_size - 1) // model_config.block_size
 
         # Optional tiered block manager (KVBM) — set via attach_kvbm().
         self.kvbm = None
@@ -583,7 +603,9 @@ class Scheduler:
         # holds the in-flight step (see _overlap_step); _tables_cache keeps
         # the last decode block-table upload so tables cross the wire only
         # when a table actually changes.
-        self._supports_overlap = hasattr(model, "decode_sample")
+        # (eva: the pipeline hands positions on from device to device and
+        # cannot stop a row at its window boundary for the roll.)
+        self._supports_overlap = hasattr(model, "decode_sample") and not self._eva
         if self._supports_overlap:
 
             def decode_sample(p, k, v, tpa, bt, te, tk, tp, key):
@@ -594,6 +616,17 @@ class Scheduler:
             self._decode_sample_jit = jax.jit(decode_sample, donate_argnums=(1, 2))
         self._pipe: Optional[dict] = None
         self._tables_cache: Optional[tuple] = None
+        # eva: the summarising program of a roll (one window of one sequence),
+        # and what the rolls have done so far.
+        self.eva_rolls_total = 0
+        self.eva_released_blocks_total = 0
+        if self._eva:
+
+            def eva_roll(p, k, v, table, row0):
+                return model.eva_roll(p, self.mc, k, v, table, row0)
+
+            self._roll_jit = jax.jit(eva_roll, donate_argnums=(1, 2))
+            self._roll_blocks = model.eva_roll_blocks(model_config)
         # Step-phase spans (runtime/tracing.py): the iteration in progress
         # and its open plan phase, which crosses from step() into whichever
         # dispatch path forms the batch.
@@ -657,8 +690,9 @@ class Scheduler:
         self._use_fused_spec = False
         self._spec_rounds = 0
         self._supports_multi_step = hasattr(model, "decode_multi")
-        # Batched admission (chunk_decode waves) — llama-family only.
-        self._supports_chunk_admit = hasattr(model, "chunk_decode")
+        # Batched admission (chunk_decode waves) — llama-family only, and not
+        # eva (a wave's chunk may straddle a window boundary).
+        self._supports_chunk_admit = hasattr(model, "chunk_decode") and not self._eva
         self._admit_jits: Dict = {}
         # Mixed prefill+decode steps (llama.mixed_step) — llama-family only.
         self._supports_mixed = hasattr(model, "mixed_step")
@@ -698,6 +732,7 @@ class Scheduler:
             and self.sc.num_scheduler_steps > 1
             and hasattr(model, "decode_multi_fused")
             and self._attn_impl == "megakernel"
+            and not self._eva
             and model_config.num_experts == 0
             and model_config.weight_dtype != "int8"
             and model_config.kv_cache_dtype != "int8"
@@ -763,6 +798,8 @@ class Scheduler:
         (_core.pyi:354-427); here the machinery is native."""
         from dynamo_tpu.engine.spec_decode import SpecDecodeStats
 
+        if self._eva or draft_config.is_eva:
+            self._refuse_eva("speculative decoding")
         if draft_config.block_size != self.mc.block_size:
             raise ValueError("draft and target must share block_size")
         if draft_config.vocab_size != self.mc.vocab_size:
@@ -935,6 +972,10 @@ class Scheduler:
             )
         if len(token_ids) >= self.mc.max_seq_len:
             raise ValueError(f"prompt length {len(token_ids)} >= max_seq_len {self.mc.max_seq_len}")
+        if self._eva and (keep_blocks_on_finish or prefilled is not None):
+            self._refuse_eva("KV export and injection (disaggregated prefill)")
+        if self._eva and mm_features is not None:
+            self._refuse_eva("multimodal feature rows")
         if mm_features is not None:
             if self.mc.architecture != "llama":
                 raise ValueError("multimodal features require the llama prefill path")
@@ -1037,14 +1078,27 @@ class Scheduler:
             if not nb:
                 continue
             allocated += nb * bs
-            used += min(s.total_len, nb * bs)
+            used += min(self._rows_for(s, s.total_len), nb * bs)
         hits, misses = a.hit_blocks_total, a.miss_blocks_total
-        return {
+        out = {
             "kv_free_blocks": len(a._free),
             "kv_cached_blocks": a.num_cached,
             "kv_fragmentation": round(1.0 - used / allocated, 6) if allocated else 0.0,
             "prefix_hit_rate": round(hits / (hits + misses), 6) if (hits + misses) else 0.0,
         }
+        if self._eva:
+            # Blocks that hold summaries of rolled windows (the last of them
+            # may also hold the current window's first rows), the rest of the
+            # live tables, and what the rolls gave back.
+            live = [s for s in list(self.running) + list(self.waiting) if s.block_ids]
+            summary = sum(-(-self.mc.summaries_per_window * s.rolls // bs) for s in live)
+            out.update(
+                eva_rolls_total=self.eva_rolls_total,
+                eva_released_blocks_total=self.eva_released_blocks_total,
+                eva_summary_blocks=summary,
+                eva_window_blocks=sum(len(s.block_ids) for s in live) - summary,
+            )
+        return out
 
     def debug_state(self) -> dict:
         """Live introspection snapshot for /debug/state: every sequence with
@@ -1064,6 +1118,7 @@ class Scheduler:
                 "cached_tokens": s.cached_tokens,
                 "blocks": len(s.block_ids),
                 "preemptions": s.preemptions,
+                **({"rolls": s.rolls, "cache_rows": self._rows_for(s, s.total_len)} if self._eva else {}),
             }
 
         a = self.allocator
@@ -1077,7 +1132,7 @@ class Scheduler:
                 "cached": a.num_cached,
                 "active": a.num_active,
                 "usage": round(a.usage(), 6),
-                **{k: v for k, v in self.kv_gauges().items() if k == "kv_fragmentation"},
+                **{k: v for k, v in self.kv_gauges().items() if k == "kv_fragmentation" or k.startswith("eva_")},
             },
             "digests": self.telemetry.summary(),
             "slo": self.slo.to_stats(),
@@ -1110,6 +1165,7 @@ class Scheduler:
             "model": {
                 "name": self.mc.name,
                 "architecture": self.mc.architecture,
+                "attention_kind": self.mc.attention_kind,
                 "max_seq_len": self.mc.max_seq_len,
                 "block_size": self.mc.block_size,
                 "kv_cache_dtype": getattr(self.mc, "kv_cache_dtype", None),
@@ -1219,6 +1275,8 @@ class Scheduler:
                 return
             self._overlap_flush(outputs)
         self._reap_aborted(outputs)
+        if self._eva:
+            self._roll_windows()
         cand = self._mixed_candidate()
         if cand is not None and not self._wave_preferred() and self._mixed_step(cand, outputs):
             return
@@ -1247,11 +1305,20 @@ class Scheduler:
         span = self._step_span
         if span is None:
             return
-        if span.attrs is None:
+        attrs = span.attrs or {}
+        if kind == "eva_roll":
+            # A roll goes before the iteration's step program: it is counted
+            # beside it, and the entry keeps that program's kind and shape.
+            span.set(rolls=attrs.get("rolls", 0) + 1)
+        elif "kind" not in attrs:
             span.set(kind=kind, key=key, rows=len(batch), ctx=sum(s.total_len for s in batch),
                      prefill=prefill, decode=decode)
+            if self._eva:
+                # Cache rows the batch's contexts hold (what a decode step
+                # attends), beside their lengths in bytes under ``ctx``.
+                span.set(attended=sum(self._rows_for(s, s.total_len) for s in batch))
         else:
-            span.set(dispatches=span.attrs.get("dispatches", 1) + 1)
+            span.set(dispatches=attrs.get("dispatches", 1) + 1)
 
     def _launch(self, kind: str, decode: bool = False) -> StepSpan:
         """The ``sched.launch`` span of one program. A decode-family launch
@@ -1266,6 +1333,83 @@ class Scheduler:
         the start of its ``sched.upload`` to the end of its ``sched.emit``
         (what the flight recorder, the bills and the itl digest are fed)."""
         return (time.monotonic_ns() - span.t0) / 1e9
+
+    # --- attention_kind "eva": rows, rolls, refusals --------------------------
+    def _refuse_eva(self, what: str) -> None:
+        raise NotImplementedError(
+            f"{what} is not built for attention_kind='eva' (model {self.mc.name!r}): "
+            "its block tables hold summaries of rolled windows, not one row a token"
+        )
+
+    def _rows_for(self, seq: Sequence, n_tokens: int) -> int:
+        """Table rows ``seq`` needs to hold its first ``n_tokens`` positions.
+        Causal: one row a token. Eva: the summaries of its rolled windows and
+        the positions of the current window, which ends at its boundary (what
+        lies past it is written after the roll, over the rows it frees)."""
+        if not self._eva:
+            return n_tokens
+        W = self.mc.window_size
+        first = seq.rolls * W  # the current window's first position, at the row after the summaries
+        return cache_rows(self.mc, first) + max(0, min(n_tokens - first, W))
+
+    def _grow_table(self, seq: Sequence, n_tokens: int) -> None:
+        """Blocks for ``seq``'s first ``n_tokens`` positions, or OutOfBlocksError."""
+        bs = self.mc.block_size
+        need = (self._rows_for(seq, n_tokens) + bs - 1) // bs - len(seq.block_ids)
+        if need > 0:
+            seq.block_ids.extend(self.allocator.allocate(need))
+
+    def _window_room(self, position: int) -> int:
+        """Positions from ``position`` to the end of its window: how far one
+        dispatch may write (a prefill chunk is cut there, a decode window's
+        row stops there), the roll coming before the next."""
+        return self.mc.window_size - position % self.mc.window_size
+
+    def _roll_windows(self) -> None:
+        """Roll every live sequence whose next write opens a window while the
+        one before is still exact rows: a phase of the iteration, before the
+        batch is formed."""
+        W = self.mc.window_size
+        for seq in list(self.running) + [s for s in self.waiting if s.state == SeqState.PREFILL]:
+            nxt = seq.total_len - 1 if seq.state == SeqState.RUNNING else seq.num_computed
+            while seq.block_ids and nxt // W > seq.rolls:
+                self._roll(seq)
+
+    def _roll(self, seq: Sequence) -> None:
+        """One roll: the summarising program over the completed window's
+        blocks, in place (the summaries land on the window's first rows, so
+        nothing is allocated), then the blocks past them go back to the
+        allocator and the table is [summary rows ; one row of the new window]."""
+        bs, M = self.mc.block_size, self.mc.summaries_per_window
+        self._end_plan()
+        with self._span("sched.roll", request_id=seq.request_id, window=seq.rolls) as roll:
+            row0 = M * seq.rolls
+            table = np.zeros((self._roll_blocks,), dtype=np.int32)
+            mine = seq.block_ids[row0 // bs : row0 // bs + self._roll_blocks]
+            table[: len(mine)] = mine
+            self.flight.record_exec("eva_roll", ())
+            self._note_step("eva_roll", (), (seq,))
+            with self._launch("eva_roll"):
+                self.cache.k, self.cache.v = self._roll_jit(
+                    self.params, self.cache.k, self.cache.v, jnp.asarray(table), jnp.int32(row0)
+                )
+            with self._span("sched.sync"):
+                # The program is short and rare; waiting for it here keeps its
+                # device time out of the step program's that follows.
+                jax.block_until_ready(self.cache.k)
+            self._accrue_kv(seq)  # the window's blocks were held until now
+            seq.rolls += 1
+            keep = (M * seq.rolls + 1 + bs - 1) // bs
+            released, seq.block_ids = seq.block_ids[keep:], seq.block_ids[:keep]
+            self.allocator.release(released)
+            self.eva_rolls_total += 1
+            self.eva_released_blocks_total += len(released)
+            roll.set(released=len(released), blocks=len(seq.block_ids))
+        self.flight.record_step(
+            "roll", self._since(roll), M, kv_read_tokens=self.mc.window_size, param_passes=0.0
+        )
+        self._trace_event(seq, "eva_roll", window=seq.rolls - 1, released=len(released))
+        self._begin_plan()
 
     def _mixed_candidate(self) -> Optional[Sequence]:
         """Head-of-queue sequence eligible to ride a mixed step, or None.
@@ -1368,6 +1512,12 @@ class Scheduler:
         chunk = min(remaining, budget)
         s_bucket = next_bucket(chunk, self.sc.prefill_buckets)
         chunk = min(chunk, s_bucket)
+        if self._eva:
+            chunk = min(chunk, self._window_room(seq.num_computed))
+            try:
+                self._grow_table(seq, seq.num_computed + chunk + 1)
+            except OutOfBlocksError:
+                return False
         chunk_tokens = pf_tokens[seq.num_computed : seq.num_computed + chunk]
         p_tok = np.zeros((s_bucket,), dtype=np.int32)
         p_tok[: len(chunk_tokens)] = chunk_tokens
@@ -1426,13 +1576,13 @@ class Scheduler:
             # phases (the step histogram itself stays under "mixed").
             self.flight.record_mixed_step(
                 dur, len(chunk_tokens), n,
-                kv_read_prefill=seq.num_computed,
-                kv_read_decode=sum(s.total_len for s in batch),
+                kv_read_prefill=self._rows_for(seq, seq.num_computed),
+                kv_read_decode=sum(self._rows_for(s, s.total_len) for s in batch),
             )
             self._bill_step(
                 dur,
-                [(seq, "prefill", len(chunk_tokens), seq.num_computed)]
-                + [(s, "decode", 1, s.total_len) for s in batch],
+                [(seq, "prefill", len(chunk_tokens), self._rows_for(seq, seq.num_computed))]
+                + [(s, "decode", 1, self._rows_for(s, s.total_len)) for s in batch],
             )
             self.telemetry.observe("itl", dur)
             self._trace_event(
@@ -1728,9 +1878,8 @@ class Scheduler:
                 seq.num_computed = min(len(matched) * bs, len(pf_tokens) - 1)
                 seq.cached_tokens = seq.num_computed
                 self.cached_tokens_total += seq.cached_tokens
-            needed = (total_tokens + bs - 1) // bs - len(seq.block_ids)
-            if needed > 0:
-                seq.block_ids.extend(self.allocator.allocate(needed))
+            # (eva: the first window's rows; later windows grow chunk by chunk.)
+            self._grow_table(seq, total_tokens)
         except OutOfBlocksError:
             self.allocator.release(seq.block_ids)
             self.cached_tokens_total -= seq.cached_tokens
@@ -1775,6 +1924,9 @@ class Scheduler:
         chunk = min(remaining, self._chunk_budget())
         bucket = next_bucket(chunk, self.sc.prefill_buckets)
         chunk = min(chunk, bucket)
+        if self._eva:
+            chunk = min(chunk, self._window_room(seq.num_computed))
+            self._grow_table(seq, seq.num_computed + chunk + 1)  # OutOfBlocksError: _admit retries later
 
         tokens = pf_tokens[seq.num_computed : seq.num_computed + chunk]
         padded = np.zeros((bucket,), dtype=np.int32)
@@ -1819,9 +1971,9 @@ class Scheduler:
         seq.prefill_chunks += 1
         with self._span("sched.account"):
             self.flight.record_step(
-                "prefill", dur, len(tokens), kv_read_tokens=seq.num_computed
+                "prefill", dur, len(tokens), kv_read_tokens=self._rows_for(seq, seq.num_computed)
             )
-            self._bill_step(dur, [(seq, "prefill", len(tokens), seq.num_computed)])
+            self._bill_step(dur, [(seq, "prefill", len(tokens), self._rows_for(seq, seq.num_computed))])
             self._trace_event(
                 seq, "prefill_chunk", tokens=len(tokens), bucket=bucket,
                 computed=seq.num_computed + len(tokens), dur_s=round(dur, 6),
@@ -2062,6 +2214,15 @@ class Scheduler:
                 self.cache.k, self.cache.v, jnp.int32(0), jnp.int32(0)
             )
             count += 1
+        if self._eva:
+            # The roll program: one executable, warmed against the scratch
+            # block (a table of zeros reads and writes block 0).
+            self.flight.record_exec("eva_roll", ())
+            self.cache.k, self.cache.v = self._roll_jit(
+                self.params, self.cache.k, self.cache.v,
+                jnp.zeros((self._roll_blocks,), jnp.int32), jnp.int32(0),
+            )
+            count += 1
         # Prefix-cache copy-on-write block copy: one executable, warmed
         # against the scratch block so a full-cover hit under traffic never
         # compiles (0-post-warmup invariant with prefix caching enabled).
@@ -2195,14 +2356,21 @@ class Scheduler:
             and self.sc.enable_mixed_batching
             and self.draft_params is None
         ):
-            p_w = max(16, width_bucket(1, self.max_blocks_per_seq))
-            for s_b in self._mixed_warm_buckets():
+            # (An eva table shrinks at every roll and regrows, so a prompt's
+            # table takes every width on its way: warm them all.)
+            p_ws = [max(16, width_bucket(1, self.max_blocks_per_seq))]
+            if self._eva:
+                p_ws = sorted({max(16, w) for w in widths} | set(p_ws))
+            # Where has_prefix changes the program (_hp_static) a prompt's
+            # first chunk and its later ones are two executables: warm both.
+            hps = (False, True) if self._hp_static else (False,)
+            for s_b, p_w in ((s, w) for s in self._mixed_warm_buckets() for w in p_ws):
                 for bucket in self.sc.decode_buckets:
-                    for width in widths:
+                    for width, hp in ((w, hp) for w in widths for hp in hps):
                         self.flight.record_exec(
                             "mixed",
                             (s_b, p_w, bucket, width)
-                            + ((False,) if self._hp_static else ()),
+                            + ((hp,) if self._hp_static else ()),
                         )
                         res = self._get_mixed_jit((s_b, p_w, bucket, width))(
                             self.params, self.cache.k, self.cache.v,
@@ -2210,7 +2378,7 @@ class Scheduler:
                             jnp.zeros((p_w,), jnp.int32), jnp.zeros((bucket,), jnp.int32),
                             jnp.zeros((bucket,), jnp.int32),
                             jnp.zeros((bucket, width), jnp.int32),
-                            jnp.zeros((bucket,), bool), False,
+                            jnp.zeros((bucket,), bool), hp,
                         )
                         _, self.cache.k, self.cache.v = self._consume_aux(res)
                         count += 1
@@ -2621,9 +2789,9 @@ class Scheduler:
         with self._span("sched.account"):
             self.flight.record_step(
                 "decode", dur, len(outputs),
-                kv_read_tokens=sum(s.total_len for s in batch),
+                kv_read_tokens=sum(self._rows_for(s, s.total_len) for s in batch),
             )
-            self._bill_step(dur, [(s, "decode", 1, s.total_len) for s in batch])
+            self._bill_step(dur, [(s, "decode", 1, self._rows_for(s, s.total_len)) for s in batch])
             self.telemetry.observe("itl", dur)
         self._begin_plan()
         return outputs
@@ -2791,14 +2959,16 @@ class Scheduler:
                 # Window would run past max_seq_len (and past the per-seq
                 # block-table capacity): let single-step finish it off.
                 return False
-            need = (seq.total_len + steps + bs - 1) // bs - len(seq.block_ids)
-            if need > 0:
-                try:
-                    seq.block_ids.extend(self.allocator.allocate(need))
-                except OutOfBlocksError:
-                    return False
+            try:
+                self._grow_table(seq, seq.total_len + steps)
+            except OutOfBlocksError:
+                return False
 
         width = self._width_bucket(max(len(seq.block_ids) for seq in batch))
+        # Tokens each row takes from the window: all of them, or for eva those
+        # before its window boundary (the program writes nothing past it, the
+        # roll comes first).
+        taken = [min(steps, self._window_room(seq.total_len - 1)) if self._eva else steps for seq in batch]
 
         from dynamo_tpu.engine.sampling import pack_param_rows
 
@@ -2926,7 +3096,14 @@ class Scheduler:
 
         exec_key = (steps, bucket, width)
         self.flight.record_exec("decode_multi", exec_key)
-        self._note_step("decode_multi", exec_key, batch, decode=steps * len(batch))
+        self._note_step("decode_multi", exec_key, batch, decode=sum(taken))
+        kv_rows = [self._rows_for(s, s.total_len) for s in batch]  # at the window's first step
+        if self._eva and self._step_span is not None:
+            # What the window needs of the device: the steps until its last
+            # row stops, and the cache rows those steps attend, a row growing
+            # by one a step until its boundary (``attended`` is the first step's).
+            self._step_span.set(live_steps=max(taken),
+                                attended_sum=sum(t * r + t * (t - 1) // 2 for t, r in zip(taken, kv_rows)))
         n0 = len(outputs)
         self._end_plan()
         with self._span("sched.upload") as upload:
@@ -2951,7 +3128,7 @@ class Scheduler:
             sampled = np.asarray(toks_out)  # [steps, bucket] — the one host sync
         with self._span("sched.emit"):
             for i, seq in enumerate(batch):
-                for s in range(steps):
+                for s in range(taken[i]):
                     if seq.state != SeqState.RUNNING:
                         break  # stopped mid-window; later tokens are trimmed
                     self._append_token(seq, int(sampled[s, i]), outputs)
@@ -2959,11 +3136,11 @@ class Scheduler:
         with self._span("sched.account"):
             self.flight.record_step(
                 "decode", dur, len(outputs) - n0,
-                kv_read_tokens=steps * sum(s.total_len for s in batch),
+                kv_read_tokens=steps * sum(kv_rows),
                 # The fori_loop window re-streams the parameter set every step.
                 param_passes=float(steps),
             )
-            self._bill_step(dur, [(s, "decode", steps, steps * s.total_len) for s in batch])
+            self._bill_step(dur, [(s, "decode", steps, steps * r) for s, r in zip(batch, kv_rows)])
             self.telemetry.observe("itl", dur / max(steps, 1))
         self._begin_plan()
         return True
@@ -3229,6 +3406,8 @@ class Scheduler:
         device-native path: in-process handoff or transfer-server pull)."""
         from dynamo_tpu.llm.block_manager.transfer import scatter_blocks, scatter_blocks_device
 
+        if self._eva:
+            self._refuse_eva("KV injection (disaggregated prefill)")
         bs = self.mc.block_size
         data = seq.prefilled
         # Token-boundary splits (elastic disagg): ``prefill_len`` marks how
@@ -3286,6 +3465,8 @@ class Scheduler:
         prompt_len) or None."""
         from dynamo_tpu.llm.block_manager.transfer import gather_blocks
 
+        if self._eva:
+            self._refuse_eva("KV export (disaggregated prefill)")
         seq = self._pending_exports.pop(request_id, None)
         self._export_deadline.pop(request_id, None)
         if seq is None:
@@ -3303,6 +3484,8 @@ class Scheduler:
         can await a remote pull while the blocks are reused."""
         from dynamo_tpu.llm.block_manager.transfer import gather_blocks_device
 
+        if self._eva:
+            self._refuse_eva("KV export (disaggregated prefill)")
         seq = self._pending_exports.pop(request_id, None)
         self._export_deadline.pop(request_id, None)
         if seq is None:
@@ -3338,6 +3521,8 @@ class Scheduler:
 
     def attach_kvbm(self, kvbm) -> None:
         """Enable tiered offload/onboard (KVBM G2/G3) for this scheduler."""
+        if self._eva:
+            self._refuse_eva("KVBM offload tiers")
         self.kvbm = kvbm
 
     def _copy_block(self, src: int, dst: int) -> None:
@@ -3355,6 +3540,8 @@ class Scheduler:
         allocator's G1 walk saw them as misses, so the counters are
         re-attributed here; ``prefix_onboard_total`` tracks the subset that
         crossed a tier boundary back into HBM."""
+        if self._eva:
+            self._refuse_eva("prefix-block matching")
         if self.kvbm is None:
             return self.allocator.match_prefix(seq.block_hashes)
         match = self.kvbm.match_prefix(seq.block_hashes)
@@ -3429,9 +3616,9 @@ class Scheduler:
         preemption) and retry; only when no victim exists does the sequence
         finish with "length"."""
         bs = self.mc.block_size
-        while seq.total_len + 1 > len(seq.block_ids) * bs:
+        while self._rows_for(seq, seq.total_len + 1) > len(seq.block_ids) * bs:
             try:
-                seq.block_ids.extend(self.allocator.allocate(1))
+                self._grow_table(seq, seq.total_len + 1)
                 return
             except OutOfBlocksError:
                 if self.sc.enable_preemption and self._preempt_for(seq):
@@ -3460,6 +3647,7 @@ class Scheduler:
         victim.block_hashes = []
         victim.num_cached_blocks = 0
         victim.num_computed = 0
+        victim.rolls = 0  # summaries are gone with the blocks: the recompute rolls again
         victim.d_n = 0  # draft cache rows are gone with the blocks
         # Recompute everything up to (not including) the last token; the
         # last token re-enters through the decode step on resume.
@@ -3641,6 +3829,8 @@ class Scheduler:
         computed instead of recomputing the whole prompt in parallel."""
         if not self.sc.enable_prefix_caching or not seq.block_hashes:
             return
+        if self._eva:
+            self._refuse_eva("prefix-block registration")
         bs = self.mc.block_size
         n_full = min(seq.num_computed, len(seq.prompt)) // bs
         n_full = min(n_full, len(seq.block_hashes), len(seq.block_ids))

@@ -34,14 +34,26 @@ class Tokenizer(Protocol):
 
 
 class ByteTokenizer:
-    """UTF-8 bytes as tokens; id 0 reserved as EOS/pad."""
+    """UTF-8 bytes as tokens; id 0 reserved as EOS/pad. With ``offset`` the
+    ids below it are special and byte ``b`` is id ``offset + b`` (a byte-level
+    model with 64 special ids: ``ByteTokenizer(64)``, vocabulary 320)."""
 
     EOS = 0
 
+    def __init__(self, offset: int = 0):
+        if offset < 0:
+            raise ValueError(f"offset must be >= 0, got {offset}")
+        self.offset = offset
+
     def encode(self, text: str) -> List[int]:
+        if self.offset:
+            return [self.offset + b for b in text.encode("utf-8")]
         return [b if b != 0 else 1 for b in text.encode("utf-8")]
 
     def decode(self, ids: Sequence[int]) -> str:
+        if self.offset:  # special ids carry no text
+            return bytes(i - self.offset for i in ids if self.offset <= i < self.offset + 256).decode(
+                "utf-8", errors="replace")
         return bytes(i & 0xFF for i in ids if i != self.EOS).decode("utf-8", errors="replace")
 
     @property
@@ -50,7 +62,7 @@ class ByteTokenizer:
 
     @property
     def vocab_size(self) -> int:
-        return 256
+        return 256 + self.offset
 
 
 class HFTokenizer:
@@ -121,7 +133,10 @@ class DecodeStream:
 
 
 def load_tokenizer(path_or_name: Optional[str]) -> Tokenizer:
-    """Local tokenizer.json dir/file → HFTokenizer; otherwise ByteTokenizer."""
+    """Local tokenizer.json dir/file → HFTokenizer; ``bytes:<n>`` → the byte
+    tokenizer with ``n`` special ids below the bytes; otherwise ByteTokenizer."""
+    if path_or_name and path_or_name.startswith("bytes:"):
+        return ByteTokenizer(offset=int(path_or_name.split(":", 1)[1]))
     if path_or_name:
         candidate = path_or_name if path_or_name.endswith(".json") else os.path.join(path_or_name, "tokenizer.json")
         if os.path.exists(candidate):

@@ -203,6 +203,57 @@ def test_decode_multi_window32_compiles(one_chip, on_tpu):
     )
 
 
+# --- attention_kind "eva" at EvaByte's widths (32 KV heads of 128: 4096-lane pages), 4 layers ------
+
+
+EVA = get_config("tiny-eva").replace(
+    name="eva-wide", hidden_size=4096, num_layers=4, num_heads=32, num_kv_heads=32, head_dim=128,
+    intermediate_size=11008, max_seq_len=10240, block_size=128, window_size=2048, chunk_size=16, num_pred_heads=8,
+    attention_impl="paged",
+)
+EVA_BLOCKS = 256  # a pool of 1.07 GB: a copy of it, or of half of it, stands out among the temporaries
+
+
+def _eva_args(sh):
+    shapes = jax.eval_shape(lambda: llama.init_params(EVA, jax.random.PRNGKey(0), dtype=BF16))
+    params = jax.tree.map(lambda s: _sds(s.shape, s.dtype, sh), shapes)
+    cache = _sds((EVA.num_layers, EVA_BLOCKS, EVA.block_size, EVA.kv_size), BF16, sh)
+    return params, cache, cache
+
+
+@pytest.mark.parametrize("program", ["mixed_step", "decode_multi_w8", "eva_roll"])
+def test_eva_step_programs_compile_and_hold_no_copy_of_the_pool(one_chip, on_tpu, program):
+    """``attention_impl="paged"``, as the benchmark's configuration sets it for
+    4096-lane pages: the mixed step holds the flash kernel for the chunk (its
+    prefix fetched once) and the paged kernel for the decode rows. No program
+    may hold a temporary of the pool's size: XLA:TPU lowers a gather of whole
+    4096-lane blocks by slicing the pool in halves (PERF.md section 6, PR 28),
+    so one sequence's table is read by dynamic slices (``llama._GATHER_MAX_LANES``)."""
+    p, k, v = _eva_args(one_chip)
+    i32, sh = jnp.int32, one_chip
+    S, Bd, Wd = 256, 16, 20
+    if program == "mixed_step":
+        fn = lambda p, k, v, pt, pv, cl, ptab, dt, dpos, dtab, dact: llama.mixed_step(  # noqa: E731
+            p, EVA, k, v, pt, pv, cl, ptab, dt, dpos, dtab, dact, use_flash=True, has_prefix=True)
+        args = (_sds((S,), i32, sh), _sds((), i32, sh), _sds((), i32, sh), _sds((Wd,), i32, sh), _sds((Bd,), i32, sh),
+                _sds((Bd,), i32, sh), _sds((Bd, Wd), i32, sh), _sds((Bd,), jnp.bool_, sh))
+    elif program == "decode_multi_w8":
+        fn = lambda p, k, v, t, pos, bt, act, te, tk, tp, key: llama.decode_multi(  # noqa: E731
+            p, EVA, k, v, t, pos, bt, act, te, tk, tp, key, 8)
+        args = (_sds((Bd,), i32, sh), _sds((Bd,), i32, sh), _sds((Bd, Wd), i32, sh), _sds((Bd,), jnp.bool_, sh),
+                _sds((Bd,), jnp.float32, sh), _sds((Bd,), i32, sh), _sds((Bd,), jnp.float32, sh), _sds((2,), jnp.uint32, sh))
+    else:
+        fn = lambda p, k, v, t, r0: llama.eva_roll(p, EVA, k, v, t, r0)  # noqa: E731
+        args = (_sds((llama.eva_roll_blocks(EVA),), i32, sh), _sds((), i32, sh))
+    compiled = jax.jit(fn, donate_argnums=(1, 2)).lower(p, k, v, *args).compile()
+    text = compiled.as_text()
+    assert ("tpu_custom_call" in text) == (program != "eva_roll")
+    if program == "mixed_step":
+        assert "paged_decode_partials" in text and "flash" in text
+    pool = EVA.num_layers * EVA_BLOCKS * EVA.block_size * EVA.kv_size * 2
+    assert compiled.memory_analysis().temp_size_in_bytes < pool // 2
+
+
 def test_decode_sample_compiles(one_chip, on_tpu):
     p, k, v = _one_chip_args(one_chip)
     io = _decode_io(one_chip)
