@@ -44,7 +44,12 @@ class ModelConfig:
     # - "dense":    every expert computes every token (exact, tiny models).
     # - "ragged":   grouped GEMM via lax.ragged_dot — exact (no token drops),
     #               per-token FLOPs scale with top-k K, not E. Single-shard /
-    #               tp-sharded meshes.
+    #               tp-sharded meshes. Layer l's GEMMs read the stored
+    #               [L, E, D, F] stacks in place, viewed [L*E, D, F] with
+    #               group sizes that are zero outside [l*E, (l+1)*E)
+    #               (llama._split_expert_stacks); the other two dispatches
+    #               take a per-layer slice from the layer scan, a copy of
+    #               the layer's experts on XLA:TPU.
     # - "capacity": GShard-style capacity-factor dispatch/combine einsums —
     #               GSPMD partitions experts over the ``ep`` mesh axis; tokens
     #               beyond an expert's capacity fall back to their residual.

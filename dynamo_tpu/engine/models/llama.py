@@ -42,6 +42,10 @@ from dynamo_tpu.engine.sharding import HEADS, PAGES, kernel_shards, over_tp, ste
 
 Params = Dict[str, jax.Array]
 
+# The per-layer FFN weights; for a MoE FFN the expert stacks [L, E, D, F] /
+# [L, E, F, D] (_split_expert_stacks).
+_EXPERT_STACKS = ("w_gate", "w_up", "w_down")
+
 # decode_multi hoisted-gather budget: the once-per-window packed prefix
 # buffer ([L, B, ctx, KVH, HD] × k+v) must stay well under spare HBM. Past
 # this, the window falls back to per-step gathers.
@@ -257,8 +261,37 @@ def _moe_dense(x: jax.Array, lp: Dict[str, jax.Array], config: ModelConfig) -> j
     return jnp.einsum("ted,te->td", out, combine)
 
 
+def _split_expert_stacks(c: ModelConfig, layers: Dict[str, jax.Array]):
+    """What a layer scan takes as ``xs`` and what it closes over: ``(scanned,
+    experts)``.
+
+    For a MoE FFN under the grouped-GEMM dispatch, ``experts`` holds the three
+    expert stacks as ``[L*E, D, F]`` / ``[L*E, F, D]`` views of the stored
+    ``[L, E, ...]`` arrays (a merge of leading dimensions: no element moves)
+    and ``scanned`` is the tree without them. A scan that slices a stack per
+    layer hands ``ragged_dot`` a dynamic slice, which XLA:TPU does not fuse
+    into it: every layer of every step then copies its ``[E, D, F]`` out of
+    the stack before reading it (12.8 ms a Mixtral layer on v5e against 4.4;
+    PERF.md §6, PR 29). ``_mlp(..., experts=experts, layer=l)`` reads layer
+    ``l``'s experts where they lie instead.
+
+    A dense FFN and the "dense"/"capacity" dispatches are returned as they
+    came, ``experts`` None: the scan slices per layer as before (int8 storage
+    does not cover expert stacks: ``ModelConfig`` refuses it)."""
+    if c.num_experts == 0 or c.moe_dispatch not in ("auto", "ragged"):
+        return layers, None
+    scanned = {k: v for k, v in layers.items() if k not in _EXPERT_STACKS}
+    experts = {k: layers[k].reshape((-1,) + layers[k].shape[2:]) for k in _EXPERT_STACKS}
+    return scanned, experts
+
+
 def _moe_ragged(
-    x: jax.Array, lp: Dict[str, jax.Array], config: ModelConfig, valid: Optional[jax.Array] = None
+    x: jax.Array,
+    lp: Dict[str, jax.Array],
+    config: ModelConfig,
+    valid: Optional[jax.Array] = None,
+    experts: Optional[Dict[str, jax.Array]] = None,
+    layer=None,
 ) -> jax.Array:
     """Sparse dispatch via grouped GEMM (``lax.ragged_dot``): sort the T·K
     (token, expert) assignments by expert, run one ragged matmul per
@@ -268,10 +301,17 @@ def _moe_ragged(
     single shard or tp-sharded weights (the group axis cannot be partitioned
     over ``ep``; use "capacity" dispatch there).
 
+    The GEMMs' ``rhs`` is ``experts`` (``_split_expert_stacks``): the whole
+    ``[L*E, D, F]`` stack, with ``L*E`` group sizes that are zero outside
+    layer ``layer``'s ``[l*E, (l+1)*E)``. XLA:TPU's ``ragged-dot`` reads the
+    weights of non-empty groups only (measured: PERF.md §6, PR 29), so this
+    reads exactly the experts layer ``l`` visits, in place. Without
+    ``experts`` the rhs is ``lp``'s own ``[E, D, F]`` (a lone layer).
+
     ``valid`` masks padded rows (inactive decode lanes / prefill padding):
-    they are folded into expert 0's group (finite compute, bounded by bucket
+    they are folded into this layer's expert 0 — group ``l*E``, not group 0,
+    which is another layer's expert — (finite compute, bounded by bucket
     padding) and combined with weight 0."""
-    T = x.shape[0]
     E, K = config.num_experts, config.num_experts_per_tok
     weights, top_idx = _route(x, lp, K)
     flat_e = top_idx.reshape(-1)  # [T*K]
@@ -283,11 +323,15 @@ def _moe_ragged(
     order = jnp.argsort(flat_e)  # stable: expert-major, token order within
     tok = order // K  # source token per sorted row
     xs = x[tok]  # [T*K, D]
-    group_sizes = jnp.bincount(flat_e, length=E)  # [E]
-    g = lax.ragged_dot(xs, lp["w_gate"], group_sizes)
-    u = lax.ragged_dot(xs, lp["w_up"], group_sizes)
+    if experts is None:
+        experts, first = lp, 0
+    else:
+        first = layer * E  # this layer's expert 0 among the stack's groups
+    group_sizes = jnp.bincount(flat_e + first, length=experts["w_gate"].shape[0])  # [L*E] or [E]
+    g = lax.ragged_dot(xs, experts["w_gate"], group_sizes)
+    u = lax.ragged_dot(xs, experts["w_up"], group_sizes)
     h = jax.nn.silu(g) * u
-    y = lax.ragged_dot(h, lp["w_down"], group_sizes)  # [T*K, D]
+    y = lax.ragged_dot(h, experts["w_down"], group_sizes)  # [T*K, D]
     w_sorted = wflat[order].astype(x.dtype)
     return jnp.zeros_like(x).at[tok].add(y * w_sorted[:, None])
 
@@ -356,6 +400,8 @@ def _mlp(
     config: ModelConfig,
     valid: Optional[jax.Array] = None,
     stats: bool = False,
+    experts: Optional[Dict[str, jax.Array]] = None,
+    layer=None,
 ):
     """Feed-forward block: dense SwiGLU, or MoE when config.num_experts > 0.
 
@@ -372,6 +418,13 @@ def _mlp(
     tokens); sparse dispatch excludes dead rows so they cannot consume
     expert capacity meant for live tokens.
 
+    ``experts`` and ``layer`` are what a layer scan got from
+    ``_split_expert_stacks`` and its layer index: with them the grouped
+    GEMMs read layer ``layer``'s experts out of the whole ``[L*E, D, F]``
+    stacks, which ``lp`` then does not hold. Without them (a dense FFN,
+    "dense"/"capacity" dispatch, a lone layer's ``lp``) the FFN weights are
+    ``lp``'s own.
+
     With ``stats=True`` returns ``(out, dropped i32)`` — the number of live
     (token, expert) assignments dropped by capacity pressure this call
     (always 0 for exact dispatch modes)."""
@@ -385,7 +438,7 @@ def _mlp(
         out = _moe_dense(x, lp, config)
         return (out, jnp.int32(0)) if stats else out
     if mode == "ragged":
-        out = _moe_ragged(x, lp, config, valid)
+        out = _moe_ragged(x, lp, config, valid, experts, layer)
         return (out, jnp.int32(0)) if stats else out
     out, dropped = _moe_capacity(x, lp, config, valid)
     return (out, dropped) if stats else out
@@ -672,6 +725,8 @@ def prefill(
             (t_iq < valid_len).astype(jnp.int32),
         )
 
+    scanned, experts = _split_expert_stacks(c, params["layers"])
+
     def layer_fn(h, xs):
         lp, l = xs  # l: scalar layer index
         lp = dequant_layer(lp, wdtype)  # int8 weight-only storage
@@ -690,10 +745,10 @@ def prefill(
             h = h + attn.reshape(T, c.q_size) @ lp["wo"]
             x = _norm(c, h, lp["mlp_norm"], wdtype)
             if moe_stats:
-                mlp_out, drops = _mlp(x, lp, c, valid=valid_q, stats=True)
+                mlp_out, drops = _mlp(x, lp, c, valid=valid_q, stats=True, experts=experts, layer=l)
                 h = h + mlp_out
                 return h, (k, v, drops)
-            h = h + _mlp(x, lp, c, valid=valid_q)
+            h = h + _mlp(x, lp, c, valid=valid_q, experts=experts, layer=l)
             return h, (k, v)
 
         # Ragged chunk attention over [cached prefix ; chunk] — shared with
@@ -717,15 +772,15 @@ def prefill(
 
         x = _norm(c, h, lp["mlp_norm"], wdtype)
         if moe_stats:
-            mlp_out, drops = _mlp(x, lp, c, valid=valid_q, stats=True)
+            mlp_out, drops = _mlp(x, lp, c, valid=valid_q, stats=True, experts=experts, layer=l)
             h = h + mlp_out
             return h, (k, v, drops)
-        h = h + _mlp(x, lp, c, valid=valid_q)
+        h = h + _mlp(x, lp, c, valid=valid_q, experts=experts, layer=l)
         return h, (k, v)
 
     if moe_stats:
         h, (k_rows, v_rows, layer_drops) = lax.scan(
-            layer_fn, h, (params["layers"], jnp.arange(L, dtype=jnp.int32))
+            layer_fn, h, (scanned, jnp.arange(L, dtype=jnp.int32))
         )
         aux = {
             "moe_dropped": jnp.sum(layer_drops),
@@ -734,7 +789,7 @@ def prefill(
         }
     else:
         h, (k_rows, v_rows) = lax.scan(
-            layer_fn, h, (params["layers"], jnp.arange(L, dtype=jnp.int32))
+            layer_fn, h, (scanned, jnp.arange(L, dtype=jnp.int32))
         )
 
     # One all-layer scatter: [L, T] targets into the donated cache buffers.
@@ -1164,6 +1219,8 @@ def _decode_layer_scan_window(
             rows_i * (w + 1) + 1 + step, jnp.ones((B,), jnp.int32),
         )
 
+    scanned, experts = _split_expert_stacks(c, layers)
+
     def layer_fn(h, xs):
         if hoisted:
             lp, l, kwl, vwl, k_ctx, v_ctx = xs
@@ -1195,9 +1252,9 @@ def _decode_layer_scan_window(
             h = h + attn.reshape(B, c.q_size) @ lp["wo"]
             x = _norm(c, h, lp["mlp_norm"], wdtype)
             if moe_stats:
-                mlp_out, drops = _mlp(x, lp, c, valid=active, stats=True)
+                mlp_out, drops = _mlp(x, lp, c, valid=active, stats=True, experts=experts, layer=l)
                 return h + mlp_out, (k, v, drops)
-            h = h + _mlp(x, lp, c, valid=active)
+            h = h + _mlp(x, lp, c, valid=active, experts=experts, layer=l)
             return h, (k, v)
         if use_paged:
             m1, l1, acc1 = _paged_prefix_partials(
@@ -1220,12 +1277,12 @@ def _decode_layer_scan_window(
         h = h + attn.reshape(B, c.q_size) @ lp["wo"]
         x = _norm(c, h, lp["mlp_norm"], wdtype)
         if moe_stats:
-            mlp_out, drops = _mlp(x, lp, c, valid=active, stats=True)
+            mlp_out, drops = _mlp(x, lp, c, valid=active, stats=True, experts=experts, layer=l)
             return h + mlp_out, (k, v, drops)
-        h = h + _mlp(x, lp, c, valid=active)
+        h = h + _mlp(x, lp, c, valid=active, experts=experts, layer=l)
         return h, (k, v)
 
-    xs = (layers, jnp.arange(L, dtype=jnp.int32), k_win, v_win)
+    xs = (scanned, jnp.arange(L, dtype=jnp.int32), k_win, v_win)
     if hoisted:
         xs = xs + (k_ctx_all, v_ctx_all)
     if moe_stats:
@@ -1306,6 +1363,8 @@ def chunk_decode(
         acc = jnp.einsum("bkgqs,bskd->bkgqd", p.astype(vp.dtype), vp).astype(jnp.float32)
         return m, l, acc
 
+    scanned, experts = _split_expert_stacks(c, params["layers"])
+
     def layer_fn(h, xs):
         lp, l = xs
         lp = dequant_layer(lp, wdtype)  # int8 weight-only storage
@@ -1334,16 +1393,16 @@ def chunk_decode(
         x = _norm(c, h, lp["mlp_norm"], wdtype)
         valid_flat = (s_i[None, :] < valid[:, None]).reshape(B * S)
         if moe_stats:
-            mlp_out, drops = _mlp(x.reshape(B * S, -1), lp, c, valid=valid_flat, stats=True)
+            mlp_out, drops = _mlp(x.reshape(B * S, -1), lp, c, valid=valid_flat, stats=True, experts=experts, layer=l)
             h = h + mlp_out.reshape(B, S, -1)
             return h, (k, v, drops)
-        mlp_out = _mlp(x.reshape(B * S, -1), lp, c, valid=valid_flat).reshape(B, S, -1)
+        mlp_out = _mlp(x.reshape(B * S, -1), lp, c, valid=valid_flat, experts=experts, layer=l).reshape(B, S, -1)
         h = h + mlp_out
         return h, (k, v)
 
     if moe_stats:
         h, (k_rows, v_rows, layer_drops) = lax.scan(
-            layer_fn, h, (params["layers"], jnp.arange(L, dtype=jnp.int32))
+            layer_fn, h, (scanned, jnp.arange(L, dtype=jnp.int32))
         )
         chunk_aux = {
             "moe_dropped": jnp.sum(layer_drops),
@@ -1352,7 +1411,7 @@ def chunk_decode(
         }
     else:
         h, (k_rows, v_rows) = lax.scan(
-            layer_fn, h, (params["layers"], jnp.arange(L, dtype=jnp.int32))
+            layer_fn, h, (scanned, jnp.arange(L, dtype=jnp.int32))
         )
 
     # Fused scatter of all chunk rows: slot (b, s) → positions0[b]+s when
@@ -1484,6 +1543,8 @@ def mixed_step(
 
     from dynamo_tpu.engine.attention.ragged import ragged_chunk_attention
 
+    scanned, experts = _split_expert_stacks(c, params["layers"])
+
     def layer_fn(h, xs):
         lp, l = xs
         lp = dequant_layer(lp, wdtype)  # int8 weight-only storage
@@ -1503,9 +1564,9 @@ def mixed_step(
             h = h + attn @ lp["wo"]
             x = _norm(c, h, lp["mlp_norm"], wdtype)
             if moe_stats:
-                mlp_out, drops = _mlp(x, lp, c, valid=valid_all, stats=True)
+                mlp_out, drops = _mlp(x, lp, c, valid=valid_all, stats=True, experts=experts, layer=l)
                 return h + mlp_out, (k, v, drops)
-            h = h + _mlp(x, lp, c, valid=valid_all)
+            h = h + _mlp(x, lp, c, valid=valid_all, experts=experts, layer=l)
             return h, (k, v)
 
         # Chunk piece: [cached prefix ; chunk] — prefill's exact math.
@@ -1544,14 +1605,14 @@ def mixed_step(
         h = h + attn @ lp["wo"]
         x = _norm(c, h, lp["mlp_norm"], wdtype)
         if moe_stats:
-            mlp_out, drops = _mlp(x, lp, c, valid=valid_all, stats=True)
+            mlp_out, drops = _mlp(x, lp, c, valid=valid_all, stats=True, experts=experts, layer=l)
             return h + mlp_out, (k, v, drops)
-        h = h + _mlp(x, lp, c, valid=valid_all)
+        h = h + _mlp(x, lp, c, valid=valid_all, experts=experts, layer=l)
         return h, (k, v)
 
     if moe_stats:
         h, (k_rows, v_rows, layer_drops) = lax.scan(
-            layer_fn, h, (params["layers"], jnp.arange(L, dtype=jnp.int32))
+            layer_fn, h, (scanned, jnp.arange(L, dtype=jnp.int32))
         )
         aux = {
             "moe_dropped": jnp.sum(layer_drops),
@@ -1560,7 +1621,7 @@ def mixed_step(
         }
     else:
         h, (k_rows, v_rows) = lax.scan(
-            layer_fn, h, (params["layers"], jnp.arange(L, dtype=jnp.int32))
+            layer_fn, h, (scanned, jnp.arange(L, dtype=jnp.int32))
         )
 
     # ONE fused ragged scatter for chunk rows + decode rows together.
@@ -1642,7 +1703,10 @@ def embed(
     valid = positions < valid_len
     mask = (positions[None, :] <= positions[:, None]) & valid[None, :]
 
-    def layer_fn(h, lp):
+    scanned, experts = _split_expert_stacks(c, params["layers"])
+
+    def layer_fn(h, xs):
+        lp, l = xs
         lp = dequant_layer(lp, wdtype)  # int8 weight-only storage
         x = _norm(c, h, lp["attn_norm"], wdtype)
         q = apply_rope((x @ lp["wq"]).reshape(T, c.num_heads, c.head_dim), positions, c.rope_theta)
@@ -1651,10 +1715,10 @@ def embed(
         attn = _attend(q, k, v, mask, c)
         h = h + attn.reshape(T, c.q_size) @ lp["wo"]
         x = _norm(c, h, lp["mlp_norm"], wdtype)
-        h = h + _mlp(x, lp, c, valid=valid)
+        h = h + _mlp(x, lp, c, valid=valid, experts=experts, layer=l)
         return h, None
 
-    h, _ = lax.scan(layer_fn, h, params["layers"])
+    h, _ = lax.scan(layer_fn, h, (scanned, jnp.arange(c.num_layers, dtype=jnp.int32)))
     h = rms_norm(h, params["final_norm"], c.rms_norm_eps).astype(jnp.float32)
     weights = valid.astype(jnp.float32)[:, None]
     pooled = jnp.sum(h * weights, axis=0) / jnp.maximum(jnp.sum(weights), 1.0)
@@ -1739,6 +1803,8 @@ def decode_layer_scan(
             rows_i, prefix_lens, rows_i, rows_i + 1, jnp.ones((B,), jnp.int32)
         )
 
+    scanned, experts = _split_expert_stacks(c, layers)
+
     def layer_fn(h, xs):
         lp, l = xs  # l: scalar layer index within this stack
         lp = dequant_layer(lp, wdtype)  # int8 weight-only storage
@@ -1777,18 +1843,18 @@ def decode_layer_scan(
 
         x = _norm(c, h, lp["mlp_norm"], wdtype)
         if moe_stats:
-            mlp_out, drops = _mlp(x, lp, c, valid=active, stats=True)
+            mlp_out, drops = _mlp(x, lp, c, valid=active, stats=True, experts=experts, layer=l)
             return h + mlp_out, (k, v, drops)
-        h = h + _mlp(x, lp, c, valid=active)
+        h = h + _mlp(x, lp, c, valid=active, experts=experts, layer=l)
         return h, (k, v)
 
     if moe_stats:
         h, (k_rows, v_rows, layer_drops) = lax.scan(
-            layer_fn, h, (layers, jnp.arange(Lp, dtype=jnp.int32))
+            layer_fn, h, (scanned, jnp.arange(Lp, dtype=jnp.int32))
         )
         return h, k_rows, v_rows, jnp.sum(layer_drops)
     h, (k_rows, v_rows) = lax.scan(
-        layer_fn, h, (layers, jnp.arange(Lp, dtype=jnp.int32))
+        layer_fn, h, (scanned, jnp.arange(Lp, dtype=jnp.int32))
     )
     return h, k_rows, v_rows
 

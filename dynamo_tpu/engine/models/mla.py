@@ -31,7 +31,7 @@ from jax import lax
 
 from dynamo_tpu.engine.config import ModelConfig
 from dynamo_tpu.engine.kv_cache import layer_flat
-from dynamo_tpu.engine.models.llama import _gather_kv, _scatter_kv, _mlp, apply_rope, rms_norm
+from dynamo_tpu.engine.models.llama import _gather_kv, _scatter_kv, _mlp, _split_expert_stacks, apply_rope, rms_norm
 
 Params = Dict[str, jax.Array]
 
@@ -161,6 +161,7 @@ def prefill(
     mask = jnp.concatenate([prefix_mask, chunk_mask], axis=1)  # [T, ctx+T]
     N = k_cache.shape[1]
     k_flat = layer_flat(k_cache)
+    scanned, experts = _split_expert_stacks(c, params["layers"])
 
     def layer_fn(h, xs):
         lp, l = xs
@@ -173,11 +174,11 @@ def prefill(
         )
         h = h + attn @ lp["wo"]
         x = rms_norm(h, lp["mlp_norm"], c.rms_norm_eps)
-        h = h + _mlp(x, lp, c, valid=valid_q)
+        h = h + _mlp(x, lp, c, valid=valid_q, experts=experts, layer=l)
         return h, latent_new
 
     h, latent_rows = lax.scan(
-        layer_fn, h, (params["layers"], jnp.arange(c.num_layers, dtype=jnp.int32))
+        layer_fn, h, (scanned, jnp.arange(c.num_layers, dtype=jnp.int32))
     )
     L = c.num_layers
     layer_idx = jnp.broadcast_to(jnp.arange(L, dtype=jnp.int32)[:, None], (L, T))
@@ -219,6 +220,7 @@ def decode(
     # Layer-flat view: no per-layer cache slice in the scan (see prefill).
     N = k_cache.shape[1]
     k_flat = layer_flat(k_cache)
+    scanned, experts = _split_expert_stacks(c, params["layers"])
 
     def layer_fn(h, xs):
         lp, l = xs
@@ -234,11 +236,11 @@ def decode(
         )(q_eff, q_rope, latent_full, mask_full)  # [B, H*v]
         h = h + attn @ lp["wo"]
         x2 = rms_norm(h, lp["mlp_norm"], c.rms_norm_eps)
-        h = h + _mlp(x2, lp, c, valid=active)
+        h = h + _mlp(x2, lp, c, valid=active, experts=experts, layer=l)
         return h, latent_row
 
     h, latent_rows = lax.scan(
-        layer_fn, h, (params["layers"], jnp.arange(c.num_layers, dtype=jnp.int32))
+        layer_fn, h, (scanned, jnp.arange(c.num_layers, dtype=jnp.int32))
     )
     L = c.num_layers
     layer_idx = jnp.broadcast_to(jnp.arange(L, dtype=jnp.int32)[:, None], (L, B))
@@ -285,6 +287,7 @@ def decode_multi(
     k_flat = layer_flat(k_cache)
     key_pos = jnp.arange(ctx, dtype=jnp.int32)
     mask0 = key_pos[None, :] < positions[:, None]  # fixed: cache not written in-window
+    scanned, experts = _split_expert_stacks(c, params["layers"])
 
     def body(i, state):
         toks, lat_win, out, key = state
@@ -309,11 +312,11 @@ def decode_multi(
             )(q_eff, q_rope, latent_full, mask_full)
             h = h + attn @ lp["wo"]
             x2 = rms_norm(h, lp["mlp_norm"], c.rms_norm_eps)
-            h = h + _mlp(x2, lp, c, valid=active)
+            h = h + _mlp(x2, lp, c, valid=active, experts=experts, layer=l)
             return h, latent_row
 
         h, lat_rows = lax.scan(
-            layer_fn, h, (params["layers"], jnp.arange(L, dtype=jnp.int32), lat_win)
+            layer_fn, h, (scanned, jnp.arange(L, dtype=jnp.int32), lat_win)
         )
         lat_win = lat_win.at[:, i].set(lat_rows)
         h = rms_norm(h, params["final_norm"], c.rms_norm_eps)

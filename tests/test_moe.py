@@ -119,6 +119,81 @@ def test_moe_ragged_matches_dense_bf16():
     )
 
 
+CFG3 = CFG.replace(num_layers=3)  # three layers: a layer index can be wrong in two ways
+T3 = 12
+
+
+def _stacked(seed=0):
+    """A 3-layer ``tiny-moe`` tree as the step programs take it (``[L, E, D, F]``
+    stacks), what a layer scan makes of it, and rows to feed a layer."""
+    layers = llama.init_params(CFG3, jax.random.PRNGKey(seed), dtype=jnp.float32)["layers"]
+    scanned, experts = llama._split_expert_stacks(CFG3, layers)
+    x = jax.random.normal(jax.random.PRNGKey(seed + 1), (T3, CFG3.hidden_size), dtype=jnp.float32)
+    return layers, scanned, experts, x
+
+
+def _layer(tree, l):
+    return {k: v[l] for k, v in tree.items()}
+
+
+VALID3 = jnp.arange(T3) % 3 != 1  # masked rows among live ones, the first row live
+
+
+def test_split_expert_stacks_views_the_stored_tree():
+    layers, scanned, experts, _ = _stacked()
+    L, E = CFG3.num_layers, CFG3.num_experts
+    assert set(experts) == {"w_gate", "w_up", "w_down"} and not set(experts) & set(scanned)
+    assert set(scanned) | set(experts) == set(layers)
+    for k, w in experts.items():
+        assert w.shape == (L * E,) + layers[k].shape[2:]
+        np.testing.assert_array_equal(np.asarray(w[1 * E + 2]), np.asarray(layers[k][1, 2]))
+    # A dense FFN and a dispatch that keeps the per-layer slice come back as
+    # they went in: the scan slices them as before.
+    dense = llama.init_params(get_config("tiny"), jax.random.PRNGKey(0), dtype=jnp.float32)["layers"]
+    assert llama._split_expert_stacks(get_config("tiny"), dense) == (dense, None)
+    for mode in ("dense", "capacity"):
+        assert llama._split_expert_stacks(CFG3.replace(moe_dispatch=mode), layers) == (layers, None)
+
+
+@pytest.mark.parametrize("masked", [False, True], ids=["all-live", "masked-rows"])
+@pytest.mark.parametrize("l", range(CFG3.num_layers))
+def test_moe_stacked_layer_matches_dense(l, masked):
+    """Layer ``l``'s grouped GEMMs over the whole ``[L*E, D, F]`` stacks equal
+    ``_moe_dense`` on that layer's own weights."""
+    layers, scanned, experts, x = _stacked()
+    valid = VALID3 if masked else None
+    out = llama._mlp(x, _layer(scanned, l), CFG3, valid=valid, experts=experts, layer=jnp.int32(l))
+    ref = llama._moe_dense(x, _layer(layers, l), CFG3)
+    if masked:
+        np.testing.assert_array_equal(np.asarray(out)[~np.asarray(valid)], 0.0)
+        ref = jnp.where(valid[:, None], ref, 0.0)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=2e-5, atol=2e-5)
+    # The other layers' experts are not what was read: their outputs differ.
+    other = llama._moe_dense(x, _layer(layers, (l + 1) % CFG3.num_layers), CFG3)
+    assert not np.allclose(np.asarray(out), np.asarray(jnp.where(valid[:, None], other, 0.0) if masked else other), atol=1e-3)
+
+
+@pytest.mark.parametrize("l", [1, 2])
+def test_moe_masked_rows_land_in_their_own_layers_expert_0(l):
+    """Masked rows are folded into group ``l*E`` — this layer's expert 0 — and
+    not into group 0, which in the stack is LAYER 0's expert 0. Layer 0's
+    expert 0 is poisoned with weights at float32's maximum: a row multiplied
+    by them overflows, and its weight of 0 then makes it NaN. (NaN weights
+    would not tell on the CPU, whose ``ragged_dot`` multiplies every group's
+    weights by zeroed rows: 0 x NaN poisons every output whatever the group
+    sizes say, while 0 x max is 0.)"""
+    _, scanned, experts, x = _stacked()
+    poisoned = {k: w.at[0].set(jnp.finfo(jnp.float32).max) for k, w in experts.items()}
+    out = llama._mlp(x, _layer(scanned, l), CFG3, valid=VALID3, experts=poisoned, layer=jnp.int32(l))
+    assert np.isfinite(np.asarray(out)).all()
+    clean = llama._mlp(x, _layer(scanned, l), CFG3, valid=VALID3, experts=experts, layer=jnp.int32(l))
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(clean))
+    # The poison does tell where it is read: in layer 0 the masked rows (and
+    # the live ones routed there) do reach group 0.
+    hit = llama._mlp(x, _layer(scanned, 0), CFG3, valid=VALID3, experts=poisoned, layer=jnp.int32(0))
+    assert not np.isfinite(np.asarray(hit)).all()
+
+
 def test_moe_capacity_matches_dense_when_no_drops():
     # capacity_factor = E/K ⇒ C = T ⇒ no token can overflow.
     E, K = 8, 2
@@ -177,14 +252,26 @@ def test_moe_sparse_flops_scale_with_k_not_e():
     # test backend: its reference decomposition pads every group to the full
     # row range, reporting E-proportional flops; the TPU Mosaic grouped-GEMM
     # kernel computes true ragged row counts.)
+    # The same holds whatever the rhs: a lone layer's [E, D, F], or the whole
+    # [L*E, D, F] stack of a 3-layer tree read at layer 1 (the step programs'
+    # operands since PR 29) — L*E groups, still T*K rows.
+    L = 3
     for cfg_i, lp_i in ((cfg_small, lp_small), (cfg_big, lp_big)):
-        jaxpr = jax.make_jaxpr(lambda lp, x: llama._moe_ragged(x, lp, cfg_i))(lp_i, x)
-        ragged_eqns = [e for e in jaxpr.jaxpr.eqns if "ragged" in e.primitive.name]
-        assert len(ragged_eqns) == 3, "expected 3 grouped GEMMs (gate/up/down)"
-        for e in ragged_eqns:
-            assert e.invars[0].aval.shape[0] == T * K, (
-                f"expert GEMM rows must be T*K={T * K}, got {e.invars[0].aval.shape[0]}"
-            )
+        E = cfg_i.num_experts
+        stack = {k: jnp.concatenate([lp_i[k]] * L) for k in ("w_gate", "w_up", "w_down")}
+        router = {"router": lp_i["router"]}
+        for groups, fn in (
+            (E, lambda lp, x: llama._moe_ragged(x, lp, cfg_i)),
+            (L * E, lambda st, x: llama._moe_ragged(x, router, cfg_i, experts=st, layer=jnp.int32(1))),
+        ):
+            jaxpr = jax.make_jaxpr(fn)(lp_i if groups == E else stack, x)
+            ragged_eqns = [e for e in jaxpr.jaxpr.eqns if "ragged" in e.primitive.name]
+            assert len(ragged_eqns) == 3, "expected 3 grouped GEMMs (gate/up/down)"
+            for e in ragged_eqns:
+                assert e.invars[0].aval.shape[0] == T * K, (
+                    f"expert GEMM rows must be T*K={T * K}, got {e.invars[0].aval.shape[0]}"
+                )
+                assert e.invars[1].aval.shape[0] == groups == e.invars[2].aval.shape[0]
 
 
 def test_moe_capacity_flops_scale_with_k_not_e():

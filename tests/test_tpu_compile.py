@@ -254,6 +254,40 @@ def test_eva_step_programs_compile_and_hold_no_copy_of_the_pool(one_chip, on_tpu
     assert compiled.memory_analysis().temp_size_in_bytes < pool // 2
 
 
+# --- MoE at Mixtral-8x7B's widths, 3 layers: the expert stacks are read where they lie -----------
+
+MOE = get_config("mixtral-8x7b").replace(name="mixtral-d3", num_layers=3, block_size=128, max_seq_len=2048)
+
+
+@pytest.mark.parametrize("program", ["mixed_step", "decode_multi_w8"])
+def test_moe_step_programs_hold_no_copy_of_an_expert_stack(one_chip, on_tpu, program):
+    """One layer of one expert stack is 8 x 4096 x 14336 bf16 = 0.94 GB. A
+    layer scan that slices the stacks copies it before ``ragged-dot`` reads
+    it (XLA:TPU fuses no slice into that operation): 0.97-1.08 GB of
+    temporaries in these programs before PR 29, 0.03-0.14 GB since
+    (PERF.md section 6, PR 29)."""
+    shapes = jax.eval_shape(lambda: llama.init_params(MOE, jax.random.PRNGKey(0), dtype=BF16))
+    p = jax.tree.map(lambda s: _sds(s.shape, s.dtype, one_chip), shapes)
+    k = v = _sds((MOE.num_layers, 512, MOE.block_size, MOE.kv_size), BF16, one_chip)
+    i32, sh = jnp.int32, one_chip
+    S, Bd, Wd = 256, 32, 16
+    if program == "mixed_step":
+        fn = lambda p, k, v, pt, pv, cl, ptab, dt, dpos, dtab, dact: llama.mixed_step(  # noqa: E731
+            p, MOE, k, v, pt, pv, cl, ptab, dt, dpos, dtab, dact, use_flash=True, has_prefix=True)
+        args = (_sds((S,), i32, sh), _sds((), i32, sh), _sds((), i32, sh), _sds((Wd,), i32, sh), _sds((Bd,), i32, sh),
+                _sds((Bd,), i32, sh), _sds((Bd, Wd), i32, sh), _sds((Bd,), jnp.bool_, sh))
+    else:
+        fn = lambda p, k, v, t, pos, bt, act, te, tk, tp, key: llama.decode_multi(  # noqa: E731
+            p, MOE, k, v, t, pos, bt, act, te, tk, tp, key, 8)
+        args = (_sds((Bd,), i32, sh), _sds((Bd,), i32, sh), _sds((Bd, Wd), i32, sh), _sds((Bd,), jnp.bool_, sh),
+                _sds((Bd,), jnp.float32, sh), _sds((Bd,), i32, sh), _sds((Bd,), jnp.float32, sh), _sds((2,), jnp.uint32, sh))
+    compiled = jax.jit(fn, donate_argnums=(1, 2)).lower(p, k, v, *args).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "ragged-dot" in text
+    one_layer = MOE.num_experts * MOE.hidden_size * MOE.intermediate_size * 2
+    assert compiled.memory_analysis().temp_size_in_bytes < one_layer // 4
+
+
 def test_decode_sample_compiles(one_chip, on_tpu):
     p, k, v = _one_chip_args(one_chip)
     io = _decode_io(one_chip)
