@@ -149,9 +149,8 @@ def _pallas_dispatch_overhead_ms(n: int = 32) -> float:
 
 def _decode_attention_cpu_parity() -> dict:
     """CPU half of the decode_attention section (interpreter-mode Pallas):
-    megakernel vs gather GREEDY TOKEN PARITY through the real scheduler and
-    the one-launch-per-decode-window invariant — the structural guarantees
-    CI gates on where no HBM roofline exists."""
+    megakernel vs gather GREEDY TOKEN PARITY through the real scheduler —
+    the structural guarantee CI gates on where no HBM roofline exists."""
     import jax
     import jax.numpy as jnp
 
@@ -189,18 +188,14 @@ def _decode_attention_cpu_parity() -> dict:
     s_m, t_m, rate_m = run("megakernel")
     s_g, t_g, rate_g = run("gather")
     parity = t_m == t_g
-    launches = s_m.flight.fused_window_pallas_launches
     assert parity, "megakernel/gather greedy token streams diverged"
-    assert launches == 1, f"fused decode window traced {launches} pallas launches"
     return {
         "cpu_parity_mode": True,
         "token_parity": parity,
-        "fused_windows": s_m.flight.fused_windows_total,
-        "fused_window_pallas_launches": launches,
         "tok_s_megakernel_interp": rate_m,
         "tok_s_gather": rate_g,
-        "note": "CPU: interpreter-mode Pallas — structural asserts (token "
-                "parity, 1 launch/window), not speed. TPU rounds report "
+        "note": "CPU: interpreter-mode Pallas — a structural assert (token "
+                "parity), not speed. TPU rounds report "
                 "tok/s + pct_hbm_roofline per impl.",
     }
 
@@ -212,7 +207,7 @@ def bench_decode_attention(cfg=None, params=None, ctx_len=1024, hbm_gbps=None):
     bench_decode_impl,profile_decode,profile_decode_split}.py into a
     standing BENCH_r* section so the roofline fraction is tracked every
     round instead of living in one-off tool runs. On CPU it degrades to
-    the parity + one-launch-per-window asserts (CI)."""
+    the parity assert (CI)."""
     import jax
 
     if jax.default_backend() != "tpu":
@@ -257,115 +252,8 @@ def bench_decode_attention(cfg=None, params=None, ctx_len=1024, hbm_gbps=None):
         "points": points,
         "pallas_dispatch_ms_per_launch": round(_pallas_dispatch_overhead_ms(), 3),
         "note": "dispatch overhead is per pallas_call on THIS runtime — the "
-                "megakernel pays it once per layer (and once per WINDOW on "
-                "the fused path), the r4 design paid it per piece.",
-    }
-
-
-def bench_fused_sampling():
-    """Fused in-kernel sampling + spec window section
-    (BENCH_FUSED_SAMPLE_ONLY): at b∈{8, 32}, sampled-fused (megakernel
-    window with the in-kernel top-k/top-p epilogue) vs sampled-multi (the
-    sync ``decode_multi`` window) tok/s, plus the fused spec window's
-    accepted-tokens/step. On CPU (interpreter-mode Pallas, CI) the numbers
-    are structural, not speed — the section's value there is the asserts:
-    sampled windows actually dispatch, the launch gauge holds 1 across
-    every fused variant, spec parity holds, and ≥2 tokens confirm per
-    spec round."""
-    import jax
-    import jax.numpy as jnp
-
-    from dynamo_tpu.engine.config import get_config
-    from dynamo_tpu.engine.models import llama
-    from dynamo_tpu.engine.sampling import SamplingParams
-    from dynamo_tpu.engine.scheduler import Scheduler, SchedulerConfig, StopConditions
-
-    cfg = get_config("tiny")
-    params = llama.init_params(cfg, jax.random.PRNGKey(0), dtype=jnp.float32)
-    on_cpu = jax.default_backend() != "tpu"
-
-    def run(impl: str, batch: int, *, draft: bool, greedy: bool = False,
-            max_tokens: int = 12):
-        sched = Scheduler(cfg.replace(attention_impl=impl), params, SchedulerConfig(
-            num_blocks=4 * batch + 32, max_running=batch,
-            prefill_buckets=[32], decode_buckets=[batch],
-            num_scheduler_steps=8, enable_prefix_caching=False,
-            enable_overlap_decode=False, enable_mixed_batching=False,
-        ), dtype=jnp.float32)
-        if draft:
-            sched.attach_draft(cfg, params, gamma=2)
-        sched.warmup(ctx_tokens=64)
-        sched.flight.mark_warmup_done(warmed=True)
-        toks: dict = {}
-        for i in range(batch):
-            sp = (SamplingParams(temperature=0.0) if draft or greedy else
-                  SamplingParams(temperature=0.8, top_k=20, top_p=0.9, seed=7 + i))
-            sched.add_request(f"r{i}", list(range(1 + i % 8, 25 + i % 8)), sp,
-                              StopConditions(max_tokens=max_tokens, ignore_eos=True))
-        t0 = time.perf_counter()
-        steps = 0
-        for _ in range(400):
-            if not sched.has_work():
-                break
-            sched_out = sched.step()
-            steps += 1
-            for s, o in sched_out:
-                if o.token_id >= 0:
-                    toks.setdefault(s.request_id, []).append(o.token_id)
-        wall = time.perf_counter() - t0
-        n = sum(len(v) for v in toks.values())
-        assert n == batch * max_tokens, f"{impl} b{batch}: {n} tokens"
-        assert sched.flight.compiles_after_warmup_total == 0, (
-            f"post-warmup compiles: {sched.flight.post_warmup_keys}"
-        )
-        return sched, toks, round(n / max(wall, 1e-9), 1)
-
-    points = []
-    for batch in (8, 32):
-        s_f, t_f, rate_f = run("megakernel", batch, draft=False)
-        s_m, t_m, rate_m = run("gather", batch, draft=False)
-        assert s_f.flight.fused_sampled_windows_total > 0, (
-            "sampled traffic never reached the fused window"
-        )
-        launches = s_f.flight.fused_window_pallas_launches
-        assert launches == 1, (
-            f"fused sampled window traced {launches} pallas launches"
-        )
-        # Same request seeds through the fused epilogue and the sync
-        # sampler draw from the same (seed, position) threefry keys — the
-        # streams only agree where both paths consume identical uniforms,
-        # so cross-path we assert shape, and the parity tests
-        # (tests/test_megakernel.py) pin bit-identity per path.
-        row = {
-            "batch": batch,
-            "tok_s_sampled_fused": rate_f,
-            "tok_s_sampled_multi": rate_m,
-            "fused_sampled_windows": s_f.flight.fused_sampled_windows_total,
-            "fused_vs_multi": round(rate_f / max(rate_m, 1e-9), 3),
-        }
-
-        s_s, t_s, rate_s = run("megakernel", batch, draft=True)
-        assert s_s._use_fused_spec, "fused spec gate must engage"
-        assert s_s.flight.spec_fused_windows_total > 0
-        st = s_s.spec_stats.to_dict()
-        assert st["accepted_per_round"] >= 2.0, st
-        # Lossless-speculation gate: greedy through the fused spec window
-        # must emit the exact token stream plain greedy decoding does.
-        _, t_gold, _ = run("gather", batch, draft=False, greedy=True)
-        assert t_s == t_gold, "fused spec diverged from plain greedy"
-        row["tok_s_spec_fused"] = rate_s
-        row["spec_accepted_per_round"] = st["accepted_per_round"]
-        row["spec_fused_windows"] = s_s.flight.spec_fused_windows_total
-        points.append(row)
-
-    return {
-        "cpu_parity_mode": on_cpu,
-        "points": points,
-        "fused_window_pallas_launches": 1,
-        "note": "CPU: interpreter-mode Pallas — structural asserts "
-                "(sampled windows dispatch, 1 launch/window across all "
-                "fused variants, >=2 accepted tokens/spec round), not "
-                "speed. TPU rounds report the real tok/s deltas.",
+                "megakernel pays it once per layer, the r4 design paid it "
+                "per piece.",
     }
 
 
@@ -1477,8 +1365,8 @@ def bench_device_truth():
     path where the answer is known. Asserts the round trip: the parser's
     per-lane interval union recovers the busy time, the flight recorder's
     ``measured_mfu`` lands on the modeled MFU, ``measured_modeled_mfu_ratio``
-    sits at 1.0 within tolerance, and the fused-window launch count
-    cross-checks to exactly 1 launch per window from TRACE events. A live
+    sits at 1.0 within tolerance, and the parser counts the attention
+    kernel's launches from TRACE events. A live
     ``jax.profiler`` window against real device work rides along
     best-effort (real traces vary by backend; reported, not asserted)."""
     import jax
@@ -1520,7 +1408,7 @@ def bench_device_truth():
     drive("run")
 
     flight = sched.flight
-    flops, bytes_moved, secs, fused = flight.roofline_totals()
+    flops, bytes_moved, secs = flight.roofline_totals()
     assert secs > 0 and flops > 0, "no modeled roofline accumulated"
     modeled_stats = flight.to_stats()
     peak_flops = flight.cost_model.peak_flops
@@ -1529,11 +1417,11 @@ def bench_device_truth():
     modeled_hbm = bytes_moved / secs / peak_bw
 
     # --- fixture path: a synthetic trace whose device lane is busy for
-    # exactly the modeled step seconds, with one fused-window launch per
-    # dispatched window. The parser must recover all of it.
+    # exactly the modeled step seconds, most of it in a few launches of the
+    # attention kernel. The parser must recover all of it.
     busy_us = secs * 1e6
-    fused_n = max(int(fused), 1)
-    fused_us = busy_us * 0.6 / fused_n
+    attn_n = 4
+    attn_us = busy_us * 0.6 / attn_n
     events = [
         {"ph": "M", "pid": 7, "name": "process_name",
          "args": {"name": "/device:TPU:0 (fixture)"}},
@@ -1546,12 +1434,12 @@ def bench_device_truth():
          "ts": 0.0, "dur": busy_us * 10},
     ]
     t = 0.0
-    for _ in range(fused_n):
+    for _ in range(attn_n):
         events.append({"ph": "X", "pid": 7, "tid": 1,
-                       "name": "fused_decode_window(steps=8)",
-                       "ts": t, "dur": fused_us})
-        t += fused_us + 3.0  # gaps: the union must not bridge them
-    other_us = busy_us - fused_us * fused_n
+                       "name": "ragged_paged_attention(layer)",
+                       "ts": t, "dur": attn_us})
+        t += attn_us + 3.0  # gaps: the union must not bridge them
+    other_us = busy_us - attn_us * attn_n
     events.append({"ph": "X", "pid": 7, "tid": 1, "name": "fusion.sample_rows",
                    "ts": t, "dur": other_us})
     summary = parse_trace_events(events)
@@ -1559,8 +1447,8 @@ def bench_device_truth():
     assert abs(summary.device_time_us - busy_us) <= max(1.0, busy_us * 1e-6), (
         f"interval union lost time: {summary.device_time_us} vs {busy_us}"
     )
-    launches = summary.launch_count("fused_decode_window")
-    assert launches == fused_n, f"launch count {launches} != {fused_n}"
+    launches = summary.launch_count("ragged_paged_attention")
+    assert launches == attn_n, f"launch count {launches} != {attn_n}"
 
     record = {
         "status": "ok",
@@ -1573,9 +1461,6 @@ def bench_device_truth():
         "truncated": summary.truncated,
         "top_kernels": summary.top(4),
         "top_kernel_share": summary.top_share(),
-        "fused_windows": fused_n,
-        "fused_kernel_launches": launches,
-        "launches_per_fused_window": launches / fused_n,
     }
     flight.record_measured_window(record)
     stats = flight.to_stats()
@@ -1590,9 +1475,6 @@ def bench_device_truth():
     )
     assert mfu_rel_err <= 0.05, (
         f"measured_mfu {measured_mfu} vs modeled {modeled_mfu}: {mfu_rel_err:.3%}"
-    )
-    assert stats["measured_launches_per_fused_window"] == 1.0, (
-        "fused-window launch invariant broken on the trace path"
     )
     assert stats["measured_windows_total"] == 1
 
@@ -1646,8 +1528,6 @@ def bench_device_truth():
             "measured_hbm_frac": stats["measured_hbm_frac"],
             "measured_device_frac": stats["measured_device_frac"],
             "measured_top_kernel_share": stats["measured_top_kernel_share"],
-            "measured_launches_per_fused_window":
-                stats["measured_launches_per_fused_window"],
             "device_seconds": round(summary.device_time_us / 1e6, 6),
         },
         "agreement": {
@@ -1660,8 +1540,7 @@ def bench_device_truth():
         "fixture": {
             "kernel_events": summary.kernel_events,
             "device_lanes": summary.device_lanes,
-            "fused_windows": fused_n,
-            "fused_launches": launches,
+            "attention_launches": launches,
         },
         "live_capture": live_report,
         "note": "fixture path is the asserted ground truth (CPU CI); the "
@@ -2176,25 +2055,6 @@ def child_main() -> None:
     else:
         errors.append("guided_overhead skipped: budget")
 
-    # --- fused in-kernel sampling + spec window (CPU subprocess) ------------
-    fused_sampling = None
-    if remaining() > 60:
-        try:
-            fused_sampling, err = _run_cpu_subprocess(
-                [sys.executable, os.path.abspath(__file__)], "points",
-                max(60, remaining() - 10), extra_env={"BENCH_FUSED_SAMPLE_ONLY": "1"},
-            )
-            if fused_sampling is None:
-                errors.append(f"fused_sampling: {err}")
-            else:
-                _emit_partial("fused_sampling", fused_sampling)
-        except subprocess.TimeoutExpired:
-            errors.append("fused_sampling: subprocess timed out")
-        except Exception as e:  # noqa: BLE001
-            errors.append(f"fused_sampling: {type(e).__name__}: {e}")
-    else:
-        errors.append("fused_sampling skipped: budget")
-
     # --- closed-loop autoscaling (traffic harness, CPU subprocess) ----------
     autoscale = None
     if remaining() > 60:
@@ -2242,12 +2102,11 @@ def child_main() -> None:
                               decode_overlap=decode_overlap,
                               prefix_reuse=prefix_reuse,
                               decode_attention=decode_attention,
-                              fused_sampling=fused_sampling,
                               autoscale=autoscale, elastic=elastic,
                               device_truth=device_truth)), flush=True)
 
 
-def assemble(decode_points, prefill_detail, http, device, model, errors, tpu_http=None, router_prefix=None, large_model=None, mixed_admission=None, observability=None, guided_overhead=None, decode_overlap=None, prefix_reuse=None, decode_attention=None, fused_sampling=None, autoscale=None, elastic=None, device_truth=None) -> dict:
+def assemble(decode_points, prefill_detail, http, device, model, errors, tpu_http=None, router_prefix=None, large_model=None, mixed_admission=None, observability=None, guided_overhead=None, decode_overlap=None, prefix_reuse=None, decode_attention=None, autoscale=None, elastic=None, device_truth=None) -> dict:
     """Build the final JSON object from whatever sections completed."""
     hbm_gbps, _ = chip_peaks(device)
     best = max(decode_points, key=lambda p: p.get("achieved_hbm_gbps") or 0.0) if decode_points else None
@@ -2267,7 +2126,6 @@ def assemble(decode_points, prefill_detail, http, device, model, errors, tpu_htt
         "detail": {
             "decode_sweep": decode_points,
             "decode_attention": decode_attention,
-            "fused_sampling": fused_sampling,
             "prefill": prefill_detail,
             "tpu_http_e2e": tpu_http,
             "http_e2e": http,
@@ -2295,10 +2153,8 @@ def assemble(decode_points, prefill_detail, http, device, model, errors, tpu_htt
                           "(attention/megakernel.py): one pallas launch per layer "
                           "serves the whole mixed step's ragged batch (chunk rows + "
                           "length-1 decode rows, GQA fold, scalar-prefetched tables, "
-                          "pl.when-skipped dead slots, int8 dequant-in-VMEM), and "
-                          "greedy decode windows fuse into ONE launch "
-                          "(decode_multi_fused, grid = steps x layers, on-chip token "
-                          "feedback) where the working set fits VMEM. Off-TPU: XLA "
+                          "pl.when-skipped dead slots, int8 dequant-in-VMEM). "
+                          "Off-TPU: XLA "
                           "width-bucketed gather (pow2 + 1.5*pow2 rungs, two-piece "
                           "online-softmax, once-per-window hoist; r5: b32 28.5% -> "
                           "~54% HBM roofline — the 3x gather traffic the megakernel "
@@ -2377,7 +2233,6 @@ def main() -> None:
             decode_overlap=partials.get("decode_overlap"),
             prefix_reuse=partials.get("prefix_reuse"),
             decode_attention=partials.get("decode_attention"),
-            fused_sampling=partials.get("fused_sampling"),
             autoscale=partials.get("autoscale"),
         )
     final["detail"]["errors"] = errors + final["detail"].get("errors", [])
@@ -2387,19 +2242,10 @@ def main() -> None:
 
 if __name__ == "__main__":
     if os.environ.get("BENCH_DECODE_ATTN_ONLY") == "1":
-        # Standalone decode_attention section (CI uses this on CPU: token
-        # parity + one-launch-per-window asserts; on TPU it reports the
-        # gather vs megakernel roofline sweep).
+        # Standalone decode_attention section (CI uses this on CPU: the token
+        # parity assert; on TPU it reports the gather vs megakernel roofline
+        # sweep).
         print(json.dumps(bench_decode_attention()), flush=True)
-    elif os.environ.get("BENCH_FUSED_SAMPLE_ONLY") == "1":
-        # CPU-pinned in CI: the subject is the fused window's in-kernel
-        # sampling epilogue + spec variant (structure + counters), not
-        # device speed — TPU rounds run it for the real tok/s deltas.
-        import jax
-
-        if jax.default_backend() != "tpu":
-            jax.config.update("jax_platforms", "cpu")
-        print(json.dumps(bench_fused_sampling()), flush=True)
     elif os.environ.get("BENCH_PREFIX_ONLY") == "1":
         # CPU-pinned: the subject is skipped prefill FLOPs vs recompute in
         # the real scheduler, not device speed.

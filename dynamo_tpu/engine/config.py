@@ -81,10 +81,7 @@ class ModelConfig:
     #   block-diagonal GQA fold, pl.when-skipped dead slots, and an int8-KV
     #   dequant-in-VMEM path. Amortizes the launches of the r4/r5
     #   per-piece kernels: 1 launch/layer/step regardless of
-    #   batch composition (vs 2+ for chunk+decode kernels), and
-    #   decode_multi_fused collapses a whole greedy decode window into ONE
-    #   launch (grid = steps × layers, on-chip token feedback) where the
-    #   working set fits VMEM (megakernel.fused_window_fits).
+    #   batch composition (vs 2+ for chunk+decode kernels).
     # - "paged": the r5 per-piece Pallas paged flash-decode kernel
     #   (attention/decode.py) — correct (interpret-mode parity tests) but
     #   NEVER auto-selected: it issues 2+ launches per layer where the

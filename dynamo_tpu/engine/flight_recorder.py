@@ -208,9 +208,7 @@ class StepCostModel:
         ``param_passes``: how many times the dispatch streams the parameter
         set from HBM — 1 for single steps, ``num_steps`` for a
         ``decode_multi`` window (the fori_loop re-reads weights every
-        step), and 1 again for the fused megakernel window (weights are
-        VMEM-resident for the whole window; that is the launch-amortization
-        win the gauge must show)."""
+        step)."""
         flops = self.flops_per_token * tokens
         bytes_moved = (
             # (0 passes: a program that reads no weights, as a roll of KV rows)
@@ -279,22 +277,9 @@ class FlightRecorder:
         self.last_step_ts: Optional[float] = None
         # Decode host gap: time from a decode dispatch RETURNING (device
         # launched, host free) to the NEXT decode dispatch being issued —
-        # the bubble the overlap pipeline exists to close. Only consecutive
+        # the bubble the device spends waiting on Python. Only consecutive
         # decode-family dispatches are measured (phase changes reset it).
         self._gap = _PhaseHist(GAP_BUCKETS)
-        # Fused decode-window launch accounting: the number of pallas_call
-        # sites traced into ONE fused-window executable (must be exactly 1 —
-        # the whole point of the megakernel window is one launch per window;
-        # CI asserts it) and how many fused windows have been dispatched.
-        self.fused_window_pallas_launches: Optional[int] = None
-        self.fused_windows_total = 0
-        # In-kernel sampling + fused speculation: windows whose epilogue
-        # sampled on-chip (uniforms operand), and whole draft+verify spec
-        # windows with their accepted-token yield — the bench/Grafana
-        # accepted-tokens-per-window signal.
-        self.fused_sampled_windows_total = 0
-        self.spec_fused_windows_total = 0
-        self.spec_fused_accepted_tokens_total = 0
         # Compile tracker state.
         self._exec_keys: Set[tuple] = set()
         self.compiles_total = 0
@@ -314,9 +299,8 @@ class FlightRecorder:
         self._measured_last: Optional[dict] = None
 
     # --- measured device truth ----------------------------------------------
-    def roofline_totals(self) -> Tuple[float, float, float, int]:
-        """Cumulative (flops, bytes, modeled step seconds, fused windows)
-        across every phase — the ContinuousProfiler's cost probe. Deltas of
+    def roofline_totals(self) -> Tuple[float, float, float]:
+        """Cumulative (flops, bytes, modeled step seconds) across every phase — the ContinuousProfiler's cost probe. Deltas of
         this across a profile window attribute measured device time to the
         modeled work done in the same span."""
         f = b = s = 0.0
@@ -324,15 +308,14 @@ class FlightRecorder:
             f += r.flops_total
             b += r.bytes_total
             s += r.secs_total
-        return f, b, s, self.fused_windows_total
+        return f, b, s
 
     def record_measured_window(self, record: dict) -> None:
         """Fold one profile window's measured truth into the recorder.
 
         ``record`` is the ContinuousProfiler's per-window dict (or a bench
         fixture shaped the same): wall_s, device_time_s, flops, bytes,
-        step_seconds, top_kernels, top_kernel_share,
-        launches_per_fused_window. Derived gauges:
+        step_seconds, top_kernels, top_kernel_share. Derived gauges:
 
         - ``measured_mfu`` / ``measured_hbm_frac``: modeled work ÷ MEASURED
           device-busy time ÷ peak — the measured sibling of ``mfu_*``.
@@ -363,10 +346,6 @@ class FlightRecorder:
             "measured_modeled_mfu_ratio": round(ratio, 6),
             "measured_top_kernel_share": round(
                 float(record.get("top_kernel_share", 0.0)), 6
-            ),
-            "measured_launches_per_fused_window": (
-                round(float(record["launches_per_fused_window"]), 6)
-                if record.get("launches_per_fused_window") is not None else 0.0
             ),
             "top_kernels": record.get("top_kernels", []),
         }
@@ -465,15 +444,6 @@ class FlightRecorder:
         if decode_tokens > 0:
             self._record_roofline("decode", f_d, b_d, dur_s * (1.0 - share_p))
 
-    def record_window_launches(self, n: int) -> None:
-        """Pallas launch sites traced into one fused decode-window
-        executable (megakernel.trace_launch_count delta across its first
-        trace). Exported as the ``fused_window_pallas_launches`` gauge; CI
-        asserts == 1 so dispatch-amortization regressions — someone
-        un-fusing the window back into per-step or per-piece kernels —
-        fail loudly instead of silently re-losing to overhead."""
-        self.fused_window_pallas_launches = int(n)
-
     def utilization(self) -> Dict[str, Tuple[float, float]]:
         """{phase: (mfu, hbm_roofline_fraction)} over the recent-step
         window; empty without a cost model."""
@@ -544,18 +514,6 @@ class FlightRecorder:
             "decode_host_gap_events_total": self._gap.total,
             "decode_host_gap_seconds_total": round(self._gap.sum_s, 6),
         }
-        if self.fused_windows_total or self.fused_window_pallas_launches is not None:
-            out["fused_windows_total"] = self.fused_windows_total
-            out["fused_sampled_windows_total"] = self.fused_sampled_windows_total
-            out["fused_window_pallas_launches"] = (
-                self.fused_window_pallas_launches
-                if self.fused_window_pallas_launches is not None else 0
-            )
-        if self.spec_fused_windows_total:
-            out["spec_fused_windows_total"] = self.spec_fused_windows_total
-            out["spec_fused_accepted_tokens_total"] = (
-                self.spec_fused_accepted_tokens_total
-            )
         for phase, h in self._hists.items():
             if not h.total and phase not in ("prefill", "decode", "mixed"):
                 continue  # wave/spec only when the path is exercised
@@ -582,7 +540,6 @@ class FlightRecorder:
             for key in (
                 "measured_mfu", "measured_hbm_frac", "measured_device_frac",
                 "measured_modeled_mfu_ratio", "measured_top_kernel_share",
-                "measured_launches_per_fused_window",
             ):
                 out[key] = last.get(key, 0.0)
         return out

@@ -819,7 +819,6 @@ def decode_multi(
     num_steps: int,
     moe_stats: bool = False,  # static: also return {"moe_dropped", "moe_assignments"}
     return_logits: bool = False,  # static: also return per-step logits [steps, B, V]
-    uniforms: Optional[jax.Array] = None,  # [num_steps, B] — inverse-CDF draws
 ) -> Tuple[jax.Array, ...]:
     """``num_steps`` autoregressive decode steps + on-device sampling in ONE
     compiled dispatch. Returns (tokens_out [num_steps, B], k_cache, v_cache).
@@ -839,19 +838,13 @@ def decode_multi(
     indexed writes inside a while body — measured ~0.9 ms/step/tensor at 1B
     scale on v5e, dominating the step); the window carry is KV-row-sized, so
     the per-step write cost is proportional to tokens produced, not cache
-    size.
-
-    With ``uniforms`` ([num_steps, B] from sampling.make_window_uniforms)
-    each step samples via the shared reference filter + inverse-CDF draw
-    instead of the threaded PRNG key — the uniforms contract the fused
-    megakernel window uses, so this path is the bit-identical host replay
-    the sampled-fused parity tests compare against."""
+    size."""
     if moe_stats and return_logits:
         raise NotImplementedError(
             "decode_multi: moe_stats and return_logits cannot be combined yet "
             "(the return tuples would be ambiguous to existing unpackers)"
         )
-    from dynamo_tpu.engine.sampling import sample_batch, sample_from_uniforms
+    from dynamo_tpu.engine.sampling import sample_batch
 
     c = config
     B = tokens.shape[0]
@@ -900,12 +893,7 @@ def decode_multi(
         v_win = v_win.at[:, i].set(v_rows)
         logits = _logits(c, params, h, wdtype)
         key, sub = jax.random.split(key)
-        if uniforms is not None:
-            nxt = sample_from_uniforms(
-                logits, temps, top_ks, top_ps, uniforms[i]
-            ).astype(jnp.int32)
-        else:
-            nxt = sample_batch(logits, temps, top_ks, top_ps, sub).astype(jnp.int32)
+        nxt = sample_batch(logits, temps, top_ks, top_ps, sub).astype(jnp.int32)
         out = out.at[i].set(nxt)
         if return_logits:
             lg_out = lg_out.at[i].set(logits)
@@ -1023,125 +1011,6 @@ def eva_roll(
     return (
         _scatter_kv(k_cache, layer_idx, blocks, offs, k_bar),
         _scatter_kv(v_cache, layer_idx, blocks, offs, v_bar),
-    )
-
-
-def decode_multi_fused(
-    params: Params,
-    config: ModelConfig,
-    k_cache: jax.Array,  # [L, N, BS, KVH*HD]
-    v_cache: jax.Array,
-    tokens: jax.Array,  # [B] current token per sequence
-    positions: jax.Array,  # [B] write slot of the current token
-    block_tables: jax.Array,  # [B, W] — must cover positions+num_steps
-    active: jax.Array,  # [B] bool
-    num_steps: int,
-    temps: Optional[jax.Array] = None,  # [B] f32 (with sampled=True)
-    top_ks: Optional[jax.Array] = None,  # [B] i32
-    top_ps: Optional[jax.Array] = None,  # [B] f32
-    uniforms: Optional[jax.Array] = None,  # [num_steps, B] f32
-    guided_rows: Optional[jax.Array] = None,  # [B] i32 (with guided=True)
-    mask_pool: Optional[jax.Array] = None,  # [P, ceil(V/32)] uint32
-    next_pool: Optional[jax.Array] = None,  # [P, V] i32
-    sampled: bool = False,
-    guided: bool = False,
-) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """``num_steps`` decode steps in ONE Pallas launch — the fused window
-    megakernel (attention/megakernel.fused_decode_window). The grid
-    spans (steps × layers); the sampled token feeds back through on-chip
-    scratch between grid steps and KV rows are written in place, so the
-    per-``pallas_call`` dispatch tax that killed the r4 kernel is paid
-    once per WINDOW instead of ``num_steps × num_layers`` times, and the
-    prefix pages are the only KV bytes read. Token-for-token and
-    cache-content parity with greedy ``decode_multi`` (tested).
-
-    ``sampled=True`` runs the in-kernel top-k/top-p epilogue against
-    host-precomputed uniforms; ``guided=True`` masks each row by its FSM
-    state's packed allow bitmask and advances the FSM on-chip via the
-    next-state pool. Dense llama only (no MoE, no int8 weights) — callers
-    gate via ``megakernel.fused_window_fits`` and fall back to
-    ``decode_multi`` (whose attention still runs the per-step ragged
-    megakernel)."""
-    from dynamo_tpu.engine.attention.megakernel import fused_decode_window
-
-    c = config
-    _refuse_eva(c, "decode_multi_fused (the fused window keeps one row a token)")
-    lp = params["layers"]
-    head = params.get("lm_head")
-    if head is None:
-        head = params["embed"].T
-    return fused_decode_window(
-        params["embed"], head, params["final_norm"],
-        lp["attn_norm"], lp["mlp_norm"],
-        lp["wq"], lp["wk"], lp["wv"], lp["wo"],
-        lp["w_gate"], lp["w_up"], lp["w_down"],
-        k_cache, v_cache, tokens, positions, block_tables, active,
-        temps, top_ks, top_ps, uniforms, guided_rows, mask_pool, next_pool,
-        num_steps=num_steps, num_heads=c.num_heads,
-        num_kv_heads=c.num_kv_heads, head_dim=c.head_dim,
-        block_size=c.block_size, rms_eps=c.rms_norm_eps,
-        theta=c.rope_theta, interpret=not _on_tpu(),
-        sampled=sampled, guided=guided,
-    )
-
-
-def decode_spec_fused(
-    target_params: Params,
-    target_config: ModelConfig,
-    draft_params: Params,
-    draft_config: ModelConfig,
-    k_t: jax.Array,
-    v_t: jax.Array,
-    k_d: jax.Array,
-    v_d: jax.Array,
-    tokens: jax.Array,  # [B] i32 — last confirmed token
-    xprev: jax.Array,  # [B] i32 — token at positions-1
-    positions: jax.Array,  # [B] i32 — position of the last confirmed token
-    tables_t: jax.Array,  # [B, W] i32
-    tables_d: jax.Array,  # [B, W] i32
-    active: jax.Array,  # [B] bool
-    temps: jax.Array,  # [B] f32
-    top_ks: jax.Array,  # [B] i32
-    top_ps: jax.Array,  # [B] f32
-    uniforms: jax.Array,  # [rounds, B, 2*gamma+1] f32
-    rounds: int,
-    gamma: int,
-) -> Tuple[jax.Array, ...]:
-    """``rounds`` speculative rounds (draft γ-burst + target verify +
-    rejection sampling) in ONE Pallas launch — megakernel.fused_spec_window
-    with both llama models' weights resolved to the kernel layout. Returns
-    (tokens_out [rounds, B, γ+1], accepted [rounds, B], k_t, v_t, k_d,
-    v_d)."""
-    from dynamo_tpu.engine.attention.megakernel import fused_spec_window
-
-    tc, dc = target_config, draft_config
-    _refuse_eva(tc, "decode_spec_fused (speculative decoding)")
-    _refuse_eva(dc, "decode_spec_fused (speculative decoding)")
-
-    def _w(p):
-        head = p.get("lm_head")
-        if head is None:
-            head = p["embed"].T
-        lp = p["layers"]
-        return (
-            p["embed"], head, p["final_norm"], lp["attn_norm"], lp["mlp_norm"],
-            lp["wq"], lp["wk"], lp["wv"], lp["wo"],
-            lp["w_gate"], lp["w_up"], lp["w_down"],
-        )
-
-    return fused_spec_window(
-        *_w(target_params), *_w(draft_params),
-        k_t, v_t, k_d, v_d,
-        tokens, xprev, positions, tables_t, tables_d, active,
-        temps, top_ks, top_ps, uniforms,
-        rounds=rounds, gamma=gamma, block_size=tc.block_size,
-        t_num_heads=tc.num_heads, t_num_kv_heads=tc.num_kv_heads,
-        t_head_dim=tc.head_dim, t_rms_eps=tc.rms_norm_eps,
-        t_theta=tc.rope_theta,
-        d_num_heads=dc.num_heads, d_num_kv_heads=dc.num_kv_heads,
-        d_head_dim=dc.head_dim, d_rms_eps=dc.rms_norm_eps,
-        d_theta=dc.rope_theta,
-        interpret=not _on_tpu(),
     )
 
 

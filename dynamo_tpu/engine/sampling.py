@@ -7,7 +7,6 @@ one compiled sampler serves mixed-parameter batches.
 
 from __future__ import annotations
 
-import functools
 from dataclasses import dataclass, field
 from typing import List, Optional
 
@@ -108,11 +107,10 @@ def filtered_probs_rows(
     top-k/top-p truncation (``_exact_thresholds``) + softmax, per row.
     Greedy rows (temperature 0) return a one-hot argmax distribution.
 
-    One implementation shared by the host sampler's exact path, the fused
-    megakernel's in-kernel epilogue, and spec_decode's verifier — tie-
-    breaking (the ``>= thresh`` keep rule after one descending sort) is
-    bit-identical everywhere, so fused vs sync parity and draft-vs-verify
-    distribution agreement hold exactly."""
+    spec_decode's verifier filters the draft's and the target's logits
+    through this one implementation — tie-breaking (the ``>= thresh`` keep
+    rule after one descending sort) is bit-identical on both sides, so
+    draft-vs-verify distribution agreement holds exactly."""
     V = logits.shape[-1]
     safe_t = jnp.where(temps > 0, temps, 1.0)
     scaled = logits / safe_t[:, None]
@@ -122,60 +120,6 @@ def filtered_probs_rows(
     probs = jax.nn.softmax(masked, axis=-1)
     greedy = jax.nn.one_hot(jnp.argmax(logits, axis=-1), V, dtype=probs.dtype)
     return jnp.where((temps > 0)[:, None], probs, greedy)
-
-
-def pick_from_probs(probs: jax.Array, u: jax.Array) -> jax.Array:
-    """Inverse-CDF draw: the first index whose cumulative probability
-    exceeds ``u`` (per row; ``probs`` [B, V], ``u`` [B] in [0, 1)). The
-    fp-degenerate tail (u beyond the row's total mass) falls back to the
-    row's mode so the pick is always a kept token."""
-    cum = jnp.cumsum(probs, axis=-1)
-    hit = cum > u[:, None]
-    picked = jnp.argmax(hit, axis=-1).astype(jnp.int32)
-    fallback = jnp.argmax(probs, axis=-1).astype(jnp.int32)
-    return jnp.where(hit[:, -1], picked, fallback)
-
-
-def sample_from_uniforms(
-    logits: jax.Array,  # [B, V] f32
-    temps: jax.Array,  # [B] f32 (0 = greedy)
-    top_ks: jax.Array,  # [B] i32 (0 = off)
-    top_ps: jax.Array,  # [B] f32 (1 = off)
-    u: jax.Array,  # [B] f32 — precomputed uniforms in [0, 1)
-) -> jax.Array:
-    """Sample one token per row from precomputed uniforms instead of a
-    threaded PRNG key — the fused megakernel's sampling contract: the host
-    derives per-(step, row) uniforms up front (``make_window_uniforms``)
-    and the kernel consumes one per step via inverse-CDF, so the in-kernel
-    epilogue and any host replay of the same uniforms pick bit-identical
-    tokens. Greedy rows ride the one-hot distribution (cum jumps 0→1 at
-    the argmax, any u < 1 picks it)."""
-    return pick_from_probs(filtered_probs_rows(logits, temps, top_ks, top_ps), u)
-
-
-@functools.partial(jax.jit, static_argnames=("num_steps",))
-def make_window_uniforms(
-    base_key: jax.Array,
-    seeds: jax.Array,  # [B] i32 (0 where unseeded)
-    positions: jax.Array,  # [B] i32 — per-request token position at window start
-    has_seed: jax.Array,  # [B] bool
-    num_steps: int,
-) -> jax.Array:
-    """Host-side uniforms for a fused sampled window → [num_steps, B].
-    ``u[s, b]`` is the inverse-CDF draw row b consumes at window step s:
-    seeded rows derive from PRNGKey(seed) folded with the row's absolute
-    token position (``make_row_keys`` semantics — batch-composition
-    independent, so a seeded request replays identically at any batch
-    slot), unseeded rows fold the per-step subkey with their row index.
-    ONE dispatch per window, not per step (no per-step host sync)."""
-
-    def step_u(s):
-        ks = make_row_keys(
-            jax.random.fold_in(base_key, s), seeds, positions + s, has_seed
-        )
-        return jax.vmap(lambda k: jax.random.uniform(k, ()))(ks)
-
-    return jnp.stack([step_u(s) for s in range(num_steps)])
 
 
 def sample_batch(

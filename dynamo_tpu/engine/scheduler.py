@@ -501,6 +501,7 @@ class Scheduler:
             StepCostModel(param_count, param_bytes, kv_per_token,
                           kv_read_factor=kv_read_factor)
         )
+        # Read by benchmark/run.py and chip_smoke.py (their "serve" reports).
         self._param_bytes = param_bytes
         self._kv_cache_bytes = kv_bytes
 
@@ -687,8 +688,6 @@ class Scheduler:
         self.draft_cache = None
         self.spec_gamma = 0
         self.spec_stats = None
-        self._use_fused_spec = False
-        self._spec_rounds = 0
         self._supports_multi_step = hasattr(model, "decode_multi")
         # Batched admission (chunk_decode waves) — llama-family only, and not
         # eva (a wave's chunk may straddle a window boundary).
@@ -719,75 +718,6 @@ class Scheduler:
                 {w for w in (8, 16, self.sc.num_scheduler_steps) if w <= self.sc.num_scheduler_steps}
             )
             self._decode_multi_jits = {w: mk_multi(w) for w in self._window_rungs}
-        # Fused megakernel decode window (llama.decode_multi_fused): a whole
-        # greedy N-step window in ONE pallas launch — embedding, layers,
-        # paged attention, lm_head, argmax, and KV writes inside one grid
-        # with on-chip token feedback. Dense bf16/f32 llama only (no MoE /
-        # int8 weights / quantized KV), and only where the working set fits
-        # VMEM (fused_window_fits); everything else keeps decode_multi,
-        # whose per-step attention still runs the ragged megakernel.
-        self._use_fused_window = False
-        if (
-            self._supports_multi_step
-            and self.sc.num_scheduler_steps > 1
-            and hasattr(model, "decode_multi_fused")
-            and self._attn_impl == "megakernel"
-            and not self._eva
-            and model_config.num_experts == 0
-            and model_config.weight_dtype != "int8"
-            and model_config.kv_cache_dtype != "int8"
-        ):
-            from dynamo_tpu.engine.attention.megakernel import fused_window_fits
-
-            self._use_fused_window = fused_window_fits(
-                self._param_bytes, self._kv_cache_bytes
-            )
-        if self._use_fused_window:
-            # Three executables per rung, keyed (steps, sampled, guided):
-            # greedy (byte-compatible with the PR-7 window), sampled (host-
-            # precomputed [steps, bucket] uniforms + packed params drive the
-            # in-kernel top-k/top-p epilogue), and guided (FSM mask + next-
-            # state pools ride along; guided always uses the sampled
-            # epilogue — greedy rows reduce to argmax through their one-hot
-            # distributions, so one executable covers mixed batches).
-            def mk_fused(steps: int, sampled: bool, guided: bool):
-                if not sampled and not guided:
-
-                    def decode_fused(p, k, v, t, pos, bt, act):
-                        return model.decode_multi_fused(
-                            p, self.mc, k, v, t, pos, bt, act, steps
-                        )
-
-                    decode_fused.__name__ = f"decode_fused_w{steps}"
-                    return jax.jit(decode_fused, donate_argnums=(1, 2))
-                if not guided:
-
-                    def decode_fused_sampled(p, k, v, t, pos, bt, act, te, tk, tp, u):
-                        return model.decode_multi_fused(
-                            p, self.mc, k, v, t, pos, bt, act, steps,
-                            temps=te, top_ks=tk, top_ps=tp, uniforms=u,
-                            sampled=True,
-                        )
-
-                    decode_fused_sampled.__name__ = f"decode_fused_sampled_w{steps}"
-                    return jax.jit(decode_fused_sampled, donate_argnums=(1, 2))
-
-                def decode_fused_guided(p, k, v, t, pos, bt, act, te, tk, tp, u, rows, mp, xp):
-                    return model.decode_multi_fused(
-                        p, self.mc, k, v, t, pos, bt, act, steps,
-                        temps=te, top_ks=tk, top_ps=tp, uniforms=u,
-                        guided_rows=rows, mask_pool=mp, next_pool=xp,
-                        sampled=True, guided=True,
-                    )
-
-                decode_fused_guided.__name__ = f"decode_fused_guided_w{steps}"
-                return jax.jit(decode_fused_guided, donate_argnums=(1, 2))
-
-            self._decode_fused_jits = {
-                (w, s, g): mk_fused(w, s, g)
-                for w in self._window_rungs
-                for (s, g) in ((False, False), (True, False), (True, True))
-            }
 
     def attach_draft(self, draft_config: ModelConfig, draft_params, *, gamma: int = 4) -> None:
         """Enable batched speculative decoding: the draft model proposes γ
@@ -874,47 +804,6 @@ class Scheduler:
 
             spec_draft_multi.__name__ = f"spec_draft_multi_w{gamma - 1}"
             self._d_multi_jit = jax.jit(spec_draft_multi, donate_argnums=(1, 2))
-        # Fused speculative window: R whole draft+verify rounds in ONE
-        # pallas launch (megakernel.fused_spec_window) — both models'
-        # weights and caches VMEM-resident, accepted bursts advancing the
-        # on-chip cursors. Gated like the fused decode window, but over the
-        # COMBINED working set; degraded gracefully to the per-round spec
-        # path above when it doesn't fit.
-        self._use_fused_spec = False
-        if (
-            self._use_fused_window
-            and hasattr(model, "decode_spec_fused")
-            and draft_config.num_experts == 0
-            and draft_config.weight_dtype != "int8"
-            and draft_config.kv_cache_dtype != "int8"
-        ):
-            from dynamo_tpu.engine.attention.megakernel import fused_window_fits
-
-            d_leaves = jax.tree_util.tree_leaves(draft_params)
-            d_param_bytes = sum(int(x.size) * x.dtype.itemsize for x in d_leaves)
-            d_kv_leaves = jax.tree_util.tree_leaves(
-                (self.draft_cache.k, self.draft_cache.v)
-            )
-            d_kv_bytes = sum(int(x.size) * x.dtype.itemsize for x in d_kv_leaves)
-            self._use_fused_spec = fused_window_fits(
-                self._param_bytes + d_param_bytes,
-                self._kv_cache_bytes + d_kv_bytes,
-            )
-        if self._use_fused_spec:
-            # Window length in ROUNDS: each round nets 1..γ+1 tokens, so
-            # num_scheduler_steps/(γ+1) rounds keeps the fused-spec window's
-            # worst-case token span equal to the plain fused window's.
-            self._spec_rounds = max(1, self.sc.num_scheduler_steps // (gamma + 1))
-            rounds = self._spec_rounds
-
-            def spec_fused(p, dp, kt, vt, kd, vd, t, xp, pos, bt, act, te, tk, tp, u):
-                return model.decode_spec_fused(
-                    p, self.mc, dp, dc, kt, vt, kd, vd, t, xp, pos,
-                    bt, bt, act, te, tk, tp, u,
-                    rounds=rounds, gamma=gamma,
-                )
-
-            self._spec_fused_jit = jax.jit(spec_fused, donate_argnums=(2, 3, 4, 5))
 
     def attach_guided(self, tokenizer) -> None:
         """Enable grammar-constrained decoding: grammars lift to token FSMs
@@ -928,23 +817,6 @@ class Scheduler:
             eos_ids=self._eos,
             vocab_size=self.mc.vocab_size,
             pool_rows=self.sc.guided_pool_rows,
-        )
-
-    def _fused_guided_ok(self) -> bool:
-        """Guided rows may ride the fused window only while BOTH device
-        pools (packed allow bitmasks + the [P, V] i32 next-row table the
-        on-chip FSM advance reads) still fit the VMEM window budget
-        alongside the weights — pool growth re-checks every window, so a
-        grammar working set outgrowing VMEM degrades row-wise to the host
-        FSM path instead of mis-launching."""
-        if not self._use_fused_window or self.guided is None:
-            return False
-        from dynamo_tpu.engine.attention.megakernel import fused_window_fits
-
-        pool = self.guided.pool
-        pool_bytes = pool.capacity * pool.words * 4 + pool.next_pool_bytes()
-        return fused_window_fits(
-            self._param_bytes, self._kv_cache_bytes + pool_bytes
         )
 
     # --- public API (called from event loop) --------------------------------
@@ -2124,67 +1996,6 @@ class Scheduler:
                             )
                         )
                         count += 1
-                if self._use_fused_window:
-                    # Fused megakernel windows: greedy, sampled, and (when a
-                    # grammar pool is attached and fits) guided variants
-                    # over the same (steps, bucket, width) key space as
-                    # decode_multi. The first trace also records the
-                    # launches-per-window gauge (must be 1).
-                    from dynamo_tpu.engine.attention import megakernel as _mk
-
-                    guided_warm = self._fused_guided_ok()
-                    for w in self._window_rungs:
-                        unif = jnp.zeros((w, bucket), jnp.float32)
-                        variants = [
-                            ("decode_fused", (w, bucket, width),
-                             (w, False, False),
-                             (toks, pos, tables, active)),
-                            ("decode_fused_sampled", (w, bucket, width),
-                             (w, True, False),
-                             (toks, pos, tables, active, temps, tks, tps, unif)),
-                        ]
-                        if guided_warm:
-                            P = int(self.guided.pool.capacity)
-                            variants.append((
-                                "decode_fused_guided", (w, bucket, width, P),
-                                (w, True, True),
-                                (toks, pos, tables, active, temps, tks, tps,
-                                 unif, jnp.zeros((bucket,), jnp.int32),
-                                 self.guided.pool.device(),
-                                 self.guided.pool.next_device()),
-                            ))
-                        for kind, key_t, jit_key, args in variants:
-                            new_exec = self.flight.record_exec(kind, key_t)
-                            launches0 = _mk.trace_launch_count()
-                            _, self.cache.k, self.cache.v = self._decode_fused_jits[jit_key](
-                                self.params, self.cache.k, self.cache.v, *args
-                            )
-                            if new_exec:
-                                # Gauge holds the WORST variant: greedy,
-                                # sampled-epilogue, and guided windows must
-                                # all trace exactly one pallas launch.
-                                self.flight.record_window_launches(max(
-                                    _mk.trace_launch_count() - launches0,
-                                    self.flight.fused_window_pallas_launches or 0,
-                                ))
-                            count += 1
-                if self.draft_params is not None and self._use_fused_spec:
-                    # Fused spec windows share decode's (bucket, width) key
-                    # space — warm every combination so a spec batch joining
-                    # warmed traffic compiles nothing.
-                    gamma = self.spec_gamma
-                    R = self._spec_rounds
-                    self.flight.record_exec("spec_fused", (R, gamma, bucket, width))
-                    unif_s = jnp.zeros((R, bucket, 2 * gamma + 1), jnp.float32)
-                    (_, _, self.cache.k, self.cache.v,
-                     self.draft_cache.k, self.draft_cache.v) = self._spec_fused_jit(
-                        self.params, self.draft_params,
-                        self.cache.k, self.cache.v,
-                        self.draft_cache.k, self.draft_cache.v,
-                        toks, toks, pos, tables, active,
-                        temps, tks, tps, unif_s,
-                    )
-                    count += 1
             self._sample_jit(
                 jnp.zeros((bucket, self.mc.vocab_size), jnp.float32),
                 jnp.zeros((bucket,), jnp.float32), jnp.zeros((bucket,), jnp.int32),
@@ -2706,54 +2517,30 @@ class Scheduler:
             or (seq.sampling.seed is not None and seq.sampling.temperature > 0)
             for seq in batch
         ):
-            # Fused spec window first (draft bursts + target verifies in ONE
-            # launch); falls through to the per-round spec path, then to
-            # plain decode, when blocks/limits don't allow it.
-            if self._use_fused_spec and self._decode_spec_fused(batch, bucket, outputs):
-                return outputs
+            # Falls through to plain decode when blocks/limits don't allow a round.
             if self._decode_spec(batch, bucket, outputs):
                 return outputs
 
-        if self.sc.num_scheduler_steps > 1 and self._supports_multi_step:
-            # Fused-eligibility is "no per-row HOST extras", not "all
-            # greedy": sampled rows ride via host-precomputed uniforms,
-            # guided rows via the device mask + next-state pools. Only
-            # penalties (history mutates inside the window), logits
-            # processors, and logprobs/top_logprobs rows — which need the
-            # host between tokens — are window-ineligible; without the
-            # fused window, guided and seeded-sampled rows are too (the
-            # decode_multi executable threads neither FSM masks nor
-            # per-row keys).
-            fused_w = self._use_fused_window
-            guided_ok = fused_w and self._fused_guided_ok()
-
-            def _window_ok(seq) -> bool:
-                if (
-                    seq.sampling.logits_processors
-                    or seq.sampling.logprobs
-                    or seq.sampling.top_logprobs
-                    or seq.sampling.has_penalties
-                ):
-                    return False
-                if seq.guided is not None:
-                    return guided_ok
-                if seq.sampling.seed is not None and seq.sampling.temperature > 0:
-                    return fused_w
-                return True
-
-            win = [seq for seq in batch if _window_ok(seq)]
-            if len(win) == len(batch):
-                if self._decode_multi(batch, bucket, outputs):
-                    return outputs
-            elif win and fused_w:
-                # Row-wise fallback: the window-eligible rows still ride the
-                # fused window; ONLY the extras rows flush to the single-
-                # step host path below (previously one logprobs row dragged
-                # the whole batch off the fused path).
-                w_bucket = next_bucket(len(win), self.sc.decode_buckets)
-                if self._decode_multi(win, w_bucket, outputs):
-                    batch = [seq for seq in batch if not _window_ok(seq)]
-                    bucket = next_bucket(len(batch), self.sc.decode_buckets)
+        # A window needs no host between its tokens: penalties (history
+        # mutates inside the window), logits processors, logprobs /
+        # top_logprobs, guided rows (the FSM advances on the host) and seeded
+        # sampled rows (decode_multi threads no per-row keys) take their whole
+        # batch to single steps.
+        if (
+            self.sc.num_scheduler_steps > 1
+            and self._supports_multi_step
+            and not any(
+                seq.sampling.logits_processors
+                or seq.sampling.logprobs
+                or seq.sampling.top_logprobs
+                or seq.sampling.has_penalties
+                or seq.guided is not None
+                or (seq.sampling.seed is not None and seq.sampling.temperature > 0)
+                for seq in batch
+            )
+            and self._decode_multi(batch, bucket, outputs)
+        ):
+            return outputs
 
         # Bucket the block-table width by the longest sequence in the batch:
         # the attention gather is O(table_width), so short contexts must not
@@ -2981,119 +2768,6 @@ class Scheduler:
             positions[i] = seq.total_len - 1
             active[i] = True
 
-        # Fused megakernel window: any batch with no per-row HOST extras
-        # dispatches the whole N-step window as ONE pallas launch (grid =
-        # steps × layers, token feedback through on-chip scratch) — the
-        # per-launch dispatch tax is paid once per WINDOW and the weights/
-        # prefix are read once, not ``steps`` times. Sampled rows ride via
-        # host-precomputed per-step uniforms (no per-step host sync) with
-        # the in-kernel top-k/top-p epilogue; guided rows ride the device
-        # mask pool with the FSM advanced on-chip through the next-state
-        # pool. Only penalties/logprobs/processors rows (and a grammar
-        # working set outgrowing VMEM) keep the multi-launch decode_multi.
-        any_guided = any(s.guided is not None for s in batch)
-        fused_ok = (
-            self._use_fused_window
-            and not any(
-                s.sampling.logits_processors
-                or s.sampling.logprobs
-                or s.sampling.top_logprobs
-                or s.sampling.has_penalties
-                for s in batch
-            )
-            and (not any_guided or self._fused_guided_ok())
-        )
-        if fused_ok:
-            from dynamo_tpu.engine.attention import megakernel as _mk
-
-            use_sampled = any_guided or any(
-                s.sampling.temperature > 0 for s in batch
-            )
-            if any_guided:
-                kind, key_t = "decode_fused_guided", (
-                    steps, bucket, width, int(self.guided.pool.capacity)
-                )
-            elif use_sampled:
-                kind, key_t = "decode_fused_sampled", (steps, bucket, width)
-            else:
-                kind, key_t = "decode_fused", (steps, bucket, width)
-            new_exec = self.flight.record_exec(kind, key_t)
-            self._note_step(kind, key_t, batch, decode=steps * len(batch))
-            launches0 = _mk.trace_launch_count() if new_exec else 0
-            n0 = len(outputs)
-            self._end_plan()
-            with self._span("sched.upload") as upload:
-                tables = self._decode_tables(batch, bucket, width)
-                args = [
-                    self.params, self.cache.k, self.cache.v,
-                    jnp.asarray(tokens), jnp.asarray(positions), tables,
-                    jnp.asarray(active),
-                ]
-                if use_sampled:
-                    # One [steps, bucket] uniforms upload per window —
-                    # threefry keys honor per-row seeds (make_row_keys), so
-                    # seeded sampled rows stay reproducible on this path.
-                    from dynamo_tpu.engine.sampling import make_window_uniforms
-
-                    self._step_counter += 1
-                    base_key = jax.random.fold_in(self._rng, self._step_counter)
-                    seeds = np.zeros((bucket,), dtype=np.int32)
-                    poss_out = np.zeros((bucket,), dtype=np.int32)
-                    has_seed = np.zeros((bucket,), dtype=bool)
-                    for i, seq in enumerate(batch):
-                        if seq.sampling.seed is not None:
-                            seeds[i] = seq.sampling.seed
-                            poss_out[i] = len(seq.output_ids)
-                            has_seed[i] = True
-                    uniforms = make_window_uniforms(
-                        base_key, jnp.asarray(seeds), jnp.asarray(poss_out),
-                        jnp.asarray(has_seed), steps,
-                    )
-                    args += [
-                        jnp.asarray(temps), jnp.asarray(top_ks),
-                        jnp.asarray(top_ps), uniforms,
-                    ]
-                if any_guided:
-                    rows0 = np.zeros((bucket,), dtype=np.int32)
-                    for i, seq in enumerate(batch):
-                        if seq.guided is not None:
-                            rows0[i] = seq.guided.row_id
-                    args += [
-                        jnp.asarray(rows0), self.guided.pool.device(),
-                        self.guided.pool.next_device(),
-                    ]
-            fjit = self._decode_fused_jits[(steps, use_sampled, any_guided)]
-            with self._launch(kind, decode=True):
-                toks_out, self.cache.k, self.cache.v = fjit(*args)
-            with self._span("sched.sync"):
-                sampled = np.asarray(toks_out)  # the one host sync per window
-            with self._span("sched.emit"):
-                for i, seq in enumerate(batch):
-                    for s in range(steps):
-                        if seq.state != SeqState.RUNNING:
-                            break
-                        self._append_token(seq, int(sampled[s, i]), outputs)
-            dur = self._since(upload)
-            with self._span("sched.account"):
-                if new_exec:
-                    # Launch sites traced into this window executable — the
-                    # amortization invariant (== 1) CI asserts.
-                    self.flight.record_window_launches(_mk.trace_launch_count() - launches0)
-                self.flight.fused_windows_total += 1
-                if use_sampled:
-                    self.flight.fused_sampled_windows_total += 1
-                self.flight.record_step(
-                    "decode", dur, len(outputs) - n0,
-                    # VMEM-resident window: weights and prefix stream from HBM
-                    # once per window, not once per step.
-                    kv_read_tokens=sum(s.total_len for s in batch),
-                    param_passes=1.0,
-                )
-                self._bill_step(dur, [(s, "decode", steps, s.total_len) for s in batch])
-                self.telemetry.observe("itl", dur / max(steps, 1))
-            self._begin_plan()
-            return True
-
         exec_key = (steps, bucket, width)
         self.flight.record_exec("decode_multi", exec_key)
         self._note_step("decode_multi", exec_key, batch, decode=sum(taken))
@@ -3143,119 +2817,6 @@ class Scheduler:
             self._bill_step(dur, [(s, "decode", steps, steps * r) for s, r in zip(batch, kv_rows)])
             self.telemetry.observe("itl", dur / max(steps, 1))
         self._begin_plan()
-        return True
-
-    def _decode_spec_fused(self, batch: List[Sequence], bucket: int, outputs: List[tuple]) -> bool:
-        """R whole speculative rounds in ONE pallas launch: per round the
-        draft proposes γ sampled tokens, the target verifies the γ+1 chunk,
-        and rejection sampling accepts a prefix + correction/bonus — with
-        the accepted burst advancing on-chip cursors, so the host syncs
-        once per WINDOW (two small int arrays) instead of 3×γ times. The
-        output distribution equals sampling the target directly (same math
-        as spec_decode.spec_verify, driven by host-precomputed uniforms);
-        greedy rows reduce to exact argmax agreement. Returns False to fall
-        back to the per-round spec path when blocks/limits don't allow the
-        full window."""
-        from dynamo_tpu.engine.sampling import pack_param_rows
-
-        gamma = self.spec_gamma
-        R = self._spec_rounds
-        span = R * (gamma + 1)  # worst-case tokens appended per window
-        bs = self.mc.block_size
-        for seq in batch:
-            if seq.total_len + span + 1 > self.mc.max_seq_len:
-                return False
-            need = (seq.total_len + span + 1 + bs - 1) // bs - len(seq.block_ids)
-            if need > 0:
-                try:
-                    seq.block_ids.extend(self.allocator.allocate(need))
-                except OutOfBlocksError:
-                    return False
-            if seq.total_len - seq.d_n > 2:
-                # The in-kernel catch-up re-feeds exactly ONE token (the one
-                # at pos-1), so the draft cache must already cover pos-2 —
-                # absorb any longer lag with prefill-style chunks first.
-                self._draft_catchup(seq, seq.all_ids, seq.total_len - 1)
-
-        B = bucket
-        width = self._width_bucket(max(len(seq.block_ids) for seq in batch))
-        from dynamo_tpu.engine.attention import megakernel as _mk
-
-        exec_key = (R, gamma, B, width)
-        new_exec = self.flight.record_exec("spec_fused", exec_key)
-        self._note_step("spec_fused", exec_key, batch, decode=span * len(batch))
-        launches0 = _mk.trace_launch_count() if new_exec else 0
-        n0 = len(outputs)
-        t_round = time.perf_counter()
-        tables = np.zeros((B, width), dtype=np.int32)
-        tok0 = np.zeros((B,), dtype=np.int32)
-        xprev0 = np.zeros((B,), dtype=np.int32)
-        pos0 = np.zeros((B,), dtype=np.int32)
-        act = np.zeros((B,), dtype=bool)
-        temps, top_ks, top_ps = pack_param_rows([s.sampling for s in batch], B)
-        for i, seq in enumerate(batch):
-            tables[i, : len(seq.block_ids)] = seq.block_ids
-            tok0[i] = seq.all_ids[-1]
-            xprev0[i] = seq.all_ids[-2]  # total_len ≥ 2 by the time we decode
-            pos0[i] = seq.total_len - 1
-            act[i] = True
-        # All of the window's draws — γ proposal draws, γ accept draws, and
-        # the correction/bonus pick per (round, row) — upload as ONE
-        # [R, B, 2γ+1] operand; nothing syncs until the window returns.
-        self._step_counter += 1
-        ukey = jax.random.fold_in(self._rng, self._step_counter)
-        uniforms = jax.random.uniform(ukey, (R, B, 2 * gamma + 1))
-
-        with self._launch("spec_fused"):
-            toks_out, accepted, self.cache.k, self.cache.v, self.draft_cache.k, self.draft_cache.v = (
-                self._spec_fused_jit(
-                    self.params, self.draft_params,
-                    self.cache.k, self.cache.v,
-                    self.draft_cache.k, self.draft_cache.v,
-                    jnp.asarray(tok0), jnp.asarray(xprev0), jnp.asarray(pos0),
-                    jnp.asarray(tables), jnp.asarray(act),
-                    jnp.asarray(temps), jnp.asarray(top_ks), jnp.asarray(top_ps),
-                    uniforms,
-                )
-            )
-        with self._span("sched.sync"):
-            toks_h = np.asarray(toks_out)  # [R, B, γ+1] — the one sync
-            acc_h = np.asarray(accepted)  # [R, B]
-        if new_exec:
-            self.flight.record_window_launches(_mk.trace_launch_count() - launches0)
-
-        st = self.spec_stats
-        for r in range(R):
-            st.num_rounds += 1
-            for i, seq in enumerate(batch):
-                if seq.state != SeqState.RUNNING:
-                    continue  # stopped in an earlier round; trailing rounds trim
-                k = int(acc_h[r, i])
-                st.record_round(k, gamma)
-                old_total = seq.total_len
-                for t in list(toks_h[r, i, :k]) + [int(toks_h[r, i, gamma])]:
-                    if seq.state != SeqState.RUNNING:
-                        break  # stop hit mid-burst; stale KV is position-masked
-                    self._append_token(seq, int(t), outputs)
-                # Draft rows are confirmed through position old_total-1+
-                # min(k, γ-1)+... — the catch-up row plus the first
-                # min(k, γ-1) proposal feeds (same ledger as _decode_spec).
-                seq.d_n = old_total + min(k, gamma - 1)
-        dur_round = time.perf_counter() - t_round
-        self.flight.spec_fused_windows_total += 1
-        self.flight.spec_fused_accepted_tokens_total += max(len(outputs) - n0, 0)
-        self.flight.record_step(
-            "spec", dur_round, len(outputs) - n0,
-            kv_read_tokens=2 * R * sum(s.total_len for s in batch),
-            # Both models' weights are VMEM-resident for the whole window.
-            param_passes=1.0,
-        )
-        self._bill_step(
-            dur_round, [(s, "decode", span, 2 * R * s.total_len) for s in batch]
-        )
-        self.telemetry.observe(
-            "itl", dur_round / max(len(outputs) - n0, 1)
-        )
         return True
 
     def _decode_spec(self, batch: List[Sequence], bucket: int, outputs: List[tuple]) -> bool:

@@ -49,7 +49,6 @@ class GuidedMaskPool:
         self._bases: Dict[int, int] = {}  # id(fsm) -> base row
         self._keep: List[TokenFSM] = []  # pin fsms so id() stays stable
         self._device = None
-        self._next_device = None
 
     def _allow_all_row(self) -> np.ndarray:
         row = np.full((self.words,), 0xFFFFFFFF, dtype=np.uint32)
@@ -84,7 +83,6 @@ class GuidedMaskPool:
         self._bases[id(fsm)] = base
         self._keep.append(fsm)
         self._device = None  # re-upload lazily
-        self._next_device = None
         return base
 
     def device(self):
@@ -95,31 +93,6 @@ class GuidedMaskPool:
 
             self._device = jnp.asarray(self._host)
         return self._device
-
-    def next_pool_bytes(self) -> int:
-        """Size of the ``[capacity, V] int32`` next-row pool the fused
-        window's on-chip FSM advance reads — the fused-eligibility gate
-        charges this against the VMEM window budget."""
-        return self.capacity * self.vocab_size * 4
-
-    def next_device(self):
-        """Device next-row pool: ``next[row, token]`` is the mask-pool row
-        the FSM lands on after emitting ``token`` from ``row`` — the fused
-        window advances guided rows ON-CHIP through this table instead of
-        flushing to the host every step. Dead transitions and row 0 map to
-        row 0 (allow-all); the host replay stops the sequence before a
-        dead/EOS transition would ever be sampled against."""
-        if self._next_device is None:
-            import jax.numpy as jnp
-
-            host = np.zeros((self.capacity, self.vocab_size), dtype=np.int32)
-            for fsm in self._keep:
-                base = self._bases[id(fsm)]
-                ns = fsm.next_state  # [S, V] i32, -1 = dead
-                rows = np.where(ns >= 0, base + ns, 0).astype(np.int32)
-                host[base : base + fsm.num_states, : ns.shape[1]] = rows
-            self._next_device = jnp.asarray(host)
-        return self._next_device
 
 
 class GuidedState:

@@ -843,9 +843,8 @@ class MockTpuEngine:
         # Device-truth parity: plausible synthetic measured siblings so the
         # aggregator/Grafana/planner stack runs engine-free. The mocker's
         # simulated clock IS its device, so the synthetic sampler reports
-        # one 250ms window per 30s of simulated busy time, 85% device-busy,
-        # a perfectly calibrated cost model, and the fused window holding
-        # its 1-launch invariant.
+        # one 250ms window per 30s of simulated busy time, 85% device-busy
+        # and a perfectly calibrated cost model.
         sim_busy_s = self.step_prefill_time_s + self.step_decode_time_s
         windows = int(sim_busy_s / 30.0) + (1 if sim_busy_s > 0 else 0)
         stats.update({
@@ -866,7 +865,6 @@ class MockTpuEngine:
                 "measured_device_frac": 0.85,
                 "measured_modeled_mfu_ratio": 1.0,
                 "measured_top_kernel_share": 0.55,
-                "measured_launches_per_fused_window": 1.0,
             })
         # Chaos plane: injected-fault counters, same keys as the engine's
         # scrape (only present on chaos-armed workers).

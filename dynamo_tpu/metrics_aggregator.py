@@ -51,9 +51,6 @@ GAUGE_KEYS = (
     # Incident autopsy plane: seconds since the last black-box capture
     # (-1 = never) — the "is anything firing / did we capture it" gauge.
     "incident_last_age_s",
-    # Pallas launch sites traced into one fused decode-window executable
-    # (must be exactly 1; CI asserts — see flight_recorder).
-    "fused_window_pallas_launches",
     # Elastic capacity dial: the live prefill:decode split each worker is
     # running (fraction ∈ [0,1]; 0.5 = configured identity) and the budget /
     # slot values it resolves to, plus the planner's fleet-wide ratio target.
@@ -66,7 +63,6 @@ GAUGE_KEYS = (
     "device_profile_duty_cycle",
     "measured_mfu", "measured_hbm_frac", "measured_device_frac",
     "measured_modeled_mfu_ratio", "measured_top_kernel_share",
-    "measured_launches_per_fused_window",
     "cost_model_calibrated",
     # Profile-derived capacity: EMA of measured per-worker tok/s the
     # autoscale controller is currently steering on (0 until warm).
@@ -122,10 +118,6 @@ COUNTER_KEYS = (
     "step_spec_flops_total", "step_spec_bytes_total",
     # Stall watchdog transitions (each is one wedged-engine incident).
     "engine_stalls_total",
-    # Fused megakernel decode windows dispatched (one pallas launch each),
-    # plus the sampled-epilogue and speculative variants of that window.
-    "fused_windows_total", "fused_sampled_windows_total",
-    "spec_fused_windows_total", "spec_fused_accepted_tokens_total",
     # Incident autopsy plane (runtime/incidents.py): anomaly-triggered
     # black-box captures, total and per trigger reason, plus on-demand /
     # per-incident device-profile captures.

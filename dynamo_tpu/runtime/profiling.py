@@ -18,8 +18,8 @@ Three layers, all exposed on the worker health server and the stats plane:
   ``zlib`` it runs on CPU CI against recorded fixtures.
 - ``ContinuousProfiler`` — a duty-cycled background sampler that opens
   short capture windows at a bounded rate, parses the artifact, and feeds
-  the per-window deltas (device time, kernel top-N, fused-window launch
-  counts) into the flight recorder so the modeled ``mfu_*`` / ``hbm_frac_*``
+  the per-window deltas (device time, kernel top-N) into the flight
+  recorder so the modeled ``mfu_*`` / ``hbm_frac_*``
   gauges gain *measured* siblings. The duty cycle is clamped
   (``window_s / effective_interval ≤ max_duty``) so the plane stays inside
   the observability budget, and the gating is pure arithmetic over an
@@ -205,8 +205,7 @@ class TraceSummary:
         ]
 
     def launch_count(self, pattern: str) -> int:
-        """Launches of kernels whose name contains ``pattern`` — the
-        dynamic side of the 1-launch-per-fused-window invariant."""
+        """Launches of kernels whose name contains ``pattern``."""
         return sum(k.count for name, k in self.kernels.items() if pattern in name)
 
     def top_share(self) -> float:
@@ -418,9 +417,6 @@ class ContinuousProfileConfig:
     max_duty: float = 0.02
     keep_artifacts: bool = False
     top_n: int = 8
-    # Kernel-name substring whose launch count is cross-checked against the
-    # flight recorder's fused-window count (1-launch-per-window, measured).
-    fused_kernel_pattern: str = "fused_decode_window"
 
 
 class ContinuousProfiler:
@@ -428,7 +424,7 @@ class ContinuousProfiler:
     the flight recorder.
 
     ``cost_probe`` returns the flight recorder's cumulative
-    ``(flops, bytes, step_seconds, fused_windows)`` so each window's deltas
+    ``(flops, bytes, step_seconds)`` so each window's deltas
     attribute measured device time to modeled work done in the same span;
     ``sink`` receives the per-window record (normally
     ``FlightRecorder.record_measured_window``). The sampler always YIELDS
@@ -441,7 +437,7 @@ class ContinuousProfiler:
         profiler: DeviceProfiler,
         config: Optional[ContinuousProfileConfig] = None,
         *,
-        cost_probe: Optional[Callable[[], Tuple[float, float, float, int]]] = None,
+        cost_probe: Optional[Callable[[], Tuple[float, float, float]]] = None,
         sink: Optional[Callable[[dict], None]] = None,
         clock: Callable[[], float] = time.monotonic,
     ):
@@ -484,7 +480,7 @@ class ContinuousProfiler:
             if not force and (now - self._last_attempt) < self.effective_interval_s:
                 return {"status": "not_due"}
             self._last_attempt = now
-        pre = self.cost_probe() if self.cost_probe else (0.0, 0.0, 0.0, 0)
+        pre = self.cost_probe() if self.cost_probe else (0.0, 0.0, 0.0)
         res = self.profiler.capture(self.config.window_s, label="continuous", wait=False)
         status = res.get("status")
         if status == "busy":
@@ -495,7 +491,7 @@ class ContinuousProfiler:
             with self._lock:
                 self.errors_total += 1
             return res
-        post = self.cost_probe() if self.cost_probe else (0.0, 0.0, 0.0, 0)
+        post = self.cost_probe() if self.cost_probe else (0.0, 0.0, 0.0)
         summary = load_trace_dir(res["path"])
         if not self.config.keep_artifacts:
             shutil.rmtree(res["path"], ignore_errors=True)
@@ -503,8 +499,6 @@ class ContinuousProfiler:
             with self._lock:
                 self.errors_total += 1
             return {"status": "error: no trace artifact", "path": res["path"]}
-        fused_delta = max(0, int(post[3]) - int(pre[3]))
-        fused_launches = summary.launch_count(self.config.fused_kernel_pattern)
         record = {
             "status": "ok",
             "wall_s": self.config.window_s,
@@ -518,11 +512,6 @@ class ContinuousProfiler:
             "truncated": summary.truncated,
             "top_kernels": summary.top(self.config.top_n),
             "top_kernel_share": summary.top_share(),
-            "fused_windows": fused_delta,
-            "fused_kernel_launches": fused_launches,
-            "launches_per_fused_window": (
-                fused_launches / fused_delta if fused_delta > 0 else None
-            ),
         }
         with self._lock:
             self.windows_total += 1

@@ -251,7 +251,6 @@ REFUSALS = {
     "prefix-matching": lambda s, p: s._match_prefix_tiers(None),
     "wave-admission-program": lambda s, p: llama.chunk_decode(p, CFG, None, None, jnp.zeros((1, 4), jnp.int32), None, None, None),
     "embeddings-program": lambda s, p: llama.embed(p, CFG, jnp.zeros((4,), jnp.int32), 4),
-    "fused-window-program": lambda s, p: llama.decode_multi_fused(p, CFG, None, None, None, None, None, None, 4),
     "a-mesh": lambda s, p: Scheduler(CFG, p, SchedulerConfig(num_blocks=16), mesh=object()),
 }
 
@@ -266,7 +265,7 @@ def test_what_equates_position_and_row_is_refused_for_eva(params, what):
 def test_eva_scheduler_turns_off_what_it_refuses(params):
     s = _bare(params)
     assert s.sc.enable_prefix_caching is False and not s._supports_overlap and not s._supports_chunk_admit
-    assert not s._use_fused_window and s.max_blocks_per_seq == math.ceil((M * ((CFG.max_seq_len - 1) // W) + W) / CFG.block_size)
+    assert s.max_blocks_per_seq == math.ceil((M * ((CFG.max_seq_len - 1) // W) + W) / CFG.block_size)
     assert s.warmup(ctx_tokens=64) > 0 and ("eva_roll",) in s.flight._exec_keys  # the roll program is warmed
 
 

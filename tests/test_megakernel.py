@@ -1,10 +1,8 @@
 """Ragged paged-attention megakernel: interpreter-mode parity vs the XLA
 gather path over head layouts (GQA/MQA/MHA), ragged edge cases (length-1
 decode rows mixed with chunk rows, short sequences in wide buckets, page-
-boundary prefix lengths, dead scratch-block-0 slots), the int8-KV
-dequant-in-VMEM path, and the fused N-step decode window (token AND KV
-cache-content parity vs ``decode_multi``, exactly ONE pallas launch per
-window, 0 post-warmup compiles at the scheduler).
+boundary prefix lengths, dead scratch-block-0 slots) and the int8-KV
+dequant-in-VMEM path.
 
 Everything runs the Pallas interpreter on CPU (tier-1 CI); the kernels are
 the same code the TPU auto-selection dispatches.
@@ -19,8 +17,6 @@ from dynamo_tpu.engine.attention import megakernel as mk
 from dynamo_tpu.engine.config import get_config
 from dynamo_tpu.engine.kv_cache import KvCacheArrays
 from dynamo_tpu.engine.models import llama
-from dynamo_tpu.engine.sampling import SamplingParams
-from dynamo_tpu.engine.scheduler import Scheduler, SchedulerConfig, StopConditions
 
 CFG = get_config("tiny")  # GQA: 4 heads over 2 KV heads
 MEGA = CFG.replace(attention_impl="megakernel")
@@ -241,97 +237,6 @@ def test_attention_impl_validation():
 
 
 # ---------------------------------------------------------------------------
-# Fused N-step decode window
-# ---------------------------------------------------------------------------
-
-
-def test_fused_window_parity_and_single_launch():
-    """One fused launch serves an entire greedy decode window: tokens AND
-    written KV cache contents match greedy ``decode_multi``, and the traced
-    executable contains exactly ONE pallas_call site."""
-    params = llama.init_params(CFG, jax.random.PRNGKey(0), dtype=jnp.float32)
-    rng = np.random.default_rng(6)
-    B, steps = 3, 4
-    toks = rng.integers(1, 255, size=21)
-    tables = np.stack([np.arange(1 + 4 * b, 5 + 4 * b, dtype=np.int32) for b in range(B)])
-
-    k, v = _fresh(CFG)
-    for b in range(B):
-        _, k, v = _prefill(params, CFG, k, v, toks, jnp.asarray(tables[b]))
-
-    dtoks = jnp.asarray(rng.integers(1, 255, size=B).astype(np.int32))
-    pos = jnp.full((B,), 21, jnp.int32)
-    active = jnp.ones((B,), bool)
-    t_j = jnp.asarray(tables)
-
-    n0 = mk.trace_launch_count()
-    toks_f, kf, vf = llama.decode_multi_fused(
-        params, MEGA, k, v, dtoks, pos, t_j, active, num_steps=steps
-    )
-    assert mk.trace_launch_count() - n0 == 1, "fused window must be ONE launch"
-
-    greedy = (jnp.zeros((B,), jnp.float32), jnp.zeros((B,), jnp.int32),
-              jnp.ones((B,), jnp.float32))
-    toks_r, kr, vr = jax.jit(
-        lambda p, k, v: llama.decode_multi(
-            p, CFG, k, v, dtoks, pos, t_j, active, *greedy,
-            jax.random.PRNGKey(9), steps,
-        )
-    )(params, k, v)
-    np.testing.assert_array_equal(np.asarray(toks_f), np.asarray(toks_r))
-    np.testing.assert_allclose(
-        np.asarray(kf)[:, 1:], np.asarray(kr)[:, 1:], atol=2e-4
-    )
-    np.testing.assert_allclose(
-        np.asarray(vf)[:, 1:], np.asarray(vr)[:, 1:], atol=2e-4
-    )
-
-
-def test_scheduler_fused_window_e2e():
-    """Scheduler end-to-end with attention_impl='megakernel': greedy token
-    streams match the gather scheduler, every decode window dispatches as
-    ONE pallas launch (flight-recorder gauge == 1), and a warmed scheduler
-    compiles NOTHING mid-traffic."""
-    params = llama.init_params(CFG, jax.random.PRNGKey(0), dtype=jnp.float32)
-
-    def run(impl, warm):
-        sched = Scheduler(CFG.replace(attention_impl=impl), params, SchedulerConfig(
-            num_blocks=128, max_running=4,
-            prefill_buckets=[32], decode_buckets=[1, 2, 4],
-            num_scheduler_steps=8, enable_prefix_caching=False,
-            enable_overlap_decode=False, enable_mixed_batching=False,
-        ), dtype=jnp.float32)
-        if warm:
-            sched.warmup(ctx_tokens=64)
-            sched.flight.mark_warmup_done(warmed=True)
-        toks = {}
-        for i in range(3):
-            sched.add_request(f"r{i}", list(range(1 + i, 25 + i)),
-                              SamplingParams(temperature=0.0),
-                              StopConditions(max_tokens=18, ignore_eos=True))
-        for _ in range(200):
-            if not sched.has_work():
-                break
-            for s, o in sched.step():
-                if o.token_id >= 0:
-                    toks.setdefault(s.request_id, []).append(o.token_id)
-        return sched, toks
-
-    s_m, t_m = run("megakernel", warm=True)
-    s_g, t_g = run("gather", warm=False)
-    assert t_m == t_g, "megakernel scheduler must emit identical greedy tokens"
-    assert s_m._use_fused_window
-    assert s_m.flight.fused_windows_total > 0
-    assert s_m.flight.fused_window_pallas_launches == 1
-    assert s_m.flight.compiles_after_warmup_total == 0, (
-        f"post-warmup compiles: {s_m.flight.post_warmup_keys}"
-    )
-    stats = s_m.flight.to_stats()
-    assert stats["fused_window_pallas_launches"] == 1
-    assert stats["fused_windows_total"] == s_m.flight.fused_windows_total
-
-
-# ---------------------------------------------------------------------------
 # Flight recorder: paged-path cost model + mixed-step phase split
 # ---------------------------------------------------------------------------
 
@@ -348,11 +253,11 @@ def test_cost_model_paged_vs_gather_bytes():
     assert fg == fp  # FLOPs don't depend on the attention path
     # gather: 2000 + 3*100*10 + 4*10; paged: 2000 + 100*10 + 4*10
     assert bg - bp == pytest.approx(2 * 100 * 10.0)
-    # A decode_multi window streams params once per step; the fused window
-    # streams them once per window.
+    # A decode_multi window streams params once per step; a single step
+    # streams them once.
     _, b_loop = paged.step_cost(32, 800, param_passes=8.0)
-    _, b_fused = paged.step_cost(32, 100, param_passes=1.0)
-    assert b_loop - b_fused == pytest.approx(7 * 2000 + 700 * 10.0)
+    _, b_once = paged.step_cost(32, 100, param_passes=1.0)
+    assert b_loop - b_once == pytest.approx(7 * 2000 + 700 * 10.0)
 
 
 def test_mixed_step_phase_split():
@@ -374,325 +279,3 @@ def test_mixed_step_phase_split():
     assert stats["step_mixed_tokens_total"] == 136
     assert stats["step_prefill_flops_total"] > 0
     assert stats["step_decode_bytes_total"] > 0
-
-
-# ---------------------------------------------------------------------------
-# In-kernel sampling epilogue: fused window vs the sync uniforms replay
-# ---------------------------------------------------------------------------
-
-
-def _window_uniforms(B, steps, seed=11):
-    from dynamo_tpu.engine.sampling import make_window_uniforms
-
-    return make_window_uniforms(
-        jax.random.PRNGKey(seed),
-        jnp.zeros((B,), jnp.int32), jnp.zeros((B,), jnp.int32),
-        jnp.zeros((B,), bool), steps,
-    )
-
-
-@pytest.mark.parametrize(
-    "B,steps",
-    [(8, 4), pytest.param(32, 2, marks=pytest.mark.slow)],
-    ids=["b8", "b32"],
-)
-def test_fused_window_sampled_parity(B, steps):
-    """The in-kernel sampling epilogue (temperature + top-k/top-p + inverse
-    CDF) picks BIT-IDENTICAL tokens to ``decode_multi`` replaying the same
-    uniforms, across mixed per-row params covering the threshold edges:
-    greedy (temp 0), k=1 (degenerate top-k), p=1.0 (top-p off), k>vocab
-    (clamps to full vocab), and plain temp>0. Written KV matches and the
-    whole window is still ONE launch."""
-    params = llama.init_params(CFG, jax.random.PRNGKey(0), dtype=jnp.float32)
-    rng = np.random.default_rng(7)
-    toks = rng.integers(1, 255, size=21)
-    tables = np.stack(
-        [np.arange(1 + 4 * b, 5 + 4 * b, dtype=np.int32) for b in range(B)]
-    )
-
-    k, v = _fresh(CFG, num_blocks=4 * B + 2)
-    for b in range(B):
-        _, k, v = _prefill(params, CFG, k, v, toks, jnp.asarray(tables[b]))
-
-    dtoks = jnp.asarray(rng.integers(1, 255, size=B).astype(np.int32))
-    pos = jnp.full((B,), 21, jnp.int32)
-    active = jnp.ones((B,), bool)
-    t_j = jnp.asarray(tables)
-
-    # Per-row params cycling through every filter edge the shared
-    # _exact_thresholds reference must hold at.
-    edge = [
-        (0.0, 0, 1.0),      # greedy row -> one-hot dist, argmax pick
-        (0.9, 1, 1.0),      # k=1: top-k degenerates to argmax
-        (0.8, 0, 1.0),      # p=1.0: top-p off entirely
-        (0.7, 999, 0.95),   # k > vocab: clamps to full vocab
-        (1.3, 20, 0.9),     # plain joint top-k/top-p
-    ]
-    rows = [edge[i % len(edge)] for i in range(B)]
-    temps = jnp.asarray([r[0] for r in rows], jnp.float32)
-    tks = jnp.asarray([r[1] for r in rows], jnp.int32)
-    tps = jnp.asarray([r[2] for r in rows], jnp.float32)
-    unif = _window_uniforms(B, steps)
-
-    n0 = mk.trace_launch_count()
-    toks_f, kf, vf = llama.decode_multi_fused(
-        params, MEGA, k, v, dtoks, pos, t_j, active, num_steps=steps,
-        temps=temps, top_ks=tks, top_ps=tps, uniforms=unif, sampled=True,
-    )
-    assert mk.trace_launch_count() - n0 == 1, "sampled window must be ONE launch"
-
-    toks_r, kr, vr = jax.jit(
-        lambda p, k, v: llama.decode_multi(
-            p, CFG, k, v, dtoks, pos, t_j, active, temps, tks, tps,
-            jax.random.PRNGKey(9), steps, uniforms=unif,
-        )
-    )(params, k, v)
-    np.testing.assert_array_equal(np.asarray(toks_f), np.asarray(toks_r))
-    np.testing.assert_allclose(
-        np.asarray(kf)[:, 1:], np.asarray(kr)[:, 1:], atol=2e-4
-    )
-    np.testing.assert_allclose(
-        np.asarray(vf)[:, 1:], np.asarray(vr)[:, 1:], atol=2e-4
-    )
-
-
-@pytest.mark.slow  # interpret-mode Pallas e2e; the CI `fused-sampling`
-# job gates the same invariants through bench.py in its own budget
-def test_scheduler_fused_sampled_e2e():
-    """Warmed megakernel scheduler serves seeded temp>0 traffic entirely on
-    the fused sampled window: the sampled-variant counter advances, ZERO
-    post-warmup compiles over the enlarged (sampled) key space, and the
-    same request seeds reproduce the same tokens on a fresh scheduler."""
-    params = llama.init_params(CFG, jax.random.PRNGKey(0), dtype=jnp.float32)
-
-    def run():
-        sched = Scheduler(MEGA, params, SchedulerConfig(
-            num_blocks=128, max_running=4,
-            prefill_buckets=[32], decode_buckets=[1, 2, 4],
-            num_scheduler_steps=8, enable_prefix_caching=False,
-            enable_overlap_decode=False, enable_mixed_batching=False,
-        ), dtype=jnp.float32)
-        sched.warmup(ctx_tokens=64)
-        sched.flight.mark_warmup_done(warmed=True)
-        toks = {}
-        for i in range(3):
-            sched.add_request(
-                f"r{i}", list(range(1 + i, 25 + i)),
-                SamplingParams(temperature=0.8, top_k=20, top_p=0.9, seed=7 + i),
-                StopConditions(max_tokens=10, ignore_eos=True),
-            )
-        for _ in range(200):
-            if not sched.has_work():
-                break
-            for s, o in sched.step():
-                if o.token_id >= 0:
-                    toks.setdefault(s.request_id, []).append(o.token_id)
-        return sched, toks
-
-    s1, t1 = run()
-    assert s1.flight.fused_sampled_windows_total > 0
-    assert s1.flight.compiles_after_warmup_total == 0, (
-        f"post-warmup compiles: {s1.flight.post_warmup_keys}"
-    )
-    assert all(len(v) == 10 for v in t1.values())
-    _, t2 = run()
-    assert t1 == t2, "seeded sampling on the fused path must be reproducible"
-
-
-@pytest.mark.slow  # interpret-mode Pallas e2e; the CI `fused-sampling`
-# job gates the same invariants through bench.py in its own budget
-def test_scheduler_guided_fused_parity():
-    """Guided rows ride the fused window (on-chip bitmask + next-state FSM
-    advance) and emit the SAME schema-constrained tokens as the gather
-    scheduler's host-FSM sync path — with zero post-warmup compiles."""
-    from dynamo_tpu.llm.tokenizer import ByteTokenizer
-
-    params = llama.init_params(CFG, jax.random.PRNGKey(0), dtype=jnp.float32)
-    pattern = '\\{"city": "(SF|NY)"\\}'
-
-    def run(impl, warm, steps):
-        sched = Scheduler(CFG.replace(attention_impl=impl), params, SchedulerConfig(
-            num_blocks=128, max_running=4,
-            prefill_buckets=[32], decode_buckets=[1, 2, 4],
-            num_scheduler_steps=steps, enable_prefix_caching=False,
-            enable_overlap_decode=False, enable_mixed_batching=False,
-            guided_pool_rows=64,
-        ), dtype=jnp.float32, eos_token_ids=[0])
-        sched.attach_guided(ByteTokenizer())
-        if warm:
-            sched.warmup(ctx_tokens=64)
-            sched.flight.mark_warmup_done(warmed=True)
-        toks = {}
-        for i in range(2):
-            sched.add_request(
-                f"g{i}", list(range(5 + i, 21 + i)),
-                SamplingParams(temperature=0.0), StopConditions(max_tokens=32),
-                guided={"kind": "regex", "pattern": pattern},
-            )
-        for _ in range(300):
-            if not sched.has_work():
-                break
-            for s, o in sched.step():
-                if o.token_id >= 0:
-                    toks.setdefault(s.request_id, []).append(o.token_id)
-        return sched, toks
-
-    s_m, t_m = run("megakernel", warm=True, steps=8)
-    s_g, t_g = run("gather", warm=False, steps=1)
-    assert t_m == t_g, "fused guided must match the host FSM path"
-    assert s_m.flight.fused_sampled_windows_total > 0  # guided rides sampled epilogue
-    assert s_m.flight.compiles_after_warmup_total == 0, (
-        f"post-warmup compiles: {s_m.flight.post_warmup_keys}"
-    )
-
-
-# ---------------------------------------------------------------------------
-# Fused speculative window
-# ---------------------------------------------------------------------------
-
-
-def _cache_rows(cache, tables, upto):
-    """Gather per-position KV rows [B, upto, KVH*HD] (layer-stacked) from a
-    paged cache given each row's block table and confirmed length."""
-    L, N, BS = cache.shape[0], cache.shape[1], cache.shape[2]
-    out = []
-    for b in range(tables.shape[0]):
-        rows = []
-        for p in range(upto[b]):
-            blk = int(tables[b, p // BS])
-            rows.append(np.asarray(cache[:, blk, p % BS]))
-        out.append(np.stack(rows, axis=1))  # [L, upto, KVH*HD]
-    return out
-
-
-@pytest.mark.slow  # interpret-mode Pallas e2e; the CI `fused-sampling`
-# job gates the same invariants through bench.py in its own budget
-def test_fused_spec_window_mixed_accept_kv_parity():
-    """One fused spec launch (draft != target => real rejections): the
-    host-replay contract reconstructs the confirmed token stream, SOME
-    rounds accept and SOME reject (mixed coverage), and the target cache's
-    confirmed KV rows are bit-for-bit what a clean prefill of that exact
-    stream writes — i.e. rejection costs no rewind and leaves no stale
-    confirmed state."""
-    R, gamma, B, P = 3, 2, 2, 12
-    params = llama.init_params(CFG, jax.random.PRNGKey(0), dtype=jnp.float32)
-    # Draft = target + tiny perturbation: a random-init tiny model's
-    # argmax is noise-sensitive, so 0.002 is already enough for rows to
-    # disagree — some proposals accept, some reject (both asserted).
-    noise = jax.random.PRNGKey(42)
-    leaves, treedef = jax.tree_util.tree_flatten(params)
-    keys = jax.random.split(noise, len(leaves))
-    draft = jax.tree_util.tree_unflatten(
-        treedef,
-        [l + 0.002 * jax.random.normal(k, l.shape, l.dtype)
-         for l, k in zip(leaves, keys)],
-    )
-
-    rng = np.random.default_rng(13)
-    prompts = [rng.integers(1, 255, size=P) for _ in range(B)]
-    tables = np.stack(
-        [np.arange(1 + 2 * b, 3 + 2 * b, dtype=np.int32) for b in range(B)]
-    )
-    t_j = jnp.asarray(tables)
-
-    k_t, v_t = _fresh(CFG, num_blocks=2 * B + 2)
-    k_d, v_d = _fresh(CFG, num_blocks=2 * B + 2)
-    for b in range(B):
-        _, k_t, v_t = _prefill(params, CFG, k_t, v_t, prompts[b], t_j[b])
-        _, k_d, v_d = _prefill(draft, CFG, k_d, v_d, prompts[b], t_j[b])
-
-    t0 = jnp.asarray(rng.integers(1, 255, size=B).astype(np.int32))
-    xprev = jnp.asarray([int(p[-1]) for p in prompts], jnp.int32)
-    pos = jnp.full((B,), P, jnp.int32)
-    active = jnp.ones((B,), bool)
-    greedy = (jnp.zeros((B,), jnp.float32), jnp.zeros((B,), jnp.int32),
-              jnp.ones((B,), jnp.float32))
-    unif = jnp.full((R, B, 2 * gamma + 1), 0.25, jnp.float32)
-
-    n0 = mk.trace_launch_count()
-    toks_out, accepted, k_t, v_t, k_d, v_d = llama.decode_spec_fused(
-        params, MEGA, draft, MEGA, k_t, v_t, k_d, v_d,
-        t0, xprev, pos, t_j, t_j, active, *greedy, unif,
-        rounds=R, gamma=gamma,
-    )
-    assert mk.trace_launch_count() - n0 == 1, "spec window must be ONE launch"
-
-    acc = np.asarray(accepted)  # [R, B]
-    toks_h = np.asarray(toks_out)  # [R, B, gamma+1]
-    assert acc.min() >= 0 and acc.max() <= gamma
-    assert acc.max() > 0, "perturbed draft should still land some proposals"
-    assert acc.min() < gamma, "perturbed draft should also get rejected"
-
-    # Host-replay contract: per round, k accepted proposals then the
-    # verifier's bonus/fallback token; cursor advances k+1.
-    streams, upto = [], []
-    for b in range(B):
-        conf = list(prompts[b]) + [int(t0[b])]
-        for r in range(R):
-            kk = int(acc[r, b])
-            conf += [int(t) for t in toks_h[r, b, :kk]] + [int(toks_h[r, b, gamma])]
-        streams.append(conf)
-        upto.append(len(conf) - 1)  # last token's KV is the next input, unwritten
-
-    # Gold: clean prefill of each confirmed stream (same math, no spec).
-    k_g, v_g = _fresh(CFG, num_blocks=2 * B + 2)
-    for b in range(B):
-        _, k_g, v_g = _prefill(params, CFG, k_g, v_g, streams[b][:-1], t_j[b])
-
-    got_k = _cache_rows(k_t, tables, upto)
-    got_v = _cache_rows(v_t, tables, upto)
-    want_k = _cache_rows(k_g, tables, upto)
-    want_v = _cache_rows(v_g, tables, upto)
-    for b in range(B):
-        np.testing.assert_allclose(got_k[b], want_k[b], atol=2e-4)
-        np.testing.assert_allclose(got_v[b], want_v[b], atol=2e-4)
-
-
-@pytest.mark.slow  # interpret-mode Pallas e2e; the CI `fused-sampling`
-# job gates the same invariants through bench.py in its own budget
-def test_scheduler_spec_fused_e2e():
-    """Scheduler spec path rides the fused spec window (draft attached,
-    gate engaged): greedy token parity with a plain gather scheduler, the
-    spec-fused counters advance, >= 2 accepted tokens/round on the
-    draft==target smoke config, and zero post-warmup compiles across the
-    enlarged key space (fused greedy + sampled + spec executables warmed)."""
-    params = llama.init_params(CFG, jax.random.PRNGKey(0), dtype=jnp.float32)
-
-    def run(impl, draft, warm, steps):
-        sched = Scheduler(CFG.replace(attention_impl=impl), params, SchedulerConfig(
-            num_blocks=128, max_running=4,
-            prefill_buckets=[32], decode_buckets=[1, 2, 4],
-            num_scheduler_steps=steps, enable_prefix_caching=False,
-            enable_overlap_decode=False, enable_mixed_batching=False,
-        ), dtype=jnp.float32)
-        if draft:
-            sched.attach_draft(CFG, params, gamma=2)
-        if warm:
-            sched.warmup(ctx_tokens=64)
-            sched.flight.mark_warmup_done(warmed=True)
-        toks = {}
-        for i in range(3):
-            sched.add_request(f"s{i}", list(range(1 + i, 25 + i)),
-                              SamplingParams(temperature=0.0),
-                              StopConditions(max_tokens=12, ignore_eos=True))
-        for _ in range(300):
-            if not sched.has_work():
-                break
-            for s, o in sched.step():
-                if o.token_id >= 0:
-                    toks.setdefault(s.request_id, []).append(o.token_id)
-        return sched, toks
-
-    s_f, t_f = run("megakernel", draft=True, warm=True, steps=8)
-    assert s_f._use_fused_spec, "fused spec gate must engage on the tiny config"
-    s_g, t_g = run("gather", draft=False, warm=False, steps=1)
-    assert t_f == t_g, "fused spec must emit identical greedy tokens"
-    assert s_f.flight.spec_fused_windows_total > 0
-    assert s_f.flight.spec_fused_accepted_tokens_total > 0
-    assert s_f.flight.compiles_after_warmup_total == 0, (
-        f"post-warmup compiles: {s_f.flight.post_warmup_keys}"
-    )
-    st = s_f.spec_stats.to_dict()
-    assert st["accepted_per_round"] >= 2.0, st
-    stats = s_f.flight.to_stats()
-    assert stats["spec_fused_windows_total"] == s_f.flight.spec_fused_windows_total

@@ -75,7 +75,7 @@ def _x(pid, tid, name, ts, dur):
 def device_fixture_events():
     """One TPU lane with an ops thread + a modules thread, one host lane.
 
-    Kernel lane (7, 1): fused windows at [0,100) [200,250) [300,350) and a
+    Kernel lane (7, 1): attention launches at [0,100) [200,250) [300,350) and a
     sampler fusion at [400,425) — busy union 225us, wall span 425us.
     """
     return [
@@ -84,9 +84,9 @@ def device_fixture_events():
         _tmeta(7, 2, "XLA Modules"),
         _pmeta(99, "python"),
         _tmeta(99, 1, "main"),
-        _x(7, 1, "fused_decode_window(steps=8)", 0, 100),
-        _x(7, 1, "fused_decode_window(steps=8)", 200, 50),
-        _x(7, 1, "fused_decode_window(steps=8)", 300, 50),
+        _x(7, 1, "ragged_paged_attention(layer)", 0, 100),
+        _x(7, 1, "ragged_paged_attention(layer)", 200, 50),
+        _x(7, 1, "ragged_paged_attention(layer)", 300, 50),
         _x(7, 1, "fusion.sample_rows", 400, 25),
         _x(7, 2, "jit_decode_window", 0, 425),  # module span, not a kernel
         _x(99, 1, "host_busy_loop", 0, 1000),   # host lane, excluded
@@ -107,13 +107,13 @@ def test_fixture_exact_attribution():
     assert s.device_lanes == 1
     assert s.device_time_us == FIXTURE_BUSY_US
     assert s.wall_us == FIXTURE_WALL_US
-    fused = s.kernels["fused_decode_window(steps=8)"]
-    assert (fused.count, fused.total_us, fused.max_us) == (3, 200.0, 100.0)
+    attn = s.kernels["ragged_paged_attention(layer)"]
+    assert (attn.count, attn.total_us, attn.max_us) == (3, 200.0, 100.0)
     sample = s.kernels["fusion.sample_rows"]
     assert (sample.count, sample.total_us) == (1, 25.0)
-    assert s.launch_count("fused_decode_window") == 3
+    assert s.launch_count("ragged_paged_attention") == 3
     top = s.top(2)
-    assert top[0]["name"] == "fused_decode_window(steps=8)"
+    assert top[0]["name"] == "ragged_paged_attention(layer)"
     assert top[0]["share"] == pytest.approx(200.0 / 225.0, abs=1e-3)
     assert s.top_share() == pytest.approx(200.0 / 225.0)
 
@@ -194,21 +194,21 @@ def test_gzip_roundtrip_matches_plain():
     gz = parse_trace_bytes(gzip.compress(raw))
     assert not gz.truncated
     assert gz.device_time_us == plain.device_time_us == FIXTURE_BUSY_US
-    assert gz.launch_count("fused_decode_window") == 3
+    assert gz.launch_count("ragged_paged_attention") == 3
 
 
 def test_truncated_json_recovers_prefix_exactly():
-    """Cut the document right after the second fused launch: the scanner
+    """Cut the document right after the second attention launch: the scanner
     must recover exactly the events serialized before the cut."""
     events = device_fixture_events()
     parts = [json.dumps(e) for e in events]
-    keep = 7  # metadata (5) + first two fused launches
+    keep = 7  # metadata (5) + first two attention launches
     text = '{"traceEvents": [' + ", ".join(parts[:keep]) + ", " + parts[keep][:10]
     s = parse_trace_bytes(text.encode())
     assert s.truncated
     assert s.kernel_events == 2
     assert s.device_time_us == 150.0  # [0,100) + [200,250)
-    assert s.launch_count("fused_decode_window") == 2
+    assert s.launch_count("ragged_paged_attention") == 2
 
 
 def test_truncated_gzip_yields_prefix_not_crash():
@@ -243,7 +243,7 @@ def test_load_trace_dir_newest_artifact_wins(tmp_path):
     os.utime(p, (now, now))
     s = load_trace_dir(str(tmp_path))
     assert s is not None and "old_kernel" not in s.kernels
-    assert s.launch_count("fused_decode_window") == 3
+    assert s.launch_count("ragged_paged_attention") == 3
 
 
 # --- continuous sampler gating under an injected clock ------------------------
@@ -326,7 +326,7 @@ def test_capture_error_counts_not_raises(tmp_path):
 
 
 def test_full_window_record_and_sink(tmp_path):
-    probes = [(1e12, 2e12, 0.20, 10), (2e12, 3e12, 0.43, 13)]
+    probes = [(1e12, 2e12, 0.20), (2e12, 3e12, 0.43)]
     sunk = []
     stub = _StubProfiler(tmp_path)
     cont, _ = _clocked(stub, cost_probe=lambda: probes.pop(0),
@@ -338,9 +338,6 @@ def test_full_window_record_and_sink(tmp_path):
     assert rec["flops"] == pytest.approx(1e12)
     assert rec["bytes"] == pytest.approx(1e12)
     assert rec["step_seconds"] == pytest.approx(0.23)
-    assert rec["fused_windows"] == 3            # cost-probe delta
-    assert rec["fused_kernel_launches"] == 3    # trace-side count
-    assert rec["launches_per_fused_window"] == 1.0
     assert rec["device_lane_found"] and not rec["truncated"]
     assert sunk == [rec]
     # keep_artifacts defaults off: the capture dir is gone after parsing.
@@ -422,8 +419,7 @@ def test_record_measured_window_derived_gauges():
     fr.record_measured_window({
         "wall_s": 0.25, "device_time_s": 0.2, "flops": 1e12, "bytes": 1e11,
         "step_seconds": 0.19, "top_kernel_share": 0.6,
-        "launches_per_fused_window": 1.0,
-        "top_kernels": [{"name": "fused_decode_window", "share": 0.6}],
+        "top_kernels": [{"name": "ragged_paged_attention", "share": 0.6}],
     })
     stats = fr.to_stats()
     assert stats["measured_windows_total"] == 1
@@ -432,9 +428,8 @@ def test_record_measured_window_derived_gauges():
     assert stats["measured_device_frac"] == pytest.approx(0.8)
     assert stats["measured_modeled_mfu_ratio"] == pytest.approx(0.19 / 0.2)
     assert stats["measured_top_kernel_share"] == pytest.approx(0.6)
-    assert stats["measured_launches_per_fused_window"] == 1.0
     snap = fr.measured_snapshot()
-    assert snap is not None and snap["top_kernels"][0]["name"] == "fused_decode_window"
+    assert snap is not None and snap["top_kernels"][0]["name"] == "ragged_paged_attention"
 
 
 def test_cost_model_calibration_band():
