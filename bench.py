@@ -167,7 +167,7 @@ def _decode_attention_cpu_parity() -> dict:
             num_blocks=128, max_running=4,
             prefill_buckets=[32], decode_buckets=[1, 2, 4],
             num_scheduler_steps=8, enable_prefix_caching=False,
-            enable_overlap_decode=False, enable_mixed_batching=False,
+            enable_mixed_batching=False,
         ), dtype=jnp.float32)
         toks: dict = {}
         t0 = time.perf_counter()
@@ -637,192 +637,6 @@ def bench_mixed_admission():
     }
 
 
-def bench_decode_overlap():
-    """Zero-bubble decode pipeline at the scheduler: steady-state decode
-    tok/s and decode_host_gap_ms p50/p99, overlap on vs off, at bucket
-    {8, 32}. The overlap path dispatches step N+1 from step N's on-device
-    sampled tokens and retires one step behind, so the host gap between
-    dispatches — readback + bookkeeping + re-upload on the sync path —
-    collapses to the pipeline's own dispatch cost. Greedy token streams are
-    asserted identical between the two modes (the acceptance bar's
-    token-exact parity)."""
-    import jax
-    import jax.numpy as jnp
-
-    from dynamo_tpu.engine.config import get_config
-    from dynamo_tpu.engine.models import llama
-    from dynamo_tpu.engine.sampling import SamplingParams
-    from dynamo_tpu.engine.scheduler import Scheduler, SchedulerConfig, StopConditions
-
-    cfg = get_config("tiny").replace(max_seq_len=4096)
-    params = llama.init_params(cfg, jax.random.PRNGKey(0), dtype=jnp.float32)
-    out_tokens = 160
-
-    def run(bucket: int, overlap: bool) -> dict:
-        sched = Scheduler(cfg, params, SchedulerConfig(
-            num_blocks=max(512, bucket * 16), max_running=bucket,
-            prefill_buckets=[32, 64],
-            decode_buckets=[b for b in (1, 2, 4, 8, 16, 32) if b <= bucket],
-            num_scheduler_steps=1, enable_prefix_caching=False,
-            enable_overlap_decode=overlap,
-        ), dtype=jnp.float32)
-        toks: dict = {}
-        for i in range(bucket):
-            sched.add_request(f"r{i}", list(range(1 + i % 24, 33 + i % 24)),
-                              SamplingParams(temperature=0.0),
-                              StopConditions(max_tokens=out_tokens, ignore_eos=True))
-        while sched.waiting:  # admission (+ executable compiles)
-            sched.step()
-        for _ in range(12):  # pipeline engaged + shapes warm before measuring
-            for s, o in sched.step():
-                if o.token_id >= 0:
-                    toks.setdefault(s.request_id, []).append(o.token_id)
-        t0 = time.perf_counter()
-        n0 = sum(len(v) for v in toks.values())
-        while len(sched.running) == bucket and sched.has_work():
-            for s, o in sched.step():
-                if o.token_id >= 0:
-                    toks.setdefault(s.request_id, []).append(o.token_id)
-        steady_s = time.perf_counter() - t0
-        steady_toks = sum(len(v) for v in toks.values()) - n0
-        while sched.has_work():  # drain the ramp-down tail unmeasured
-            for s, o in sched.step():
-                if o.token_id >= 0:
-                    toks.setdefault(s.request_id, []).append(o.token_id)
-        return {
-            "overlap": overlap,
-            "tok_s": round(steady_toks / max(steady_s, 1e-9), 1),
-            "host_gap_p50_ms": round(sched.flight.gap_percentile(0.50) * 1000, 3),
-            "host_gap_p99_ms": round(sched.flight.gap_percentile(0.99) * 1000, 3),
-            "overlap_steps": sched.overlap_steps_total,
-            "overlap_flushes": sched.overlap_flushes_total,
-            "tokens": toks,
-        }
-
-    points = []
-    for bucket in (8, 32):
-        on = run(bucket, True)
-        off = run(bucket, False)
-        parity = on.pop("tokens") == off.pop("tokens")
-        points.append({
-            "bucket": bucket,
-            "overlap_on": on,
-            "overlap_off": off,
-            "speedup": round(on["tok_s"] / max(off["tok_s"], 1e-9), 3),
-            "token_parity": parity,
-        })
-
-    # Static/dynamic cross-validation of the 1-sync/step invariant: the
-    # dtlint SYNC001 allowlist DECLARES the overlap path's blocking-sync
-    # budget (role=per_step, path=overlap — must be exactly 1 entry), and
-    # the measured steady-state count must agree. If someone adds a stray
-    # readback, dtlint fails statically; if someone allowlists a second
-    # per-step sync, this measurement (and the allowlist shape assert)
-    # fails dynamically — the two views cannot drift apart.
-    import json as _json
-    import os as _os
-
-    import numpy as np
-
-    import dynamo_tpu.engine.scheduler as _sched_mod
-
-    with open(_os.path.join(_os.path.dirname(_os.path.abspath(__file__)),
-                            "tools", "dtlint", "sync_allowlist.json")) as f:
-        _allow = _json.load(f)
-    declared = [e for e in _allow["allowed_syncs"]
-                if e["role"] == "per_step" and e["path"] == "overlap"]
-    assert len(declared) == 1, (
-        f"sync_allowlist declares {len(declared)} per-step overlap syncs; "
-        "the zero-bubble budget is exactly 1"
-    )
-    sched = Scheduler(cfg, params, SchedulerConfig(
-        num_blocks=512, max_running=4, prefill_buckets=[32, 64],
-        decode_buckets=[1, 2, 4], num_scheduler_steps=1,
-        enable_prefix_caching=False, enable_overlap_decode=True,
-    ), dtype=jnp.float32)
-    for i in range(4):
-        sched.add_request(f"s{i}", list(range(3 + i, 27 + i)),
-                          SamplingParams(temperature=0.0),
-                          StopConditions(max_tokens=120, ignore_eos=True))
-    for _ in range(60):
-        if sched._pipe is not None:
-            break
-        sched.step()
-    assert sched._pipe is not None, "overlap pipeline never engaged"
-    sched.step()
-    counter = [0]
-    real_asarray, real_device_get = np.asarray, jax.device_get
-
-    def counting_asarray(a, *args, **kw):
-        if isinstance(a, jax.Array):
-            counter[0] += 1
-        return real_asarray(a, *args, **kw)
-
-    def counting_device_get(x, *args, **kw):
-        counter[0] += 1
-        return real_device_get(x, *args, **kw)
-
-    steps = 10
-    _sched_mod.np.asarray = counting_asarray
-    _sched_mod.jax.device_get = counting_device_get
-    try:
-        for _ in range(steps):
-            sched.step()
-    finally:
-        _sched_mod.np.asarray = real_asarray
-        _sched_mod.jax.device_get = real_device_get
-    while sched.has_work():
-        sched.step()
-    measured_per_step = counter[0] / steps
-    assert measured_per_step <= len(declared), (
-        f"measured {measured_per_step} blocking syncs/step vs "
-        f"{len(declared)} declared in sync_allowlist.json"
-    )
-
-    # Static/dynamic cross-validation of the warmup key space: every
-    # executable kind the flight recorder observed compiling during this
-    # section must be statically enumerable by dtlint's WARM001 scan, at a
-    # statically registered arity. If a new record_exec site appears
-    # without a warmup twin, WARM001 fails statically; if the static
-    # enumeration drifts from what actually dispatches, this check fails
-    # dynamically — the two views of the 0-compile invariant cannot
-    # diverge silently.
-    from tools.dtlint.rules_warmup import static_warmup_report
-
-    static = static_warmup_report(
-        _os.path.dirname(_os.path.abspath(__file__)))
-    dynamic_keys = sched.flight.exec_key_summary()
-    for kind, arities in dynamic_keys.items():
-        assert kind in static["warmed"], (
-            f"recorder compiled kind '{kind}' that WARM001's static warmup "
-            f"enumeration does not register"
-        )
-        static_ar = set(static["warmed"][kind])
-        assert not static_ar or set(arities) <= static_ar, (
-            f"kind '{kind}' compiled at arities {arities} but warmup "
-            f"statically registers {sorted(static_ar)}"
-        )
-
-    return {
-        "points": points,
-        "out_tokens": out_tokens,
-        # The warmup key space, both views.
-        "static_warmed_kinds": sorted(static["warmed"]),
-        "dynamic_exec_kinds": sorted(dynamic_keys),
-        "static_dynamic_warmup_views_agree": True,
-        # The 1-sync/step invariant, both views.
-        "sync_allowlist_per_step_overlap": len(declared),
-        "measured_blocking_syncs_per_step": round(measured_per_step, 3),
-        "static_dynamic_sync_views_agree": measured_per_step <= len(declared),
-        "note": "tiny model — on CPU the dispatch gap the pipeline hides is "
-                "small, so the tok/s ratio is structural, not the TPU win; "
-                "host_gap percentiles + the ≤1-sync bound in "
-                "tests/test_overlap_decode.py carry the CPU-fallback "
-                "acceptance. On a real chip the sync path's gap includes the "
-                "full host round-trip per step.",
-    }
-
-
 def bench_prefix_reuse():
     """Automatic prefix caching, measured at the REAL engine: KV-aware
     routing vs round-robin over two live Schedulers (tiny model). Groups of
@@ -871,13 +685,13 @@ def bench_prefix_reuse():
                 cfg, params,
                 SchedulerConfig(
                     # Sequential single-request serving: decode bucket 1
-                    # only, mixed/overlap paths off — keeps the warmup grid
+                    # only, mixed steps off — keeps the warmup grid
                     # (2 workers × every shape) CPU-affordable while the
                     # serving-hot prefill buckets stay real.
                     num_blocks=num_blocks, max_running=8,
                     prefill_buckets=[128, 256, 512, 1024],
                     decode_buckets=[1], num_scheduler_steps=1,
-                    enable_mixed_batching=False, enable_overlap_decode=False,
+                    enable_mixed_batching=False,
                 ),
                 dtype=jnp.float32,
                 on_kv_event=lambda ev, w=w: indexer.apply_event(w, ev.to_wire()),
@@ -1960,25 +1774,6 @@ def child_main() -> None:
     else:
         errors.append("mixed_admission skipped: budget")
 
-    # --- zero-bubble decode overlap (scheduler-level, CPU subprocess) -------
-    decode_overlap = None
-    if remaining() > 60:
-        try:
-            decode_overlap, err = _run_cpu_subprocess(
-                [sys.executable, os.path.abspath(__file__)], "points",
-                max(60, remaining() - 10), extra_env={"BENCH_OVERLAP_ONLY": "1"},
-            )
-            if decode_overlap is None:
-                errors.append(f"decode_overlap: {err}")
-            else:
-                _emit_partial("decode_overlap", decode_overlap)
-        except subprocess.TimeoutExpired:
-            errors.append("decode_overlap: subprocess timed out")
-        except Exception as e:  # noqa: BLE001
-            errors.append(f"decode_overlap: {type(e).__name__}: {e}")
-    else:
-        errors.append("decode_overlap skipped: budget")
-
     # --- engine-level prefix reuse (real schedulers, CPU subprocess) --------
     prefix_reuse = None
     if remaining() > 60:
@@ -2099,14 +1894,13 @@ def child_main() -> None:
                               mixed_admission=mixed_admission,
                               observability=observability,
                               guided_overhead=guided_overhead,
-                              decode_overlap=decode_overlap,
                               prefix_reuse=prefix_reuse,
                               decode_attention=decode_attention,
                               autoscale=autoscale, elastic=elastic,
                               device_truth=device_truth)), flush=True)
 
 
-def assemble(decode_points, prefill_detail, http, device, model, errors, tpu_http=None, router_prefix=None, large_model=None, mixed_admission=None, observability=None, guided_overhead=None, decode_overlap=None, prefix_reuse=None, decode_attention=None, autoscale=None, elastic=None, device_truth=None) -> dict:
+def assemble(decode_points, prefill_detail, http, device, model, errors, tpu_http=None, router_prefix=None, large_model=None, mixed_admission=None, observability=None, guided_overhead=None, prefix_reuse=None, decode_attention=None, autoscale=None, elastic=None, device_truth=None) -> dict:
     """Build the final JSON object from whatever sections completed."""
     hbm_gbps, _ = chip_peaks(device)
     best = max(decode_points, key=lambda p: p.get("achieved_hbm_gbps") or 0.0) if decode_points else None
@@ -2136,7 +1930,6 @@ def assemble(decode_points, prefill_detail, http, device, model, errors, tpu_htt
             "observability": observability,
             "device_truth": device_truth,
             "guided_overhead": guided_overhead,
-            "decode_overlap": decode_overlap,
             "autoscale": autoscale,
             "elastic": elastic,
             "device": device,
@@ -2230,7 +2023,6 @@ def main() -> None:
             observability=partials.get("observability"),
             device_truth=partials.get("device_truth"),
             guided_overhead=partials.get("guided_overhead"),
-            decode_overlap=partials.get("decode_overlap"),
             prefix_reuse=partials.get("prefix_reuse"),
             decode_attention=partials.get("decode_attention"),
             autoscale=partials.get("autoscale"),
@@ -2253,13 +2045,6 @@ if __name__ == "__main__":
 
         jax.config.update("jax_platforms", "cpu")
         print(json.dumps(bench_prefix_reuse()), flush=True)
-    elif os.environ.get("BENCH_OVERLAP_ONLY") == "1":
-        # CPU-pinned: the subject is pipeline structure (overlapped vs sync
-        # step loop), not device speed.
-        import jax
-
-        jax.config.update("jax_platforms", "cpu")
-        print(json.dumps(bench_decode_overlap()), flush=True)
     elif os.environ.get("BENCH_MIXED_ONLY") == "1":
         # CPU-pinned like the http section: the subject is scheduler
         # structure (mixed vs phase-separated steps), not the device.

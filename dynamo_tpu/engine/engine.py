@@ -560,11 +560,6 @@ class TpuEngine:
             "mixed_steps_total": m.mixed_steps_total,
             "mixed_prefill_tokens_total": m.mixed_prefill_tokens_total,
             "mixed_decode_tokens_total": m.mixed_decode_tokens_total,
-            # Zero-bubble decode pipeline: overlapped steps vs flushes back
-            # to the sync path (admission/finish/growth/extras). The gap
-            # histogram itself rides flight.to_stats() below.
-            "overlap_steps_total": m.overlap_steps_total,
-            "overlap_flushes_total": m.overlap_flushes_total,
             # Automatic prefix caching: skipped prompt tokens + the block
             # hit/miss/evict/onboard account (Grafana "Prefix cache" rows).
             "cached_tokens_total": m.cached_tokens_total,

@@ -45,11 +45,9 @@ PHASES = ("prefill", "decode", "mixed", "wave", "spec")
 STEP_RECORD = "flight.step"
 RECENT_STEPS = 64
 
-# Host-gap buckets: the decode pipeline's subject is the SUB-millisecond
-# window between a dispatch returning and the next dispatch being issued —
-# far finer-grained than step durations. Overlapped steady state should sit
-# in the lowest buckets; sync-path steps pay the full
-# readback+bookkeeping+upload gap.
+# Host-gap buckets: the window between a decode dispatch returning and the
+# next one being issued (readback + bookkeeping + upload) — far
+# finer-grained than step durations.
 GAP_BUCKETS: Tuple[float, ...] = (
     0.0001, 0.00025, 0.0005, 0.001, 0.0025, 0.005, 0.01, 0.025, 0.05, 0.1, 0.25
 )
@@ -300,9 +298,10 @@ class FlightRecorder:
 
     # --- measured device truth ----------------------------------------------
     def roofline_totals(self) -> Tuple[float, float, float]:
-        """Cumulative (flops, bytes, modeled step seconds) across every phase — the ContinuousProfiler's cost probe. Deltas of
-        this across a profile window attribute measured device time to the
-        modeled work done in the same span."""
+        """Cumulative (flops, bytes, modeled step seconds) across every
+        phase — the ContinuousProfiler's cost probe. Deltas of this across a
+        profile window attribute measured device time to the modeled work
+        done in the same span."""
         f = b = s = 0.0
         for r in self._roofline.values():
             f += r.flops_total

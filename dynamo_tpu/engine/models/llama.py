@@ -1510,50 +1510,6 @@ def mixed_step(
     return logits, k_new, v_new
 
 
-def decode_sample(
-    params: Params,
-    config: ModelConfig,
-    k_cache: jax.Array,  # [L, N, BS, KVH*HD]
-    v_cache: jax.Array,
-    tpa: jax.Array,  # [3, B] i32 — rows: (tokens, positions, active)
-    block_tables: jax.Array,  # [B, max_blocks]
-    temps: jax.Array,  # [B] f32 (0 = greedy)
-    top_ks: jax.Array,  # [B] i32 (0 = off)
-    top_ps: jax.Array,  # [B] f32 (1 = off)
-    rng_key: jax.Array,
-    moe_stats: bool = False,  # static: also return {"moe_dropped", "moe_assignments"}
-) -> Tuple[jax.Array, ...]:
-    """One FUSED decode+sample step for the zero-bubble overlap pipeline:
-    the forward pass, on-device sampling, and the next step's input-state
-    advance run as ONE executable. Returns ``(sampled [B] i32,
-    next_tpa [3, B] i32, k_cache, v_cache)``.
-
-    ``next_tpa`` is the on-device token feedback: row 0 is the sampled
-    tokens (the next step's inputs), row 1 the advanced positions, row 2
-    the unchanged active lanes — so the scheduler can dispatch step N+1 by
-    handing step N's ``next_tpa`` straight back without a host round-trip
-    on the critical path. The [3, B] packing also serves the sync path:
-    tokens/positions/active ride ONE host→device transfer instead of three."""
-    tokens = tpa[0]
-    positions = tpa[1]
-    active = tpa[2].astype(bool)
-    res = decode(
-        params, config, k_cache, v_cache, tokens, positions, block_tables, active,
-        moe_stats=moe_stats,
-    )
-    if moe_stats:
-        logits, k_new, v_new, aux = res
-    else:
-        logits, k_new, v_new = res
-    from dynamo_tpu.engine.sampling import sample_batch
-
-    sampled = sample_batch(logits, temps, top_ks, top_ps, rng_key)
-    next_tpa = jnp.stack([sampled, positions + 1, tpa[2]])
-    if moe_stats:
-        return sampled, next_tpa, k_new, v_new, aux
-    return sampled, next_tpa, k_new, v_new
-
-
 def embed(
     params: Params,
     config: ModelConfig,

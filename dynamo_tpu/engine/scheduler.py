@@ -277,19 +277,6 @@ class SchedulerConfig:
     # its blocks, re-prefill it later) instead of finishing the starved
     # sequence with "length" (ref: vLLM recompute preemption).
     enable_preemption: bool = True
-    # Zero-bubble decode: overlap the host's per-step bookkeeping with the
-    # NEXT step's device compute. The fused decode+sample executable
-    # (llama.decode_sample) returns the sampled tokens as a DEVICE array
-    # that feeds straight back as the next dispatch's input, so step N+1
-    # launches before step N's tokens ever reach the host; the readback +
-    # stop/detok bookkeeping then run one step behind, overlapped with
-    # device compute. Batch-composition changes (admission, finish,
-    # preemption, block-table growth) and per-row extras (guided /
-    # processors / seeded sampling / logprobs / penalties — all need host
-    # work between steps) flush the pipeline back to the sync path, same
-    # fallback shape as the spec/multi-step exclusions. Streaming runs one
-    # step behind on this path (README "Decode pipeline").
-    enable_overlap_decode: bool = True
     # Guided decoding: initial device mask-pool capacity in FSM-state rows.
     # The masked-sampling executable's shape is (decode_bucket, pool_rows);
     # warmup() precompiles it at this capacity, so as long as the total
@@ -340,12 +327,6 @@ class ForwardPassMetrics:
     mixed_steps_total: int = 0
     mixed_prefill_tokens_total: int = 0
     mixed_decode_tokens_total: int = 0
-    # Zero-bubble decode pipeline: steps that ran overlapped (dispatch N+1
-    # before step N's readback) and pipeline flushes back to the sync path
-    # (admission/finish/growth/extras). flushes/steps is the fraction of
-    # pipeline restarts — high ratios mean the traffic mix defeats overlap.
-    overlap_steps_total: int = 0
-    overlap_flushes_total: int = 0
     # Automatic prefix caching: prompt tokens served from resident KV
     # instead of prefill compute, and the block-granular hit/miss/evict/
     # onboard account behind them. hit/(hit+miss) is the block hit rate;
@@ -599,23 +580,8 @@ class Scheduler:
         # candidate cap's (ids, logprobs) in the same dispatch.
         self._sample_tlp_jit = jax.jit(sample_batch_top_logprobs)
         self._guided_sample_tlp_jit = jax.jit(guided_sample_batch_top_logprobs)
-        # Zero-bubble overlapped decode (llama.decode_sample): fused
-        # decode+sample+state-advance, device-side token feedback. _pipe
-        # holds the in-flight step (see _overlap_step); _tables_cache keeps
-        # the last decode block-table upload so tables cross the wire only
-        # when a table actually changes.
-        # (eva: the pipeline hands positions on from device to device and
-        # cannot stop a row at its window boundary for the roll.)
-        self._supports_overlap = hasattr(model, "decode_sample") and not self._eva
-        if self._supports_overlap:
-
-            def decode_sample(p, k, v, tpa, bt, te, tk, tp, key):
-                return model.decode_sample(
-                    p, self.mc, k, v, tpa, bt, te, tk, tp, key, **stats_kw
-                )
-
-            self._decode_sample_jit = jax.jit(decode_sample, donate_argnums=(1, 2))
-        self._pipe: Optional[dict] = None
+        # The last decode block-table upload (_decode_tables): tables cross
+        # the wire only when a table actually changes.
         self._tables_cache: Optional[tuple] = None
         # eva: the summarising program of a roll (one window of one sequence),
         # and what the rolls have done so far.
@@ -633,27 +599,12 @@ class Scheduler:
         # dispatch path forms the batch.
         self._step_span: Optional[StepSpan] = None
         self._plan_span: Optional[StepSpan] = None
-        self.overlap_steps_total = 0
-        self.overlap_flushes_total = 0
-        # Deferred-retirement KV rollback: zero the slot the speculative
-        # in-flight step wrote for a row that turned out finished (one
-        # donated in-place scatter — a bare .at[].set would copy the cache).
-        from dynamo_tpu.engine.kv_cache import QuantKv
-
-        def _zero_slot(c, b, o):
-            if isinstance(c, QuantKv):
-                return QuantKv(c.q.at[:, b, o].set(0), c.scale.at[:, b, o].set(0))
-            return c.at[:, b, o].set(jnp.zeros((), c.dtype))
-
-        def kv_rollback(k, v, b, o):
-            return _zero_slot(k, b, o), _zero_slot(v, b, o)
-
-        self._kv_zero_jit = jax.jit(kv_rollback, donate_argnums=(0, 1))
         # Prefix-cache copy-on-write: duplicate one block's contents into a
         # private block (full-cover hits recompute only the LAST prompt
         # token, whose KV write would otherwise land in a block other
         # sequences still reference). Donated in-place scatter, one
         # executable for every (src, dst) pair; warmed against scratch.
+        from dynamo_tpu.engine.kv_cache import QuantKv
 
         def _copy_block_arr(c, src, dst):
             if isinstance(c, QuantKv):
@@ -923,8 +874,6 @@ class Scheduler:
             mixed_steps_total=self.mixed_steps_total,
             mixed_prefill_tokens_total=self.mixed_prefill_tokens_total,
             mixed_decode_tokens_total=self.mixed_decode_tokens_total,
-            overlap_steps_total=self.overlap_steps_total,
-            overlap_flushes_total=self.overlap_flushes_total,
             cached_tokens_total=self.cached_tokens_total,
             prefix_hit_blocks_total=a.hit_blocks_total,
             prefix_miss_blocks_total=a.miss_blocks_total,
@@ -1113,12 +1062,6 @@ class Scheduler:
         phase stalls the other. Otherwise the phase-separated order runs:
         decode first (ITL), then admit one prefill (TTFT).
 
-        With an overlapped decode pipeline in flight (``_pipe``), the
-        iteration instead dispatches step N+1 from the previous step's
-        on-device sampled tokens and retires step N while the device runs —
-        unless a composition change (waiting work, aborts, block growth,
-        finish) forces a flush back to this sync path.
-
         The iteration is one ``sched.step`` span whose phases (plan, upload,
         launch, sync, sample, emit, account — runtime/tracing.py) partition
         it: ``sched.plan`` opens here and each dispatch path closes it where
@@ -1137,15 +1080,7 @@ class Scheduler:
         return outputs
 
     def _step(self, outputs: List[tuple]) -> None:
-        # Deadline sweep runs before the overlap fast path too: an expired
-        # row marks itself aborted, which forces the pipeline flush below
-        # (otherwise a pure-decode window could outlive the deadline).
         self._sweep_deadlines()
-        if self._pipe is not None:
-            if self._overlap_should_continue():
-                self._overlap_step(outputs)
-                return
-            self._overlap_flush(outputs)
         self._reap_aborted(outputs)
         if self._eva:
             self._roll_windows()
@@ -1975,17 +1910,6 @@ class Scheduler:
                     )
                 )
                 count += 1
-                if self.sc.enable_overlap_decode and self._supports_overlap:
-                    # Fused overlap step: same (bucket, width) key space as
-                    # plain decode, so the pipeline never compiles mid-
-                    # traffic (flight-recorder 0-post-warmup gate).
-                    self.flight.record_exec("decode_sample", (bucket, width))
-                    res = self._decode_sample_jit(
-                        self.params, self.cache.k, self.cache.v, tpa, tables,
-                        temps, tks, tps, key,
-                    )
-                    _, _, self.cache.k, self.cache.v = self._consume_aux(res)
-                    count += 1
                 if self.sc.num_scheduler_steps > 1 and self._supports_multi_step:
                     for w, mjit in self._decode_multi_jits.items():
                         self.flight.record_exec("decode_multi", (w, bucket, width))
@@ -2016,15 +1940,6 @@ class Scheduler:
                 jnp.ones((bucket,), jnp.float32), key, None,
             )
             count += 3
-        # Deferred-retirement KV rollback (overlap pipeline): one executable,
-        # warmed against the scratch slot so a finish-mid-pipeline never
-        # compiles under traffic.
-        if self.sc.enable_overlap_decode and self._supports_overlap:
-            self.flight.record_exec("kv_rollback", ())
-            self.cache.k, self.cache.v = self._kv_zero_jit(
-                self.cache.k, self.cache.v, jnp.int32(0), jnp.int32(0)
-            )
-            count += 1
         if self._eva:
             # The roll program: one executable, warmed against the scratch
             # block (a table of zeros reads and writes block 0).
@@ -2279,7 +2194,7 @@ class Scheduler:
         where the target's chunks started."""
         self._draft_catchup(seq, pf_tokens, seq.num_computed)
 
-    # --- zero-bubble overlapped decode --------------------------------------
+    # --- decode ---------------------------------------------------------------
     def _decode_tables(self, batch: List[Sequence], bucket: int, width: int) -> jnp.ndarray:
         """Decode block tables as a device array, re-uploaded ONLY when a
         table actually changed. Block tables are append-only between
@@ -2310,187 +2225,6 @@ class Scheduler:
         if prev is not None and prev[4].get("decode"):
             self.flight.record_host_gap((time.monotonic_ns() - prev[2]) / 1e9)
 
-    def _overlap_row_ok(self, seq: Sequence) -> bool:
-        """Rows needing host work between steps can't ride the pipeline:
-        guided (the FSM must advance before the next mask), processors and
-        penalties (host/history logits edits), seeded sampling (per-row
-        keys), logprobs (separate readback shape), disagg prefill-role
-        exports. Same fallback shape as the spec/multi-step exclusions."""
-        s = seq.sampling
-        return not (
-            seq.aborted
-            or seq.guided is not None
-            or s.logprobs
-            or s.top_logprobs
-            or s.logits_processors
-            or s.has_penalties
-            or (s.seed is not None and s.temperature > 0)
-            or seq.keep_blocks_on_finish
-        )
-
-    def _overlap_start_ok(self, batch: List[Sequence]) -> bool:
-        return (
-            self.sc.enable_overlap_decode
-            and self._supports_overlap
-            and self.draft_params is None
-            and not self.waiting
-            and all(self._overlap_row_ok(s) for s in batch)
-        )
-
-    def _overlap_can_dispatch(self, batch: List[Sequence], positions: List[int]) -> bool:
-        """The next fused dispatch writes KV at each row's input position:
-        every slot must already exist (block-table growth flushes to the
-        sync path, which allocates/preempts there) and stay inside
-        max_seq_len."""
-        bs = self.mc.block_size
-        for seq, p in zip(batch, positions):
-            if p + 1 > len(seq.block_ids) * bs or p >= self.mc.max_seq_len:
-                return False
-        return True
-
-    def _overlap_should_continue(self) -> bool:
-        pipe = self._pipe
-        return (
-            not self.waiting
-            and not any(s.aborted for s in pipe["batch"])
-            and self._overlap_can_dispatch(pipe["batch"], pipe["positions"])
-        )
-
-    def _dispatch_overlap(self, pipe: dict, tpa_dev) -> None:
-        """Issue one fused decode+sample dispatch (async — returns as soon as
-        the device has the work) and stage its outputs in the pipe."""
-        with self._launch("decode_sample", decode=True):
-            self._step_counter += 1
-            key = jax.random.fold_in(self._rng, self._step_counter)
-            exec_key = (pipe["bucket"], pipe["width"])
-            self.flight.record_exec("decode_sample", exec_key)
-            self._note_step("decode_sample", exec_key, pipe["batch"], decode=len(pipe["batch"]))
-            res = self._decode_sample_jit(
-                self.params, self.cache.k, self.cache.v, tpa_dev, pipe["tables"],
-                pipe["temps"], pipe["tks"], pipe["tps"], key,
-            )
-            sampled, next_tpa, self.cache.k, self.cache.v = self._consume_aux(res)
-        pipe["sampled"] = sampled
-        pipe["next_tpa"] = next_tpa
-        self.overlap_steps_total += 1
-
-    def _overlap_start(self, batch: List[Sequence], bucket: int, width: int) -> bool:
-        """Dispatch pipeline step 0. No tokens are retired this iteration —
-        streaming runs one step behind on the overlap path (documented in
-        README "Decode pipeline")."""
-        positions = [s.total_len - 1 for s in batch]
-        if not self._overlap_can_dispatch(batch, positions):
-            return False
-        from dynamo_tpu.engine.sampling import pack_param_rows
-
-        temps, top_ks, top_ps = pack_param_rows([s.sampling for s in batch], bucket)
-        tpa = np.zeros((3, bucket), dtype=np.int32)
-        for i, seq in enumerate(batch):
-            tpa[0, i] = seq.all_ids[-1]
-            tpa[1, i] = positions[i]
-            tpa[2, i] = 1
-        self._end_plan()
-        with self._span("sched.upload"):
-            pipe = {
-                "batch": batch, "bucket": bucket, "width": width,
-                "tables": self._decode_tables(batch, bucket, width),
-                "temps": jnp.asarray(temps), "tks": jnp.asarray(top_ks),
-                "tps": jnp.asarray(top_ps),
-            }
-            tpa_d = jnp.asarray(tpa)
-        self._dispatch_overlap(pipe, tpa_d)
-        pipe["positions"] = [p + 1 for p in positions]
-        self._pipe = pipe
-        self._begin_plan()
-        return True
-
-    def _overlap_step(self, outputs: List[tuple]) -> None:
-        """Steady state: dispatch step N+1 from the previous step's ON-DEVICE
-        sampled tokens, THEN read back and retire step N — the readback and
-        all host bookkeeping overlap step N+1's device compute (JAX async
-        dispatch). Exactly ONE blocking sync per steady-state step. A row
-        that turns out finished at step N makes step N+1's token for it
-        speculative garbage — the flush discards it and rolls back its KV
-        write slot."""
-        pipe = self._pipe
-        prev_sampled = pipe["sampled"]
-        # Capture rollback targets BEFORE retirement mutates block tables:
-        # the N+1 dispatch writes each row's last-appended token's KV at
-        # the row's pre-retire total_len.
-        rollback = self._rollback_targets(pipe["batch"])
-        self._end_plan()
-        t0 = time.monotonic_ns()
-        self._dispatch_overlap(pipe, pipe["next_tpa"])
-        pipe["positions"] = [p + 1 for p in pipe["positions"]]
-        # Retire step N while N+1 runs on device.
-        with self._span("sched.sync"):
-            sampled_h = np.asarray(prev_sampled)  # the step's one blocking sync
-        with self._span("sched.emit"):
-            finished = False
-            for i, seq in enumerate(pipe["batch"]):
-                self._append_token(seq, int(sampled_h[i]), outputs)
-                if seq.state != SeqState.RUNNING:
-                    finished = True
-        dur = (time.monotonic_ns() - t0) / 1e9
-        with self._span("sched.account"):
-            self.flight.record_step(
-                "decode", dur, len(pipe["batch"]),
-                kv_read_tokens=sum(s.total_len for s in pipe["batch"]),
-            )
-            self._bill_step(dur, [(s, "decode", 1, s.total_len) for s in pipe["batch"]])
-            self.telemetry.observe("itl", dur)
-        self._begin_plan()
-        if finished:
-            self._overlap_flush(outputs, rollback=rollback)
-
-    def _rollback_targets(self, batch: List[Sequence]) -> List[Optional[tuple]]:
-        """(block, offset) each row's in-flight dispatch writes to — the slot
-        to zero if the row turns out finished while that dispatch runs."""
-        bs = self.mc.block_size
-        out: List[Optional[tuple]] = []
-        for seq in batch:
-            p = seq.total_len
-            out.append((seq.block_ids[p // bs], p % bs) if p < len(seq.block_ids) * bs else None)
-        return out
-
-    def _overlap_flush(self, outputs: List[tuple], rollback: Optional[List] = None) -> None:
-        """Absorb the in-flight step and return to the sync path. Rows still
-        running keep their token (the in-flight step computed exactly what
-        the sync path would have — no wasted work); rows that finished at
-        the previous retire discard their speculative token and get the KV
-        slot the in-flight step wrote zeroed (same shape as the preemption-
-        resume recompute: the device state must not outrun the host's
-        account of the sequence). ``rollback`` is only passed by
-        _overlap_step's finish path — on a plain composition flush every
-        row is still running and nothing rolls back."""
-        pipe, self._pipe = self._pipe, None
-        self.overlap_flushes_total += 1
-        with self._span("sched.sync"):
-            sampled_h = np.asarray(pipe["sampled"])
-        for i, seq in enumerate(pipe["batch"]):
-            if seq.state != SeqState.RUNNING:
-                # Rollback applies ONLY to rows that finished at the previous
-                # retire (a row preempted by a batchmate's capacity growth
-                # below lands here WAITING — its blocks are already released
-                # and possibly re-owned, nothing to zero).
-                if (
-                    rollback is not None and rollback[i] is not None
-                    and seq.state == SeqState.FINISHED and not seq.aborted
-                ):
-                    blk, off = rollback[i]
-                    self.flight.record_exec("kv_rollback", ())
-                    with self._launch("kv_rollback"):
-                        self.cache.k, self.cache.v = self._kv_zero_jit(
-                            self.cache.k, self.cache.v, jnp.int32(blk), jnp.int32(off)
-                        )
-                continue
-            if seq.aborted:
-                continue  # _reap_aborted finishes it without the extra token
-            self._ensure_block_capacity(seq)
-            if seq.state != SeqState.RUNNING:
-                continue
-            self._append_token(seq, int(sampled_h[i]), outputs)
-
     def _decode_step(self) -> List[tuple]:
         outputs: List[tuple] = []
         # Batch size caps at the largest decode bucket — NOT max_running:
@@ -2502,42 +2236,33 @@ class Scheduler:
         batch = self.running[:n]
         bucket = next_bucket(n, self.sc.decode_buckets)
 
-        if self.draft_params is not None and not any(
+        # Rows that need the host between their tokens: penalties (history
+        # mutates from token to token), logits processors, logprobs /
+        # top_logprobs, guided rows (the FSM advances on the host; proposal
+        # sampling ignores its mask) and seeded sampled rows (neither a spec
+        # round nor decode_multi threads per-row keys; a greedy row's seed is
+        # a no-op). One such row takes its whole batch to single steps.
+        host_between_tokens = any(
             seq.sampling.logits_processors
             or seq.sampling.logprobs
             or seq.sampling.top_logprobs
             or seq.sampling.has_penalties
-            or seq.mm_features is not None
-            # Guided rows can't ride speculation (proposal sampling
-            # ignores the FSM mask): the batch gracefully falls back to
-            # the non-spec single-step path below.
             or seq.guided is not None
-            # Seeded sampling needs per-row keys the spec round doesn't
-            # thread; greedy seeded rows are fine (seed is a no-op).
             or (seq.sampling.seed is not None and seq.sampling.temperature > 0)
             for seq in batch
+        )
+        # Each falls through to the next when blocks/limits don't allow it.
+        if (
+            self.draft_params is not None
+            and not host_between_tokens
+            and not any(seq.mm_features is not None for seq in batch)
+            and self._decode_spec(batch, bucket, outputs)
         ):
-            # Falls through to plain decode when blocks/limits don't allow a round.
-            if self._decode_spec(batch, bucket, outputs):
-                return outputs
-
-        # A window needs no host between its tokens: penalties (history
-        # mutates inside the window), logits processors, logprobs /
-        # top_logprobs, guided rows (the FSM advances on the host) and seeded
-        # sampled rows (decode_multi threads no per-row keys) take their whole
-        # batch to single steps.
+            return outputs
         if (
             self.sc.num_scheduler_steps > 1
             and self._supports_multi_step
-            and not any(
-                seq.sampling.logits_processors
-                or seq.sampling.logprobs
-                or seq.sampling.top_logprobs
-                or seq.sampling.has_penalties
-                or seq.guided is not None
-                or (seq.sampling.seed is not None and seq.sampling.temperature > 0)
-                for seq in batch
-            )
+            and not host_between_tokens
             and self._decode_multi(batch, bucket, outputs)
         ):
             return outputs
@@ -2548,12 +2273,6 @@ class Scheduler:
         # the executable count at log2(max_blocks) so warmup() precompiles
         # them all.
         width = self._width_bucket(max(len(seq.block_ids) for seq in batch))
-
-        # Zero-bubble pipeline entry: no-extras batches with no waiting work
-        # hand off to the overlapped fused-step loop (tokens stream one step
-        # behind; this iteration emits nothing).
-        if self._overlap_start_ok(batch) and self._overlap_start(batch, bucket, width):
-            return outputs
 
         tpa = np.zeros((3, bucket), dtype=np.int32)
         for i, seq in enumerate(batch):

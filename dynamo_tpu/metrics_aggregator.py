@@ -90,7 +90,6 @@ COUNTER_KEYS = (
     "request_total", "preemptions_total",
     "moe_dropped_total", "moe_assignments_total",
     "mixed_steps_total", "mixed_prefill_tokens_total", "mixed_decode_tokens_total",
-    "overlap_steps_total", "overlap_flushes_total",
     "cached_tokens_total",
     "prefix_hit_blocks_total", "prefix_miss_blocks_total",
     "prefix_evicted_blocks_total", "prefix_onboard_total",

@@ -19,8 +19,8 @@ Three layers, all exposed on the worker health server and the stats plane:
 - ``ContinuousProfiler`` — a duty-cycled background sampler that opens
   short capture windows at a bounded rate, parses the artifact, and feeds
   the per-window deltas (device time, kernel top-N) into the flight
-  recorder so the modeled ``mfu_*`` / ``hbm_frac_*``
-  gauges gain *measured* siblings. The duty cycle is clamped
+  recorder so the modeled ``mfu_*`` / ``hbm_frac_*`` gauges gain *measured*
+  siblings. The duty cycle is clamped
   (``window_s / effective_interval ≤ max_duty``) so the plane stays inside
   the observability budget, and the gating is pure arithmetic over an
   injected clock so CI can drive it deterministically.

@@ -290,16 +290,15 @@ def test_real_baseline_entries_all_carry_reasons():
 
 def test_sync_allowlist_declares_one_per_step_sync_per_path():
     """The statically declared blocking-sync budget: each decode path gets
-    AT MOST one per_step allowlist entry, and the overlap path's budget is
-    exactly 1 (PR 4's invariant; bench.py cross-checks the measured
-    count)."""
+    AT MOST one per_step allowlist entry, and the single-step path's budget
+    is exactly 1."""
     with open(os.path.join(REPO, "tools/dtlint/sync_allowlist.json")) as f:
         cfg = json.load(f)
     per_step = [e for e in cfg["allowed_syncs"] if e["role"] == "per_step"]
     by_path = {}
     for e in per_step:
         by_path.setdefault(e["path"], []).append(e)
-    assert len(by_path.get("overlap", [])) == 1
+    assert len(by_path.get("sync", [])) == 1
     for path, entries in by_path.items():
         assert len(entries) == 1, f"path {path} declares {len(entries)} per-step syncs"
 

@@ -264,7 +264,7 @@ def test_what_equates_position_and_row_is_refused_for_eva(params, what):
 
 def test_eva_scheduler_turns_off_what_it_refuses(params):
     s = _bare(params)
-    assert s.sc.enable_prefix_caching is False and not s._supports_overlap and not s._supports_chunk_admit
+    assert s.sc.enable_prefix_caching is False and not s._supports_chunk_admit
     assert s.max_blocks_per_seq == math.ceil((M * ((CFG.max_seq_len - 1) // W) + W) / CFG.block_size)
     assert s.warmup(ctx_tokens=64) > 0 and ("eva_roll",) in s.flight._exec_keys  # the roll program is warmed
 

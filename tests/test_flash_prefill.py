@@ -134,7 +134,7 @@ def test_has_prefix_keys_an_executable_only_where_it_changes_the_program(attenti
     sched = Scheduler(c, params, SchedulerConfig(
         num_blocks=64, max_running=4, prefill_buckets=[32], decode_buckets=[4],
         max_prefill_chunk=32, mixed_prefill_budget=32, num_scheduler_steps=1,
-        enable_prefix_caching=False, enable_overlap_decode=False,
+        enable_prefix_caching=False,
     ), dtype=jnp.float32)
     assert sched._use_flash_prefill and sched._hp_static is static
     stop = StopConditions(max_tokens=6, ignore_eos=True)

@@ -28,7 +28,7 @@ PHASES = ("sched.plan", "sched.upload", "sched.launch", "sched.sync", "sched.sam
 
 def mk_sched(**kw) -> Scheduler:
     sc = dict(num_blocks=128, max_running=8, prefill_buckets=[16, 32], decode_buckets=[1, 2, 4, 8],
-              num_scheduler_steps=1, enable_prefix_caching=False, enable_overlap_decode=False)
+              num_scheduler_steps=1, enable_prefix_caching=False)
     sc.update(kw)
     return Scheduler(CFG, PARAMS, SchedulerConfig(**sc), dtype=jnp.float32)
 
@@ -55,7 +55,6 @@ def drain(sched, late=()):
 # Each case dispatches one step path: (scheduler settings, first requests, late requests, kind).
 PATHS = {
     "decode": (dict(), [("a", list(range(1, 20)), 6)], [], "decode"),
-    "decode_sample": (dict(enable_overlap_decode=True), [("a", list(range(1, 20)), 8)], [], "decode_sample"),
     "decode_multi": (dict(num_scheduler_steps=8), [("a", list(range(1, 20)), 12)], [], "decode_multi"),
     "prefill": (dict(), [("a", list(range(1, 40)), 3)], [], "prefill"),
     "mixed": (dict(), [("a", list(range(1, 20)), 12)], [("b", list(range(30, 70)), 4)], "mixed"),
@@ -104,7 +103,7 @@ def test_every_step_path_writes_a_step_with_its_kind_and_phases_partition_it(pat
         covered, total = covered + own, total + dur
         if st[4]:  # an iteration that dispatched: launch, and what the exec/done marks carried
             assert {"kind", "key", "rows", "ctx", "prefill", "decode"} <= set(st[4])
-            assert any(e[0] == "sched.launch" for e in top) or path in ("decode_sample",)
+            assert any(e[0] == "sched.launch" for e in top)
     assert covered >= 0.98 * total
     dispatched = [st for st, _ in steps if st[4] and st[4]["kind"] == kind]
     names = {e[0] for st, top in steps if st in dispatched for e in top}
@@ -251,7 +250,7 @@ def test_spans_reach_a_real_profiler_trace(tmp_path):
 
 
 def test_step_programs_are_named_after_their_kind():
-    sched = mk_sched(num_scheduler_steps=8, enable_overlap_decode=True, enable_prefix_caching=True)
+    sched = mk_sched(num_scheduler_steps=8, enable_prefix_caching=True)
     k, v, p = sched.cache.k, sched.cache.v, sched.params
     b, w = 2, 4
     i32 = jnp.int32
@@ -263,13 +262,11 @@ def test_step_programs_are_named_after_their_kind():
     programs = {
         "prefill": (sched._prefill_jit, (p, k, v, chunk, i32(1), i32(0), ptab, False)),
         "decode": (sched._decode_jit, (p, k, v, tpa, tables)),
-        "decode_sample": (sched._decode_sample_jit, (p, k, v, tpa, tables, temps, tks, tps, key)),
         "decode_multi_w8": (sched._decode_multi_jits[8], (p, k, v, toks, pos, tables, act, temps, tks, tps, key)),
         "mixed_step": (sched._get_mixed_jit((16, 16, b, w)),
                        (p, k, v, chunk, i32(1), i32(0), ptab, toks, pos, tables, act, False)),
         "admit_wave": (sched._get_admit_jit((b, 16, w)),
                        (p, k, v, jnp.zeros((b, 16), i32), pos, pos, tables)),
-        "kv_rollback": (sched._kv_zero_jit, (k, v, i32(0), i32(0))),
         "kv_block_copy": (sched._kv_copy_jit, (k, v, i32(0), i32(0))),
         "sample_batch": (sched._sample_jit, (jnp.zeros((b, CFG.vocab_size)), temps, tks, tps, key, None)),
     }

@@ -288,18 +288,6 @@ def test_moe_step_programs_hold_no_copy_of_an_expert_stack(one_chip, on_tpu, pro
     assert compiled.memory_analysis().temp_size_in_bytes < one_layer // 4
 
 
-def test_decode_sample_compiles(one_chip, on_tpu):
-    p, k, v = _one_chip_args(one_chip)
-    io = _decode_io(one_chip)
-    _compile(
-        lambda p, k, v, tpa, bt, te, tk, tp, key: llama.decode_sample(
-            p, CFG, k, v, tpa, bt, te, tk, tp, key
-        ),
-        p, k, v, _sds((3, B), jnp.int32, one_chip), io["tables"],
-        io["temps"], io["top_ks"], io["top_ps"], io["key"],
-    )
-
-
 # --- tp=4 over the described 2x2: the kernels must partition -------------------
 
 

@@ -41,7 +41,7 @@ from typing import Any, Dict, List, Optional, Tuple
 _SECTION_KEYS = (
     "decode_attention", "prefill", "tpu_http_e2e", "http_e2e", "router_prefix",
     "prefix_reuse", "large_model", "mixed_admission", "observability",
-    "device_truth", "guided_overhead", "decode_overlap", "autoscale", "elastic",
+    "device_truth", "guided_overhead", "autoscale", "elastic",
 )
 
 

@@ -1,11 +1,11 @@
 """SYNC001 — blocking device syncs inside scheduler/engine hot paths.
 
-PR 4's zero-bubble pipeline rests on ONE invariant: a steady-state decode
-step performs exactly one blocking host↔device sync (the previous step's
-sampled-token readback). Every extra ``np.asarray``/``float()``/
-``.item()``/``jax.device_get``/``.block_until_ready()`` on a device value
-re-serializes the host against the device and reopens the bubble — and
-the regression is invisible until a bench round measures the gap.
+A steady-state decode dispatch performs exactly one blocking host↔device
+sync (its sampled-token readback: one a step, or one a window). Every
+extra ``np.asarray``/``float()``/``.item()``/``jax.device_get``/
+``.block_until_ready()`` on a device value serializes the host against
+the device once more — and the regression is invisible until a chip run
+measures the idle share.
 
 The rule scopes to the hot-path functions named in
 ``tools/dtlint/sync_allowlist.json`` and classifies every local name as
@@ -16,7 +16,7 @@ HOST / DEVICE / UNKNOWN with a small per-function taint pass:
 - HOST: ``np.*`` results, literals/displays/comprehensions, ``len``,
   ``time.*``, ``jax.device_get`` results, params annotated with host
   types (int/float/bool/str/List/...).
-- UNKNOWN: everything else (``self._pipe["sampled"]``, helper returns).
+- UNKNOWN: everything else (attribute reads, helper returns).
 
 ``block_until_ready``/``device_get`` always flag; ``np.asarray``/
 ``np.array`` flag on DEVICE **and UNKNOWN** arguments (guilty until
@@ -26,9 +26,8 @@ one-line allowlist entry, a missed device sync is a perf regression);
 
 The allowlist file names each *sanctioned* sync — (file, func, call) with
 a role and a reason. The ``role: "per_step"`` entries are the statically
-declared 1-sync-per-step budget; ``bench.py`` cross-validates them
-against the measured blocking-sync count (static and dynamic views of
-the same invariant must agree).
+declared 1-sync-per-step budget (``tests/test_dtlint.py`` holds each
+path to at most one).
 """
 
 from __future__ import annotations
