@@ -1,30 +1,47 @@
-"""Ragged paged-attention megakernel: ONE Pallas launch for the whole
-mixed prefill+decode step's attention.
+"""Ragged paged-attention megakernel: one Pallas kernel for every row of a
+step's attention, launched once for its length-1 rows and once for a wide row.
 
 Why this exists: the r4 per-piece Pallas paged kernel issued 2+ launches
 per layer (chunk flash kernel + decode prefix kernel), and the XLA gather
 fallback moves triple traffic (gather read + packed-copy write + attend
-re-read). The per-launch dispatch cost that motivated amortizing launches
-is not measured on a directly attached chip (ROADMAP S2; blueprint:
-"Ragged Paged Attention", arxiv 2604.15464).
+re-read). (Blueprint: "Ragged Paged Attention", arxiv 2604.15464; the
+upstream TPU kernel, ``jax.experimental.pallas.ops.tpu.ragged_paged_attention``,
+for the tiling — its pool interleaves K and V and is not ours.)
 
-``ragged_paged_attention``: one launch per layer serves EVERY row of a
-mixed step. A row is a ``(start, len)`` run of queries over ``[paged
-prefix ; fresh keys]``: prefill chunks are wide rows, decode entries are
-length-1 rows, and both share one grid — ``(query, page)`` — with
+``ragged_paged_attention``: a row is a ``(start, len)`` run of queries over
+``[paged prefix ; fresh keys]``. Decode entries are length-1 rows and walk
+the grid ``(query, page)``; a prefill chunk is one wide row and walks
+``(tile of queries, page)`` — ``tile`` (``chunk_tile``) consecutive queries
+share a grid row, so one page fetch, one score dot and one value dot a
+page serve all of them, and the fresh keys are one step a tile with the
+causal frontier taken from the tile's first query. One kernel body,
+parameterised by the static tile. ``decode`` and ``decode_multi`` launch it
+once a layer, ``prefill`` once (tiled), ``mixed_step`` twice: the chunk's
+queries and the decode rows are disjoint outputs, so nothing merges. With
 
 - *scalar-prefetched block tables* (the page fetch is a plain BlockSpec
   whose index_map reads the table; Pallas double-buffers the HBM→VMEM
   streams, nothing is ever written back — vs the gather's 3× traffic),
-- the *block-diagonal GQA fold* proven in ``attention/decode.py`` (one
-  MXU-shaped dot per page instead of G tiny ones; decode attention has
-  ~100× MXU headroom, bytes are the budget),
-- ``pl.when`` skipping for dead slots: padded queries and
-  table slots past a row's true length cost no page fetch and no
-  compute, so ragged batches cost bytes, not bucket width,
+- for a length-1 row the *block-diagonal GQA fold* proven in
+  ``attention/decode.py`` (one MXU-shaped dot per page instead of G tiny
+  ones; decode attention has ~100× MXU headroom, bytes are the budget); for a
+  tile, which is not short of MXU rows, dots inside a *lane group* of whole
+  KV heads (``lane_fold``: one head from a head size of 128) — no fold
+  FLOPs, no ×KVH query bytes,
+- ``pl.when`` skipping for dead slots: padded queries, wholly padded tiles
+  and table slots past a row's true length cost no compute (and no page
+  fetch beyond the scratch page), so ragged batches cost bytes, not bucket
+  width,
 - an int8-KV dequant-in-VMEM path (per-(token, head) scales streamed
-  alongside the int8 codes and expanded over lanes in-kernel), so
-  capacity-mode deployments keep the fused path.
+  alongside the int8 codes and expanded over lanes in-kernel; a tile
+  dequantises a page once for all its queries), so capacity-mode
+  deployments keep the fused path.
+
+On a v5e (tools/attn_chunk_bench.py, PERF.md §6 PR 31): 256 chunk queries
+of 32/8 heads of 128 beside 32 decode rows of 12 pages took 3,576–4,034 µs a
+layer as one (query, page) walk; the chunk at a tile of 256 takes 43–146 µs
+(prefix 0–1,408) and the rows' launch 456. Folded over all KV heads a
+tile of 32 took 340–650.
 """
 
 from __future__ import annotations
@@ -38,6 +55,13 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
+# A wide row's tile (``chunk_tile``): at least a bf16 sublane tile of queries,
+# at most what the chip read as fastest (tools/attn_chunk_bench.py, PERF.md
+# §6 PR 31), inside the VMEM the launch may ask for.
+TILE_MIN = 16
+TILE_MAX = 256
+TILE_VMEM = 40 << 20
+TILE_VMEM_LIMIT = 64 << 20
 
 
 def build_meta(
@@ -59,9 +83,58 @@ def build_meta(
     )
 
 
-def _online_update(m_ref, l_ref, acc_ref, s, v):
-    """Fold one score tile + value tile into the online-softmax scratch."""
-    m_prev = m_ref[:]
+def lane_fold(num_kv_heads: int, head_dim: int) -> int:
+    """KV heads that a tile's dots fold into one lane group: the fewest whose
+    lanes fill a vreg row (128), so a page is cut at lane-tile boundaries and
+    the fold's extra FLOPs stop at 128 / HD. 1 from a head size of 128."""
+    return max(
+        d for d in range(1, num_kv_heads + 1)
+        if num_kv_heads % d == 0 and d * head_dim <= max(128, head_dim)
+    )
+
+
+def _tile_vmem_bytes(tile, fresh, num_heads, num_kv_heads, head_dim, block_size, q_bytes, kv_bytes):
+    """What a tiled launch asks of VMEM: the tile's operand and output
+    (double-buffered), its f32 softmax state (``m`` and ``l`` pad to a lane
+    tile), the ``fresh`` keys and the pages in flight, one dequantised page
+    and one group's scores."""
+    fold = lane_fold(num_kv_heads, head_dim)
+    lanes = max(fold * head_dim, 128)
+    rows = tile * num_heads
+    kv_lanes = num_kv_heads * head_dim
+    group_rows = tile * fold * (num_heads // num_kv_heads)
+    return (
+        2 * 2 * rows * lanes * q_bytes
+        + rows * (lanes + 2 * 128) * 4
+        + 2 * 2 * fresh * kv_lanes * q_bytes
+        + 2 * 2 * block_size * kv_lanes * kv_bytes
+        + 2 * block_size * kv_lanes * q_bytes
+        + 3 * group_rows * max(block_size, fresh) * 4
+    )
+
+
+def chunk_tile(
+    num_queries: int, num_heads: int, num_kv_heads: int, head_dim: int,
+    block_size: int, q_bytes: int = 2, kv_bytes: int = 2,
+) -> int:
+    """Queries of one wide row (a prefill chunk) that share a grid row: the
+    largest power of two up to ``TILE_MAX`` that the chunk fills and whose
+    working set fits ``TILE_VMEM``. The heads are the caller's shard's."""
+    tile = TILE_MIN
+    while (
+        tile < min(num_queries, TILE_MAX)
+        and _tile_vmem_bytes(
+            tile * 2, num_queries, num_heads, num_kv_heads, head_dim, block_size, q_bytes, kv_bytes
+        ) <= TILE_VMEM
+    ):
+        tile *= 2
+    return tile
+
+
+def _online_update(m_ref, l_ref, acc_ref, rows, s, v):
+    """Fold one score tile + value tile into ``rows`` of the online-softmax
+    scratch."""
+    m_prev = m_ref[rows]
     m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
     p = jnp.exp(s - m_new)
     alpha = jnp.exp(m_prev - m_new)
@@ -69,25 +142,34 @@ def _online_update(m_ref, l_ref, acc_ref, s, v):
         p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32,
     )
-    m_ref[:] = m_new
-    l_ref[:] = l_ref[:] * alpha + jnp.sum(p, axis=1, keepdims=True)
-    acc_ref[:] = acc_ref[:] * alpha + pv
+    m_ref[rows] = m_new
+    l_ref[rows] = l_ref[rows] * alpha + jnp.sum(p, axis=1, keepdims=True)
+    acc_ref[rows] = acc_ref[rows] * alpha + pv
 
 
 def _mega_kernel(
     tables_ref,  # SMEM [R, W] i32 — per-row page ids (layer-offset, dead → 0)
-    meta_ref,  # SMEM [5, NQ] i32 — build_meta layout
-    wq_ref,  # VMEM [1, KVG, KVHD] — this query's block-diagonal fold
+    meta_ref,  # SMEM [5, NT] i32 — build_meta layout, one column a grid row
+    wq_ref,  # VMEM [1, groups*rows, lanes] — this grid row's queries, folded
     ke_ref,  # VMEM [CK, KVHD] — ALL fresh keys (lane-merged), loaded once
     ve_ref,  # VMEM [CK, KVHD]
-    k_ref,  # VMEM [1, BS, KVHD] — this (query, slot)'s K page
+    k_ref,  # VMEM [1, BS, KVHD] — this (grid row, slot)'s K page
     v_ref,
     *rest,  # (ks_ref, vs_ref)? o_ref, m_ref, l_ref, acc_ref
     block_size: int,
     num_slots: int,
     scale: float,
     quant: bool,
+    tile: int,
+    groups: int,
 ):
+    """A grid row is ``tile`` consecutive queries of one sequence row: one
+    query (``tile`` 1, a decode row) or a run of a chunk's. Its queries share
+    the row's prefix and every page fetch; the fresh-key frontier of query
+    ``i`` of the tile is the first's plus ``i``, and the first
+    ``meta[4]`` of them are live. The lanes of a page are cut into
+    ``groups`` runs of whole KV heads; a group's ``rows`` queries-by-heads
+    meet only its lanes (block-diagonally where it folds several heads)."""
     if quant:
         ks_ref, vs_ref, o_ref, m_ref, l_ref, acc_ref = rest
     else:
@@ -97,10 +179,51 @@ def _mega_kernel(
     prefix_len = meta_ref[1, nq]
     e_start = meta_ref[2, nq]
     e_end = meta_ref[3, nq]
-    live = meta_ref[4, nq] > 0
+    n_live = meta_ref[4, nq]
+    live = n_live > 0
     bs = block_size
-    wq = wq_ref[0]  # [KVG, KVHD]
-    rows = wq.shape[0]
+    rows = wq_ref.shape[1] // groups
+    lanes = wq_ref.shape[2]
+    dtype = wq_ref.dtype
+    # One group: its operand serves both pieces. Several: each loads its rows
+    # where it meets its lanes, so no more than one group is held at a time.
+    wq = wq_ref[0] if groups == 1 else None
+
+    def page(ref, scale_ref, g):
+        """Lane group ``g`` of the page in flight (``[1, BS, KVHD]``, int8
+        with its scales) or of the fresh keys (``[CK, KVHD]``)."""
+        ln = slice(g * lanes, (g + 1) * lanes)
+        if scale_ref is None:
+            return ref[0, :, ln] if len(ref.shape) == 3 else ref[:, ln]
+        # int8 dequant in VMEM: per-(token, head) scales expand over
+        # the HD lanes (lane j of the merged (kvh, hd) axis carries
+        # head j // HD). The codes stream at 1 byte/value — the whole
+        # point of int8 KV is capacity, and the fused path keeps it.
+        hd = ref.shape[2] // scale_ref.shape[2]
+        heads = slice(g * lanes // hd, (g + 1) * lanes // hd)
+        return ref[0, :, ln].astype(dtype) * jnp.repeat(
+            scale_ref[0, :, heads], hd, axis=-1
+        ).astype(dtype)
+
+    def attend(keys, key_scales, values, value_scales, mask):
+        """Every group's scores against ``keys``, kept where ``mask(shape)``
+        says, folded with ``values`` into the scratch."""
+        keep = None
+        for g in range(groups):
+            r = slice(g * rows, (g + 1) * rows)
+            k = page(keys, key_scales, g)  # [n, lanes]
+            v = page(values, value_scales, g)
+            q = wq if groups == 1 else wq_ref[0, r, :]
+            s = (
+                lax.dot_general(
+                    q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+                )
+                * scale
+            )  # [rows, n]
+            if keep is None:
+                keep = mask(s.shape)
+            s = jnp.where(keep, s, NEG_INF)
+            _online_update(m_ref, l_ref, acc_ref, r, s, v)
 
     @pl.when(w == 0)
     def _init():
@@ -108,37 +231,18 @@ def _mega_kernel(
         l_ref[:] = jnp.zeros(l_ref.shape, jnp.float32)
         acc_ref[:] = jnp.zeros(acc_ref.shape, jnp.float32)
 
-    # Paged-prefix piece: slot w holds tokens [w*bs, w*bs+bs) of this
-    # query's row. Dead queries and slots past the true prefix are skipped
+    # Paged-prefix piece: slot w holds tokens [w*bs, w*bs+bs) of this grid
+    # row's sequence. Dead rows and slots past the true prefix are skipped
     # entirely — no page fetch is wasted on bucket width (consecutive
     # identical table entries reuse the pipelined fetch, so a short row in
     # a wide bucket costs one scratch-page fetch, not W).
     @pl.when(live & (w < num_slots) & (w * bs < prefix_len))
     def _page():
-        if quant:
-            # int8 dequant in VMEM: per-(token, head) scales expand over
-            # the HD lanes (lane j of the merged (kvh, hd) axis carries
-            # head j // HD). The codes stream at 1 byte/value — the whole
-            # point of int8 KV is capacity, and the fused path keeps it.
-            hd = k_ref.shape[2] // ks_ref.shape[2]
-            k = k_ref[0].astype(wq.dtype) * jnp.repeat(
-                ks_ref[0], hd, axis=-1
-            ).astype(wq.dtype)
-            v = v_ref[0].astype(wq.dtype) * jnp.repeat(
-                vs_ref[0], hd, axis=-1
-            ).astype(wq.dtype)
-        else:
-            k = k_ref[0]  # [BS, KVHD]
-            v = v_ref[0]
-        s = (
-            lax.dot_general(
-                wq, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-            )
-            * scale
-        )  # [KVG, BS]
-        kpos = w * bs + lax.broadcasted_iota(jnp.int32, (rows, bs), 1)
-        s = jnp.where(kpos < prefix_len, s, NEG_INF)
-        _online_update(m_ref, l_ref, acc_ref, s, v)
+        def in_prefix(shape):
+            kpos = w * bs + lax.broadcasted_iota(jnp.int32, shape, 1)
+            return kpos < prefix_len
+
+        attend(k_ref, ks_ref, v_ref, vs_ref, in_prefix)
 
     # Final slot: the in-flight (not-yet-cached) keys — a chunk query's
     # causal window over its own chunk, a decode query's current token, a
@@ -147,27 +251,29 @@ def _mega_kernel(
     def _fresh_and_final():
         @pl.when(live & (e_end > e_start))
         def _fresh():
-            ke = ke_ref[:]  # [CK, KVHD]
-            ve = ve_ref[:]
-            s = (
-                lax.dot_general(
-                    wq, ke, (((1,), (1,)), ((), ())),
-                    preferred_element_type=jnp.float32,
-                )
-                * scale
-            )  # [KVG, CK]
-            cpos = lax.broadcasted_iota(jnp.int32, (rows, ke.shape[0]), 1)
-            s = jnp.where((cpos >= e_start) & (cpos < e_end), s, NEG_INF)
-            _online_update(m_ref, l_ref, acc_ref, s, ve)
+            def in_window(shape):
+                cpos = lax.broadcasted_iota(jnp.int32, shape, 1)
+                end = e_end
+                if tile > 1:
+                    end = e_end + lax.broadcasted_iota(jnp.int32, shape, 0) // (rows // tile)
+                return (cpos >= e_start) & (cpos < end)
 
-        o_ref[0] = (acc_ref[:] / jnp.maximum(l_ref[:], 1e-30)).astype(o_ref.dtype)
+            attend(ke_ref, None, ve_ref, None, in_window)
+
+        out = acc_ref[:] / jnp.maximum(l_ref[:], 1e-30)
+        if tile > 1:
+            # A live tile's dead queries (a chunk's pad) attended as live
+            # ones do; they return the zeros a dead grid row returns.
+            qi = lax.broadcasted_iota(jnp.int32, (groups * rows, 1), 0) % rows // (rows // tile)
+            out = jnp.where(qi < n_live, out, 0.0)
+        o_ref[0] = out.astype(o_ref.dtype)
 
 
 @functools.partial(
-    jax.jit, static_argnames=("num_kv_heads", "block_size", "interpret")
+    jax.jit, static_argnames=("num_kv_heads", "block_size", "tile", "interpret")
 )
 def ragged_paged_attention(
-    q: jax.Array,  # [NQ, H, HD] post-rope queries (chunk rows then decode rows)
+    q: jax.Array,  # [NQ, H, HD] post-rope queries
     k_extra: jax.Array,  # [CK, KVH, HD] in-flight keys (chunk K, window rows, current tokens)
     v_extra: jax.Array,
     k_pages,  # [NP, BS, KVH*HD] layer-flat page pool, or QuantKv (scales [NP, BS, KVH])
@@ -177,13 +283,20 @@ def ragged_paged_attention(
     *,
     num_kv_heads: int,
     block_size: int,
+    tile: int = 1,
     interpret: bool = False,
 ) -> jax.Array:
-    """Attention for a whole ragged batch over [paged prefix ; fresh keys]
-    in ONE kernel launch. Returns normalized ``[NQ, H, HD]`` — the prefix
-    pages and the fresh piece merge inside the kernel's online softmax, so
-    no external ``_merge_pieces`` is needed and no gathered prefix copy is
-    ever materialized in HBM.
+    """Attention for a ragged batch over [paged prefix ; fresh keys] in one
+    kernel launch. Returns normalized ``[NQ, H, HD]`` — the prefix pages and
+    the fresh piece merge inside the kernel's online softmax, so no external
+    ``_merge_pieces`` is needed and no gathered prefix copy is ever
+    materialized in HBM.
+
+    ``tile`` > 1 (``chunk_tile``) is the caller's word that ``meta`` describes
+    wide rows: every run of ``tile`` queries from a multiple of ``tile`` has
+    one ``row_of``, ``prefix_len`` and ``extra_start``, ``extra_end`` rising
+    by one a query, and its live queries first — a prefill chunk. The grid
+    then walks (tile, page), not (query, page).
 
     Dead queries (``meta`` active = 0) return zeros and read nothing.
 
@@ -200,13 +313,28 @@ def ragged_paged_attention(
     CK = k_extra.shape[0]
     quant = isinstance(k_pages, QuantKv)
 
-    # Block-diagonal GQA fold (attention/decode.py): off-block lanes hit
-    # zeros, so one [KVG, KVHD]×[KVHD, BS] dot yields exact per-head
-    # scores. The ×KVH query-byte inflation is immaterial next to the KV
-    # bytes the kernel exists to save.
-    q_r = q.reshape(NQ, KVH, G, HD)
-    eye = jnp.eye(KVH, dtype=q.dtype)[:, None, :, None]
-    wq = (q_r[:, :, :, None, :] * eye[None]).reshape(NQ, KVG, KVHD)
+    if tile == 1:
+        # Block-diagonal GQA fold (attention/decode.py): off-block lanes hit
+        # zeros, so one [KVG, KVHD]×[KVHD, BS] dot yields exact per-head
+        # scores. The ×KVH query-byte inflation is immaterial next to the KV
+        # bytes the kernel exists to save.
+        NT, fold = NQ, KVH
+        q_r = q.reshape(NQ, KVH, G, HD)
+        eye = jnp.eye(KVH, dtype=q.dtype)[:, None, :, None]
+        wq = (q_r[:, :, :, None, :] * eye[None]).reshape(NQ, KVG, KVHD)
+    else:
+        # A tile is not short of MXU rows: its dots stay inside a lane group
+        # (one KV head from HD 128), rows (query, folded head, g).
+        NT, fold = -(-NQ // tile), lane_fold(KVH, HD)
+        pad = NT * tile - NQ
+        wq = jnp.pad(q, ((0, pad), (0, 0), (0, 0))).reshape(NT, tile, KVH // fold, fold, G, 1, HD)
+        if fold > 1:
+            wq = wq * jnp.eye(fold, dtype=q.dtype)[:, None, :, None]
+        wq = wq.transpose(0, 2, 1, 3, 4, 5, 6).reshape(NT, tile * KVG, fold * HD)
+        first = jnp.pad(meta, ((0, 0), (0, pad))).reshape(5, NT, tile)
+        meta = jnp.concatenate([first[:4, :, 0], first[4:].sum(-1)])
+    groups = KVH // fold
+    block = (1,) + wq.shape[1:]
 
     ke = k_extra.reshape(CK, KVHD)
     ve = v_extra.reshape(CK, KVHD)
@@ -218,7 +346,7 @@ def ragged_paged_attention(
         return (t[mt[0, nq], jnp.minimum(w, W - 1)], 0, 0)
 
     in_specs = [
-        pl.BlockSpec((1, KVG, KVHD), lambda nq, w, t, mt: (nq, 0, 0)),
+        pl.BlockSpec(block, lambda nq, w, t, mt: (nq, 0, 0)),
         pl.BlockSpec((CK, KVHD), lambda nq, w, t, mt: (0, 0)),
         pl.BlockSpec((CK, KVHD), lambda nq, w, t, mt: (0, 0)),
         pl.BlockSpec((1, BS, KVHD), page_idx),
@@ -235,13 +363,13 @@ def ragged_paged_attention(
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(NQ, W + 1),
+        grid=(NT, W + 1),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, KVG, KVHD), lambda nq, w, t, mt: (nq, 0, 0)),
+        out_specs=pl.BlockSpec(block, lambda nq, w, t, mt: (nq, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((KVG, 1), jnp.float32),
-            pltpu.VMEM((KVG, 1), jnp.float32),
-            pltpu.VMEM((KVG, KVHD), jnp.float32),
+            pltpu.VMEM((block[1], 1), jnp.float32),
+            pltpu.VMEM((block[1], 1), jnp.float32),
+            pltpu.VMEM(block[1:], jnp.float32),
         ],
     )
     out = pl.pallas_call(
@@ -251,13 +379,21 @@ def ragged_paged_attention(
             num_slots=W,
             scale=HD**-0.5,
             quant=quant,
+            tile=tile,
+            groups=groups,
         ),
-        out_shape=jax.ShapeDtypeStruct((NQ, KVG, KVHD), q.dtype),
+        out_shape=jax.ShapeDtypeStruct(wq.shape, q.dtype),
         grid_spec=grid_spec,
         interpret=interpret,
+        # A tile's state is megabytes where a query's is kilobytes.
+        compiler_params=None if tile == 1 else pltpu.CompilerParams(vmem_limit_bytes=TILE_VMEM_LIMIT),
     )(tables.astype(jnp.int32), meta.astype(jnp.int32), *args)
 
     # Each query's output lives in its head's diagonal block of the fold.
-    out = out.reshape(NQ, KVH, G, KVH, HD)
-    out = out[:, jnp.arange(KVH), :, jnp.arange(KVH), :]  # [KVH, NQ, G, HD]
-    return out.transpose(1, 0, 2, 3).reshape(NQ, H, HD)
+    if tile == 1:
+        out = out.reshape(NQ, KVH, G, KVH, HD)
+        out = out[:, jnp.arange(KVH), :, jnp.arange(KVH), :]  # [KVH, NQ, G, HD]
+        return out.transpose(1, 0, 2, 3).reshape(NQ, H, HD)
+    out = out.reshape(NT, groups, tile, fold, G, fold, HD)
+    out = out[:, :, :, jnp.arange(fold), :, jnp.arange(fold), :]  # [fold, NT, groups, tile, G, HD]
+    return out.transpose(1, 3, 2, 0, 4, 5).reshape(NT * tile, H, HD)[:NQ]

@@ -75,26 +75,29 @@ class ModelConfig:
     #   merge, once-per-window hoist (decode_multi). The CPU/debug
     #   baseline, and the off-TPU resolution of "auto".
     # - "megakernel": the ragged paged-attention megakernel
-    #   (attention/megakernel.py) — ONE pallas_call per layer serves the
-    #   whole step's ragged batch ((start, len) chunk rows + length-1
-    #   decode rows share one grid), scalar-prefetched block tables,
-    #   block-diagonal GQA fold, pl.when-skipped dead slots, and an int8-KV
-    #   dequant-in-VMEM path. Amortizes the launches of the r4/r5
-    #   per-piece kernels: 1 launch/layer/step regardless of
-    #   batch composition (vs 2+ for chunk+decode kernels).
+    #   (attention/megakernel.py) — one kernel for the step's ragged batch:
+    #   scalar-prefetched block tables, pl.when-skipped dead slots, and an
+    #   int8-KV dequant-in-VMEM path. Length-1 decode rows walk the grid
+    #   (query, page) under the block-diagonal GQA fold, one launch a layer
+    #   (decode, decode_multi). A wide row — a prefill chunk — walks (tile of
+    #   queries, page): a tile read off the shapes (megakernel.chunk_tile; 256
+    #   at the benchmark's widths) shares each page fetch and each dot, per
+    #   lane group of whole KV heads. prefill launches it once a layer,
+    #   mixed_step twice (the chunk, then the decode rows: disjoint outputs,
+    #   no merge). A sched.step entry of a chunk-carrying dispatch names the
+    #   path as traced: chunk_attn = tile<TQ> | paged | gather.
     # - "paged": the r5 per-piece Pallas paged flash-decode kernel
     #   (attention/decode.py) — correct (interpret-mode parity tests) but
-    #   NEVER auto-selected: it issues 2+ launches per layer where the
-    #   megakernel issues one. The per-launch dispatch cost that decided
-    #   this is not measured on a directly attached chip. No int8
+    #   NEVER auto-selected (a configuration may set it: evabyte-d16's
+    #   4096-lane pages, PERF.md section 6 PR 28 and PR 31). No int8
     #   path — int8 caches degrade to gather with a logged warning
     #   (llama.resolve_attention_impl).
     # - "auto": "megakernel" on TPU, "gather" elsewhere (interpreted
     #   Pallas is test-only). Measured record: decode at b32 sat at ~54%
     #   of HBM roofline on the gather (BENCH_r05 — the gather's
     #   read + packed-copy write + attend re-read is 3× the true KV
-    #   bytes); the megakernel streams each page HBM→VMEM exactly once
-    #   per launch and pays dispatch once per layer, not per piece. Track
+    #   bytes); the megakernel streams each page HBM→VMEM once per grid
+    #   row (a decode query, or a tile of a chunk's queries). Track
     #   via bench.py's `decode_attention` section (tok/s,
     #   pct_hbm_roofline, per-launch dispatch overhead, gather vs
     #   megakernel at b∈{8,32}).

@@ -389,12 +389,16 @@ class FlightRecorder:
 
     def recent_steps(self, n: int = RECENT_STEPS) -> List[dict]:
         """The newest ``n`` dispatches, oldest first, in the shape
-        /debug/state, incident bundles and ``tools/autopsy.py`` read."""
+        /debug/state, incident bundles and ``tools/autopsy.py`` read. A
+        dispatch that carried a prefill chunk also says how the chunk met
+        its keys (``chunk_attn``, from its iteration's ``sched.step``)."""
         now = time.monotonic_ns()
+        paths = {step: a["chunk_attn"] for _, _, _, step, a in self.log.named("sched.step") if a and "chunk_attn" in a}
         return [
             {"age_s": round((now - t1) / 1e9, 3), "phase": a["phase"],
-             "dur_s": round((t1 - t0) / 1e9, 6), "tokens": a["tokens"]}
-            for _, t0, t1, _, a in self.log.named(STEP_RECORD, n)
+             "dur_s": round((t1 - t0) / 1e9, 6), "tokens": a["tokens"],
+             **({"chunk_attn": paths[step]} if step in paths and a["phase"] in ("prefill", "mixed") else {})}
+            for _, t0, t1, step, a in self.log.named(STEP_RECORD, n)
         ]
 
     def _record_roofline(

@@ -548,11 +548,33 @@ def _use_paged_decode(c: ModelConfig, k_cache) -> bool:
 
 
 def _use_megakernel(c: ModelConfig, k_cache) -> bool:
-    """Ragged paged-attention megakernel (attention/megakernel.py): ONE
-    launch per layer serves every row of the step — prefill chunks,
-    mixed-step ragged batches, and decode rows — with no gathered prefix
-    copy and pl.when-skipped dead slots. Auto-selected on TPU."""
+    """Ragged paged-attention megakernel (attention/megakernel.py): one
+    kernel serves every row of the step — decode rows a query a grid row,
+    a prefill chunk by tiles of its queries (a launch a layer each; a
+    mixed step has both) — with no gathered prefix copy and pl.when-skipped
+    dead slots. Auto-selected on TPU."""
     return resolve_attention_impl(c, k_cache) == "megakernel"
+
+
+def _chunk_tile(c: ModelConfig, k_cache, num_queries: int, dtype) -> int:
+    """The megakernel's tile for a chunk of ``num_queries`` ``dtype`` queries
+    (megakernel.chunk_tile at this step's shard of the heads)."""
+    from dynamo_tpu.engine.attention.megakernel import chunk_tile
+
+    tp = max(kernel_shards(c.num_kv_heads), 1)
+    return chunk_tile(
+        num_queries, c.num_heads // tp, c.num_kv_heads // tp, c.head_dim, c.block_size,
+        q_bytes=jnp.dtype(dtype).itemsize,
+        kv_bytes=jnp.dtype(k_cache.dtype).itemsize,
+    )
+
+
+def chunk_attn_path(c: ModelConfig, k_cache, num_queries: int, dtype) -> str:
+    """How a chunk of ``num_queries`` meets its keys in ``prefill`` and
+    ``mixed_step`` as they trace now: ``tile<TQ>`` (the ragged megakernel's
+    (tile, page) walk), ``paged`` or ``gather``. For the step log."""
+    impl = resolve_attention_impl(c, k_cache)
+    return f"tile{_chunk_tile(c, k_cache, num_queries, dtype)}" if impl == "megakernel" else impl
 
 
 def _mega_attend_rows(
@@ -564,15 +586,17 @@ def _mega_attend_rows(
     v_flat,
     tables: jax.Array,  # [R, W] layer-offset page tables
     meta: jax.Array,  # [5, NQ] megakernel.build_meta
+    chunk: bool = False,  # the queries are one wide row (a prefill chunk), walked by tiles
 ) -> jax.Array:
-    """One fused ragged-attention launch for a whole step's rows — per tp
-    shard over its local heads when the step runs under a mesh."""
+    """One fused ragged-attention launch for a step's rows — per tp shard
+    over its local heads when the step runs under a mesh."""
     from dynamo_tpu.engine.attention.megakernel import ragged_paged_attention
 
     attend = over_tp(
         ragged_paged_attention, c.num_kv_heads,
         (HEADS, HEADS, HEADS, PAGES, PAGES, P(), P()), HEADS,
         block_size=c.block_size, interpret=not _on_tpu(),
+        tile=_chunk_tile(c, k_flat, q.shape[0], q.dtype) if chunk else 1,
     )
     return attend(q, k_extra, v_extra, k_flat, v_flat, tables, meta)
 
@@ -710,10 +734,10 @@ def prefill(
 
     use_mega = _use_megakernel(c, k_cache)
     if use_mega:
-        # The prefill chunk is one ragged megakernel row: causal fresh
-        # chunk + paged prefix in ONE launch per layer — no gathered
-        # prefix copy, pad queries (and fresh prefills' empty prefix)
-        # skipped dead in-kernel.
+        # The prefill chunk is one wide megakernel row, walked by tiles of
+        # its queries: causal fresh chunk + paged prefix in ONE launch per
+        # layer — no gathered prefix copy, pad queries (and fresh prefills'
+        # empty prefix) skipped dead in-kernel.
         from dynamo_tpu.engine.attention.megakernel import build_meta
 
         t_iq = jnp.arange(T, dtype=jnp.int32)
@@ -740,7 +764,7 @@ def prefill(
         if use_mega:
             attn = _mega_attend_rows(
                 c, q, k, v, k_flat, v_flat,
-                (block_table + l * N)[None, :], mega_meta,
+                (block_table + l * N)[None, :], mega_meta, chunk=True,
             ).astype(wdtype)
             h = h + attn.reshape(T, c.q_size) @ lp["wo"]
             x = _norm(c, h, lp["mlp_norm"], wdtype)
@@ -1385,30 +1409,22 @@ def mixed_step(
     use_mega = _use_megakernel(c, k_cache)
     d_prefix_lens = jnp.minimum(d_rows, ctx_d).astype(jnp.int32)
     if use_mega:
-        # Megakernel packing: the WHOLE mixed step's attention — the chunk's
-        # (start, len) queries AND the B length-1 decode rows — is one
-        # ragged batch sharing one grid, one launch per layer. Tables pack
-        # [chunk row ; decode rows]; padded table slots hold the scratch
-        # page and are skipped (pl.when) along with dead chunk-bucket
-        # queries and inactive decode lanes.
+        # Megakernel packing: the mixed step's attention is the two shapes
+        # it has, each a launch per layer of the one kernel — the chunk, a
+        # wide row walked by tiles of its queries over its own table and
+        # its own fresh keys, and the B length-1 decode rows, walked a query
+        # at a time over theirs. Padded table slots hold the scratch page
+        # and are skipped (pl.when) along with dead chunk-bucket queries
+        # and inactive decode lanes.
         from dynamo_tpu.engine.attention.megakernel import build_meta
 
-        Wp, Wd = p_table.shape[0], d_tables.shape[1]
-        Wmax = max(Wp, Wd)
-        mega_tbl = jnp.zeros((1 + B, Wmax), jnp.int32)
-        mega_tbl = mega_tbl.at[0, :Wp].set(p_table.astype(jnp.int32))
-        mega_tbl = mega_tbl.at[1:, :Wd].set(d_tables.astype(jnp.int32))
         s_iq = jnp.arange(S, dtype=jnp.int32)
         d_iq = jnp.arange(B, dtype=jnp.int32)
-        mega_meta = build_meta(
-            jnp.concatenate([jnp.zeros((S,), jnp.int32), 1 + d_iq]),
-            jnp.concatenate([jnp.full((S,), p_prefix_rows, jnp.int32), d_prefix_lens]),
-            jnp.concatenate([jnp.zeros((S,), jnp.int32), S + d_iq]),
-            jnp.concatenate([s_iq + 1, S + d_iq + 1]),
-            jnp.concatenate(
-                [(s_iq < p_valid).astype(jnp.int32), d_active.astype(jnp.int32)]
-            ),
+        p_meta = build_meta(
+            jnp.zeros((S,), jnp.int32), jnp.full((S,), p_prefix_rows, jnp.int32),
+            jnp.zeros((S,), jnp.int32), s_iq + 1, s_iq < p_valid,
         )
+        d_meta = build_meta(d_iq, d_prefix_lens, d_iq, d_iq + 1, d_active)
 
     from dynamo_tpu.engine.attention.ragged import ragged_chunk_attention
 
@@ -1425,11 +1441,12 @@ def mixed_step(
         k = apply_rope(k, positions_all, c.rope_theta)
 
         if use_mega:
-            # ONE fused launch for chunk + decode rows: the fresh-key piece
-            # is the packed [chunk K ; decode K] projection output itself.
-            attn = _mega_attend_rows(
-                c, q, k, v, k_flat, v_flat, mega_tbl + l * N, mega_meta
-            ).astype(wdtype).reshape(S + B, c.q_size)
+            # Each piece's fresh keys are its own rows of the projection.
+            attn_p = _mega_attend_rows(
+                c, q[:S], k[:S], v[:S], k_flat, v_flat, (p_table + l * N)[None, :], p_meta, chunk=True,
+            )
+            attn_d = _mega_attend_rows(c, q[S:], k[S:], v[S:], k_flat, v_flat, d_tables + l * N, d_meta)
+            attn = jnp.concatenate([attn_p, attn_d]).astype(wdtype).reshape(S + B, c.q_size)
             h = h + attn @ lp["wo"]
             x = _norm(c, h, lp["mlp_norm"], wdtype)
             if moe_stats:

@@ -474,6 +474,7 @@ class Scheduler:
 
         self._model = model = bind_mesh(get_module(model_config), mesh)
         self._attn_impl = "gather"
+        self._chunk_attn_paths: Dict[int, str] = {}  # chunk bucket -> llama.chunk_attn_path, for the step log
         if model_config.architecture == "llama":
             model.warn_attention_impl_degrade(model_config, self.cache.k)
             self._attn_impl = model.resolve_attention_impl(model_config, self.cache.k)
@@ -1126,6 +1127,20 @@ class Scheduler:
                 span.set(attended=sum(self._rows_for(s, s.total_len) for s in batch))
         else:
             span.set(dispatches=attrs.get("dispatches", 1) + 1)
+        if kind in ("mixed", "prefill", "prefill_mm"):
+            # The chunk's attention path, also where the chunk is the
+            # iteration's second dispatch (a bare chunk after a window).
+            span.set(chunk_attn=self._chunk_attn(key[0]))
+
+    def _chunk_attn(self, bucket: int) -> str:
+        """How a chunk of ``bucket`` queries meets its keys in ``prefill`` and
+        ``mixed_step`` as this engine traced them: ``tile<TQ>`` (the ragged
+        megakernel's walk by tiles of TQ queries), ``paged`` or ``gather``."""
+        if self._attn_impl != "megakernel":
+            return self._attn_impl
+        if bucket not in self._chunk_attn_paths:  # 0.8 ms to work out: once a bucket, not once a dispatch
+            self._chunk_attn_paths[bucket] = self._model.chunk_attn_path(self.mc, self.cache.k, bucket, self.dtype)
+        return self._chunk_attn_paths[bucket]
 
     def _launch(self, kind: str, decode: bool = False) -> StepSpan:
         """The ``sched.launch`` span of one program. A decode-family launch

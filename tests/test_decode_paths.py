@@ -175,3 +175,38 @@ def test_warmup_registers_exactly_the_kinds_that_can_dispatch(preset):
     registered = {k[0] for k in sched.flight._exec_keys}
     assert registered == expected
     assert not registered & GONE
+
+
+def _decode_programs(cfg, params):
+    """(``decode``, ``decode_multi``) of ``cfg`` as jaxprs, on shapes alone."""
+    from dynamo_tpu.engine.kv_cache import KvCacheArrays
+
+    B, W, i32, f32 = 4, 4, jnp.int32, jnp.float32
+    cache = KvCacheArrays.create(cfg, num_blocks=16, dtype=f32)
+    io = (jnp.zeros((B,), i32), jnp.full((B,), 5, i32), jnp.ones((B, W), i32), jnp.ones((B,), bool))
+    one = jax.make_jaxpr(lambda p, k, v: llama.decode(p, cfg, k, v, *io))(params, cache.k, cache.v)
+    window = jax.make_jaxpr(lambda p, k, v: llama.decode_multi(
+        p, cfg, k, v, *io, jnp.zeros((B,), f32), jnp.zeros((B,), i32), jnp.ones((B,), f32), jax.random.PRNGKey(1), 8,
+    ))(params, cache.k, cache.v)
+    return {"decode": one, "decode_multi": window}
+
+
+@pytest.mark.parametrize("program", ["decode", "decode_multi"])
+@pytest.mark.parametrize("preset", ["tiny", "tiny-moe", "tiny-eva"])
+def test_decode_programs_do_not_depend_on_the_chunk_tile(preset, program, monkeypatch):
+    """Every row of ``decode`` and ``decode_multi`` is a length-1 row: under the
+    megakernel each launch walks (query, page) — a grid row a query — and the
+    traced program is the same whatever tile a chunk would take. (Against the
+    parent commit, once: PERF.md section 6, PR 31.)"""
+    from dynamo_tpu.engine.attention import megakernel as mk
+    from tests.test_megakernel import _kernel_grids
+
+    cfg = get_config(preset).replace(attention_impl="megakernel")
+    params = llama.init_params(cfg, jax.random.PRNGKey(0), dtype=jnp.float32)
+    traced = _decode_programs(cfg, params)[program]
+    grids = _kernel_grids(traced.jaxpr)
+    assert grids and set(grids) == {(4, 4 + 1)}, grids  # B rows x (W pages + the fresh keys)
+    monkeypatch.setattr(mk, "TILE_MAX", 16)
+    monkeypatch.setattr(mk, "TILE_MIN", 2)
+    mk.ragged_paged_attention.clear_cache()
+    assert str(_decode_programs(cfg, params)[program]) == str(traced)

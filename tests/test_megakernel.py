@@ -74,23 +74,130 @@ def test_decode_parity_head_layouts(kvh):
 
 
 # ---------------------------------------------------------------------------
-# Ragged edge cases
+# A chunk's queries share their grid steps: the tile's edges, against a dense
+# float32 reference
 # ---------------------------------------------------------------------------
 
 
-def test_prefill_chunk_with_prefix_parity():
-    """A (start, len) chunk row over a cached prefix — including a chunk
-    that starts exactly ON a page boundary — matches the gather path."""
+def _dense_reference(q, ke, ve, k_pages, v_pages, tables, meta, kvh):
+    """Plain float32 softmax attention, a query at a time, over ``[the first
+    prefix_len tokens of the row's pages ; fresh keys [start, end)]``; dead
+    queries return zeros."""
+    q, ke, ve, k_pages, v_pages = (np.asarray(x, np.float32) for x in (q, ke, ve, k_pages, v_pages))
+    NQ, H, HD = q.shape
+    out = np.zeros_like(q)
+    for n in range(NQ):
+        row, prefix, start, end, live = (int(x) for x in np.asarray(meta)[:, n])
+        if not live:
+            continue
+        pages = np.asarray(tables)[row]
+        k = np.concatenate([k_pages[pages].reshape(-1, kvh, HD)[:prefix], ke[start:end]])
+        v = np.concatenate([v_pages[pages].reshape(-1, kvh, HD)[:prefix], ve[start:end]])
+        for h in range(H):
+            s = k[:, h // (H // kvh)] @ q[n, h] * HD**-0.5
+            p = np.exp(s - s.max())
+            out[n, h] = (p / p.sum()) @ v[:, h // (H // kvh)]
+    return out
+
+
+# (queries in the chunk's bucket, live ones, prefix tokens, tile, KV heads, G, HD, int8 pool); W 4 pages of 16.
+TILE_EDGES = {
+    "chunk-shorter-than-a-tile": (6, 6, 21, 16, 2, 2, 16, False),
+    "chunk-not-a-multiple": (19, 19, 21, 8, 2, 2, 16, False),
+    "valid-under-bucket-with-a-dead-tile": (32, 13, 37, 8, 2, 2, 16, False),
+    "one-live-query": (32, 1, 16, 8, 2, 2, 16, False),
+    "prefix-0": (32, 32, 0, 16, 2, 2, 16, False),
+    "prefix-on-a-page-boundary": (16, 16, 32, 8, 2, 2, 16, False),
+    "prefix-in-the-tables-last-slot": (16, 16, 59, 8, 2, 2, 16, False),
+    "prefix-fills-the-table": (16, 16, 64, 8, 2, 2, 16, False),
+    "g1-mha": (24, 20, 37, 8, 4, 1, 16, False),
+    "g4": (24, 20, 37, 8, 2, 4, 16, False),
+    "mqa": (24, 20, 37, 8, 1, 4, 16, False),
+    "hd128-a-group-a-head": (32, 29, 37, 16, 2, 4, 128, False),
+    "hd64-two-heads-a-group": (24, 20, 50, 8, 4, 2, 64, False),
+    "int8-pool": (24, 20, 37, 8, 2, 2, 16, True),
+    "int8-pool-a-group-a-head": (16, 16, 21, 8, 2, 1, 128, True),
+}
+
+
+@pytest.mark.parametrize("edge", list(TILE_EDGES))
+def test_tiled_chunk_matches_the_dense_reference(edge):
+    """``tile`` queries of a chunk a grid row, and a query a grid row, give
+    what plain softmax attention gives; dead queries return exact zeros."""
+    from dynamo_tpu.engine.kv_cache import QuantKv, quantize_kv_rows
+
+    nq, valid, prefix, tile, kvh, G, hd, quant = TILE_EDGES[edge]
+    W, bs = 4, 16
+    rng = np.random.default_rng(len(edge))
+    q = jnp.asarray(rng.standard_normal((nq, kvh * G, hd)).astype(np.float32))
+    ke = jnp.asarray(rng.standard_normal((nq, kvh, hd)).astype(np.float32))
+    ve = jnp.asarray(rng.standard_normal((nq, kvh, hd)).astype(np.float32))
+    k_pages = jnp.asarray(rng.standard_normal((W + 3, bs, kvh * hd)).astype(np.float32))
+    v_pages = jnp.asarray(rng.standard_normal((W + 3, bs, kvh * hd)).astype(np.float32))
+    k_ref, v_ref = k_pages, v_pages
+    if quant:
+        k_pages = quantize_kv_rows(k_pages.reshape(W + 3, bs, kvh, hd))
+        v_pages = quantize_kv_rows(v_pages.reshape(W + 3, bs, kvh, hd))
+        k_ref, v_ref = (p.q.astype(jnp.float32) * jnp.repeat(p.scale, hd, axis=-1) for p in (k_pages, v_pages))
+        assert isinstance(k_pages, QuantKv)
+    tables = jnp.asarray(np.array([[3, 1, 4, 2]], np.int32))
+    i = jnp.arange(nq, dtype=jnp.int32)
+    meta = mk.build_meta(jnp.zeros_like(i), jnp.full_like(i, prefix), jnp.zeros_like(i), i + 1, i < valid)
+    want = _dense_reference(q, ke, ve, k_ref, v_ref, tables, meta, kvh)
+    for t in (tile, 1):
+        got = np.asarray(mk.ragged_paged_attention(
+            q, ke, ve, k_pages, v_pages, tables, meta, num_kv_heads=kvh, block_size=bs, tile=t, interpret=True,
+        ))
+        np.testing.assert_allclose(got, want, atol=2e-5, err_msg=f"tile {t}")
+        assert np.all(got[valid:] == 0.0), f"tile {t}: dead queries must return zeros"
+
+
+@pytest.mark.parametrize("num_queries,heads,kv_heads,head_dim,want", [
+    (256, 32, 8, 128, mk.TILE_MAX), (16, 4, 2, 16, 16), (19, 4, 2, 16, 32), (4, 4, 2, 16, mk.TILE_MIN),
+    (2048, 32, 8, 128, 128), (256, 32, 32, 128, mk.TILE_MAX), (256, 64, 8, 128, 128),
+])
+def test_chunk_tile_follows_the_shapes(num_queries, heads, kv_heads, head_dim, want):
+    """The tile is read off the chunk and the widths: a power of two that the
+    chunk fills, capped where the chip read fastest and by the VMEM a launch
+    may ask for (a chunk of 2,048 brings 2,048 fresh keys a score row;
+    64 heads of 128 hold twice the state of 32)."""
+    tile = mk.chunk_tile(num_queries, heads, kv_heads, head_dim, 128)
+    assert tile == want
+    assert mk._tile_vmem_bytes(tile, num_queries, heads, kv_heads, head_dim, 128, 2, 2) <= max(
+        mk.TILE_VMEM, mk._tile_vmem_bytes(mk.TILE_MIN, num_queries, heads, kv_heads, head_dim, 128, 2, 2))
+    assert mk.lane_fold(kv_heads, head_dim) * head_dim >= min(128, kv_heads * head_dim)
+
+
+# ---------------------------------------------------------------------------
+# The step programs: chunks through the tiles against the XLA gather path
+# ---------------------------------------------------------------------------
+
+
+@pytest.fixture(params=[None, 16], ids=["one-tile", "tiles-of-16"])
+def tile_max(request, monkeypatch):
+    """The program's tile for these chunks (one covers each), and tiles of 16:
+    a chunk of 19 or 40 is then not a multiple, one of 9 in a bucket of 32
+    leaves a tile wholly dead."""
+    if request.param:
+        monkeypatch.setattr(mk, "TILE_MAX", request.param)
+    return request.param
+
+
+@pytest.mark.parametrize("first_len,second_len", [(32, 19), (21, 40)], ids=["prefix-2-pages", "prefix-21"])
+def test_prefill_chunk_with_prefix_parity(tile_max, first_len, second_len):
+    """A (start, len) chunk row over a cached prefix — a chunk that starts
+    exactly ON a page boundary, and one that starts inside a page — matches
+    the gather path."""
     params = llama.init_params(CFG, jax.random.PRNGKey(0), dtype=jnp.float32)
     rng = np.random.default_rng(2)
     table = jnp.asarray(np.arange(1, 6, dtype=np.int32))
-    first = rng.integers(1, 255, size=32)  # ends exactly at 2 pages (bs=16)
-    second = rng.integers(1, 255, size=19)
+    first = rng.integers(1, 255, size=first_len)  # 32 ends exactly at 2 pages (bs=16)
+    second = rng.integers(1, 255, size=second_len)
 
     def run(cfg):
         k, v = _fresh(cfg)
         lg1, k, v = _prefill(params, cfg, k, v, first, table)
-        lg2, k, v = _prefill(params, cfg, k, v, second, table, cache_len=32)
+        lg2, k, v = _prefill(params, cfg, k, v, second, table, cache_len=first_len)
         return lg1, lg2, k, v
 
     g1, g2, kg, vg = run(CFG)
@@ -100,9 +207,10 @@ def test_prefill_chunk_with_prefix_parity():
     np.testing.assert_allclose(np.asarray(kg), np.asarray(km), atol=2e-5)
 
 
-def test_mixed_step_parity_chunk_plus_decode_rows():
-    """The whole mixed step — a ragged chunk row AND length-1 decode rows in
-    one launch — matches the two-shape XLA path, including padded chunk
+@pytest.mark.parametrize("bucket,valid", [(16, 9), (32, 9), (32, 27)], ids=["9-of-16", "9-of-32", "27-of-32"])
+def test_mixed_step_parity_chunk_plus_decode_rows(tile_max, bucket, valid):
+    """The whole mixed step — a ragged chunk row AND length-1 decode rows —
+    matches the two-shape XLA path, including padded chunk
     queries (len < bucket) and an INACTIVE decode lane. Scratch block 0 is
     excluded from the KV comparison: dead rows sink different garbage
     there by design and it is never handed out or read."""
@@ -121,8 +229,8 @@ def test_mixed_step_parity_chunk_plus_decode_rows():
     )
     active = jnp.asarray(np.array([True, True, True, False]))
 
-    chunk = np.zeros((16,), np.int32)
-    chunk[:9] = rng.integers(1, 255, size=9)
+    chunk = np.zeros((bucket,), np.int32)
+    chunk[:valid] = rng.integers(1, 255, size=valid)
 
     # Fixed prompts so both impls seed bit-identical caches. The chunk
     # sequence's 21-token cached prefix (toks above) lives at blocks 5-8.
@@ -140,7 +248,7 @@ def test_mixed_step_parity_chunk_plus_decode_rows():
                                jnp.asarray(tbl.astype(np.int32)))
         return jax.jit(
             lambda p, k, v: llama.mixed_step(
-                p, cfg, k, v, jnp.asarray(chunk), jnp.int32(9), jnp.int32(21),
+                p, cfg, k, v, jnp.asarray(chunk), jnp.int32(valid), jnp.int32(21),
                 p_table, dtoks, dpos, tables_d, active,
             )
         )(params, k, v)
@@ -155,31 +263,34 @@ def test_mixed_step_parity_chunk_plus_decode_rows():
     np.testing.assert_allclose(np.asarray(vg)[:, 1:], np.asarray(vm)[:, 1:], atol=2e-5)
 
 
-def test_dead_queries_return_zeros():
+@pytest.mark.parametrize("tile", [1, 2, 4])
+def test_dead_queries_return_zeros(tile):
     """Dead ragged rows (meta active=0) read nothing and return exact zeros
-    from the kernel — the pl.when skip, not masked softmax garbage."""
+    from the kernel — the pl.when skip, not masked softmax garbage — whether
+    a grid row is one query or a tile of them: two rows of four queries, the
+    second row's last three dead (half a tile of 2 and one whole, or most of
+    a tile of 4), and a third row dead altogether."""
     kvh, hd, bs = 2, 16, 16
     H = 4
     rng = np.random.default_rng(4)
-    q = jnp.asarray(rng.standard_normal((3, H, hd)).astype(np.float32))
-    ke = jnp.asarray(rng.standard_normal((3, kvh, hd)).astype(np.float32))
+    q = jnp.asarray(rng.standard_normal((12, H, hd)).astype(np.float32))
+    ke = jnp.asarray(rng.standard_normal((12, kvh, hd)).astype(np.float32))
     # Pages in the pool's layout: heads merged into lanes (KvCacheArrays).
     k_pages = jnp.asarray(rng.standard_normal((6, bs, kvh * hd)).astype(np.float32))
     v_pages = jnp.asarray(rng.standard_normal((6, bs, kvh * hd)).astype(np.float32))
     tables = jnp.asarray(np.array([[1, 2], [3, 4], [0, 0]], np.int32))
+    i = np.arange(12, dtype=np.int32)
+    live = np.array([1, 1, 1, 1, 1, 0, 0, 0, 0, 0, 0, 0], np.int32)
     meta = mk.build_meta(
-        jnp.asarray(np.array([0, 1, 2], np.int32)),
-        jnp.asarray(np.array([20, 20, 0], np.int32)),
-        jnp.asarray(np.array([0, 1, 2], np.int32)),
-        jnp.asarray(np.array([1, 2, 2], np.int32)),  # row 2: no fresh keys either
-        jnp.asarray(np.array([1, 1, 0], np.int32)),  # row 2 dead
+        jnp.asarray(i // 4), jnp.asarray(np.where(i < 8, 20, 0).astype(np.int32)),
+        jnp.asarray(i // 4 * 4), jnp.asarray(i + 1), jnp.asarray(live),
     )
-    out = mk.ragged_paged_attention(
+    out = np.asarray(mk.ragged_paged_attention(
         q, ke, ke, k_pages, v_pages, tables, meta,
-        num_kv_heads=kvh, block_size=bs, interpret=True,
-    )
-    assert np.all(np.asarray(out)[2] == 0.0), "dead query must return zeros"
-    assert np.all(np.isfinite(np.asarray(out)[:2]))
+        num_kv_heads=kvh, block_size=bs, tile=tile, interpret=True,
+    ))
+    assert np.all(out[live == 0] == 0.0), "dead query must return zeros"
+    assert np.all(np.isfinite(out)) and np.all(np.abs(out[live == 1]).sum(axis=(1, 2)) > 0)
 
 
 # ---------------------------------------------------------------------------
@@ -187,35 +298,87 @@ def test_dead_queries_return_zeros():
 # ---------------------------------------------------------------------------
 
 
-def test_int8_kv_megakernel_parity():
+def test_int8_kv_megakernel_parity(tile_max):
     """Megakernel attention over a QuantKv cache (int8 codes + per-(token,
     head) scales dequantized in VMEM) matches the gather path reading the
     SAME quantized cache — bitwise-equal inputs, so tolerance is float
-    accumulation, not quantization error."""
+    accumulation, not quantization error. A fresh prefill, a chunk of 21
+    over that int8 prefix (the tile dequantises a page once for its
+    queries), then a decode step."""
     cfg8_g = CFG.replace(kv_cache_dtype="int8")
     cfg8_m = cfg8_g.replace(attention_impl="megakernel")
     params = llama.init_params(CFG, jax.random.PRNGKey(0), dtype=jnp.float32)
     rng = np.random.default_rng(5)
     table = jnp.asarray(np.arange(1, 5, dtype=np.int32))
     toks = rng.integers(1, 255, size=30)
+    more = rng.integers(1, 255, size=21)
 
     B = 2
     dtoks = jnp.asarray(rng.integers(1, 255, size=B).astype(np.int32))
-    pos = jnp.full((B,), 30, jnp.int32)
+    pos = jnp.full((B,), 51, jnp.int32)
     tables_d = jnp.asarray(np.tile(np.arange(1, 5, dtype=np.int32), (B, 1)))
     active = jnp.ones((B,), bool)
 
     def run(cfg):
         k, v = _fresh(cfg)
         _, k, v = _prefill(params, cfg, k, v, toks, table)
+        lg_chunk, k, v = _prefill(params, cfg, k, v, more, table, cache_len=30)
         lg, k, v = jax.jit(
             lambda p, k, v: llama.decode(p, cfg, k, v, dtoks, pos, tables_d, active)
         )(params, k, v)
-        return lg
+        return lg_chunk, lg
 
-    lg_g = run(cfg8_g)
-    lg_m = run(cfg8_m)
-    np.testing.assert_allclose(np.asarray(lg_g), np.asarray(lg_m), atol=5e-4)
+    for g, m in zip(run(cfg8_g), run(cfg8_m)):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(m), atol=5e-4)
+
+
+# ---------------------------------------------------------------------------
+# The mechanism, read off the traced programs: grid steps of the launches
+# ---------------------------------------------------------------------------
+
+
+def _kernel_grids(jaxpr):
+    """Grids of the ``pallas_call``s under ``jaxpr``, a layer scan's once."""
+    from tests.test_kv_layout import _sub_jaxprs
+
+    grids = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            grids.append(tuple(eqn.params["grid_mapping"].grid))
+        for inner in _sub_jaxprs(eqn):
+            grids += _kernel_grids(inner)
+    return grids
+
+
+# The cells' widths (Mistral-7B: 32/8 heads of 128, pages of 128) at two layers, traced on shapes alone.
+CELL = CFG.replace(name="cell-widths", hidden_size=4096, num_heads=32, num_kv_heads=8, head_dim=128, intermediate_size=512,
+                   block_size=128, max_seq_len=2048, attention_impl="megakernel")
+
+
+@pytest.mark.parametrize("cfg,S,B,W", [(CELL, 256, 32, 12), (CELL, 256, 4, 16), (MEGA, 32, 4, 4), (MEGA, 16, 2, 8)],
+                         ids=["cell-b32", "cell-b4", "tiny-32", "tiny-16"])
+def test_a_chunks_queries_share_their_grid_steps(cfg, S, B, W):
+    """A chunk of ``S`` queries beside ``B`` decode rows takes at most
+    ``ceil(S/TQ)*(W+1) + B*(W+1)`` grid steps a layer in ``mixed_step``, and
+    ``ceil(S/TQ)*(W+1)`` in ``prefill`` — not ``(S+B)*(W+1)``, a table walk a
+    query (3,744 at the cell's 288 x 13 before PR 31)."""
+    shapes = jax.eval_shape(lambda: llama.init_params(cfg, jax.random.PRNGKey(0), dtype=jnp.bfloat16))
+    cache = jax.ShapeDtypeStruct((cfg.num_layers, 64, cfg.block_size, cfg.kv_size), jnp.bfloat16)
+    i32 = jnp.int32
+    tq = mk.chunk_tile(S, cfg.num_heads, cfg.num_kv_heads, cfg.head_dim, cfg.block_size)
+    tiles = -(-S // tq)
+    assert llama.chunk_attn_path(cfg, cache, S, jnp.bfloat16) == f"tile{tq}"
+
+    mixed = jax.make_jaxpr(lambda p, k, v: llama.mixed_step(
+        p, cfg, k, v, jnp.zeros((S,), i32), i32(S - 3), i32(40), jnp.ones((W,), i32),
+        jnp.zeros((B,), i32), jnp.full((B,), 9, i32), jnp.ones((B, W), i32), jnp.ones((B,), bool)))(shapes, cache, cache)
+    grids = _kernel_grids(mixed.jaxpr)
+    assert sorted(grids) == sorted([(tiles, W + 1), (B, W + 1)])
+    assert sum(a * b for a, b in grids) <= tiles * (W + 1) + B * (W + 1) < (S + B) * (W + 1)
+
+    chunk = jax.make_jaxpr(lambda p, k, v: llama.prefill(
+        p, cfg, k, v, jnp.zeros((S,), i32), i32(S - 3), i32(40), jnp.ones((W,), i32)))(shapes, cache, cache)
+    assert _kernel_grids(chunk.jaxpr) == [(tiles, W + 1)]
 
 
 def test_paged_int8_degrades_to_gather():

@@ -138,7 +138,8 @@ def test_flight_records_and_recent_steps_keep_their_shape():
     drain(sched)
     for recent in (sched.debug_state()["flight"]["recent_steps"], sched.flight.ring_snapshot()["recent_steps"]):
         assert 0 < len(recent) <= RECENT_STEPS
-        assert all(set(r) == {"age_s", "phase", "dur_s", "tokens"} for r in recent)
+        assert all(set(r) - {"chunk_attn"} == {"age_s", "phase", "dur_s", "tokens"} for r in recent)
+        assert all(("chunk_attn" in r) == (r["phase"] in ("prefill", "mixed")) for r in recent)
         assert {r["phase"] for r in recent} <= {"prefill", "decode", "mixed", "wave", "spec"}
         assert all(r["age_s"] >= 0 and r["dur_s"] >= 0 and isinstance(r["tokens"], int) for r in recent)
         assert [r["age_s"] for r in recent] == sorted((r["age_s"] for r in recent), reverse=True)  # oldest first
@@ -151,6 +152,27 @@ def test_flight_records_and_recent_steps_keep_their_shape():
     assert len(recent) == RECENT_STEPS and recent[-1] == {**recent[-1], "phase": "mixed", "tokens": 136}
     assert recent[0]["dur_s"] == pytest.approx(0.004, abs=1e-6) and fr.last_step_phase == "mixed"
     assert abs(time.monotonic() - fr.last_step_ts) < 1.0  # the stall watchdog's clock
+
+
+@pytest.mark.parametrize("impl,path", [("gather", "gather"), ("paged", "paged"), ("megakernel", "tile32")])
+def test_a_chunk_carrying_step_names_the_chunks_attention_path(impl, path):
+    """``sched.step`` of a dispatch that carried a prefill chunk says how the
+    chunk met its keys in the program as traced — a bare chunk, a mixed step,
+    and a chunk that follows a decode dispatch in its iteration — and
+    ``recent_steps`` of /debug/state shows it."""
+    sched = Scheduler(CFG.replace(attention_impl=impl), PARAMS,
+                      SchedulerConfig(num_blocks=128, max_running=8, prefill_buckets=[32], decode_buckets=[4],
+                                      num_scheduler_steps=1, enable_prefix_caching=False), dtype=jnp.float32)
+    add(sched, "a", list(range(1, 40)), 12)
+    drain(sched, late=[("b", list(range(30, 100)), 4)])
+    steps = [e[4] for e in sched.flight.log.spans if e[0] == "sched.step" and e[4]]
+    carried = [a for a in steps if a["prefill"] or a["kind"] in ("prefill", "mixed")]
+    assert {a["kind"] for a in carried} >= {"prefill", "mixed"}
+    assert carried and all(a["chunk_attn"] == path for a in carried)
+    assert all("chunk_attn" not in a for a in steps if a not in carried and a.get("dispatches", 1) == 1)
+    recent = sched.debug_state()["flight"]["recent_steps"]
+    assert {r["chunk_attn"] for r in recent if r["phase"] in ("prefill", "mixed")} == {path}
+    assert all("chunk_attn" not in r for r in recent if r["phase"] == "decode")
 
 
 def test_host_gap_is_read_from_the_logs_launch_stamps():

@@ -73,10 +73,10 @@ def _sds(shape, dtype, sharding):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
-def _pages(sh, quant=False, n=16 * NUM_BLOCKS):
+def _pages(sh, quant=False, n=16 * NUM_BLOCKS, kvh=KVH, hd=HD, bs=BS):
     if quant:
-        return QuantKv(_sds((n, BS, KVH * HD), jnp.int8, sh), _sds((n, BS, KVH), jnp.float32, sh))
-    return _sds((n, BS, KVH * HD), BF16, sh)
+        return QuantKv(_sds((n, bs, kvh * hd), jnp.int8, sh), _sds((n, bs, kvh), jnp.float32, sh))
+    return _sds((n, bs, kvh * hd), BF16, sh)
 
 
 def _model_args(param_sh, cache_sh):
@@ -95,23 +95,43 @@ def _one_chip_args(one_chip):
 # --- kernels ----------------------------------------------------------------
 
 
-@pytest.mark.parametrize(
-    "nq,ck,rows,width,quant",
-    [(8, 8, 8, 64, False), (520, 520, 9, 64, False), (8, 8, 8, 64, True)],
-    ids=["decode-b8", "mixed-chunk512+b8", "decode-b8-int8kv"],
-)
-def test_ragged_paged_attention_compiles(one_chip, nq, ck, rows, width, quant):
-    from dynamo_tpu.engine.attention.megakernel import ragged_paged_attention
+WIDTHS = {  # H, KVH, HD, BS
+    "1b": (H, KVH, HD, BS),
+    "cell": (32, 8, 128, 128),  # the benchmark's llama cells: Mistral-7B's and Mixtral's heads, pages of 128
+}
 
+
+@pytest.mark.parametrize(
+    "widths,nq,ck,rows,width,quant,chunk",
+    [("1b", 8, 8, 8, 64, False, False), ("1b", 520, 520, 9, 64, False, False), ("1b", 8, 8, 8, 64, True, False),
+     ("1b", 512, 512, 1, 32, False, True),
+     # What a mixed step of the cells launches a layer: the chunk by tiles over its one row of 16 slots,
+     # the 32 decode rows a query a grid row (33 rows x 16 slots, 288 queries in one launch before PR 31).
+     ("cell", 256, 256, 1, 16, False, True), ("cell", 256, 256, 1, 16, True, True),
+     ("cell", 32, 32, 32, 16, False, False), ("cell", 32, 32, 32, 16, True, False),
+     ("cell", 288, 288, 33, 16, False, False), ("cell", 2048, 2048, 1, 16, False, True)],
+    ids=["decode-b8", "mixed-chunk512+b8", "decode-b8-int8kv", "chunk512-tiles",
+         "cell-chunk256-tiles", "cell-chunk256-tiles-int8kv", "cell-rows32", "cell-rows32-int8kv",
+         "cell-walk288", "cell-chunk2048-tiles"],
+)
+def test_ragged_paged_attention_compiles(one_chip, widths, nq, ck, rows, width, quant, chunk):
+    """The kernel at the widths and shapes the programs launch it with: a VMEM
+    overflow or a slice Mosaic refuses shows here, before a chip run."""
+    from dynamo_tpu.engine.attention.megakernel import chunk_tile, ragged_paged_attention
+
+    h, kvh, hd, bs = WIDTHS[widths]
     i32 = jnp.int32
+    tile = chunk_tile(nq, h, kvh, hd, bs, kv_bytes=1 if quant else 2) if chunk else 1
+    pages = _pages(one_chip, quant, kvh=kvh, hd=hd, bs=bs)
     compiled = ragged_paged_attention.lower(
-        _sds((nq, H, HD), BF16, one_chip),
-        _sds((ck, KVH, HD), BF16, one_chip), _sds((ck, KVH, HD), BF16, one_chip),
-        _pages(one_chip, quant), _pages(one_chip, quant),
+        _sds((nq, h, hd), BF16, one_chip),
+        _sds((ck, kvh, hd), BF16, one_chip), _sds((ck, kvh, hd), BF16, one_chip),
+        pages, pages,
         _sds((rows, width), i32, one_chip), _sds((5, nq), i32, one_chip),
-        num_kv_heads=KVH, block_size=BS, interpret=False,
+        num_kv_heads=kvh, block_size=bs, tile=tile, interpret=False,
     ).compile()
     assert "tpu_custom_call" in compiled.as_text()
+    assert (tile > 1) == chunk
 
 
 @pytest.mark.parametrize("T", [512, 2048])
@@ -326,10 +346,16 @@ def test_tp4_decode_step_partitions(tp4, on_tpu):
 
 @pytest.mark.parametrize("attention_impl", ["auto", "gather"], ids=["megakernel-rows", "flash-kernel"])
 def test_tp4_prefill_step_partitions(tp4, on_tpu, attention_impl):
+    """Under the megakernel the chunk is walked by tiles inside the
+    ``shard_map``: the tile is taken at a shard's 8/2 heads."""
     cfg = CFG.replace(attention_impl=attention_impl)
     model = bind_mesh(llama, tp4)
     assert model.resolve_prefill_impl(cfg) == "flash"
     p, k, v = _tp4_args(tp4)
+    if attention_impl == "auto":
+        from dynamo_tpu.engine.attention.megakernel import chunk_tile
+
+        assert model.chunk_attn_path(cfg, k, 512, BF16) == f"tile{chunk_tile(512, H // 4, KVH // 4, HD, BS)}"
     rep = NamedSharding(tp4, P())
     i32 = jnp.int32
     compiled = jax.jit(

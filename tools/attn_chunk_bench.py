@@ -1,0 +1,182 @@
+#!/usr/bin/env python3
+"""Micro-benchmark of one layer's attention in a mixed step (PERF.md §6, PR 31):
+a chunk of 256 queries over a paged prefix and its own keys, beside 32 decode
+rows of 12 pages, at two pool widths — ``KVH*HD`` 1,024 lanes (Mistral-7B:
+32/8 heads of 128) and 4,096 (EvaByte: 32/32 heads of 128).
+
+Forms, each a ``lax.scan`` over L layers of a layer-flat pool, timed whole and
+divided by L (the queries of layer l+1 follow from layer l's output, so nothing hoists):
+
+- ``walk``:        one launch of ``ragged_paged_attention`` over all 288
+                   queries, a grid row a query (the program before PR 31).
+- ``tile<T>``:     the chunk as tiles of T queries, its dots per lane group
+                   (``megakernel.lane_fold``), and the decode rows a query a
+                   grid row: two launches (what ``llama.mixed_step`` runs).
+- ``tile<T>fold``: the same with every KV head folded block-diagonally into
+                   one group, as a length-1 row's are.
+- ``chunk<T>`` / ``rows``: the two launches of ``tile<T>`` apart.
+- ``paged``:       the chunk on ``attention_impl="paged"``'s path (the prefix
+                   fetched through ``llama._gather_kv``, flash kernel for the
+                   chunk, XLA score product for the prefix); no decode rows.
+- ``pagedrows``:   the decode rows on that path (``paged_decode_partials``
+                   merged with the current token's piece).
+
+    chiprun -- python tools/attn_chunk_bench.py
+    JAX_PLATFORMS=cpu python tools/attn_chunk_bench.py --tiny   # control flow only
+
+Prints one JSON line per reading (µs a layer) and writes them to
+``chiprun_out/attn_chunk_bench.jsonl``.
+"""
+
+from __future__ import annotations
+
+import argparse
+import json
+import os
+import sys
+import time
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+from dynamo_tpu.engine.attention import megakernel as mk
+from dynamo_tpu.engine.attention.decode import paged_decode_partials
+from dynamo_tpu.engine.attention.ragged import ragged_chunk_attention
+from dynamo_tpu.engine.models import llama
+
+
+def build(form, tile, *, S, B, W, N, L, KVH, BS, prefix, d_prefix, interpret):
+    """``fn(q [S+B, H, HD], k, v [S+B, KVH, HD], k_pool, v_pool [L*N, BS, KVH*HD])``
+    -> the last layer's attention output, by ``form``."""
+    i32 = jnp.int32
+    s_iq, d_iq = jnp.arange(S, dtype=i32), jnp.arange(B, dtype=i32)
+    p_table = jnp.arange(1, W + 1, dtype=i32)  # the chunk's pages
+    d_tables = (W + 1 + (d_iq[:, None] * W + jnp.arange(W, dtype=i32)[None]) % (N - W - 1)).astype(i32)
+    zeros = jnp.zeros((S,), i32)
+    p_meta = mk.build_meta(zeros, jnp.full((S,), prefix, i32), zeros, s_iq + 1, jnp.ones((S,), i32))
+    d_meta = mk.build_meta(d_iq, jnp.full((B,), d_prefix, i32), d_iq, d_iq + 1, jnp.ones((B,), i32))
+    kw = dict(num_kv_heads=KVH, block_size=BS, interpret=interpret)
+
+    def chunk(q, k, v, kp, vp, l):
+        return mk.ragged_paged_attention(q[:S], k[:S], v[:S], kp, vp, (p_table + l * N)[None], p_meta, tile=tile, **kw)
+
+    def rows(q, k, v, kp, vp, l):
+        return mk.ragged_paged_attention(q[S:], k[S:], v[S:], kp, vp, d_tables + l * N, d_meta, **kw)
+
+    def walk(q, k, v, kp, vp, l):
+        tables = jnp.zeros((1 + B, W), i32).at[0].set(p_table).at[1:].set(d_tables) + l * N
+        meta = mk.build_meta(
+            jnp.concatenate([zeros, 1 + d_iq]), jnp.concatenate([p_meta[1], d_meta[1]]),
+            jnp.concatenate([zeros, S + d_iq]), jnp.concatenate([s_iq + 1, S + d_iq + 1]), jnp.ones((S + B,), i32),
+        )
+        return mk.ragged_paged_attention(q, k, v, kp, vp, tables, meta, **kw)
+
+    def paged(q, k, v, kp, vp, l):
+        ctx = [llama._gather_kv(p, p_table + l * N, q.dtype).reshape(W * BS, KVH, -1) for p in (kp, vp)]
+        return ragged_chunk_attention(
+            q[:S], k[:S], v[:S], *ctx, jnp.int32(S), jnp.int32(prefix),
+            num_kv_heads=KVH, use_flash=True, has_prefix=prefix > 0, interpret=interpret,
+        )
+
+    def pagedrows(q, k, v, kp, vp, l):
+        prefix = paged_decode_partials(q[S:], kp, vp, d_tables + l * N, d_meta[1], **kw)
+        qg = q[S:].reshape(B, KVH, -1, q.shape[-1])
+        own = llama._attend_piece(qg, k[S:, None], v[S:, None], jnp.ones((B, 1), bool), q.shape[-1] ** -0.5)
+        return llama._merge_pieces(*prefix, *own).reshape(B, -1, q.shape[-1])
+
+    def both(*a):
+        return jnp.concatenate([chunk(*a), rows(*a)])
+
+    # The form's layer, and the queries it writes.
+    layer, lo, hi = {"walk": (walk, 0, S + B), "tile": (both, 0, S + B), "chunk": (chunk, 0, S), "rows": (rows, S, S + B),
+                     "paged": (paged, 0, S), "pagedrows": (pagedrows, S, S + B)}[form]
+
+    def fn(q, k, v, kp, vp):
+        # The queries are carried in float32 and rounded once a layer, so that
+        # two forms differ by their attention alone.
+        def body(q32, l):
+            out = layer(q32.astype(q.dtype), k, v, kp, vp, l)
+            return q32.at[lo:hi].add(0.01 * out.astype(jnp.float32)), None
+
+        return lax.scan(body, q.astype(jnp.float32), jnp.arange(L, dtype=i32))[0]
+
+    return fn, lo, hi
+
+
+def measure(fn, args, iters):
+    compiled = jax.jit(fn).lower(*args).compile()
+    out = compiled(*args)
+    out.block_until_ready()
+    t0 = time.perf_counter()
+    for _ in range(iters):
+        out = compiled(*args)
+    out.block_until_ready()
+    return (time.perf_counter() - t0) / iters * 1e6, out
+
+
+def main():
+    ap = argparse.ArgumentParser()
+    ap.add_argument("--tiny", action="store_true")
+    ap.add_argument("--layers", type=int, default=16)
+    ap.add_argument("--iters", type=int, default=10)
+    ap.add_argument("--lanes", type=int, nargs="*", default=[1024, 4096])
+    ap.add_argument("--prefix", type=int, nargs="*", default=[0, 512, 1408])
+    ap.add_argument("--tiles", type=int, nargs="*", default=[32, 64, 128, 256])
+    ap.add_argument("--fold-tiles", type=int, nargs="*", default=[16, 32])
+    a = ap.parse_args()
+    on_tpu = jax.devices()[0].platform == "tpu"
+    if a.tiny:
+        S, B, W, N, L, H, HD, BS, dtype = 32, 4, 4, 16, 2, 4, 16, 16, jnp.float32
+        a.lanes, a.prefix, a.tiles, a.fold_tiles, a.iters = [32, 64], [0, 24], [16, 32], [16], 1
+    else:
+        S, B, W, N, L, H, HD, BS, dtype = 256, 32, 16, 64, a.layers, 32, 128, 128, jnp.bfloat16
+    os.makedirs("chiprun_out", exist_ok=True)
+    log = open("chiprun_out/attn_chunk_bench.jsonl", "a")
+
+    def say(**kw):
+        line = json.dumps(kw)
+        print(line, flush=True)
+        log.write(line + "\n")
+        log.flush()
+
+    say(device=str(jax.devices()[0].device_kind), S=S, B=B, W=W, L=L, H=H, HD=HD, BS=BS, dtype=str(jnp.dtype(dtype)))
+    lane_fold = mk.lane_fold
+    for lanes in a.lanes:
+        KVH = lanes // HD
+        keys = jax.random.split(jax.random.PRNGKey(lanes), 5)
+        q = jax.random.normal(keys[0], (S + B, H, HD), dtype)
+        k = jax.random.normal(keys[1], (S + B, KVH, HD), dtype)
+        v = jax.random.normal(keys[2], (S + B, KVH, HD), dtype)
+        kp = jax.random.normal(keys[3], (L * N, BS, lanes), dtype)
+        vp = jax.random.normal(keys[4], (L * N, BS, lanes), dtype)
+        d_prefix = min(12, W) * BS - BS // 2
+        for prefix in a.prefix:
+            forms = [("walk", 1, False), ("rows", 1, False), ("paged", 1, False), ("pagedrows", 1, False)]
+            forms += [(f, t, False) for t in a.tiles for f in ("tile", "chunk")]
+            forms += [("tile", t, True) for t in a.fold_tiles]
+            ref = None
+            for form, tile, fold in forms:
+                name = form + (str(tile) if tile > 1 else "") + ("fold" if fold else "")
+                mk.lane_fold = (lambda kvh, hd: kvh) if fold else lane_fold
+                mk.ragged_paged_attention.clear_cache()  # its traces are keyed by the tile, not by the fold
+                try:
+                    fn, lo, hi = build(form, tile, S=S, B=B, W=W, N=N, L=L, KVH=KVH, BS=BS, prefix=prefix,
+                               d_prefix=d_prefix, interpret=not on_tpu)
+                    us, out = measure(fn, (q, k, v, kp, vp), a.iters)
+                except Exception as e:  # a tile the compiler refuses is a reading too
+                    say(lanes=lanes, prefix=prefix, form=name, error=f"{type(e).__name__}: {str(e)[:300]}")
+                    continue
+                finally:
+                    mk.lane_fold = lane_fold
+                out = jnp.asarray(out, jnp.float32)
+                if form == "walk":
+                    ref = out
+                err = float(jnp.max(jnp.abs(ref[lo:hi] - out[lo:hi])) / (jnp.max(jnp.abs(ref[lo:hi])) + 1e-9))
+                say(lanes=lanes, prefix=prefix, form=name, us_per_layer=us / L, max_rel_diff_vs_walk=err)
+
+
+if __name__ == "__main__":
+    main()
