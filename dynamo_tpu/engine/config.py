@@ -10,7 +10,7 @@ from __future__ import annotations
 
 import dataclasses
 from dataclasses import dataclass
-from typing import Optional
+from typing import Optional, Tuple
 
 
 @dataclass(frozen=True)
@@ -146,6 +146,45 @@ class ModelConfig:
     # head 0 (columns [0, vocab)) gives the next-token logits, the only ones
     # served; the others' weights are held, not multiplied.
     num_pred_heads: int = 1
+    # Mixer of each layer, in order: "attention" or "mamba" (a Mamba-2
+    # state-space mixer). Empty: every layer is attention, one stack, the
+    # llama step programs. Non-empty: ``models/hybrid.py`` drives the stack as
+    # ordered groups of one kind (``layer_groups``), each group one scan; the
+    # paged pool then holds the attention layers only, and every running
+    # sequence holds one *slot* of recurrent state beside it
+    # (``kv_cache.SlotKv``). Every layer has the same FFN.
+    layer_types: Tuple[str, ...] = ()
+    # Mamba-2 sizes under their published names (``mamba_<key>``): the state
+    # of one head is [d_head, d_state]; d_inner = expand * hidden_size =
+    # n_heads * d_head; the causal depthwise convolution of width d_conv runs
+    # over d_inner + 2 * n_groups * d_state lanes; ``chunk_size`` is the block
+    # of the chunked (SSD) form a prefill chunk goes through.
+    mamba_d_state: int = 0
+    mamba_d_conv: int = 4
+    mamba_n_heads: int = 0
+    mamba_d_head: int = 0
+    mamba_n_groups: int = 1
+    mamba_expand: int = 2
+    mamba_chunk_size: int = 256
+    # An expert layer that holds a share of its experts: the router is
+    # ``num_experts`` wide and picks ``num_experts_per_tok`` of them, the
+    # stacks hold experts [first_expert_held, first_expert_held +
+    # num_experts_held) and an assignment to any other adds nothing here (the
+    # chip that holds it adds it). 0 held = all of them.
+    num_experts_held: int = 0
+    first_expert_held: int = 0
+    # Width of a dense SwiGLU every token passes beside the routed experts,
+    # added ungated (0 = none).
+    shared_intermediate_size: int = 0
+    # Rotary position embedding on queries and keys (off: "nope").
+    use_rope: bool = True
+    # Multiplier of q . k before the softmax (0 = head_dim ** -0.5).
+    attention_scale: float = 0.0
+    # The Granite multipliers: of the embedding rows, of every residual
+    # branch, and the divisor of the logits.
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
 
     def __post_init__(self):
         if self.attention_impl not in ("auto", "gather", "paged", "megakernel"):
@@ -191,6 +230,57 @@ class ModelConfig:
                 "weight_dtype='int8' does not cover MoE expert stacks "
                 "(ragged/capacity dispatch would re-dequantize per expert)"
             )
+        if self.layer_types:
+            self._check_hybrid()
+        elif (
+            self.num_experts_held
+            or self.shared_intermediate_size
+            or not self.use_rope
+            or self.attention_scale
+            or (self.embedding_multiplier, self.residual_multiplier, self.logits_scaling) != (1.0, 1.0, 1.0)
+        ):
+            raise ValueError(
+                "a share of the experts, a shared expert, use_rope=False, attention_scale and the multipliers "
+                "are read by the layer-group step programs only: state layer_types"
+            )
+
+    def _check_hybrid(self) -> None:
+        """What ``models/hybrid.py`` serves, and what it refuses by name."""
+        if len(self.layer_types) != self.num_layers or set(self.layer_types) - {"attention", "mamba"}:
+            raise ValueError(
+                f"layer_types names 'attention' or 'mamba' for each of num_layers={self.num_layers} layers, "
+                f"got {self.layer_types!r}"
+            )
+        refused = {
+            "architecture other than 'llama'": self.architecture != "llama",
+            "attention_kind 'eva'": self.is_eva,
+            "weight_dtype 'int8' (int8 weights)": self.weight_dtype == "int8",
+            "kv_cache_dtype 'int8'": self.kv_cache_dtype == "int8",
+            "attention_impl 'paged'": self.attention_impl == "paged",
+            "a float32 residual, a norm unit offset or several prediction heads": (
+                self.residual_fp32 or self.norm_unit_offset or self.num_pred_heads > 1
+            ),
+            "moe_dispatch other than auto|ragged": self.num_experts > 0 and self.moe_dispatch not in ("auto", "ragged"),
+        }
+        for what, hit in refused.items():
+            if hit:
+                self.refuse_for_layer_types(what)
+        if "mamba" in self.layer_types:
+            if min(self.mamba_d_state, self.mamba_n_heads, self.mamba_d_head, self.mamba_n_groups) <= 0:
+                raise ValueError("a 'mamba' layer needs mamba_d_state, mamba_n_heads, mamba_d_head, mamba_n_groups > 0")
+            if self.mamba_n_heads * self.mamba_d_head != self.mamba_expand * self.hidden_size:
+                raise ValueError(
+                    f"mamba_n_heads * mamba_d_head = {self.mamba_n_heads * self.mamba_d_head} is not "
+                    f"mamba_expand * hidden_size = {self.mamba_expand * self.hidden_size}"
+                )
+            if self.mamba_n_heads % self.mamba_n_groups or self.mamba_d_conv < 2 or self.mamba_chunk_size < 1:
+                raise ValueError("mamba_n_groups divides mamba_n_heads; mamba_d_conv >= 2; mamba_chunk_size >= 1")
+        held = self.num_experts_held
+        if held and not (0 < held <= self.num_experts and 0 <= self.first_expert_held <= self.num_experts - held):
+            raise ValueError(
+                f"experts [{self.first_expert_held}, {self.first_expert_held + held}) are not among "
+                f"the router's {self.num_experts}"
+            )
 
     @property
     def q_size(self) -> int:
@@ -208,6 +298,66 @@ class ModelConfig:
     def summaries_per_window(self) -> int:
         """Cache rows a completed window leaves behind (eva)."""
         return self.window_size // self.chunk_size
+
+    def refuse_for_layer_types(self, what: str) -> None:
+        """What equates a sequence with its block table alone is not built for
+        a model that also holds a slot of recurrent state: refuse by name,
+        never serve a sequence without its state."""
+        if self.layer_types:
+            raise NotImplementedError(f"{what} is not built for layer_types (model {self.name!r})")
+
+    @property
+    def is_hybrid(self) -> bool:
+        """Layers of stated kinds, driven as groups (``models/hybrid.py``)."""
+        return bool(self.layer_types)
+
+    @property
+    def layer_groups(self) -> Tuple[Tuple[str, int], ...]:
+        """``layer_types`` as ordered runs of one kind: ((kind, count), ...)."""
+        groups: list = []
+        for kind in self.layer_types:
+            if groups and groups[-1][0] == kind:
+                groups[-1][1] += 1
+            else:
+                groups.append([kind, 1])
+        return tuple((k, n) for k, n in groups)
+
+    @property
+    def num_attention_layers(self) -> int:
+        """Layers the paged pool holds rows for."""
+        return self.layer_types.count("attention") if self.layer_types else self.num_layers
+
+    @property
+    def num_mamba_layers(self) -> int:
+        return self.layer_types.count("mamba")
+
+    @property
+    def experts_held(self) -> int:
+        """Experts the stacks hold (all of the router's unless a share is stated)."""
+        return self.num_experts_held or self.num_experts
+
+    @property
+    def mamba_d_inner(self) -> int:
+        return self.mamba_n_heads * self.mamba_d_head
+
+    @property
+    def mamba_state_shape(self) -> Tuple[int, int, int]:
+        """How one slot's recurrent state of one layer is STORED: ``[H/g, N,
+        g*P]``, ``g`` heads side by side on the lanes (``g*P`` = 128 where
+        ``d_head`` divides it and ``g`` divides the heads, else ``g`` = 1) and
+        ``d_state`` on the sublanes. Head ``h``'s ``[P, N]`` state is
+        ``stored[h // g, :, (h % g) * P : (h % g + 1) * P].T``: a row of ``x``
+        or of ``y`` (``d_head`` lanes of ``g`` heads) then meets the state
+        without a transpose, and ``B``/``C`` broadcast along lanes
+        (``hybrid.ssm_update_rows``)."""
+        H, P, N = self.mamba_n_heads, self.mamba_d_head, self.mamba_d_state
+        g = 128 // P if P and 128 % P == 0 and H % (128 // P) == 0 else 1
+        return (H // g, N, g * P)
+
+    @property
+    def mamba_conv_dim(self) -> int:
+        """Lanes the convolution runs over: x, B and C."""
+        return self.mamba_d_inner + 2 * self.mamba_n_groups * self.mamba_d_state
 
     def replace(self, **kwargs) -> "ModelConfig":
         return dataclasses.replace(self, **kwargs)
@@ -294,6 +444,37 @@ PRESETS = {
         norm_unit_offset=True,
         residual_fp32=True,
         num_pred_heads=2,
+    ),
+    # Tiny hybrid config for unit tests: the shape of a published pattern
+    # (Mamba-2 layers around one attention layer) at hidden 64: two groups of
+    # Mamba, 8 experts top-3 of which 4 are held, a shared expert, no rope,
+    # a stated attention scale, every multiplier off 1.
+    "tiny-hybrid": ModelConfig(
+        name="tiny-hybrid",
+        vocab_size=256,
+        hidden_size=64,
+        num_layers=5,
+        num_heads=4,
+        num_kv_heads=2,
+        head_dim=16,
+        intermediate_size=32,
+        max_seq_len=256,
+        block_size=8,
+        tie_word_embeddings=True,
+        num_experts=8,
+        num_experts_per_tok=3,
+        num_experts_held=4,
+        shared_intermediate_size=48,
+        layer_types=("mamba", "mamba", "attention", "mamba", "mamba"),
+        mamba_d_state=16,
+        mamba_n_heads=8,
+        mamba_d_head=16,
+        mamba_chunk_size=16,
+        use_rope=False,
+        attention_scale=0.05,
+        embedding_multiplier=6.0,
+        residual_multiplier=0.5,
+        logits_scaling=4.0,
     ),
     # Tiny MLA config (DeepSeek-style latent attention) for unit tests.
     "tiny-mla": ModelConfig(

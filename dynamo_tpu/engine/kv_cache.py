@@ -51,6 +51,43 @@ class QuantKv(NamedTuple):
         return self.q.dtype
 
 
+class SlotKv(NamedTuple):
+    """One side of a hybrid model's cache (``ModelConfig.layer_types``): the
+    attention layers' paged pool and, beside it, one of the two slot arrays of
+    the state-space layers. A running sequence holds one *slot* from admission
+    to finish or preemption, whatever its length (``SlotAllocator``); slot 0 is
+    the scratch sink that padded rows read and write, as block 0 is the pool's.
+
+    ``k`` carries the recurrent state ``[L_m, S, *mamba_state_shape]``
+    (float32; ``ModelConfig.mamba_state_shape``: heads side by side on the
+    lanes, ``d_state`` on the sublanes) and the map ``slot_of`` from a block to the slot of the sequence
+    whose table *begins* with that block; ``v`` carries the convolution's last
+    ``d_conv - 1`` input columns ``[L_m, S, d_conv - 1, conv_dim]`` (compute
+    dtype; the columns are the leading axis of a slot so that the lane axis is
+    ``conv_dim``). A step program finds a row's slot as ``slot_of[table[0]]``
+    (``hybrid.open_slot`` writes the entry when it zeroes the slot), so its
+    signature is that of every other family's: a table of zeros reads and
+    writes the scratch slot. A pytree: it flows through jit arguments and
+    donation like a plain array."""
+
+    pool: Any  # jax.Array — [L_a, N, BS, KVH*HD]
+    slots: jax.Array
+    slot_of: Optional[jax.Array] = None  # [N] i32, on the k side only
+
+    @property
+    def shape(self):
+        return self.pool.shape
+
+    @property
+    def dtype(self):
+        return self.pool.dtype
+
+
+def pool_of(cache):
+    """The paged pool of one side of the cache, whatever rides beside it."""
+    return cache.pool if isinstance(cache, SlotKv) else cache
+
+
 def quantize_kv_rows(rows: jax.Array) -> QuantKv:
     """Symmetric int8 quantization of rows ``[..., KVH, HD]`` over the
     head_dim axis, returned in the pool's layout: codes ``[..., KVH*HD]``,
@@ -145,7 +182,7 @@ class KvCacheArrays:
     programs to this. Sharding over ``tp`` is on axis 3: a contiguous
     ``KVH*HD/tp`` slice is ``KVH/tp`` whole heads."""
 
-    k: Any  # jax.Array | QuantKv — [L, N, BS, KVH*HD]
+    k: Any  # jax.Array | QuantKv | SlotKv — [L, N, BS, KVH*HD]
     v: Any
     kv_heads: int = 1  # KVH of the merged axis (1 for MLA's latent row)
 
@@ -156,7 +193,13 @@ class KvCacheArrays:
         num_blocks: int,
         dtype=jnp.bfloat16,
         sharding: Optional[jax.sharding.Sharding] = None,
+        num_slots: int = 0,
     ) -> "KvCacheArrays":
+        """``L`` is the number of attention layers: all of them, or for a
+        hybrid model those ``layer_types`` names (its state-space layers hold
+        ``num_slots`` slots instead, scratch slot 0 included: ``SlotKv``)."""
+        if sharding is not None:
+            config.refuse_for_layer_types("a sharded cache (a mesh)")
         if config.architecture == "mla":
             # MLA stores one shared latent row per token (kv_lora_rank +
             # rope dim) in ``k``; ``v`` is a placeholder (values decompress
@@ -166,7 +209,7 @@ class KvCacheArrays:
             kv_heads, lanes = 1, config.kv_lora_rank + config.qk_rope_head_dim
         else:
             kv_heads, lanes = config.num_kv_heads, config.num_kv_heads * config.head_dim
-        rows = (config.num_layers, num_blocks, config.block_size)
+        rows = (config.num_attention_layers, num_blocks, config.block_size)
 
         def zeros(shape, dt):
             init = jnp.zeros(shape, dtype=dt)
@@ -178,11 +221,46 @@ class KvCacheArrays:
             return zeros((*rows, lanes), dtype)
 
         v = jnp.zeros((config.num_layers, 1, 1, 1), dtype=dtype) if config.architecture == "mla" else mk()
-        return cls(k=mk(), v=v, kv_heads=kv_heads)
+        k = mk()
+        if config.is_hybrid:
+            if num_slots < 2:
+                raise ValueError("a hybrid model's cache needs the scratch slot and at least one more (num_slots >= 2)")
+            c, Lm = config, config.num_mamba_layers
+            state = jnp.zeros((Lm, num_slots, *c.mamba_state_shape), jnp.float32)
+            columns = jnp.zeros((Lm, num_slots, c.mamba_d_conv - 1, c.mamba_conv_dim), dtype)
+            k = SlotKv(k, state, jnp.zeros((num_blocks,), jnp.int32))
+            v = SlotKv(v, columns)
+        return cls(k=k, v=v, kv_heads=kv_heads)
 
 
 class OutOfBlocksError(Exception):
     pass
+
+
+class SlotAllocator:
+    """Host-side bookkeeping of the state slots beside the block pool: a
+    sequence takes one at admission and gives it back at finish or preemption.
+    Slot 0 is the scratch sink and is never handed out."""
+
+    def __init__(self, num_slots: int):
+        self.num_slots = num_slots
+        self._free: List[int] = list(range(num_slots - 1, 0, -1))
+        self.allocs_total = 0
+
+    @property
+    def in_use(self) -> int:
+        return self.num_slots - 1 - len(self._free)
+
+    def allocate(self) -> int:
+        if not self._free:
+            raise OutOfBlocksError("no state slot free")
+        self.allocs_total += 1
+        return self._free.pop()
+
+    def release(self, slot: int) -> None:
+        if not 0 < slot < self.num_slots or slot in self._free:
+            raise ValueError(f"slot {slot} is not held")
+        self._free.append(slot)
 
 
 @dataclass

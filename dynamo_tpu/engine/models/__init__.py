@@ -10,6 +10,12 @@ from dynamo_tpu.engine.config import ModelConfig
 
 
 def get_module(config: ModelConfig):
+    if config.is_hybrid:
+        # Layers of stated kinds (ModelConfig.layer_types): the layer-group
+        # step programs, on the llama family's building blocks.
+        from dynamo_tpu.engine.models import hybrid
+
+        return hybrid
     if config.architecture == "llama":
         from dynamo_tpu.engine.models import llama
 

@@ -1,0 +1,753 @@
+"""Layer-group step programs: a stack whose layers are not all alike.
+
+``ModelConfig.layer_types`` names each layer's mixer, "attention" (GQA over
+the paged pool) or "mamba" (a Mamba-2 state-space mixer over one *slot* of
+recurrent state a sequence); every layer has the same FFN (sparse experts of
+which this chip may hold a share, a shared expert beside them, or a dense
+SwiGLU). The stack is driven as the ordered groups of one kind that
+``layer_types`` spells (``ModelConfig.layer_groups``): each group is one
+``lax.scan`` whose body indexes the kind's own stacked weights and the FFN
+stacks of all layers, so the compiled program holds one body a group, never
+one a layer, and the expert GEMMs read the one ``[L*E_held, D, F]`` view
+across all groups in place (``llama._split_expert_stacks``).
+
+The four step programs the scheduler serves — ``prefill``, ``mixed_step``,
+``decode``, ``decode_multi`` — take and return what ``llama``'s do, with the
+cache sides as ``kv_cache.SlotKv`` (the attention layers' pool, and beside it
+the slot arrays: recurrent state on the ``k`` side, the convolution's last
+columns on the ``v`` side) and one more result at the end: the expert layer's
+counts for the step log (``held_assignments``, ``experts_visited``, summed
+over layers). A row's slot is ``slot_of[table[0]]`` (``open_slot``): padded
+rows and tables of zeros read and write scratch slot 0.
+
+The Mamba-2 mixer has two bodies over one set of weights: the single-step
+recurrence for length-1 rows (decode rows; a multi-step window carries the
+slot arrays in its loop; on a TPU the Pallas kernel ``ssm_update_rows``, which
+advances the rows' slots in place, elsewhere a gather, ``_ssm_update`` and a
+scatter) and the chunked (SSD) form of the same recurrence for a wide row (a
+prefill chunk), which takes the slot's state and columns in and writes them
+back. A slot's state is stored ``[H/g, N, g*P]`` (heads side by side on the
+lanes, ``ModelConfig.mamba_state_shape``), which both bodies read as it lies.
+Padded positions of a chunk get a step of zero length (decay 1, no input) and
+are left out of the new columns, so they leave the slot as it was.
+
+Attention layers go through the llama family's attention paths ("gather",
+"megakernel"; "paged" is refused), without rope where the configuration says
+so; a stated ``attention_scale`` is folded into the queries, so that no kernel
+needs to know it. The Granite multipliers scale the embedding rows, every
+residual branch and the logits.
+
+Not built for this kind, and refused by name
+(``ModelConfig.refuse_for_layer_types``): prefix-block reuse,
+KV export/injection and the KVBM tiers, speculative verification and rollback,
+wave admission, a mesh, sequence embeddings, int8 weights.
+"""
+
+from __future__ import annotations
+
+import functools
+from typing import Dict, Tuple
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+from dynamo_tpu.engine.config import ModelConfig
+from dynamo_tpu.engine.kv_cache import SlotKv, layer_flat, ragged_scatter_targets
+from dynamo_tpu.engine.models import llama
+from dynamo_tpu.engine.models.llama import (  # noqa: F401 — the scheduler reads the resolvers off its model module
+    Params,
+    _attend_piece,
+    _gather_kv,
+    _mega_attend_rows,
+    _merge_pieces,
+    _moe_held,
+    _norm,
+    _scatter_kv,
+    _split_expert_stacks,
+    _use_megakernel,
+    apply_rope,
+    chunk_attn_path,
+    decode_targets,
+    resolve_attention_impl,
+    resolve_prefill_impl,
+    warn_attention_impl_degrade,
+)
+
+_HI = lax.Precision.HIGHEST  # the chunked scan's small float32 products: never a bf16 pass
+
+
+# ---------------------------------------------------------------------------
+# Initialization
+# ---------------------------------------------------------------------------
+
+
+def init_params(config: ModelConfig, key: jax.Array, dtype=jnp.bfloat16) -> Params:
+    """Random-init weights (testing). ``layers`` holds the FFN of every layer,
+    ``attn`` and ``mamba`` the mixers of the layers of each kind, stacked in
+    layer order."""
+    c = config
+    D, L, La, Lm = c.hidden_size, c.num_layers, c.num_attention_layers, c.num_mamba_layers
+    ks = iter(jax.random.split(key, 24))
+
+    def dense(shape, scale=None):
+        scale = shape[-2] ** -0.5 if scale is None else scale
+        return (jax.random.normal(next(ks), shape, dtype=jnp.float32) * scale).astype(dtype)
+
+    def norm(shape):
+        return (1.0 + 0.1 * jax.random.normal(next(ks), shape, dtype=jnp.float32)).astype(dtype)
+
+    layers: Dict[str, jax.Array] = {"mlp_norm": norm((L, D))}
+    F = c.intermediate_size
+    if c.num_experts:
+        E = c.experts_held
+        layers.update(
+            router=dense((L, D, c.num_experts)),
+            w_gate=dense((L, E, D, F)), w_up=dense((L, E, D, F)), w_down=dense((L, E, F, D)),
+        )
+    else:
+        layers.update(w_gate=dense((L, D, F)), w_up=dense((L, D, F)), w_down=dense((L, F, D)))
+    if c.shared_intermediate_size:
+        Fs = c.shared_intermediate_size
+        layers.update(shared_gate=dense((L, D, Fs)), shared_up=dense((L, D, Fs)), shared_down=dense((L, Fs, D)))
+    params: Params = {
+        "embed": dense((c.vocab_size, D), scale=0.02),
+        "final_norm": norm((D,)),
+        "layers": layers,
+        "attn": {
+            "attn_norm": norm((La, D)),
+            "wq": dense((La, D, c.q_size)), "wk": dense((La, D, c.kv_size)),
+            "wv": dense((La, D, c.kv_size)), "wo": dense((La, c.q_size, D)),
+        },
+    }
+    if Lm:
+        H, di, cd, K = c.mamba_n_heads, c.mamba_d_inner, c.mamba_conv_dim, c.mamba_d_conv
+        # Steps log-uniform in [1e-3, 1e-1] and A in [1, 16], as Mamba-2 is initialised: per-step decays
+        # exp(-dt * A) from 0.2 to 0.999.
+        dt = jnp.exp(jax.random.uniform(next(ks), (Lm, H), minval=jnp.log(1e-3), maxval=jnp.log(1e-1)))
+        params["mamba"] = {
+            "norm": norm((Lm, D)),
+            "in_proj": dense((Lm, D, 2 * di + 2 * c.mamba_n_groups * c.mamba_d_state + H)),
+            "conv_w": dense((Lm, K, cd), scale=K ** -0.5), "conv_b": dense((Lm, cd), scale=0.1),
+            "dt_bias": jnp.log(jnp.expm1(dt)).astype(jnp.float32),  # softplus^-1
+            "A_log": jnp.log(jax.random.uniform(next(ks), (Lm, H), minval=1.0, maxval=16.0)),
+            "D": jnp.ones((Lm, H), jnp.float32),
+            "gate_norm": norm((Lm, di)),
+            "out_proj": dense((Lm, di, D)),
+        }
+    if not c.tie_word_embeddings:
+        params["lm_head"] = dense((D, c.vocab_size), scale=0.02)
+    return params
+
+
+def open_slot(k_cache: SlotKv, v_cache: SlotKv, block: jax.Array, slot: jax.Array) -> Tuple[SlotKv, SlotKv]:
+    """A sequence whose table begins with ``block`` takes ``slot``: its state
+    and columns are zeroed in every layer and the step programs find it
+    through ``slot_of``. (Donate both sides: three in-place writes.)"""
+    return (
+        k_cache._replace(slots=k_cache.slots.at[:, slot].set(0.0), slot_of=k_cache.slot_of.at[block].set(slot)),
+        v_cache._replace(slots=v_cache.slots.at[:, slot].set(0)),
+    )
+
+
+# ---------------------------------------------------------------------------
+# Building blocks
+# ---------------------------------------------------------------------------
+
+
+def _at(tree, i):
+    """Layer ``i`` of stacked weights (``i`` traced: what a scan's ``xs`` does)."""
+    return jax.tree.map(lambda a: lax.dynamic_index_in_dim(a, i, axis=0, keepdims=False), tree)
+
+
+def _embed(c: ModelConfig, params: Params, tokens: jax.Array):
+    h = params["embed"].at[tokens].get(mode="clip")
+    return h * jnp.asarray(c.embedding_multiplier, h.dtype), h.dtype
+
+
+def _logits(c: ModelConfig, params: Params, h: jax.Array, wdtype) -> jax.Array:
+    x = _norm(c, h, params["final_norm"], wdtype)
+    head = params.get("lm_head")
+    head = head if head is not None else params["embed"].T
+    return (x @ head).astype(jnp.float32) / c.logits_scaling
+
+
+def _qkv(c: ModelConfig, lp, x: jax.Array, positions: jax.Array):
+    """Queries (with the stated scale folded in, so every attention path's own
+    ``head_dim ** -0.5`` makes it up), keys and values of ``x``'s rows."""
+    R = x.shape[0]
+    q = (x @ lp["wq"]).reshape(R, c.num_heads, c.head_dim)
+    k = (x @ lp["wk"]).reshape(R, c.num_kv_heads, c.head_dim)
+    v = (x @ lp["wv"]).reshape(R, c.num_kv_heads, c.head_dim)
+    if c.use_rope:
+        q, k = apply_rope(q, positions, c.rope_theta), apply_rope(k, positions, c.rope_theta)
+    if c.attention_scale:
+        q = (q.astype(jnp.float32) * (c.attention_scale * c.head_dim ** 0.5)).astype(q.dtype)
+    return q, k, v
+
+
+def _rows_attention(c: ModelConfig, k_pool, v_pool, tables, prefix_lens, window: int = 0, step=None):
+    """Attention of ``B`` length-1 rows over their paged prefixes, as
+    ``llama.decode_layer_scan`` (``window`` 0) and its window variant have it:
+    returns ``attend(q, k, v, la, kwl=None, vwl=None) -> [B, q_size]`` for
+    attention layer ``la``; ``kwl``/``vwl`` ``[w, B, KVH, HD]`` are the rows the
+    window wrote before step ``step``."""
+    B, N, bs = tables.shape[0], k_pool.shape[1], c.block_size
+    kvh, G, hd = c.num_kv_heads, c.num_heads // c.num_kv_heads, c.head_dim
+    ctx, w = tables.shape[1] * bs, window
+    k_flat, v_flat = layer_flat(k_pool), layer_flat(v_pool)
+    use_mega = _use_megakernel(c, k_pool)
+    prefix_lens = jnp.minimum(prefix_lens, ctx).astype(jnp.int32)
+    if use_mega:
+        from dynamo_tpu.engine.attention.megakernel import build_meta
+
+        rows_i = jnp.arange(B, dtype=jnp.int32)
+        first = rows_i * (w + 1)
+        meta = build_meta(rows_i, prefix_lens, first, first + 1 + (0 if step is None else step), jnp.ones((B,), jnp.int32))
+    else:
+        mask = jnp.arange(ctx, dtype=jnp.int32)[None, :] < prefix_lens[:, None]
+        small_mask = jnp.ones((B, 1), dtype=bool)
+        if w:
+            small_mask = jnp.concatenate(
+                [jnp.broadcast_to((jnp.arange(w, dtype=jnp.int32) < step)[None, :], (B, w)), small_mask], axis=1
+            )
+
+    def attend(q, k, v, la, kwl=None, vwl=None):
+        tables_l = tables + la * N
+        if use_mega:
+            if w:
+                k = jnp.concatenate([k[:, None], jnp.swapaxes(kwl, 0, 1)], axis=1).reshape(B * (w + 1), kvh, hd)
+                v = jnp.concatenate([v[:, None], jnp.swapaxes(vwl, 0, 1)], axis=1).reshape(B * (w + 1), kvh, hd)
+            return _mega_attend_rows(c, q, k, v, k_flat, v_flat, tables_l, meta).astype(q.dtype).reshape(B, c.q_size)
+        qg = q.reshape(B, kvh, G, hd)
+        k_ctx = _gather_kv(k_flat, tables_l, q.dtype).reshape(B, ctx, kvh, hd)
+        v_ctx = _gather_kv(v_flat, tables_l, q.dtype).reshape(B, ctx, kvh, hd)
+        m1, l1, acc1 = _attend_piece(qg, k_ctx, v_ctx, mask, hd ** -0.5)
+        k_small, v_small = k[:, None], v[:, None]
+        if w:
+            k_small = jnp.concatenate([jnp.swapaxes(kwl, 0, 1), k_small], axis=1)
+            v_small = jnp.concatenate([jnp.swapaxes(vwl, 0, 1), v_small], axis=1)
+        m2, l2, acc2 = _attend_piece(qg, k_small, v_small, small_mask, hd ** -0.5)
+        return _merge_pieces(m1, l1, acc1, m2, l2, acc2).astype(q.dtype).reshape(B, c.q_size)
+
+    return attend
+
+
+def _chunk_attention(c: ModelConfig, k_pool, v_pool, table, prefix_rows, valid_len, T: int, use_flash, has_prefix):
+    """Attention of one wide row (a prefill chunk of ``T`` queries) over
+    ``[its paged prefix ; itself]``, as ``llama.prefill`` has it: returns
+    ``attend(q, k, v, la) -> [T, q_size]``."""
+    N, bs, kvh, hd = k_pool.shape[1], c.block_size, c.num_kv_heads, c.head_dim
+    ctx = table.shape[0] * bs
+    k_flat, v_flat = layer_flat(k_pool), layer_flat(v_pool)
+    use_mega = _use_megakernel(c, k_pool)
+    if use_mega:
+        from dynamo_tpu.engine.attention.megakernel import build_meta
+
+        t_iq = jnp.arange(T, dtype=jnp.int32)
+        meta = build_meta(
+            jnp.zeros((T,), jnp.int32), jnp.full((T,), prefix_rows, jnp.int32),
+            jnp.zeros((T,), jnp.int32), t_iq + 1, (t_iq < valid_len).astype(jnp.int32),
+        )
+
+    def attend(q, k, v, la):
+        table_l = table + la * N
+        if use_mega:
+            out = _mega_attend_rows(c, q, k, v, k_flat, v_flat, table_l[None, :], meta, chunk=True)
+            return out.astype(q.dtype).reshape(T, c.q_size)
+        from dynamo_tpu.engine.attention.ragged import ragged_chunk_attention
+
+        k_ctx = v_ctx = None
+        if not (use_flash and not has_prefix):
+            k_ctx = _gather_kv(k_flat, table_l, q.dtype).reshape(ctx, kvh, hd)
+            v_ctx = _gather_kv(v_flat, table_l, q.dtype).reshape(ctx, kvh, hd)
+        out = ragged_chunk_attention(
+            q, k, v, k_ctx, v_ctx, valid_len, prefix_rows,
+            num_kv_heads=kvh, use_flash=use_flash, has_prefix=has_prefix, interpret=not llama._on_tpu(),
+        )
+        return out.reshape(T, c.q_size)
+
+    return attend
+
+
+# --- the Mamba-2 mixer -------------------------------------------------------
+
+
+def _mamba_split(c: ModelConfig, zxbcdt: jax.Array):
+    """``in_proj``'s columns: gate ``z``, the convolution's lanes ``xBC``, ``dt``."""
+    di, cd = c.mamba_d_inner, c.mamba_conv_dim
+    return zxbcdt[..., :di], zxbcdt[..., di:di + cd], zxbcdt[..., di + cd:]
+
+
+def _ssm_inputs(c: ModelConfig, lp, xbc: jax.Array, dt: jax.Array):
+    """The recurrence's float32 inputs of ``R`` rows from the convolved lanes:
+    ``x [R, H, P]``, ``B``/``C`` per head ``[R, H, N]`` (a group's heads share
+    them), steps ``dt [R, H]`` and ``A [H]`` (negative)."""
+    R, H, P, G, N = xbc.shape[0], c.mamba_n_heads, c.mamba_d_head, c.mamba_n_groups, c.mamba_d_state
+    di = c.mamba_d_inner
+    x = xbc[:, :di].reshape(R, H, P)
+    Bh = jnp.repeat(xbc[:, di:di + G * N].reshape(R, G, N), H // G, axis=1)
+    Ch = jnp.repeat(xbc[:, di + G * N:].reshape(R, G, N), H // G, axis=1)
+    dt = jax.nn.softplus(dt.astype(jnp.float32) + lp["dt_bias"].astype(jnp.float32))
+    return x, Bh, Ch, dt, -jnp.exp(lp["A_log"].astype(jnp.float32))
+
+
+def _ssm_update(state, x, Bh, Ch, dt, A, D):
+    """One step of the recurrence for ``B`` rows (float32, elementwise and a
+    lane reduction: one pass over the state): ``h <- exp(dt A) h + dt x (x) B``,
+    ``y = h C + D x``. Returns ``(y [B, H, P], state [B, H, P, N])``."""
+    with jax.named_scope("ssm_update"):
+        state = state * jnp.exp(dt * A)[..., None, None] + (dt[..., None] * x)[..., None] * Bh[:, :, None, :]
+        y = jnp.sum(state * Ch[:, :, None, :], axis=-1) + D[None, :, None] * x
+    return y, state
+
+
+def _from_slot(c: ModelConfig, stored: jax.Array) -> jax.Array:
+    """A slot's state as the recurrence writes it, ``[..., H, P, N]``, from
+    how it is stored (``ModelConfig.mamba_state_shape``)."""
+    Hg, N, gP = c.mamba_state_shape
+    lead, g = stored.shape[:-3], c.mamba_n_heads // Hg
+    h = stored.reshape(*lead, Hg, N, g, gP // g)
+    return jnp.moveaxis(h, -3, -1).reshape(*lead, c.mamba_n_heads, gP // g, N)
+
+
+def _to_slot(c: ModelConfig, state: jax.Array) -> jax.Array:
+    """The inverse of ``_from_slot``."""
+    Hg, N, gP = c.mamba_state_shape
+    lead, g = state.shape[:-3], c.mamba_n_heads // Hg
+    h = state.reshape(*lead, Hg, g, gP // g, N)
+    return jnp.moveaxis(h, -1, -3).reshape(*lead, Hg, N, gP)
+
+
+def _rows_kernel_fits(c: ModelConfig) -> bool:
+    """``ssm_update_rows`` wants one group, whole lanes (heads side by side
+    fill 128) and ``d_state`` in whole sublanes."""
+    Hg, N, gP = c.mamba_state_shape
+    return c.mamba_n_groups == 1 and gP == 128 and N % 8 == 0 and (Hg % 8 == 0 or Hg < 8)
+
+
+def _use_rows_kernel(c: ModelConfig) -> bool:
+    """On a TPU (llama's ``_on_tpu``: the one place that decides) where the
+    kernel fits; elsewhere a gather, ``_ssm_update`` and a scatter."""
+    return llama._on_tpu() and _rows_kernel_fits(c)
+
+
+def _ssm_rows_kernel(rows_ref, ssm_ref, dx_ref, dec_ref, b_ref, c_ref, ssm_out_ref, y_ref, *, tiles: int):
+    """One (row, block of lane rows) cell of ``ssm_update_rows``: each of the
+    block's ``[N, 128]`` tiles (``g`` heads side by side) read, advanced and
+    written back. ``dx`` and the decay are lane rows that broadcast down the
+    sublanes, ``B`` and ``C`` come broadcast along the lanes, and ``y`` is a
+    sum down the sublanes: a lane row again. Nothing is transposed."""
+    del rows_ref  # read by the block specs' index maps
+    Bb, Cb = b_ref[0], c_ref[0]  # [N, 128]
+    for i in range(tiles):
+        s = ssm_ref[0, i] * dec_ref[0, i:i + 1, :] + dx_ref[0, i:i + 1, :] * Bb
+        ssm_out_ref[0, i] = s
+        y_ref[0, i:i + 1, :] = jnp.sum(s * Cb, axis=0, keepdims=True)
+
+
+def ssm_update_rows(c: ModelConfig, ssm, rows, x, Bm, Cm, dt, A, D, *, interpret: bool = False, tiles: int = 16):
+    """``_ssm_update`` for ``B`` rows IN PLACE on the slot array (a Pallas
+    kernel): ``ssm [R, *mamba_state_shape]`` float32 is aliased to the first
+    result, and row ``b`` reads and writes slot ``rows[b]`` (scalar prefetch:
+    the block specs fetch the slot, nothing is gathered or scattered), once.
+    One group: ``Bm``/``Cm`` are ``[B, N]``. Rows that name the same slot
+    (padded rows, the scratch slot) overwrite each other; consecutive ones
+    fetch it once. Returns ``(ssm, y [B, H, P])``."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    B, H, P = x.shape
+    Hg, N, gP = c.mamba_state_shape
+    hb = min(tiles, Hg)
+    lanes = lambda a: jnp.broadcast_to(a[:, :, None], (B, H, P)).reshape(B, Hg, gP)  # noqa: E731 - a head's value on its lanes
+    dx, dec = (dt[:, :, None] * x).reshape(B, Hg, gP), lanes(jnp.exp(dt * A))
+    down = lambda a: jnp.broadcast_to(a[:, :, None], (B, N, gP))  # noqa: E731 - B and C along the lanes
+    slot = pl.BlockSpec((1, hb, N, gP), lambda b, j, rows: (rows[b], j, 0, 0))
+    row = pl.BlockSpec((1, hb, gP), lambda b, j, rows: (b, j, 0))
+    whole = pl.BlockSpec((1, N, gP), lambda b, j, rows: (b, 0, 0))
+    with jax.named_scope("ssm_update"):
+        ssm, y = pl.pallas_call(
+            functools.partial(_ssm_rows_kernel, tiles=hb),
+            out_shape=(jax.ShapeDtypeStruct(ssm.shape, ssm.dtype), jax.ShapeDtypeStruct((B, Hg, gP), jnp.float32)),
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=1, grid=(B, Hg // hb),
+                in_specs=[slot, row, row, whole, whole], out_specs=(slot, row),
+            ),
+            input_output_aliases={1: 0},  # the slot array (operand 1, after the prefetched rows) is result 0
+            interpret=interpret,
+            name="ssm_update_rows",
+        )(rows.astype(jnp.int32), ssm, dx, dec, down(Bm), down(Cm))
+        return ssm, y.reshape(B, H, P) + D[None, :, None] * x
+
+
+def _ssd_chunk(state, x, Bh, Ch, dt, A, D, block: int):
+    """The chunked (SSD) form of the same recurrence over ``T`` positions of
+    one sequence, ``block`` positions at a time, from and to the slot's state
+    AS STORED (``[H/g, N, g*P]``, ``ModelConfig.mamba_state_shape``: the two
+    products that touch the state take it as it lies, so nothing of the slot
+    array is transposed): inside a block every position reads the state the
+    block began with, decayed, and the earlier positions of the block through
+    the masked matrix of their decays; the state then moves to the block's
+    end. A position with ``dt`` 0 neither decays nor feeds the state. Returns
+    ``(y [T, H, P], state)``."""
+    T, H, P = x.shape
+    Hg, N, gP = state.shape
+    g, Q = H // Hg, min(block, T)
+    if T % Q:
+        raise ValueError(f"a chunk of {T} positions is not whole blocks of {Q}")
+    causal = jnp.tril(jnp.ones((Q, Q), dtype=bool))
+    pairs = lambda a: a.reshape(a.shape[0], Hg, g, *a.shape[2:])  # noqa: E731 - [.., H, ..] as lane rows of g heads
+
+    def one(state, xs):
+        x, Bh, Ch, dt = xs  # [Q, H, ...]
+        cs = jnp.cumsum(dt * A, axis=0)  # [Q, H] log-decay from the block's start through t
+        decay = jnp.exp(jnp.where(causal[:, :, None], cs[:, None, :] - cs[None, :, :], -jnp.inf))  # [t, s, H]
+        scores = jnp.einsum("thn,shn->tsh", Ch, Bh, precision=_HI) * decay * dt[None, :, :]
+        y = jnp.einsum("tsh,shp->thp", scores, x, precision=_HI)
+        from_state = jnp.einsum("tagn,angp->tagp", pairs(Ch), state, precision=_HI).reshape(Q, H, P)
+        y = y + from_state * jnp.exp(cs)[:, :, None]
+        to_end = jnp.exp(cs[-1][None, :] - cs) * dt  # [s, H]
+        fed = jnp.einsum("sagp,sagn->angp", pairs(to_end[:, :, None] * x), pairs(Bh), precision=_HI)
+        state = state * jnp.exp(cs[-1]).reshape(Hg, 1, g, 1) + fed
+        return state, y + D[None, :, None] * x
+
+    with jax.named_scope("ssd_chunk"):
+        blocks = jax.tree.map(lambda a: a.reshape(T // Q, Q, *a.shape[1:]), (x, Bh, Ch, dt))
+        state, y = lax.scan(one, state.reshape(Hg, N, g, P), blocks)
+    return y.reshape(T, H, P), state.reshape(Hg, N, gP)
+
+
+def _conv_chunk(lp, cols, xbc, valid_len):
+    """Causal depthwise convolution (with bias) and silu over a chunk's rows
+    ``xbc [T, C]`` after the slot's columns ``cols [K-1, C]``. Returns
+    (float32 ``[T, C]``, the new columns: the last ``K-1`` *valid* inputs)."""
+    with jax.named_scope("ssm_conv"):
+        K, T = lp["conv_w"].shape[0], xbc.shape[0]
+        seq = jnp.concatenate([cols.astype(xbc.dtype), xbc], axis=0)  # [K-1+T, C]; input t stands at row K-1+t
+        w = lp["conv_w"].astype(jnp.float32)
+        out = sum(seq[k:k + T].astype(jnp.float32) * w[k] for k in range(K)) + lp["conv_b"].astype(jnp.float32)
+        return jax.nn.silu(out), lax.dynamic_slice_in_dim(seq, valid_len, K - 1, axis=0)
+
+
+def _conv_rows(lp, cols, xbc):
+    """The same convolution for ``B`` length-1 rows: ``cols [B, K-1, C]``."""
+    with jax.named_scope("ssm_conv"):
+        window = jnp.concatenate([cols.astype(xbc.dtype), xbc[:, None]], axis=1)  # [B, K, C]
+        out = jnp.sum(window.astype(jnp.float32) * lp["conv_w"].astype(jnp.float32)[None], axis=1)
+        return jax.nn.silu(out + lp["conv_b"].astype(jnp.float32)), window[:, 1:]
+
+
+def _mamba_out(c: ModelConfig, lp, y: jax.Array, z: jax.Array, wdtype) -> jax.Array:
+    """Gate, then norm over all inner lanes, then ``out_proj``."""
+    y = y.reshape(y.shape[0], c.mamba_d_inner) * jax.nn.silu(z.astype(jnp.float32))
+    y = y * lax.rsqrt(jnp.mean(y * y, axis=-1, keepdims=True) + c.rms_norm_eps) * lp["gate_norm"].astype(jnp.float32)
+    return y.astype(wdtype) @ lp["out_proj"]
+
+
+def _mamba_mixer(c: ModelConfig, lp, lm, x, ssm, conv, slots, chunk, wdtype):
+    """The Mamba-2 mixer of Mamba layer ``lm`` over ``x``'s rows: first the
+    wide row ``chunk = (T, slot, valid_len)`` if any (the chunked form, on
+    that slot), then length-1 rows on ``slots [B]`` (the single step). One
+    ``in_proj`` and one ``out_proj`` over all rows. ``ssm``/``conv`` are the
+    slot arrays viewed ``[L_m*S, ...]`` (the state as stored:
+    ``ModelConfig.mamba_state_shape``); returns ``(out [R, D], ssm, conv)``."""
+    S = ssm.shape[0] // c.num_mamba_layers
+    z, xbc, dt = _mamba_split(c, x @ lp["in_proj"])
+    D = lp["D"].astype(jnp.float32)
+    ys = []
+    T = 0
+    if chunk is not None:
+        T, slot, valid_len = chunk
+        row = lm * S + slot
+        act, cols = _conv_chunk(lp, lax.dynamic_index_in_dim(conv, row, keepdims=False), xbc[:T], valid_len)
+        xs, Bh, Ch, dts, A = _ssm_inputs(c, lp, act, dt[:T])
+        dts = jnp.where((jnp.arange(T, dtype=jnp.int32) < valid_len)[:, None], dts, 0.0)
+        # (The state goes in and out as stored: a transposed view of the slice made XLA:TPU re-lay the WHOLE slot array,
+        # 2.45 GB a layer at the benchmark's sizes; compiled for a described v5e, PR 32.)
+        y, state = _ssd_chunk(lax.dynamic_index_in_dim(ssm, row, keepdims=False), xs, Bh, Ch, dts, A, D, c.mamba_chunk_size)
+        ssm = lax.dynamic_update_index_in_dim(ssm, state, row, axis=0)
+        conv = lax.dynamic_update_index_in_dim(conv, cols.astype(conv.dtype), row, axis=0)
+        ys.append(y)
+        if slots is None and _use_rows_kernel(c):
+            # A chunk with no decode row beside it: one idle step on the layer's scratch slot all the same. The kernel's
+            # operand pins the slot array's layout; left to itself XLA:TPU re-lays the whole array around the chunk's
+            # products (2.5 GB of temporaries and two copies a dispatch; compiled for a described v5e, PR 32).
+            H, P, N = c.mamba_n_heads, c.mamba_d_head, c.mamba_d_state
+            idle = jnp.zeros((1, H), jnp.float32)
+            ssm, _ = ssm_update_rows(c, ssm, jnp.reshape(lm * S, (1,)), jnp.zeros((1, H, P), jnp.float32),
+                                     jnp.zeros((1, N), jnp.float32), jnp.zeros((1, N), jnp.float32), idle, A, D,
+                                     interpret=not llama._on_tpu())
+    if slots is not None:
+        rows = lm * S + slots
+        act, cols = _conv_rows(lp, conv[rows], xbc[T:])
+        xs, Bh, Ch, dts, A = _ssm_inputs(c, lp, act, dt[T:])
+        if _use_rows_kernel(c):
+            ssm, y = ssm_update_rows(c, ssm, rows, xs, Bh[:, 0], Ch[:, 0], dts, A, D, interpret=not llama._on_tpu())
+        else:
+            y, state = _ssm_update(_from_slot(c, ssm[rows]), xs, Bh, Ch, dts, A, D)
+            ssm = ssm.at[rows].set(_to_slot(c, state))
+        conv = conv.at[rows].set(cols.astype(conv.dtype))
+        ys.append(y)
+    return _mamba_out(c, lp, jnp.concatenate(ys) if len(ys) > 1 else ys[0], z, wdtype), ssm, conv
+
+
+# --- the stack ---------------------------------------------------------------
+
+
+def _ffn(c: ModelConfig, scanned, experts, h, l, valid, wdtype):
+    """The FFN every layer has, as a residual branch: the held experts' share
+    and the shared expert. Returns ``(h, held, visited)``."""
+    lp = _at(scanned, l)
+    x = _norm(c, h, lp["mlp_norm"], wdtype)
+    held = visited = jnp.int32(0)
+    if c.num_experts:
+        out, held, visited = _moe_held(x, lp, c, valid, experts, l)
+    else:
+        out = (jax.nn.silu(x @ lp["w_gate"]) * (x @ lp["w_up"])) @ lp["w_down"]
+    if c.shared_intermediate_size:
+        with jax.named_scope("moe_shared"):
+            out = out + (jax.nn.silu(x @ lp["shared_gate"]) * (x @ lp["shared_up"])) @ lp["shared_down"]
+    return h + out * jnp.asarray(c.residual_multiplier, out.dtype), held, visited
+
+
+def _drive(c: ModelConfig, params: Params, h, ssm, conv, attend, positions, slots, chunk, valid, wdtype, window=None):
+    """``h`` through the stack, group by group (``ModelConfig.layer_groups``),
+    each group one scan over its layers' indices. ``attend(q, k, v, la, ...)``
+    is the step's attention over the pool; ``slots``/``chunk`` say which rows
+    are length-1 rows and which a wide row (``_mamba_mixer``); ``window``
+    ``(k_win, v_win) [L_a, w, B, KVH, HD]`` are a multi-step window's rows so
+    far. Returns ``(h, ssm, conv, k_rows, v_rows, stats)``: the attention
+    layers' fresh rows ``[L_a, R, KVH, HD]`` for the caller's one scatter,
+    and the expert counts."""
+    scanned, experts = _split_expert_stacks(c, params["layers"])
+    rm = c.residual_multiplier
+    l0 = la0 = lm0 = 0
+    k_rows, v_rows = [], []
+    stats = (jnp.int32(0), jnp.int32(0))
+    flat = jax.tree.map(lambda a: a.reshape(a.shape[0] * a.shape[1], *a.shape[2:]), (ssm, conv))
+
+    def mamba_layer(carry, idx):
+        h, (ssm, conv), (held, visited) = carry
+        l, lm = idx
+        lp = _at(params["mamba"], lm)
+        out, ssm, conv = _mamba_mixer(c, lp, lm, _norm(c, h, lp["norm"], wdtype), ssm, conv, slots, chunk, wdtype)
+        h, n_held, n_visited = _ffn(c, scanned, experts, h + out * jnp.asarray(rm, out.dtype), l, valid, wdtype)
+        return (h, (ssm, conv), (held + n_held, visited + n_visited)), None
+
+    def attention_layer(carry, idx):
+        h, flat, (held, visited) = carry
+        l, la = idx
+        lp = _at(params["attn"], la)
+        q, k, v = _qkv(c, lp, _norm(c, h, lp["attn_norm"], wdtype), positions)
+        win = () if window is None else tuple(lax.dynamic_index_in_dim(a, la, keepdims=False) for a in window)
+        out = attend(q, k, v, la, *win) @ lp["wo"]
+        h, n_held, n_visited = _ffn(c, scanned, experts, h + out * jnp.asarray(rm, out.dtype), l, valid, wdtype)
+        return (h, flat, (held + n_held, visited + n_visited)), (k, v)
+
+    for kind, count in c.layer_groups:
+        layer_ids = jnp.arange(l0, l0 + count, dtype=jnp.int32)
+        if kind == "mamba":
+            (h, flat, stats), _ = lax.scan(mamba_layer, (h, flat, stats), (layer_ids, layer_ids - l0 + lm0))
+            lm0 += count
+        else:
+            (h, flat, stats), (k, v) = lax.scan(attention_layer, (h, flat, stats), (layer_ids, layer_ids - l0 + la0))
+            k_rows.append(k)
+            v_rows.append(v)
+            la0 += count
+        l0 += count
+    ssm, conv = (a.reshape(b.shape) for a, b in zip(flat, (ssm, conv)))
+    aux = {"held_assignments": stats[0], "experts_visited": stats[1]}
+    return h, ssm, conv, jnp.concatenate(k_rows), jnp.concatenate(v_rows), aux
+
+
+def _slots_of(k_cache: SlotKv, tables: jax.Array) -> jax.Array:
+    """The slot of each row: that of the sequence whose table begins with the
+    row's first block (``open_slot``); a table of zeros gives scratch slot 0."""
+    return k_cache.slot_of[tables[..., 0]]
+
+
+def _write(k_cache: SlotKv, v_cache: SlotKv, ssm, conv, k_rows, v_rows, blocks, offs):
+    """The step's one scatter of the attention layers' rows ``[L_a, R, ...]``
+    into the pool, and the slot arrays as the stack left them."""
+    La, R = k_rows.shape[0], k_rows.shape[1]
+    layer_idx = jnp.broadcast_to(jnp.arange(La, dtype=jnp.int32)[:, None], (La, R))
+    return (
+        k_cache._replace(pool=_scatter_kv(k_cache.pool, layer_idx, blocks[None, :], offs[None, :], k_rows), slots=ssm),
+        v_cache._replace(pool=_scatter_kv(v_cache.pool, layer_idx, blocks[None, :], offs[None, :], v_rows), slots=conv),
+    )
+
+
+# ---------------------------------------------------------------------------
+# The step programs
+# ---------------------------------------------------------------------------
+
+
+def prefill(
+    params: Params,
+    config: ModelConfig,
+    k_cache: SlotKv,
+    v_cache: SlotKv,
+    tokens: jax.Array,  # [T] bucket-padded token ids
+    valid_len: jax.Array,  # scalar: actual new tokens
+    cache_len: jax.Array,  # scalar: tokens of the sequence already computed (earlier chunks)
+    block_table: jax.Array,  # [W] block ids (0 = scratch)
+    all_logits: bool = False,  # static: logits of every position [T, V]
+    use_flash: bool = False,
+    has_prefix: bool = True,
+):
+    """One prefill chunk of one sequence, as ``llama.prefill``: returns
+    ``(last_logits [V] | [T, V], k_cache, v_cache, aux)``. The sequence's slot
+    carries its state from chunk to chunk."""
+    c = config
+    T = tokens.shape[0]
+    h, wdtype = _embed(c, params, tokens)
+    positions = cache_len + jnp.arange(T, dtype=jnp.int32)
+    valid_q = jnp.arange(T, dtype=jnp.int32) < valid_len
+    blocks, offs = ragged_scatter_targets(block_table, positions, valid_q, c.block_size)
+    attend = _chunk_attention(c, k_cache.pool, v_cache.pool, block_table, cache_len, valid_len, T, use_flash, has_prefix)
+    chunk = (T, _slots_of(k_cache, block_table), valid_len)
+    h, ssm, conv, k_rows, v_rows, aux = _drive(
+        c, params, h, k_cache.slots, v_cache.slots, attend, positions, None, chunk, valid_q, wdtype
+    )
+    k_new, v_new = _write(k_cache, v_cache, ssm, conv, k_rows, v_rows, blocks, offs)
+    logits = _logits(c, params, h if all_logits else h[jnp.maximum(valid_len - 1, 0)], wdtype)
+    return logits, k_new, v_new, aux
+
+
+def decode(
+    params: Params,
+    config: ModelConfig,
+    k_cache: SlotKv,
+    v_cache: SlotKv,
+    tokens: jax.Array,  # [B]
+    positions: jax.Array,  # [B] position of each token (its write row)
+    block_tables: jax.Array,  # [B, W]
+    active: jax.Array,  # [B] bool — padded rows are False (and their tables zeros)
+):
+    """One decode step for a batch: ``(logits [B, V], k_cache, v_cache, aux)``."""
+    c = config
+    h, wdtype = _embed(c, params, tokens)
+    blocks, offs, _ = decode_targets(positions, block_tables, active, c.block_size)
+    attend = _rows_attention(c, k_cache.pool, v_cache.pool, block_tables, positions)
+    slots = jnp.where(active, _slots_of(k_cache, block_tables), 0)
+    h, ssm, conv, k_rows, v_rows, aux = _drive(
+        c, params, h, k_cache.slots, v_cache.slots, attend, positions, slots, None, active, wdtype
+    )
+    k_new, v_new = _write(k_cache, v_cache, ssm, conv, k_rows, v_rows, blocks, offs)
+    return _logits(c, params, h, wdtype), k_new, v_new, aux
+
+
+def mixed_step(
+    params: Params,
+    config: ModelConfig,
+    k_cache: SlotKv,
+    v_cache: SlotKv,
+    p_tokens: jax.Array,  # [S] prefill-chunk token ids (bucket-padded)
+    p_valid: jax.Array,  # scalar i32
+    p_cache_len: jax.Array,  # scalar i32
+    p_table: jax.Array,  # [Wp]
+    d_tokens: jax.Array,  # [B]
+    d_positions: jax.Array,  # [B]
+    d_tables: jax.Array,  # [B, Wd]
+    d_active: jax.Array,  # [B] bool
+    use_flash: bool = False,
+    has_prefix: bool = True,
+):
+    """One mixed step, as ``llama.mixed_step``: a prefill chunk and the decode
+    batch in one dispatch; ``(logits [1+B, V], k_cache, v_cache, aux)``. Every
+    Mamba layer runs the chunked form on the chunk's slot and the single step
+    on the decode rows' slots, between one ``in_proj`` and one ``out_proj``."""
+    c = config
+    S, B = p_tokens.shape[0], d_tokens.shape[0]
+    p_positions = p_cache_len + jnp.arange(S, dtype=jnp.int32)
+    p_valid_q = jnp.arange(S, dtype=jnp.int32) < p_valid
+    h, wdtype = _embed(c, params, jnp.concatenate([p_tokens, d_tokens]))
+    p_attend = _chunk_attention(c, k_cache.pool, v_cache.pool, p_table, p_cache_len, p_valid, S, use_flash, has_prefix)
+    d_attend = _rows_attention(c, k_cache.pool, v_cache.pool, d_tables, d_positions)
+
+    def attend(q, k, v, la):
+        return jnp.concatenate([p_attend(q[:S], k[:S], v[:S], la), d_attend(q[S:], k[S:], v[S:], la)])
+
+    chunk = (S, _slots_of(k_cache, p_table), p_valid)
+    slots = jnp.where(d_active, _slots_of(k_cache, d_tables), 0)
+    h, ssm, conv, k_rows, v_rows, aux = _drive(
+        c, params, h, k_cache.slots, v_cache.slots, attend, jnp.concatenate([p_positions, d_positions]),
+        slots, chunk, jnp.concatenate([p_valid_q, d_active]), wdtype,
+    )
+    p_blocks, p_offs = ragged_scatter_targets(p_table, p_positions, p_valid_q, c.block_size)
+    d_blocks, d_offs, _ = decode_targets(d_positions, d_tables, d_active, c.block_size)
+    k_new, v_new = _write(
+        k_cache, v_cache, ssm, conv, k_rows, v_rows,
+        jnp.concatenate([p_blocks, d_blocks]), jnp.concatenate([p_offs, d_offs]),
+    )
+    last_p = jnp.maximum(p_valid - 1, 0)
+    logits = _logits(c, params, jnp.concatenate([h[last_p][None], h[S:]], axis=0), wdtype)
+    return logits, k_new, v_new, aux
+
+
+def decode_multi(
+    params: Params,
+    config: ModelConfig,
+    k_cache: SlotKv,
+    v_cache: SlotKv,
+    tokens: jax.Array,  # [B]
+    positions: jax.Array,  # [B]
+    block_tables: jax.Array,  # [B, W] — must cover positions + num_steps
+    active: jax.Array,  # [B] bool
+    temps: jax.Array,
+    top_ks: jax.Array,
+    top_ps: jax.Array,
+    rng_key: jax.Array,
+    num_steps: int,
+    return_logits: bool = False,  # static: also the per-step logits [steps, B, V]
+):
+    """``num_steps`` decode steps and on-device sampling in one dispatch, as
+    ``llama.decode_multi``: ``(tokens_out [num_steps, B], [logits,] k_cache,
+    v_cache, aux)``. The pool is read-only for the window (its rows ride a
+    small carry and one scatter writes them at the end); the slot arrays are
+    carried through the loop and advanced in place at every step."""
+    from dynamo_tpu.engine.sampling import sample_batch
+
+    c = config
+    B, La, KVH, HD, bs = tokens.shape[0], c.num_attention_layers, c.num_kv_heads, c.head_dim, c.block_size
+    wdtype = params["embed"].dtype
+    slots = jnp.where(active, _slots_of(k_cache, block_tables), 0)
+
+    def body(i, carry):
+        toks, ssm, conv, k_win, v_win, out, lg_out, key, held, visited = carry
+        h, _ = _embed(c, params, toks)
+        attend = _rows_attention(c, k_cache.pool, v_cache.pool, block_tables, positions, window=num_steps, step=i)
+        h, ssm, conv, k_rows, v_rows, aux = _drive(
+            c, params, h, ssm, conv, attend, positions + i, slots, None, active, wdtype, window=(k_win, v_win)
+        )
+        k_win, v_win = k_win.at[:, i].set(k_rows), v_win.at[:, i].set(v_rows)
+        logits = _logits(c, params, h, wdtype)
+        key, sub = jax.random.split(key)
+        nxt = sample_batch(logits, temps, top_ks, top_ps, sub).astype(jnp.int32)
+        if return_logits:
+            lg_out = lg_out.at[i].set(logits)
+        return (nxt, ssm, conv, k_win, v_win, out.at[i].set(nxt), lg_out, key,
+                held + aux["held_assignments"], visited + aux["experts_visited"])
+
+    win0 = jnp.zeros((La, num_steps, B, KVH, HD), dtype=wdtype)
+    V = params["embed"].shape[0]
+    lg0 = jnp.zeros((num_steps if return_logits else 1, B, V if return_logits else 1), jnp.float32)
+    _, ssm, conv, k_win, v_win, out, lg_steps, _, held, visited = lax.fori_loop(
+        0, num_steps, body,
+        (tokens, k_cache.slots, v_cache.slots, win0, win0, jnp.zeros((num_steps, B), jnp.int32), lg0, rng_key,
+         jnp.int32(0), jnp.int32(0)),
+    )
+    # One scatter for the whole window: row (la, j, b) -> position_b + j.
+    steps_i = jnp.arange(num_steps, dtype=jnp.int32)
+    live = jnp.broadcast_to(active[None, :], (num_steps, B))
+    rows = jnp.where(live, positions[None, :] + steps_i[:, None], 0)
+    blocks = jnp.where(live, block_tables[jnp.arange(B)[None, :], rows // bs], 0)
+    k_new, v_new = _write(
+        k_cache, v_cache, ssm, conv, k_win.reshape(La, num_steps * B, KVH, HD), v_win.reshape(La, num_steps * B, KVH, HD),
+        blocks.reshape(-1), (rows % bs).reshape(-1),
+    )
+    aux = {"held_assignments": held, "experts_visited": visited}
+    if return_logits:
+        return out, lg_steps, k_new, v_new, aux
+    return out, k_new, v_new, aux

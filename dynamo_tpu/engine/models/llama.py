@@ -129,6 +129,10 @@ def init_params(config: ModelConfig, key: jax.Array, dtype=jnp.bfloat16) -> Para
     """Random-init weights (testing / benchmarking). HF checkpoint loading
     lives in ``dynamo_tpu.engine.weights``."""
     c = config
+    if c.is_hybrid:
+        from dynamo_tpu.engine.models import hybrid
+
+        return hybrid.init_params(c, key, dtype)
     k_embed, k_layers, k_head = jax.random.split(key, 3)
 
     def dense(key, shape, scale=None):
@@ -336,6 +340,78 @@ def _moe_ragged(
     return jnp.zeros_like(x).at[tok].add(y * w_sorted[:, None])
 
 
+def _held_dot(lhs: jax.Array, rhs: jax.Array, group_sizes: jax.Array) -> jax.Array:
+    """The grouped product of ``_moe_held``: ``lhs [M, K]`` rows sorted by
+    group against ``rhs [G, K, N]``. On a TPU the megablox ``gmm`` kernel at
+    row tiles of 128 and whole-K, whole-N tiles: at narrow experts (768 wide)
+    it reads 1.03 ms a layer at 32 rows where ``lax.ragged_dot`` reads 1.46,
+    and 1.72 against 2.80 at a mixed step's rows (tools/ssm_step_bench.py;
+    PERF.md §6, PR 32). ``_moe_ragged`` (every expert held, Mixtral's
+    14336-wide experts) still gives ``lax.ragged_dot`` its groups although
+    ``gmm`` with wide tiles read 3.78 ms a layer against 4.40 there too
+    (tools/moe_gemm_bench.py, PR 29): its cell could not show the gain end to
+    end, so nobody shipped it; one path for both is ROADMAP S18. Rows past the
+    last group belong to no tile and come back as they lie. Elsewhere
+    ``lax.ragged_dot``."""
+    if not _on_tpu():
+        return lax.ragged_dot(lhs, rhs, group_sizes)
+    from jax.experimental.pallas.ops.tpu.megablox import gmm
+
+    m, k, n = lhs.shape[0], rhs.shape[1], rhs.shape[2]
+    tm = min(128, -(-m // 8) * 8)
+    if m % tm:
+        lhs = jnp.concatenate([lhs, jnp.zeros((-m % tm, k), lhs.dtype)])
+    return gmm(lhs, rhs, group_sizes, preferred_element_type=lhs.dtype, tiling=(tm, min(4096, k), min(4096, n)))[:m]
+
+
+def _moe_held(
+    x: jax.Array,
+    lp: Dict[str, jax.Array],
+    config: ModelConfig,
+    valid: Optional[jax.Array] = None,
+    experts: Optional[Dict[str, jax.Array]] = None,
+    layer=None,
+):
+    """``_moe_ragged`` for an expert layer that holds a share
+    (``ModelConfig.num_experts_held``): the router is ``num_experts`` wide and
+    the gates are the softmax over all K chosen logits, as published; the
+    stacks hold experts ``[first_expert_held, first_expert_held + E_held)``.
+    Assignments to held experts sort to the front, expert-major, and make the
+    groups of the three grouped products (``_held_dot``); those to absent
+    experts (the other chip's) and those of padded rows sort past them into no
+    group and add nothing. ``experts`` is the
+    ``[L*E_held, D, F]`` view of ``_split_expert_stacks`` and ``layer`` the
+    layer's index in it.
+
+    Returns ``(out, held, visited)``: the held share's sum per token, the
+    assignments that fell on held experts and the experts that got any (i32
+    scalars, for the step log)."""
+    E_held, K = config.experts_held, config.num_experts_per_tok
+    weights, top_idx = _route(x, lp, K)
+    local = top_idx.reshape(-1) - config.first_expert_held  # [T*K]
+    held = (local >= 0) & (local < E_held)
+    if valid is not None:
+        held = held & jnp.repeat(valid, K)
+    order = jnp.argsort(jnp.where(held, local, E_held))  # stable: held first, expert-major
+    tok = order // K
+    if experts is None:
+        experts, first = lp, 0
+    else:
+        first = layer * E_held
+    groups = experts["w_gate"].shape[0]
+    group_sizes = jnp.zeros((groups,), jnp.int32).at[jnp.where(held, local + first, groups)].add(1, mode="drop")
+    with jax.named_scope("moe_held"):
+        xs = x[tok]
+        g = _held_dot(xs, experts["w_gate"], group_sizes)
+        u = _held_dot(xs, experts["w_up"], group_sizes)
+        y = _held_dot(jax.nn.silu(g) * u, experts["w_down"], group_sizes)  # [T*K, D]
+    # Rows past the last group belong to no GEMM: whatever stands there is dropped, not scaled by zero.
+    held_sorted = held[order]
+    y = jnp.where(held_sorted[:, None], y * weights.reshape(-1)[order].astype(x.dtype)[:, None], 0)
+    out = jnp.zeros_like(x).at[tok].add(y)
+    return out, jnp.sum(held).astype(jnp.int32), jnp.sum(group_sizes > 0).astype(jnp.int32)
+
+
 def _moe_capacity(
     x: jax.Array, lp: Dict[str, jax.Array], config: ModelConfig, valid: Optional[jax.Array] = None
 ) -> jax.Array:
@@ -449,6 +525,7 @@ def _refuse_eva(c: ModelConfig, what: str) -> None:
     built for ``attention_kind='eva'``: refuse, never serve wrong rows."""
     if c.is_eva:
         raise NotImplementedError(f"{what} is not built for attention_kind='eva' (model {c.name!r})")
+    c.refuse_for_layer_types(what)  # nor for a model that holds recurrent state beside its table
 
 
 def _on_tpu() -> bool:

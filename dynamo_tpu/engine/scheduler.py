@@ -41,7 +41,9 @@ import numpy as np
 
 from dynamo_tpu.engine.config import ModelConfig
 from dynamo_tpu.engine.flight_recorder import FlightRecorder, StepCostModel
-from dynamo_tpu.engine.kv_cache import BlockAllocator, KvCacheArrays, KvEvent, OutOfBlocksError, cache_rows
+from dynamo_tpu.engine.kv_cache import (
+    BlockAllocator, KvCacheArrays, KvEvent, OutOfBlocksError, SlotAllocator, cache_rows, pool_of,
+)
 from dynamo_tpu.runtime.ledger import RequestBill, TenantLedger
 from dynamo_tpu.runtime.telemetry import SloConfig, SloJudge, Telemetry
 from dynamo_tpu.engine.sampling import SamplingParams, guided_sample_batch, sample_batch
@@ -146,6 +148,9 @@ class Sequence:
     # attention_kind "eva": completed windows whose rows are summaries by now
     # (Scheduler._roll); the table is then [summary rows ; current window].
     rolls: int = 0
+    # ModelConfig.layer_types: the slot of recurrent state this sequence holds
+    # from admission to finish or preemption (0 = none: the scratch slot).
+    state_slot: int = 0
     block_hashes: List[int] = field(default_factory=list)
     num_cached_blocks: int = 0  # prefix blocks reused from cache
     cached_tokens: int = 0  # prompt tokens skipped by the prefix cache
@@ -374,6 +379,8 @@ class Scheduler:
         self.mc = model_config
         if model_config.is_eva and mesh is not None:
             self._refuse_eva("sharded serving (a mesh)")
+        if mesh is not None:
+            model_config.refuse_for_layer_types("sharded serving (a mesh)")
         ep = parallel.ep if parallel is not None else (mesh.shape.get("ep", 1) if mesh else 1)
         model_config = resolve_moe_dispatch(model_config, ep)
         self.mc = model_config
@@ -384,9 +391,19 @@ class Scheduler:
         # (kv_cache.cache_rows), completed windows are rolled into summaries
         # (_roll_windows), and what equates the two is refused (_refuse_eva).
         self._eva = model_config.is_eva
-        if self._eva and self.sc.enable_prefix_caching:
-            logger.info("attention_kind='eva': prefix-block reuse is not built for it, prefix caching is off")
+        # layer_types: every running sequence holds one slot of recurrent state
+        # beside its blocks (self.slots, _open_slot / _drop_slot); what equates
+        # a sequence with its block table alone is refused (_refuse_unbuilt).
+        self._hybrid = model_config.is_hybrid
+        if (self._eva or self._hybrid) and self.sc.enable_prefix_caching:
+            logger.info("model %r: prefix-block reuse is not built for it, prefix caching is off", model_config.name)
             self.sc.enable_prefix_caching = False
+        self.slots: Optional[SlotAllocator] = None
+        if self._hybrid:
+            # One slot a running sequence and the scratch slot: a sequence is
+            # admitted only while len(running) < max_running, so no more are held.
+            self.slots = SlotAllocator(self.sc.max_running + 1)
+        self.ssm_preempt_recomputes_total = 0
         self.allocator = BlockAllocator(self.sc.num_blocks, on_event=on_kv_event)
         # Reserve block 0 as the scratch sink for padded scatter positions.
         self.allocator._free.remove(0)
@@ -403,7 +420,9 @@ class Scheduler:
             cache_sharding = NamedSharding(mesh, kv_cache_spec(model_config.num_kv_heads, tp))
             self.cache = KvCacheArrays.create(model_config, self.sc.num_blocks, dtype=dtype, sharding=cache_sharding)
         else:
-            self.cache = KvCacheArrays.create(model_config, self.sc.num_blocks, dtype=dtype)
+            self.cache = KvCacheArrays.create(
+                model_config, self.sc.num_blocks, dtype=dtype, num_slots=self.slots.num_slots if self.slots else 0
+            )
         self.params = params
         # Widest table a sequence can hold: every position's row, or for eva the
         # summaries of every completed window and one whole window.
@@ -458,7 +477,8 @@ class Scheduler:
         p_leaves = jax.tree_util.tree_leaves(params)
         param_count = sum(int(x.size) for x in p_leaves)
         param_bytes = sum(int(x.size) * x.dtype.itemsize for x in p_leaves)
-        kv_leaves = jax.tree_util.tree_leaves((self.cache.k, self.cache.v))
+        # (The paged pool alone: a hybrid model's slot arrays ride beside it and are counted apart.)
+        kv_leaves = jax.tree_util.tree_leaves((pool_of(self.cache.k), pool_of(self.cache.v)))
         kv_bytes = sum(int(x.size) * x.dtype.itemsize for x in kv_leaves)
         kv_per_token = kv_bytes / max(self.sc.num_blocks * model_config.block_size, 1)
         # KV-read traffic factor per attention path: the XLA gather's
@@ -476,8 +496,8 @@ class Scheduler:
         self._attn_impl = "gather"
         self._chunk_attn_paths: Dict[int, str] = {}  # chunk bucket -> llama.chunk_attn_path, for the step log
         if model_config.architecture == "llama":
-            model.warn_attention_impl_degrade(model_config, self.cache.k)
-            self._attn_impl = model.resolve_attention_impl(model_config, self.cache.k)
+            model.warn_attention_impl_degrade(model_config, pool_of(self.cache.k))
+            self._attn_impl = model.resolve_attention_impl(model_config, pool_of(self.cache.k))
         kv_read_factor = 1.0 if self._attn_impl in ("paged", "megakernel") else 3.0
         self.flight.set_cost_model(
             StepCostModel(param_count, param_bytes, kv_per_token,
@@ -527,6 +547,9 @@ class Scheduler:
         self._elastic_fraction = 0.5  # guarded-by: _aux_lock
         self.elastic_dial_changes_total = 0  # guarded-by: _aux_lock
         self._pending_aux: list = []
+        # layer_types: the step programs' last result, the expert layer's counts
+        # of the dispatch (device scalars until _note_aux reads them back).
+        self._step_aux = None
         # _drain_aux runs on the step thread (overflow drain in
         # _consume_aux) AND the event loop (metrics()/moe_* properties via
         # the stats scrape): the swap-and-accumulate must not interleave.
@@ -588,6 +611,8 @@ class Scheduler:
         # and what the rolls have done so far.
         self.eva_rolls_total = 0
         self.eva_released_blocks_total = 0
+        if self._hybrid:
+            self._open_slot_jit = jax.jit(model.open_slot, donate_argnums=(0, 1))
         if self._eva:
 
             def eva_roll(p, k, v, table, row0):
@@ -643,7 +668,7 @@ class Scheduler:
         self._supports_multi_step = hasattr(model, "decode_multi")
         # Batched admission (chunk_decode waves) — llama-family only, and not
         # eva (a wave's chunk may straddle a window boundary).
-        self._supports_chunk_admit = hasattr(model, "chunk_decode") and not self._eva
+        self._supports_chunk_admit = hasattr(model, "chunk_decode") and not self._eva and not self._hybrid
         self._admit_jits: Dict = {}
         # Mixed prefill+decode steps (llama.mixed_step) — llama-family only.
         self._supports_mixed = hasattr(model, "mixed_step")
@@ -682,6 +707,8 @@ class Scheduler:
 
         if self._eva or draft_config.is_eva:
             self._refuse_eva("speculative decoding")
+        self.mc.refuse_for_layer_types("speculative decoding (verification and rollback)")
+        draft_config.refuse_for_layer_types("speculative decoding (verification and rollback)")
         if draft_config.block_size != self.mc.block_size:
             raise ValueError("draft and target must share block_size")
         if draft_config.vocab_size != self.mc.vocab_size:
@@ -796,10 +823,10 @@ class Scheduler:
             )
         if len(token_ids) >= self.mc.max_seq_len:
             raise ValueError(f"prompt length {len(token_ids)} >= max_seq_len {self.mc.max_seq_len}")
-        if self._eva and (keep_blocks_on_finish or prefilled is not None):
-            self._refuse_eva("KV export and injection (disaggregated prefill)")
-        if self._eva and mm_features is not None:
-            self._refuse_eva("multimodal feature rows")
+        if keep_blocks_on_finish or prefilled is not None:
+            self._refuse_unbuilt("KV export and injection (disaggregated prefill)")
+        if mm_features is not None:
+            self._refuse_unbuilt("multimodal feature rows")
         if mm_features is not None:
             if self.mc.architecture != "llama":
                 raise ValueError("multimodal features require the llama prefill path")
@@ -908,6 +935,13 @@ class Scheduler:
             "kv_fragmentation": round(1.0 - used / allocated, 6) if allocated else 0.0,
             "prefix_hit_rate": round(hits / (hits + misses), 6) if (hits + misses) else 0.0,
         }
+        if self._hybrid:
+            out.update({
+                "ssm_slots_total": self.slots.num_slots - 1,
+                "ssm_slots_in_use": self.slots.in_use,
+                "ssm_slot_allocs_total": self.slots.allocs_total,
+                "ssm_preempt_recomputes_total": self.ssm_preempt_recomputes_total,
+            })
         if self._eva:
             # Blocks that hold summaries of rolled windows (the last of them
             # may also hold the current window's first rows), the rest of the
@@ -941,6 +975,7 @@ class Scheduler:
                 "blocks": len(s.block_ids),
                 "preemptions": s.preemptions,
                 **({"rolls": s.rolls, "cache_rows": self._rows_for(s, s.total_len)} if self._eva else {}),
+                **({"state_slot": s.state_slot} if self._hybrid else {}),
             }
 
         a = self.allocator
@@ -954,7 +989,7 @@ class Scheduler:
                 "cached": a.num_cached,
                 "active": a.num_active,
                 "usage": round(a.usage(), 6),
-                **{k: v for k, v in self.kv_gauges().items() if k == "kv_fragmentation" or k.startswith("eva_")},
+                **{k: v for k, v in self.kv_gauges().items() if k == "kv_fragmentation" or k.startswith(("eva_", "ssm_"))},
             },
             "digests": self.telemetry.summary(),
             "slo": self.slo.to_stats(),
@@ -1125,6 +1160,9 @@ class Scheduler:
                 # Cache rows the batch's contexts hold (what a decode step
                 # attends), beside their lengths in bytes under ``ctx``.
                 span.set(attended=sum(self._rows_for(s, s.total_len) for s in batch))
+            if self._hybrid:
+                # Slots the dispatch advances (its decode rows' and its chunk's), and slots held.
+                span.set(ssm_rows=len(batch) + (1 if kind == "mixed" else 0), ssm_slots=self.slots.in_use)
         else:
             span.set(dispatches=attrs.get("dispatches", 1) + 1)
         if kind in ("mixed", "prefill", "prefill_mm"):
@@ -1139,7 +1177,7 @@ class Scheduler:
         if self._attn_impl != "megakernel":
             return self._attn_impl
         if bucket not in self._chunk_attn_paths:  # 0.8 ms to work out: once a bucket, not once a dispatch
-            self._chunk_attn_paths[bucket] = self._model.chunk_attn_path(self.mc, self.cache.k, bucket, self.dtype)
+            self._chunk_attn_paths[bucket] = self._model.chunk_attn_path(self.mc, pool_of(self.cache.k), bucket, self.dtype)
         return self._chunk_attn_paths[bucket]
 
     def _launch(self, kind: str, decode: bool = False) -> StepSpan:
@@ -1162,6 +1200,49 @@ class Scheduler:
             f"{what} is not built for attention_kind='eva' (model {self.mc.name!r}): "
             "its block tables hold summaries of rolled windows, not one row a token"
         )
+
+    def _refuse_unbuilt(self, what: str) -> None:
+        """``what`` equates a sequence with one row a token in its block table:
+        not built where the table holds summaries (eva) or where a slot of
+        recurrent state rides beside it (layer_types)."""
+        if self._eva:
+            self._refuse_eva(what)
+        self.mc.refuse_for_layer_types(what)
+
+    # --- layer_types: slots of recurrent state beside the block pool -----------
+    def _open_slot(self, seq: Sequence) -> None:
+        """``seq`` (its blocks just allocated) takes a slot: zeroed on the
+        device in every state-space layer and tied to the table's first block,
+        by which the step programs find it. OutOfBlocksError when none is free."""
+        self._end_plan()
+        try:
+            with self._span("sched.slots", request_id=seq.request_id) as span:
+                seq.state_slot = self.slots.allocate()
+                span.set(slot=seq.state_slot)
+                self.flight.record_exec("open_slot", ())
+                self.cache.k, self.cache.v = self._open_slot_jit(
+                    self.cache.k, self.cache.v, jnp.int32(seq.block_ids[0]), jnp.int32(seq.state_slot)
+                )
+        finally:
+            self._begin_plan()
+
+    def _drop_slot(self, seq: Sequence) -> None:
+        """``seq`` gives its slot back (finish, abort, preemption): the next
+        sequence to take it zeroes it."""
+        if seq.state_slot:
+            self.slots.release(seq.state_slot)
+            seq.state_slot = 0
+
+    def _note_aux(self) -> None:
+        """The expert layer's counts of the dispatch just synced, onto the open
+        ``sched.step``: read after the step's blocking read-back, so the two
+        scalars are there already and the read waits for nothing."""
+        aux, self._step_aux = self._step_aux, None
+        if aux is not None and self._step_span is not None:
+            held, visited = jax.device_get((aux["held_assignments"], aux["experts_visited"]))
+            attrs = self._step_span.attrs or {}
+            self._step_span.set(held_assignments=attrs.get("held_assignments", 0) + int(held),
+                                experts_visited=attrs.get("experts_visited", 0) + int(visited))
 
     def _rows_for(self, seq: Sequence, n_tokens: int) -> int:
         """Table rows ``seq`` needs to hold its first ``n_tokens`` positions.
@@ -1453,6 +1534,7 @@ class Scheduler:
                 # Mid-prefill cancellations already hold blocks — release them.
                 self.allocator.release(seq.block_ids)
                 seq.block_ids = []
+                self._drop_slot(seq)
                 self.by_id.pop(seq.request_id, None)
                 outputs.append((seq, StepOutput(token_id=-1, finished=True, finish_reason=seq.abort_reason)))
 
@@ -1702,6 +1784,8 @@ class Scheduler:
                 self.cached_tokens_total += seq.cached_tokens
             # (eva: the first window's rows; later windows grow chunk by chunk.)
             self._grow_table(seq, total_tokens)
+            if self._hybrid:
+                self._open_slot(seq)
         except OutOfBlocksError:
             self.allocator.release(seq.block_ids)
             self.cached_tokens_total -= seq.cached_tokens
@@ -1830,6 +1914,7 @@ class Scheduler:
         # Prompt fully computed: sample the first token.
         with self._span("sched.sample"):
             token = self._sample_one(seq, logits)
+            self._note_aux()
         with self._span("sched.emit"):
             seq.first_token_ts = time.monotonic()
             seq.state = SeqState.RUNNING
@@ -1955,6 +2040,11 @@ class Scheduler:
                 jnp.ones((bucket,), jnp.float32), key, None,
             )
             count += 3
+        if self._hybrid:
+            # Taking a slot: one executable, warmed on the scratch slot and block.
+            self.flight.record_exec("open_slot", ())
+            self.cache.k, self.cache.v = self._open_slot_jit(self.cache.k, self.cache.v, jnp.int32(0), jnp.int32(0))
+            count += 1
         if self._eva:
             # The roll program: one executable, warmed against the scratch
             # block (a table of zeros reads and writes block 0).
@@ -2416,6 +2506,7 @@ class Scheduler:
                     sampled, logprobs_np = jax.device_get(res)
                 else:
                     sampled = np.asarray(res)
+                self._note_aux()
 
         with self._span("sched.emit"):
             for i, seq in enumerate(batch):
@@ -2534,6 +2625,7 @@ class Scheduler:
             toks_out, self.cache.k, self.cache.v = self._consume_aux(res)
         with self._span("sched.sync"):
             sampled = np.asarray(toks_out)  # [steps, bucket] — the one host sync
+            self._note_aux()
         with self._span("sched.emit"):
             for i, seq in enumerate(batch):
                 for s in range(taken[i]):
@@ -2701,8 +2793,7 @@ class Scheduler:
         device-native path: in-process handoff or transfer-server pull)."""
         from dynamo_tpu.llm.block_manager.transfer import scatter_blocks, scatter_blocks_device
 
-        if self._eva:
-            self._refuse_eva("KV injection (disaggregated prefill)")
+        self._refuse_unbuilt("KV injection (disaggregated prefill)")
         bs = self.mc.block_size
         data = seq.prefilled
         # Token-boundary splits (elastic disagg): ``prefill_len`` marks how
@@ -2760,8 +2851,7 @@ class Scheduler:
         prompt_len) or None."""
         from dynamo_tpu.llm.block_manager.transfer import gather_blocks
 
-        if self._eva:
-            self._refuse_eva("KV export (disaggregated prefill)")
+        self._refuse_unbuilt("KV export (disaggregated prefill)")
         seq = self._pending_exports.pop(request_id, None)
         self._export_deadline.pop(request_id, None)
         if seq is None:
@@ -2779,8 +2869,7 @@ class Scheduler:
         can await a remote pull while the blocks are reused."""
         from dynamo_tpu.llm.block_manager.transfer import gather_blocks_device
 
-        if self._eva:
-            self._refuse_eva("KV export (disaggregated prefill)")
+        self._refuse_unbuilt("KV export (disaggregated prefill)")
         seq = self._pending_exports.pop(request_id, None)
         self._export_deadline.pop(request_id, None)
         if seq is None:
@@ -2816,8 +2905,7 @@ class Scheduler:
 
     def attach_kvbm(self, kvbm) -> None:
         """Enable tiered offload/onboard (KVBM G2/G3) for this scheduler."""
-        if self._eva:
-            self._refuse_eva("KVBM offload tiers")
+        self._refuse_unbuilt("KVBM offload tiers")
         self.kvbm = kvbm
 
     def _copy_block(self, src: int, dst: int) -> None:
@@ -2835,8 +2923,7 @@ class Scheduler:
         allocator's G1 walk saw them as misses, so the counters are
         re-attributed here; ``prefix_onboard_total`` tracks the subset that
         crossed a tier boundary back into HBM."""
-        if self._eva:
-            self._refuse_eva("prefix-block matching")
+        self._refuse_unbuilt("prefix-block matching")
         if self.kvbm is None:
             return self.allocator.match_prefix(seq.block_hashes)
         match = self.kvbm.match_prefix(seq.block_hashes)
@@ -2858,6 +2945,9 @@ class Scheduler:
         tuple. The aux scalars stay on device — forcing them here would add a
         host sync per step on a path that otherwise syncs once; metrics()
         drains them in a batch."""
+        if self._hybrid:
+            *main, self._step_aux = res
+            return tuple(main)
         if not self._moe_stats:
             return res
         *main, aux = res
@@ -2938,6 +3028,10 @@ class Scheduler:
         self._accrue_kv(victim)
         victim.kv_ts = None
         self.allocator.release(victim.block_ids)
+        if victim.state_slot:
+            # The state goes with the blocks: the recompute starts from a zeroed slot.
+            self._drop_slot(victim)
+            self.ssm_preempt_recomputes_total += 1
         victim.block_ids = []
         victim.block_hashes = []
         victim.num_cached_blocks = 0
@@ -3124,8 +3218,7 @@ class Scheduler:
         computed instead of recomputing the whole prompt in parallel."""
         if not self.sc.enable_prefix_caching or not seq.block_hashes:
             return
-        if self._eva:
-            self._refuse_eva("prefix-block registration")
+        self._refuse_unbuilt("prefix-block registration")
         bs = self.mc.block_size
         n_full = min(seq.num_computed, len(seq.prompt)) // bs
         n_full = min(n_full, len(seq.block_hashes), len(seq.block_ids))
@@ -3287,6 +3380,7 @@ class Scheduler:
         else:
             self.allocator.release(seq.block_ids)
             seq.block_ids = []
+        self._drop_slot(seq)
         if emit:
             outputs.append((seq, StepOutput(token_id=-1, finished=True, finish_reason=reason)))
         self.by_id.pop(seq.request_id, None)

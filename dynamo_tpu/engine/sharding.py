@@ -64,7 +64,8 @@ def build_mesh(parallel: ParallelConfig, devices: Optional[Sequence[jax.Device]]
 
 
 def param_specs(tie_word_embeddings: bool, num_experts: int = 0, pp: bool = False) -> dict:
-    """PartitionSpec pytree matching llama.init_params structure.
+    """PartitionSpec pytree matching llama.init_params structure (one stack of
+    identical layers: ``shard_params`` refuses a tree with mixer stacks).
 
     MoE: experts shard over ``ep`` and the FFN hidden dim over ``tp`` —
     the wide-EP layout (each chip holds E/ep experts, each split tp-ways).
@@ -115,6 +116,8 @@ def kv_cache_spec(num_kv_heads: int = 0, tp_size: int = 1, pp: bool = False) -> 
 
 
 def shard_params(params, mesh: Mesh, tie_word_embeddings: bool, num_experts: int = 0, pp: bool = False):
+    if "mamba" in params:
+        raise NotImplementedError("sharded parameters (a mesh) are not built for layer_types: no spec for the mixer stacks")
     specs = param_specs(tie_word_embeddings, num_experts, pp=pp)
 
     def _put(x, s):

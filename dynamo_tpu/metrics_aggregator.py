@@ -70,6 +70,9 @@ GAUGE_KEYS = (
     # Tenant capacity ledger (runtime/ledger.py): tenants currently tracked
     # by the worker's device-seconds heavy-hitter sketch (≤ top_k).
     "tenant_tracked",
+    # Hybrid models (ModelConfig.layer_types): slots of recurrent state beside
+    # the block pool, and how many running sequences hold one.
+    "ssm_slots_total", "ssm_slots_in_use",
 )
 
 # Fleet-level digest families the aggregator re-exports (merged across
@@ -88,6 +91,9 @@ FLEET_DIGEST_PREFIX = "dynamo_component_fleet_"
 # worker restarted and the new total is counted from zero.
 COUNTER_KEYS = (
     "request_total", "preemptions_total",
+    # layer_types: slots taken at admission, and states dropped at preemption
+    # (each is a whole recompute of the sequence's recurrent state).
+    "ssm_slot_allocs_total", "ssm_preempt_recomputes_total",
     "moe_dropped_total", "moe_assignments_total",
     "mixed_steps_total", "mixed_prefill_tokens_total", "mixed_decode_tokens_total",
     "cached_tokens_total",

@@ -161,7 +161,7 @@ def readme_step_programs():
     return {r[0].strip("`"): r[3] for r in rows}
 
 
-@pytest.mark.parametrize("preset", ["tiny", "tiny-moe", "tiny-eva"])
+@pytest.mark.parametrize("preset", ["tiny", "tiny-moe", "tiny-eva", "tiny-hybrid"])
 def test_warmup_registers_exactly_the_kinds_that_can_dispatch(preset):
     cfg = get_config(preset)
     sched = mk_sched(cfg, llama.init_params(cfg, jax.random.PRNGKey(0), dtype=jnp.float32), num_blocks=64,
@@ -169,9 +169,9 @@ def test_warmup_registers_exactly_the_kinds_that_can_dispatch(preset):
                      enable_prefix_caching=True)  # the program's default (eva turns it off)
     assert sched.warmup(ctx_tokens=64) > 0
     table = readme_step_programs()
-    assert len(table) == 9 and not GONE & set(table)
-    expected = {kind for kind, when in table.items()
-                if when == "yes" or when.startswith(f"yes, `{cfg.attention_kind}` only")}
+    assert len(table) == 10 and not GONE & set(table)
+    only = "layer_types" if cfg.is_hybrid else cfg.attention_kind  # (layer_types: neither waves nor prefix blocks)
+    expected = {kind for kind, when in table.items() if when == "yes" or when.startswith(f"yes, `{only}` only")}
     registered = {k[0] for k in sched.flight._exec_keys}
     assert registered == expected
     assert not registered & GONE

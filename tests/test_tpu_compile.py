@@ -367,3 +367,86 @@ def test_tp4_prefill_step_partitions(tp4, on_tpu, attention_impl):
         _sds((32,), i32, rep),
     ).compile()
     _assert_partitioned(compiled, per_device_limit=1 << 30)
+
+
+# --- layer_types at Granite-4.0-H-Small's widths: the slot kernel and the step programs ----------
+
+
+@pytest.mark.parametrize("rows,tiles", [(32, 16), (64, 16), (64, 32)], ids=["rows32", "rows64", "rows64-blocks-of-32"])
+def test_ssm_update_rows_compiles_in_place(one_chip, rows, tiles):
+    """The in-place state update at the published widths (128 heads of 64 with
+    a state of 128, stored [64, 128, 128]; the benchmark's 9 x 65 slots):
+    Mosaic takes the tiles, the slot array is aliased to the result and
+    nothing of its size is a temporary."""
+    from dynamo_tpu.engine.models import hybrid
+
+    mc = get_config("tiny-hybrid").replace(hidden_size=4096, mamba_n_heads=128, mamba_d_head=64, mamba_d_state=128)
+    Hm, Pm, Nm, slots = 128, 64, 128, 9 * 65
+    assert mc.mamba_state_shape == (64, 128, 128) and hybrid._rows_kernel_fits(mc)
+    f32 = lambda *s: _sds(s, jnp.float32, one_chip)  # noqa: E731
+    compiled = jax.jit(
+        lambda ssm, idx, x, b, c, dt, a, d: hybrid.ssm_update_rows(mc, ssm, idx, x, b, c, dt, a, d, tiles=tiles),
+        donate_argnums=(0,),
+    ).lower(f32(slots, *mc.mamba_state_shape), _sds((rows,), jnp.int32, one_chip), f32(rows, Hm, Pm), f32(rows, Nm), f32(rows, Nm),
+            f32(rows, Hm), f32(Hm), f32(Hm)).compile()
+    mem = compiled.memory_analysis()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert mem.alias_size_in_bytes == slots * Hm * Pm * Nm * 4 and mem.temp_size_in_bytes < 64 << 20
+
+
+@pytest.mark.parametrize("program", ["decode_multi", "mixed_step", "prefill", "check-decode_multi", "check-mixed_step", "check-prefill"])
+def test_granite_step_programs_compile_and_fit_beside_the_weights(one_chip, on_tpu, program):
+    """The benchmark's ``granite-4.0-h-small-d10-e36`` as configured (65 slots,
+    1,025 blocks, 64 rows): the groups scan, the slot kernel and the megakernel
+    compile into one program whose arguments (9.9 GB of weights, 3.0 GB of
+    pool and slots, aliased to the results) and temporaries fit the chip.
+    ``check-``: as the output check calls them (``granite_hybrid.program_logits``:
+    the same pool and slots, its bucket and table width, logits returned)."""
+    import json
+
+    from benchmark import families
+    from dynamo_tpu.engine.kv_cache import KvCacheArrays
+    from dynamo_tpu.engine.models import hybrid
+
+    with open(os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "benchmark", "configs",
+                           "granite-4.0-h-small-d10-e36.json")) as f:
+        cfg = json.load(f)
+    fam = families.load("granite_hybrid")
+    mc = fam.model_config(cfg, "granite")
+    sc = cfg["scheduler"]
+    place = lambda tree: jax.tree.map(lambda s: _sds(s.shape, s.dtype, one_chip), tree)  # noqa: E731
+    params = place(jax.eval_shape(lambda: fam.make_params(mc, 0)))
+    k, v = place(jax.eval_shape(
+        lambda: (lambda c: (c.k, c.v))(KvCacheArrays.create(mc, sc["num_blocks"], dtype=BF16, num_slots=sc["max_running"] + 1))))
+    B, W = sc["max_running"], 16
+    check, program = program.startswith("check-"), program.removeprefix("check-")
+    if check:
+        B, W = cfg["parity"]["decode_bucket"], 8
+    flash = check and hybrid.resolve_prefill_impl(mc) == "flash"
+    i32 = lambda *s: _sds(s, jnp.int32, one_chip)  # noqa: E731
+    f32 = lambda *s: _sds(s, jnp.float32, one_chip)  # noqa: E731
+    act = _sds((B,), jnp.bool_, one_chip)
+    if program == "decode_multi":
+        compiled = jax.jit(
+            lambda p, k, v, t, pos, bt, a, te, tk, tp, key: hybrid.decode_multi(p, mc, k, v, t, pos, bt, a, te, tk, tp, key, 8,
+                                                                                return_logits=check),
+            donate_argnums=(1, 2),
+        ).lower(params, k, v, i32(B), i32(B), i32(B, W), act, f32(B), i32(B), f32(B), _sds((2,), jnp.uint32, one_chip)).compile()
+    elif program == "prefill":  # a chunk with no decode row: the slot array's layout stays pinned (hybrid._mamba_mixer)
+        compiled = jax.jit(
+            lambda p, k, v, t, vl, cl, bt: hybrid.prefill(p, mc, k, v, t, vl, cl, bt, all_logits=check, has_prefix=not check,
+                                                          use_flash=flash),
+            donate_argnums=(1, 2),
+        ).lower(params, k, v, i32(256), i32(), i32(), i32(W)).compile()
+    else:
+        compiled = jax.jit(
+            lambda p, k, v, pt, pv, cl, ptab, dt, dpos, dtab, da: hybrid.mixed_step(p, mc, k, v, pt, pv, cl, ptab, dt, dpos, dtab, da,
+                                                                                    use_flash=flash),
+            donate_argnums=(1, 2),
+        ).lower(params, k, v, i32(256), i32(), i32(), i32(W), i32(B), i32(B), i32(B, W), act).compile()
+    mem, text = compiled.memory_analysis(), compiled.as_text()
+    assert text.count("tpu_custom_call") >= 2 and "ssm_update_rows" in text  # the slot kernel and the attention kernel
+    state = (k.slots.size * 4 + v.slots.size * 2)
+    assert mem.alias_size_in_bytes >= state  # pool and slots are updated in place
+    assert 9.9e9 < mem.argument_size_in_bytes - mem.alias_size_in_bytes < 10.0e9  # the weights
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.0e9, (mem.argument_size_in_bytes, mem.temp_size_in_bytes)
