@@ -501,20 +501,23 @@ async def tp4_phase(args, meter: CompileMeter) -> None:
     rs = np.random.RandomState(args.seed)
     prompt = rs.randint(1, s_1.mc.vocab_size, size=T).astype(np.int32)
     table = (1 + np.arange(W)).astype(np.int32)
-    tpa = np.zeros((3, B), np.int32)
-    tpa[:, 0] = (int(rs.randint(1, s_1.mc.vocab_size)), T, 1)
+    # The packed operands of a dispatch (scheduler.pack_operands): the chunk's tokens, then its length and its start; a
+    # batch's [3, B] lanes (token, position, active).
+    from dynamo_tpu.engine.scheduler import pack_operands
+
+    chunk = pack_operands(prompt, T, 0)
+    rows = np.zeros((3, B), np.int32)
+    rows[:, 0] = (int(rs.randint(1, s_1.mc.vocab_size)), T, 1)
     tables = np.zeros((B, W), np.int32)
     tables[0] = table
 
     def logits_of(s):
-        res = s._prefill_jit(
-            s.params, s.cache.k, s.cache.v, jnp.asarray(prompt), jnp.int32(T), jnp.int32(0),
-            jnp.asarray(table), False,
-        )
-        pre, s.cache.k, s.cache.v = res[:3]
-        res = s._decode_jit(s.params, s.cache.k, s.cache.v, jnp.asarray(tpa), jnp.asarray(tables))
-        dec, s.cache.k, s.cache.v = res[:3]
-        return np.asarray(pre)[None], np.asarray(dec)[:1]
+        hp = (False,) if s._hp_static else ()
+        res = s._prefill_jit(s.params, s.cache.k, s.cache.v, jnp.asarray(chunk), jnp.asarray(table), *hp)
+        _, pre, s.cache.k, s.cache.v = res[:4]
+        res = s._decode_jit(s.params, s.cache.k, s.cache.v, jnp.asarray(pack_operands(rows)), jnp.asarray(tables))
+        _, dec, s.cache.k, s.cache.v = res[:4]
+        return np.asarray(pre), np.asarray(dec)[:1]
 
     got_p, got_d = logits_of(s_tp)
     ref_p, ref_d = logits_of(s_1)

@@ -46,7 +46,7 @@ from dynamo_tpu.engine.kv_cache import (
 )
 from dynamo_tpu.runtime.ledger import RequestBill, TenantLedger
 from dynamo_tpu.runtime.telemetry import SloConfig, SloJudge, Telemetry
-from dynamo_tpu.engine.sampling import SamplingParams, guided_sample_batch, sample_batch
+from dynamo_tpu.engine.sampling import SamplingParams, guided_sample_batch, make_row_keys, sample_batch
 from dynamo_tpu.llm.tokens import extend_block_hashes
 from dynamo_tpu.runtime.logging import get_logger
 from dynamo_tpu.runtime.tracing import StepSpan, get_tracer
@@ -78,6 +78,59 @@ def width_bucket(n: int, cap: int) -> int:
     """Smallest pow2-or-1.5·pow2 rung ≥ n, clamped to ``cap``. bench.py uses
     the same rule so driver decode numbers reflect production table widths."""
     return min(width_rungs(max(n, 1))[-1], cap)
+
+
+def pack_operands(*parts) -> np.ndarray:
+    """A dispatch's small operands as ONE int32 vector, so that they cost one
+    upload: ints and bools as they are, floats bit-cast from float32
+    (``_f32`` undoes it inside the program)."""
+    out = []
+    for part in parts:
+        a = np.asarray(part).reshape(-1)
+        out.append(a.astype(np.float32).view(np.int32) if a.dtype.kind == "f" else a.astype(np.int32))
+    return np.concatenate(out)
+
+
+def _f32(x: jax.Array) -> jax.Array:
+    return jax.lax.bitcast_convert_type(x, jnp.float32)
+
+
+def _unpack_rows(rows: jax.Array):
+    """(tokens, positions, active) of a decode batch out of the first three
+    of the lanes ``Scheduler._pack_rows`` laid down."""
+    return rows[0], rows[1], rows[2].astype(bool)
+
+
+def _greedy(logits: jax.Array) -> jax.Array:
+    """What a step program samples: each row's argmax. (A row that draws is
+    the host path's, Scheduler._needs_host: the sampler in every executable
+    of every key is set-up time and device memory, PERF.md section 6, PR 35.)"""
+    return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+
+
+_M32 = 0xFFFFFFFF
+_ROTATIONS = ((13, 15, 26, 6), (17, 29, 16, 24))
+
+
+def fold_key(key: tuple, data: int) -> tuple:
+    """``jax.random.fold_in(key, data)`` of a raw threefry key, on the host:
+    the two words of Threefry-2x32 over the block ``[0, data]``, in Python
+    integers. A window's key rides its packed operands as two lanes and the
+    host path's goes up as it is, so no step program holds a fold (250
+    operations to lower again in every executable that has one, 30 ms of
+    set-up a key), no program folds eagerly on the step thread, and the seed
+    is data, never a constant of an HLO."""
+    k0, k1 = key
+    ks = (k0, k1, k0 ^ k1 ^ 0x1BD11BDA)
+    x0, x1 = ks[0], (data + ks[1]) & _M32
+    for i in range(5):
+        for r in _ROTATIONS[i % 2]:
+            x0 = (x0 + x1) & _M32
+            x1 = ((x1 << r) | (x1 >> (32 - r))) & _M32
+            x1 ^= x0
+        x0 = (x0 + ks[(i + 1) % 3]) & _M32
+        x1 = (x1 + ks[(i + 2) % 3] + i + 1) & _M32
+    return x0, x1
 
 
 @dataclass
@@ -451,7 +504,13 @@ class Scheduler:
         self._prefill_tok_s: Optional[float] = None
         self._eos = eos_token_ids or []
         self._rng = jax.random.PRNGKey(rng_seed)
+        self._rng_words = tuple(int(w) for w in np.asarray(self._rng))  # the engine's key on the host, for fold_key
         self._step_counter = 0
+        # Host-to-device transfers of the iteration in progress (_up), and
+        # who sampled each dispatch so far (_note_sampled).
+        self._uploads = 0
+        self.sampled_in_program_total = 0
+        self.sampled_on_host_total = 0
         # SLA telemetry: mergeable latency digests (ttft/tpot/itl/queue_wait
         # + per-phase step durations via the flight recorder) and the SLO
         # judge behind the goodput account. All host-side — no dispatches.
@@ -560,31 +619,38 @@ class Scheduler:
         # Modules" line then reads jit_<kind>(...) (the flight recorder's
         # kind, with the window rung where there is one), so a program's
         # device time is found by name, without host marks.
+        #
+        # A dispatch is one upload, one program, one read-back: the small
+        # operands ride ONE packed int32 vector (pack_operands) that the
+        # program splits, and the program takes every row's argmax beside the
+        # logits it returns, which stay on the device unless a row needs the
+        # host between its logits and its token (_needs_host): then the host
+        # path samples from them and the program's tokens are left unread.
+        def prefill_body(p, k, v, buf, bt, **kw):  # buf: [chunk tokens | its length, its start]
+            res = model.prefill(p, self.mc, k, v, buf[:-2], buf[-2], buf[-1], bt, **kw, **stats_kw)
+            logits = res[0][None]  # [1, V]: the row the samplers take
+            return (_greedy(logits), logits) + tuple(res[1:])
+
         if self._hp_static:
 
-            def prefill(p, k, v, t, vl, cl, bt, hp):
-                return model.prefill(
-                    p, self.mc, k, v, t, vl, cl, bt, use_flash=True, has_prefix=hp, **stats_kw
-                )
+            def prefill(p, k, v, buf, bt, hp):
+                return prefill_body(p, k, v, buf, bt, use_flash=True, has_prefix=hp)
 
-            self._prefill_jit = jax.jit(prefill, donate_argnums=(1, 2), static_argnums=(7,))
+            self._prefill_jit = jax.jit(prefill, donate_argnums=(1, 2), static_argnums=(5,))
         else:
-            # ``hp`` rides as a TRACED (unused) arg here: the XLA path's
-            # masks and the megakernel's ragged rows cover prefix and fresh
-            # prefills alike, and a static arg would compile two
-            # byte-identical executables per bucket.
-            def prefill(p, k, v, t, vl, cl, bt, hp):
-                return model.prefill(p, self.mc, k, v, t, vl, cl, bt, **stats_kw)
+            # No ``hp`` here: the XLA path's masks and the megakernel's ragged
+            # rows cover prefix and fresh prefills alike (a static argument
+            # would compile two byte-identical executables per bucket, a
+            # traced one would be an upload of its own).
+            def prefill(p, k, v, buf, bt):
+                return prefill_body(p, k, v, buf, bt)
 
             self._prefill_jit = jax.jit(prefill, donate_argnums=(1, 2))
 
-        # tokens/positions/active ride ONE packed [3, bucket] i32 upload and
-        # split in-jit — three small per-step H2D transfers collapsed into
-        # one.
-        def decode(p, k, v, tpa, bt):
-            return model.decode(
-                p, self.mc, k, v, tpa[0], tpa[1], bt, tpa[2].astype(bool), **stats_kw
-            )
+        def decode(p, k, v, buf, bt):  # buf: the rows' 3 x B lanes
+            t, pos, act = _unpack_rows(buf.reshape(3, -1))
+            res = model.decode(p, self.mc, k, v, t, pos, bt, act, **stats_kw)
+            return (_greedy(res[0]),) + tuple(res)
 
         self._decode_jit = jax.jit(decode, donate_argnums=(1, 2))
         self._sample_jit = jax.jit(sample_batch)
@@ -599,6 +665,12 @@ class Scheduler:
         )
 
         self._sample_lp_jit = jax.jit(sample_batch_logprobs)
+        # The host path's first-token logprobs, warmed like the samplers
+        # (nothing eager on the step thread).
+        from dynamo_tpu.engine.sampling import compute_logprobs, compute_topk_logprobs
+
+        self._lp_jit = jax.jit(compute_logprobs)
+        self._tlp_jit = jax.jit(compute_topk_logprobs)
         self._guided_sample_lp_jit = jax.jit(guided_sample_batch_logprobs)
         # Top-k variants (OpenAI top_logprobs): chosen logprob + the static
         # candidate cap's (ids, logprobs) in the same dispatch.
@@ -682,10 +754,12 @@ class Scheduler:
             # 32-step window wastes half the dispatch). _decode_multi picks
             # the smallest rung covering the batch's remaining budget.
             def mk_multi(steps: int):
-                def decode_multi(p, k, v, t, pos, bt, act, te, tk, tp, key):
+                def decode_multi(p, k, v, buf, bt):  # buf: [the rows' 6 x B lanes | the window's key]
+                    rows = buf[:-2].reshape(6, -1)
+                    t, pos, act = _unpack_rows(rows)
                     return model.decode_multi(
-                        p, self.mc, k, v, t, pos, bt, act, te, tk, tp, key,
-                        steps, **stats_kw,
+                        p, self.mc, k, v, t, pos, bt, act, _f32(rows[3]), rows[4], _f32(rows[5]),
+                        jax.lax.bitcast_convert_type(buf[-2:], jnp.uint32), steps, **stats_kw,
                     )
 
                 decode_multi.__name__ = f"decode_multi_w{steps}"
@@ -993,6 +1067,10 @@ class Scheduler:
             },
             "digests": self.telemetry.summary(),
             "slo": self.slo.to_stats(),
+            # Dispatches whose tokens the step program sampled, and those the host
+            # path did (a row that needs the host between logits and token; a wave).
+            "sampled_in_program_total": self.sampled_in_program_total,
+            "sampled_on_host_total": self.sampled_on_host_total,
             "flight": {
                 "last_step_phase": f.last_step_phase,
                 "last_step_s": round(f.last_step_s, 6),
@@ -1107,11 +1185,14 @@ class Scheduler:
         log.step += 1
         with log.span("sched.step") as span:
             self._step_span = span
+            self._uploads = 0
             self._begin_plan()
             try:
                 self._step(outputs)
             finally:
                 self._end_plan()
+                if self._uploads:
+                    span.set(uploads=self._uploads)  # host-to-device transfers of the iteration (_up)
                 self._step_span = None
         return outputs
 
@@ -1180,6 +1261,70 @@ class Scheduler:
             self._chunk_attn_paths[bucket] = self._model.chunk_attn_path(self.mc, pool_of(self.cache.k), bucket, self.dtype)
         return self._chunk_attn_paths[bucket]
 
+    # --- what a dispatch costs the host: uploads, the packed operands, who samples ---
+    def _up(self, host_array) -> jax.Array:
+        """One host-to-device transfer, counted (``uploads`` of the iteration's ``sched.step`` entry)."""
+        self._uploads += 1
+        return jax.device_put(host_array)
+
+    def _pack_rows(self, batch: List[Sequence], bucket: int, sampler: bool = False) -> np.ndarray:
+        """A decode batch's int32 lanes (``_unpack_rows`` in the program):
+        token, write position, active; with ``sampler`` (a window samples in
+        its program) also temperature, top-k, top-p, the float ones bit-cast.
+        Pad lanes are inactive and greedy."""
+        rows = np.zeros((6 if sampler else 3, bucket), dtype=np.int32)
+        for i, seq in enumerate(batch):
+            rows[0, i] = seq.all_ids[-1]
+            rows[1, i] = seq.total_len - 1  # write slot of the current token
+            rows[2, i] = 1
+        if sampler:
+            from dynamo_tpu.engine.sampling import pack_param_rows
+
+            temps, top_ks, top_ps = pack_param_rows([s.sampling for s in batch], bucket)
+            rows[3], rows[4], rows[5] = temps.view(np.int32), top_ks, top_ps.view(np.int32)
+        return rows
+
+    @staticmethod
+    def _host_between_tokens(seq: Sequence) -> bool:
+        """Does ``seq`` need the host between two of its tokens? Penalties
+        (history mutates from token to token), logits processors, logprobs /
+        top_logprobs, a guided row (the FSM advances on the host; proposal
+        sampling ignores its mask) and a seeded sampled row (neither a spec
+        round nor decode_multi threads per-row keys; a greedy row's seed is a
+        no-op). One such row takes its whole batch to single steps."""
+        s = seq.sampling
+        return bool(
+            s.logits_processors
+            or s.logprobs
+            or s.top_logprobs
+            or s.has_penalties
+            or seq.guided is not None
+            or (s.seed is not None and s.temperature > 0)
+        )
+
+    @classmethod
+    def _needs_host(cls, seq: Sequence) -> bool:
+        """Does ``seq`` need the host between its logits and its token in a
+        single step (``decode``, ``mixed``, ``prefill``)? What needs it
+        between tokens, and every row that draws: the single-step programs
+        take the argmax and hold no sampler (a window's program does, as
+        before). One such row takes its whole dispatch to the host path: the
+        program's tokens are left unread and the warmed samplers run on the
+        logits it returned."""
+        return seq.sampling.temperature > 0 or cls._host_between_tokens(seq)
+
+    def _note_sampled(self, how: str) -> None:
+        """Who sampled the dispatch just synced, onto the open ``sched.step``:
+        ``"program"`` or ``"host"`` (which stays, where an iteration makes two
+        dispatches and one of them is the host's)."""
+        if how == "program":
+            self.sampled_in_program_total += 1
+        else:
+            self.sampled_on_host_total += 1
+        span = self._step_span
+        if span is not None and (how == "host" or "sampled" not in (span.attrs or {})):
+            span.set(sampled=how)
+
     def _launch(self, kind: str, decode: bool = False) -> StepSpan:
         """The ``sched.launch`` span of one program. A decode-family launch
         first books the decode host gap (see _record_host_gap)."""
@@ -1221,7 +1366,7 @@ class Scheduler:
                 span.set(slot=seq.state_slot)
                 self.flight.record_exec("open_slot", ())
                 self.cache.k, self.cache.v = self._open_slot_jit(
-                    self.cache.k, self.cache.v, jnp.int32(seq.block_ids[0]), jnp.int32(seq.state_slot)
+                    self.cache.k, self.cache.v, self._up(np.int32(seq.block_ids[0])), self._up(np.int32(seq.state_slot))
                 )
         finally:
             self._begin_plan()
@@ -1232,6 +1377,24 @@ class Scheduler:
         if seq.state_slot:
             self.slots.release(seq.state_slot)
             seq.state_slot = 0
+
+    def _read(self, tokens: jax.Array) -> np.ndarray:
+        """The dispatch's one blocking read-back: what the program sampled
+        and, for layer_types, the expert layer's counts of the same dispatch,
+        which ride the same transfer onto the open ``sched.step``."""
+        aux, self._step_aux = self._step_aux, None
+        if aux is None or self._step_span is None:
+            return jax.device_get(tokens)
+        sampled, held, visited = jax.device_get((tokens, aux["held_assignments"], aux["experts_visited"]))
+        attrs = self._step_span.attrs or {}
+        self._step_span.set(held_assignments=attrs.get("held_assignments", 0) + int(held),
+                            experts_visited=attrs.get("experts_visited", 0) + int(visited))
+        return sampled
+
+    def _read_step(self, tokens: jax.Array) -> np.ndarray:
+        """``_read`` of a single step's tokens, inside its ``sched.sample`` and ``sched.sync``."""
+        with self._span("sched.sample"), self._span("sched.sync"):
+            return self._read(tokens)
 
     def _note_aux(self) -> None:
         """The expert layer's counts of the dispatch just synced, onto the open
@@ -1294,7 +1457,7 @@ class Scheduler:
             self._note_step("eva_roll", (), (seq,))
             with self._launch("eva_roll"):
                 self.cache.k, self.cache.v = self._roll_jit(
-                    self.params, self.cache.k, self.cache.v, jnp.asarray(table), jnp.int32(row0)
+                    self.params, self.cache.k, self.cache.v, self._up(table), self._up(np.int32(row0))
                 )
             with self._span("sched.sync"):
                 # The program is short and rare; waiting for it here keeps its
@@ -1370,21 +1533,27 @@ class Scheduler:
         if key not in self._mixed_jits:
             model = self._model
             stats_kw = {"moe_stats": True} if self._moe_stats else {}
+            S, Wp = key[0], key[1]
+
+            def mixed_body(p, k, v, buf, dtab, **kw):
+                # [chunk tokens S | its length, its start | the rows' 3 x B lanes | the chunk's table Wp]
+                dt, dpos, dact = _unpack_rows(buf[S + 2 : -Wp].reshape(3, -1))
+                res = model.mixed_step(
+                    p, self.mc, k, v, buf[:S], buf[S], buf[S + 1], buf[-Wp:], dt, dpos, dtab, dact, **kw, **stats_kw
+                )
+                logits = res[0]  # [chunk's last row ; decode rows]
+                return (_greedy(logits), logits[:1], logits[1:]) + tuple(res[1:])
+
             if self._hp_static:
 
-                def mixed_step(p, k, v, pt, pv, cl, ptab, dt, dpos, dtab, dact, hp):
-                    return model.mixed_step(
-                        p, self.mc, k, v, pt, pv, cl, ptab, dt, dpos, dtab, dact,
-                        use_flash=True, has_prefix=hp, **stats_kw,
-                    )
+                def mixed_step(p, k, v, buf, dtab, hp):
+                    return mixed_body(p, k, v, buf, dtab, use_flash=True, has_prefix=hp)
 
-                self._mixed_jits[key] = jax.jit(mixed_step, donate_argnums=(1, 2), static_argnums=(11,))
+                self._mixed_jits[key] = jax.jit(mixed_step, donate_argnums=(1, 2), static_argnums=(5,))
             else:
 
-                def mixed_step(p, k, v, pt, pv, cl, ptab, dt, dpos, dtab, dact, hp):
-                    return model.mixed_step(
-                        p, self.mc, k, v, pt, pv, cl, ptab, dt, dpos, dtab, dact, **stats_kw
-                    )
+                def mixed_step(p, k, v, buf, dtab):
+                    return mixed_body(p, k, v, buf, dtab)
 
                 self._mixed_jits[key] = jax.jit(mixed_step, donate_argnums=(1, 2))
         return self._mixed_jits[key]
@@ -1425,6 +1594,9 @@ class Scheduler:
         p_tok = np.zeros((s_bucket,), dtype=np.int32)
         p_tok[: len(chunk_tokens)] = chunk_tokens
         has_prefix = seq.num_computed > 0
+        # Does this chunk end the prompt, so that its last row's token is the
+        # request's first? (A resume's final token re-enters through decode.)
+        samples = not resuming and seq.num_computed + len(chunk_tokens) >= len(pf_tokens)
 
         # Decode batch formation — identical to _decode_step (see there for
         # why max_running is NOT a term: dial shrinks must not strand rows).
@@ -1432,37 +1604,24 @@ class Scheduler:
         batch = self.running[:n]
         d_bucket = next_bucket(n, self.sc.decode_buckets)
         width = self._width_bucket(max(len(s.block_ids) for s in batch))
-        tokens = np.zeros((d_bucket,), dtype=np.int32)
-        positions = np.zeros((d_bucket,), dtype=np.int32)
-        active = np.zeros((d_bucket,), dtype=bool)
-        for i, s in enumerate(batch):
-            tokens[i] = s.all_ids[-1]
-            positions[i] = s.total_len - 1
-            active[i] = True
+        on_host = any(self._needs_host(s) for s in batch) or (samples and self._needs_host(seq))
+        p_table = self._prefill_table(seq)
+        buf = pack_operands(p_tok, len(chunk_tokens), seq.num_computed, self._pack_rows(batch, d_bucket), p_table)
 
         self._end_plan()
         with self._span("sched.upload") as upload:
-            p_table = self._prefill_table(seq)
             tables = self._decode_tables(batch, d_bucket, width)
-            p_tok_d, p_len_d, p_start_d = (
-                jnp.asarray(p_tok), jnp.int32(len(chunk_tokens)), jnp.int32(seq.num_computed)
-            )
-            tokens_d, positions_d, active_d = (
-                jnp.asarray(tokens), jnp.asarray(positions), jnp.asarray(active)
-            )
+            buf_d = self._up(buf)
             mixed_key = (s_bucket, int(p_table.shape[0]), d_bucket, width)
             exec_key = mixed_key + ((has_prefix,) if self._hp_static else ())
             self.flight.record_exec("mixed", exec_key)
             self._note_step("mixed", exec_key, batch, prefill=len(chunk_tokens), decode=n)
         with self._launch("mixed"):
             res = self._get_mixed_jit(mixed_key)(
-                self.params, self.cache.k, self.cache.v,
-                p_tok_d, p_len_d, p_start_d,
-                p_table, tokens_d, positions_d, tables,
-                active_d, has_prefix,
+                self.params, self.cache.k, self.cache.v, buf_d, tables,
+                *((has_prefix,) if self._hp_static else ()),
             )
-            logits, self.cache.k, self.cache.v = self._consume_aux(res)
-            decode_logits = logits[1:]
+            toks, chunk_logits, decode_logits, self.cache.k, self.cache.v = self._consume_aux(res)
         self.mixed_steps_total += 1
         self.mixed_prefill_tokens_total += len(chunk_tokens)
         self.mixed_decode_tokens_total += n
@@ -1470,7 +1629,13 @@ class Scheduler:
 
         # Decode rows first (output-order parity with the phase-separated
         # decode-then-admit iteration), then the chunk's progress.
-        self._finish_decode_rows(batch, d_bucket, decode_logits, outputs)
+        if on_host:
+            self._finish_decode_rows(batch, d_bucket, decode_logits, outputs)
+        else:
+            sampled = self._read_step(toks)  # the dispatch's one read-back: [chunk's last row ; decode rows]
+            self._step_counter += 2 if samples else 1  # (as the host path counts: a later draw finds the key it would have)
+            self._emit_decode_rows(batch, sampled[1:], outputs)
+        self._note_sampled("host" if on_host else "program")
         dur = self._since(upload)
         with self._span("sched.account"):
             # Mixed-step roofline split: the chunk's FLOPs/bytes land in the
@@ -1510,8 +1675,11 @@ class Scheduler:
             # re-enters via decode — nothing to sample or emit.
             seq.resume_tokens = None
         else:
-            with self._span("sched.sample"):
-                token = self._sample_one(seq, logits[0])
+            if on_host:
+                with self._span("sched.sample"):
+                    token = self._sample_one(seq, chunk_logits)
+            else:
+                token = int(sampled[0])
             with self._span("sched.emit"):
                 seq.first_token_ts = time.monotonic()
                 self._append_token(seq, token, outputs)
@@ -1702,7 +1870,7 @@ class Scheduler:
         self._end_plan()
         with self._span("sched.upload") as upload:
             tokens_d, pos0_d, valid_d, tables_d = (
-                jnp.asarray(tokens), jnp.asarray(pos0), jnp.asarray(valid), jnp.asarray(tables)
+                self._up(tokens), self._up(pos0), self._up(valid), self._up(tables)
             )
             wave_key = (b_bucket, s_bucket, width)
             self.flight.record_exec("admit", wave_key)
@@ -1715,12 +1883,13 @@ class Scheduler:
             lg, self.cache.k, self.cache.v = self._consume_aux(res)
         with self._span("sched.sample"):
             self._step_counter += 1
-            skey = jax.random.fold_in(self._rng, self._step_counter)
+            skey = self._key()
             res = self._sample_jit(
-                lg, jnp.asarray(temps), jnp.asarray(top_ks), jnp.asarray(top_ps), skey, None
+                lg, self._up(temps), self._up(top_ks), self._up(top_ps), skey, None
             )
             with self._span("sched.sync"):
                 sampled = np.asarray(res)  # the wave's ONE host sync
+        self._note_sampled("host")  # a wave keeps its sampler as a program of its own
         with self._span("sched.emit"):
             for i, seq in enumerate(admitted):
                 self.waiting.remove(seq)
@@ -1838,7 +2007,6 @@ class Scheduler:
         padded = np.zeros((bucket,), dtype=np.int32)
         padded[: len(tokens)] = tokens
         has_prefix = seq.num_computed > 0
-        mm_args = ()
         if seq.mm_features is not None:
             feats = seq.mm_features
             fb = 16
@@ -1848,31 +2016,35 @@ class Scheduler:
             padded_f[: feats.shape[0]] = feats
 
         t0 = time.monotonic() if self.sc.itl_budget_ms else None
+        # Does the chunk end the prompt (its last row's token is the request's first), and who samples it?
+        samples = not resuming and seq.num_computed + len(tokens) >= len(pf_tokens)
+        on_host = seq.mm_features is not None or (samples and self._needs_host(seq))  # (multimodal: no sampler in its program)
         self._end_plan()
         with self._span("sched.upload") as upload:
-            table = self._prefill_table(seq)
-            padded_d, len_d, start_d = (
-                jnp.asarray(padded), jnp.int32(len(tokens)), jnp.int32(seq.num_computed)
-            )
+            table = self._up(self._prefill_table(seq))
             if seq.mm_features is not None:
-                mm_args = (jnp.asarray(padded_f), jnp.int32(feats.shape[0]))
+                # The multimodal variant keeps its own operands (lazily built, off the text path).
+                args = (self._up(padded), self._up(np.int32(len(tokens))), self._up(np.int32(seq.num_computed)),
+                        table, has_prefix, self._up(padded_f), self._up(np.int32(feats.shape[0])))
                 kind, fn = "prefill_mm", self._prefill_mm_jit()
                 key = (bucket, int(table.shape[0]), fb, has_prefix)
                 self.flight.record_exec("prefill_mm", key)
             else:
-                # Shape key mirrors warmup(): on the XLA path has_prefix is a
-                # traced no-op arg (one executable serves both values).
+                buf = pack_operands(padded, len(tokens), seq.num_computed)
+                args = (self._up(buf), table) + ((has_prefix,) if self._hp_static else ())
+                # Shape key mirrors warmup(): has_prefix keys an executable only
+                # where it is static (_hp_static).
                 kind, fn = "prefill", self._prefill_jit
                 key = (bucket, int(table.shape[0]), has_prefix if self._hp_static else False)
                 self.flight.record_exec("prefill", key)
             self._note_step(kind, key, (seq,), prefill=len(tokens))
         with self._launch(kind):
-            res = fn(
-                self.params, self.cache.k, self.cache.v,
-                padded_d, len_d, start_d,
-                table, has_prefix, *mm_args,
-            )
-            logits, self.cache.k, self.cache.v = self._consume_aux(res)
+            res = fn(self.params, self.cache.k, self.cache.v, *args)
+            if seq.mm_features is not None:
+                logits, self.cache.k, self.cache.v = self._consume_aux(res)
+                logits = logits[None, :]
+            else:
+                tok, logits, self.cache.k, self.cache.v = self._consume_aux(res)
         dur = self._since(upload)
         seq.prefill_chunks += 1
         with self._span("sched.account"):
@@ -1908,13 +2080,20 @@ class Scheduler:
                 self._register_full_blocks(seq)
                 self._trace_event(seq, "resume", total_len=seq.total_len)
         if not done or resuming:
+            self._note_sampled("host" if on_host else "program")  # (nothing to read: the program's lane is left)
             self._begin_plan()
             return done  # False: more chunks to go
 
-        # Prompt fully computed: sample the first token.
-        with self._span("sched.sample"):
-            token = self._sample_one(seq, logits)
-            self._note_aux()
+        # Prompt fully computed: its first token, sampled by the program or, for
+        # a row that needs the host between logits and token, by _sample_one.
+        if on_host:
+            with self._span("sched.sample"):
+                token = self._sample_one(seq, logits)
+                self._note_aux()
+        else:
+            token = int(self._read_step(tok)[0])
+            self._step_counter += 1
+        self._note_sampled("host" if on_host else "program")
         with self._span("sched.emit"):
             seq.first_token_ts = time.monotonic()
             seq.state = SeqState.RUNNING
@@ -1958,11 +2137,9 @@ class Scheduler:
         if cm is None:
             return
         try:
-            tpa = jnp.zeros((3, bucket), jnp.int32)
+            buf = jnp.zeros((3 * bucket,), jnp.int32)
             tables = jnp.zeros((bucket, width), jnp.int32)
-            compiled = self._decode_jit.lower(
-                self.params, self.cache.k, self.cache.v, tpa, tables
-            ).compile()
+            compiled = self._decode_jit.lower(self.params, self.cache.k, self.cache.v, buf, tables).compile()
             cost = compiled.cost_analysis()
             if isinstance(cost, (list, tuple)):
                 cost = cost[0] if cost else {}
@@ -1989,35 +2166,27 @@ class Scheduler:
         max_w = self._width_bucket((ctx_tokens + bs - 1) // bs)
         widths = sorted(set(min(r, self.max_blocks_per_seq) for r in width_rungs(max_w)))
         count = 0
-        key = jax.random.PRNGKey(0)
+        key = self._rng
         # Ask XLA for the decode executable's own FLOPs count before the
         # first dispatch of the same shape compiles it for real.
         self._calibrate_cost_model(self.sc.decode_buckets[0], widths[0])
         for bucket in self.sc.decode_buckets:
             for width in widths:
-                toks = jnp.zeros((bucket,), jnp.int32)
-                pos = jnp.zeros((bucket,), jnp.int32)
-                tpa = jnp.zeros((3, bucket), jnp.int32)
                 tables = jnp.zeros((bucket, width), jnp.int32)
-                active = jnp.zeros((bucket,), bool)
-                temps = jnp.zeros((bucket,), jnp.float32)
-                tks = jnp.zeros((bucket,), jnp.int32)
-                tps = jnp.ones((bucket,), jnp.float32)
                 self.flight.record_exec("decode", (bucket, width))
-                logits, self.cache.k, self.cache.v = self._consume_aux(
+                _, _, self.cache.k, self.cache.v = self._consume_aux(  # (all rows inactive)
                     self._decode_jit(
-                        self.params, self.cache.k, self.cache.v, tpa, tables
+                        self.params, self.cache.k, self.cache.v, jax.device_put(pack_operands(self._pack_rows([], bucket))), tables
                     )
                 )
                 count += 1
                 if self.sc.num_scheduler_steps > 1 and self._supports_multi_step:
+                    # (All rows inactive and greedy, a key of zeros.)
+                    buf = jax.device_put(pack_operands(self._pack_rows([], bucket, sampler=True), (0, 0)))
                     for w, mjit in self._decode_multi_jits.items():
                         self.flight.record_exec("decode_multi", (w, bucket, width))
                         _, self.cache.k, self.cache.v = self._consume_aux(
-                            mjit(
-                                self.params, self.cache.k, self.cache.v, toks, pos, tables,
-                                active, temps, tks, tps, key,
-                            )
+                            mjit(self.params, self.cache.k, self.cache.v, buf, tables)
                         )
                         count += 1
             self._sample_jit(
@@ -2039,7 +2208,14 @@ class Scheduler:
                 jnp.zeros((bucket,), jnp.float32), jnp.zeros((bucket,), jnp.int32),
                 jnp.ones((bucket,), jnp.float32), key, None,
             )
-            count += 3
+            # ... and the keys of a batch that holds a seeded sampled row.
+            make_row_keys(key, jnp.zeros((bucket,), jnp.int32), jnp.zeros((bucket,), jnp.int32), jnp.zeros((bucket,), bool))
+            count += 4
+        # The host path's first-token logprobs (its sampler at one row is warmed below).
+        tok = jnp.zeros((1,), jnp.int32)
+        self._lp_jit(jnp.zeros((1, self.mc.vocab_size), jnp.float32), tok)
+        self._tlp_jit(jnp.zeros((1, self.mc.vocab_size), jnp.float32), tok)
+        count += 2
         if self._hybrid:
             # Taking a slot: one executable, warmed on the scratch slot and block.
             self.flight.record_exec("open_slot", ())
@@ -2116,18 +2292,15 @@ class Scheduler:
                 if r >= min_w
             ))
             for width in p_widths:
-                # Both has_prefix variants: fresh prefills AND chunked/
-                # prefix-hit continuations. (On the XLA path hp is a traced
-                # no-op arg, so the second call is a cache hit.)
-                for hp in (False, True):
-                    self.flight.record_exec(
-                        "prefill", (bucket, width, hp if self._hp_static else False)
-                    )
-                    _, self.cache.k, self.cache.v = self._consume_aux(
+                # Where has_prefix is static (_hp_static) both variants: fresh
+                # prefills AND chunked / prefix-hit continuations.
+                for hp in (False, True) if self._hp_static else (False,):
+                    self.flight.record_exec("prefill", (bucket, width, hp))
+                    _, _, self.cache.k, self.cache.v = self._consume_aux(
                         self._prefill_jit(
                             self.params, self.cache.k, self.cache.v,
-                            jnp.zeros((bucket,), jnp.int32), jnp.int32(1), jnp.int32(0),
-                            jnp.zeros((width,), jnp.int32), hp,
+                            jax.device_put(pack_operands(np.zeros((bucket,), np.int32), 1, 0)),  # one valid token at position 0
+                            jnp.zeros((width,), jnp.int32), *((hp,) if self._hp_static else ()),
                         )
                     )
                     count += 1
@@ -2205,13 +2378,13 @@ class Scheduler:
                         )
                         res = self._get_mixed_jit((s_b, p_w, bucket, width))(
                             self.params, self.cache.k, self.cache.v,
-                            jnp.zeros((s_b,), jnp.int32), jnp.int32(1), jnp.int32(0),
-                            jnp.zeros((p_w,), jnp.int32), jnp.zeros((bucket,), jnp.int32),
-                            jnp.zeros((bucket,), jnp.int32),
+                            jax.device_put(pack_operands(
+                                np.zeros((s_b,), np.int32), 1, 0, self._pack_rows([], bucket), np.zeros((p_w,), np.int32)
+                            )),
                             jnp.zeros((bucket, width), jnp.int32),
-                            jnp.zeros((bucket,), bool), hp,
+                            *((hp,) if self._hp_static else ()),
                         )
-                        _, self.cache.k, self.cache.v = self._consume_aux(res)
+                        _, _, _, self.cache.k, self.cache.v = self._consume_aux(res)
                         count += 1
         # Speculative-round executables (draft chunk+sample, γ-1 proposal
         # window, target chunk scoring, rejection verify): _decode_spec keys
@@ -2288,7 +2461,7 @@ class Scheduler:
                 _, self.draft_cache.k, self.draft_cache.v = self._d_prefill_jit(
                     self.draft_params, self.draft_cache.k, self.draft_cache.v,
                     jnp.asarray(padded), jnp.int32(len(toks)), jnp.int32(start),
-                    self._prefill_table(seq),
+                    self._up(self._prefill_table(seq)),
                 )
             seq.d_n += len(toks)
 
@@ -2315,7 +2488,7 @@ class Scheduler:
         tables = np.zeros((bucket, width), dtype=np.int32)
         for i, s in enumerate(batch):
             tables[i, : len(s.block_ids)] = s.block_ids
-        dev = jnp.asarray(tables)
+        dev = self._up(tables)
         self._tables_cache = (key, blocks, dev)
         return dev
 
@@ -2341,21 +2514,9 @@ class Scheduler:
         batch = self.running[:n]
         bucket = next_bucket(n, self.sc.decode_buckets)
 
-        # Rows that need the host between their tokens: penalties (history
-        # mutates from token to token), logits processors, logprobs /
-        # top_logprobs, guided rows (the FSM advances on the host; proposal
-        # sampling ignores its mask) and seeded sampled rows (neither a spec
-        # round nor decode_multi threads per-row keys; a greedy row's seed is
-        # a no-op). One such row takes its whole batch to single steps.
-        host_between_tokens = any(
-            seq.sampling.logits_processors
-            or seq.sampling.logprobs
-            or seq.sampling.top_logprobs
-            or seq.sampling.has_penalties
-            or seq.guided is not None
-            or (seq.sampling.seed is not None and seq.sampling.temperature > 0)
-            for seq in batch
-        )
+        # One row that needs the host between its tokens takes its whole batch
+        # to single steps: neither a spec round nor decode_multi has it there.
+        host_between_tokens = any(self._host_between_tokens(seq) for seq in batch)
         # Each falls through to the next when blocks/limits don't allow it.
         if (
             self.draft_params is not None
@@ -2379,23 +2540,25 @@ class Scheduler:
         # them all.
         width = self._width_bucket(max(len(seq.block_ids) for seq in batch))
 
-        tpa = np.zeros((3, bucket), dtype=np.int32)
-        for i, seq in enumerate(batch):
-            tpa[0, i] = seq.all_ids[-1]
-            tpa[1, i] = seq.total_len - 1  # write slot of the current token
-            tpa[2, i] = 1
+        buf = pack_operands(self._pack_rows(batch, bucket))
+        on_host = any(self._needs_host(seq) for seq in batch)
 
         self._end_plan()
         with self._span("sched.upload") as upload:
             tables = self._decode_tables(batch, bucket, width)
-            tpa_d = jnp.asarray(tpa)
+            buf_d = self._up(buf)
             exec_key = (bucket, width)
             self.flight.record_exec("decode", exec_key)
             self._note_step("decode", exec_key, batch, decode=len(batch))
         with self._launch("decode", decode=True):
-            res = self._decode_jit(self.params, self.cache.k, self.cache.v, tpa_d, tables)
-            logits, self.cache.k, self.cache.v = self._consume_aux(res)
-        self._finish_decode_rows(batch, bucket, logits, outputs)
+            res = self._decode_jit(self.params, self.cache.k, self.cache.v, buf_d, tables)
+            toks, logits, self.cache.k, self.cache.v = self._consume_aux(res)
+        if on_host:
+            self._finish_decode_rows(batch, bucket, logits, outputs)
+        else:
+            self._step_counter += 1
+            self._emit_decode_rows(batch, self._read_step(toks), outputs)  # the step's one read-back
+        self._note_sampled("host" if on_host else "program")
         dur = self._since(upload)
         with self._span("sched.account"):
             self.flight.record_step(
@@ -2410,11 +2573,13 @@ class Scheduler:
     def _finish_decode_rows(
         self, batch: List[Sequence], bucket: int, logits: jax.Array, outputs: List[tuple]
     ) -> None:
-        """Post-dispatch half of a single decode step: penalties, logits
-        processors, sampling (with per-request seeds), logprobs, and token
-        append/stop handling. Shared by _decode_step and _mixed_step — the
-        decode rows of a mixed dispatch carry the same per-row [B, V]
-        logits a plain decode step produces."""
+        """The HOST path of a single decode step, for a batch that holds a row
+        which needs the host between its logits and its token (_needs_host):
+        penalties, logits processors, sampling (with per-request seeds),
+        logprobs, and token append/stop handling, on the logits the step
+        program returned beside its own (then unread) tokens. Shared by
+        _decode_step and _mixed_step — the decode rows of a mixed dispatch
+        carry the same per-row [B, V] logits a plain decode step produces."""
         from dynamo_tpu.engine.sampling import pack_param_rows
 
         with self._span("sched.sample"):
@@ -2432,19 +2597,17 @@ class Scheduler:
                 from dynamo_tpu.logits_processing import apply_chain
 
                 proc_rows = [i for i, seq in enumerate(batch) if seq.sampling.logits_processors]
-                sel = jnp.asarray(np.asarray(proc_rows, dtype=np.int32))
+                sel = self._up(np.asarray(proc_rows, dtype=np.int32))
                 sub = np.array(logits[sel])  # [n_proc, V] writable host copy
                 for j, i in enumerate(proc_rows):
                     sub[j] = np.asarray(
                         apply_chain(batch[i].sampling.logits_processors, batch[i].output_ids, jnp.asarray(sub[j]))
                     )
-                logits = logits.at[sel].set(jnp.asarray(sub))
+                logits = logits.at[sel].set(self._up(sub))
             self._step_counter += 1
-            key = jax.random.fold_in(self._rng, self._step_counter)
+            key = self._key()
             row_keys = None
             if any(seq.sampling.seed is not None for seq in batch):
-                from dynamo_tpu.engine.sampling import make_row_keys
-
                 seeds = np.zeros((bucket,), dtype=np.int32)
                 poss_out = np.zeros((bucket,), dtype=np.int32)
                 has_seed = np.zeros((bucket,), dtype=bool)
@@ -2454,7 +2617,7 @@ class Scheduler:
                         poss_out[i] = len(seq.output_ids)
                         has_seed[i] = True
                 row_keys = make_row_keys(
-                    key, jnp.asarray(seeds), jnp.asarray(poss_out), jnp.asarray(has_seed)
+                    key, self._up(seeds), self._up(poss_out), self._up(has_seed)
                 )
             temps, top_ks, top_ps = pack_param_rows([s.sampling for s in batch], bucket)
             # Logprobs fold into the SAME sampling dispatch when any row wants
@@ -2485,8 +2648,8 @@ class Scheduler:
                     else self._guided_sample_jit
                 )
                 res = guided_jit(
-                    logits, pool, jnp.asarray(k_rows),
-                    jnp.asarray(temps), jnp.asarray(top_ps), key, row_keys,
+                    logits, pool, self._up(k_rows),
+                    self._up(temps), self._up(top_ps), key, row_keys,
                 )
             else:
                 sample_jit = (
@@ -2495,7 +2658,7 @@ class Scheduler:
                     else self._sample_jit
                 )
                 res = sample_jit(
-                    logits, jnp.asarray(temps), jnp.asarray(top_ks), jnp.asarray(top_ps), key, row_keys
+                    logits, self._up(temps), self._up(top_ks), self._up(top_ps), key, row_keys
                 )
             # The step's blocking read-back: the device runs the step program and
             # the sampler while the host waits here.
@@ -2508,6 +2671,13 @@ class Scheduler:
                     sampled = np.asarray(res)
                 self._note_aux()
 
+        self._emit_decode_rows(batch, sampled, outputs, logprobs_np, top_ids_np, top_lps_np)
+
+    def _emit_decode_rows(
+        self, batch: List[Sequence], sampled, outputs: List[tuple], logprobs_np=None, top_ids_np=None, top_lps_np=None
+    ) -> None:
+        """Append each live row's token of a single decode step (read back by
+        the caller), growing its table for the next one."""
         with self._span("sched.emit"):
             for i, seq in enumerate(batch):
                 if seq.state != SeqState.RUNNING:
@@ -2582,17 +2752,6 @@ class Scheduler:
         # roll comes first).
         taken = [min(steps, self._window_room(seq.total_len - 1)) if self._eva else steps for seq in batch]
 
-        from dynamo_tpu.engine.sampling import pack_param_rows
-
-        tokens = np.zeros((bucket,), dtype=np.int32)
-        positions = np.zeros((bucket,), dtype=np.int32)
-        active = np.zeros((bucket,), dtype=bool)
-        temps, top_ks, top_ps = pack_param_rows([s.sampling for s in batch], bucket)
-        for i, seq in enumerate(batch):
-            tokens[i] = seq.all_ids[-1]
-            positions[i] = seq.total_len - 1
-            active[i] = True
-
         exec_key = (steps, bucket, width)
         self.flight.record_exec("decode_multi", exec_key)
         self._note_step("decode_multi", exec_key, batch, decode=sum(taken))
@@ -2604,28 +2763,18 @@ class Scheduler:
             self._step_span.set(live_steps=max(taken),
                                 attended_sum=sum(t * r + t * (t - 1) // 2 for t, r in zip(taken, kv_rows)))
         n0 = len(outputs)
+        self._step_counter += 1
+        buf = pack_operands(self._pack_rows(batch, bucket, sampler=True), fold_key(self._rng_words, self._step_counter))
         self._end_plan()
         with self._span("sched.upload") as upload:
-            self._step_counter += 1
-            key = jax.random.fold_in(self._rng, self._step_counter)
             tables = self._decode_tables(batch, bucket, width)
-            tokens_d, positions_d, active_d = (
-                jnp.asarray(tokens), jnp.asarray(positions), jnp.asarray(active)
-            )
-            temps_d, top_ks_d, top_ps_d = (
-                jnp.asarray(temps), jnp.asarray(top_ks), jnp.asarray(top_ps)
-            )
+            buf_d = self._up(buf)
         with self._launch("decode_multi", decode=True):
-            res = self._decode_multi_jits[steps](
-                self.params, self.cache.k, self.cache.v,
-                tokens_d, positions_d, tables,
-                active_d, temps_d, top_ks_d,
-                top_ps_d, key,
-            )
+            res = self._decode_multi_jits[steps](self.params, self.cache.k, self.cache.v, buf_d, tables)
             toks_out, self.cache.k, self.cache.v = self._consume_aux(res)
         with self._span("sched.sync"):
-            sampled = np.asarray(toks_out)  # [steps, bucket] — the one host sync
-            self._note_aux()
+            sampled = self._read(toks_out)  # [steps, bucket] — the one host sync
+        self._note_sampled("program")
         with self._span("sched.emit"):
             for i, seq in enumerate(batch):
                 for s in range(taken[i]):
@@ -2699,7 +2848,7 @@ class Scheduler:
         # Draft: catch-up chunk + SAMPLED first proposal (+ its dist), then
         # γ-1 sampled window steps with per-step logits.
         self._step_counter += 1
-        key = jax.random.fold_in(self._rng, self._step_counter)
+        key = self._key()
         with self._launch("spec_draft_chunk"):
             tok1, lg1, self.draft_cache.k, self.draft_cache.v = self._d_chunk_sample_jit(
                 self.draft_params, self.draft_cache.k, self.draft_cache.v,
@@ -2717,7 +2866,7 @@ class Scheduler:
             act[i] = True
         if gamma > 1:
             self._step_counter += 1
-            key2 = jax.random.fold_in(self._rng, self._step_counter)
+            key2 = self._key()
             with self._launch("spec_draft_multi"):
                 toks_out, lg_steps, self.draft_cache.k, self.draft_cache.v = self._d_multi_jit(
                     self.draft_params, self.draft_cache.k, self.draft_cache.v,
@@ -2751,7 +2900,7 @@ class Scheduler:
 
         # Rejection-sampling verification (greedy rows: exact argmax check).
         self._step_counter += 1
-        vkey = jax.random.fold_in(self._rng, self._step_counter)
+        vkey = self._key()
         with self._launch("spec_verify"):
             accepted, next_tok = self._spec_verify_jit(
                 draft_logits, t_logits, jnp.asarray(proposals), temps_j, tks_j, tps_j, vkey
@@ -2982,18 +3131,18 @@ class Scheduler:
             self._mm_jit = jax.jit(prefill_mm, donate_argnums=(1, 2), static_argnums=(7,))
         return self._mm_jit
 
-    def _prefill_table(self, seq: Sequence) -> jnp.ndarray:
+    def _prefill_table(self, seq: Sequence) -> np.ndarray:
         """Prefill block table bucketed to a power-of-two width covering the
         sequence's blocks — NOT padded to max_blocks_per_seq. The prefill
         prefix gather/mask is O(width·block_size), so a 2K prompt must not
         pay for a 128K max_seq_len (measured: the dominant prefill cost at
         1B on v5e before this). Rung widths (see width_rungs) bound the
         executable count at 2·log2(max_blocks) variants per prefill
-        bucket."""
+        bucket. A host array: a mixed step packs it with its other operands."""
         w = max(16, width_bucket(len(seq.block_ids), self.max_blocks_per_seq))
         table = np.zeros((w,), dtype=np.int32)
         table[: len(seq.block_ids)] = seq.block_ids
-        return jnp.asarray(table)
+        return table
 
     def _ensure_block_capacity(self, seq: Sequence) -> None:
         """Grow the block table if the *next* token would overflow it.
@@ -3076,67 +3225,61 @@ class Scheduler:
             freq[i] = seq.sampling.frequency_penalty
             pres[i] = seq.sampling.presence_penalty
         return apply_penalties(
-            logits, jnp.asarray(hist), jnp.asarray(hist_len), jnp.asarray(freq), jnp.asarray(pres)
+            logits, self._up(hist), self._up(hist_len), self._up(freq), self._up(pres)
         )
 
-    def _row_key(self, seq: Sequence) -> jax.Array:
-        """Per-row PRNG key. Seeded requests fold the per-request position
-        (same seed + prompt ⇒ same samples, whatever the batch around them);
-        unseeded rows fold the global step counter."""
-        if seq.sampling.seed is not None:
-            return jax.random.fold_in(jax.random.PRNGKey(seq.sampling.seed), len(seq.output_ids))
-        return jax.random.fold_in(self._rng, self._step_counter)
+    def _key(self, seq: Optional[Sequence] = None) -> jax.Array:
+        """The key of the step just counted (the engine's key folded with
+        ``_step_counter``) or, for a seeded ``seq``, its own: its seed folded
+        with its output position (same seed + prompt ⇒ same samples, whatever
+        the batch around them). Folded on the host (fold_key) and sent up: an
+        eager ``fold_in`` builds its programs on the step thread."""
+        if seq is not None and seq.sampling.seed is not None:
+            words = fold_key((0, seq.sampling.seed & _M32), len(seq.output_ids))  # PRNGKey(seed) is [0, seed]
+        else:
+            words = fold_key(self._rng_words, self._step_counter)
+        return self._up(np.asarray(words, dtype=np.uint32))
 
     def _sample_one(self, seq: Sequence, logits: jax.Array) -> int:
+        """The host path's first token of ``seq`` from its prompt's last
+        logits ``[1, V]`` (a row that needs the host between logits and token:
+        _needs_host; or the multimodal prefill)."""
         self._step_counter += 1
         s = seq.sampling
         if s.logits_processors:
             from dynamo_tpu.logits_processing import apply_chain
 
-            logits = apply_chain(s.logits_processors, seq.output_ids, logits)
+            # (User code on the host: its programs are built when first met.)
+            logits = apply_chain(s.logits_processors, seq.output_ids, logits[0])[None, :]
+        temp_d, top_p_d = self._up(np.asarray([s.temperature], np.float32)), self._up(np.asarray([s.top_p], np.float32))
         if seq.guided is not None:
             # First token after prefill: same fused mask+sample executable
             # as the batched path at bucket 1.
             pool = self.guided.pool.device()
             self.flight.record_exec("guided_sample", (1, int(pool.shape[0])))
             tok = self._guided_sample_jit(
-                logits[None, :], pool,
-                jnp.asarray([[s.top_k], [seq.guided.row_id]], dtype=jnp.int32),
-                jnp.asarray([s.temperature], dtype=jnp.float32),
-                jnp.asarray([s.top_p], dtype=jnp.float32),
-                self._row_key(seq),
+                logits, pool, self._up(np.asarray([[s.top_k], [seq.guided.row_id]], np.int32)),
+                temp_d, top_p_d, self._key(seq), None,  # (row_keys spelled out, as warmup spells it: the same executable)
             )
         else:
             tok = self._sample_jit(
-                logits[None, :],
-                jnp.asarray([s.temperature], dtype=jnp.float32),
-                jnp.asarray([s.top_k], dtype=jnp.int32),
-                jnp.asarray([s.top_p], dtype=jnp.float32),
-                self._row_key(seq),
+                logits, temp_d, self._up(np.asarray([s.top_k], np.int32)), top_p_d, self._key(seq), None,
             )
         with self._span("sched.sync"):
-            token = int(np.asarray(tok)[0])
+            token = int(jax.device_get(tok)[0])
         if s.top_logprobs:
             # First token's alternatives: same op group as the batched
             # top-k path (guided rows already applied their mask above via
             # the fused sampler; these logprobs are of the raw logits the
             # single-row sampler saw).
-            from dynamo_tpu.engine.sampling import compute_topk_logprobs
-
-            chosen, ids, lps = jax.device_get(
-                compute_topk_logprobs(logits[None, :], jnp.asarray([token]))
-            )
+            chosen, ids, lps = jax.device_get(self._tlp_jit(logits, tok))
             seq._pending_logprob = float(chosen[0])
             k = min(s.top_logprobs, ids.shape[1])
             seq._pending_top_logprobs = [
                 (int(ids[0, j]), float(lps[0, j])) for j in range(k)
             ]
         elif s.logprobs:
-            from dynamo_tpu.engine.sampling import compute_logprobs
-
-            seq._pending_logprob = float(
-                np.asarray(compute_logprobs(logits[None, :], jnp.asarray([token])))[0]
-            )
+            seq._pending_logprob = float(jax.device_get(self._lp_jit(logits, tok))[0])
         return token
 
     def _append_token(

@@ -276,17 +276,18 @@ def test_step_programs_are_named_after_their_kind():
     k, v, p = sched.cache.k, sched.cache.v, sched.params
     b, w = 2, 4
     i32 = jnp.int32
-    toks, pos, act = jnp.zeros((b,), i32), jnp.zeros((b,), i32), jnp.zeros((b,), bool)
-    tpa, tables = jnp.zeros((3, b), i32), jnp.zeros((b, w), i32)
+    pos, tables = jnp.zeros((b,), i32), jnp.zeros((b, w), i32)
     temps, tks, tps = jnp.zeros((b,), jnp.float32), jnp.zeros((b,), i32), jnp.ones((b,), jnp.float32)
     key = jax.random.PRNGKey(0)
-    chunk, ptab = jnp.zeros((16,), i32), jnp.zeros((16,), i32)
+    # The packed operands of a dispatch (scheduler.pack_operands): a batch's three lanes (a window's six and its key); a
+    # chunk's tokens, its length and its start; in a mixed step the chunk, the batch's lanes and the chunk's table.
+    chunk, ptab = jnp.zeros((16 + 2,), i32), jnp.zeros((16,), i32)
     programs = {
-        "prefill": (sched._prefill_jit, (p, k, v, chunk, i32(1), i32(0), ptab, False)),
-        "decode": (sched._decode_jit, (p, k, v, tpa, tables)),
-        "decode_multi_w8": (sched._decode_multi_jits[8], (p, k, v, toks, pos, tables, act, temps, tks, tps, key)),
+        "prefill": (sched._prefill_jit, (p, k, v, chunk, ptab)),
+        "decode": (sched._decode_jit, (p, k, v, jnp.zeros((3 * b,), i32), tables)),
+        "decode_multi_w8": (sched._decode_multi_jits[8], (p, k, v, jnp.zeros((6 * b + 2,), i32), tables)),
         "mixed_step": (sched._get_mixed_jit((16, 16, b, w)),
-                       (p, k, v, chunk, i32(1), i32(0), ptab, toks, pos, tables, act, False)),
+                       (p, k, v, jnp.zeros((16 + 2 + 3 * b + 16,), i32), tables)),
         "admit_wave": (sched._get_admit_jit((b, 16, w)),
                        (p, k, v, jnp.zeros((b, 16), i32), pos, pos, tables)),
         "kv_block_copy": (sched._kv_copy_jit, (k, v, i32(0), i32(0))),
