@@ -9,15 +9,20 @@ upstream TPU kernel, ``jax.experimental.pallas.ops.tpu.ragged_paged_attention``,
 for the tiling — its pool interleaves K and V and is not ours.)
 
 ``ragged_paged_attention``: a row is a ``(start, len)`` run of queries over
-``[paged prefix ; fresh keys]``. Decode entries are length-1 rows and walk
-the grid ``(query, page)``; a prefill chunk is one wide row and walks
-``(tile of queries, page)`` — ``tile`` (``chunk_tile``) consecutive queries
-share a grid row, so one page fetch, one score dot and one value dot a
-page serve all of them, and the fresh keys are one step a tile with the
-causal frontier taken from the tile's first query. One kernel body,
-parameterised by the static tile. ``decode`` and ``decode_multi`` launch it
-once a layer, ``prefill`` once (tiled), ``mixed_step`` twice: the chunk's
-queries and the decode rows are disjoint outputs, so nothing merges. With
+``[paged prefix ; fresh keys]``. Decode entries are length-1 rows and walk a
+*work list* (``build_work``): one grid step a page that holds part of a live
+row's prefix, the last of a row's also closing it (its fresh keys, the
+normalisation, its output block), the count of them the grid's traced
+bound — a padded row of the batch bucket, and a table slot past a row's
+prefix, is no step at all; a prefill chunk is one wide row and walks the
+static grid ``(tile of queries, page)`` — ``tile`` (``chunk_tile``)
+consecutive queries share a grid row, so one page fetch, one score dot and
+one value dot a page serve all of them, and the fresh keys are one step a
+tile with the causal frontier taken from the tile's first query. One kernel
+body, parameterised by the static tile and by where it reads its step from.
+``decode`` and ``decode_multi`` launch it once a layer, ``prefill`` once
+(tiled), ``mixed_step`` twice: the chunk's queries and the decode rows are
+disjoint outputs, so nothing merges. With
 
 - *scalar-prefetched block tables* (the page fetch is a plain BlockSpec
   whose index_map reads the table; Pallas double-buffers the HBM→VMEM
@@ -28,10 +33,13 @@ queries and the decode rows are disjoint outputs, so nothing merges. With
   tile, which is not short of MXU rows, dots inside a *lane group* of whole
   KV heads (``lane_fold``: one head from a head size of 128) — no fold
   FLOPs, no ×KVH query bytes,
-- ``pl.when`` skipping for dead slots: padded queries, wholly padded tiles
-  and table slots past a row's true length cost no compute (and no page
-  fetch beyond the scratch page), so ragged batches cost bytes, not bucket
-  width,
+- no step for what is dead: a rows launch lists its live pages only, so a
+  ragged batch costs its pages, not bucket x table width (on a v5e an empty
+  grid step is 0.14 µs of pipeline bookkeeping, and a padded row 0.6 µs more:
+  its 64 KB query and output blocks, its state, its fresh-key dots); a
+  chunk's padded queries, wholly padded tiles and table slots past its
+  prefix are ``pl.when``-skipped steps of its short static grid (no compute,
+  no page fetch beyond the scratch page),
 - an int8-KV dequant-in-VMEM path (per-(token, head) scales streamed
   alongside the int8 codes and expanded over lanes in-kernel; a tile
   dequantises a page once for all its queries), so capacity-mode
@@ -41,7 +49,11 @@ On a v5e (tools/attn_chunk_bench.py, PERF.md §6 PR 31): 256 chunk queries
 of 32/8 heads of 128 beside 32 decode rows of 12 pages took 3,576–4,034 µs a
 layer as one (query, page) walk; the chunk at a tile of 256 takes 43–146 µs
 (prefix 0–1,408) and the rows' launch 456. Folded over all KV heads a
-tile of 32 took 340–650.
+tile of 32 took 340–650. The rows' launch (``--study rows``, PERF.md §6 PR
+38; a window's last step, 9 fresh keys a row): 5 live rows of 4 pages in a
+bucket of 32 took 94–110 µs as the walk of ``32 x (width + 1)`` steps and
+take 37 as the list of their 20; a full batch (32 rows of 12 pages) 395 and
+369.
 """
 
 from __future__ import annotations
@@ -50,6 +62,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
@@ -81,6 +94,54 @@ def build_meta(
             active.astype(jnp.int32),
         ]
     )
+
+
+# A work item: row << 16 | table slot.
+_ROW_SHIFT = 16
+_SLOT_MASK = (1 << _ROW_SHIFT) - 1
+
+
+def build_work(prefix_len: jax.Array, active: jax.Array, num_slots: int, block_size: int) -> jax.Array:
+    """The work list of a length-1 rows launch (``prefix_len`` ``[NQ]`` i32
+    and ``active`` ``[NQ]`` bool as ``build_meta`` takes them), ``[1 + NQ *
+    (W + 1)]`` i32: the count of live items, then the items, a live row's
+    after the live row before it — one a page that holds part of its prefix,
+    table slot 0, 1, ... (slot 0 alone for a row with no prefix). The launch's
+    grid is the count: a dead row, and a table slot past a row's prefix, is no
+    step at all; a row's last item also closes it (its fresh keys, the
+    normalisation, its output block). Prefixes and liveness are a step's, not
+    a layer's: build it once a step program, outside the layer scan. With no
+    live row the count is 1 and the one item is row 0's slot 0, which is dead
+    and reads nothing."""
+    NQ, W = prefix_len.shape[0], num_slots
+    assert NQ < 1 << (31 - _ROW_SHIFT) and W < _SLOT_MASK, (NQ, W)
+    # A dozen and a half primitives bound directly: an operator on a traced
+    # array, an index or a jax.numpy function is a jitted helper traced anew
+    # at every new shape, every primitive is lowered anew at every new shape,
+    # and a warm set-up traces and lowers this once a step program (PERF.md
+    # section 6, PR 38).
+    cells, past = (NQ, W + 1), NQ * (W + 1)
+    over = functools.partial(lax.broadcast_in_dim, shape=cells, broadcast_dimensions=(0,))
+
+    def full(value, shape=(NQ,)):
+        return np.full(shape, value, np.int32)
+
+    if active.dtype != jnp.bool_:
+        active = lax.ne(active, lax.full_like(active, 0))
+    pages = lax.min(lax.div(lax.add(prefix_len, full(block_size - 1)), full(block_size)), full(W))
+    counts = lax.select(active, lax.max(pages, full(1)), full(0))
+    ends = lax.cumsum(counts)
+    # Slot j of row r for every (r, j) of the bucket: a row's first counts[r]
+    # land at its start + j, the rest past the list's end, where they drop.
+    j = np.broadcast_to(np.arange(W + 1, dtype=np.int32), cells)
+    items = (np.arange(NQ, dtype=np.int32)[:, None] << _ROW_SHIFT) | j
+    place = lax.select(lax.lt(j, over(counts)), lax.add(over(lax.sub(ends, counts)), j), full(past, cells))
+    listed = lax.scatter(
+        full(0, (past,)), lax.reshape(place, (past, 1)), items.reshape(past),
+        lax.ScatterDimensionNumbers(update_window_dims=(), inserted_window_dims=(0,), scatter_dims_to_operand_dims=(0,)),
+        mode=lax.GatherScatterMode.FILL_OR_DROP,
+    )
+    return lax.concatenate([lax.max(lax.slice(ends, (NQ - 1,), (NQ,)), full(1, (1,))), listed], 0)
 
 
 def lane_fold(num_kv_heads: int, head_dim: int) -> int:
@@ -150,12 +211,7 @@ def _online_update(m_ref, l_ref, acc_ref, rows, s, v):
 def _mega_kernel(
     tables_ref,  # SMEM [R, W] i32 — per-row page ids (layer-offset, dead → 0)
     meta_ref,  # SMEM [5, NT] i32 — build_meta layout, one column a grid row
-    wq_ref,  # VMEM [1, groups*rows, lanes] — this grid row's queries, folded
-    ke_ref,  # VMEM [CK, KVHD] — ALL fresh keys (lane-merged), loaded once
-    ve_ref,  # VMEM [CK, KVHD]
-    k_ref,  # VMEM [1, BS, KVHD] — this (grid row, slot)'s K page
-    v_ref,
-    *rest,  # (ks_ref, vs_ref)? o_ref, m_ref, l_ref, acc_ref
+    *refs,  # work_ref? wq_ref, ke_ref, ve_ref, k_ref, v_ref, (ks_ref, vs_ref)? o_ref, m_ref, l_ref, acc_ref
     block_size: int,
     num_slots: int,
     scale: float,
@@ -169,19 +225,43 @@ def _mega_kernel(
     ``i`` of the tile is the first's plus ``i``, and the first
     ``meta[4]`` of them are live. The lanes of a page are cut into
     ``groups`` runs of whole KV heads; a group's ``rows`` queries-by-heads
-    meet only its lanes (block-diagonally where it folds several heads)."""
+    meet only its lanes (block-diagonally where it folds several heads).
+
+    A step is one (grid row, table slot): read off the grid ``(NT, W + 1)``,
+    whose last step a grid row, past its table, closes the row; or, for
+    length-1 rows (``tile`` 1), off item ``program_id(0)`` of the work list (``build_work``:
+    SMEM ``[1 + NT * (W + 1)]``), whose grid is its live items and nothing
+    else, a row closing on the step of its last page."""
+    listed = tile == 1
+    if listed:
+        work_ref, *refs = refs
+    # wq_ref VMEM [1, groups*rows, lanes]: this grid row's queries, folded;
+    # ke_ref, ve_ref VMEM [CK, KVHD]: ALL fresh keys (lane-merged), loaded
+    # once; k_ref, v_ref VMEM [1, BS, KVHD]: this step's page.
+    wq_ref, ke_ref, ve_ref, k_ref, v_ref, *rest = refs
     if quant:
         ks_ref, vs_ref, o_ref, m_ref, l_ref, acc_ref = rest
     else:
         o_ref, m_ref, l_ref, acc_ref = rest
         ks_ref = vs_ref = None
-    nq, w = pl.program_id(0), pl.program_id(1)
+    if listed:
+        item = work_ref[1 + pl.program_id(0)]
+        nq, w = item >> _ROW_SHIFT, item & _SLOT_MASK
+    else:
+        nq, w = pl.program_id(0), pl.program_id(1)
     prefix_len = meta_ref[1, nq]
     e_start = meta_ref[2, nq]
     e_end = meta_ref[3, nq]
     n_live = meta_ref[4, nq]
     live = n_live > 0
     bs = block_size
+    if listed:
+        # A listed row closes on the step of its prefix's last page (slot 0 where it has none),
+        pages = lax.min(lax.div(prefix_len + (bs - 1), jnp.int32(bs)), jnp.int32(num_slots))
+        closes = w == lax.max(pages, jnp.int32(1)) - 1
+    else:
+        # a grid row on a step of its own after its table's last slot.
+        closes = w == num_slots
     rows = wq_ref.shape[1] // groups
     lanes = wq_ref.shape[2]
     dtype = wq_ref.dtype
@@ -232,10 +312,11 @@ def _mega_kernel(
         acc_ref[:] = jnp.zeros(acc_ref.shape, jnp.float32)
 
     # Paged-prefix piece: slot w holds tokens [w*bs, w*bs+bs) of this grid
-    # row's sequence. Dead rows and slots past the true prefix are skipped
-    # entirely — no page fetch is wasted on bucket width (consecutive
-    # identical table entries reuse the pipelined fetch, so a short row in
-    # a wide bucket costs one scratch-page fetch, not W).
+    # row's sequence. On the static grid dead rows and slots past the true
+    # prefix are skipped — no page fetch is wasted on the table's width
+    # (consecutive identical table entries reuse the pipelined fetch, so a
+    # short prefix in a wide table costs one scratch-page fetch, not W); a
+    # list holds no such step.
     @pl.when(live & (w < num_slots) & (w * bs < prefix_len))
     def _page():
         def in_prefix(shape):
@@ -244,10 +325,10 @@ def _mega_kernel(
 
         attend(k_ref, ks_ref, v_ref, vs_ref, in_prefix)
 
-    # Final slot: the in-flight (not-yet-cached) keys — a chunk query's
+    # Closing step: the in-flight (not-yet-cached) keys — a chunk query's
     # causal window over its own chunk, a decode query's current token, a
     # window query's carry rows — then close the softmax and normalize.
-    @pl.when(w == num_slots)
+    @pl.when(closes)
     def _fresh_and_final():
         @pl.when(live & (e_end > e_start))
         def _fresh():
@@ -280,6 +361,7 @@ def ragged_paged_attention(
     v_pages,
     tables: jax.Array,  # [R, W] i32 — per-sequence-row page ids (layer-offset)
     meta: jax.Array,  # [5, NQ] i32 — build_meta
+    work: jax.Array | None = None,  # [1 + NQ*(W+1)] i32 — build_work of meta's prefixes and liveness, length-1 rows only
     *,
     num_kv_heads: int,
     block_size: int,
@@ -297,6 +379,11 @@ def ragged_paged_attention(
     one ``row_of``, ``prefix_len`` and ``extra_start``, ``extra_end`` rising
     by one a query, and its live queries first — a prefill chunk. The grid
     then walks (tile, page), not (query, page).
+
+    Length-1 rows (``tile`` 1) walk their ``work`` list (``build_work``): a
+    step a live page and one to close a live row, the count of them the
+    grid's traced bound. A step program builds the list once and hands it to
+    every layer's launch; left out, it is built here.
 
     Dead queries (``meta`` active = 0) return zeros and read nothing.
 
@@ -319,9 +406,12 @@ def ragged_paged_attention(
         # scores. The ×KVH query-byte inflation is immaterial next to the KV
         # bytes the kernel exists to save.
         NT, fold = NQ, KVH
-        q_r = q.reshape(NQ, KVH, G, HD)
-        eye = jnp.eye(KVH, dtype=q.dtype)[:, None, :, None]
-        wq = (q_r[:, :, :, None, :] * eye[None]).reshape(NQ, KVG, KVHD)
+        folded = (NQ, KVH, G, KVH, HD)
+        wq = lax.mul(
+            lax.broadcast_in_dim(lax.reshape(q, (NQ, KVH, G, HD)), folded, (0, 1, 2, 4)),
+            lax.broadcast_in_dim(np.eye(KVH, dtype=q.dtype), folded, (1, 3)),
+        )
+        wq = lax.reshape(wq, (NQ, KVG, KVHD))
     else:
         # A tile is not short of MXU rows: its dots stay inside a lane group
         # (one KV head from HD 128), rows (query, folded head, g).
@@ -342,13 +432,37 @@ def ragged_paged_attention(
     BS = k_pages.shape[1]
     assert k_pages.shape[2] == KVHD, (k_pages.shape, KVH, HD)
 
-    def page_idx(nq, w, t, mt):
+    # Where a step is, (grid row, table slot): off the grid, or off its item.
+    if tile == 1:
+        if work is None:
+            work = build_work(meta[1], meta[4] > 0, W, block_size)
+        scalars = (tables, meta, work)
+        grid = (lax.index_in_dim(work, 0, keepdims=False),)
+
+        def at(i, t, mt, wk):
+            return wk[1 + i] >> _ROW_SHIFT, wk[1 + i] & _SLOT_MASK
+    else:
+        scalars = (tables, meta)
+        grid = (NT, W + 1)
+
+        def at(nq, w, t, mt):
+            return nq, w
+
+    def row_idx(*step):
+        return (at(*step)[0], 0, 0)
+
+    def page_idx(*step):
+        nq, w = at(*step)
+        t, mt = step[len(grid) : len(grid) + 2]
         return (t[mt[0, nq], jnp.minimum(w, W - 1)], 0, 0)
 
+    def fresh_idx(*_):
+        return (0, 0)
+
     in_specs = [
-        pl.BlockSpec(block, lambda nq, w, t, mt: (nq, 0, 0)),
-        pl.BlockSpec((CK, KVHD), lambda nq, w, t, mt: (0, 0)),
-        pl.BlockSpec((CK, KVHD), lambda nq, w, t, mt: (0, 0)),
+        pl.BlockSpec(block, row_idx),
+        pl.BlockSpec((CK, KVHD), fresh_idx),
+        pl.BlockSpec((CK, KVHD), fresh_idx),
         pl.BlockSpec((1, BS, KVHD), page_idx),
         pl.BlockSpec((1, BS, KVHD), page_idx),
     ]
@@ -362,10 +476,10 @@ def ragged_paged_attention(
         args = [wq, ke, ve, k_pages, v_pages]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(NT, W + 1),
+        num_scalar_prefetch=len(scalars),
+        grid=grid,
         in_specs=in_specs,
-        out_specs=pl.BlockSpec(block, lambda nq, w, t, mt: (nq, 0, 0)),
+        out_specs=pl.BlockSpec(block, row_idx),
         scratch_shapes=[
             pltpu.VMEM((block[1], 1), jnp.float32),
             pltpu.VMEM((block[1], 1), jnp.float32),
@@ -387,13 +501,19 @@ def ragged_paged_attention(
         interpret=interpret,
         # A tile's state is megabytes where a query's is kilobytes.
         compiler_params=None if tile == 1 else pltpu.CompilerParams(vmem_limit_bytes=TILE_VMEM_LIMIT),
-    )(tables.astype(jnp.int32), meta.astype(jnp.int32), *args)
+    )(*(s.astype(jnp.int32) for s in scalars), *args)
 
     # Each query's output lives in its head's diagonal block of the fold.
     if tile == 1:
-        out = out.reshape(NQ, KVH, G, KVH, HD)
-        out = out[:, jnp.arange(KVH), :, jnp.arange(KVH), :]  # [KVH, NQ, G, HD]
-        return out.transpose(1, 0, 2, 3).reshape(NQ, H, HD)
+        diagonal = lax.GatherDimensionNumbers(offset_dims=(1, 2, 3), collapsed_slice_dims=(1, 3), start_index_map=(1, 3))
+        out = lax.gather(
+            lax.reshape(out, folded), np.repeat(np.arange(KVH, dtype=np.int32)[:, None], 2, axis=1), diagonal,
+            slice_sizes=(NQ, 1, G, 1, HD), mode=lax.GatherScatterMode.PROMISE_IN_BOUNDS,
+        )  # [KVH, NQ, G, HD]
+        out = lax.reshape(lax.transpose(out, (1, 0, 2, 3)), (NQ, H, HD))
+        # No step visited a dead row's output block: what it holds is not zeros.
+        live = lax.gt(lax.index_in_dim(meta, 4, keepdims=False), np.zeros((NQ,), np.int32))
+        return lax.select(lax.broadcast_in_dim(live, out.shape, (0,)), out, lax.full_like(out, 0))
     out = out.reshape(NT, groups, tile, fold, G, fold, HD)
     out = out[:, :, :, jnp.arange(fold), :, jnp.arange(fold), :]  # [fold, NT, groups, tile, G, HD]
     return out.transpose(1, 3, 2, 0, 4, 5).reshape(NT * tile, H, HD)[:NQ]

@@ -186,12 +186,13 @@ def _qkv(c: ModelConfig, lp, x: jax.Array, positions: jax.Array):
     return q, k, v
 
 
-def _rows_attention(c: ModelConfig, k_pool, v_pool, tables, prefix_lens, window: int = 0, step=None):
-    """Attention of ``B`` length-1 rows over their paged prefixes, as
-    ``llama.decode_layer_scan`` (``window`` 0) and its window variant have it:
-    returns ``attend(q, k, v, la, kwl=None, vwl=None) -> [B, q_size]`` for
-    attention layer ``la``; ``kwl``/``vwl`` ``[w, B, KVH, HD]`` are the rows the
-    window wrote before step ``step``."""
+def _rows_attention(c: ModelConfig, k_pool, v_pool, tables, prefix_lens, active, window: int = 0, step=None):
+    """Attention of ``B`` length-1 rows (``active`` ``[B]`` bool: the live
+    ones) over their paged prefixes, as ``llama.decode_layer_scan``
+    (``window`` 0) and its window variant have it: returns ``attend(q, k, v,
+    la, kwl=None, vwl=None) -> [B, q_size]`` for attention layer ``la``;
+    ``kwl``/``vwl`` ``[w, B, KVH, HD]`` are the rows the window wrote before
+    step ``step``."""
     B, N, bs = tables.shape[0], k_pool.shape[1], c.block_size
     kvh, G, hd = c.num_kv_heads, c.num_heads // c.num_kv_heads, c.head_dim
     ctx, w = tables.shape[1] * bs, window
@@ -199,11 +200,12 @@ def _rows_attention(c: ModelConfig, k_pool, v_pool, tables, prefix_lens, window:
     use_mega = _use_megakernel(c, k_pool)
     prefix_lens = jnp.minimum(prefix_lens, ctx).astype(jnp.int32)
     if use_mega:
-        from dynamo_tpu.engine.attention.megakernel import build_meta
+        from dynamo_tpu.engine.attention.megakernel import build_meta, build_work
 
         rows_i = jnp.arange(B, dtype=jnp.int32)
         first = rows_i * (w + 1)
-        meta = build_meta(rows_i, prefix_lens, first, first + 1 + (0 if step is None else step), jnp.ones((B,), jnp.int32))
+        meta = build_meta(rows_i, prefix_lens, first, first + 1 + (0 if step is None else step), active)
+        work = build_work(prefix_lens, active, tables.shape[1], bs)
     else:
         mask = jnp.arange(ctx, dtype=jnp.int32)[None, :] < prefix_lens[:, None]
         small_mask = jnp.ones((B, 1), dtype=bool)
@@ -218,7 +220,7 @@ def _rows_attention(c: ModelConfig, k_pool, v_pool, tables, prefix_lens, window:
             if w:
                 k = jnp.concatenate([k[:, None], jnp.swapaxes(kwl, 0, 1)], axis=1).reshape(B * (w + 1), kvh, hd)
                 v = jnp.concatenate([v[:, None], jnp.swapaxes(vwl, 0, 1)], axis=1).reshape(B * (w + 1), kvh, hd)
-            return _mega_attend_rows(c, q, k, v, k_flat, v_flat, tables_l, meta).astype(q.dtype).reshape(B, c.q_size)
+            return _mega_attend_rows(c, q, k, v, k_flat, v_flat, tables_l, meta, work).astype(q.dtype).reshape(B, c.q_size)
         qg = q.reshape(B, kvh, G, hd)
         k_ctx = _gather_kv(k_flat, tables_l, q.dtype).reshape(B, ctx, kvh, hd)
         v_ctx = _gather_kv(v_flat, tables_l, q.dtype).reshape(B, ctx, kvh, hd)
@@ -253,7 +255,7 @@ def _chunk_attention(c: ModelConfig, k_pool, v_pool, table, prefix_rows, valid_l
     def attend(q, k, v, la):
         table_l = table + la * N
         if use_mega:
-            out = _mega_attend_rows(c, q, k, v, k_flat, v_flat, table_l[None, :], meta, chunk=True)
+            out = _mega_attend_rows(c, q, k, v, k_flat, v_flat, table_l[None, :], meta)
             return out.astype(q.dtype).reshape(T, c.q_size)
         from dynamo_tpu.engine.attention.ragged import ragged_chunk_attention
 
@@ -629,7 +631,7 @@ def decode(
     c = config
     h, wdtype = _embed(c, params, tokens)
     blocks, offs, _ = decode_targets(positions, block_tables, active, c.block_size)
-    attend = _rows_attention(c, k_cache.pool, v_cache.pool, block_tables, positions)
+    attend = _rows_attention(c, k_cache.pool, v_cache.pool, block_tables, positions, active)
     slots = jnp.where(active, _slots_of(k_cache, block_tables), 0)
     h, ssm, conv, k_rows, v_rows, aux = _drive(
         c, params, h, k_cache.slots, v_cache.slots, attend, positions, slots, None, active, wdtype
@@ -664,7 +666,7 @@ def mixed_step(
     p_valid_q = jnp.arange(S, dtype=jnp.int32) < p_valid
     h, wdtype = _embed(c, params, jnp.concatenate([p_tokens, d_tokens]))
     p_attend = _chunk_attention(c, k_cache.pool, v_cache.pool, p_table, p_cache_len, p_valid, S, use_flash, has_prefix)
-    d_attend = _rows_attention(c, k_cache.pool, v_cache.pool, d_tables, d_positions)
+    d_attend = _rows_attention(c, k_cache.pool, v_cache.pool, d_tables, d_positions, d_active)
 
     def attend(q, k, v, la):
         return jnp.concatenate([p_attend(q[:S], k[:S], v[:S], la), d_attend(q[S:], k[S:], v[S:], la)])
@@ -717,7 +719,7 @@ def decode_multi(
     def body(i, carry):
         toks, ssm, conv, k_win, v_win, out, lg_out, key, held, visited = carry
         h, _ = _embed(c, params, toks)
-        attend = _rows_attention(c, k_cache.pool, v_cache.pool, block_tables, positions, window=num_steps, step=i)
+        attend = _rows_attention(c, k_cache.pool, v_cache.pool, block_tables, positions, active, window=num_steps, step=i)
         h, ssm, conv, k_rows, v_rows, aux = _drive(
             c, params, h, ssm, conv, attend, positions + i, slots, None, active, wdtype, window=(k_win, v_win)
         )

@@ -663,19 +663,21 @@ def _mega_attend_rows(
     v_flat,
     tables: jax.Array,  # [R, W] layer-offset page tables
     meta: jax.Array,  # [5, NQ] megakernel.build_meta
-    chunk: bool = False,  # the queries are one wide row (a prefill chunk), walked by tiles
+    work: Optional[jax.Array] = None,  # length-1 rows: their megakernel.build_work, built once a step
 ) -> jax.Array:
     """One fused ragged-attention launch for a step's rows — per tp shard
-    over its local heads when the step runs under a mesh."""
+    over its local heads when the step runs under a mesh. Without ``work``
+    the queries are one wide row (a prefill chunk), walked by tiles."""
     from dynamo_tpu.engine.attention.megakernel import ragged_paged_attention
 
+    args = (q, k_extra, v_extra, k_flat, v_flat, tables, meta) + (() if work is None else (work,))
     attend = over_tp(
         ragged_paged_attention, c.num_kv_heads,
-        (HEADS, HEADS, HEADS, PAGES, PAGES, P(), P()), HEADS,
+        (HEADS, HEADS, HEADS, PAGES, PAGES) + (P(),) * (len(args) - 5), HEADS,
         block_size=c.block_size, interpret=not _on_tpu(),
-        tile=_chunk_tile(c, k_flat, q.shape[0], q.dtype) if chunk else 1,
+        tile=_chunk_tile(c, k_flat, q.shape[0], q.dtype) if work is None else 1,
     )
-    return attend(q, k_extra, v_extra, k_flat, v_flat, tables, meta)
+    return attend(*args)
 
 
 def _paged_prefix_partials(c: ModelConfig, q, k_flat, v_flat, tables_l, lengths):
@@ -841,7 +843,7 @@ def prefill(
         if use_mega:
             attn = _mega_attend_rows(
                 c, q, k, v, k_flat, v_flat,
-                (block_table + l * N)[None, :], mega_meta, chunk=True,
+                (block_table + l * N)[None, :], mega_meta,
             ).astype(wdtype)
             h = h + attn.reshape(T, c.q_size) @ lp["wo"]
             x = _norm(c, h, lp["mlp_norm"], wdtype)
@@ -1181,13 +1183,13 @@ def _decode_layer_scan_window(
         # row's slice of [current ; window rows] — a contiguous [start,
         # end) column window, end advancing with the in-window step (the
         # not-yet-written carry rows stay masked for free).
-        from dynamo_tpu.engine.attention.megakernel import build_meta
+        from dynamo_tpu.engine.attention.megakernel import build_meta, build_work
 
         rows_i = jnp.arange(B, dtype=jnp.int32)
         mega_meta = build_meta(
-            rows_i, win_prefix_lens, rows_i * (w + 1),
-            rows_i * (w + 1) + 1 + step, jnp.ones((B,), jnp.int32),
+            rows_i, win_prefix_lens, rows_i * (w + 1), rows_i * (w + 1) + 1 + step, active,
         )
+        mega_work = build_work(win_prefix_lens, active, block_tables.shape[1], bs)
 
     scanned, experts = _split_expert_stacks(c, layers)
 
@@ -1217,7 +1219,7 @@ def _decode_layer_scan_window(
             ).reshape(B * (w + 1), kvh, hd)
             attn = _mega_attend_rows(
                 c, q, k_extra, v_extra, k_flat, v_flat,
-                block_tables + l * N, mega_meta,
+                block_tables + l * N, mega_meta, mega_work,
             ).astype(wdtype)
             h = h + attn.reshape(B, c.q_size) @ lp["wo"]
             x = _norm(c, h, lp["mlp_norm"], wdtype)
@@ -1489,11 +1491,12 @@ def mixed_step(
         # Megakernel packing: the mixed step's attention is the two shapes
         # it has, each a launch per layer of the one kernel — the chunk, a
         # wide row walked by tiles of its queries over its own table and
-        # its own fresh keys, and the B length-1 decode rows, walked a query
-        # at a time over theirs. Padded table slots hold the scratch page
-        # and are skipped (pl.when) along with dead chunk-bucket queries
-        # and inactive decode lanes.
-        from dynamo_tpu.engine.attention.megakernel import build_meta
+        # its own fresh keys, and the B length-1 decode rows, whose launch
+        # walks the list of their live pages (build_work): an inactive lane
+        # or a padded table slot is no step of it. The chunk's padded slots
+        # hold the scratch page and are skipped (pl.when) along with its
+        # bucket's dead queries.
+        from dynamo_tpu.engine.attention.megakernel import build_meta, build_work
 
         s_iq = jnp.arange(S, dtype=jnp.int32)
         d_iq = jnp.arange(B, dtype=jnp.int32)
@@ -1502,6 +1505,7 @@ def mixed_step(
             jnp.zeros((S,), jnp.int32), s_iq + 1, s_iq < p_valid,
         )
         d_meta = build_meta(d_iq, d_prefix_lens, d_iq, d_iq + 1, d_active)
+        d_work = build_work(d_prefix_lens, d_active, d_tables.shape[1], bs)
 
     from dynamo_tpu.engine.attention.ragged import ragged_chunk_attention
 
@@ -1520,9 +1524,9 @@ def mixed_step(
         if use_mega:
             # Each piece's fresh keys are its own rows of the projection.
             attn_p = _mega_attend_rows(
-                c, q[:S], k[:S], v[:S], k_flat, v_flat, (p_table + l * N)[None, :], p_meta, chunk=True,
+                c, q[:S], k[:S], v[:S], k_flat, v_flat, (p_table + l * N)[None, :], p_meta,
             )
-            attn_d = _mega_attend_rows(c, q[S:], k[S:], v[S:], k_flat, v_flat, d_tables + l * N, d_meta)
+            attn_d = _mega_attend_rows(c, q[S:], k[S:], v[S:], k_flat, v_flat, d_tables + l * N, d_meta, d_work)
             attn = jnp.concatenate([attn_p, attn_d]).astype(wdtype).reshape(S + B, c.q_size)
             h = h + attn @ lp["wo"]
             x = _norm(c, h, lp["mlp_norm"], wdtype)
@@ -1715,12 +1719,12 @@ def decode_layer_scan(
     wdtype = h.dtype if wdtype is None else wdtype
     prefix_lens = jnp.minimum(cache_rows(c, positions), ctx).astype(jnp.int32)
     if use_mega:
-        from dynamo_tpu.engine.attention.megakernel import build_meta
+        from dynamo_tpu.engine.attention.megakernel import build_meta, build_work
 
         rows_i = jnp.arange(B, dtype=jnp.int32)
-        mega_meta = build_meta(
-            rows_i, prefix_lens, rows_i, rows_i + 1, jnp.ones((B,), jnp.int32)
-        )
+        live = jnp.ones((B,), bool) if active is None else active
+        mega_meta = build_meta(rows_i, prefix_lens, rows_i, rows_i + 1, live)
+        mega_work = build_work(prefix_lens, live, block_tables.shape[1], bs)
 
     scanned, experts = _split_expert_stacks(c, layers)
 
@@ -1742,7 +1746,7 @@ def decode_layer_scan(
             # inside ONE launch's online softmax — no gathered copy, no
             # external piece merge (attention/megakernel.py).
             attn = _mega_attend_rows(
-                c, q, k, v, k_flat, v_flat, tables_l, mega_meta
+                c, q, k, v, k_flat, v_flat, tables_l, mega_meta, mega_work
             ).astype(wdtype)
         else:
             # Two online-softmax pieces: cached prefix + current token

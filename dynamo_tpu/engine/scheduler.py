@@ -511,6 +511,10 @@ class Scheduler:
         self._uploads = 0
         self.sampled_in_program_total = 0
         self.sampled_on_host_total = 0
+        # What the decode rows' attention launches walked, and what their
+        # bucket and table width spanned (_note_step).
+        self.attn_items_total = 0
+        self.attn_slots_total = 0
         # SLA telemetry: mergeable latency digests (ttft/tpot/itl/queue_wait
         # + per-phase step durations via the flight recorder) and the SLO
         # judge behind the goodput account. All host-side — no dispatches.
@@ -1071,6 +1075,10 @@ class Scheduler:
             # path did (a row that needs the host between logits and token; a wave).
             "sampled_in_program_total": self.sampled_in_program_total,
             "sampled_on_host_total": self.sampled_on_host_total,
+            # Steps the decode rows' attention launches took a layer (one a live page),
+            # and the bucket x (table width + 1) they would span.
+            "attn_items_total": self.attn_items_total,
+            "attn_slots_total": self.attn_slots_total,
             "flight": {
                 "last_step_phase": f.last_step_phase,
                 "last_step_s": round(f.last_step_s, 6),
@@ -1244,6 +1252,15 @@ class Scheduler:
             if self._hybrid:
                 # Slots the dispatch advances (its decode rows' and its chunk's), and slots held.
                 span.set(ssm_rows=len(batch) + (1 if kind == "mixed" else 0), ssm_slots=self.slots.in_use)
+            if kind in ("decode", "decode_multi", "mixed") and self._attn_impl == "megakernel":
+                # The decode rows' attention launch (megakernel.build_work): the steps it
+                # takes a layer, one a page under a row's current token, beside its batch
+                # bucket x (table width + 1).
+                bs, (bucket, width) = self.mc.block_size, key[-2:]
+                items = sum(max(min(-(-self._rows_for(s, s.total_len - 1) // bs), width), 1) for s in batch)
+                span.set(attn_items=items, attn_slots=bucket * (width + 1))
+                self.attn_items_total += items
+                self.attn_slots_total += bucket * (width + 1)
         else:
             span.set(dispatches=attrs.get("dispatches", 1) + 1)
         if kind in ("mixed", "prefill", "prefill_mm"):

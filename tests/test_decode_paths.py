@@ -195,9 +195,12 @@ def _decode_programs(cfg, params):
 @pytest.mark.parametrize("preset", ["tiny", "tiny-moe", "tiny-eva"])
 def test_decode_programs_do_not_depend_on_the_chunk_tile(preset, program, monkeypatch):
     """Every row of ``decode`` and ``decode_multi`` is a length-1 row: under the
-    megakernel each launch walks (query, page) — a grid row a query — and the
-    traced program is the same whatever tile a chunk would take. (Against the
-    parent commit, once: PERF.md section 6, PR 31.)"""
+    megakernel each launch walks the list of its rows' live pages — one axis
+    of steps, its bound traced — and the traced program is the same whatever
+    tile a chunk would take. (Against the parent commit, once: PERF.md
+    section 6, PR 31.)"""
+    from jax._src.pallas.core import dynamic_grid_dim
+
     from dynamo_tpu.engine.attention import megakernel as mk
     from tests.test_megakernel import _kernel_grids
 
@@ -205,7 +208,7 @@ def test_decode_programs_do_not_depend_on_the_chunk_tile(preset, program, monkey
     params = llama.init_params(cfg, jax.random.PRNGKey(0), dtype=jnp.float32)
     traced = _decode_programs(cfg, params)[program]
     grids = _kernel_grids(traced.jaxpr)
-    assert grids and set(grids) == {(4, 4 + 1)}, grids  # B rows x (W pages + the fresh keys)
+    assert grids and set(grids) == {(dynamic_grid_dim,)}, grids  # the live items, not B rows x (W pages + the fresh keys)
     monkeypatch.setattr(mk, "TILE_MAX", 16)
     monkeypatch.setattr(mk, "TILE_MIN", 2)
     mk.ragged_paged_attention.clear_cache()

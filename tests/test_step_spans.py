@@ -175,6 +175,49 @@ def test_a_chunk_carrying_step_names_the_chunks_attention_path(impl, path):
     assert all("chunk_attn" not in r for r in recent if r["phase"] == "decode")
 
 
+@pytest.mark.parametrize("path", ["decode", "decode_multi", "mixed"])
+def test_a_step_with_decode_rows_counts_its_attention_items_beside_its_slots(path, monkeypatch):
+    """``attn_items`` on a ``sched.step`` entry is the count the rows' launch
+    reads off the dispatch's own operands (``megakernel.build_work`` on the
+    packed rows and the uploaded tables' width): a step a page under a live
+    row's current token; ``attn_slots`` is the bucket x (table width + 1) the
+    static grid spanned. ``debug_state`` sums both."""
+    from dynamo_tpu.engine.attention import megakernel as mk
+
+    settings, first, late, kind = PATHS[path]
+    sched = Scheduler(CFG.replace(attention_impl="megakernel"), PARAMS,
+                      SchedulerConfig(**{**dict(num_blocks=128, max_running=8, prefill_buckets=[16, 32], decode_buckets=[4, 8],
+                                                num_scheduler_steps=1, enable_prefix_caching=False), **settings}),
+                      dtype=jnp.float32)
+    bs = CFG.block_size
+    packed, pack, tables_of = [], sched._pack_rows, sched._decode_tables
+    monkeypatch.setattr(sched, "_pack_rows", lambda batch, bucket, **kw: (packed.append(pack(batch, bucket, **kw)), packed[-1])[1])
+    monkeypatch.setattr(sched, "_decode_tables", lambda batch, bucket, width: (
+        packed.append(width), tables_of(batch, bucket, width))[1])
+    add(sched, "a", list(range(1, 20)), 12)
+    add(sched, "b", list(range(1, 40)), 9)  # a longer row: its pages set the table's width
+    drain(sched, late)
+    steps = [e[4] for e in sched.flight.log.spans if e[0] == "sched.step" and e[4] and "attn_items" in e[4]]
+    assert kind in {a["kind"] for a in steps} and {a["kind"] for a in steps} <= {"decode", "decode_multi", "mixed"}
+    assert all("attn_items" in e[4] for e in sched.flight.log.spans
+               if e[0] == "sched.step" and e[4] and e[4].get("kind") in ("decode", "decode_multi", "mixed"))
+    operands = list(zip(packed[0::2], packed[1::2]))  # (rows, width) of every dispatch that carried decode rows, in order
+    assert len(operands) == len(steps)
+    for attrs, (rows, width) in zip(steps, operands):
+        bucket = rows.shape[1]
+        work = mk.build_work(jnp.minimum(rows[1], width * bs), rows[2] > 0, width, bs)
+        assert attrs["attn_items"] == int(work[0]) and attrs["attn_slots"] == bucket * (width + 1) == work.shape[0] - 1
+        assert attrs["rows"] <= attrs["attn_items"] <= attrs["attn_slots"]
+    assert any(a["attn_items"] < a["attn_slots"] // 2 for a in steps)  # two rows in a bucket of 4: most of the span is dead
+    state = sched.debug_state()
+    assert state["attn_items_total"] == sum(a["attn_items"] for a in steps)
+    assert state["attn_slots_total"] == sum(a["attn_slots"] for a in steps)
+    gather = mk_sched()
+    add(gather, "a", list(range(1, 20)), 4)
+    drain(gather)
+    assert gather.debug_state()["attn_items_total"] == 0  # the gather path launches no such walk
+
+
 def test_host_gap_is_read_from_the_logs_launch_stamps():
     sched = mk_sched()
     add(sched, "a", list(range(1, 20)), 10)

@@ -1,8 +1,10 @@
 #!/usr/bin/env python3
-"""Micro-benchmark of one layer's attention in a mixed step (PERF.md §6, PR 31):
-a chunk of 256 queries over a paged prefix and its own keys, beside 32 decode
-rows of 12 pages, at two pool widths — ``KVH*HD`` 1,024 lanes (Mistral-7B:
-32/8 heads of 128) and 4,096 (EvaByte: 32/32 heads of 128).
+"""Micro-benchmark of one layer's attention. ``--study chunk`` (PERF.md §6, PR
+31): a mixed step's, a chunk of 256 queries over a paged prefix and its own
+keys, beside 32 decode rows of 12 pages, at two pool widths — ``KVH*HD`` 1,024
+lanes (Mistral-7B: 32/8 heads of 128) and 4,096 (EvaByte: 32/32 heads of 128).
+``--study rows`` (PERF.md §6, PR 38): the decode rows' launch alone, by how
+many of a bucket's rows are live and how much of their table they fill.
 
 Forms, each a ``lax.scan`` over L layers of a layer-flat pool, timed whole and
 divided by L (the queries of layer l+1 follow from layer l's output, so nothing hoists):
@@ -21,8 +23,22 @@ divided by L (the queries of layer l+1 follow from layer l's output, so nothing 
 - ``pagedrows``:   the decode rows on that path (``paged_decode_partials``
                    merged with the current token's piece).
 
+The rows study takes shapes ``live:pages:bucket:width`` (live rows, full pages
+each, the batch bucket, the table's width) and windows (the fresh keys a row
+brings: the carry rows of a ``decode_multi`` window, 0 for a single step), at
+the first of ``--lanes``, and reads
+
+- ``rows``:        the launch as the step programs make it: padded rows dead,
+                   the grid the list of live pages (``megakernel.build_work``).
+- ``rows_walk``:   the same kernel handed every slot of every bucket row, the
+                   padded rows live with no prefix: the ``bucket x (width + 1)``
+                   steps the static grid took before PR 38.
+- ``rows_parent``: with ``--parent DIR`` (a checkout of another commit), that
+                   commit's kernel on ``rows_walk``'s inputs.
+
     chiprun -- python tools/attn_chunk_bench.py
-    JAX_PLATFORMS=cpu python tools/attn_chunk_bench.py --tiny   # control flow only
+    chiprun -- python tools/attn_chunk_bench.py --study rows [--parent .parent]
+    JAX_PLATFORMS=cpu python tools/attn_chunk_bench.py --tiny [--study rows]   # control flow only
 
 Prints one JSON line per reading (µs a layer) and writes them to
 ``chiprun_out/attn_chunk_bench.jsonl``.
@@ -106,6 +122,77 @@ def build(form, tile, *, S, B, W, N, L, KVH, BS, prefix, d_prefix, interpret):
     return fn, lo, hi
 
 
+def walk_work(bucket, width):
+    """``build_work``'s list with every slot of every bucket row in it and one
+    more a row: the steps of the static grid ``(bucket, width + 1)``, a slot
+    past a row's prefix one that fetches the table's entry and computes
+    nothing."""
+    row = jnp.arange(bucket, dtype=jnp.int32)[:, None] << mk._ROW_SHIFT
+    items = (row | jnp.arange(width + 1, dtype=jnp.int32)[None]).reshape(-1)
+    return jnp.concatenate([jnp.full((1,), items.shape[0], jnp.int32), items])
+
+
+def build_rows(form, kernel, *, live, pages, B, W, window, N, L, KVH, BS, interpret):
+    """``fn(q [B, H, HD], k, v [B*(window+1), KVH, HD], k_pool, v_pool)`` -> the
+    last layer's output: ``live`` of ``B`` rows hold ``pages`` full pages less
+    half of the last in a table ``W`` wide, each with its ``window + 1`` fresh
+    keys as a window's last step has them."""
+    i32 = jnp.int32
+    iq = jnp.arange(B, dtype=i32)
+    is_live = iq < live
+    tables = jnp.where(is_live[:, None] & (jnp.arange(W)[None] < pages), 1 + (iq[:, None] * pages + jnp.arange(W, dtype=i32)[None]) % (N - 1), 0)
+    first = iq * (window + 1)
+    prefix = jnp.where(is_live, pages * BS - BS // 2, 0)
+    if form == "rows":
+        meta = mk.build_meta(iq, prefix, first, first + window + 1, is_live)
+        work = (mk.build_work(prefix, is_live, W, BS),)
+    else:
+        meta = mk.build_meta(iq, prefix, first, first + window + 1, jnp.ones((B,), i32))
+        work = (walk_work(B, W),) if form == "rows_walk" else ()
+
+    def fn(q, k, v, kp, vp):
+        def body(q32, l):
+            out = kernel(q32.astype(q.dtype), k, v, kp, vp, tables + l * N, meta, *work,
+                         num_kv_heads=KVH, block_size=BS, interpret=interpret)
+            return q32 + 0.01 * out.astype(jnp.float32), None
+
+        return lax.scan(body, q.astype(jnp.float32), jnp.arange(L, dtype=i32))[0]
+
+    return fn
+
+
+def rows_study(a, say, *, lanes, N, L, H, HD, BS, dtype, on_tpu):
+    kernels = {"rows": mk.ragged_paged_attention, "rows_walk": mk.ragged_paged_attention}
+    if a.parent:
+        import importlib.util
+
+        spec = importlib.util.spec_from_file_location(
+            "parent_megakernel", os.path.join(a.parent, "dynamo_tpu/engine/attention/megakernel.py"))
+        parent = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(parent)
+        kernels["rows_parent"] = parent.ragged_paged_attention
+    KVH = lanes // HD
+    keys = jax.random.split(jax.random.PRNGKey(lanes), 5)
+    kp = jax.random.normal(keys[3], (L * N, BS, lanes), dtype)
+    vp = jax.random.normal(keys[4], (L * N, BS, lanes), dtype)
+    for shape in a.rows:
+        live, pages, B, W = (int(x) for x in shape.split(":"))
+        for window in a.windows:
+            q = jax.random.normal(keys[0], (B, H, HD), dtype)
+            k = jax.random.normal(keys[1], (B * (window + 1), KVH, HD), dtype)
+            v = jax.random.normal(keys[2], (B * (window + 1), KVH, HD), dtype)
+            ref = None
+            for form, kernel in kernels.items():
+                fn = build_rows(form, kernel, live=live, pages=pages, B=B, W=W, window=window, N=N, L=L, KVH=KVH, BS=BS,
+                                interpret=not on_tpu)
+                us, out = measure(fn, (q, k, v, kp, vp), a.iters)
+                out = jnp.asarray(out, jnp.float32)[:live]
+                ref = out if ref is None else ref
+                say(lanes=lanes, shape=shape, window=window, form=form, us_per_layer=us / L,
+                    kv_mb=live * pages * BS * lanes * 2 * jnp.dtype(dtype).itemsize / 1e6,
+                    max_rel_diff_vs_rows=float(jnp.max(jnp.abs(ref - out)) / (jnp.max(jnp.abs(ref)) + 1e-9)))
+
+
 def measure(fn, args, iters):
     compiled = jax.jit(fn).lower(*args).compile()
     out = compiled(*args)
@@ -126,11 +213,17 @@ def main():
     ap.add_argument("--prefix", type=int, nargs="*", default=[0, 512, 1408])
     ap.add_argument("--tiles", type=int, nargs="*", default=[32, 64, 128, 256])
     ap.add_argument("--fold-tiles", type=int, nargs="*", default=[16, 32])
+    ap.add_argument("--study", choices=["chunk", "rows"], default="chunk")
+    ap.add_argument("--rows", nargs="*", default=["5:4:32:8", "5:4:32:12", "32:12:32:16", "4:4:4:4"],
+                    help="live:pages:bucket:width")
+    ap.add_argument("--windows", type=int, nargs="*", default=[0, 8])
+    ap.add_argument("--parent", help="a checkout of another commit, whose kernel is read beside this one's")
     a = ap.parse_args()
     on_tpu = jax.devices()[0].platform == "tpu"
     if a.tiny:
         S, B, W, N, L, H, HD, BS, dtype = 32, 4, 4, 16, 2, 4, 16, 16, jnp.float32
         a.lanes, a.prefix, a.tiles, a.fold_tiles, a.iters = [32, 64], [0, 24], [16, 32], [16], 1
+        a.rows, a.windows = ["2:2:4:4", "4:3:4:4"], [0, 2]
     else:
         S, B, W, N, L, H, HD, BS, dtype = 256, 32, 16, 64, a.layers, 32, 128, 128, jnp.bfloat16
     os.makedirs("chiprun_out", exist_ok=True)
@@ -143,6 +236,8 @@ def main():
         log.flush()
 
     say(device=str(jax.devices()[0].device_kind), S=S, B=B, W=W, L=L, H=H, HD=HD, BS=BS, dtype=str(jnp.dtype(dtype)))
+    if a.study == "rows":
+        return rows_study(a, say, lanes=a.lanes[0], N=N, L=L, H=H, HD=HD, BS=BS, dtype=dtype, on_tpu=on_tpu)
     lane_fold = mk.lane_fold
     for lanes in a.lanes:
         KVH = lanes // HD
