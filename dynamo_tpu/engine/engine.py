@@ -25,6 +25,7 @@ from typing import Any, AsyncIterator, Callable, List, Optional
 import jax
 import jax.numpy as jnp
 
+from dynamo_tpu.engine.compile_cache import BUILD_LOG, enable_compile_cache
 from dynamo_tpu.engine.config import ModelConfig, get_config
 from dynamo_tpu.engine.kv_cache import KvEvent
 from dynamo_tpu.engine.models import llama
@@ -39,7 +40,7 @@ from dynamo_tpu.engine.scheduler import (
 )
 from dynamo_tpu.runtime.engine import Context
 from dynamo_tpu.runtime.logging import get_logger
-from dynamo_tpu.runtime.tracing import get_tracer
+from dynamo_tpu.runtime.tracing import StepLog, get_tracer
 
 logger = get_logger(__name__)
 
@@ -98,6 +99,17 @@ class EngineArgs:
     profile_interval_s: float = 30.0
     # Artifact root for ALL capture paths (falls back to DYN_PROFILE_DIR).
     profile_dir: Optional[str] = None
+
+
+def _log_built(b: dict, warmup_ctx: int) -> None:
+    """The start-up line: what ``debug_state()["build"]`` holds in full."""
+    logger.info(
+        "engine.build %.1f s (params %.1f, scheduler %.1f, warm-up at ctx %d %.1f): %d keys warmed, "
+        "%d executables (%d eager, %d from the cache) = trace %.1f + lower %.1f + backend %.1f + other %.1f s",
+        b["engine_build"]["span_s"], *(b["phase_s"].get(p, 0.0) for p in ("build.params", "build.scheduler")), warmup_ctx,
+        b["phase_s"].get("build.warmup", 0.0), b["keys"], b["executables"], b["eager"], b["cache_hits"],
+        *(b["engine_build"][p] for p in ("trace_s", "lower_s", "backend_s", "other_s")),
+    )
 
 
 class TpuEngine:
@@ -163,127 +175,140 @@ class TpuEngine:
         draft_params=None,
         kv_event_sink: Optional[Callable[[KvEvent], None]] = None,
     ) -> "TpuEngine":
-        from dynamo_tpu.engine.compile_cache import enable_compile_cache
-
+        """The engine, warmed. The whole of it is one ``engine.build`` span on
+        the engine's step log, with children ``build.params`` (weights: loading
+        or making them, quantizing them), ``build.scheduler`` (the pool, the
+        slots, the ``jax.jit`` objects) and ``build.warmup``
+        (``Scheduler.warmup``); every executable JAX builds inside is an entry
+        of the build log (engine/compile_cache.py) under its phase and key."""
         enable_compile_cache()
-        mc = args.model_config or get_config(args.model)
-        if args.kv_cache_dtype != "auto":
-            mc = mc.replace(kv_cache_dtype=args.kv_cache_dtype)
-        if args.weight_dtype != "auto":
-            mc = mc.replace(weight_dtype=args.weight_dtype)
-        dtype = jnp.bfloat16 if args.dtype == "bfloat16" else jnp.float32
-        if params is None:
-            if args.checkpoint_path:
-                from dynamo_tpu.engine.weights import load_checkpoint
+        log = StepLog()
+        # (One function, no larger than it was: the bytes of the Python frames under a jitted
+        # call decide where CPython's stack chunks end inside JAX's lowering, and a warm
+        # set-up's lowering seconds move with them: PERF.md §6, PR 39.)
+        with BUILD_LOG.scope(log, "engine.build"):
+            mc = args.model_config or get_config(args.model)
+            if args.kv_cache_dtype != "auto":
+                mc = mc.replace(kv_cache_dtype=args.kv_cache_dtype)
+            if args.weight_dtype != "auto":
+                mc = mc.replace(weight_dtype=args.weight_dtype)
+            dtype = jnp.bfloat16 if args.dtype == "bfloat16" else jnp.float32
+            with BUILD_LOG.scope(log, "build.params"):
+                if params is None:
+                    if args.checkpoint_path:
+                        from dynamo_tpu.engine.weights import load_checkpoint
 
-                params = load_checkpoint(args.checkpoint_path, mc, dtype=dtype)
-            else:
+                        params = load_checkpoint(args.checkpoint_path, mc, dtype=dtype)
+                    else:
+                        from dynamo_tpu.engine.models import get_module
+
+                        logger.warning("no checkpoint: initializing random weights for %s", mc.name)
+                        params = get_module(mc).init_params(mc, jax.random.PRNGKey(args.seed), dtype=dtype)
+                if mc.weight_dtype == "int8":
+                    from dynamo_tpu.engine.quant import params_quantized, quantize_params
+
+                    if not params_quantized(params):
+                        params = quantize_params(params)
+                        logger.info("int8 weight-only quantization applied (layer matmul weights)")
+            mesh = None
+            if args.parallel is not None and args.parallel.total > 1:
+                from dynamo_tpu.engine.sharding import build_mesh
+
+                mesh = build_mesh(args.parallel)
+            with BUILD_LOG.scope(log, "build.scheduler"):
+                scheduler = Scheduler(
+                    mc,
+                    params,
+                    args.scheduler,
+                    dtype=dtype,
+                    eos_token_ids=args.eos_token_ids,
+                    on_kv_event=lambda ev: engine._on_kv_event(ev),
+                    rng_seed=args.seed,
+                    mesh=mesh,
+                    parallel=args.parallel,
+                    step_log=log,
+                )
+                engine = cls(scheduler, kv_event_sink=kv_event_sink)
+            if args.draft_model:
                 from dynamo_tpu.engine.models import get_module
 
-                logger.warning("no checkpoint: initializing random weights for %s", mc.name)
-                params = get_module(mc).init_params(mc, jax.random.PRNGKey(args.seed), dtype=dtype)
-        if mc.weight_dtype == "int8":
-            from dynamo_tpu.engine.quant import params_quantized, quantize_params
+                dc = get_config(args.draft_model)
+                if draft_params is None:
+                    if args.draft_checkpoint_path:
+                        from dynamo_tpu.engine.weights import load_checkpoint
 
-            if not params_quantized(params):
-                params = quantize_params(params)
-                logger.info("int8 weight-only quantization applied (layer matmul weights)")
-        mesh = None
-        if args.parallel is not None and args.parallel.total > 1:
-            from dynamo_tpu.engine.sharding import build_mesh
+                        draft_params = load_checkpoint(args.draft_checkpoint_path, dc, dtype=dtype)
+                    else:
+                        logger.warning("no draft checkpoint: random weights for %s", dc.name)
+                        draft_params = get_module(dc).init_params(
+                            dc, jax.random.PRNGKey(args.seed + 1), dtype=dtype
+                        )
+                engine.scheduler.attach_draft(dc, draft_params, gamma=args.spec_gamma)
+            if args.tokenizer is not None:
+                engine.scheduler.attach_guided(args.tokenizer)
+            if args.warmup_ctx > 0:
+                with BUILD_LOG.scope(log, "build.warmup", ctx=args.warmup_ctx):
+                    engine.scheduler.warmup(args.warmup_ctx)
+            # From here on, compiles are mid-traffic: the flight recorder counts
+            # them (and alerts when a warmup pass was supposed to cover them).
+            engine.scheduler.flight.mark_warmup_done(warmed=args.warmup_ctx > 0)
+            # Incident capture: point the plane at the bundle directory (CLI /
+            # env); the detector is armed either way — counters flow to the
+            # scrape even when no bundles are written.
+            import os as _os
 
-            mesh = build_mesh(args.parallel)
-        engine = cls(
-            Scheduler(
-                mc,
-                params,
-                args.scheduler,
-                dtype=dtype,
-                eos_token_ids=args.eos_token_ids,
-                on_kv_event=lambda ev: engine._on_kv_event(ev),
-                rng_seed=args.seed,
-                mesh=mesh,
-                parallel=args.parallel,
-            ),
-            kv_event_sink=kv_event_sink,
-        )
-        if args.draft_model:
-            from dynamo_tpu.engine.models import get_module
+            from dynamo_tpu.runtime.incidents import INCIDENT_DIR_ENV, IncidentConfig, IncidentPlane
 
-            dc = get_config(args.draft_model)
-            if draft_params is None:
-                if args.draft_checkpoint_path:
-                    from dynamo_tpu.engine.weights import load_checkpoint
-
-                    draft_params = load_checkpoint(args.draft_checkpoint_path, dc, dtype=dtype)
-                else:
-                    logger.warning("no draft checkpoint: random weights for %s", dc.name)
-                    draft_params = get_module(dc).init_params(
-                        dc, jax.random.PRNGKey(args.seed + 1), dtype=dtype
-                    )
-            engine.scheduler.attach_draft(dc, draft_params, gamma=args.spec_gamma)
-        if args.tokenizer is not None:
-            engine.scheduler.attach_guided(args.tokenizer)
-        if args.warmup_ctx > 0:
-            n = engine.scheduler.warmup(args.warmup_ctx)
-            logger.info("warmed %d executables (ctx %d)", n, args.warmup_ctx)
-        # From here on, compiles are mid-traffic: the flight recorder counts
-        # them (and alerts when a warmup pass was supposed to cover them).
-        engine.scheduler.flight.mark_warmup_done(warmed=args.warmup_ctx > 0)
-        # Incident capture: point the plane at the bundle directory (CLI /
-        # env); the detector is armed either way — counters flow to the
-        # scrape even when no bundles are written.
-        import os as _os
-
-        from dynamo_tpu.runtime.incidents import INCIDENT_DIR_ENV, IncidentConfig, IncidentPlane
-
-        incident_dir = args.incident_dir or _os.environ.get(INCIDENT_DIR_ENV) or None
-        # One shared DeviceProfiler for every capture path — incident
-        # captures, the health server's POST /debug/profile, and the
-        # continuous sampler all serialize through its capture lock.
-        if args.profile_dir:
-            engine.profiler.out_dir = args.profile_dir
-        elif args.profile_on_incident and incident_dir:
-            engine.profiler.out_dir = _os.path.join(incident_dir, "profiles")
-        engine.incidents = IncidentPlane(
-            IncidentConfig(
-                dir=incident_dir,
-                keep=args.incident_keep,
-                profile_on_incident=args.profile_on_incident,
-            ),
-            state_probe=engine.debug_state,
-            flight_probe=engine.scheduler.flight.ring_snapshot,
-            config_probe=engine.scheduler.config_snapshot,
-            profiler=engine.profiler,
-        )
-        if args.continuous_profiling:
-            from dynamo_tpu.runtime.profiling import (
-                ContinuousProfileConfig,
-                ContinuousProfiler,
-            )
-
-            flight = engine.scheduler.flight
-            engine.continuous_profiler = ContinuousProfiler(
-                engine.profiler,
-                ContinuousProfileConfig(
-                    window_s=args.profile_window_s,
-                    interval_s=args.profile_interval_s,
+            incident_dir = args.incident_dir or _os.environ.get(INCIDENT_DIR_ENV) or None
+            # One shared DeviceProfiler for every capture path — incident
+            # captures, the health server's POST /debug/profile, and the
+            # continuous sampler all serialize through its capture lock.
+            if args.profile_dir:
+                engine.profiler.out_dir = args.profile_dir
+            elif args.profile_on_incident and incident_dir:
+                engine.profiler.out_dir = _os.path.join(incident_dir, "profiles")
+            engine.incidents = IncidentPlane(
+                IncidentConfig(
+                    dir=incident_dir,
+                    keep=args.incident_keep,
+                    profile_on_incident=args.profile_on_incident,
                 ),
-                cost_probe=flight.roofline_totals,
-                sink=flight.record_measured_window,
+                state_probe=engine.debug_state,
+                flight_probe=engine.scheduler.flight.ring_snapshot,
+                config_probe=engine.scheduler.config_snapshot,
+                profiler=engine.profiler,
             )
-            engine.continuous_profiler.start()
-        if args.kvbm_host_blocks > 0:
-            from dynamo_tpu.llm.block_manager import KvBlockManager
+            if args.continuous_profiling:
+                from dynamo_tpu.runtime.profiling import (
+                    ContinuousProfileConfig,
+                    ContinuousProfiler,
+                )
 
-            engine.kvbm = KvBlockManager(
-                engine.scheduler.cache,
-                engine.scheduler.allocator,
-                host_blocks=args.kvbm_host_blocks,
-                disk_dir=args.kvbm_disk_dir,
-                disk_blocks=args.kvbm_disk_blocks,
-            )
-            engine.scheduler.attach_kvbm(engine.kvbm)
+                flight = engine.scheduler.flight
+                engine.continuous_profiler = ContinuousProfiler(
+                    engine.profiler,
+                    ContinuousProfileConfig(
+                        window_s=args.profile_window_s,
+                        interval_s=args.profile_interval_s,
+                    ),
+                    cost_probe=flight.roofline_totals,
+                    sink=flight.record_measured_window,
+                )
+                engine.continuous_profiler.start()
+            if args.kvbm_host_blocks > 0:
+                from dynamo_tpu.llm.block_manager import KvBlockManager
+
+                engine.kvbm = KvBlockManager(
+                    engine.scheduler.cache,
+                    engine.scheduler.allocator,
+                    host_blocks=args.kvbm_host_blocks,
+                    disk_dir=args.kvbm_disk_dir,
+                    disk_blocks=args.kvbm_disk_blocks,
+                )
+                engine.scheduler.attach_kvbm(engine.kvbm)
+        flight = engine.scheduler.flight
+        flight.since_ns = log.last("engine.build")[1]
+        _log_built(flight.builds.summary(flight.since_ns), args.warmup_ctx)
         return engine
 
     def _on_kv_event(self, ev: KvEvent) -> None:

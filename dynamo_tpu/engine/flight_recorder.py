@@ -13,7 +13,9 @@ keeps a host-side, allocation-free account of every dispatch:
   compiled mid-traffic — PR 1's silent killer (decode executables compiling
   under load, measured as the dominant serving-plane latency) — and is
   counted and logged with its shape key so it alerts instead of hiding in
-  p99.
+  p99. A key first seen is a guess that XLA compiled: what JAX really built,
+  with its key and its seconds, is the build log's (``self.builds``,
+  engine/compile_cache.py).
 
 Everything is plain Python ints/floats mutated from the step thread and
 read from the event loop via ``to_stats()`` — last-write-wins races on a
@@ -27,6 +29,7 @@ import time
 from collections import deque
 from typing import Dict, List, Optional, Set, Tuple
 
+from dynamo_tpu.engine.compile_cache import BUILD_LOG
 from dynamo_tpu.runtime.logging import get_logger
 from dynamo_tpu.runtime.tracing import StepLog
 
@@ -257,7 +260,7 @@ class _PhaseRoofline:
 class FlightRecorder:
     """Owned by one Scheduler; mutated on the step thread only."""
 
-    def __init__(self, telemetry=None) -> None:
+    def __init__(self, telemetry=None, log: Optional[StepLog] = None) -> None:
         self._hists: Dict[str, _PhaseHist] = {p: _PhaseHist() for p in PHASES}
         # Optional runtime.telemetry.Telemetry: record_step feeds per-phase
         # ``{phase}_step`` digests so step-duration percentiles merge
@@ -269,8 +272,14 @@ class FlightRecorder:
         self._roofline: Dict[str, _PhaseRoofline] = {}
         # The engine's step log (runtime/tracing.py): every span of the
         # served path and every finished request, and — through record_step's
-        # own entries — the /debug/state step timeline.
-        self.log = StepLog()
+        # own entries — the /debug/state step timeline. ``TpuEngine.build``
+        # hands over the log its ``engine.build`` span opened on.
+        self.log = log if log is not None else StepLog()
+        # What JAX really built (engine/compile_cache.py: the process's log,
+        # fed by JAX's own events), read from ``since_ns`` on: this recorder's
+        # creation, or the start of the ``engine.build`` that made it.
+        self.builds = BUILD_LOG
+        self.since_ns = time.monotonic_ns()
         # Stall watchdog reference point.
         self.last_step_ts: Optional[float] = None
         # Decode host gap: time from a decode dispatch RETURNING (device
@@ -280,6 +289,7 @@ class FlightRecorder:
         self._gap = _PhaseHist(GAP_BUCKETS)
         # Compile tracker state.
         self._exec_keys: Set[tuple] = set()
+        self.last_exec: Optional[tuple] = None  # the newest (kind, *key): a launch's scope in the build log
         self.compiles_total = 0
         self.compiles_after_warmup_total = 0
         self.post_warmup_keys: List[tuple] = []
@@ -468,9 +478,9 @@ class FlightRecorder:
     # --- compile tracking ---------------------------------------------------
     def record_exec(self, kind: str, key: tuple) -> bool:
         """Register a dispatch's executable shape key. Returns True when the
-        key is new (== XLA compiled for it). New keys after warmup are the
-        alert condition."""
-        k = (kind,) + tuple(key)
+        key is new (a guess that XLA compiled for it: what JAX really built
+        is in ``self.builds``). New keys after warmup are the alert condition."""
+        k = self.last_exec = (kind,) + tuple(key)
         if k in self._exec_keys:
             return False
         self._exec_keys.add(k)
@@ -493,6 +503,7 @@ class FlightRecorder:
         counted."""
         self._warmup_done = True
         self._warmed = warmed
+        self.builds.serving = True  # entries outside every scope carry phase "serving" from here on
 
     def exec_key_summary(self) -> Dict[str, List[int]]:
         """{kind: sorted key arities} of every executable key registered so

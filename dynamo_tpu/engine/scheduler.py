@@ -49,7 +49,7 @@ from dynamo_tpu.runtime.telemetry import SloConfig, SloJudge, Telemetry
 from dynamo_tpu.engine.sampling import SamplingParams, guided_sample_batch, make_row_keys, sample_batch
 from dynamo_tpu.llm.tokens import extend_block_hashes
 from dynamo_tpu.runtime.logging import get_logger
-from dynamo_tpu.runtime.tracing import StepSpan, get_tracer
+from dynamo_tpu.runtime.tracing import StepLog, StepSpan, get_tracer
 
 logger = get_logger(__name__)
 
@@ -426,6 +426,7 @@ class Scheduler:
         rng_seed: int = 0,
         mesh=None,
         parallel=None,
+        step_log: Optional[StepLog] = None,
     ):
         from dynamo_tpu.engine.config import resolve_moe_dispatch
 
@@ -509,6 +510,7 @@ class Scheduler:
         # Host-to-device transfers of the iteration in progress (_up), and
         # who sampled each dispatch so far (_note_sampled).
         self._uploads = 0
+        self._built0 = (0, 0)  # the build log's counts when the iteration in progress began (step)
         self.sampled_in_program_total = 0
         self.sampled_on_host_total = 0
         # What the decode rows' attention launches walked, and what their
@@ -532,7 +534,7 @@ class Scheduler:
         # (every dispatch registers its shape key; keys first seen after
         # warmup are counted/logged). Tracer: per-request lifecycle events
         # for sequences whose trace is sampled.
-        self.flight = FlightRecorder(telemetry=self.telemetry)
+        self.flight = FlightRecorder(telemetry=self.telemetry, log=step_log)
         self.tracer = get_tracer()
         # Per-step FLOPs+bytes roofline model from the REAL params/cache
         # byte widths (int8 weights/KV are modeled as stored): BENCH
@@ -1079,6 +1081,9 @@ class Scheduler:
             # and the bucket x (table width + 1) they would span.
             "attn_items_total": self.attn_items_total,
             "attn_slots_total": self.attn_slots_total,
+            # What JAX built for this engine (engine/compile_cache.py): seconds by
+            # phase, by kind, the costliest keys, eager executables, entries since warm-up.
+            "build": f.builds.summary(f.since_ns),
             "flight": {
                 "last_step_phase": f.last_step_phase,
                 "last_step_s": round(f.last_step_s, 6),
@@ -1191,6 +1196,7 @@ class Scheduler:
         outputs: List[tuple] = []
         log = self.flight.log
         log.step += 1
+        self._built0 = self.flight.builds.total, self.flight.builds.total_ns
         with log.span("sched.step") as span:
             self._step_span = span
             self._uploads = 0
@@ -1201,8 +1207,18 @@ class Scheduler:
                 self._end_plan()
                 if self._uploads:
                     span.set(uploads=self._uploads)  # host-to-device transfers of the iteration (_up)
+                if self.flight.builds.total != self._built0[0]:
+                    self._note_built(span)
                 self._step_span = None
         return outputs
+
+    def _note_built(self, span: StepSpan) -> None:
+        """JAX built executables during this iteration (engine/compile_cache.py
+        has each with its key): how many, and their trace, lowering and
+        backend seconds, on its ``sched.step`` entry."""
+        total, total_ns = self._built0
+        builds = self.flight.builds
+        span.set(built=builds.total - total, build_s=(builds.total_ns - total_ns) / 1e9)
 
     def _step(self, outputs: List[tuple]) -> None:
         self._sweep_deadlines()
@@ -1344,11 +1360,25 @@ class Scheduler:
 
     def _launch(self, kind: str, decode: bool = False) -> StepSpan:
         """The ``sched.launch`` span of one program. A decode-family launch
-        first books the decode host gap (see _record_host_gap)."""
+        first books the decode host gap (see _record_host_gap). While the span
+        is open it is the build log's scope on this thread: what JAX builds
+        inside is of ``kind`` and of the key ``record_exec`` was just handed."""
         if decode:
             self._record_host_gap()
-            return self.flight.log.span("sched.launch", kind=kind, decode=True)
-        return self.flight.log.span("sched.launch", kind=kind)
+            span = self.flight.log.span("sched.launch", kind=kind, decode=True)
+        else:
+            span = self.flight.log.span("sched.launch", kind=kind)
+        self.flight.builds.launching(span, self.flight.last_exec)
+        return span
+
+    def _building(self, kind: Optional[str] = None, key: tuple = ()):
+        """A ``build.key`` scope (engine/compile_cache.py): what JAX builds
+        inside belongs to the executable key ``(kind, *key)``; with no
+        argument, to the key ``record_exec`` was just handed (as a launch's
+        does in serving)."""
+        if kind is None:
+            kind, key = self.flight.last_exec[0], self.flight.last_exec[1:]
+        return self.flight.builds.scope(self.flight.log, "build.key", kind=kind, key=key)
 
     def _since(self, span: StepSpan) -> float:
         """Seconds since ``span`` began: a dispatch's timed part runs from
@@ -2156,8 +2186,10 @@ class Scheduler:
         try:
             buf = jnp.zeros((3 * bucket,), jnp.int32)
             tables = jnp.zeros((bucket, width), jnp.int32)
-            compiled = self._decode_jit.lower(self.params, self.cache.k, self.cache.v, buf, tables).compile()
-            cost = compiled.cost_analysis()
+            # (The decode executable of this shape is built here, under the kind
+            # "calibrate": the warm-up call of the same key then finds it built.)
+            with self._building("calibrate", (bucket, width)):
+                cost = self._decode_jit.lower(self.params, self.cache.k, self.cache.v, buf, tables).compile().cost_analysis()
             if isinstance(cost, (list, tuple)):
                 cost = cost[0] if cost else {}
             flops = float(cost.get("flops", 0.0) or 0.0)
@@ -2170,6 +2202,14 @@ class Scheduler:
         except Exception as e:  # noqa: BLE001 — calibration is best-effort
             logger.debug("cost_analysis calibration unavailable: %s", e)
 
+    def _sampler_args(self, rows: int, key) -> tuple:
+        """What warm-up hands the host path's samplers at ``rows`` rows: logits, temperature, top-k, top-p, key."""
+        return (
+            jnp.zeros((rows, self.mc.vocab_size), jnp.float32),
+            jnp.zeros((rows,), jnp.float32), jnp.zeros((rows,), jnp.int32),
+            jnp.ones((rows,), jnp.float32), key, None,
+        )
+
     def warmup(self, ctx_tokens: int = 2048) -> int:
         """Precompile the serving-hot executables so traffic never waits on
         XLA (the reference's engines warm up at startup for the same reason;
@@ -2178,7 +2218,16 @@ class Scheduler:
         multi-step window variant when enabled, fresh-prefill chunks per
         bucket, and the sampler per bucket. Dispatches run with all rows
         inactive, so writes land in the reserved scratch block 0 and cache
-        contents are untouched. Returns the number of executables warmed."""
+        contents are untouched. Returns the number of executable KEYS warmed
+        (the calls made); what JAX built for each is in the build log
+        (engine/compile_cache.py), whose ``build.key`` scopes these calls open:
+        a key may be several executables, or none that was not built already.
+        Array arguments are made before a key's scope opens, so that their
+        fills stay the eager executables they are. (No function stands between
+        this one and its jitted calls, and its frame is no larger than it was:
+        the bytes of the Python frames under a jitted call decide where
+        CPython's stack chunks end inside JAX's lowering, and a warm set-up's
+        lowering seconds move with them: PERF.md §6, PR 39.)"""
         bs = self.mc.block_size
         max_w = self._width_bucket((ctx_tokens + bs - 1) // bs)
         widths = sorted(set(min(r, self.max_blocks_per_seq) for r in width_rungs(max_w)))
@@ -2191,70 +2240,64 @@ class Scheduler:
             for width in widths:
                 tables = jnp.zeros((bucket, width), jnp.int32)
                 self.flight.record_exec("decode", (bucket, width))
-                _, _, self.cache.k, self.cache.v = self._consume_aux(  # (all rows inactive)
-                    self._decode_jit(
-                        self.params, self.cache.k, self.cache.v, jax.device_put(pack_operands(self._pack_rows([], bucket))), tables
+                with self._building():
+                    _, _, self.cache.k, self.cache.v = self._consume_aux(  # (all rows inactive)
+                        self._decode_jit(
+                            self.params, self.cache.k, self.cache.v, jax.device_put(pack_operands(self._pack_rows([], bucket))), tables
+                        )
                     )
-                )
                 count += 1
                 if self.sc.num_scheduler_steps > 1 and self._supports_multi_step:
                     # (All rows inactive and greedy, a key of zeros.)
                     buf = jax.device_put(pack_operands(self._pack_rows([], bucket, sampler=True), (0, 0)))
                     for w, mjit in self._decode_multi_jits.items():
                         self.flight.record_exec("decode_multi", (w, bucket, width))
-                        _, self.cache.k, self.cache.v = self._consume_aux(
-                            mjit(self.params, self.cache.k, self.cache.v, buf, tables)
-                        )
+                        with self._building():
+                            _, self.cache.k, self.cache.v = self._consume_aux(
+                                mjit(self.params, self.cache.k, self.cache.v, buf, tables)
+                            )
                         count += 1
-            self._sample_jit(
-                jnp.zeros((bucket, self.mc.vocab_size), jnp.float32),
-                jnp.zeros((bucket,), jnp.float32), jnp.zeros((bucket,), jnp.int32),
-                jnp.ones((bucket,), jnp.float32), key, None,
-            )
-            # Fused logprobs variant too: a logprobs row joining a warmed
-            # batch must not compile the sampler mid-traffic.
-            self._sample_lp_jit(
-                jnp.zeros((bucket, self.mc.vocab_size), jnp.float32),
-                jnp.zeros((bucket,), jnp.float32), jnp.zeros((bucket,), jnp.int32),
-                jnp.ones((bucket,), jnp.float32), key, None,
-            )
-            # ... and the top-k variant (OpenAI top_logprobs; static
-            # candidate cap, so one warm covers every requested k).
-            self._sample_tlp_jit(
-                jnp.zeros((bucket, self.mc.vocab_size), jnp.float32),
-                jnp.zeros((bucket,), jnp.float32), jnp.zeros((bucket,), jnp.int32),
-                jnp.ones((bucket,), jnp.float32), key, None,
-            )
+            # The sampler, its fused logprobs variant (a logprobs row joining a
+            # warmed batch must not compile the sampler mid-traffic) and the
+            # top-k variant (OpenAI top_logprobs; static candidate cap, so one
+            # warm covers every requested k).
+            args = self._sampler_args(bucket, key)
+            with self._building("sampler", (bucket,)):
+                self._sample_jit(*args)
+                self._sample_lp_jit(*args)
+                self._sample_tlp_jit(*args)
             # ... and the keys of a batch that holds a seeded sampled row.
-            make_row_keys(key, jnp.zeros((bucket,), jnp.int32), jnp.zeros((bucket,), jnp.int32), jnp.zeros((bucket,), bool))
+            args = (key, jnp.zeros((bucket,), jnp.int32), jnp.zeros((bucket,), jnp.int32), jnp.zeros((bucket,), bool))
+            with self._building("sampler", ("row_keys", bucket)):
+                make_row_keys(*args)
             count += 4
         # The host path's first-token logprobs (its sampler at one row is warmed below).
-        tok = jnp.zeros((1,), jnp.int32)
-        self._lp_jit(jnp.zeros((1, self.mc.vocab_size), jnp.float32), tok)
-        self._tlp_jit(jnp.zeros((1, self.mc.vocab_size), jnp.float32), tok)
+        args = (jnp.zeros((1, self.mc.vocab_size), jnp.float32), jnp.zeros((1,), jnp.int32))
+        with self._building("sampler", ("first_token_logprobs",)):
+            self._lp_jit(*args)
+            self._tlp_jit(*args)
         count += 2
         if self._hybrid:
             # Taking a slot: one executable, warmed on the scratch slot and block.
             self.flight.record_exec("open_slot", ())
-            self.cache.k, self.cache.v = self._open_slot_jit(self.cache.k, self.cache.v, jnp.int32(0), jnp.int32(0))
+            with self._building():
+                self.cache.k, self.cache.v = self._open_slot_jit(self.cache.k, self.cache.v, jnp.int32(0), jnp.int32(0))
             count += 1
         if self._eva:
             # The roll program: one executable, warmed against the scratch
             # block (a table of zeros reads and writes block 0).
             self.flight.record_exec("eva_roll", ())
-            self.cache.k, self.cache.v = self._roll_jit(
-                self.params, self.cache.k, self.cache.v,
-                jnp.zeros((self._roll_blocks,), jnp.int32), jnp.int32(0),
-            )
+            tables = jnp.zeros((self._roll_blocks,), jnp.int32)
+            with self._building():
+                self.cache.k, self.cache.v = self._roll_jit(self.params, self.cache.k, self.cache.v, tables, jnp.int32(0))
             count += 1
         # Prefix-cache copy-on-write block copy: one executable, warmed
         # against the scratch block so a full-cover hit under traffic never
         # compiles (0-post-warmup invariant with prefix caching enabled).
         if self.sc.enable_prefix_caching:
             self.flight.record_exec("kv_block_copy", ())
-            self.cache.k, self.cache.v = self._kv_copy_jit(
-                self.cache.k, self.cache.v, jnp.int32(0), jnp.int32(0)
-            )
+            with self._building():
+                self.cache.k, self.cache.v = self._kv_copy_jit(self.cache.k, self.cache.v, jnp.int32(0), jnp.int32(0))
             count += 1
         # Guided masked-sampling executables: one per decode bucket (plus
         # the bucket-1 prefill-tail sampler) at the current pool capacity —
@@ -2264,24 +2307,16 @@ class Scheduler:
             P = int(pool.shape[0])
             for bucket in sorted(set(self.sc.decode_buckets) | {1}):
                 self.flight.record_exec("guided_sample", (bucket, P))
-                self._guided_sample_jit(
+                args = (
                     jnp.zeros((bucket, self.mc.vocab_size), jnp.float32), pool,
                     jnp.zeros((2, bucket), jnp.int32),
                     jnp.zeros((bucket,), jnp.float32),
                     jnp.ones((bucket,), jnp.float32), key, None,
                 )
-                self._guided_sample_lp_jit(
-                    jnp.zeros((bucket, self.mc.vocab_size), jnp.float32), pool,
-                    jnp.zeros((2, bucket), jnp.int32),
-                    jnp.zeros((bucket,), jnp.float32),
-                    jnp.ones((bucket,), jnp.float32), key, None,
-                )
-                self._guided_sample_tlp_jit(
-                    jnp.zeros((bucket, self.mc.vocab_size), jnp.float32), pool,
-                    jnp.zeros((2, bucket), jnp.int32),
-                    jnp.zeros((bucket,), jnp.float32),
-                    jnp.ones((bucket,), jnp.float32), key, None,
-                )
+                with self._building():
+                    self._guided_sample_jit(*args)
+                    self._guided_sample_lp_jit(*args)
+                    self._guided_sample_tlp_jit(*args)
                 count += 3
         prev_bucket = 0
         for bucket in self.sc.prefill_buckets:
@@ -2311,28 +2346,28 @@ class Scheduler:
             for width in p_widths:
                 # Where has_prefix is static (_hp_static) both variants: fresh
                 # prefills AND chunked / prefix-hit continuations.
+                tables = jnp.zeros((width,), jnp.int32)
                 for hp in (False, True) if self._hp_static else (False,):
                     self.flight.record_exec("prefill", (bucket, width, hp))
-                    _, _, self.cache.k, self.cache.v = self._consume_aux(
-                        self._prefill_jit(
-                            self.params, self.cache.k, self.cache.v,
-                            jax.device_put(pack_operands(np.zeros((bucket,), np.int32), 1, 0)),  # one valid token at position 0
-                            jnp.zeros((width,), jnp.int32), *((hp,) if self._hp_static else ()),
+                    with self._building():
+                        _, _, self.cache.k, self.cache.v = self._consume_aux(
+                            self._prefill_jit(
+                                self.params, self.cache.k, self.cache.v,
+                                jax.device_put(pack_operands(np.zeros((bucket,), np.int32), 1, 0)),  # one valid token at position 0
+                                tables, *((hp,) if self._hp_static else ()),
+                            )
                         )
-                    )
                     count += 1
                 if self.draft_params is not None:
-                    _, self.draft_cache.k, self.draft_cache.v = self._d_prefill_jit(
-                        self.draft_params, self.draft_cache.k, self.draft_cache.v,
-                        jnp.zeros((bucket,), jnp.int32), jnp.int32(1), jnp.int32(0),
-                        jnp.zeros((width,), jnp.int32),
-                    )
+                    args = (jnp.zeros((bucket,), jnp.int32), jnp.int32(1), jnp.int32(0), tables)
+                    with self._building("draft_prefill", (bucket, width)):
+                        _, self.draft_cache.k, self.draft_cache.v = self._d_prefill_jit(
+                            self.draft_params, self.draft_cache.k, self.draft_cache.v, *args
+                        )
                     count += 1
-            self._sample_jit(
-                jnp.zeros((1, self.mc.vocab_size), jnp.float32),
-                jnp.zeros((1,), jnp.float32), jnp.zeros((1,), jnp.int32),
-                jnp.ones((1,), jnp.float32), key, None,
-            )
+            args = self._sampler_args(1, key)
+            with self._building("sampler", (1,)):
+                self._sample_jit(*args)
             count += 1
             # Wave-admission executables for this chunk bucket: every batch
             # rung a wave can form (≥2 admitted) × the table-width rungs
@@ -2358,13 +2393,12 @@ class Scheduler:
                 for b_b in (b for b in self.sc.decode_buckets if b >= 2):
                     for w in wave_ws:
                         self.flight.record_exec("admit", (b_b, bucket, w))
-                        _, self.cache.k, self.cache.v = self._consume_aux(
-                            self._get_admit_jit((b_b, bucket, w))(
-                                self.params, self.cache.k, self.cache.v,
-                                jnp.zeros((b_b, bucket), jnp.int32), jnp.zeros((b_b,), jnp.int32),
-                                jnp.zeros((b_b,), jnp.int32), jnp.zeros((b_b, w), jnp.int32),
+                        args = (jnp.zeros((b_b, bucket), jnp.int32), jnp.zeros((b_b,), jnp.int32),
+                                jnp.zeros((b_b,), jnp.int32), jnp.zeros((b_b, w), jnp.int32))
+                        with self._building():
+                            _, self.cache.k, self.cache.v = self._consume_aux(
+                                self._get_admit_jit((b_b, bucket, w))(self.params, self.cache.k, self.cache.v, *args)
                             )
-                        )
                         count += 1
         # Mixed prefill+decode executables: every budget-sized chunk bucket
         # the capacity dial can produce (_mixed_warm_buckets — a ratio
@@ -2393,15 +2427,16 @@ class Scheduler:
                             (s_b, p_w, bucket, width)
                             + ((hp,) if self._hp_static else ()),
                         )
-                        res = self._get_mixed_jit((s_b, p_w, bucket, width))(
-                            self.params, self.cache.k, self.cache.v,
-                            jax.device_put(pack_operands(
-                                np.zeros((s_b,), np.int32), 1, 0, self._pack_rows([], bucket), np.zeros((p_w,), np.int32)
-                            )),
-                            jnp.zeros((bucket, width), jnp.int32),
-                            *((hp,) if self._hp_static else ()),
-                        )
-                        _, _, _, self.cache.k, self.cache.v = self._consume_aux(res)
+                        tables = jnp.zeros((bucket, width), jnp.int32)
+                        buf = jax.device_put(pack_operands(
+                            np.zeros((s_b,), np.int32), 1, 0, self._pack_rows([], bucket), np.zeros((p_w,), np.int32)
+                        ))
+                        with self._building():
+                            res = self._get_mixed_jit((s_b, p_w, bucket, width))(
+                                self.params, self.cache.k, self.cache.v, buf, tables,
+                                *((hp,) if self._hp_static else ()),
+                            )
+                            _, _, _, self.cache.k, self.cache.v = self._consume_aux(res)
                         count += 1
         # Speculative-round executables (draft chunk+sample, γ-1 proposal
         # window, target chunk scoring, rejection verify): _decode_spec keys
@@ -2423,38 +2458,39 @@ class Scheduler:
                     toks = jnp.zeros((bucket, S), jnp.int32)
                     pos0 = jnp.zeros((bucket,), jnp.int32)
                     valid = jnp.zeros((bucket,), jnp.int32)
-                    tok1, lg1, self.draft_cache.k, self.draft_cache.v = (
-                        self._d_chunk_sample_jit(
-                            self.draft_params, self.draft_cache.k, self.draft_cache.v,
-                            toks, pos0, valid, tables, temps, tks, tps, key,
-                        )
-                    )
-                    count += 1
-                    if gamma > 1:
-                        _, lg_steps, self.draft_cache.k, self.draft_cache.v = (
-                            self._d_multi_jit(
+                    with self._building():  # its four executables, and the glue between them
+                        tok1, lg1, self.draft_cache.k, self.draft_cache.v = (
+                            self._d_chunk_sample_jit(
                                 self.draft_params, self.draft_cache.k, self.draft_cache.v,
-                                tok1, pos0, tables, jnp.zeros((bucket,), bool),
-                                temps, tks, tps, key,
+                                toks, pos0, valid, tables, temps, tks, tps, key,
                             )
                         )
-                        draft_logits = jnp.concatenate(
-                            [lg1[:, None], jnp.transpose(lg_steps, (1, 0, 2))], axis=1
-                        )
                         count += 1
-                    else:
-                        draft_logits = lg1[:, None]
-                    t_logits, self.cache.k, self.cache.v = self._consume_aux(
-                        self._t_chunk_jit(
-                            self.params, self.cache.k, self.cache.v,
-                            toks, pos0, valid, tables,
+                        if gamma > 1:
+                            _, lg_steps, self.draft_cache.k, self.draft_cache.v = (
+                                self._d_multi_jit(
+                                    self.draft_params, self.draft_cache.k, self.draft_cache.v,
+                                    tok1, pos0, tables, jnp.zeros((bucket,), bool),
+                                    temps, tks, tps, key,
+                                )
+                            )
+                            draft_logits = jnp.concatenate(
+                                [lg1[:, None], jnp.transpose(lg_steps, (1, 0, 2))], axis=1
+                            )
+                            count += 1
+                        else:
+                            draft_logits = lg1[:, None]
+                        t_logits, self.cache.k, self.cache.v = self._consume_aux(
+                            self._t_chunk_jit(
+                                self.params, self.cache.k, self.cache.v,
+                                toks, pos0, valid, tables,
+                            )
                         )
-                    )
-                    self._spec_verify_jit(
-                        draft_logits, t_logits,
-                        jnp.zeros((bucket, gamma), jnp.int32),
-                        temps, tks, tps, key,
-                    )
+                        self._spec_verify_jit(
+                            draft_logits, t_logits,
+                            jnp.zeros((bucket, gamma), jnp.int32),
+                            temps, tks, tps, key,
+                        )
                     count += 2
         return count
 
