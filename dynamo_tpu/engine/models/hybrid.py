@@ -66,7 +66,6 @@ from dynamo_tpu.engine.models.llama import (  # noqa: F401 — the scheduler rea
     _scatter_kv,
     _split_expert_stacks,
     _use_megakernel,
-    apply_rope,
     chunk_attn_path,
     decode_targets,
     resolve_attention_impl,
@@ -176,11 +175,10 @@ def _qkv(c: ModelConfig, lp, x: jax.Array, positions: jax.Array):
     """Queries (with the stated scale folded in, so every attention path's own
     ``head_dim ** -0.5`` makes it up), keys and values of ``x``'s rows."""
     R = x.shape[0]
-    q = (x @ lp["wq"]).reshape(R, c.num_heads, c.head_dim)
-    k = (x @ lp["wk"]).reshape(R, c.num_kv_heads, c.head_dim)
+    rotate = positions if c.use_rope else None
+    q = llama.project_heads(x, lp["wq"], c.num_heads, rotate, c.rope_theta)
+    k = llama.project_heads(x, lp["wk"], c.num_kv_heads, rotate, c.rope_theta)
     v = (x @ lp["wv"]).reshape(R, c.num_kv_heads, c.head_dim)
-    if c.use_rope:
-        q, k = apply_rope(q, positions, c.rope_theta), apply_rope(k, positions, c.rope_theta)
     if c.attention_scale:
         q = (q.astype(jnp.float32) * (c.attention_scale * c.head_dim ** 0.5)).astype(q.dtype)
     return q, k, v

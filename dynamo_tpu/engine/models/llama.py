@@ -242,6 +242,46 @@ def apply_rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
     return out.astype(x.dtype)
 
 
+def project_heads(x: jax.Array, w: jax.Array, heads: int, positions: Optional[jax.Array] = None, theta: float = 0.0) -> jax.Array:
+    """``x @ w`` cut into heads, ``[..., heads, w.shape[-1] // heads]``, and
+    rotated where ``positions`` (``x``'s shape less its last axis) are given.
+    Every q and k projection of a step program goes through here.
+
+    The barrier holds the product as the dot writes it, row-major
+    ``[rows, heads * head_dim]`` like ``wv``'s, ``wo``'s and the FFN's, so
+    that what gets re-laid to heads is the activation (at most a chunk's
+    rows). Without it XLA:TPU carries the head-major layout that rope and the
+    attention kernels want back through the reshape into the dot, writes the
+    product head by head, and for that re-lays the WEIGHT: a window's program
+    held ``copy.69 = bf16[L,4096,4096]{1,2,0} copy(p.layers.wq)`` (both whole
+    stacks transposed once a dispatch) and a mixed step
+    ``copy.196 = bf16[1,4096,4096]{1,2,0} copy(constant_dynamic-slice_fusion.5)``
+    (a layer's slice of ``wq``) inside its layer scan: 0.64 s of copies and
+    1.07 s of slices in 11 s of device time on ``evabyte-d16.doc-bytes``
+    (ledger, PR 39; PERF.md section 6, PR 40). A barrier after the reshape does
+    not do: it is the reshape of the dot's own result that carries the layout.
+    ``tests/test_tpu_compile.py::test_no_step_program_re_lays_a_weight`` reads
+    the compiled programs.
+
+    On a TPU the product stays float32 from the dot to the end of the rotation
+    and is rounded to ``x.dtype`` once, there: what the programs computed there
+    before the barrier, when XLA fused the dot with the rotation's widening
+    and never rounded between them (``convert_bitcast_fusion =
+    f32[B,1,32,128]``; excess precision it is allowed). A barrier on the bf16
+    product forces that rounding: the output check's ``rel_err`` on
+    ``evabyte-d16.doc-bytes`` then read 0.0092-0.0096 where it had read
+    0.0089-0.0092 on the same four seeds; so it reads 0.0089-0.0092, and the
+    two ``llama`` cells read the parent's to the last digit. Off the TPU XLA
+    did round there, and so does this: the CPU's results
+    (``tests/benchmark/frozen_parent.json``) stay what they were."""
+    wide = jnp.float32 if _on_tpu() else x.dtype
+    y = lax.optimization_barrier(jnp.dot(x, w, preferred_element_type=wide))
+    y = y.reshape(*x.shape[:-1], heads, w.shape[-1] // heads)
+    if positions is not None:
+        y = apply_rope(y, positions, theta)
+    return y.astype(x.dtype)
+
+
 def _route(x: jax.Array, lp: Dict[str, jax.Array], K: int):
     """Top-k routing: (weights [T,K] f32 softmax over the chosen experts,
     expert ids [T,K] i32)."""
@@ -834,11 +874,9 @@ def prefill(
         lp, l = xs  # l: scalar layer index
         lp = dequant_layer(lp, wdtype)  # int8 weight-only storage
         x = _norm(c, h, lp["attn_norm"], wdtype)
-        q = (x @ lp["wq"]).reshape(T, c.num_heads, c.head_dim)
-        k = (x @ lp["wk"]).reshape(T, c.num_kv_heads, c.head_dim)
+        q = project_heads(x, lp["wq"], c.num_heads, positions, c.rope_theta)
+        k = project_heads(x, lp["wk"], c.num_kv_heads, positions, c.rope_theta)
         v = (x @ lp["wv"]).reshape(T, c.num_kv_heads, c.head_dim)
-        q = apply_rope(q, positions, c.rope_theta)
-        k = apply_rope(k, positions, c.rope_theta)
 
         if use_mega:
             attn = _mega_attend_rows(
@@ -1200,12 +1238,9 @@ def _decode_layer_scan_window(
             lp, l, kwl, vwl = xs  # kwl/vwl: [w, B, KVH, HD] this layer's window rows
         lp = dequant_layer(lp, wdtype)  # int8 weight-only storage
         x = _norm(c, h, lp["attn_norm"], wdtype)
-        q = (x @ lp["wq"]).reshape(B, 1, c.num_heads, c.head_dim)
-        k = (x @ lp["wk"]).reshape(B, 1, c.num_kv_heads, c.head_dim)
-        v = (x @ lp["wv"]).reshape(B, 1, c.num_kv_heads, c.head_dim)
-        q = apply_rope(q, positions[:, None], c.rope_theta)[:, 0]
-        k = apply_rope(k, positions[:, None], c.rope_theta)[:, 0]
-        v = v[:, 0]
+        q = project_heads(x, lp["wq"], c.num_heads, positions, c.rope_theta)
+        k = project_heads(x, lp["wk"], c.num_kv_heads, positions, c.rope_theta)
+        v = (x @ lp["wv"]).reshape(B, c.num_kv_heads, c.head_dim)
         qg = q.reshape(B, kvh, G, hd)
 
         if use_mega:
@@ -1341,11 +1376,9 @@ def chunk_decode(
         lp, l = xs
         lp = dequant_layer(lp, wdtype)  # int8 weight-only storage
         x = _norm(c, h, lp["attn_norm"], wdtype)
-        q = (x @ lp["wq"]).reshape(B, S, c.num_heads, hd)
-        k = (x @ lp["wk"]).reshape(B, S, kvh, hd)
+        q = project_heads(x, lp["wq"], c.num_heads, positions, c.rope_theta)
+        k = project_heads(x, lp["wk"], kvh, positions, c.rope_theta)
         v = (x @ lp["wv"]).reshape(B, S, kvh, hd)
-        q = apply_rope(q, positions, c.rope_theta)
-        k = apply_rope(k, positions, c.rope_theta)
         qg = q.reshape(B, S, kvh, G, hd)
 
         tables_l = block_tables + l * N
@@ -1515,11 +1548,9 @@ def mixed_step(
         lp, l = xs
         lp = dequant_layer(lp, wdtype)  # int8 weight-only storage
         x = _norm(c, h, lp["attn_norm"], wdtype)
-        q = (x @ lp["wq"]).reshape(S + B, c.num_heads, hd)
-        k = (x @ lp["wk"]).reshape(S + B, kvh, hd)
+        q = project_heads(x, lp["wq"], c.num_heads, positions_all, c.rope_theta)
+        k = project_heads(x, lp["wk"], kvh, positions_all, c.rope_theta)
         v = (x @ lp["wv"]).reshape(S + B, kvh, hd)
-        q = apply_rope(q, positions_all, c.rope_theta)
-        k = apply_rope(k, positions_all, c.rope_theta)
 
         if use_mega:
             # Each piece's fresh keys are its own rows of the projection.
@@ -1632,8 +1663,8 @@ def embed(
         lp, l = xs
         lp = dequant_layer(lp, wdtype)  # int8 weight-only storage
         x = _norm(c, h, lp["attn_norm"], wdtype)
-        q = apply_rope((x @ lp["wq"]).reshape(T, c.num_heads, c.head_dim), positions, c.rope_theta)
-        k = apply_rope((x @ lp["wk"]).reshape(T, c.num_kv_heads, c.head_dim), positions, c.rope_theta)
+        q = project_heads(x, lp["wq"], c.num_heads, positions, c.rope_theta)
+        k = project_heads(x, lp["wk"], c.num_kv_heads, positions, c.rope_theta)
         v = (x @ lp["wv"]).reshape(T, c.num_kv_heads, c.head_dim)
         attn = _attend(q, k, v, mask, c)
         h = h + attn.reshape(T, c.q_size) @ lp["wo"]
@@ -1732,12 +1763,9 @@ def decode_layer_scan(
         lp, l = xs  # l: scalar layer index within this stack
         lp = dequant_layer(lp, wdtype)  # int8 weight-only storage
         x = _norm(c, h, lp["attn_norm"], wdtype)
-        q = (x @ lp["wq"]).reshape(B, 1, c.num_heads, c.head_dim)
-        k = (x @ lp["wk"]).reshape(B, 1, c.num_kv_heads, c.head_dim)
-        v = (x @ lp["wv"]).reshape(B, 1, c.num_kv_heads, c.head_dim)
-        q = apply_rope(q, positions[:, None], c.rope_theta)[:, 0]  # [B, H, hd]
-        k = apply_rope(k, positions[:, None], c.rope_theta)[:, 0]  # [B, KVH, hd]
-        v = v[:, 0]
+        q = project_heads(x, lp["wq"], c.num_heads, positions, c.rope_theta)  # [B, H, hd]
+        k = project_heads(x, lp["wk"], c.num_kv_heads, positions, c.rope_theta)  # [B, KVH, hd]
+        v = (x @ lp["wv"]).reshape(B, c.num_kv_heads, c.head_dim)
         qg = q.reshape(B, kvh, G, hd)
 
         tables_l = block_tables + l * N

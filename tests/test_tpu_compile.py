@@ -13,7 +13,10 @@ file. ``llama._on_tpu`` is steered by monkeypatch here, not by an option of
 the program.
 """
 
+import functools
+import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -23,6 +26,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSh
 from dynamo_tpu.engine.config import get_config
 from dynamo_tpu.engine.kv_cache import QuantKv
 from dynamo_tpu.engine.models import llama
+from dynamo_tpu.engine.quant import QUANT_KEYS, QuantW
 from dynamo_tpu.engine.sharding import bind_mesh, kv_cache_spec, param_specs
 
 CFG = get_config("llama-3.2-1b")  # 16 layers, hidden 2048, 32/8 heads, HD 64, vocab 128256
@@ -234,11 +238,44 @@ EVA = get_config("tiny-eva").replace(
 EVA_BLOCKS = 256  # a pool of 1.07 GB: a copy of it, or of half of it, stands out among the temporaries
 
 
-def _eva_args(sh):
-    shapes = jax.eval_shape(lambda: llama.init_params(EVA, jax.random.PRNGKey(0), dtype=BF16))
-    params = jax.tree.map(lambda s: _sds(s.shape, s.dtype, sh), shapes)
-    cache = _sds((EVA.num_layers, EVA_BLOCKS, EVA.block_size, EVA.kv_size), BF16, sh)
-    return params, cache, cache
+def _llama_step_program(cfg, params, program, sh, blocks, S, Bd, Wd):
+    """``mixed_step`` (a chunk of ``S`` with a cached prefix beside ``Bd`` decode
+    rows) or ``decode_multi_w8`` of ``llama.py`` as the scheduler jits them,
+    compiled for the described chip."""
+    i32 = jnp.int32
+    k = v = _sds((cfg.num_layers, blocks, cfg.block_size, cfg.kv_size), BF16, sh)
+    if program == "mixed_step":
+        fn = lambda p, k, v, pt, pv, cl, ptab, dt, dpos, dtab, dact: llama.mixed_step(  # noqa: E731
+            p, cfg, k, v, pt, pv, cl, ptab, dt, dpos, dtab, dact, use_flash=True, has_prefix=True)
+        args = (_sds((S,), i32, sh), _sds((), i32, sh), _sds((), i32, sh), _sds((Wd,), i32, sh), _sds((Bd,), i32, sh),
+                _sds((Bd,), i32, sh), _sds((Bd, Wd), i32, sh), _sds((Bd,), jnp.bool_, sh))
+    else:
+        fn = lambda p, k, v, t, pos, bt, act, te, tk, tp, key: llama.decode_multi(  # noqa: E731
+            p, cfg, k, v, t, pos, bt, act, te, tk, tp, key, 8)
+        args = (_sds((Bd,), i32, sh), _sds((Bd,), i32, sh), _sds((Bd, Wd), i32, sh), _sds((Bd,), jnp.bool_, sh),
+                _sds((Bd,), jnp.float32, sh), _sds((Bd,), i32, sh), _sds((Bd,), jnp.float32, sh), _sds((2,), jnp.uint32, sh))
+    return jax.jit(fn, donate_argnums=(1, 2)).lower(params, k, v, *args).compile()
+
+
+def _param_shapes(cfg, sh, int8=False):
+    """The params tree as shapes; ``int8``: as ``quant.quantize_params`` leaves it."""
+    params = jax.tree.map(lambda s: _sds(s.shape, s.dtype, sh),
+                          jax.eval_shape(lambda: llama.init_params(cfg, jax.random.PRNGKey(0), dtype=BF16)))
+    if int8:
+        for key in QUANT_KEYS:
+            w = params["layers"][key]
+            params["layers"][key] = QuantW(_sds(w.shape, jnp.int8, sh), _sds((*w.shape[:-2], 1, w.shape[-1]), jnp.float32, sh))
+    return params
+
+
+@functools.cache  # a program that two tests read is compiled once
+def _eva_compiled(sh, program):
+    """The cell's step programs: bucket 16, tables of 20 blocks."""
+    if program != "eva_roll":
+        return _llama_step_program(EVA, _param_shapes(EVA, sh), program, sh, EVA_BLOCKS, 256, 16, 20)
+    k = v = _sds((EVA.num_layers, EVA_BLOCKS, EVA.block_size, EVA.kv_size), BF16, sh)
+    return jax.jit(lambda p, k, v, t, r0: llama.eva_roll(p, EVA, k, v, t, r0), donate_argnums=(1, 2)).lower(
+        _param_shapes(EVA, sh), k, v, _sds((llama.eva_roll_blocks(EVA),), jnp.int32, sh), _sds((), jnp.int32, sh)).compile()
 
 
 @pytest.mark.parametrize("program", ["mixed_step", "decode_multi_w8", "eva_roll"])
@@ -249,23 +286,7 @@ def test_eva_step_programs_compile_and_hold_no_copy_of_the_pool(one_chip, on_tpu
     may hold a temporary of the pool's size: XLA:TPU lowers a gather of whole
     4096-lane blocks by slicing the pool in halves (PERF.md section 6, PR 28),
     so one sequence's table is read by dynamic slices (``llama._GATHER_MAX_LANES``)."""
-    p, k, v = _eva_args(one_chip)
-    i32, sh = jnp.int32, one_chip
-    S, Bd, Wd = 256, 16, 20
-    if program == "mixed_step":
-        fn = lambda p, k, v, pt, pv, cl, ptab, dt, dpos, dtab, dact: llama.mixed_step(  # noqa: E731
-            p, EVA, k, v, pt, pv, cl, ptab, dt, dpos, dtab, dact, use_flash=True, has_prefix=True)
-        args = (_sds((S,), i32, sh), _sds((), i32, sh), _sds((), i32, sh), _sds((Wd,), i32, sh), _sds((Bd,), i32, sh),
-                _sds((Bd,), i32, sh), _sds((Bd, Wd), i32, sh), _sds((Bd,), jnp.bool_, sh))
-    elif program == "decode_multi_w8":
-        fn = lambda p, k, v, t, pos, bt, act, te, tk, tp, key: llama.decode_multi(  # noqa: E731
-            p, EVA, k, v, t, pos, bt, act, te, tk, tp, key, 8)
-        args = (_sds((Bd,), i32, sh), _sds((Bd,), i32, sh), _sds((Bd, Wd), i32, sh), _sds((Bd,), jnp.bool_, sh),
-                _sds((Bd,), jnp.float32, sh), _sds((Bd,), i32, sh), _sds((Bd,), jnp.float32, sh), _sds((2,), jnp.uint32, sh))
-    else:
-        fn = lambda p, k, v, t, r0: llama.eva_roll(p, EVA, k, v, t, r0)  # noqa: E731
-        args = (_sds((llama.eva_roll_blocks(EVA),), i32, sh), _sds((), i32, sh))
-    compiled = jax.jit(fn, donate_argnums=(1, 2)).lower(p, k, v, *args).compile()
+    compiled = _eva_compiled(one_chip, program)
     text = compiled.as_text()
     assert ("tpu_custom_call" in text) == (program != "eva_roll")
     if program == "mixed_step":
@@ -286,22 +307,7 @@ def test_moe_step_programs_hold_no_copy_of_an_expert_stack(one_chip, on_tpu, pro
     it (XLA:TPU fuses no slice into that operation): 0.97-1.08 GB of
     temporaries in these programs before PR 29, 0.03-0.14 GB since
     (PERF.md section 6, PR 29)."""
-    shapes = jax.eval_shape(lambda: llama.init_params(MOE, jax.random.PRNGKey(0), dtype=BF16))
-    p = jax.tree.map(lambda s: _sds(s.shape, s.dtype, one_chip), shapes)
-    k = v = _sds((MOE.num_layers, 512, MOE.block_size, MOE.kv_size), BF16, one_chip)
-    i32, sh = jnp.int32, one_chip
-    S, Bd, Wd = 256, 32, 16
-    if program == "mixed_step":
-        fn = lambda p, k, v, pt, pv, cl, ptab, dt, dpos, dtab, dact: llama.mixed_step(  # noqa: E731
-            p, MOE, k, v, pt, pv, cl, ptab, dt, dpos, dtab, dact, use_flash=True, has_prefix=True)
-        args = (_sds((S,), i32, sh), _sds((), i32, sh), _sds((), i32, sh), _sds((Wd,), i32, sh), _sds((Bd,), i32, sh),
-                _sds((Bd,), i32, sh), _sds((Bd, Wd), i32, sh), _sds((Bd,), jnp.bool_, sh))
-    else:
-        fn = lambda p, k, v, t, pos, bt, act, te, tk, tp, key: llama.decode_multi(  # noqa: E731
-            p, MOE, k, v, t, pos, bt, act, te, tk, tp, key, 8)
-        args = (_sds((Bd,), i32, sh), _sds((Bd,), i32, sh), _sds((Bd, Wd), i32, sh), _sds((Bd,), jnp.bool_, sh),
-                _sds((Bd,), jnp.float32, sh), _sds((Bd,), i32, sh), _sds((Bd,), jnp.float32, sh), _sds((2,), jnp.uint32, sh))
-    compiled = jax.jit(fn, donate_argnums=(1, 2)).lower(p, k, v, *args).compile()
+    compiled = _llama_step_program(MOE, _param_shapes(MOE, one_chip), program, one_chip, 512, 256, 32, 16)
     text = compiled.as_text()
     assert "tpu_custom_call" in text and "ragged-dot" in text
     one_layer = MOE.num_experts * MOE.hidden_size * MOE.intermediate_size * 2
@@ -394,13 +400,11 @@ def test_ssm_update_rows_compiles_in_place(one_chip, rows, tiles):
     assert mem.alias_size_in_bytes == slots * Hm * Pm * Nm * 4 and mem.temp_size_in_bytes < 64 << 20
 
 
-@pytest.mark.parametrize("program", ["decode_multi", "mixed_step", "prefill", "check-decode_multi", "check-mixed_step", "check-prefill"])
-def test_granite_step_programs_compile_and_fit_beside_the_weights(one_chip, on_tpu, program):
-    """The benchmark's ``granite-4.0-h-small-d10-e36`` as configured (65 slots,
-    1,025 blocks, 64 rows): the groups scan, the slot kernel and the megakernel
-    compile into one program whose arguments (9.9 GB of weights, 3.0 GB of
-    pool and slots, aliased to the results) and temporaries fit the chip.
-    ``check-``: as the output check calls them (``granite_hybrid.program_logits``:
+@functools.cache  # as above
+def _granite_compiled(sh, program):
+    """``(compiled, params, k, v)``: a step program of the benchmark's
+    ``granite-4.0-h-small-d10-e36`` as configured (65 slots, 1,025 blocks, 64
+    rows). ``check-``: as the output check calls it (``granite_hybrid.program_logits``:
     the same pool and slots, its bucket and table width, logits returned)."""
     import json
 
@@ -414,7 +418,7 @@ def test_granite_step_programs_compile_and_fit_beside_the_weights(one_chip, on_t
     fam = families.load("granite_hybrid")
     mc = fam.model_config(cfg, "granite")
     sc = cfg["scheduler"]
-    place = lambda tree: jax.tree.map(lambda s: _sds(s.shape, s.dtype, one_chip), tree)  # noqa: E731
+    place = lambda tree: jax.tree.map(lambda s: _sds(s.shape, s.dtype, sh), tree)  # noqa: E731
     params = place(jax.eval_shape(lambda: fam.make_params(mc, 0)))
     k, v = place(jax.eval_shape(
         lambda: (lambda c: (c.k, c.v))(KvCacheArrays.create(mc, sc["num_blocks"], dtype=BF16, num_slots=sc["max_running"] + 1))))
@@ -423,15 +427,15 @@ def test_granite_step_programs_compile_and_fit_beside_the_weights(one_chip, on_t
     if check:
         B, W = cfg["parity"]["decode_bucket"], 8
     flash = check and hybrid.resolve_prefill_impl(mc) == "flash"
-    i32 = lambda *s: _sds(s, jnp.int32, one_chip)  # noqa: E731
-    f32 = lambda *s: _sds(s, jnp.float32, one_chip)  # noqa: E731
-    act = _sds((B,), jnp.bool_, one_chip)
+    i32 = lambda *s: _sds(s, jnp.int32, sh)  # noqa: E731
+    f32 = lambda *s: _sds(s, jnp.float32, sh)  # noqa: E731
+    act = _sds((B,), jnp.bool_, sh)
     if program == "decode_multi":
         compiled = jax.jit(
             lambda p, k, v, t, pos, bt, a, te, tk, tp, key: hybrid.decode_multi(p, mc, k, v, t, pos, bt, a, te, tk, tp, key, 8,
                                                                                 return_logits=check),
             donate_argnums=(1, 2),
-        ).lower(params, k, v, i32(B), i32(B), i32(B, W), act, f32(B), i32(B), f32(B), _sds((2,), jnp.uint32, one_chip)).compile()
+        ).lower(params, k, v, i32(B), i32(B), i32(B, W), act, f32(B), i32(B), f32(B), _sds((2,), jnp.uint32, sh)).compile()
     elif program == "prefill":  # a chunk with no decode row: the slot array's layout stays pinned (hybrid._mamba_mixer)
         compiled = jax.jit(
             lambda p, k, v, t, vl, cl, bt: hybrid.prefill(p, mc, k, v, t, vl, cl, bt, all_logits=check, has_prefix=not check,
@@ -444,9 +448,68 @@ def test_granite_step_programs_compile_and_fit_beside_the_weights(one_chip, on_t
                                                                                     use_flash=flash),
             donate_argnums=(1, 2),
         ).lower(params, k, v, i32(256), i32(), i32(), i32(W), i32(B), i32(B), i32(B, W), act).compile()
+    return compiled, params, k, v
+
+
+@pytest.mark.parametrize("program", ["decode_multi", "mixed_step", "prefill", "check-decode_multi", "check-mixed_step", "check-prefill"])
+def test_granite_step_programs_compile_and_fit_beside_the_weights(one_chip, on_tpu, program):
+    """The groups scan, the slot kernel and the megakernel compile into one
+    program whose arguments (9.9 GB of weights, 3.0 GB of pool and slots,
+    aliased to the results) and temporaries fit the chip."""
+    compiled, _, k, v = _granite_compiled(one_chip, program)
     mem, text = compiled.memory_analysis(), compiled.as_text()
     assert text.count("tpu_custom_call") >= 2 and "ssm_update_rows" in text  # the slot kernel and the attention kernel
     state = (k.slots.size * 4 + v.slots.size * 2)
     assert mem.alias_size_in_bytes >= state  # pool and slots are updated in place
     assert 9.9e9 < mem.argument_size_in_bytes - mem.alias_size_in_bytes < 10.0e9  # the weights
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.0e9, (mem.argument_size_in_bytes, mem.temp_size_in_bytes)
+
+
+# --- no step program re-lays a weight ---------------------------------------------------------
+
+M7_D4 = get_config("mistral-7b").replace(name="mistral-7b-d4", num_layers=4, block_size=128, max_seq_len=2048)
+_ARRAY = re.compile(r"\b[a-z]+\d+\[([\d,]+)\]\{([\d,]+)")  # an array's dimensions and its layout, minor to major
+
+
+def _re_laid_weights(text, params):
+    """The lines of a compiled program that copy a parameter of the layer
+    stacks, or that hold an array of a layer weight's shape (the stack's
+    ``[L, ...]``, a layer's ``[1, ...]`` or ``[...]``; weights of a Mi
+    elements a layer or more, which no activation's shape meets) in another
+    layout than the row-major one it is stored in."""
+    shapes = set()
+    for leaf in jax.tree.leaves(params):
+        if leaf.ndim >= 3 and math.prod(leaf.shape[1:]) >= 1 << 20:
+            shapes |= {leaf.shape, (1, *leaf.shape[1:]), leaf.shape[1:]}
+    assert shapes and re.search(r"%p__(layers|attn)__[\w.]+ = ", text)  # the parameters carry their paths, as the first rule expects
+    bad = []
+    for line in text.splitlines():
+        if re.search(r" copy\(%p__(layers|attn|mamba)__", line):
+            bad.append(line.strip()[:160])
+        for dims, layout in _ARRAY.findall(line):
+            dims, layout = tuple(map(int, dims.split(","))), list(map(int, layout.split(",")))
+            if dims in shapes and layout != sorted(layout, reverse=True):
+                bad.append(line.strip()[:160])
+                break
+    return bad
+
+
+@pytest.mark.parametrize("program", ["mixed_step", "decode_multi_w8"])
+@pytest.mark.parametrize("tree", ["eva-bf16", "mistral-7b-int8", "granite"])
+def test_no_step_program_re_lays_a_weight(one_chip, on_tpu, tree, program):
+    """Every weight is read where it lies, sliced out of its stack inside the
+    product that uses it. XLA:TPU wants q and k head-major and, left alone,
+    gets them by transposing ``wq`` and ``wk``: both whole stacks once a
+    window (``copy.69 = bf16[L,4096,4096]{1,2,0} copy(p.layers.wq)``), a layer's
+    slice of each inside a mixed step's scan: ``llama.project_heads`` (PERF.md
+    section 6, PR 40). The cells' bf16 tree at EvaByte's widths (bucket 16,
+    tables of 20), an int8 tree at Mistral-7B's (bucket 32, tables of 16), and
+    the granite programs with their one attention layer in ten."""
+    if tree == "granite":
+        compiled, params, _, _ = _granite_compiled(one_chip, program.removesuffix("_w8"))
+    elif tree == "eva-bf16":
+        compiled, params = _eva_compiled(one_chip, program), _param_shapes(EVA, one_chip)
+    else:
+        params = _param_shapes(M7_D4, one_chip, int8=True)
+        compiled = _llama_step_program(M7_D4, params, program, one_chip, 256, 256, 32, 16)
+    assert not _re_laid_weights(compiled.as_text(), params)
