@@ -146,13 +146,16 @@ class ModelConfig:
     # head 0 (columns [0, vocab)) gives the next-token logits, the only ones
     # served; the others' weights are held, not multiplied.
     num_pred_heads: int = 1
-    # Mixer of each layer, in order: "attention" or "mamba" (a Mamba-2
-    # state-space mixer). Empty: every layer is attention, one stack, the
-    # llama step programs. Non-empty: ``models/hybrid.py`` drives the stack as
-    # ordered groups of one kind (``layer_groups``), each group one scan; the
-    # paged pool then holds the attention layers only, and every running
-    # sequence holds one *slot* of recurrent state beside it
-    # (``kv_cache.SlotKv``). Every layer has the same FFN.
+    # Mixer of each layer, in order: "attention", "mamba" (a Mamba-2
+    # state-space mixer) or "cca" (attention in a compressed latent behind two
+    # causal convolutions and a value shift; every layer of the stack or
+    # none). Empty: every layer is attention, one stack, the llama step
+    # programs. Non-empty: ``models/hybrid.py`` drives the stack as ordered
+    # groups of one kind (``layer_groups``), each group one scan; the paged
+    # pool then holds the attention and cca layers only, and every running
+    # sequence holds one *slot* beside it (``kv_cache.SlotKv``): of recurrent
+    # state in each mamba layer, of the convolutions' and the shift's last
+    # columns in each cca layer. Every layer has the same FFN.
     layer_types: Tuple[str, ...] = ()
     # Mamba-2 sizes under their published names (``mamba_<key>``): the state
     # of one head is [d_head, d_state]; d_inner = expand * hidden_size =
@@ -185,6 +188,28 @@ class ModelConfig:
     embedding_multiplier: float = 1.0
     residual_multiplier: float = 1.0
     logits_scaling: float = 1.0
+    # "cca" layers (Compressed Convolutional Attention, arXiv:2510.04476):
+    # queries live in ``q_size`` lanes and keys and values in ``kv_size``
+    # lanes, both narrower than ``hidden_size``; their projections pass a
+    # causal depthwise convolution of ``cca_time0`` taps and one grouped by
+    # head of ``cca_time1`` taps, and the second value head comes from the
+    # token before (``hybrid._cca_mixer``).
+    cca_time0: int = 2
+    cca_time1: int = 2
+    # Share of each head's lanes that rotate (the leading ones).
+    rope_fraction: float = 1.0
+    # The expert layer's router: "linear" (softmax over the top-k logits of
+    # one matrix) or "zaya" (a down-projection to ``router_hidden_size`` that
+    # adds the router state of the layer before, scaled, and hands its own
+    # on; then a norm and a three-matrix GELU MLP in float32, a softmax over
+    # all choices and the top-1 of it plus a stored balancing bias).
+    router_kind: str = "linear"
+    router_hidden_size: int = 0
+    # One more choice than experts: a token that draws it passes by them.
+    moe_skip_choice: bool = False
+    # Residual merge with four learned vectors a sublayer:
+    # x <- (gx * x + bx) + (gf * f + bf).
+    residual_merge: bool = False
 
     def __post_init__(self):
         if self.attention_impl not in ("auto", "gather", "paged", "megakernel"):
@@ -238,19 +263,25 @@ class ModelConfig:
             or not self.use_rope
             or self.attention_scale
             or (self.embedding_multiplier, self.residual_multiplier, self.logits_scaling) != (1.0, 1.0, 1.0)
+            or self.rope_fraction != 1.0
+            or self.router_kind != "linear"
+            or self.moe_skip_choice
+            or self.residual_merge
         ):
             raise ValueError(
-                "a share of the experts, a shared expert, use_rope=False, attention_scale and the multipliers "
+                "a share of the experts, a shared expert, use_rope=False, attention_scale, the multipliers, "
+                "rope_fraction, a router kind, a skip choice and the residual merge "
                 "are read by the layer-group step programs only: state layer_types"
             )
 
     def _check_hybrid(self) -> None:
         """What ``models/hybrid.py`` serves, and what it refuses by name."""
-        if len(self.layer_types) != self.num_layers or set(self.layer_types) - {"attention", "mamba"}:
+        if len(self.layer_types) != self.num_layers or set(self.layer_types) - {"attention", "mamba", "cca"}:
             raise ValueError(
-                f"layer_types names 'attention' or 'mamba' for each of num_layers={self.num_layers} layers, "
+                f"layer_types names 'attention', 'mamba' or 'cca' for each of num_layers={self.num_layers} layers, "
                 f"got {self.layer_types!r}"
             )
+        cca = "cca" in self.layer_types
         refused = {
             "architecture other than 'llama'": self.architecture != "llama",
             "attention_kind 'eva'": self.is_eva,
@@ -261,6 +292,13 @@ class ModelConfig:
                 self.residual_fp32 or self.norm_unit_offset or self.num_pred_heads > 1
             ),
             "moe_dispatch other than auto|ragged": self.num_experts > 0 and self.moe_dispatch not in ("auto", "ragged"),
+            "'cca' layers beside layers of another kind": cca and len(set(self.layer_types)) > 1,
+            "'cca' layers with use_rope=False, an attention_scale or a share of the experts": cca and (
+                not self.use_rope or self.attention_scale or self.num_experts_held
+            ),
+            "rope_fraction, router_kind 'zaya', a skip choice or the residual merge without 'cca' layers": not cca and (
+                self.rope_fraction != 1.0 or self.router_kind != "linear" or self.moe_skip_choice or self.residual_merge
+            ),
         }
         for what, hit in refused.items():
             if hit:
@@ -275,6 +313,21 @@ class ModelConfig:
                 )
             if self.mamba_n_heads % self.mamba_n_groups or self.mamba_d_conv < 2 or self.mamba_chunk_size < 1:
                 raise ValueError("mamba_n_groups divides mamba_n_heads; mamba_d_conv >= 2; mamba_chunk_size >= 1")
+        if cca:
+            rot = self.head_dim * self.rope_fraction
+            if (self.cca_time0, self.cca_time1) != (2, 2):
+                raise ValueError("a 'cca' layer's convolutions have two taps (cca_time0 = cca_time1 = 2): one column a slot")
+            if self.num_heads % self.num_kv_heads or self.num_kv_heads % 2 or rot != int(rot) or int(rot) % 2 or not 0 < rot <= self.head_dim:
+                raise ValueError(
+                    "a 'cca' layer needs num_kv_heads dividing num_heads, an even num_kv_heads (half the value heads "
+                    "are the shifted ones) and an even number of rotating lanes"
+                )
+        if self.router_kind not in ("linear", "zaya"):
+            raise ValueError(f"router_kind must be linear|zaya, got {self.router_kind!r}")
+        if self.router_kind == "zaya" and (self.router_hidden_size <= 0 or self.num_experts_per_tok != 1 or not self.num_experts):
+            raise ValueError("router_kind 'zaya' needs router_hidden_size > 0, experts, and num_experts_per_tok = 1")
+        if self.moe_skip_choice and self.router_kind != "zaya":
+            raise ValueError("the skip choice is the 'zaya' router's")
         held = self.num_experts_held
         if held and not (0 < held <= self.num_experts and 0 <= self.first_expert_held <= self.num_experts - held):
             raise ValueError(
@@ -325,11 +378,35 @@ class ModelConfig:
     @property
     def num_attention_layers(self) -> int:
         """Layers the paged pool holds rows for."""
-        return self.layer_types.count("attention") if self.layer_types else self.num_layers
+        if not self.layer_types:
+            return self.num_layers
+        return self.layer_types.count("attention") + self.num_cca_layers
 
     @property
     def num_mamba_layers(self) -> int:
         return self.layer_types.count("mamba")
+
+    @property
+    def num_cca_layers(self) -> int:
+        return self.layer_types.count("cca")
+
+    @property
+    def cca_channels(self) -> int:
+        """Lanes the two convolutions run over: queries and keys, head by head."""
+        return self.q_size + self.kv_size
+
+    @property
+    def cca_slot_lanes(self) -> int:
+        """What a sequence carries in ONE cca layer: the last input of each
+        convolution (``cca_channels`` lanes each) and the last token's
+        projection for the shifted value head (``head_dim`` lanes for each
+        second half of the key/value heads)."""
+        return 2 * self.cca_channels + self.kv_size // 2
+
+    @property
+    def router_choices(self) -> int:
+        """Width of the router's softmax: the experts, and the skip choice."""
+        return self.num_experts + int(self.moe_skip_choice)
 
     @property
     def experts_held(self) -> int:
@@ -475,6 +552,32 @@ PRESETS = {
         embedding_multiplier=6.0,
         residual_multiplier=0.5,
         logits_scaling=4.0,
+    ),
+    # Tiny ZAYA-shaped config for unit tests: every layer "cca" (attention in
+    # a latent half the hidden size, two convolutions, a value shift) and four
+    # experts top-1 with a skip choice behind a router MLP that carries its
+    # state; half of each head's lanes rotate; the residual merge.
+    "tiny-zaya": ModelConfig(
+        name="tiny-zaya",
+        vocab_size=512,
+        hidden_size=64,
+        num_layers=4,
+        num_heads=4,
+        num_kv_heads=2,
+        head_dim=16,
+        intermediate_size=32,
+        max_seq_len=256,
+        block_size=8,
+        rope_theta=5000000.0,
+        tie_word_embeddings=True,
+        num_experts=4,
+        num_experts_per_tok=1,
+        layer_types=("cca",) * 4,
+        rope_fraction=0.5,
+        router_kind="zaya",
+        router_hidden_size=16,
+        moe_skip_choice=True,
+        residual_merge=True,
     ),
     # Tiny MLA config (DeepSeek-style latent attention) for unit tests.
     "tiny-mla": ModelConfig(

@@ -68,7 +68,14 @@ class SlotKv(NamedTuple):
     (``hybrid.open_slot`` writes the entry when it zeroes the slot), so its
     signature is that of every other family's: a table of zeros reads and
     writes the scratch slot. A pytree: it flows through jit arguments and
-    donation like a plain array."""
+    donation like a plain array.
+
+    A stack of "cca" layers has BOTH in every layer: a pool row a token and a
+    slot a sequence. Its slot holds columns only, so ``v`` carries them,
+    ``[L_cca, S, cca_slot_lanes]`` (the first convolution's last input, the
+    second's, and the last token's projection for the shifted value heads,
+    side by side on the lanes), and ``k`` carries an array of no elements
+    ``[L_cca, S, 0]`` beside ``slot_of``."""
 
     pool: Any  # jax.Array — [L_a, N, BS, KVH*HD]
     slots: jax.Array
@@ -196,8 +203,9 @@ class KvCacheArrays:
         num_slots: int = 0,
     ) -> "KvCacheArrays":
         """``L`` is the number of attention layers: all of them, or for a
-        hybrid model those ``layer_types`` names (its state-space layers hold
-        ``num_slots`` slots instead, scratch slot 0 included: ``SlotKv``)."""
+        hybrid model those ``layer_types`` names "attention" or "cca" (its
+        state-space layers hold ``num_slots`` slots instead, its cca layers
+        beside the pool, scratch slot 0 included: ``SlotKv``)."""
         if sharding is not None:
             config.refuse_for_layer_types("a sharded cache (a mesh)")
         if config.architecture == "mla":
@@ -225,9 +233,13 @@ class KvCacheArrays:
         if config.is_hybrid:
             if num_slots < 2:
                 raise ValueError("a hybrid model's cache needs the scratch slot and at least one more (num_slots >= 2)")
-            c, Lm = config, config.num_mamba_layers
-            state = jnp.zeros((Lm, num_slots, *c.mamba_state_shape), jnp.float32)
-            columns = jnp.zeros((Lm, num_slots, c.mamba_d_conv - 1, c.mamba_conv_dim), dtype)
+            c, Lm, Lc = config, config.num_mamba_layers, config.num_cca_layers
+            if Lc:
+                state = jnp.zeros((Lc, num_slots, 0), dtype)
+                columns = jnp.zeros((Lc, num_slots, c.cca_slot_lanes), dtype)
+            else:
+                state = jnp.zeros((Lm, num_slots, *c.mamba_state_shape), jnp.float32)
+                columns = jnp.zeros((Lm, num_slots, c.mamba_d_conv - 1, c.mamba_conv_dim), dtype)
             k = SlotKv(k, state, jnp.zeros((num_blocks,), jnp.int32))
             v = SlotKv(v, columns)
         return cls(k=k, v=v, kv_heads=kv_heads)

@@ -1,10 +1,14 @@
 """Layer-group step programs: a stack whose layers are not all alike.
 
 ``ModelConfig.layer_types`` names each layer's mixer, "attention" (GQA over
-the paged pool) or "mamba" (a Mamba-2 state-space mixer over one *slot* of
-recurrent state a sequence); every layer has the same FFN (sparse experts of
+the paged pool), "mamba" (a Mamba-2 state-space mixer over one *slot* of
+recurrent state a sequence) or "cca" (attention in a compressed latent over
+the paged pool, behind two causal convolutions and a value shift whose last
+columns a sequence keeps in its slot: pool AND slot in every layer); every
+layer has the same FFN (sparse experts of
 which this chip may hold a share, a shared expert beside them, or a dense
-SwiGLU). The stack is driven as the ordered groups of one kind that
+SwiGLU; the experts behind a linear router or the ZAYA router MLP, which
+hands a state from layer to layer). The stack is driven as the ordered groups of one kind that
 ``layer_types`` spells (``ModelConfig.layer_groups``): each group is one
 ``lax.scan`` whose body indexes the kind's own stacked weights and the FFN
 stacks of all layers, so the compiled program holds one body a group, never
@@ -36,6 +40,15 @@ Attention layers go through the llama family's attention paths ("gather",
 so; a stated ``attention_scale`` is folded into the queries, so that no kernel
 needs to know it. The Granite multipliers scale the embedding rows, every
 residual branch and the logits.
+
+The cca mixer (``_cca_mixer``) has one body for both forms: a length-1 row
+takes what stood before it out of its slot, a chunk's rows take it from the
+row before and the chunk's first from the slot, so chunks of a prompt agree
+with one pass over it; padded positions leave the slot as it was. The keys are
+cached as attended (normed, tempered, rotated), so the pool's rows and every
+attention path are those of a GQA layer of ``num_heads``/``num_kv_heads``
+heads. The ZAYA router's state is a second carry of the layer scan beside
+``h``; it is float32 from the down-projection on.
 
 Not built for this kind, and refused by name
 (``ModelConfig.refuse_for_layer_types``): prefix-block reuse,
@@ -83,8 +96,11 @@ _HI = lax.Precision.HIGHEST  # the chunked scan's small float32 products: never 
 
 def init_params(config: ModelConfig, key: jax.Array, dtype=jnp.bfloat16) -> Params:
     """Random-init weights (testing). ``layers`` holds the FFN of every layer,
-    ``attn`` and ``mamba`` the mixers of the layers of each kind, stacked in
-    layer order."""
+    ``attn``, ``mamba`` and ``cca`` the mixers of the layers of each kind,
+    stacked in layer order. A cca mixer's ``w_in`` is its four projections
+    side by side, ``[Wq | Wk | Wv1 | Wv2]``; ``conv1_w[g]`` is head ``g``'s two
+    taps stacked, the earlier token's on top. The ZAYA router's tensors past
+    its down-projection, and the keys' temperatures, are float32."""
     c = config
     D, L, La, Lm = c.hidden_size, c.num_layers, c.num_attention_layers, c.num_mamba_layers
     ks = iter(jax.random.split(key, 24))
@@ -98,27 +114,55 @@ def init_params(config: ModelConfig, key: jax.Array, dtype=jnp.bfloat16) -> Para
 
     layers: Dict[str, jax.Array] = {"mlp_norm": norm((L, D))}
     F = c.intermediate_size
+    # (The tensors of the kinds added later draw from a stream of their own: the older presets' weights stay what they were.)
+    ks2 = iter(jax.random.split(jax.random.fold_in(key, 1), 40))
+
+    def f32(shape, scale, mean=0.0):
+        return mean + scale * jax.random.normal(next(ks2), shape, dtype=jnp.float32)
+
+    def merge(n):
+        return {"res_gx": f32((n, D), 0.1, 1.0).astype(dtype), "res_bx": f32((n, D), 0.02).astype(dtype),
+                "res_gf": f32((n, D), 0.1, 1.0).astype(dtype), "res_bf": f32((n, D), 0.02).astype(dtype)}
+
     if c.num_experts:
         E = c.experts_held
-        layers.update(
-            router=dense((L, D, c.num_experts)),
-            w_gate=dense((L, E, D, F)), w_up=dense((L, E, D, F)), w_down=dense((L, E, F, D)),
-        )
+        if c.router_kind != "zaya":
+            layers.update(router=dense((L, D, c.num_experts)))
+        layers.update(w_gate=dense((L, E, D, F)), w_up=dense((L, E, D, F)), w_down=dense((L, E, F, D)))
+        if c.router_kind == "zaya":
+            r, n = c.router_hidden_size, c.router_choices
+            layers.update(
+                router_down=f32((L, D, r), D ** -0.5).astype(dtype), router_down_b=f32((L, r), 0.1),
+                router_gamma=f32((L, r), 0.2, 1.0), router_norm=f32((L, r), 0.1, 1.0),
+                router_w1=f32((L, r, r), 2.0 * r ** -0.5), router_b1=f32((L, r), 0.1),
+                router_w2=f32((L, r, r), 2.0 * r ** -0.5), router_b2=f32((L, r), 0.1),
+                router_w3=f32((L, r, n), 4.0 * r ** -0.5), router_beta=f32((L, n), 0.02),
+            )
     else:
         layers.update(w_gate=dense((L, D, F)), w_up=dense((L, D, F)), w_down=dense((L, F, D)))
+    if c.residual_merge:
+        layers.update(merge(L))
     if c.shared_intermediate_size:
         Fs = c.shared_intermediate_size
         layers.update(shared_gate=dense((L, D, Fs)), shared_up=dense((L, D, Fs)), shared_down=dense((L, Fs, D)))
-    params: Params = {
-        "embed": dense((c.vocab_size, D), scale=0.02),
-        "final_norm": norm((D,)),
-        "layers": layers,
-        "attn": {
+    params: Params = {"embed": dense((c.vocab_size, D), scale=0.02), "final_norm": norm((D,)), "layers": layers}
+    Lc = c.num_cca_layers
+    if Lc:
+        C, hd = c.cca_channels, c.head_dim
+        params["cca"] = {
+            "attn_norm": f32((Lc, D), 0.1, 1.0).astype(dtype),
+            "w_in": f32((Lc, D, C + c.kv_size), D ** -0.5).astype(dtype), "wo": f32((Lc, c.q_size, D), c.q_size ** -0.5).astype(dtype),
+            "conv0_w": f32((Lc, 2, C), 2 ** -0.5).astype(dtype), "conv0_b": f32((Lc, C), 0.1).astype(dtype),
+            "conv1_w": f32((Lc, C // hd, 2 * hd, hd), (2 * hd) ** -0.5).astype(dtype), "conv1_b": f32((Lc, C), 0.1).astype(dtype),
+            "k_temp": 6.0 + 4.0 * jax.random.uniform(next(ks2), (Lc, c.num_kv_heads), dtype=jnp.float32),
+            **(merge(Lc) if c.residual_merge else {}),
+        }
+    else:
+        params["attn"] = {
             "attn_norm": norm((La, D)),
             "wq": dense((La, D, c.q_size)), "wk": dense((La, D, c.kv_size)),
             "wv": dense((La, D, c.kv_size)), "wo": dense((La, c.q_size, D)),
-        },
-    }
+        }
     if Lm:
         H, di, cd, K = c.mamba_n_heads, c.mamba_d_inner, c.mamba_conv_dim, c.mamba_d_conv
         # Steps log-uniform in [1e-3, 1e-1] and A in [1, 16], as Mamba-2 is initialised: per-step decays
@@ -142,9 +186,11 @@ def init_params(config: ModelConfig, key: jax.Array, dtype=jnp.bfloat16) -> Para
 def open_slot(k_cache: SlotKv, v_cache: SlotKv, block: jax.Array, slot: jax.Array) -> Tuple[SlotKv, SlotKv]:
     """A sequence whose table begins with ``block`` takes ``slot``: its state
     and columns are zeroed in every layer and the step programs find it
-    through ``slot_of``. (Donate both sides: three in-place writes.)"""
+    through ``slot_of``. (Donate both sides: three in-place writes; a stack of
+    cca layers keeps no state, two.)"""
+    state = k_cache.slots.at[:, slot].set(0.0) if k_cache.slots.size else k_cache.slots
     return (
-        k_cache._replace(slots=k_cache.slots.at[:, slot].set(0.0), slot_of=k_cache.slot_of.at[block].set(slot)),
+        k_cache._replace(slots=state, slot_of=k_cache.slot_of.at[block].set(slot)),
         v_cache._replace(slots=v_cache.slots.at[:, slot].set(0)),
     )
 
@@ -492,58 +538,201 @@ def _mamba_mixer(c: ModelConfig, lp, lm, x, ssm, conv, slots, chunk, wdtype):
     return _mamba_out(c, lp, jnp.concatenate(ys) if len(ys) > 1 else ys[0], z, wdtype), ssm, conv
 
 
+# --- the cca mixer -----------------------------------------------------------
+
+
+def _wide(eq: str, a: jax.Array, b: jax.Array) -> jax.Array:
+    """``einsum(eq, a, b)`` with a float32 result of compute-dtype operands: on a
+    TPU the product leaves the MXU wide; elsewhere (XLA:CPU has no bf16 x bf16
+    = f32 dot) the operands are widened first."""
+    if llama._on_tpu():
+        return jnp.einsum(eq, a, b, preferred_element_type=jnp.float32)
+    return jnp.einsum(eq, a.astype(jnp.float32), b.astype(jnp.float32))
+
+
+def _unit(x: jax.Array) -> jax.Array:
+    """``x / |x|_2`` over the last axis (float32; a zero row stays zero)."""
+    return x * lax.rsqrt(jnp.maximum(jnp.sum(x * x, axis=-1, keepdims=True), 1e-30))
+
+
+def _rope_part(c: ModelConfig, x: jax.Array, positions: jax.Array) -> jax.Array:
+    """Rotate the leading ``rope_fraction`` of each head's lanes of ``x [R, heads, head_dim]``."""
+    rd = int(c.head_dim * c.rope_fraction)
+    rotated = llama.apply_rope(x[..., :rd], positions, c.rope_theta)
+    return rotated if rd == c.head_dim else jnp.concatenate([rotated, x[..., rd:]], axis=-1)
+
+
+def _cca_mixer(c: ModelConfig, lp, lc, x, cols, slots, chunk, positions, attend, win=()):
+    """The cca mixer of cca layer ``lc`` over ``x``'s rows: first the wide row
+    ``chunk = (T, slot, valid_len)`` if any, then length-1 rows on ``slots
+    [B]``. One ``w_in``, one attention launch a kind of row (``attend``) and
+    one ``wo`` over all rows. ``cols`` is the slot array viewed ``[L_c*S,
+    cca_slot_lanes]``: a slot holds, side by side, the last token's ``[q~ ;
+    k~]`` (the first convolution's earlier input), its first convolution's
+    output (the second's earlier input) and its projection for the shifted
+    value heads. A length-1 row reads what stood before it out of its slot; a
+    chunk's row reads the row before it, the first the slot; the slot then
+    takes the chunk's last *valid* row. Returns ``(out [R, q_size], cols,
+    k [R, KVH, HD], v)``, the keys as cached: unit norm times the head's
+    temperature, rotated, with ``sqrt(head_dim)`` folded in so that every
+    attention path's own ``head_dim ** -0.5`` makes it up."""
+    R, C, Q, hd = x.shape[0], c.cca_channels, c.q_size, c.head_dim
+    Hq, Hk, half = c.num_heads, c.num_kv_heads, c.kv_size // 2
+    S = cols.shape[0] // c.num_cca_layers
+    with jax.named_scope("cca_conv"):
+        proj = x @ lp["w_in"]  # [R, C + kv_size]: q~ | k~ | this token's value heads | the next token's shifted ones
+        qk, v_own, v_next = proj[:, :C], proj[:, C:C + half], proj[:, C + half:]
+        T = 0
+        if chunk is not None:
+            T, slot, valid_len = chunk
+            row = lc * S + slot
+            old_chunk = lax.dynamic_index_in_dim(cols, row, keepdims=True)  # [1, W]: the slot as the chunk finds it
+        if slots is not None:
+            rows = lc * S + slots
+            old_rows = cols[rows].astype(x.dtype)  # [B, W]
+
+        def earlier(cur, lo, hi):
+            """What stood before each row of ``cur``, the slot's lanes ``[lo, hi)``."""
+            parts = [old_chunk[:, lo:hi].astype(x.dtype), cur[:T - 1]] if chunk is not None else []
+            return jnp.concatenate(parts + ([old_rows[:, lo:hi]] if slots is not None else []))
+
+        w0 = lp["conv0_w"].astype(jnp.float32)
+        y = (earlier(qk, 0, C).astype(jnp.float32) * w0[0] + qk.astype(jnp.float32) * w0[1]
+             + lp["conv0_b"].astype(jnp.float32)).astype(x.dtype)  # depthwise, two taps
+        taps = jnp.concatenate([earlier(y, C, 2 * C).reshape(R, C // hd, hd), y.reshape(R, C // hd, hd)], axis=-1)
+        z = _wide("rgk,gko->rgo", taps, lp["conv1_w"])  # grouped by head, two taps
+        z = z + lp["conv1_b"].astype(jnp.float32).reshape(C // hd, hd)
+        # The q-k mean goes round the convolutions: each query head with its key head, each key head with its queries' mean.
+        qt = qk[:, :Q].astype(jnp.float32).reshape(R, Hk, Hq // Hk, hd)
+        kt = qk[:, Q:].astype(jnp.float32).reshape(R, Hk, 1, hd)
+        q = z[:, :Hq] + (0.5 * (qt + kt)).reshape(R, Hq, hd)
+        k = z[:, Hq:] + 0.5 * (jnp.mean(qt, axis=2) + kt[:, :, 0])
+        temp = lp["k_temp"].astype(jnp.float32) * hd ** 0.5
+        q = _rope_part(c, _unit(q), positions).astype(x.dtype)
+        k = _rope_part(c, _unit(k) * temp[None, :, None], positions).astype(x.dtype)
+        v = jnp.concatenate([v_own, earlier(v_next, 2 * C, 2 * C + half)], axis=1).reshape(R, Hk, hd)
+        now = jnp.concatenate([qk, y, v_next], axis=1).astype(cols.dtype)  # [R, W]: what each row leaves behind
+        if chunk is not None:
+            # Row ``valid_len`` of [the slot as it was ; the chunk's rows]: the last valid row, or with none the slot itself.
+            left = lax.dynamic_index_in_dim(jnp.concatenate([old_chunk, now[:T]]), valid_len, keepdims=False)
+            cols = lax.dynamic_update_index_in_dim(cols, left, row, axis=0)
+        if slots is not None:
+            cols = cols.at[rows].set(now[T:])
+    return attend(q, k, v, lc, *win) @ lp["wo"], cols, k, v
+
+
+def _zaya_route(c: ModelConfig, lp, x: jax.Array, s: jax.Array):
+    """The ZAYA router on the expert layer's input ``x [R, D]`` (the normed
+    stream, float32: not yet rounded to the compute type) and the state ``s [R,
+    router_hidden_size]`` the layer before handed on (zeros before the first):
+    ``r = x W_d + b_d + gamma * s`` goes on to the next layer; ``p =
+    softmax(MLP(norm(r)))`` over the experts and the skip choice; the choice is
+    the argmax of ``p + beta`` and its weight ``p`` there. Float32 throughout,
+    at full precision: with one expert a token, a tie that rounding turns sends
+    the whole token elsewhere, and the rounding of the router's input to
+    bfloat16 alone turned as many choices as all the rest of a bfloat16 stream
+    (PERF.md section 6, PR 41). Returns ``(weights [R, 1], ids [R, 1], r)``."""
+    with jax.named_scope("zaya_router"):
+        r = jnp.dot(x, lp["router_down"].astype(jnp.float32), precision=_HI) + lp["router_down_b"] + lp["router_gamma"] * s
+        a = r * lax.rsqrt(jnp.mean(r * r, axis=-1, keepdims=True) + c.rms_norm_eps) * lp["router_norm"]
+        a = jax.nn.gelu(jnp.dot(a, lp["router_w1"], precision=_HI) + lp["router_b1"], approximate=False)
+        a = jax.nn.gelu(jnp.dot(a, lp["router_w2"], precision=_HI) + lp["router_b2"], approximate=False)
+        p = jax.nn.softmax(jnp.dot(a, lp["router_w3"], precision=_HI), axis=-1)
+        ids = jnp.argmax(p + lp["router_beta"], axis=-1).astype(jnp.int32)[:, None]
+        return jnp.take_along_axis(p, ids, axis=-1), ids, r
+
+
 # --- the stack ---------------------------------------------------------------
 
 
-def _ffn(c: ModelConfig, scanned, experts, h, l, valid, wdtype):
+def _residual(c: ModelConfig, lp, h: jax.Array, out: jax.Array) -> jax.Array:
+    """A sublayer's output joins the stream: scaled by the residual multiplier,
+    or merged with the sublayer's four learned vectors."""
+    if not c.residual_merge:
+        return h + out * jnp.asarray(c.residual_multiplier, out.dtype)
+    f32 = lambda a: a.astype(jnp.float32)  # noqa: E731
+    merged = (f32(lp["res_gx"]) * f32(h) + f32(lp["res_bx"])) + (f32(lp["res_gf"]) * f32(out) + f32(lp["res_bf"]))
+    return merged.astype(h.dtype)
+
+
+def _stats0(c: ModelConfig, rows: int) -> tuple:  # (AUX_KEYS names its counts)
+    """What the layer scans carry beside ``h`` and the slot arrays: the expert
+    layer's counts ``(held, visited)``, and with the ZAYA router the rows that
+    drew the skip and the router's state ``[rows, router_hidden_size]``."""
+    counts = (jnp.int32(0), jnp.int32(0))
+    if c.router_kind != "zaya":
+        return counts
+    return counts + (jnp.int32(0), jnp.zeros((rows, c.router_hidden_size), jnp.float32))
+
+
+AUX_KEYS = ("held_assignments", "experts_visited", "skipped_rows")  # the step log's names of ``_stats0``'s counts, in its order
+
+
+def _ffn(c: ModelConfig, scanned, experts, h, l, valid, wdtype, stats):
     """The FFN every layer has, as a residual branch: the held experts' share
-    and the shared expert. Returns ``(h, held, visited)``."""
+    and the shared expert. Returns ``(h, stats)`` (``_stats0``)."""
     lp = _at(scanned, l)
     x = _norm(c, h, lp["mlp_norm"], wdtype)
-    held = visited = jnp.int32(0)
+    counts, state = (jnp.int32(0), jnp.int32(0)), ()  # this layer's counts; the router's state for the next
     if c.num_experts:
-        out, held, visited = _moe_held(x, lp, c, valid, experts, l)
+        route = None
+        if c.router_kind == "zaya":
+            weights, ids, r = _zaya_route(c, lp, _norm(c, h, lp["mlp_norm"], jnp.float32), stats[3])
+            route = lambda *_: (weights, ids)  # noqa: E731 - routed already: the state had to come out
+        out, held, visited = _moe_held(x, lp, c, valid, experts, l, route=route)
+        counts = (held, visited)
+        if route:
+            counts, state = counts + (jnp.sum(valid & (ids[:, 0] == c.num_experts)).astype(jnp.int32),), (r,)
     else:
         out = (jax.nn.silu(x @ lp["w_gate"]) * (x @ lp["w_up"])) @ lp["w_down"]
     if c.shared_intermediate_size:
         with jax.named_scope("moe_shared"):
             out = out + (jax.nn.silu(x @ lp["shared_gate"]) * (x @ lp["shared_up"])) @ lp["shared_down"]
-    return h + out * jnp.asarray(c.residual_multiplier, out.dtype), held, visited
+    h = _residual(c, lp, h, out)
+    return h, tuple(total + n for total, n in zip(stats, counts)) + state
 
 
 def _drive(c: ModelConfig, params: Params, h, ssm, conv, attend, positions, slots, chunk, valid, wdtype, window=None):
     """``h`` through the stack, group by group (``ModelConfig.layer_groups``),
     each group one scan over its layers' indices. ``attend(q, k, v, la, ...)``
     is the step's attention over the pool; ``slots``/``chunk`` say which rows
-    are length-1 rows and which a wide row (``_mamba_mixer``); ``window``
-    ``(k_win, v_win) [L_a, w, B, KVH, HD]`` are a multi-step window's rows so
-    far. Returns ``(h, ssm, conv, k_rows, v_rows, stats)``: the attention
-    layers' fresh rows ``[L_a, R, KVH, HD]`` for the caller's one scatter,
-    and the expert counts."""
+    are length-1 rows and which a wide row (``_mamba_mixer``, ``_cca_mixer``);
+    ``window`` ``(k_win, v_win) [L_a, w, B, KVH, HD]`` are a multi-step window's
+    rows so far. Returns ``(h, ssm, conv, k_rows, v_rows, aux)``: the attention
+    and cca layers' fresh rows ``[L_a, R, KVH, HD]`` for the caller's one
+    scatter, and the expert counts."""
     scanned, experts = _split_expert_stacks(c, params["layers"])
-    rm = c.residual_multiplier
     l0 = la0 = lm0 = 0
     k_rows, v_rows = [], []
-    stats = (jnp.int32(0), jnp.int32(0))
+    stats = _stats0(c, h.shape[0])
     flat = jax.tree.map(lambda a: a.reshape(a.shape[0] * a.shape[1], *a.shape[2:]), (ssm, conv))
 
     def mamba_layer(carry, idx):
-        h, (ssm, conv), (held, visited) = carry
+        h, (ssm, conv), stats = carry
         l, lm = idx
         lp = _at(params["mamba"], lm)
         out, ssm, conv = _mamba_mixer(c, lp, lm, _norm(c, h, lp["norm"], wdtype), ssm, conv, slots, chunk, wdtype)
-        h, n_held, n_visited = _ffn(c, scanned, experts, h + out * jnp.asarray(rm, out.dtype), l, valid, wdtype)
-        return (h, (ssm, conv), (held + n_held, visited + n_visited)), None
+        h, stats = _ffn(c, scanned, experts, _residual(c, lp, h, out), l, valid, wdtype, stats)
+        return (h, (ssm, conv), stats), None
 
     def attention_layer(carry, idx):
-        h, flat, (held, visited) = carry
+        h, flat, stats = carry
         l, la = idx
         lp = _at(params["attn"], la)
         q, k, v = _qkv(c, lp, _norm(c, h, lp["attn_norm"], wdtype), positions)
         win = () if window is None else tuple(lax.dynamic_index_in_dim(a, la, keepdims=False) for a in window)
         out = attend(q, k, v, la, *win) @ lp["wo"]
-        h, n_held, n_visited = _ffn(c, scanned, experts, h + out * jnp.asarray(rm, out.dtype), l, valid, wdtype)
-        return (h, flat, (held + n_held, visited + n_visited)), (k, v)
+        h, stats = _ffn(c, scanned, experts, _residual(c, lp, h, out), l, valid, wdtype, stats)
+        return (h, flat, stats), (k, v)
+
+    def cca_layer(carry, idx):
+        h, (none, cols), stats = carry
+        l, lc = idx
+        lp = _at(params["cca"], lc)
+        win = () if window is None else tuple(lax.dynamic_index_in_dim(a, lc, keepdims=False) for a in window)
+        out, cols, k, v = _cca_mixer(c, lp, lc, _norm(c, h, lp["attn_norm"], wdtype), cols, slots, chunk, positions, attend, win)
+        h, stats = _ffn(c, scanned, experts, _residual(c, lp, h, out), l, valid, wdtype, stats)
+        return (h, (none, cols), stats), (k, v)
 
     for kind, count in c.layer_groups:
         layer_ids = jnp.arange(l0, l0 + count, dtype=jnp.int32)
@@ -551,14 +740,14 @@ def _drive(c: ModelConfig, params: Params, h, ssm, conv, attend, positions, slot
             (h, flat, stats), _ = lax.scan(mamba_layer, (h, flat, stats), (layer_ids, layer_ids - l0 + lm0))
             lm0 += count
         else:
-            (h, flat, stats), (k, v) = lax.scan(attention_layer, (h, flat, stats), (layer_ids, layer_ids - l0 + la0))
+            body = cca_layer if kind == "cca" else attention_layer
+            (h, flat, stats), (k, v) = lax.scan(body, (h, flat, stats), (layer_ids, layer_ids - l0 + la0))
             k_rows.append(k)
             v_rows.append(v)
             la0 += count
         l0 += count
     ssm, conv = (a.reshape(b.shape) for a, b in zip(flat, (ssm, conv)))
-    aux = {"held_assignments": stats[0], "experts_visited": stats[1]}
-    return h, ssm, conv, jnp.concatenate(k_rows), jnp.concatenate(v_rows), aux
+    return h, ssm, conv, jnp.concatenate(k_rows), jnp.concatenate(v_rows), dict(zip(AUX_KEYS, stats[:3]))
 
 
 def _slots_of(k_cache: SlotKv, tables: jax.Array) -> jax.Array:
@@ -715,7 +904,7 @@ def decode_multi(
     slots = jnp.where(active, _slots_of(k_cache, block_tables), 0)
 
     def body(i, carry):
-        toks, ssm, conv, k_win, v_win, out, lg_out, key, held, visited = carry
+        toks, ssm, conv, k_win, v_win, out, lg_out, key, counts = carry
         h, _ = _embed(c, params, toks)
         attend = _rows_attention(c, k_cache.pool, v_cache.pool, block_tables, positions, active, window=num_steps, step=i)
         h, ssm, conv, k_rows, v_rows, aux = _drive(
@@ -728,15 +917,15 @@ def decode_multi(
         if return_logits:
             lg_out = lg_out.at[i].set(logits)
         return (nxt, ssm, conv, k_win, v_win, out.at[i].set(nxt), lg_out, key,
-                held + aux["held_assignments"], visited + aux["experts_visited"])
+                tuple(n + aux[name] for n, name in zip(counts, AUX_KEYS)))
 
     win0 = jnp.zeros((La, num_steps, B, KVH, HD), dtype=wdtype)
     V = params["embed"].shape[0]
     lg0 = jnp.zeros((num_steps if return_logits else 1, B, V if return_logits else 1), jnp.float32)
-    _, ssm, conv, k_win, v_win, out, lg_steps, _, held, visited = lax.fori_loop(
+    _, ssm, conv, k_win, v_win, out, lg_steps, _, counts = lax.fori_loop(
         0, num_steps, body,
         (tokens, k_cache.slots, v_cache.slots, win0, win0, jnp.zeros((num_steps, B), jnp.int32), lg0, rng_key,
-         jnp.int32(0), jnp.int32(0)),
+         _stats0(c, B)[:3]),
     )
     # One scatter for the whole window: row (la, j, b) -> position_b + j.
     steps_i = jnp.arange(num_steps, dtype=jnp.int32)
@@ -747,7 +936,7 @@ def decode_multi(
         k_cache, v_cache, ssm, conv, k_win.reshape(La, num_steps * B, KVH, HD), v_win.reshape(La, num_steps * B, KVH, HD),
         blocks.reshape(-1), (rows % bs).reshape(-1),
     )
-    aux = {"held_assignments": held, "experts_visited": visited}
+    aux = dict(zip(AUX_KEYS, counts))
     if return_logits:
         return out, lg_steps, k_new, v_new, aux
     return out, k_new, v_new, aux

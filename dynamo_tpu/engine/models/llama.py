@@ -380,6 +380,9 @@ def _moe_ragged(
     return jnp.zeros_like(x).at[tok].add(y * w_sorted[:, None])
 
 
+_GMM_RHS_TILE_BYTES = 6 * 2**20  # the largest tile of an expert's weights ``_held_dot`` asks of gmm (4096 x 768 bf16)
+
+
 def _held_dot(lhs: jax.Array, rhs: jax.Array, group_sizes: jax.Array) -> jax.Array:
     """The grouped product of ``_moe_held``: ``lhs [M, K]`` rows sorted by
     group against ``rhs [G, K, N]``. On a TPU the megablox ``gmm`` kernel at
@@ -401,7 +404,10 @@ def _held_dot(lhs: jax.Array, rhs: jax.Array, group_sizes: jax.Array) -> jax.Arr
     tm = min(128, -(-m // 8) * 8)
     if m % tm:
         lhs = jnp.concatenate([lhs, jnp.zeros((-m % tm, k), lhs.dtype)])
-    return gmm(lhs, rhs, group_sizes, preferred_element_type=lhs.dtype, tiling=(tm, min(4096, k), min(4096, n)))[:m]
+    tk, tn = min(4096, k), min(4096, n)
+    while tk * tn * rhs.dtype.itemsize > _GMM_RHS_TILE_BYTES and tn % 256 == 0:
+        tn //= 2  # a wide expert (2048 x 2048): two fetched tiles of the weights have to fit the kernel's fast memory
+    return gmm(lhs, rhs, group_sizes, preferred_element_type=lhs.dtype, tiling=(tm, tk, tn))[:m]
 
 
 def _moe_held(
@@ -411,6 +417,7 @@ def _moe_held(
     valid: Optional[jax.Array] = None,
     experts: Optional[Dict[str, jax.Array]] = None,
     layer=None,
+    route=None,
 ):
     """``_moe_ragged`` for an expert layer that holds a share
     (``ModelConfig.num_experts_held``): the router is ``num_experts`` wide and
@@ -421,13 +428,16 @@ def _moe_held(
     experts (the other chip's) and those of padded rows sort past them into no
     group and add nothing. ``experts`` is the
     ``[L*E_held, D, F]`` view of ``_split_expert_stacks`` and ``layer`` the
-    layer's index in it.
+    layer's index in it. ``route(x, lp) -> (weights [T, K], ids [T, K])`` is
+    the router (default: ``_route`` at ``num_experts_per_tok``); an id past
+    the experts (a skip choice) is an absent expert's: it adds nothing.
 
     Returns ``(out, held, visited)``: the held share's sum per token, the
     assignments that fell on held experts and the experts that got any (i32
     scalars, for the step log)."""
-    E_held, K = config.experts_held, config.num_experts_per_tok
-    weights, top_idx = _route(x, lp, K)
+    E_held = config.experts_held
+    weights, top_idx = _route(x, lp, config.num_experts_per_tok) if route is None else route(x, lp)
+    K = top_idx.shape[-1]
     local = top_idx.reshape(-1) - config.first_expert_held  # [T*K]
     held = (local >= 0) & (local < E_held)
     if valid is not None:

@@ -452,6 +452,10 @@ class Scheduler:
         if (self._eva or self._hybrid) and self.sc.enable_prefix_caching:
             logger.info("model %r: prefix-block reuse is not built for it, prefix caching is off", model_config.name)
             self.sc.enable_prefix_caching = False
+        # What a slot holds names its gauges and step-log counts: recurrent state
+        # ("ssm") or, for a stack of cca layers, the convolutions' columns ("cca").
+        self._slot_kind = "cca" if model_config.num_cca_layers else "ssm"
+        self.moe_skipped_rows_total = 0  # rows x layers that drew the ZAYA router's skip choice
         self.slots: Optional[SlotAllocator] = None
         if self._hybrid:
             # One slot a running sequence and the scratch slot: a sequence is
@@ -1016,12 +1020,15 @@ class Scheduler:
             "prefix_hit_rate": round(hits / (hits + misses), 6) if (hits + misses) else 0.0,
         }
         if self._hybrid:
+            kind = self._slot_kind
             out.update({
-                "ssm_slots_total": self.slots.num_slots - 1,
-                "ssm_slots_in_use": self.slots.in_use,
-                "ssm_slot_allocs_total": self.slots.allocs_total,
-                "ssm_preempt_recomputes_total": self.ssm_preempt_recomputes_total,
+                f"{kind}_slots_total": self.slots.num_slots - 1,
+                f"{kind}_slots_in_use": self.slots.in_use,
+                f"{kind}_slot_allocs_total": self.slots.allocs_total,
+                f"{kind}_preempt_recomputes_total": self.ssm_preempt_recomputes_total,
             })
+            if self.mc.moe_skip_choice:
+                out["moe_skipped_rows_total"] = self.moe_skipped_rows_total
         if self._eva:
             # Blocks that hold summaries of rolled windows (the last of them
             # may also hold the current window's first rows), the rest of the
@@ -1069,7 +1076,7 @@ class Scheduler:
                 "cached": a.num_cached,
                 "active": a.num_active,
                 "usage": round(a.usage(), 6),
-                **{k: v for k, v in self.kv_gauges().items() if k == "kv_fragmentation" or k.startswith(("eva_", "ssm_"))},
+                **{k: v for k, v in self.kv_gauges().items() if k == "kv_fragmentation" or k.startswith(("eva_", "ssm_", "cca_", "moe_skipped_"))},
             },
             "digests": self.telemetry.summary(),
             "slo": self.slo.to_stats(),
@@ -1267,7 +1274,8 @@ class Scheduler:
                 span.set(attended=sum(self._rows_for(s, s.total_len) for s in batch))
             if self._hybrid:
                 # Slots the dispatch advances (its decode rows' and its chunk's), and slots held.
-                span.set(ssm_rows=len(batch) + (1 if kind == "mixed" else 0), ssm_slots=self.slots.in_use)
+                span.set(**{f"{self._slot_kind}_rows": len(batch) + (1 if kind == "mixed" else 0),
+                            f"{self._slot_kind}_slots": self.slots.in_use})
             if kind in ("decode", "decode_multi", "mixed") and self._attn_impl == "megakernel":
                 # The decode rows' attention launch (megakernel.build_work): the steps it
                 # takes a layer, one a page under a row's current token, beside its batch
@@ -1432,11 +1440,15 @@ class Scheduler:
         aux, self._step_aux = self._step_aux, None
         if aux is None or self._step_span is None:
             return jax.device_get(tokens)
-        sampled, held, visited = jax.device_get((tokens, aux["held_assignments"], aux["experts_visited"]))
-        attrs = self._step_span.attrs or {}
-        self._step_span.set(held_assignments=attrs.get("held_assignments", 0) + int(held),
-                            experts_visited=attrs.get("experts_visited", 0) + int(visited))
+        sampled, aux = jax.device_get((tokens, aux))
+        self._count_aux(aux)
         return sampled
+
+    def _count_aux(self, aux: dict) -> None:
+        """The expert layer's counts of one dispatch (read back already), added onto the open ``sched.step``."""
+        attrs = self._step_span.attrs or {}
+        self._step_span.set(**{name: attrs.get(name, 0) + int(n) for name, n in aux.items()})
+        self.moe_skipped_rows_total += int(aux.get("skipped_rows", 0))
 
     def _read_step(self, tokens: jax.Array) -> np.ndarray:
         """``_read`` of a single step's tokens, inside its ``sched.sample`` and ``sched.sync``."""
@@ -1449,10 +1461,7 @@ class Scheduler:
         scalars are there already and the read waits for nothing."""
         aux, self._step_aux = self._step_aux, None
         if aux is not None and self._step_span is not None:
-            held, visited = jax.device_get((aux["held_assignments"], aux["experts_visited"]))
-            attrs = self._step_span.attrs or {}
-            self._step_span.set(held_assignments=attrs.get("held_assignments", 0) + int(held),
-                                experts_visited=attrs.get("experts_visited", 0) + int(visited))
+            self._count_aux(jax.device_get(aux))
 
     def _rows_for(self, seq: Sequence, n_tokens: int) -> int:
         """Table rows ``seq`` needs to hold its first ``n_tokens`` positions.

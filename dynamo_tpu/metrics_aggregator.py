@@ -73,6 +73,8 @@ GAUGE_KEYS = (
     # Hybrid models (ModelConfig.layer_types): slots of recurrent state beside
     # the block pool, and how many running sequences hold one.
     "ssm_slots_total", "ssm_slots_in_use",
+    # A stack of cca layers: a slot holds the convolutions' last columns.
+    "cca_slots_total", "cca_slots_in_use",
 )
 
 # Fleet-level digest families the aggregator re-exports (merged across
@@ -94,6 +96,9 @@ COUNTER_KEYS = (
     # layer_types: slots taken at admission, and states dropped at preemption
     # (each is a whole recompute of the sequence's recurrent state).
     "ssm_slot_allocs_total", "ssm_preempt_recomputes_total",
+    "cca_slot_allocs_total", "cca_preempt_recomputes_total",
+    # rows x layers that drew the ZAYA router's skip choice
+    "moe_skipped_rows_total",
     "moe_dropped_total", "moe_assignments_total",
     "mixed_steps_total", "mixed_prefill_tokens_total", "mixed_decode_tokens_total",
     "cached_tokens_total",

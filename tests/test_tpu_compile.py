@@ -465,6 +465,74 @@ def test_granite_step_programs_compile_and_fit_beside_the_weights(one_chip, on_t
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.0e9, (mem.argument_size_in_bytes, mem.temp_size_in_bytes)
 
 
+@functools.cache  # as above
+def _zaya_compiled(sh, program):
+    """``(compiled, params, k, v)``: a step program of the benchmark's
+    ``zaya1-8b-d20`` as configured (65 slots, 1,025 blocks, 64 rows, tables of
+    24). ``check-``: as the output check calls it (``zaya.program_logits``: its
+    bucket and table width, every position's logits)."""
+    import json
+
+    from benchmark import families
+    from dynamo_tpu.engine.kv_cache import KvCacheArrays
+    from dynamo_tpu.engine.models import hybrid
+
+    with open(os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "benchmark", "configs",
+                           "zaya1-8b-d20.json")) as f:
+        cfg = json.load(f)
+    fam = families.load("zaya")
+    mc = fam.model_config(cfg, "zaya")
+    sc = cfg["scheduler"]
+    place = lambda tree: jax.tree.map(lambda s: _sds(s.shape, s.dtype, sh), tree)  # noqa: E731
+    params = place(jax.eval_shape(lambda: fam.make_params(mc, 0)))
+    k, v = place(jax.eval_shape(
+        lambda: (lambda c: (c.k, c.v))(KvCacheArrays.create(mc, sc["num_blocks"], dtype=BF16, num_slots=sc["max_running"] + 1))))
+    check, program = program.startswith("check-"), program.removeprefix("check-")
+    B, W = (cfg["parity"]["decode_bucket"], 8) if check else (sc["max_running"], 24)
+    i32 = lambda *s: _sds(s, jnp.int32, sh)  # noqa: E731
+    f32 = lambda *s: _sds(s, jnp.float32, sh)  # noqa: E731
+    act = _sds((B,), jnp.bool_, sh)
+    if program == "decode_multi":
+        compiled = jax.jit(
+            lambda p, k, v, t, pos, bt, a, te, tk, tp, key: hybrid.decode_multi(p, mc, k, v, t, pos, bt, a, te, tk, tp, key, 8,
+                                                                                return_logits=check),
+            donate_argnums=(1, 2),
+        ).lower(params, k, v, i32(B), i32(B), i32(B, W), act, f32(B), i32(B), f32(B), _sds((2,), jnp.uint32, sh)).compile()
+    elif program == "prefill":
+        compiled = jax.jit(
+            lambda p, k, v, t, vl, cl, bt: hybrid.prefill(p, mc, k, v, t, vl, cl, bt, all_logits=check),
+            donate_argnums=(1, 2),
+        ).lower(params, k, v, i32(256), i32(), i32(), i32(W)).compile()
+    else:
+        compiled = jax.jit(
+            lambda p, k, v, pt, pv, cl, ptab, dt, dpos, dtab, da: hybrid.mixed_step(p, mc, k, v, pt, pv, cl, ptab, dt, dpos, dtab, da),
+            donate_argnums=(1, 2),
+        ).lower(params, k, v, i32(256), i32(), i32(), i32(W), i32(B), i32(B), i32(B, W), act).compile()
+    return compiled, params, k, v
+
+
+@pytest.mark.parametrize("program", ["decode_multi", "mixed_step", "check-prefill"])
+def test_zaya_step_programs_compile_and_fit_beside_the_weights(one_chip, on_tpu, program):
+    """The cca group's scan, the megakernel at 8/2 heads over 256-lane pages and
+    the grouped products over 2048 x 2048 experts (tiles of 2048 x 1024) compile
+    into one program whose arguments (9.4 GB of weights, 2.7 GB of pool and
+    slots, aliased to the results) and temporaries fit the chip; no weight and
+    no expert stack is copied or re-laid."""
+    compiled, params, k, v = _zaya_compiled(one_chip, program)
+    mem, text = compiled.memory_analysis(), compiled.as_text()
+    assert text.count("tpu_custom_call") >= 4 and "ragged_paged_attention" in text and "gmm" in text
+    assert mem.alias_size_in_bytes >= 2 * k.pool.size * 2 + v.slots.size * 2  # pool and slots are updated in place
+    assert 9.3e9 < mem.argument_size_in_bytes - mem.alias_size_in_bytes < 9.5e9  # the weights
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 14.5e9, (mem.argument_size_in_bytes, mem.temp_size_in_bytes)
+    shapes = {leaf.shape[1:] for leaf in jax.tree.leaves(params) if leaf.ndim >= 3 and math.prod(leaf.shape[1:]) >= 1 << 20}
+    for line in text.splitlines():
+        copied = re.search(r" copy\(%p__(layers|cca)__", line)
+        for dims, layout in _ARRAY.findall(line):
+            dims, layout = tuple(map(int, dims.split(","))), list(map(int, layout.split(",")))
+            assert not (copied and math.prod(dims) >= 1 << 20), line.strip()[:160]  # (a layer's two temperatures may move)
+            assert not (dims[1:] in shapes and len(dims) > 2 and layout != sorted(layout, reverse=True)), line.strip()[:160]
+
+
 # --- no step program re-lays a weight ---------------------------------------------------------
 
 M7_D4 = get_config("mistral-7b").replace(name="mistral-7b-d4", num_layers=4, block_size=128, max_seq_len=2048)
