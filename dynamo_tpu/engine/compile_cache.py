@@ -33,16 +33,29 @@ a set-up (``engine.build`` > ``build.params``, ``build.scheduler``,
 ``build.warmup``) are scopes too, and the log keeps every scope's interval
 beside the entries: the step log's ring turns over inside one minute of
 serving, this one holds a process's set-up until someone reads it.
+
+One stack chunk for a set-up (``in_one_chunk``). CPython 3.11+ keeps a thread's
+Python frames in chunks of 16 KiB: a call whose frame is the first of a new
+chunk maps it and the return that empties it unmaps it, so a loop whose callee
+sits on a chunk's edge pays an ``mmap`` and a ``munmap`` every iteration (on the
+chip's host 100 µs a call against 0.07: ``tools/stack_chunk_probe.py``). JAX's
+lowering is deep recursion with loops at every level, and half to two thirds of
+a warm set-up's lowering seconds were those system calls (PERF.md §6, PR 42).
+``TpuEngine.build`` therefore runs below one frame too large for a 16 KiB
+chunk: CPython maps one large chunk for it, once, and every frame of the
+set-up lives in the room that frame leaves free. An entry says whether it was
+built below that frame (``in_one_chunk``); one built in serving was not.
 """
 
 from __future__ import annotations
 
 import os
+import sys
 import threading
 import time
 from collections import deque
 from contextlib import contextmanager
-from typing import Any, Dict, Iterator, NamedTuple, Optional
+from typing import Any, Callable, Dict, Iterator, NamedTuple, Optional
 
 _DEFAULT_DIR = os.path.join(
     os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))), ".jax_cache"
@@ -63,6 +76,48 @@ BUILD_LOG_SIZE = 4096
 TOP = 10
 PARTS = ("trace_s", "lower_s", "backend_s")
 
+# The stack slots of ``in_one_chunk``'s frame: 1 MiB. ``push_chunk`` (CPython's Python/pystate.c) gives a frame that fits no
+# chunk one of 8 × (slots + 1000) bytes rounded up to a power of two, here 2 MiB, and the frames below live in the 1,048,456 B it
+# leaves free: 24 × the 43,888 B (114 frames) lowering's deepest call stood on below it in any of the five cells (PERF.md §6, PR 42).
+ANCHOR_SLOTS = 1 << 17
+
+
+class _Stack(threading.local):
+    anchored = False  # this thread is below ``in_one_chunk``'s frame
+
+
+_stack = _Stack()
+
+
+def _make_anchor() -> Optional[Callable]:
+    """The frame a set-up runs below, or None where the interpreter keeps no
+    frames in chunks (anything but CPython 3.11+). Stack slots are not
+    initialised, so the frame costs its mapping and not a write."""
+    if sys.implementation.name != "cpython" or sys.version_info < (3, 11):
+        return None
+
+    def anchor(fn, args, kwargs):
+        return fn(*args, **kwargs)
+
+    anchor.__code__ = anchor.__code__.replace(co_stacksize=ANCHOR_SLOTS)
+    return anchor
+
+
+_anchor = _make_anchor()
+
+
+def in_one_chunk(fn: Callable, *args: Any, **kwargs: Any) -> Any:
+    """``fn(*args, **kwargs)`` with every Python frame below it in one stack
+    chunk, mapped at this call and given back at its return (9 µs here, 0.1 ms
+    on the chip's host): for a set-up, not for a dispatch."""
+    if _anchor is None:
+        return fn(*args, **kwargs)
+    was, _stack.anchored = _stack.anchored, True
+    try:
+        return _anchor(fn, args, kwargs)
+    finally:
+        _stack.anchored = was
+
 
 class BuildEntry(NamedTuple):
     """One executable JAX built (compiled, or loaded from the persistent cache)."""
@@ -78,6 +133,7 @@ class BuildEntry(NamedTuple):
     backend_s: float  # a compile, or a load from the persistent cache
     cache: Optional[str]  # "hit" | "miss" | None (the persistent cache is off, or kept no entry)
     nested_traces: int
+    in_one_chunk: bool = False  # built below ``in_one_chunk``'s frame (a set-up), not on a serving thread's own stack
 
     @property
     def seconds(self) -> float:
@@ -86,7 +142,8 @@ class BuildEntry(NamedTuple):
     def brief(self) -> dict:
         return {"kind": self.kind, "key": None if self.key is None else str(self.key), "fun_name": self.fun_name,
                 "phase": self.phase, "trace_s": round(self.trace_s, 4), "lower_s": round(self.lower_s, 4),
-                "backend_s": round(self.backend_s, 4), "cache": self.cache, "nested_traces": self.nested_traces}
+                "backend_s": round(self.backend_s, 4), "cache": self.cache, "nested_traces": self.nested_traces,
+                "in_one_chunk": self.in_one_chunk}
 
 
 class _Thread(threading.local):
@@ -158,7 +215,8 @@ class BuildLog:
             key = tuple(last[1:]) if last and last[0] == kind else ()
         if phase is None and self.serving:
             phase = SERVING
-        entry = BuildEntry(now, threading.get_ident(), fun_name, phase, kind, key, trace_s, lower_s, backend_s, cache, nested)
+        entry = BuildEntry(now, threading.get_ident(), fun_name, phase, kind, key, trace_s, lower_s, backend_s, cache, nested,
+                           _stack.anchored)
         with self._lock:
             self.entries.append(entry)
             self.total += 1
@@ -226,6 +284,7 @@ class BuildLog:
             "keys": len(keys),
             "cache_hits": sum(e.cache == "hit" for e in entries),
             "cache_misses": sum(e.cache == "miss" for e in entries),
+            "in_one_chunk": sum(e.in_one_chunk for e in entries),
         }
         if build is not None:
             span_s = (build[3] - build[2]) / 1e9

@@ -25,7 +25,7 @@ from typing import Any, AsyncIterator, Callable, List, Optional
 import jax
 import jax.numpy as jnp
 
-from dynamo_tpu.engine.compile_cache import BUILD_LOG, enable_compile_cache
+from dynamo_tpu.engine.compile_cache import BUILD_LOG, enable_compile_cache, in_one_chunk
 from dynamo_tpu.engine.config import ModelConfig, get_config
 from dynamo_tpu.engine.kv_cache import KvEvent
 from dynamo_tpu.engine.models import llama
@@ -105,9 +105,9 @@ def _log_built(b: dict, warmup_ctx: int) -> None:
     """The start-up line: what ``debug_state()["build"]`` holds in full."""
     logger.info(
         "engine.build %.1f s (params %.1f, scheduler %.1f, warm-up at ctx %d %.1f): %d keys warmed, "
-        "%d executables (%d eager, %d from the cache) = trace %.1f + lower %.1f + backend %.1f + other %.1f s",
+        "%d executables (%d in one stack chunk, %d eager, %d from the cache) = trace %.1f + lower %.1f + backend %.1f + other %.1f s",
         b["engine_build"]["span_s"], *(b["phase_s"].get(p, 0.0) for p in ("build.params", "build.scheduler")), warmup_ctx,
-        b["phase_s"].get("build.warmup", 0.0), b["keys"], b["executables"], b["eager"], b["cache_hits"],
+        b["phase_s"].get("build.warmup", 0.0), b["keys"], b["executables"], b["in_one_chunk"], b["eager"], b["cache_hits"],
         *(b["engine_build"][p] for p in ("trace_s", "lower_s", "backend_s", "other_s")),
     )
 
@@ -180,12 +180,16 @@ class TpuEngine:
         or making them, quantizing them), ``build.scheduler`` (the pool, the
         slots, the ``jax.jit`` objects) and ``build.warmup``
         (``Scheduler.warmup``); every executable JAX builds inside is an entry
-        of the build log (engine/compile_cache.py) under its phase and key."""
+        of the build log (engine/compile_cache.py) under its phase and key.
+        All of it runs below ``in_one_chunk``'s frame, this one call: what is
+        traced and lowered here pays no system call at a stack chunk's edge,
+        whatever stands above this function or inside it (PERF.md §6, PR 42)."""
+        return in_one_chunk(cls._build, args, params, draft_params, kv_event_sink)
+
+    @classmethod
+    def _build(cls, args: EngineArgs, params, draft_params, kv_event_sink) -> "TpuEngine":
         enable_compile_cache()
         log = StepLog()
-        # (One function, no larger than it was: the bytes of the Python frames under a jitted
-        # call decide where CPython's stack chunks end inside JAX's lowering, and a warm
-        # set-up's lowering seconds move with them: PERF.md §6, PR 39.)
         with BUILD_LOG.scope(log, "engine.build"):
             mc = args.model_config or get_config(args.model)
             if args.kv_cache_dtype != "auto":

@@ -2232,11 +2232,11 @@ class Scheduler:
         (engine/compile_cache.py), whose ``build.key`` scopes these calls open:
         a key may be several executables, or none that was not built already.
         Array arguments are made before a key's scope opens, so that their
-        fills stay the eager executables they are. (No function stands between
-        this one and its jitted calls, and its frame is no larger than it was:
-        the bytes of the Python frames under a jitted call decide where
-        CPython's stack chunks end inside JAX's lowering, and a warm set-up's
-        lowering seconds move with them: PERF.md §6, PR 39.)"""
+        fills stay the eager executables they are. What these calls trace and
+        lower pays no system call at an edge of CPython's stack chunks where
+        the caller stands below ``compile_cache.in_one_chunk``'s frame, as
+        ``TpuEngine.build`` does: the size of this frame, and what stands
+        between it and its jitted calls, no longer move a set-up's seconds."""
         bs = self.mc.block_size
         max_w = self._width_bucket((ctx_tokens + bs - 1) // bs)
         widths = sorted(set(min(r, self.max_blocks_per_seq) for r in width_rungs(max_w)))

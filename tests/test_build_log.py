@@ -342,3 +342,24 @@ def test_a_stream_after_warmup_adds_no_entry_and_an_unwarmed_key_adds_one_with_i
     assert all(a["kind"] in {k[0] for k in unwarmed} for a in built_steps)
     since = sched.debug_state()["build"]["since_warmup"]
     assert [e["key"] for e in since if e["kind"] != "eager"] == [str(tuple(k[1:])) for k in unwarmed]
+
+
+def test_every_entry_inside_engine_build_was_built_in_one_stack_chunk_and_none_in_serving_is(built):
+    """PR 42: ``TpuEngine.build`` runs below ``compile_cache.in_one_chunk``'s frame; the step thread does not."""
+    engine, _ = built
+    sched = engine.scheduler
+    flight = sched.flight
+    _, t0, t1, _, _ = flight.log.named("engine.build")[0]
+    inside = [e for e in BUILD_LOG.entries if t0 <= e.t_ns <= t1]
+    assert len(inside) > 10 and all(e.in_one_chunk for e in inside)
+    # Not the warm-up calls alone. (``build.params`` too, where no earlier test of the process has built the weights' programs.)
+    assert {"build.scheduler", "build.warmup"} <= {e.phase for e in inside} <= {"build.params", "build.scheduler", "build.warmup"}
+    # A prompt of 99 tokens takes a table wider than any that warm-up (ctx 64) or the tests above met.
+    total0 = BUILD_LOG.total
+    assert serve(sched, {0: [("odd", list(range(1, 100)), 2)]}) == {"odd": 2}
+    new = list(BUILD_LOG.entries)[total0 - BUILD_LOG.total:] if BUILD_LOG.total > total0 else []
+    assert new and all(e.phase == "serving" and not e.in_one_chunk for e in new)
+    b = engine.debug_state()["build"]
+    serving = [e for e in BUILD_LOG.entries if e.t_ns > t1 and e.t_ns >= flight.since_ns]
+    assert b["in_one_chunk"] == len(inside) == b["executables"] - len(serving)
+    assert all(e["in_one_chunk"] for e in b["costliest"] if e["phase"] != "serving") and not any(e["in_one_chunk"] for e in b["since_warmup"])
