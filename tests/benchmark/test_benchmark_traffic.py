@@ -37,6 +37,10 @@ def test_exactly_rate_times_seconds_requests_are_due_inside_the_window(seconds):
         ramp = [r for r in reqs if not r.counted]
         if mix.get("order") != "rotate":  # a rotated ramp is the end of the circle, however many are due there
             assert len(ramp) == round(mix["rate_rps"] * mix["ramp_s"])
+        else:  # and those are the window's last requests, one turn earlier
+            last = [r for r in counted if r.due_s >= seconds - mix["ramp_s"]]
+            assert [(r.prompt_tokens, r.max_tokens) for r in ramp] == [(r.prompt_tokens, r.max_tokens) for r in last]
+            assert all(abs(a.due_s + seconds - b.due_s) < 1e-9 for a, b in zip(ramp, last))
         assert ramp and all(-mix["ramp_s"] <= r.due_s < 0 for r in ramp)
         assert [r.due_s for r in reqs] == sorted(r.due_s for r in reqs)
         assert [r.rid for r in reqs] == list(range(len(reqs)))
@@ -49,8 +53,9 @@ def test_seeds_differ_in_order_and_same_seed_repeats():
     assert [(r.due_s, r.max_tokens, r.word_ids) for r in a] == [(r.due_s, r.max_tokens, r.word_ids) for r in a2]
 
 
-def test_a_rotated_mix_offers_every_seed_the_same_requests_with_the_same_neighbours():
-    mix = traffic.load_mix("chat")
+@pytest.mark.parametrize("mix_name", ["chat", "chat-sat"])
+def test_a_rotated_mix_offers_every_seed_the_same_requests_with_the_same_neighbours(mix_name):
+    mix = traffic.load_mix(mix_name)
     assert mix["order"] == "rotate"
     runs = [[r for r in traffic.schedule(mix, s, 50, 32768) if r.counted] for s in SEEDS]
     base = [(r.prompt_tokens, r.max_tokens) for r in runs[0]]

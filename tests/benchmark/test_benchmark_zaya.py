@@ -163,30 +163,25 @@ ACCEPTED = ["client_ttft_p50_ms", "client_ttft_p90_ms", "compiles_in_window", "d
 
 
 def test_the_cell_is_in_the_manifest_and_its_metric_files_stand_ready():
-    """The cell reports `tpot_p50_ms`, `setup_s` and, per layer, `compile_s`. Its
-    27 other per-layer metrics are files a `benchmark` PR has only to list: an
-    accepted test (`test_benchmark_build_metrics.py`) holds the manifest's LAST
-    four per-layer entries to be PR 39's, new entries may only go at the end
-    ("one put first or in the middle reads as a change to what was there": the
-    builder's instructions, which refused PR 36 as `benchmark_edited`), and no
-    PR of this kind may edit that test. The `benchmark` PR that lists them
-    comes before any `perf_opt` that claims on this cell (PERF.md section 7,
-    PR 41 (1); ROADMAP, heads of the queues). When they are listed, turn this
-    test round: assert `set(mine) == set(ready) | {"compile_s"}`."""
+    """The cell reports `tpot_p50_ms`, `setup_s` and, per layer, `compile_s` and
+    the 27 metrics whose files end in `.reason.json`: each entry's fields are
+    its file's, and the 27 stand at the end of the list, after PR 39's
+    `build_eager_executables`, in this file's order (`NEW`, `ACCEPTED`, the
+    nine `.cca.` twins). PR 41 brought the files, PR 44 listed them (PERF.md
+    section 6)."""
     mine = {m["name"]: m for m in MANIFEST["per_layer"] if CELL in m.get("workloads", [])}
-    assert set(mine) == {"compile_s"} and mine["compile_s"]["moves"] == "setup_s"
+    ready = [n + ".reason" for n in NEW + ACCEPTED] + [n + ".cca.reason" for n in NINE]
+    assert set(mine) == set(ready) | {"compile_s"} and mine["compile_s"]["moves"] == "setup_s"
     assert CELL in next(m for m in MANIFEST["end_to_end"] if m["name"] == "tpot_p50_ms")["workloads"]
     assert CELL not in next(m for m in MANIFEST["end_to_end"] if m["name"] == "out_tok_s")["workloads"]
-    ready = [n + ".reason" for n in NEW + ACCEPTED] + [n + ".cca.reason" for n in NINE]
     on_disk = sorted(f[:-5] for f in os.listdir(os.path.join(ROOT, "benchmark", "metrics")) if f.endswith(".reason.json"))
     assert on_disk == sorted(ready) and len(ready) == 27
-    listed = {m["name"] for m in MANIFEST["per_layer"]}
-    layers = {m["layer"] for m in MANIFEST["per_layer"]}
+    listed = [m["name"] for m in MANIFEST["per_layer"]]
+    assert sorted(ready, key=listed.index) == ready and listed.index(ready[0]) > listed.index("build_eager_executables")
     for name in ready:
-        spec = readers.load_metric(name)  # what an entry needs, all but the cell's name
-        assert name not in listed and spec["moves"] == "tpot_p50_ms" and spec["layer"] in layers  # the layers the benchmark names
-        assert spec["better"] in ("lower", "higher") and spec["source"] in ("device_trace", "program_span", "program_counter", "host_clock")
-        assert "workloads" not in spec and len(spec["unit"]) <= 16
+        spec, entry = readers.load_metric(name), mine[name]
+        assert entry == {**{k: spec[k] for k in ("name", "unit", "better", "source", "layer", "moves")}, "workloads": [CELL]}
+        assert spec["moves"] == "tpot_p50_ms" and "workloads" not in spec and len(spec["unit"]) <= 16
         assert spec["reader"] in readers.READERS or os.path.exists(os.path.join(ROOT, "benchmark", "metrics", name + ".py"))
 
 
