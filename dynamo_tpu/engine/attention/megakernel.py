@@ -10,11 +10,16 @@ for the tiling — its pool interleaves K and V and is not ours.)
 
 ``ragged_paged_attention``: a row is a ``(start, len)`` run of queries over
 ``[paged prefix ; fresh keys]``. Decode entries are length-1 rows and walk a
-*work list* (``build_work``): one grid step a page that holds part of a live
-row's prefix, the last of a row's also closing it (its fresh keys, the
-normalisation, its output block), the count of them the grid's traced
-bound — a padded row of the batch bucket, and a table slot past a row's
-prefix, is no step at all; a prefill chunk is one wide row and walks the
+*work list* (``build_work``): one grid step a group of ``P`` consecutive
+table slots of a live row whose first holds part of its prefix
+(``pages_per_step``: as many pages as fill 256 KB a side, 1 from a page of
+128 tokens by 1,024 bf16 lanes, 4 by 256 lanes — a step costs the serial
+chain inside it, not its bytes, so narrow pages share one), its P key pages
+and P value pages fetched together, scored under one frontier mask and
+folded by ONE online-softmax update; the last step of a row also closes it
+(its fresh keys, the normalisation, its output block), the count of steps
+is the grid's traced bound — a padded row of the batch bucket, and a group
+past a row's prefix, is no step at all; a prefill chunk is one wide row and walks the
 static grid ``(tile of queries, page)`` — ``tile`` (``chunk_tile``)
 consecutive queries share a grid row, so one page fetch, one score dot and
 one value dot a page serve all of them, and the fresh keys are one step a
@@ -33,8 +38,11 @@ disjoint outputs, so nothing merges. With
   tile, which is not short of MXU rows, dots inside a *lane group* of whole
   KV heads (``lane_fold``: one head from a head size of 128) — no fold
   FLOPs, no ×KVH query bytes,
-- no step for what is dead: a rows launch lists its live pages only, so a
-  ragged batch costs its pages, not bucket x table width (on a v5e an empty
+- no step for what is dead: a rows launch lists its live groups of pages
+  only, so a ragged batch costs its pages, not bucket x table width (a slot
+  of a row's last group past its prefix is a fetch that is masked: a step is
+  bound by its chain, not its bytes, and 40 rows of 5 pages take what 40 of
+  8 take at 4 pages a step, 102 us a layer; on a v5e an empty
   grid step is 0.14 µs of pipeline bookkeeping, and a padded row 0.6 µs more:
   its 64 KB query and output blocks, its state, its fresh-key dots); a
   chunk's padded queries, wholly padded tiles and table slots past its
@@ -96,31 +104,73 @@ def build_meta(
     )
 
 
-# A work item: row << 16 | table slot.
+# A work item: row << 16 | the first table slot of its group of pages.
 _ROW_SHIFT = 16
 _SLOT_MASK = (1 << _ROW_SHIFT) - 1
 
+# What a step of a rows launch should fetch a side (``pages_per_step``): a
+# page of 128 tokens by 1,024 bf16 lanes, the llama cells' page. A step costs
+# the serial chain inside it (dots of 8 rows, mask, max, exp, three
+# read-modify-writes of the softmax state, the pipeline's bookkeeping and its
+# DMA waits), not its bytes. On a v5e (tools/attn_chunk_bench.py --study rows
+# --heads 8 --lanes 256 --pages-per-step 1 2 4 8, PERF.md section 6 PR 47),
+# pages of 128 tokens by 256 lanes, 64 KB a side: a step of 1 / 2 / 4 / 8
+# pages takes 0.49 / 0.65 / 0.9 / 1.6 us, so 40 rows of 7 pages in a bucket
+# of 64 take 168 / 131 / 106 / 97 us a layer (their 37 MB need 45) and 40 rows
+# of 16 pages 343 / 229 / 179 / 162, but 40 rows of 3 pages 90 / 81 / 71 / 98:
+# past 256 KB a side a short row pays for pages it masks.
+ROWS_STEP_BYTES = 256 << 10
+# No more page operands a side than the tool read: 2 P operands a launch, 4 P
+# over an int8 pool, every one a DMA to start, to wait for and to compare with
+# the last step's. Reached only by pages under 32 KB a side.
+ROWS_STEP_PAGES = 8
 
-def build_work(prefix_len: jax.Array, active: jax.Array, num_slots: int, block_size: int) -> jax.Array:
+
+def pages_per_step(block_size: int, kv_lanes: int, kv_bytes: int, num_slots: int) -> int:
+    """Consecutive table slots that one step of a length-1 rows launch takes
+    (``P``): the largest power of two of pages whose bytes a side stay within
+    ``ROWS_STEP_BYTES``, at least 1, at most ``ROWS_STEP_PAGES`` and the
+    table's width. 4 for pages of 128 tokens by 256 bf16 lanes, 1 by 1,024.
+    ``kv_lanes`` are the launch's own (a tp shard's)."""
+    most = min(ROWS_STEP_BYTES // (block_size * kv_lanes * kv_bytes), ROWS_STEP_PAGES, num_slots)
+    p = 1
+    while p * 2 <= most:
+        p *= 2
+    return p
+
+
+def work_len(num_rows: int, num_slots: int, pages_per_step: int = 1) -> int:
+    """Length of ``build_work``'s list: the count, then room for every group
+    of every row and one more a row. It differs between any two ``P`` a table
+    admits, so a list built at another ``P`` than its launch's fails the
+    launch's trace."""
+    return 1 + num_rows * (-(-num_slots // pages_per_step) + 1)
+
+
+def build_work(
+    prefix_len: jax.Array, active: jax.Array, num_slots: int, block_size: int, pages_per_step: int = 1,
+) -> jax.Array:
     """The work list of a length-1 rows launch (``prefix_len`` ``[NQ]`` i32
-    and ``active`` ``[NQ]`` bool as ``build_meta`` takes them), ``[1 + NQ *
-    (W + 1)]`` i32: the count of live items, then the items, a live row's
-    after the live row before it — one a page that holds part of its prefix,
-    table slot 0, 1, ... (slot 0 alone for a row with no prefix). The launch's
-    grid is the count: a dead row, and a table slot past a row's prefix, is no
-    step at all; a row's last item also closes it (its fresh keys, the
+    and ``active`` ``[NQ]`` bool as ``build_meta`` takes them), ``[work_len(NQ,
+    W, P)]`` i32: the count of live items, then the items, a live row's
+    after the live row before it — one a group of ``P`` (``pages_per_step``)
+    consecutive table slots of which the first holds part of its prefix,
+    first slot 0, P, 2 P, ... (slot 0 alone for a row with no prefix). The
+    launch's grid is the count: a dead row, and a group past a row's prefix,
+    is no step at all; a row's last item also closes it (its fresh keys, the
     normalisation, its output block). Prefixes and liveness are a step's, not
     a layer's: build it once a step program, outside the layer scan. With no
     live row the count is 1 and the one item is row 0's slot 0, which is dead
     and reads nothing."""
-    NQ, W = prefix_len.shape[0], num_slots
-    assert NQ < 1 << (31 - _ROW_SHIFT) and W < _SLOT_MASK, (NQ, W)
+    NQ, W, P = prefix_len.shape[0], num_slots, pages_per_step
+    G = -(-W // P)
+    assert NQ < 1 << (31 - _ROW_SHIFT) and W < _SLOT_MASK and P >= 1, (NQ, W, P)
     # A dozen and a half primitives bound directly: an operator on a traced
     # array, an index or a jax.numpy function is a jitted helper traced anew
     # at every new shape, every primitive is lowered anew at every new shape,
     # and a warm set-up traces and lowers this once a step program (PERF.md
     # section 6, PR 38).
-    cells, past = (NQ, W + 1), NQ * (W + 1)
+    cells, past = (NQ, G + 1), NQ * (G + 1)
     over = functools.partial(lax.broadcast_in_dim, shape=cells, broadcast_dimensions=(0,))
 
     def full(value, shape=(NQ,)):
@@ -129,12 +179,14 @@ def build_work(prefix_len: jax.Array, active: jax.Array, num_slots: int, block_s
     if active.dtype != jnp.bool_:
         active = lax.ne(active, lax.full_like(active, 0))
     pages = lax.min(lax.div(lax.add(prefix_len, full(block_size - 1)), full(block_size)), full(W))
+    if P > 1:
+        pages = lax.div(lax.add(pages, full(P - 1)), full(P))
     counts = lax.select(active, lax.max(pages, full(1)), full(0))
     ends = lax.cumsum(counts)
-    # Slot j of row r for every (r, j) of the bucket: a row's first counts[r]
+    # Group j of row r for every (r, j) of the bucket: a row's first counts[r]
     # land at its start + j, the rest past the list's end, where they drop.
-    j = np.broadcast_to(np.arange(W + 1, dtype=np.int32), cells)
-    items = (np.arange(NQ, dtype=np.int32)[:, None] << _ROW_SHIFT) | j
+    j = np.broadcast_to(np.arange(G + 1, dtype=np.int32), cells)
+    items = (np.arange(NQ, dtype=np.int32)[:, None] << _ROW_SHIFT) | j * P
     place = lax.select(lax.lt(j, over(counts)), lax.add(over(lax.sub(ends, counts)), j), full(past, cells))
     listed = lax.scatter(
         full(0, (past,)), lax.reshape(place, (past, 1)), items.reshape(past),
@@ -192,32 +244,37 @@ def chunk_tile(
     return tile
 
 
-def _online_update(m_ref, l_ref, acc_ref, rows, s, v):
-    """Fold one score tile + value tile into ``rows`` of the online-softmax
-    scratch."""
+def _online_update(m_ref, l_ref, acc_ref, rows, scores, values):
+    """Fold score tiles (equal shapes) and their value tiles into ``rows`` of
+    the online-softmax scratch, as one update: one running max over all of
+    them, one rescale of the state."""
     m_prev = m_ref[rows]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-    p = jnp.exp(s - m_new)
+    m_new = jnp.maximum(m_prev, jnp.max(functools.reduce(jnp.maximum, scores), axis=1, keepdims=True))
+    ps = [jnp.exp(s - m_new) for s in scores]
     alpha = jnp.exp(m_prev - m_new)
-    pv = lax.dot_general(
-        p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
+    pv = functools.reduce(lax.add, [
+        lax.dot_general(
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+        for p, v in zip(ps, values)
+    ])
     m_ref[rows] = m_new
-    l_ref[rows] = l_ref[rows] * alpha + jnp.sum(p, axis=1, keepdims=True)
+    l_ref[rows] = l_ref[rows] * alpha + jnp.sum(functools.reduce(lax.add, ps), axis=1, keepdims=True)
     acc_ref[rows] = acc_ref[rows] * alpha + pv
 
 
 def _mega_kernel(
     tables_ref,  # SMEM [R, W] i32 — per-row page ids (layer-offset, dead → 0)
     meta_ref,  # SMEM [5, NT] i32 — build_meta layout, one column a grid row
-    *refs,  # work_ref? wq_ref, ke_ref, ve_ref, k_ref, v_ref, (ks_ref, vs_ref)? o_ref, m_ref, l_ref, acc_ref
+    *refs,  # work_ref? wq_ref, ke_ref, ve_ref, k_refs, v_refs, (ks_refs, vs_refs)? o_ref, m_ref, l_ref, acc_ref
     block_size: int,
     num_slots: int,
     scale: float,
     quant: bool,
     tile: int,
     groups: int,
+    pages_per_step: int,
 ):
     """A grid row is ``tile`` consecutive queries of one sequence row: one
     query (``tile`` 1, a decode row) or a run of a chunk's. Its queries share
@@ -230,20 +287,24 @@ def _mega_kernel(
     A step is one (grid row, table slot): read off the grid ``(NT, W + 1)``,
     whose last step a grid row, past its table, closes the row; or, for
     length-1 rows (``tile`` 1), off item ``program_id(0)`` of the work list (``build_work``:
-    SMEM ``[1 + NT * (W + 1)]``), whose grid is its live items and nothing
-    else, a row closing on the step of its last page."""
-    listed = tile == 1
+    SMEM ``[work_len(NT, W, P)]``), whose grid is its live items and nothing
+    else. A listed step takes ``P`` (``pages_per_step``) consecutive slots
+    from its item's: P key pages and P value pages in flight, their scores
+    masked by one frontier and folded by one softmax update; a row closes on
+    the step of its last group."""
+    listed, P = tile == 1, pages_per_step
     if listed:
         work_ref, *refs = refs
     # wq_ref VMEM [1, groups*rows, lanes]: this grid row's queries, folded;
     # ke_ref, ve_ref VMEM [CK, KVHD]: ALL fresh keys (lane-merged), loaded
-    # once; k_ref, v_ref VMEM [1, BS, KVHD]: this step's page.
-    wq_ref, ke_ref, ve_ref, k_ref, v_ref, *rest = refs
+    # once; k_refs, v_refs P x VMEM [1, BS, KVHD]: this step's pages.
+    wq_ref, ke_ref, ve_ref, *rest = refs
+    k_refs, v_refs, rest = rest[:P], rest[P : 2 * P], rest[2 * P :]
     if quant:
-        ks_ref, vs_ref, o_ref, m_ref, l_ref, acc_ref = rest
+        ks_refs, vs_refs, rest = rest[:P], rest[P : 2 * P], rest[2 * P :]
     else:
-        o_ref, m_ref, l_ref, acc_ref = rest
-        ks_ref = vs_ref = None
+        ks_refs = vs_refs = (None,) * P
+    o_ref, m_ref, l_ref, acc_ref = rest
     if listed:
         item = work_ref[1 + pl.program_id(0)]
         nq, w = item >> _ROW_SHIFT, item & _SLOT_MASK
@@ -256,9 +317,12 @@ def _mega_kernel(
     live = n_live > 0
     bs = block_size
     if listed:
-        # A listed row closes on the step of its prefix's last page (slot 0 where it has none),
+        # A listed row closes on the step of its prefix's last group of pages (slot 0 where it has none),
         pages = lax.min(lax.div(prefix_len + (bs - 1), jnp.int32(bs)), jnp.int32(num_slots))
-        closes = w == lax.max(pages, jnp.int32(1)) - 1
+        if P > 1:
+            pages = lax.div(pages + (P - 1), jnp.int32(P))
+        last = lax.max(pages, jnp.int32(1)) - 1
+        closes = w == (last * P if P > 1 else last)
     else:
         # a grid row on a step of its own after its table's last slot.
         closes = w == num_slots
@@ -270,7 +334,7 @@ def _mega_kernel(
     wq = wq_ref[0] if groups == 1 else None
 
     def page(ref, scale_ref, g):
-        """Lane group ``g`` of the page in flight (``[1, BS, KVHD]``, int8
+        """Lane group ``g`` of a page in flight (``[1, BS, KVHD]``, int8
         with its scales) or of the fresh keys (``[CK, KVHD]``)."""
         ln = slice(g * lanes, (g + 1) * lanes)
         if scale_ref is None:
@@ -286,24 +350,26 @@ def _mega_kernel(
         ).astype(dtype)
 
     def attend(keys, key_scales, values, value_scales, mask):
-        """Every group's scores against ``keys``, kept where ``mask(shape)``
-        says, folded with ``values`` into the scratch."""
+        """Every group's scores against each of ``keys`` (equal shapes), piece
+        ``i`` kept where ``mask(i, shape)`` says, folded with ``values`` into
+        the scratch in one update a group."""
         keep = None
         for g in range(groups):
             r = slice(g * rows, (g + 1) * rows)
-            k = page(keys, key_scales, g)  # [n, lanes]
-            v = page(values, value_scales, g)
+            ks = [page(*ref, g) for ref in zip(keys, key_scales)]  # [n, lanes] each
+            vs = [page(*ref, g) for ref in zip(values, value_scales)]
             q = wq if groups == 1 else wq_ref[0, r, :]
-            s = (
+            ss = [
                 lax.dot_general(
                     q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
                 )
                 * scale
-            )  # [rows, n]
+                for k in ks
+            ]  # [rows, n] each
             if keep is None:
-                keep = mask(s.shape)
-            s = jnp.where(keep, s, NEG_INF)
-            _online_update(m_ref, l_ref, acc_ref, r, s, v)
+                keep = [mask(i, s.shape) for i, s in enumerate(ss)]
+            ss = [jnp.where(kept, s, NEG_INF) for kept, s in zip(keep, ss)]
+            _online_update(m_ref, l_ref, acc_ref, r, ss, vs)
 
     @pl.when(w == 0)
     def _init():
@@ -311,19 +377,20 @@ def _mega_kernel(
         l_ref[:] = jnp.zeros(l_ref.shape, jnp.float32)
         acc_ref[:] = jnp.zeros(acc_ref.shape, jnp.float32)
 
-    # Paged-prefix piece: slot w holds tokens [w*bs, w*bs+bs) of this grid
-    # row's sequence. On the static grid dead rows and slots past the true
-    # prefix are skipped — no page fetch is wasted on the table's width
-    # (consecutive identical table entries reuse the pipelined fetch, so a
-    # short prefix in a wide table costs one scratch-page fetch, not W); a
-    # list holds no such step.
+    # Paged-prefix piece: slot w + i holds tokens [(w+i)*bs, (w+i)*bs+bs) of
+    # this grid row's sequence. On the static grid dead rows and slots past
+    # the true prefix are skipped — no page fetch is wasted on the table's
+    # width (consecutive identical table entries reuse the pipelined fetch,
+    # so a short prefix in a wide table costs one scratch-page fetch, not W);
+    # a list holds no such step, and a slot of a listed group past the prefix
+    # is all masked.
     @pl.when(live & (w < num_slots) & (w * bs < prefix_len))
     def _page():
-        def in_prefix(shape):
-            kpos = w * bs + lax.broadcasted_iota(jnp.int32, shape, 1)
+        def in_prefix(i, shape):
+            kpos = (w + i if i else w) * bs + lax.broadcasted_iota(jnp.int32, shape, 1)
             return kpos < prefix_len
 
-        attend(k_ref, ks_ref, v_ref, vs_ref, in_prefix)
+        attend(k_refs, ks_refs, v_refs, vs_refs, in_prefix)
 
     # Closing step: the in-flight (not-yet-cached) keys — a chunk query's
     # causal window over its own chunk, a decode query's current token, a
@@ -332,14 +399,14 @@ def _mega_kernel(
     def _fresh_and_final():
         @pl.when(live & (e_end > e_start))
         def _fresh():
-            def in_window(shape):
+            def in_window(_, shape):
                 cpos = lax.broadcasted_iota(jnp.int32, shape, 1)
                 end = e_end
                 if tile > 1:
                     end = e_end + lax.broadcasted_iota(jnp.int32, shape, 0) // (rows // tile)
                 return (cpos >= e_start) & (cpos < end)
 
-            attend(ke_ref, None, ve_ref, None, in_window)
+            attend((ke_ref,), (None,), (ve_ref,), (None,), in_window)
 
         out = acc_ref[:] / jnp.maximum(l_ref[:], 1e-30)
         if tile > 1:
@@ -361,7 +428,7 @@ def ragged_paged_attention(
     v_pages,
     tables: jax.Array,  # [R, W] i32 — per-sequence-row page ids (layer-offset)
     meta: jax.Array,  # [5, NQ] i32 — build_meta
-    work: jax.Array | None = None,  # [1 + NQ*(W+1)] i32 — build_work of meta's prefixes and liveness, length-1 rows only
+    work: jax.Array | None = None,  # [work_len(NQ, W, P)] i32 — build_work of meta's prefixes and liveness at this launch's pages_per_step, length-1 rows only
     *,
     num_kv_heads: int,
     block_size: int,
@@ -433,9 +500,12 @@ def ragged_paged_attention(
     assert k_pages.shape[2] == KVHD, (k_pages.shape, KVH, HD)
 
     # Where a step is, (grid row, table slot): off the grid, or off its item.
+    P = 1
     if tile == 1:
+        P = pages_per_step(BS, KVHD, jnp.dtype((k_pages.q if quant else k_pages).dtype).itemsize, W)
         if work is None:
-            work = build_work(meta[1], meta[4] > 0, W, block_size)
+            work = build_work(meta[1], meta[4] > 0, W, block_size, P)
+        assert work.shape == (work_len(NQ, W, P),), f"a work list of {work.shape} for {NQ} rows of {W} slots by {P}"
         scalars = (tables, meta, work)
         grid = (lax.index_in_dim(work, 0, keepdims=False),)
 
@@ -451,29 +521,31 @@ def ragged_paged_attention(
     def row_idx(*step):
         return (at(*step)[0], 0, 0)
 
-    def page_idx(*step):
+    def page_idx(i, *step):
+        """Slot ``w + i`` of the step's row (past a listed row's prefix the
+        table holds the scratch page, and the kernel masks all of it)."""
         nq, w = at(*step)
         t, mt = step[len(grid) : len(grid) + 2]
-        return (t[mt[0, nq], jnp.minimum(w, W - 1)], 0, 0)
+        return (t[mt[0, nq], jnp.minimum(w + i if i else w, W - 1)], 0, 0)
 
     def fresh_idx(*_):
         return (0, 0)
+
+    def page_specs(lanes):
+        return [pl.BlockSpec((1, BS, lanes), functools.partial(page_idx, i)) for i in range(P)]
 
     in_specs = [
         pl.BlockSpec(block, row_idx),
         pl.BlockSpec((CK, KVHD), fresh_idx),
         pl.BlockSpec((CK, KVHD), fresh_idx),
-        pl.BlockSpec((1, BS, KVHD), page_idx),
-        pl.BlockSpec((1, BS, KVHD), page_idx),
+        *page_specs(KVHD),
+        *page_specs(KVHD),
     ]
     if quant:
-        in_specs += [
-            pl.BlockSpec((1, BS, KVH), page_idx),
-            pl.BlockSpec((1, BS, KVH), page_idx),
-        ]
-        args = [wq, ke, ve, k_pages.q, v_pages.q, k_pages.scale, v_pages.scale]
+        in_specs += page_specs(KVH) + page_specs(KVH)
+        args = [wq, ke, ve, *[k_pages.q] * P, *[v_pages.q] * P, *[k_pages.scale] * P, *[v_pages.scale] * P]
     else:
-        args = [wq, ke, ve, k_pages, v_pages]
+        args = [wq, ke, ve, *[k_pages] * P, *[v_pages] * P]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(scalars),
@@ -495,6 +567,7 @@ def ragged_paged_attention(
             quant=quant,
             tile=tile,
             groups=groups,
+            pages_per_step=P,
         ),
         out_shape=jax.ShapeDtypeStruct(wq.shape, q.dtype),
         grid_spec=grid_spec,

@@ -73,6 +73,7 @@ from dynamo_tpu.engine.models.llama import (  # noqa: F401 — the scheduler rea
     _attend_piece,
     _gather_kv,
     _mega_attend_rows,
+    _mega_rows_work,
     _merge_pieces,
     _moe_held,
     _norm,
@@ -83,6 +84,7 @@ from dynamo_tpu.engine.models.llama import (  # noqa: F401 — the scheduler rea
     decode_targets,
     resolve_attention_impl,
     resolve_prefill_impl,
+    rows_pages_per_step,
     warn_attention_impl_degrade,
 )
 
@@ -244,12 +246,12 @@ def _rows_attention(c: ModelConfig, k_pool, v_pool, tables, prefix_lens, active,
     use_mega = _use_megakernel(c, k_pool)
     prefix_lens = jnp.minimum(prefix_lens, ctx).astype(jnp.int32)
     if use_mega:
-        from dynamo_tpu.engine.attention.megakernel import build_meta, build_work
+        from dynamo_tpu.engine.attention.megakernel import build_meta
 
         rows_i = jnp.arange(B, dtype=jnp.int32)
         first = rows_i * (w + 1)
         meta = build_meta(rows_i, prefix_lens, first, first + 1 + (0 if step is None else step), active)
-        work = build_work(prefix_lens, active, tables.shape[1], bs)
+        work = _mega_rows_work(c, k_pool, prefix_lens, active, tables.shape[1])
     else:
         mask = jnp.arange(ctx, dtype=jnp.int32)[None, :] < prefix_lens[:, None]
         small_mask = jnp.ones((B, 1), dtype=bool)

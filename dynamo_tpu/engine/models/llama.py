@@ -704,6 +704,28 @@ def chunk_attn_path(c: ModelConfig, k_cache, num_queries: int, dtype) -> str:
     return f"tile{_chunk_tile(c, k_cache, num_queries, dtype)}" if impl == "megakernel" else impl
 
 
+def rows_pages_per_step(c: ModelConfig, k_cache, num_slots: int) -> int:
+    """Table slots a step of the length-1 rows' launch takes over ``k_cache``
+    (any layout of the pool: its last axes are a page) and a table
+    ``num_slots`` wide: what ``ragged_paged_attention`` reads off this
+    step's shard of a page (megakernel.pages_per_step)."""
+    from dynamo_tpu.engine.attention.megakernel import pages_per_step
+
+    tp = max(kernel_shards(c.num_kv_heads), 1)
+    return pages_per_step(
+        k_cache.shape[-2], k_cache.shape[-1] // tp, jnp.dtype(k_cache.dtype).itemsize, num_slots
+    )
+
+
+def _mega_rows_work(c: ModelConfig, k_cache, prefix_lens: jax.Array, active: jax.Array, num_slots: int) -> jax.Array:
+    """The work list of a step's length-1 rows (megakernel.build_work), at
+    the pages a step their launch takes: built once a step program, handed to
+    every layer's ``_mega_attend_rows``."""
+    from dynamo_tpu.engine.attention.megakernel import build_work
+
+    return build_work(prefix_lens, active, num_slots, c.block_size, rows_pages_per_step(c, k_cache, num_slots))
+
+
 def _mega_attend_rows(
     c: ModelConfig,
     q: jax.Array,  # [NQ, H, HD]
@@ -1231,13 +1253,13 @@ def _decode_layer_scan_window(
         # row's slice of [current ; window rows] — a contiguous [start,
         # end) column window, end advancing with the in-window step (the
         # not-yet-written carry rows stay masked for free).
-        from dynamo_tpu.engine.attention.megakernel import build_meta, build_work
+        from dynamo_tpu.engine.attention.megakernel import build_meta
 
         rows_i = jnp.arange(B, dtype=jnp.int32)
         mega_meta = build_meta(
             rows_i, win_prefix_lens, rows_i * (w + 1), rows_i * (w + 1) + 1 + step, active,
         )
-        mega_work = build_work(win_prefix_lens, active, block_tables.shape[1], bs)
+        mega_work = _mega_rows_work(c, k_cache, win_prefix_lens, active, block_tables.shape[1])
 
     scanned, experts = _split_expert_stacks(c, layers)
 
@@ -1539,7 +1561,7 @@ def mixed_step(
         # or a padded table slot is no step of it. The chunk's padded slots
         # hold the scratch page and are skipped (pl.when) along with its
         # bucket's dead queries.
-        from dynamo_tpu.engine.attention.megakernel import build_meta, build_work
+        from dynamo_tpu.engine.attention.megakernel import build_meta
 
         s_iq = jnp.arange(S, dtype=jnp.int32)
         d_iq = jnp.arange(B, dtype=jnp.int32)
@@ -1548,7 +1570,7 @@ def mixed_step(
             jnp.zeros((S,), jnp.int32), s_iq + 1, s_iq < p_valid,
         )
         d_meta = build_meta(d_iq, d_prefix_lens, d_iq, d_iq + 1, d_active)
-        d_work = build_work(d_prefix_lens, d_active, d_tables.shape[1], bs)
+        d_work = _mega_rows_work(c, k_cache, d_prefix_lens, d_active, d_tables.shape[1])
 
     from dynamo_tpu.engine.attention.ragged import ragged_chunk_attention
 
@@ -1760,12 +1782,12 @@ def decode_layer_scan(
     wdtype = h.dtype if wdtype is None else wdtype
     prefix_lens = jnp.minimum(cache_rows(c, positions), ctx).astype(jnp.int32)
     if use_mega:
-        from dynamo_tpu.engine.attention.megakernel import build_meta, build_work
+        from dynamo_tpu.engine.attention.megakernel import build_meta
 
         rows_i = jnp.arange(B, dtype=jnp.int32)
         live = jnp.ones((B,), bool) if active is None else active
         mega_meta = build_meta(rows_i, prefix_lens, rows_i, rows_i + 1, live)
-        mega_work = build_work(prefix_lens, live, block_tables.shape[1], bs)
+        mega_work = _mega_rows_work(c, k_cache, prefix_lens, live, block_tables.shape[1])
 
     scanned, experts = _split_expert_stacks(c, layers)
 

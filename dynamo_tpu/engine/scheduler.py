@@ -520,7 +520,9 @@ class Scheduler:
         # What the decode rows' attention launches walked, and what their
         # bucket and table width spanned (_note_step).
         self.attn_items_total = 0
+        self.attn_pages_total = 0
         self.attn_slots_total = 0
+        self._rows_step_pages: Dict[int, int] = {}
         # SLA telemetry: mergeable latency digests (ttft/tpot/itl/queue_wait
         # + per-phase step durations via the flight recorder) and the SLO
         # judge behind the goodput account. All host-side — no dispatches.
@@ -1084,9 +1086,11 @@ class Scheduler:
             # path did (a row that needs the host between logits and token; a wave).
             "sampled_in_program_total": self.sampled_in_program_total,
             "sampled_on_host_total": self.sampled_on_host_total,
-            # Steps the decode rows' attention launches took a layer (one a live page),
-            # and the bucket x (table width + 1) they would span.
+            # Steps the decode rows' attention launches took a layer (one a group of
+            # pages_per_step live pages), the pages under them (pages / items: what a step
+            # carried), and the bucket x (table width + 1) they would span.
             "attn_items_total": self.attn_items_total,
+            "attn_pages_total": self.attn_pages_total,
             "attn_slots_total": self.attn_slots_total,
             # What JAX built for this engine (engine/compile_cache.py): seconds by
             # phase, by kind, the costliest keys, eager executables, entries since warm-up.
@@ -1277,13 +1281,16 @@ class Scheduler:
                 span.set(**{f"{self._slot_kind}_rows": len(batch) + (1 if kind == "mixed" else 0),
                             f"{self._slot_kind}_slots": self.slots.in_use})
             if kind in ("decode", "decode_multi", "mixed") and self._attn_impl == "megakernel":
-                # The decode rows' attention launch (megakernel.build_work): the steps it
-                # takes a layer, one a page under a row's current token, beside its batch
-                # bucket x (table width + 1).
+                # The decode rows' attention launch (megakernel.build_work): the pages under
+                # the rows' current tokens, the steps it takes a layer (one a group of
+                # pages_per_step of a row's pages), beside its batch bucket x (table width + 1).
                 bs, (bucket, width) = self.mc.block_size, key[-2:]
-                items = sum(max(min(-(-self._rows_for(s, s.total_len - 1) // bs), width), 1) for s in batch)
-                span.set(attn_items=items, attn_slots=bucket * (width + 1))
+                per_step = self._rows_pages_per_step(width)
+                held = [max(min(-(-self._rows_for(s, s.total_len - 1) // bs), width), 1) for s in batch]
+                pages, items = sum(held), sum(-(-h // per_step) for h in held)
+                span.set(attn_items=items, attn_pages=pages, attn_slots=bucket * (width + 1))
                 self.attn_items_total += items
+                self.attn_pages_total += pages
                 self.attn_slots_total += bucket * (width + 1)
         else:
             span.set(dispatches=attrs.get("dispatches", 1) + 1)
@@ -1291,6 +1298,14 @@ class Scheduler:
             # The chunk's attention path, also where the chunk is the
             # iteration's second dispatch (a bare chunk after a window).
             span.set(chunk_attn=self._chunk_attn(key[0]))
+
+    def _rows_pages_per_step(self, width: int) -> int:
+        """Pages a step of the decode rows' attention launch takes under a
+        table ``width`` slots wide, as this engine traced it
+        (megakernel.pages_per_step over this engine's pool)."""
+        if width not in self._rows_step_pages:  # once a table width, not once a dispatch
+            self._rows_step_pages[width] = self._model.rows_pages_per_step(self.mc, pool_of(self.cache.k), width)
+        return self._rows_step_pages[width]
 
     def _chunk_attn(self, bucket: int) -> str:
         """How a chunk of ``bucket`` queries meets its keys in ``prefill`` and

@@ -239,7 +239,11 @@ def test_a_second_enable_compile_cache_registers_nothing_twice(monkeypatch):
 @pytest.fixture(scope="module")
 def built():
     """(engine, BUILD_LOG.total before its build): ``tiny`` on the gather path,
-    one chunk bucket, one batch bucket, windows of 4, tables of 4 blocks."""
+    one chunk bucket, one batch bucket, windows of 4, tables of 4 blocks.
+    JAX's in-memory caches are emptied first: a test file that served the same
+    preset in this worker before (xdist hands files out as workers fall free)
+    has left its step programs there, and this build would then build nothing."""
+    jax.clear_caches()
     total0 = BUILD_LOG.total
     engine = TpuEngine.build(EngineArgs(
         model="tiny", dtype="float32", warmup_ctx=64,

@@ -311,20 +311,43 @@ ROWS = {
 }
 
 
-@pytest.mark.parametrize("case", list(ROWS))
-def test_listed_rows_match_the_dense_reference_and_the_walk_of_every_slot(case):
-    """A batch of length-1 rows, its launch one step a live page, the last
-    of a row's closing it (``build_work``), gives what plain softmax attention
-    gives, and on its live rows, to the bit, what the walk of every slot of
-    every bucket row gave (the static grid before PR 38, every bucket row
-    marked live as its callers did); dead rows return exact zeros. A window's
-    steps (a row's fresh keys ``[start, start + 1 + step)``) share one list."""
+@pytest.fixture
+def step_pages(monkeypatch):
+    """``step_pages(P, page_bytes)``: the rows' launch takes ``P`` pages of
+    ``page_bytes`` a step where its table admits them — the one constant
+    ``pages_per_step`` reads, moved; the launch's traces are keyed by shapes,
+    so they are dropped around the move."""
+    def move(P, page_bytes):
+        monkeypatch.setattr(mk, "ROWS_STEP_BYTES", P * page_bytes)
+        mk.ragged_paged_attention.clear_cache()
+
+    yield move
+    mk.ragged_paged_attention.clear_cache()
+
+
+def _work_reference(prefixes, live, W, bs, P=1):
+    """``build_work`` in plain Python. At ``P`` 1 it is the list before PR
+    47: an item ``row << 16 | slot`` a page under a live row's prefix."""
+    items = []
+    for r, (prefix, alive) in enumerate(zip(prefixes, live)):
+        if alive:
+            pages = max(min(-(-int(prefix) // bs), W), 1)
+            items += [r << 16 | slot for slot in range(0, pages, P)]
+    listed = np.zeros(mk.work_len(len(prefixes), W, P), np.int32)
+    listed[0] = max(len(items), 1)
+    listed[1 : 1 + len(items)] = items
+    return listed
+
+
+def _check_listed_rows(prefixes, live, window, quant, W, P, seed, walk=True):
+    """The rows' launch over a table ``W`` pages of 16 wide, at ``P`` pages a
+    step, against ``_dense_reference`` and (``walk``) the walk of every group
+    of every bucket row; returns the list."""
     from dynamo_tpu.engine.kv_cache import quantize_kv_rows
     from tools.attn_chunk_bench import walk_work
 
-    prefixes, live, window, quant = ROWS[case]
-    B, W, bs, kvh, G, hd = len(prefixes), 6, 16, 2, 2, 16
-    rng = np.random.default_rng(len(case))
+    B, bs, kvh, G, hd = len(prefixes), 16, 2, 2, 16
+    rng = np.random.default_rng(seed)
     q = jnp.asarray(rng.standard_normal((B, kvh * G, hd)).astype(np.float32))
     ke = jnp.asarray(rng.standard_normal((B * (window + 1), kvh, hd)).astype(np.float32))
     ve = jnp.asarray(rng.standard_normal((B * (window + 1), kvh, hd)).astype(np.float32))
@@ -336,6 +359,7 @@ def test_listed_rows_match_the_dense_reference_and_the_walk_of_every_slot(case):
         k_pages = quantize_kv_rows(k_pages.reshape(n_pages, bs, kvh, hd))
         v_pages = quantize_kv_rows(v_pages.reshape(n_pages, bs, kvh, hd))
         k_ref, v_ref = (p.q.astype(jnp.float32) * jnp.repeat(p.scale, hd, axis=-1) for p in (k_pages, v_pages))
+    assert mk.pages_per_step(bs, kvh * hd, 1 if quant else 4, W) == P
     # A row's pages, in an order of their own; slots past them hold the scratch page, as the scheduler's tables do.
     held = -(-np.asarray(prefixes) // bs)
     tables = np.zeros((B, W), np.int32)
@@ -348,19 +372,114 @@ def test_listed_rows_match_the_dense_reference_and_the_walk_of_every_slot(case):
     for step in range(window + 1):
         meta = mk.build_meta(i, jnp.asarray(prefixes, jnp.int32), first, first + 1 + step, jnp.asarray(live, jnp.int32))
         if work is None:
-            work = mk.build_work(meta[1], meta[4] > 0, W, bs)
-            assert int(work[0]) == max(sum(max(h, 1) for h, l in zip(held, live) if l), 1) and work.shape == (1 + B * (W + 1),)
+            work = mk.build_work(meta[1], meta[4] > 0, W, bs, P)
+            assert int(work[0]) == max(sum(-(-max(h, 1) // P) for h, l in zip(held, live) if l), 1)
+            assert work.shape == (1 + B * (-(-W // P) + 1),)
         kw = dict(num_kv_heads=kvh, block_size=bs, interpret=True)
         got = np.asarray(mk.ragged_paged_attention(q, ke, ve, k_pages, v_pages, tables, meta, work, **kw))
         want = _dense_reference(q, ke, ve, k_ref, v_ref, tables, meta, kvh)
         np.testing.assert_allclose(got, want, atol=2e-5, err_msg=f"step {step}")
         dead = np.asarray(live) == 0
         assert np.all(got[dead] == 0.0), "dead rows must return zeros"
-        every_row = mk.build_meta(*meta[:4], jnp.ones((B,), jnp.int32))
-        walked = np.asarray(mk.ragged_paged_attention(q, ke, ve, k_pages, v_pages, tables, every_row, walk_work(B, W), **kw))
-        assert np.array_equal(got[~dead], walked[~dead]), f"step {step}: the same pages in the same order, the same bits"
-        built_here = np.asarray(mk.ragged_paged_attention(q, ke, ve, k_pages, v_pages, tables, meta, **kw))
-        assert np.array_equal(got, built_here)
+        if walk:
+            every_row = mk.build_meta(*meta[:4], jnp.ones((B,), jnp.int32))
+            walked = np.asarray(mk.ragged_paged_attention(q, ke, ve, k_pages, v_pages, tables, every_row, walk_work(B, W, P), **kw))
+            assert np.array_equal(got[~dead], walked[~dead]), f"step {step}: the same pages in the same order, the same bits"
+            built_here = np.asarray(mk.ragged_paged_attention(q, ke, ve, k_pages, v_pages, tables, meta, **kw))
+            assert np.array_equal(got, built_here)
+    return np.asarray(work)
+
+
+@pytest.mark.parametrize("pages_per_step", [1, 2, 4])
+@pytest.mark.parametrize("case", list(ROWS))
+def test_listed_rows_match_the_dense_reference_and_the_walk_of_every_slot(case, pages_per_step, step_pages):
+    """A batch of length-1 rows, its launch one step a group of
+    ``pages_per_step`` live pages, the last of a row's closing it
+    (``build_work``), gives what plain softmax attention gives, and on its
+    live rows, to the bit, what the walk of every group of every bucket row
+    gives (at one page a step the static grid before PR 38, every bucket row
+    marked live as its callers did); dead rows return exact zeros. A window's
+    steps (a row's fresh keys ``[start, start + 1 + step)``) share one list."""
+    prefixes, live, window, quant = ROWS[case]
+    step_pages(pages_per_step, 16 * 32 * (1 if quant else 4))
+    _check_listed_rows(prefixes, live, window, quant, 6, pages_per_step, seed=len(case))
+
+
+# Ragged prefixes at every edge of a group of P pages of 16, by the table's width W: no prefix, one token, a
+# page less a token, exactly P pages, P pages and a token, the table's full width less a token and in full,
+# dead rows (one that holds pages) between live ones.
+def _group_edges(P, W):
+    edges = [0, 1, 15, 16 * min(P, W), min(16 * P + 1, 16 * W), 0, 16 * W - 1, 40, 16 * W]
+    return edges, [1, 1, 1, 1, 1, 0, 1, 0, 1]
+
+
+@pytest.mark.parametrize("quant", [False, True], ids=["f32-pool", "int8-pool"])
+@pytest.mark.parametrize("W", [3, 6, 9], ids=["table-3", "table-6", "table-9"])
+@pytest.mark.parametrize("pages_per_step", [1, 2, 4])
+def test_a_step_of_several_pages_at_every_edge_of_a_group(pages_per_step, W, quant, step_pages):
+    """The rows' launch at 1, 2 and 4 pages a step over tables narrower than a
+    group (3 slots under 4 pages: the step takes 2), not a multiple of it and
+    wider, a window's two steps sharing one list, over float and int8 pages:
+    plain softmax attention on every live row, zeros on the dead, and the list
+    ``build_work`` is specified to build, element for element."""
+    step_pages(pages_per_step, 16 * 32 * (1 if quant else 4))
+    P = min(pages_per_step, 2 if W == 3 else W)
+    prefixes, live = _group_edges(P, W)
+    work = _check_listed_rows(prefixes, live, 1, quant, W, P, seed=P * W, walk=False)
+    assert np.array_equal(work, _work_reference(prefixes, live, W, 16, P))
+
+
+@pytest.mark.parametrize("pages_per_step", [1, 2, 4])
+@pytest.mark.parametrize("case", list(ROWS))
+def test_build_work_lists_a_group_of_pages_an_item(case, pages_per_step):
+    """``build_work``: at one page a step the list before PR 47, element for
+    element; at ``P`` an item every ``P`` slots of a live row's pages, the
+    count ``sum ceil(max(pages, 1) / P)`` over live rows (1 with none)."""
+    prefixes, live, _, _ = ROWS[case]
+    W, bs = 6, 16
+    args = (jnp.asarray(prefixes, jnp.int32), jnp.asarray(live, jnp.int32) > 0, W, bs)
+    got = np.asarray(mk.build_work(*args, pages_per_step))
+    assert np.array_equal(got, _work_reference(prefixes, live, W, bs, pages_per_step))
+    held = [max(min(-(-p // bs), W), 1) for p, l in zip(prefixes, live) if l]
+    assert got[0] == max(sum(-(-h // pages_per_step) for h in held), 1)
+    if pages_per_step == 1:
+        assert np.array_equal(got, np.asarray(mk.build_work(*args))) and got.shape == (1 + len(prefixes) * (W + 1),)
+
+
+@pytest.mark.parametrize(
+    "block_size,kv_lanes,kv_bytes,num_slots,want",
+    [(128, 256, 2, 24, 4), (128, 1024, 2, 16, 1), (128, 4096, 2, 16, 1), (128, 256, 2, 3, 2), (128, 256, 2, 1, 1),
+     (128, 256, 1, 24, 8), (128, 1024, 1, 16, 2), (128, 128, 2, 64, 8), (16, 512, 2, 64, 8), (16, 32, 4, 6, 4),
+     (128, 256, 2, 4, 4), (128, 384, 2, 24, 2)],
+    ids=["zaya", "llama-cells", "evabyte", "table-of-3", "table-of-1", "zaya-int8", "llama-int8", "one-head-of-128",
+         "1b-pages-of-16", "tiny", "table-of-4", "three-heads"],
+)
+def test_pages_per_step_follows_the_pages_bytes(block_size, kv_lanes, kv_bytes, num_slots, want):
+    """Pages whose bytes a side stay within a llama cell's one page (256 KB),
+    a power of two, never more than the table holds or than the tool read."""
+    got = mk.pages_per_step(block_size, kv_lanes, kv_bytes, num_slots)
+    assert got == want and got <= num_slots and got & (got - 1) == 0
+    assert got == 1 or got * block_size * kv_lanes * kv_bytes <= mk.ROWS_STEP_BYTES
+    assert mk.work_len(5, num_slots, got) == 1 + 5 * (-(-num_slots // got) + 1)
+
+
+def test_a_list_built_at_another_pages_per_step_fails_the_launchs_trace(step_pages):
+    """The list and the launch read ``P`` off the same shapes through one
+    function; a list built at another ``P`` has another length, and the launch
+    refuses it while it traces."""
+    B, W, bs, kvh, hd = 4, 6, 16, 2, 16
+    step_pages(4, bs * kvh * hd * 4)
+    i = jnp.arange(B, dtype=jnp.int32)
+    meta = mk.build_meta(i, jnp.full((B,), 40, jnp.int32), i, i + 1, jnp.ones((B,), jnp.int32))
+    z = jnp.zeros
+    args = (z((B, kvh * 2, hd)), z((B, kvh, hd)), z((B, kvh, hd)), z((1 + B * W, bs, kvh * hd)), z((1 + B * W, bs, kvh * hd)),
+            z((B, W), jnp.int32), meta)
+    kw = dict(num_kv_heads=kvh, block_size=bs, interpret=True)
+    assert {mk.work_len(B, W, P) for P in (1, 2, 4)} == {29, 17, 13}
+    for P in (1, 2):
+        with pytest.raises(AssertionError, match="work list"):
+            mk.ragged_paged_attention(*args, mk.build_work(meta[1], meta[4] > 0, W, bs, P), **kw)
+    mk.ragged_paged_attention(*args, mk.build_work(meta[1], meta[4] > 0, W, bs, 4), **kw)
 
 
 @pytest.mark.parametrize("program", ["decode", "decode_multi"])

@@ -179,9 +179,11 @@ def test_a_chunk_carrying_step_names_the_chunks_attention_path(impl, path):
 def test_a_step_with_decode_rows_counts_its_attention_items_beside_its_slots(path, monkeypatch):
     """``attn_items`` on a ``sched.step`` entry is the count the rows' launch
     reads off the dispatch's own operands (``megakernel.build_work`` on the
-    packed rows and the uploaded tables' width): a step a page under a live
-    row's current token; ``attn_slots`` is the bucket x (table width + 1) the
-    static grid spanned. ``debug_state`` sums both."""
+    packed rows and the uploaded tables' width, at the pages a step the
+    engine's launch takes): a step a group of pages under a live row's current
+    token; ``attn_pages`` counts those pages (the list's count at a page a
+    step); ``attn_slots`` is the bucket x (table width + 1) the static grid
+    spanned. ``debug_state`` sums all three."""
     from dynamo_tpu.engine.attention import megakernel as mk
 
     settings, first, late, kind = PATHS[path]
@@ -205,17 +207,44 @@ def test_a_step_with_decode_rows_counts_its_attention_items_beside_its_slots(pat
     assert len(operands) == len(steps)
     for attrs, (rows, width) in zip(steps, operands):
         bucket = rows.shape[1]
-        work = mk.build_work(jnp.minimum(rows[1], width * bs), rows[2] > 0, width, bs)
-        assert attrs["attn_items"] == int(work[0]) and attrs["attn_slots"] == bucket * (width + 1) == work.shape[0] - 1
-        assert attrs["rows"] <= attrs["attn_items"] <= attrs["attn_slots"]
+        per_step = llama.rows_pages_per_step(CFG, sched.cache.k, width)
+        assert per_step == mk.pages_per_step(bs, CFG.num_kv_heads * CFG.head_dim, 4, width) > 1  # pages of 2 KB
+        prefixes, live = jnp.minimum(rows[1], width * bs), rows[2] > 0
+        work, a_page_a_step = mk.build_work(prefixes, live, width, bs, per_step), mk.build_work(prefixes, live, width, bs)
+        assert attrs["attn_items"] == int(work[0]) and attrs["attn_pages"] == int(a_page_a_step[0])
+        assert attrs["attn_slots"] == bucket * (width + 1) == a_page_a_step.shape[0] - 1
+        assert attrs["rows"] <= attrs["attn_items"] <= attrs["attn_pages"] <= attrs["attn_slots"]
     assert any(a["attn_items"] < a["attn_slots"] // 2 for a in steps)  # two rows in a bucket of 4: most of the span is dead
+    assert any(a["attn_items"] < a["attn_pages"] for a in steps)  # a row of 3 pages is 2 steps at 2 pages a step, 1 at 4
     state = sched.debug_state()
     assert state["attn_items_total"] == sum(a["attn_items"] for a in steps)
+    assert state["attn_pages_total"] == sum(a["attn_pages"] for a in steps)
     assert state["attn_slots_total"] == sum(a["attn_slots"] for a in steps)
     gather = mk_sched()
     add(gather, "a", list(range(1, 20)), 4)
     drain(gather)
     assert gather.debug_state()["attn_items_total"] == 0  # the gather path launches no such walk
+
+
+@pytest.mark.parametrize("kv_heads,carried", [(8, 1.0), (2, 3.5)], ids=["1024-lanes", "256-lanes"])
+def test_pages_a_step_of_the_rows_launch_follow_the_pages_lanes(kv_heads, carried):
+    """``attn_pages / attn_items`` is the pages a step of the rows' launch
+    carried: 1.0 where a page of 64 float32 tokens is 1,024 lanes wide (256
+    KB a side: ``pages_per_step`` 1), 3.5 over 256 lanes (64 KB: 4 pages a
+    step; rows of 3 and 4 pages are a step each)."""
+    cfg = CFG.replace(num_heads=kv_heads, num_kv_heads=kv_heads, head_dim=128, block_size=64, attention_impl="megakernel")
+    params = llama.init_params(cfg, jax.random.PRNGKey(1), dtype=jnp.float32)
+    sched = Scheduler(cfg, params, SchedulerConfig(num_blocks=16, max_running=4, prefill_buckets=[256], decode_buckets=[2],
+                                                   num_scheduler_steps=1, enable_prefix_caching=False), dtype=jnp.float32)
+    add(sched, "a", [1 + i % 200 for i in range(150)], 3)
+    add(sched, "b", [2 + i % 200 for i in range(240)], 3)
+    drain(sched)
+    steps = [e[4] for e in sched.flight.log.spans if e[0] == "sched.step" and e[4] and e[4].get("kind") == "decode"]
+    both = [a for a in steps if a["rows"] == 2]
+    assert both and all(a["attn_pages"] == 7 and a["attn_pages"] / a["attn_items"] == carried for a in both)
+    state = sched.debug_state()
+    assert state["attn_pages_total"] == sum(a["attn_pages"] for a in steps if "attn_pages" in a) >= 7
+    assert (state["attn_pages_total"] == state["attn_items_total"]) == (carried == 1.0)
 
 
 def test_host_gap_is_read_from_the_logs_launch_stamps():

@@ -102,6 +102,7 @@ def _one_chip_args(one_chip):
 WIDTHS = {  # H, KVH, HD, BS
     "1b": (H, KVH, HD, BS),
     "cell": (32, 8, 128, 128),  # the benchmark's llama cells: Mistral-7B's and Mixtral's heads, pages of 128
+    "zaya": (8, 2, 128, 128),  # ZAYA1's compressed latent: pages of 128 tokens by 256 lanes, 4 of them a rows step
 }
 
 
@@ -113,10 +114,12 @@ WIDTHS = {  # H, KVH, HD, BS
      # the 32 decode rows a query a grid row (33 rows x 16 slots, 288 queries in one launch before PR 31).
      ("cell", 256, 256, 1, 16, False, True), ("cell", 256, 256, 1, 16, True, True),
      ("cell", 32, 32, 32, 16, False, False), ("cell", 32, 32, 32, 16, True, False),
-     ("cell", 288, 288, 33, 16, False, False), ("cell", 2048, 2048, 1, 16, False, True)],
+     ("cell", 288, 288, 33, 16, False, False), ("cell", 2048, 2048, 1, 16, False, True),
+     # zaya1-8b-d20.reason's rows: a bucket of 64 under its widest table, a window's 9 fresh keys a row.
+     ("zaya", 64, 64, 64, 24, False, False), ("zaya", 64, 576, 64, 24, False, False), ("zaya", 64, 64, 64, 24, True, False)],
     ids=["decode-b8", "mixed-chunk512+b8", "decode-b8-int8kv", "chunk512-tiles",
          "cell-chunk256-tiles", "cell-chunk256-tiles-int8kv", "cell-rows32", "cell-rows32-int8kv",
-         "cell-walk288", "cell-chunk2048-tiles"],
+         "cell-walk288", "cell-chunk2048-tiles", "zaya-rows64", "zaya-rows64-window8", "zaya-rows64-int8kv"],
 )
 def test_ragged_paged_attention_compiles(one_chip, widths, nq, ck, rows, width, quant, chunk):
     """The kernel at the widths and shapes the programs launch it with: a VMEM
@@ -136,6 +139,12 @@ def test_ragged_paged_attention_compiles(one_chip, widths, nq, ck, rows, width, 
     ).compile()
     assert "tpu_custom_call" in compiled.as_text()
     assert (tile > 1) == chunk
+    if not chunk and nq == rows:
+        # Length-1 rows: pages a step by the page's bytes, 2 P page operands of the launch (4 P over int8 pages).
+        from dynamo_tpu.engine.attention.megakernel import pages_per_step
+
+        p = pages_per_step(bs, kvh * hd, 1 if quant else 2, width)
+        assert p == {"1b": 8, "cell": 2 if quant else 1, "zaya": 8 if quant else 4}[widths]
 
 
 @pytest.mark.parametrize("T", [512, 2048])

@@ -29,12 +29,15 @@ brings: the carry rows of a ``decode_multi`` window, 0 for a single step), at
 the first of ``--lanes``, and reads
 
 - ``rows``:        the launch as the step programs make it: padded rows dead,
-                   the grid the list of live pages (``megakernel.build_work``).
-- ``rows_walk``:   the same kernel handed every slot of every bucket row, the
-                   padded rows live with no prefix: the ``bucket x (width + 1)``
-                   steps the static grid took before PR 38.
+                   the grid the list of live groups of pages
+                   (``megakernel.build_work``), ``megakernel.pages_per_step``
+                   pages a step or each of ``--pages-per-step`` (PERF.md §6,
+                   PR 47; ``--heads 8 --lanes 256`` is ZAYA1's latent).
+- ``rows_walk``:   the same kernel handed every group of every bucket row, the
+                   padded rows live with no prefix: at a page a step the
+                   ``bucket x (width + 1)`` steps the static grid took before PR 38.
 - ``rows_parent``: with ``--parent DIR`` (a checkout of another commit), that
-                   commit's kernel on ``rows_walk``'s inputs.
+                   commit's kernel on ``rows_walk``'s inputs at a page a step.
 
     chiprun -- python tools/attn_chunk_bench.py
     chiprun -- python tools/attn_chunk_bench.py --study rows [--parent .parent]
@@ -122,17 +125,18 @@ def build(form, tile, *, S, B, W, N, L, KVH, BS, prefix, d_prefix, interpret):
     return fn, lo, hi
 
 
-def walk_work(bucket, width):
-    """``build_work``'s list with every slot of every bucket row in it and one
-    more a row: the steps of the static grid ``(bucket, width + 1)``, a slot
-    past a row's prefix one that fetches the table's entry and computes
-    nothing."""
+def walk_work(bucket, width, pages_per_step=1):
+    """``build_work``'s list with every group of slots of every bucket row in
+    it and one more a row: at one page a step the steps of the static grid
+    ``(bucket, width + 1)``, a slot past a row's prefix one that fetches the
+    table's entry and computes nothing."""
     row = jnp.arange(bucket, dtype=jnp.int32)[:, None] << mk._ROW_SHIFT
-    items = (row | jnp.arange(width + 1, dtype=jnp.int32)[None]).reshape(-1)
+    groups = -(-width // pages_per_step) + 1
+    items = (row | jnp.arange(groups, dtype=jnp.int32)[None] * pages_per_step).reshape(-1)
     return jnp.concatenate([jnp.full((1,), items.shape[0], jnp.int32), items])
 
 
-def build_rows(form, kernel, *, live, pages, B, W, window, N, L, KVH, BS, interpret):
+def build_rows(form, kernel, *, live, pages, B, W, window, N, L, KVH, BS, interpret, P=1):
     """``fn(q [B, H, HD], k, v [B*(window+1), KVH, HD], k_pool, v_pool)`` -> the
     last layer's output: ``live`` of ``B`` rows hold ``pages`` full pages less
     half of the last in a table ``W`` wide, each with its ``window + 1`` fresh
@@ -145,10 +149,11 @@ def build_rows(form, kernel, *, live, pages, B, W, window, N, L, KVH, BS, interp
     prefix = jnp.where(is_live, pages * BS - BS // 2, 0)
     if form == "rows":
         meta = mk.build_meta(iq, prefix, first, first + window + 1, is_live)
-        work = (mk.build_work(prefix, is_live, W, BS),)
+        work = (mk.build_work(prefix, is_live, W, BS, P),)
     else:
         meta = mk.build_meta(iq, prefix, first, first + window + 1, jnp.ones((B,), i32))
-        work = (walk_work(B, W),) if form == "rows_walk" else ()
+        # The parent takes the list of its own kernel: a page an item.
+        work = (walk_work(B, W, P if form == "rows_walk" else 1),)
 
     def fn(q, k, v, kp, vp):
         def body(q32, l):
@@ -175,6 +180,8 @@ def rows_study(a, say, *, lanes, N, L, H, HD, BS, dtype, on_tpu):
     keys = jax.random.split(jax.random.PRNGKey(lanes), 5)
     kp = jax.random.normal(keys[3], (L * N, BS, lanes), dtype)
     vp = jax.random.normal(keys[4], (L * N, BS, lanes), dtype)
+    page_bytes = BS * lanes * jnp.dtype(dtype).itemsize
+    step_bytes, step_pages = mk.ROWS_STEP_BYTES, mk.ROWS_STEP_PAGES
     for shape in a.rows:
         live, pages, B, W = (int(x) for x in shape.split(":"))
         for window in a.windows:
@@ -182,15 +189,30 @@ def rows_study(a, say, *, lanes, N, L, H, HD, BS, dtype, on_tpu):
             k = jax.random.normal(keys[1], (B * (window + 1), KVH, HD), dtype)
             v = jax.random.normal(keys[2], (B * (window + 1), KVH, HD), dtype)
             ref = None
-            for form, kernel in kernels.items():
-                fn = build_rows(form, kernel, live=live, pages=pages, B=B, W=W, window=window, N=N, L=L, KVH=KVH, BS=BS,
-                                interpret=not on_tpu)
-                us, out = measure(fn, (q, k, v, kp, vp), a.iters)
-                out = jnp.asarray(out, jnp.float32)[:live]
-                ref = out if ref is None else ref
-                say(lanes=lanes, shape=shape, window=window, form=form, us_per_layer=us / L,
-                    kv_mb=live * pages * BS * lanes * 2 * jnp.dtype(dtype).itemsize / 1e6,
-                    max_rel_diff_vs_rows=float(jnp.max(jnp.abs(ref - out)) / (jnp.max(jnp.abs(ref)) + 1e-9)))
+            # Pages a step: the program's own, or each of --pages-per-step (the
+            # constants pages_per_step reads, moved for the reading).
+            wants = a.pages_per_step or [None]
+            for want in wants:
+                if want:
+                    mk.ROWS_STEP_BYTES, mk.ROWS_STEP_PAGES = want * page_bytes, max(step_pages, want)
+                    mk.ragged_paged_attention.clear_cache()
+                P = mk.pages_per_step(BS, lanes, jnp.dtype(dtype).itemsize, W)
+                for form, kernel in kernels.items():
+                    if form == "rows_parent" and want != wants[0]:
+                        continue  # the parent's kernel reads no constant of this one: once a shape
+                    at = dict(lanes=lanes, heads=H, shape=shape, window=window, form=form, pages_per_step=1 if form == "rows_parent" else P)
+                    try:
+                        fn = build_rows(form, kernel, live=live, pages=pages, B=B, W=W, window=window, N=N, L=L, KVH=KVH,
+                                        BS=BS, P=P, interpret=not on_tpu)
+                        us, out = measure(fn, (q, k, v, kp, vp), a.iters)
+                    except Exception as e:  # a step the compiler refuses is a reading too
+                        say(**at, error=f"{type(e).__name__}: {str(e)[:300]}")
+                        continue
+                    out = jnp.asarray(out, jnp.float32)[:live]
+                    ref = out if ref is None else ref
+                    say(**at, us_per_layer=us / L, kv_mb=live * pages * page_bytes * 2 / 1e6,
+                        max_rel_diff_vs_rows=float(jnp.max(jnp.abs(ref - out)) / (jnp.max(jnp.abs(ref)) + 1e-9)))
+    mk.ROWS_STEP_BYTES, mk.ROWS_STEP_PAGES = step_bytes, step_pages
 
 
 def measure(fn, args, iters):
@@ -218,14 +240,18 @@ def main():
                     help="live:pages:bucket:width")
     ap.add_argument("--windows", type=int, nargs="*", default=[0, 8])
     ap.add_argument("--parent", help="a checkout of another commit, whose kernel is read beside this one's")
+    ap.add_argument("--heads", type=int, default=32, help="query heads (the rows study; ZAYA1's latent has 8 over 2)")
+    ap.add_argument("--pages-per-step", type=int, nargs="*", default=[],
+                    help="the rows study at each of these pages a grid step, not at megakernel.pages_per_step's own")
     a = ap.parse_args()
     on_tpu = jax.devices()[0].platform == "tpu"
     if a.tiny:
         S, B, W, N, L, H, HD, BS, dtype = 32, 4, 4, 16, 2, 4, 16, 16, jnp.float32
         a.lanes, a.prefix, a.tiles, a.fold_tiles, a.iters = [32, 64], [0, 24], [16, 32], [16], 1
-        a.rows, a.windows = ["2:2:4:4", "4:3:4:4"], [0, 2]
+        a.rows, a.windows, a.pages_per_step = ["2:2:4:4", "4:3:4:4"], [0, 2], [1, 2]
     else:
         S, B, W, N, L, H, HD, BS, dtype = 256, 32, 16, 64, a.layers, 32, 128, 128, jnp.bfloat16
+        H = a.heads if a.study == "rows" else H
     os.makedirs("chiprun_out", exist_ok=True)
     log = open("chiprun_out/attn_chunk_bench.jsonl", "a")
 
