@@ -12,6 +12,18 @@ built (``TpuEngine.build``) and by scripts that jit before that:
 
 ``tests/conftest.py`` keeps the cache off for tests.
 
+The program store (``engine/program_store.py``) lives under the same rule, in
+``programs/`` INSIDE that directory (``program_store_dir``): JAX's eviction
+counts and deletes ``*-cache`` files at the directory's top level alone, so it
+neither counts nor deletes the store, and a machine that keeps the cache from
+one run to the next keeps the store with it. Where the persistent cache is off
+the store is off. The cache holds executables by the hash of their HLO, which
+a process knows only after it has traced and lowered the program in Python;
+the store holds the lowered programs themselves (``jax.export``) by a digest
+of what the tracing would read, so a warm set-up traces and lowers nothing it
+has lowered before. An entry of the build log says whether its program came
+from the store (``store``: ``hit``, ``miss`` or none).
+
 The build log. ``jax.monitoring`` reports every trace
 (``jaxpr_trace_duration``), every lowering (``jaxpr_to_mlir_module_duration``)
 and every compile or load from the persistent cache
@@ -134,6 +146,9 @@ class BuildEntry(NamedTuple):
     cache: Optional[str]  # "hit" | "miss" | None (the persistent cache is off, or kept no entry)
     nested_traces: int
     in_one_chunk: bool = False  # built below ``in_one_chunk``'s frame (a set-up), not on a serving thread's own stack
+    # "hit": built from a module the program store held; "miss": traced, lowered, exported and written first (those
+    # seconds are in trace_s and lower_s); None: today's path (no store, a mesh, an export that failed, an eager executable)
+    store: Optional[str] = None
 
     @property
     def seconds(self) -> float:
@@ -143,7 +158,7 @@ class BuildEntry(NamedTuple):
         return {"kind": self.kind, "key": None if self.key is None else str(self.key), "fun_name": self.fun_name,
                 "phase": self.phase, "trace_s": round(self.trace_s, 4), "lower_s": round(self.lower_s, 4),
                 "backend_s": round(self.backend_s, 4), "cache": self.cache, "nested_traces": self.nested_traces,
-                "in_one_chunk": self.in_one_chunk}
+                "in_one_chunk": self.in_one_chunk, "store": self.store}
 
 
 class _Thread(threading.local):
@@ -153,6 +168,8 @@ class _Thread(threading.local):
         self.traces: "deque[tuple]" = deque(maxlen=BUILD_LOG_SIZE)  # (arrival ns, fun_name, seconds)
         self.lower: Optional[tuple] = None  # (fun_name, seconds)
         self.cache: Optional[str] = None
+        self.store: Optional[str] = None  # where the program about to be built came from (``BuildLog.stored``)
+        self.exported = (0.0, 0.0, 0)  # trace_s, lower_s, nested traces of the export a store miss made (``BuildLog.exported``)
         self.scopes: list = []  # open StepSpans, outermost first
         self.launch: Optional[tuple] = None  # (the newest sched.launch span, the key record_exec was handed)
 
@@ -186,12 +203,14 @@ class BuildLog:
         if cache is not None:
             self._thread.cache = cache
 
-    def _close(self, fun_name: str, backend_s: float) -> None:
-        now = time.monotonic_ns()
+    def _take_pending(self, fun_name: str) -> tuple:
+        """(trace_s, lower_s, nested traces) of what this thread's events have
+        said of ``fun_name`` ("jit(mixed_step)") since they were last taken,
+        and forget them. "jit(mixed_step)" was traced as "mixed_step". Traces
+        that arrive after the outer one ended belong to the lowering (a scan's
+        condition)."""
         mine = self._thread
         lower_s = mine.lower[1] if mine.lower is not None and mine.lower[0] == fun_name else 0.0
-        # "jit(mixed_step)" was traced as "mixed_step". Traces that arrive after
-        # the outer one ended belong to the lowering (a scan's condition).
         traced_as = fun_name[fun_name.find("(") + 1:-1] if fun_name.endswith(")") else fun_name
         trace_s, nested = 0.0, 0
         for t, name, secs in reversed(mine.traces):
@@ -199,9 +218,14 @@ class BuildLog:
                 began = t - int(secs * 1e9)
                 trace_s, nested = secs, sum(1 for other in mine.traces if other[0] >= began) - 1
                 break
-        cache = mine.cache
         mine.traces.clear()
-        mine.lower = mine.cache = None
+        mine.lower = None
+        return trace_s, lower_s, nested
+
+    def where(self) -> tuple:
+        """(kind, key, phase) of what this thread builds now: the innermost
+        ``build.key`` scope, else the open ``sched.launch``, else eager."""
+        mine = self._thread
         kind, key, phase = EAGER, None, None
         for span in reversed(mine.scopes):
             if span.name == KEY_SCOPE:
@@ -215,12 +239,36 @@ class BuildLog:
             key = tuple(last[1:]) if last and last[0] == kind else ()
         if phase is None and self.serving:
             phase = SERVING
+        return kind, key, phase
+
+    def _close(self, fun_name: str, backend_s: float) -> None:
+        now = time.monotonic_ns()
+        mine = self._thread
+        trace_s, lower_s, nested = self._take_pending(fun_name)
+        trace_s, lower_s, nested = trace_s + mine.exported[0], lower_s + mine.exported[1], nested + mine.exported[2]
+        cache, store = mine.cache, mine.store
+        mine.cache = mine.store = None
+        mine.exported = (0.0, 0.0, 0)
+        kind, key, phase = self.where()
         entry = BuildEntry(now, threading.get_ident(), fun_name, phase, kind, key, trace_s, lower_s, backend_s, cache, nested,
-                           _stack.anchored)
+                           _stack.anchored, store)
         with self._lock:
             self.entries.append(entry)
             self.total += 1
             self.total_ns += int(entry.seconds * 1e9)
+
+    # --- the program store (engine/program_store.py) ------------------------------
+    def stored(self, store: Optional[str]) -> None:
+        """The next executable this thread builds is of a program that came
+        from the store (``"hit"``), was written to it just now (``"miss"``), or
+        takes today's path (None)."""
+        self._thread.store = store
+
+    def exported(self, fun_name: str) -> None:
+        """A store miss has just traced and lowered ``fun_name`` to export it:
+        those seconds go to the entry that closes next on this thread, beside
+        what building from the exported module costs."""
+        self._thread.exported = self._take_pending(fun_name)
 
     # --- scopes -----------------------------------------------------------------
     @contextmanager
@@ -285,6 +333,8 @@ class BuildLog:
             "cache_hits": sum(e.cache == "hit" for e in entries),
             "cache_misses": sum(e.cache == "miss" for e in entries),
             "in_one_chunk": sum(e.in_one_chunk for e in entries),
+            "store_hits": sum(e.store == "hit" for e in entries),
+            "store_misses": sum(e.store == "miss" for e in entries),
         }
         if build is not None:
             span_s = (build[3] - build[2]) / 1e9
@@ -324,3 +374,15 @@ def enable_compile_cache() -> str:
     if jax.config.jax_compilation_cache_dir != _DEFAULT_DIR:
         jax.config.update("jax_compilation_cache_dir", _DEFAULT_DIR)
     return _DEFAULT_DIR
+
+
+def program_store_dir() -> Optional[str]:
+    """Where the program store keeps its modules: ``programs/`` inside the
+    persistent cache's directory, or None where that cache is off or has no
+    directory yet (nothing called ``enable_compile_cache``)."""
+    import jax
+
+    cache_dir = jax.config.jax_compilation_cache_dir
+    if not (jax.config.jax_enable_compilation_cache and cache_dir):
+        return None
+    return os.path.join(cache_dir, "programs")

@@ -105,9 +105,10 @@ def _log_built(b: dict, warmup_ctx: int) -> None:
     """The start-up line: what ``debug_state()["build"]`` holds in full."""
     logger.info(
         "engine.build %.1f s (params %.1f, scheduler %.1f, warm-up at ctx %d %.1f): %d keys warmed, "
-        "%d executables (%d in one stack chunk, %d eager, %d from the cache) = trace %.1f + lower %.1f + backend %.1f + other %.1f s",
+        "%d executables (%d in one stack chunk, %d eager, %d from the cache, %d of their programs from the store and %d written to it)"
+        " = trace %.1f + lower %.1f + backend %.1f + other %.1f s",
         b["engine_build"]["span_s"], *(b["phase_s"].get(p, 0.0) for p in ("build.params", "build.scheduler")), warmup_ctx,
-        b["phase_s"].get("build.warmup", 0.0), b["keys"], b["executables"], b["in_one_chunk"], b["eager"], b["cache_hits"],
+        b["phase_s"].get("build.warmup", 0.0), b["keys"], b["executables"], b["in_one_chunk"], b["eager"], b["cache_hits"], b["store_hits"], b["store_misses"],
         *(b["engine_build"][p] for p in ("trace_s", "lower_s", "backend_s", "other_s")),
     )
 
@@ -180,7 +181,10 @@ class TpuEngine:
         or making them, quantizing them), ``build.scheduler`` (the pool, the
         slots, the ``jax.jit`` objects) and ``build.warmup``
         (``Scheduler.warmup``); every executable JAX builds inside is an entry
-        of the build log (engine/compile_cache.py) under its phase and key.
+        of the build log (engine/compile_cache.py) under its phase and key, and
+        says whether its program came from the program store
+        (engine/program_store.py: where the persistent cache is on, a key's
+        lowered program is read from disk, not traced and lowered again).
         All of it runs below ``in_one_chunk``'s frame, this one call: what is
         traced and lowered here pays no system call at a stack chunk's edge,
         whatever stands above this function or inside it (PERF.md §6, PR 42)."""

@@ -30,6 +30,7 @@ from __future__ import annotations
 
 import asyncio
 import enum
+import os
 import threading
 import time
 from dataclasses import dataclass, field
@@ -46,6 +47,7 @@ from dynamo_tpu.engine.kv_cache import (
 )
 from dynamo_tpu.runtime.ledger import RequestBill, TenantLedger
 from dynamo_tpu.runtime.telemetry import SloConfig, SloJudge, Telemetry
+from dynamo_tpu.engine.program_store import StoredJit, open_store
 from dynamo_tpu.engine.sampling import SamplingParams, guided_sample_batch, make_row_keys, sample_batch
 from dynamo_tpu.llm.tokens import extend_block_hashes
 from dynamo_tpu.runtime.logging import get_logger
@@ -627,6 +629,14 @@ class Scheduler:
         self._aux_lock = threading.Lock()
         # llama-only kwargs (MLA's forward has its own signature).
         stats_kw = {"moe_stats": True} if self._moe_stats else {}
+        # The program store (engine/program_store.py), or None where the step
+        # programs take today's path (the persistent cache off, a mesh): what
+        # every program of this engine reads beside its arguments and its key.
+        self._store = open_store(
+            repr((self.mc, self.sc, jnp.dtype(dtype).name, self._attn_impl, self._use_flash_prefill, self._hp_static,
+                  os.environ.get("DYNAMO_TPU_HOIST_GATHER_MAX_BYTES"))),
+            mesh,
+        )
         # Every step program is a NAMED function: the profiler's "XLA
         # Modules" line then reads jit_<kind>(...) (the flight recorder's
         # kind, with the window rung where there is one), so a program's
@@ -648,7 +658,7 @@ class Scheduler:
             def prefill(p, k, v, buf, bt, hp):
                 return prefill_body(p, k, v, buf, bt, use_flash=True, has_prefix=hp)
 
-            self._prefill_jit = jax.jit(prefill, donate_argnums=(1, 2), static_argnums=(5,))
+            self._prefill_jit = self._jit(prefill, donate_argnums=(1, 2), static_argnums=(5,))
         else:
             # No ``hp`` here: the XLA path's masks and the megakernel's ragged
             # rows cover prefix and fresh prefills alike (a static argument
@@ -657,15 +667,16 @@ class Scheduler:
             def prefill(p, k, v, buf, bt):
                 return prefill_body(p, k, v, buf, bt)
 
-            self._prefill_jit = jax.jit(prefill, donate_argnums=(1, 2))
+            self._prefill_jit = self._jit(prefill, donate_argnums=(1, 2))
 
         def decode(p, k, v, buf, bt):  # buf: the rows' 3 x B lanes
             t, pos, act = _unpack_rows(buf.reshape(3, -1))
             res = model.decode(p, self.mc, k, v, t, pos, bt, act, **stats_kw)
             return (_greedy(res[0]),) + tuple(res)
 
-        self._decode_jit = jax.jit(decode, donate_argnums=(1, 2))
-        self._sample_jit = jax.jit(sample_batch)
+        self._decode_jit = self._jit(decode, donate_argnums=(1, 2))
+        self._sample_jit = self._jit(sample_batch)
+        self._row_keys_jit = self._jit(make_row_keys)
         # Logprobs folded into the sampling dispatch (one executable, one
         # readback) — the separate compute_logprobs op cost an extra device
         # round-trip per step for any batch with a logprobs row.
@@ -676,18 +687,18 @@ class Scheduler:
             sample_batch_top_logprobs,
         )
 
-        self._sample_lp_jit = jax.jit(sample_batch_logprobs)
+        self._sample_lp_jit = self._jit(sample_batch_logprobs)
         # The host path's first-token logprobs, warmed like the samplers
         # (nothing eager on the step thread).
         from dynamo_tpu.engine.sampling import compute_logprobs, compute_topk_logprobs
 
-        self._lp_jit = jax.jit(compute_logprobs)
-        self._tlp_jit = jax.jit(compute_topk_logprobs)
-        self._guided_sample_lp_jit = jax.jit(guided_sample_batch_logprobs)
+        self._lp_jit = self._jit(compute_logprobs)
+        self._tlp_jit = self._jit(compute_topk_logprobs)
+        self._guided_sample_lp_jit = self._jit(guided_sample_batch_logprobs)
         # Top-k variants (OpenAI top_logprobs): chosen logprob + the static
         # candidate cap's (ids, logprobs) in the same dispatch.
-        self._sample_tlp_jit = jax.jit(sample_batch_top_logprobs)
-        self._guided_sample_tlp_jit = jax.jit(guided_sample_batch_top_logprobs)
+        self._sample_tlp_jit = self._jit(sample_batch_top_logprobs)
+        self._guided_sample_tlp_jit = self._jit(guided_sample_batch_top_logprobs)
         # The last decode block-table upload (_decode_tables): tables cross
         # the wire only when a table actually changes.
         self._tables_cache: Optional[tuple] = None
@@ -696,13 +707,13 @@ class Scheduler:
         self.eva_rolls_total = 0
         self.eva_released_blocks_total = 0
         if self._hybrid:
-            self._open_slot_jit = jax.jit(model.open_slot, donate_argnums=(0, 1))
+            self._open_slot_jit = self._jit(model.open_slot, donate_argnums=(0, 1))
         if self._eva:
 
             def eva_roll(p, k, v, table, row0):
                 return model.eva_roll(p, self.mc, k, v, table, row0)
 
-            self._roll_jit = jax.jit(eva_roll, donate_argnums=(1, 2))
+            self._roll_jit = self._jit(eva_roll, donate_argnums=(1, 2))
             self._roll_blocks = model.eva_roll_blocks(model_config)
         # Step-phase spans (runtime/tracing.py): the iteration in progress
         # and its open plan phase, which crosses from step() into whichever
@@ -724,7 +735,7 @@ class Scheduler:
         def kv_block_copy(k, v, s, d):
             return _copy_block_arr(k, s, d), _copy_block_arr(v, s, d)
 
-        self._kv_copy_jit = jax.jit(kv_block_copy, donate_argnums=(0, 1))
+        self._kv_copy_jit = self._jit(kv_block_copy, donate_argnums=(0, 1))
         # Prefix-cache accounting: reuse is only "automatic" if it is
         # visible — cached_tokens flows request-level (StepOutput → usage)
         # and these totals flow through stats → aggregator → Grafana.
@@ -740,7 +751,7 @@ class Scheduler:
         # Guided decoding (attach_guided): grammar compiler + device mask
         # pool. One fused mask+sample executable serves every guided batch.
         self.guided = None
-        self._guided_sample_jit = jax.jit(guided_sample_batch)
+        self._guided_sample_jit = self._jit(guided_sample_batch)
         self.dtype = dtype
         self._mm_jit = None  # lazy: multimodal prefill variant
         # Speculative decoding (attach_draft): draft model + stats.
@@ -775,7 +786,7 @@ class Scheduler:
                     )
 
                 decode_multi.__name__ = f"decode_multi_w{steps}"
-                return jax.jit(decode_multi, donate_argnums=(1, 2))
+                return self._jit(decode_multi, donate_argnums=(1, 2))  # (the rung is in its name)
 
             self._window_rungs = sorted(
                 {w for w in (8, 16, self.sc.num_scheduler_steps) if w <= self.sc.num_scheduler_steps}
@@ -832,7 +843,7 @@ class Scheduler:
         def draft_prefill(p, k, v, t, vl, cl, bt):
             return model.prefill(p, dc, k, v, t, vl, cl, bt)
 
-        self._d_prefill_jit = jax.jit(draft_prefill, donate_argnums=(1, 2))
+        self._d_prefill_jit = self._jit(draft_prefill, donate_argnums=(1, 2), closure=dc)
 
         def spec_draft_chunk(p, k, v, t, pos, val, bt, te, tk, tp, key):
             # Draft catch-up chunk + FIRST proposal sampled from the row's
@@ -845,7 +856,7 @@ class Scheduler:
             tok = sample_batch(last, te, tk, tp, key)
             return tok.astype(jnp.int32), last, k, v
 
-        self._d_chunk_sample_jit = jax.jit(spec_draft_chunk, donate_argnums=(1, 2))
+        self._d_chunk_sample_jit = self._jit(spec_draft_chunk, donate_argnums=(1, 2), closure=dc)
         t_stats_kw = {"moe_stats": True} if self._moe_stats else {}
 
         def spec_target_chunk(p, k, v, t, pos, val, bt):
@@ -853,10 +864,10 @@ class Scheduler:
                 p, self.mc, k, v, t, pos, val, bt, all_logits=True, **t_stats_kw
             )
 
-        self._t_chunk_jit = jax.jit(spec_target_chunk, donate_argnums=(1, 2))
+        self._t_chunk_jit = self._jit(spec_target_chunk, donate_argnums=(1, 2))
         from dynamo_tpu.engine.spec_decode import spec_verify
 
-        self._spec_verify_jit = jax.jit(spec_verify)
+        self._spec_verify_jit = self._jit(spec_verify)
         if gamma > 1:
             # On-device window for proposals 2..γ: one dispatch + one sync
             # instead of γ-1 round-trips; samples with the rows' REAL
@@ -868,7 +879,7 @@ class Scheduler:
                 )
 
             spec_draft_multi.__name__ = f"spec_draft_multi_w{gamma - 1}"
-            self._d_multi_jit = jax.jit(spec_draft_multi, donate_argnums=(1, 2))
+            self._d_multi_jit = self._jit(spec_draft_multi, donate_argnums=(1, 2), closure=(dc, gamma))
 
     def attach_guided(self, tokenizer) -> None:
         """Enable grammar-constrained decoding: grammars lift to token FSMs
@@ -1394,6 +1405,15 @@ class Scheduler:
         self.flight.builds.launching(span, self.flight.last_exec)
         return span
 
+    def _jit(self, fun: Callable, closure=(), **jit_kw):
+        """``jax.jit(fun, **jit_kw)``, its programs read from and written to
+        the program store where this engine has one. ``closure``: what ``fun``
+        closes over that neither its arguments nor the engine's configuration
+        name (a mixed step's widths, the draft's configuration)."""
+        if self._store is None:
+            return jax.jit(fun, **jit_kw)
+        return StoredJit(self._store, fun, closure=closure, **jit_kw)
+
     def _building(self, kind: Optional[str] = None, key: tuple = ()):
         """A ``build.key`` scope (engine/compile_cache.py): what JAX builds
         inside belongs to the executable key ``(kind, *key)``; with no
@@ -1620,13 +1640,13 @@ class Scheduler:
                 def mixed_step(p, k, v, buf, dtab, hp):
                     return mixed_body(p, k, v, buf, dtab, use_flash=True, has_prefix=hp)
 
-                self._mixed_jits[key] = jax.jit(mixed_step, donate_argnums=(1, 2), static_argnums=(5,))
+                self._mixed_jits[key] = self._jit(mixed_step, donate_argnums=(1, 2), static_argnums=(5,), closure=key)
             else:
 
                 def mixed_step(p, k, v, buf, dtab):
                     return mixed_body(p, k, v, buf, dtab)
 
-                self._mixed_jits[key] = jax.jit(mixed_step, donate_argnums=(1, 2))
+                self._mixed_jits[key] = self._jit(mixed_step, donate_argnums=(1, 2), closure=key)
         return self._mixed_jits[key]
 
     def _mixed_step(self, seq: Sequence, outputs: List[tuple]) -> bool:
@@ -1847,7 +1867,7 @@ class Scheduler:
                     p, self.mc, k, v, t, p0, vl, bt, last_logits=True, **stats_kw
                 )
 
-            self._admit_jits[key] = jax.jit(admit_wave, donate_argnums=(1, 2))
+            self._admit_jits[key] = self._jit(admit_wave, donate_argnums=(1, 2))
         return self._admit_jits[key]
 
     def _wave_eligible(self, seq: Sequence) -> bool:
@@ -2293,7 +2313,7 @@ class Scheduler:
             # ... and the keys of a batch that holds a seeded sampled row.
             args = (key, jnp.zeros((bucket,), jnp.int32), jnp.zeros((bucket,), jnp.int32), jnp.zeros((bucket,), bool))
             with self._building("sampler", ("row_keys", bucket)):
-                make_row_keys(*args)
+                self._row_keys_jit(*args)
             count += 4
         # The host path's first-token logprobs (its sampler at one row is warmed below).
         args = (jnp.zeros((1, self.mc.vocab_size), jnp.float32), jnp.zeros((1,), jnp.int32))
@@ -2693,7 +2713,7 @@ class Scheduler:
                         seeds[i] = seq.sampling.seed
                         poss_out[i] = len(seq.output_ids)
                         has_seed[i] = True
-                row_keys = make_row_keys(
+                row_keys = self._row_keys_jit(
                     key, self._up(seeds), self._up(poss_out), self._up(has_seed)
                 )
             temps, top_ks, top_ps = pack_param_rows([s.sampling for s in batch], bucket)
@@ -3205,7 +3225,7 @@ class Scheduler:
                     moe_stats=self._moe_stats,
                 )
 
-            self._mm_jit = jax.jit(prefill_mm, donate_argnums=(1, 2), static_argnums=(7,))
+            self._mm_jit = self._jit(prefill_mm, donate_argnums=(1, 2), static_argnums=(7,))
         return self._mm_jit
 
     def _prefill_table(self, seq: Sequence) -> np.ndarray:

@@ -247,10 +247,10 @@ EVA = get_config("tiny-eva").replace(
 EVA_BLOCKS = 256  # a pool of 1.07 GB: a copy of it, or of half of it, stands out among the temporaries
 
 
-def _llama_step_program(cfg, params, program, sh, blocks, S, Bd, Wd):
+def _llama_step_jit(cfg, params, program, sh, blocks, S, Bd, Wd):
     """``mixed_step`` (a chunk of ``S`` with a cached prefix beside ``Bd`` decode
-    rows) or ``decode_multi_w8`` of ``llama.py`` as the scheduler jits them,
-    compiled for the described chip."""
+    rows) or ``decode_multi_w8`` of ``llama.py`` as the scheduler jits them:
+    ``(jitted, its arguments as shapes on the described chip)``."""
     i32 = jnp.int32
     k = v = _sds((cfg.num_layers, blocks, cfg.block_size, cfg.kv_size), BF16, sh)
     if program == "mixed_step":
@@ -263,7 +263,13 @@ def _llama_step_program(cfg, params, program, sh, blocks, S, Bd, Wd):
             p, cfg, k, v, t, pos, bt, act, te, tk, tp, key, 8)
         args = (_sds((Bd,), i32, sh), _sds((Bd,), i32, sh), _sds((Bd, Wd), i32, sh), _sds((Bd,), jnp.bool_, sh),
                 _sds((Bd,), jnp.float32, sh), _sds((Bd,), i32, sh), _sds((Bd,), jnp.float32, sh), _sds((2,), jnp.uint32, sh))
-    return jax.jit(fn, donate_argnums=(1, 2)).lower(params, k, v, *args).compile()
+    return jax.jit(fn, donate_argnums=(1, 2)), (params, k, v, *args)
+
+
+def _llama_step_program(*a):
+    """``_llama_step_jit``'s program compiled for the described chip."""
+    jitted, args = _llama_step_jit(*a)
+    return jitted.lower(*args).compile()
 
 
 def _param_shapes(cfg, sh, int8=False):
@@ -410,8 +416,8 @@ def test_ssm_update_rows_compiles_in_place(one_chip, rows, tiles):
 
 
 @functools.cache  # as above
-def _granite_compiled(sh, program):
-    """``(compiled, params, k, v)``: a step program of the benchmark's
+def _granite_jit(sh, program):
+    """``(jitted, args, params, k, v)``: a step program of the benchmark's
     ``granite-4.0-h-small-d10-e36`` as configured (65 slots, 1,025 blocks, 64
     rows). ``check-``: as the output check calls it (``granite_hybrid.program_logits``:
     the same pool and slots, its bucket and table width, logits returned)."""
@@ -440,24 +446,34 @@ def _granite_compiled(sh, program):
     f32 = lambda *s: _sds(s, jnp.float32, sh)  # noqa: E731
     act = _sds((B,), jnp.bool_, sh)
     if program == "decode_multi":
-        compiled = jax.jit(
+        jitted = jax.jit(
             lambda p, k, v, t, pos, bt, a, te, tk, tp, key: hybrid.decode_multi(p, mc, k, v, t, pos, bt, a, te, tk, tp, key, 8,
                                                                                 return_logits=check),
             donate_argnums=(1, 2),
-        ).lower(params, k, v, i32(B), i32(B), i32(B, W), act, f32(B), i32(B), f32(B), _sds((2,), jnp.uint32, sh)).compile()
+        )
+        args = (params, k, v, i32(B), i32(B), i32(B, W), act, f32(B), i32(B), f32(B), _sds((2,), jnp.uint32, sh))
     elif program == "prefill":  # a chunk with no decode row: the slot array's layout stays pinned (hybrid._mamba_mixer)
-        compiled = jax.jit(
+        jitted = jax.jit(
             lambda p, k, v, t, vl, cl, bt: hybrid.prefill(p, mc, k, v, t, vl, cl, bt, all_logits=check, has_prefix=not check,
                                                           use_flash=flash),
             donate_argnums=(1, 2),
-        ).lower(params, k, v, i32(256), i32(), i32(), i32(W)).compile()
+        )
+        args = (params, k, v, i32(256), i32(), i32(), i32(W))
     else:
-        compiled = jax.jit(
+        jitted = jax.jit(
             lambda p, k, v, pt, pv, cl, ptab, dt, dpos, dtab, da: hybrid.mixed_step(p, mc, k, v, pt, pv, cl, ptab, dt, dpos, dtab, da,
                                                                                     use_flash=flash),
             donate_argnums=(1, 2),
-        ).lower(params, k, v, i32(256), i32(), i32(), i32(W), i32(B), i32(B), i32(B, W), act).compile()
-    return compiled, params, k, v
+        )
+        args = (params, k, v, i32(256), i32(), i32(), i32(W), i32(B), i32(B), i32(B, W), act)
+    return jitted, args, params, k, v
+
+
+@functools.cache  # a program that two tests read is compiled once
+def _granite_compiled(sh, program):
+    """``(compiled, params, k, v)`` of ``_granite_jit``'s program."""
+    jitted, args, params, k, v = _granite_jit(sh, program)
+    return jitted.lower(*args).compile(), params, k, v
 
 
 @pytest.mark.parametrize("program", ["decode_multi", "mixed_step", "prefill", "check-decode_multi", "check-mixed_step", "check-prefill"])
@@ -475,8 +491,8 @@ def test_granite_step_programs_compile_and_fit_beside_the_weights(one_chip, on_t
 
 
 @functools.cache  # as above
-def _zaya_compiled(sh, program):
-    """``(compiled, params, k, v)``: a step program of the benchmark's
+def _zaya_jit(sh, program):
+    """``(jitted, args, params, k, v)``: a step program of the benchmark's
     ``zaya1-8b-d20`` as configured (65 slots, 1,025 blocks, 64 rows, tables of
     24). ``check-``: as the output check calls it (``zaya.program_logits``: its
     bucket and table width, every position's logits)."""
@@ -502,22 +518,32 @@ def _zaya_compiled(sh, program):
     f32 = lambda *s: _sds(s, jnp.float32, sh)  # noqa: E731
     act = _sds((B,), jnp.bool_, sh)
     if program == "decode_multi":
-        compiled = jax.jit(
+        jitted = jax.jit(
             lambda p, k, v, t, pos, bt, a, te, tk, tp, key: hybrid.decode_multi(p, mc, k, v, t, pos, bt, a, te, tk, tp, key, 8,
                                                                                 return_logits=check),
             donate_argnums=(1, 2),
-        ).lower(params, k, v, i32(B), i32(B), i32(B, W), act, f32(B), i32(B), f32(B), _sds((2,), jnp.uint32, sh)).compile()
+        )
+        args = (params, k, v, i32(B), i32(B), i32(B, W), act, f32(B), i32(B), f32(B), _sds((2,), jnp.uint32, sh))
     elif program == "prefill":
-        compiled = jax.jit(
+        jitted = jax.jit(
             lambda p, k, v, t, vl, cl, bt: hybrid.prefill(p, mc, k, v, t, vl, cl, bt, all_logits=check),
             donate_argnums=(1, 2),
-        ).lower(params, k, v, i32(256), i32(), i32(), i32(W)).compile()
+        )
+        args = (params, k, v, i32(256), i32(), i32(), i32(W))
     else:
-        compiled = jax.jit(
+        jitted = jax.jit(
             lambda p, k, v, pt, pv, cl, ptab, dt, dpos, dtab, da: hybrid.mixed_step(p, mc, k, v, pt, pv, cl, ptab, dt, dpos, dtab, da),
             donate_argnums=(1, 2),
-        ).lower(params, k, v, i32(256), i32(), i32(), i32(W), i32(B), i32(B), i32(B, W), act).compile()
-    return compiled, params, k, v
+        )
+        args = (params, k, v, i32(256), i32(), i32(), i32(W), i32(B), i32(B), i32(B, W), act)
+    return jitted, args, params, k, v
+
+
+@functools.cache  # a program that two tests read is compiled once
+def _zaya_compiled(sh, program):
+    """``(compiled, params, k, v)`` of ``_zaya_jit``'s program."""
+    jitted, args, params, k, v = _zaya_jit(sh, program)
+    return jitted.lower(*args).compile(), params, k, v
 
 
 @pytest.mark.parametrize("program", ["decode_multi", "mixed_step", "check-prefill"])
@@ -546,6 +572,12 @@ def test_zaya_step_programs_compile_and_fit_beside_the_weights(one_chip, on_tpu,
 
 M7_D4 = get_config("mistral-7b").replace(name="mistral-7b-d4", num_layers=4, block_size=128, max_seq_len=2048)
 _ARRAY = re.compile(r"\b[a-z]+\d+\[([\d,]+)\]\{([\d,]+)")  # an array's dimensions and its layout, minor to major
+
+
+@functools.cache  # a program that two tests read is compiled once
+def _m7_compiled(sh, program):
+    """An int8 tree at Mistral-7B's widths, 4 layers: bucket 32, tables of 16."""
+    return _llama_step_program(M7_D4, _param_shapes(M7_D4, sh, int8=True), program, sh, 256, 256, 32, 16)
 
 
 def _re_laid_weights(text, params):
@@ -587,6 +619,41 @@ def test_no_step_program_re_lays_a_weight(one_chip, on_tpu, tree, program):
     elif tree == "eva-bf16":
         compiled, params = _eva_compiled(one_chip, program), _param_shapes(EVA, one_chip)
     else:
-        params = _param_shapes(M7_D4, one_chip, int8=True)
-        compiled = _llama_step_program(M7_D4, params, program, one_chip, 256, 256, 32, 16)
+        compiled, params = _m7_compiled(one_chip, program), _param_shapes(M7_D4, one_chip, int8=True)
     assert not _re_laid_weights(compiled.as_text(), params)
+
+
+# --- a step program out of the program store compiles to what tracing compiles --------------------
+
+
+@pytest.mark.parametrize("family", ["mistral-7b-int8", "eva", "granite", "zaya"])
+def test_a_stored_step_program_compiles_with_its_kernels_and_its_pools_in_place(one_chip, on_tpu, family, tmp_path):
+    """What ``program_store.StoredJit`` does on a miss and a hit, for the
+    described chip: export the jitted step, write and read the module through
+    the store's own file, and compile ``jit(call, donate)`` around it. Every
+    kernel is in the result, the pool (and the slots) alias their results as
+    in the direct compile, and a module is well under a megabyte."""
+    from dynamo_tpu.engine.program_store import ProgramStore
+
+    if family == "mistral-7b-int8":
+        jitted, args = _llama_step_jit(M7_D4, _param_shapes(M7_D4, one_chip, int8=True), "decode_multi_w8", one_chip, 256, 256, 32, 16)
+        direct = _m7_compiled(one_chip, "decode_multi_w8")
+    elif family == "eva":
+        jitted, args = _llama_step_jit(EVA, _param_shapes(EVA, one_chip), "mixed_step", one_chip, EVA_BLOCKS, 256, 16, 20)
+        direct = _eva_compiled(one_chip, "mixed_step")
+    else:
+        jitted, args = (_granite_jit if family == "granite" else _zaya_jit)(one_chip, "decode_multi")[:2]
+        direct = (_granite_compiled if family == "granite" else _zaya_compiled)(one_chip, "decode_multi")[0]
+    store = ProgramStore(str(tmp_path), "a described v5e")
+    path = store.path("decode_multi", family, "0" * 64)
+    store.write(path, bytes(jax.export.export(jitted, platforms=["tpu"])(*args).serialize()))
+    assert os.path.getsize(path) < 1 << 20
+    exported = store.read(path)
+
+    def call(*a):
+        return exported.call(*a)
+
+    compiled = jax.jit(call, donate_argnums=(1, 2)).lower(*args).compile()
+    kernels = direct.as_text().count("tpu_custom_call")
+    assert compiled.as_text().count("tpu_custom_call") == kernels > 0
+    assert compiled.memory_analysis().alias_size_in_bytes == direct.memory_analysis().alias_size_in_bytes > 0

@@ -10,9 +10,13 @@ prints one more JSON line before the result line, ``{"phase": "build_log", ...}`
 what ``/debug/state`` shows under ``build`` for the run's engine (the
 ``engine.build`` span as trace + lowering + backend + other seconds, the same
 by kind, the costliest keys, the eager executables by name, what was built
-since warm-up, and ``in_one_chunk`` beside ``executables``: how many of them
+since warm-up, ``in_one_chunk`` beside ``executables``: how many of them
 were built below ``compile_cache.in_one_chunk``'s frame, which is all of a
-set-up's and none of serving's), and beside it the whole process's count and backend seconds
+set-up's and none of serving's, and ``store_hits`` / ``store_misses`` beside
+``cache_hits`` / ``cache_misses``: how many were built from a module the
+program store held, ``engine/program_store.py``, and how many were traced,
+lowered and written to it; ``program_store``: its files and bytes on disk,
+this checkout's generation and all), and beside it the whole process's count and backend seconds
 (what the harness's ``CompileMeter`` prints as ``executables`` and
 ``compile_seconds``) and what the harness built before ``engine.build`` (its
 weights, its output check). The last line is still the run's result.
@@ -80,6 +84,21 @@ def watch_stack() -> None:
     watched(mosaic, "jaxpr_subcomp")
 
 
+def store_on_disk() -> dict:
+    """The program store's files and bytes: every generation's, and this source's."""
+    from dynamo_tpu.engine.compile_cache import program_store_dir
+    from dynamo_tpu.engine.program_store import ProgramStore
+
+    root = program_store_dir()
+    if root is None or not os.path.isdir(root):
+        return {}
+    sizes = {os.path.join(d, f): os.path.getsize(os.path.join(d, f)) for d, _, fs in os.walk(root) for f in fs}
+    mine = ProgramStore(root, "").dir
+    return {"program_store": {"dir": root, "files": len(sizes), "bytes": sum(sizes.values()),
+                              "generation_files": sum(p.startswith(mine) for p in sizes),
+                              "generation_bytes": sum(n for p, n in sizes.items() if p.startswith(mine))}}
+
+
 def report() -> dict:
     from dynamo_tpu.engine.compile_cache import BUILD, BUILD_LOG
 
@@ -91,7 +110,7 @@ def report() -> dict:
             "backend_s_whole_run": sum(e.backend_s for e in entries),
             "before_engine_build": {"executables": len(before), "trace_s": sum(e.trace_s for e in before),
                                     "lower_s": sum(e.lower_s for e in before), "backend_s": sum(e.backend_s for e in before)},
-            **({"deepest_stack": DEEPEST} if DEEPEST else {}),
+            **({"deepest_stack": DEEPEST} if DEEPEST else {}), **store_on_disk(),
             **BUILD_LOG.summary(since)}
 
 
