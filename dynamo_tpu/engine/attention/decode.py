@@ -11,8 +11,8 @@ FlashAttention/paged-attention plays inside the reference's GPU engines
 block-table owner there): attention reads each prefix page from HBM into
 VMEM exactly once, and nothing is ever written back.
 
-Design notes (v5e, measured with the decode ablation harness — now folded
-into bench.py's ``decode_attention`` section):
+Design notes (v5e; from the decode ablations of the rounds before PR 1,
+not measured on today's code):
 - **Pages ARE the pipeline blocks.** The grid is ``(B, W)`` — one program
   per (sequence, table slot) — and the page fetch is a plain BlockSpec
   whose index_map reads the block id from the scalar-prefetched table.

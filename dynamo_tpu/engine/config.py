@@ -93,14 +93,10 @@ class ModelConfig:
     #   path — int8 caches degrade to gather with a logged warning
     #   (llama.resolve_attention_impl).
     # - "auto": "megakernel" on TPU, "gather" elsewhere (interpreted
-    #   Pallas is test-only). Measured record: decode at b32 sat at ~54%
-    #   of HBM roofline on the gather (BENCH_r05 — the gather's
-    #   read + packed-copy write + attend re-read is 3× the true KV
-    #   bytes); the megakernel streams each page HBM→VMEM once per grid
-    #   row (a decode query, or a tile of a chunk's queries). Track
-    #   via bench.py's `decode_attention` section (tok/s,
-    #   pct_hbm_roofline, per-launch dispatch overhead, gather vs
-    #   megakernel at b∈{8,32}).
+    #   Pallas is test-only). The gather's read + packed-copy write +
+    #   attend re-read is 3× the true KV bytes; the megakernel streams
+    #   each page HBM→VMEM once per grid row (a decode query, or a tile
+    #   of a chunk's queries). Its share of a step: PERF.md section 5.
     attention_impl: str = "auto"
     # Prefill chunk attention — for phase-separated prefills AND the
     # ragged chunk rows of mixed steps (attention/ragged.py): "auto" =

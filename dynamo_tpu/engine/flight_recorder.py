@@ -508,8 +508,8 @@ class FlightRecorder:
     def exec_key_summary(self) -> Dict[str, List[int]]:
         """{kind: sorted key arities} of every executable key registered so
         far — the dynamic twin of dtlint's ``static_warmup_report()``.
-        bench.py diffs the two so the static warmup enumeration and the
-        recorder's observed compile keys cannot drift apart."""
+        ``tests/test_decode_paths.py`` holds a warmed scheduler's keys
+        inside the static enumeration, so the two cannot drift apart."""
         out: Dict[str, Set[int]] = {}
         for k in self._exec_keys:
             out.setdefault(k[0], set()).add(len(k) - 1)

@@ -1763,7 +1763,7 @@ def decode_layer_scan(
     context / folded into the kernel's online softmax) and returned stacked
     ``[L', B, KVH, HD]`` for the caller's single fused scatter. Writing the
     cache inside the scan carry forced XLA into a full cache copy per layer
-    (~5 ms/step at 1B/b8 on v5e — measured with tools/profile_cache.py);
+    (seen before PR 1; its cost is not measured on today's code);
     read-only xs slicing leaves the buffers untouched."""
     B = h.shape[0]
     bs = c.block_size

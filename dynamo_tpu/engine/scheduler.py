@@ -77,8 +77,8 @@ def width_rungs(max_w: int, start: int = 4) -> List[int]:
 
 
 def width_bucket(n: int, cap: int) -> int:
-    """Smallest pow2-or-1.5·pow2 rung ≥ n, clamped to ``cap``. bench.py uses
-    the same rule so driver decode numbers reflect production table widths."""
+    """Smallest pow2-or-1.5·pow2 rung ≥ n, clamped to ``cap``: the width a
+    block table is padded to, so that table widths are few."""
     return min(width_rungs(max(n, 1))[-1], cap)
 
 
@@ -3180,11 +3180,6 @@ class Scheduler:
             self.allocator.hit_blocks_total += onboarded
             self.allocator.miss_blocks_total -= onboarded
         return blocks
-
-    def _block_table(self, seq: Sequence) -> jnp.ndarray:
-        table = np.zeros((self.max_blocks_per_seq,), dtype=np.int32)
-        table[: len(seq.block_ids)] = seq.block_ids
-        return jnp.asarray(table)
 
     def _consume_aux(self, res):
         """Strip the moe-stats aux (when enabled) from a jitted step's result

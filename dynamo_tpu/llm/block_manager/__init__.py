@@ -19,7 +19,7 @@ TPU-native mapping:
 
 Lookup walks tiers: G1 hit ⇒ free; G2/G3 hit ⇒ *onboard* (copy back into
 freshly allocated G1 blocks) — still far cheaper than recomputing prefill
-(the reference reports +40% TTFT from host offload alone, BASELINE.md).
+(the reference reports +40% TTFT from host offload alone, SURVEY.md §6).
 """
 
 from __future__ import annotations

@@ -93,7 +93,7 @@ class KvScheduler:
             # tie-breaking concentrated every cold request onto one worker
             # (measured: a serial warm pass put 8 prefix groups on a single
             # mocker, evicting two of them, and KV routing then LOST to
-            # round-robin in tools/bench_router_prefix.py).
+            # round-robin).
             best = min(c[1] for c in costs)
             return self.rng.choice([c for c in costs if c[1] == best])
         # softmax over -cost/temperature (ref: softmax_sample scheduler.rs:375)

@@ -57,9 +57,7 @@ class ActiveSequencesMultiWorker:
         cost the worker no extra HBM and no extra write bandwidth, so
         counting them at full weight made the cost model route high-overlap
         requests AWAY from their warm worker the moment it had one request
-        in flight (the engine's prefix-cache hit then never happened —
-        measured as the 1.1× router-benefit plateau in
-        tools/bench_router_prefix.py)."""
+        in flight (the engine's prefix-cache hit then never happened)."""
         self.ensure_worker(worker)
         prefill = max(0, prompt_tokens - overlap_blocks * self.block_size)
         blocks = (prompt_tokens + self.block_size - 1) // self.block_size

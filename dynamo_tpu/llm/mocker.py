@@ -641,8 +641,8 @@ class MockTpuEngine:
                 seq.cached_tokens = 0
                 return 0
             # Count hits only on a COMMITTED first touch — a rolled-back
-            # admission retries and would double-count (which inflated the
-            # thrash-prone policy's hit rate in bench_router_prefix).
+            # admission retries and would double-count (which inflated a
+            # thrash-prone policy's hit rate).
             self.cached_tokens_total += seq.cached_tokens
             if seq.admitted_ts is None:
                 seq.admitted_ts = time.monotonic()

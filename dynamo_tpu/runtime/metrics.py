@@ -107,10 +107,3 @@ class MetricsRegistry:
 TTFT_BUCKETS = (0.001, 0.005, 0.01, 0.025, 0.05, 0.1, 0.25, 0.5, 1.0, 2.5, 5.0, 10.0, 30.0, 60.0)
 ITL_BUCKETS = (0.0005, 0.001, 0.0025, 0.005, 0.01, 0.025, 0.05, 0.1, 0.25, 0.5, 1.0)
 DURATION_BUCKETS = (0.01, 0.05, 0.1, 0.25, 0.5, 1.0, 2.5, 5.0, 10.0, 30.0, 60.0, 120.0, 300.0)
-# Mixed-step composition: prefill tokens riding one engine step (the
-# scheduler's prefill-bucket rungs — see SchedulerConfig.mixed_prefill_budget).
-# Workers export the raw counters (mixed_steps_total / mixed_prefill_tokens_
-# total / mixed_decode_tokens_total via stats → metrics_aggregator gauges);
-# these buckets are for per-step composition histograms in dashboards and
-# bench.py's mixed-batch section.
-MIXED_PREFILL_TOKEN_BUCKETS = (0.0, 16.0, 32.0, 64.0, 128.0, 256.0, 512.0, 1024.0, 2048.0)

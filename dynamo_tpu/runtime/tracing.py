@@ -6,8 +6,7 @@ writer). The reference exports OTLP; here spans land in a JSONL file a
 developer can grep, feed to ``tools/trace_view.py``, or convert to the
 Chrome ``chrome://tracing`` / Perfetto format.
 
-Design constraints (why this is not just the asyncio Recorder from
-``llm/perf.py``):
+Design constraints (why this is not an asyncio-only recorder):
 
 - **Emitters live on both sides of the thread boundary.** The scheduler
   emits from the engine's step thread (``asyncio.to_thread``); the HTTP
@@ -154,8 +153,7 @@ class Tracer:
 
     ``emit``/``Span.end`` enqueue records on a thread-safe queue; a daemon
     writer thread batches them to disk, so neither the event loop nor the
-    engine step thread ever waits on file IO (the perf.py Recorder
-    pattern, portable across the thread boundary)."""
+    engine step thread ever waits on file IO."""
 
     def __init__(self, path: Optional[str] = None, sample: float = 1.0,
                  service: str = "dynamo", ring_size: int = 0, tail: bool = False):

@@ -524,9 +524,8 @@ async def test_planner_stats_flow_through_aggregator():
 
 
 # --- the closed loop ----------------------------------------------------------
-@pytest.mark.slow  # ~25s of real-time ramp; the CI `autoscale` job runs this
-# same loop every push via `BENCH_AUTOSCALE_ONLY=1 python bench.py` and gates
-# on convergence/SLO/token-loss — tier-1 keeps the fast decision/fleet layers.
+@pytest.mark.slow  # ~25s of real-time ramp (`-m slow` runs it); tier-1 keeps
+# the fast decision/fleet layers.
 async def test_autoscale_closed_loop_with_chaos():
     """Shortened harness diurnal ramp through the FULL plane. Asserts the
     acceptance criteria: independent pool growth, convergence to the

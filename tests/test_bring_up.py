@@ -1,11 +1,12 @@
 """Bring-up guards: nothing on the chip path quietly falls back to the CPU,
-the compile cache has one rule, and ``chip_smoke.py`` / ``bench.py`` fail
-without an accelerator. The smoke's control flow is rehearsed at the ``tiny``
+the compile cache has one rule, and ``chip_smoke.py`` fails without an
+accelerator. The smoke's control flow is rehearsed at the ``tiny``
 preset (``--rehearse``) so a later PR cannot break it unnoticed; the real run
 needs the chip and is the builder's / driver's."""
 
 import json
 import os
+import re
 import shutil
 import subprocess
 import sys
@@ -64,24 +65,6 @@ def test_unknown_accelerator_is_an_error_not_a_default(platform, kind):
         flight_recorder.peaks_for(platform, kind)
 
 
-def test_bench_chip_peaks_uses_the_one_table():
-    sys.path.insert(0, REPO)
-    try:
-        import bench
-    finally:
-        sys.path.remove(REPO)
-    assert bench.chip_peaks("TPU v5 lite") == (819.0, 197.0)
-    with pytest.raises(ValueError):
-        bench.chip_peaks("TpuDevice(id=0)")
-
-
-def test_bench_without_a_chip_exits_nonzero():
-    proc = _run(["bench.py"], BENCH_BUDGET_S="120")
-    assert proc.returncode != 0
-    assert "no accelerator" in proc.stderr
-    assert '"metric"' not in proc.stdout  # no chip, no number
-
-
 # --- compile cache: placed from outside, or one fixed path --------------------
 
 
@@ -137,3 +120,22 @@ def test_chip_smoke_rehearsal(chips):
         assert sum(p.get("phase") == "parity" and "compare" in p for p in phases) == 4
     else:
         assert sum(p.get("phase") == "tp4" and "compare" in p for p in phases) == 2
+
+
+# --- the README names what the checkout holds ----------------------------------
+
+
+def test_readme_names_files_that_exist():
+    """Every back-quoted path of README.md under a directory of the checkout, or a ``*.py`` /
+    ``*.json`` / ``*.md`` at its root, is there (``::name`` and ``:line`` cut off; globs and
+    ``<...>`` are patterns, not paths)."""
+    with open(os.path.join(REPO, "README.md")) as f:
+        quoted = re.findall(r"`([^`\n]+)`", f.read())
+    dirs = ("tools/", "dynamo_tpu/", "benchmark/", "tests/", "deploy/", "examples/")
+    paths = {re.split(r"::|:\d", q.split()[0])[0] for q in quoted if q.strip()}
+    paths = {
+        p for p in paths
+        if not re.search(r"[*<>{}$]", p) and (p.startswith(dirs) or re.fullmatch(r"[\w.-]+\.(py|json|md)", p))
+    }
+    assert len(paths) > 30, sorted(paths)  # the pattern still finds the README's paths
+    assert [p for p in sorted(paths) if not os.path.exists(os.path.join(REPO, p))] == []

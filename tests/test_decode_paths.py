@@ -103,6 +103,19 @@ def test_batch_rides_the_program_its_rows_allow(row, warmed):
         assert all(re.fullmatch("[a-e]{12}", ByteTokenizer().decode(t)) for t in toks.values())
 
 
+def test_what_warmup_compiled_is_inside_the_static_enumeration(warmed):
+    """The flight recorder's keys of a warmed scheduler against dtlint WARM001's reading of
+    ``Scheduler.warmup()``: every compiled kind is one the linter lists, at an arity it lists."""
+    from tools.dtlint.rules_warmup import static_warmup_report
+
+    static = static_warmup_report(REPO)["warmed"]
+    compiled = warmed.flight.exec_key_summary()
+    assert {"prefill", "decode", "decode_multi"} <= set(compiled)
+    for kind, arities in compiled.items():
+        assert kind in static, f"'{kind}' compiled, WARM001 does not list it"
+        assert not static[kind] or set(arities) <= set(static[kind]), (kind, arities, static[kind])
+
+
 def test_one_extras_row_takes_its_batch_to_single_steps():
     greedy = batch_of(dict())
     sched = mk_sched()

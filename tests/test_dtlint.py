@@ -303,6 +303,16 @@ def test_sync_allowlist_declares_one_per_step_sync_per_path():
         assert len(entries) == 1, f"path {path} declares {len(entries)} per-step syncs"
 
 
+def test_sync_allowlist_sanctions_no_sync_on_a_stats_path():
+    """What a scrape calls while traffic runs is in SYNC001's scope and declares no
+    blocking sync (the one batched exception lives in the baseline, with its reason)."""
+    with open(os.path.join(REPO, "tools/dtlint/sync_allowlist.json")) as f:
+        cfg = json.load(f)
+    stats_funcs = {"Scheduler.metrics", "Scheduler.kv_gauges", "Scheduler.debug_state"}
+    assert [e for e in cfg["allowed_syncs"] if e["func"] in stats_funcs] == []
+    assert stats_funcs <= set(cfg["hot_paths"]["dynamo_tpu/engine/scheduler.py"])
+
+
 # --- CLI ----------------------------------------------------------------------
 
 def test_cli_json_exit_codes():
@@ -383,7 +393,7 @@ def test_rule_registry_is_complete():
 
 
 def test_static_warmup_report_agrees_with_the_real_scheduler():
-    """The bench-facing export over the REAL tree: the kinds the scheduler
+    """The export over the REAL tree: the kinds the scheduler
     serves are (modulo the baselined open-ended mm bucket) all statically
     warmed, including the spec-decode round added for exactly this gap."""
     from tools.dtlint.rules_warmup import static_warmup_report
@@ -396,8 +406,8 @@ def test_static_warmup_report_agrees_with_the_real_scheduler():
     )
     # Every serving-path dispatch kind (modulo the baselined mm bucket) is
     # statically warmed at an intersecting arity — the same coverage
-    # relation WARM001 enforces, exported here for bench.py's dynamic
-    # cross-check against the flight recorder.
+    # relation WARM001 enforces (the dynamic cross-check against the
+    # flight recorder is tests/test_decode_paths.py's).
     for kind, arities in report["serving"].items():
         if kind == "prefill_mm":
             continue

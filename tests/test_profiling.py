@@ -19,16 +19,11 @@ Four layers, matching the subsystem's split:
   the cost-model calibration sanity band, and the ProfiledCapacityModel
   replay: an autoscale decision table that starts on wrong declared rates
   and converges to the measured-rate oracle.
-
-Plus ``tools/bench_diff.py``: every load_round input shape the BENCH_r*
-history actually contains, and the per-direction regression verdicts.
 """
 
 import gzip
-import importlib.util
 import json
 import os
-import sys
 import threading
 import time
 
@@ -54,8 +49,6 @@ from dynamo_tpu.runtime.profiling import (
     parse_trace_bytes,
     parse_trace_events,
 )
-
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 # --- fixture builders ---------------------------------------------------------
@@ -349,6 +342,25 @@ def test_full_window_record_and_sink(tmp_path):
     assert stats["device_profile_duty_cycle"] <= 0.02
 
 
+def test_a_started_sampler_is_armed_and_idles_until_its_first_window(tmp_path):
+    """The default posture: the thread lives, no window is due for 30 s, nothing errs, and
+    ``stop`` disarms it; a disabled configuration never starts."""
+    cont = ContinuousProfiler(_StubProfiler(tmp_path), ContinuousProfileConfig())
+    cont.start()
+    try:
+        assert cont.armed
+        stats = cont.to_stats()
+        assert stats["device_profile_windows_total"] == 0 and not cont.profiler.calls
+        assert stats["device_profile_errors_total"] == 0
+        assert stats["device_profile_duty_cycle"] <= 0.02
+    finally:
+        cont.stop()
+    assert not cont.armed
+    off = ContinuousProfiler(_StubProfiler(tmp_path), ContinuousProfileConfig(enabled=False))
+    off.start()
+    assert not off.armed
+
+
 def test_sink_failure_does_not_kill_the_window(tmp_path):
     def bad_sink(_rec):
         raise RuntimeError("sink bug")
@@ -521,109 +533,3 @@ def test_planner_stats_ride_prior_until_warm():
     stats = ctrl.to_stats()
     assert stats["planner_measured_prefill_tok_s"] == 0.0
     assert stats["planner_measured_decode_tok_s"] == 0.0
-
-
-# --- tools/bench_diff.py ------------------------------------------------------
-@pytest.fixture(scope="module")
-def bench_diff():
-    spec = importlib.util.spec_from_file_location(
-        "bench_diff", os.path.join(REPO, "tools", "bench_diff.py"))
-    mod = importlib.util.module_from_spec(spec)
-    sys.modules["bench_diff"] = mod  # dataclass field resolution needs this
-    spec.loader.exec_module(mod)
-    yield mod
-    sys.modules.pop("bench_diff", None)
-
-
-def _round(detail, metric="tok_s", value=100.0):
-    return {"metric": metric, "value": value, "detail": detail}
-
-
-def test_bench_diff_load_round_all_history_shapes(bench_diff, tmp_path):
-    raw = tmp_path / "raw.json"
-    raw.write_text(json.dumps(_round({"observability": {"overhead_pct": 1.0}})))
-    obj, src = bench_diff.load_round(str(raw))
-    assert src == "raw" and obj["detail"]["observability"]["overhead_pct"] == 1.0
-
-    wrapped = tmp_path / "wrapped.json"
-    wrapped.write_text(json.dumps({"n": 6, "cmd": "bench", "rc": 0, "tail": "",
-                                   "parsed": _round({})}))
-    _, src = bench_diff.load_round(str(wrapped))
-    assert src == "wrapper"
-
-    # parsed=null but a complete final JSON line survived in the tail.
-    tail_line = tmp_path / "tail_line.json"
-    tail_line.write_text(json.dumps({
-        "n": 7, "cmd": "bench", "rc": 1, "parsed": None,
-        "tail": "noise line\n" + json.dumps(_round({"prefill": {"tok_s": 9}})),
-    }))
-    obj, src = bench_diff.load_round(str(tail_line))
-    assert src == "tail-line" and obj["detail"]["prefill"]["tok_s"] == 9
-
-    # parsed=null and the tail is a front-truncated fragment (the BENCH_r05
-    # shape): intact per-section sub-objects are still recovered.
-    frag = ('"ttft_p50_ms": 38.7}, "observability": {"overhead_pct": 1.2, '
-            '"within_budget": true}, "autoscale": {"summary": '
-            '{"slo_attainment": 0.97, "converged": true}}, '
-            '"decode_sweep": [{"batch": 8, "ctx": 1024, "tok_s_per_user": 11.0}]')
-    tail_frag = tmp_path / "tail_frag.json"
-    tail_frag.write_text(json.dumps({"n": 5, "cmd": "bench", "rc": 1,
-                                     "parsed": None, "tail": frag}))
-    obj, src = bench_diff.load_round(str(tail_frag))
-    assert src.startswith("tail-fragment")
-    assert obj["detail"]["observability"]["within_budget"] is True
-    assert obj["detail"]["decode_sweep"][0]["batch"] == 8
-
-    bad = tmp_path / "bad.json"
-    bad.write_text(json.dumps({"something": "else"}))
-    with pytest.raises(ValueError):
-        bench_diff.load_round(str(bad))
-
-
-def test_bench_diff_verdicts_per_direction(bench_diff):
-    old = _round({
-        "observability": {"overhead_pct": 1.0, "within_budget": True,
-                          "compiles_after_warmup": 0},
-        "prefix_reuse": {"speedup": 2.0},
-        "autoscale": {"summary": {"slo_attainment": 0.97, "converged": True}},
-        "http_e2e": {"tok_s": 100.0},
-        "decode_sweep": [{"batch": 8, "ctx": 1024, "tok_s_per_user": 10.0}],
-    })
-    new = _round({
-        "observability": {"overhead_pct": 2.5, "within_budget": False,
-                          "compiles_after_warmup": 0},
-        "prefix_reuse": {"speedup": 1.9},     # −5%: inside the 15% band
-        "autoscale": {"summary": {"slo_attainment": 0.99, "converged": True}},
-        "http_e2e": {"tok_s": 120.0},
-        "decode_sweep": [{"batch": 8, "ctx": 1024, "tok_s_per_user": 8.0}],
-    }, value=50.0)
-    rows = bench_diff.compare(old, new)
-    by_label = {r["label"]: r["verdict"] for r in rows}
-    assert by_label["tok_s"] == "regression"              # headline −50%
-    assert by_label["b8 ctx1024 tok/s/user"] == "regression"  # −20% point
-    assert by_label["tracing overhead %"] == "regression"  # +1.5 > 1.0 abs tol
-    assert by_label["within ≤2% budget"] == "regression"   # flag flip
-    assert by_label["post-warmup compiles = 0"] == "ok"
-    assert by_label["prefix-reuse speedup"] == "ok"        # inside rel band
-    assert by_label["SLO attainment"] == "improved"        # summary fallback dug
-    assert by_label["http e2e tok/s"] == "improved"
-    assert by_label["measured/modeled agreement"] == "not-comparable"
-    # A side with no sections at all can never regress anything.
-    only_old = bench_diff.compare(old, _round({}))
-    assert all(r["verdict"] != "regression" for r in only_old)
-
-
-def test_bench_diff_strict_exit_codes(bench_diff, tmp_path, capsys):
-    good = _round({"observability": {"overhead_pct": 1.0, "within_budget": True}})
-    bad = _round({"observability": {"overhead_pct": 3.0, "within_budget": False}})
-    p_good, p_bad = tmp_path / "g.json", tmp_path / "b.json"
-    p_good.write_text(json.dumps(good))
-    p_bad.write_text(json.dumps(bad))
-    assert bench_diff.main([str(p_good), str(p_bad)]) == 0          # report only
-    assert bench_diff.main([str(p_good), str(p_bad), "--strict"]) == 1
-    assert bench_diff.main([str(p_good), str(p_good), "--strict"]) == 0
-    capsys.readouterr()  # drop the human-format reports
-    assert bench_diff.main([str(p_good), str(p_bad), "--json"]) == 0
-    payload = json.loads(capsys.readouterr().out)
-    assert payload["regressions"] >= 2
-    assert payload["new"]["source"] == "raw"

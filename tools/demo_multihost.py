@@ -17,10 +17,9 @@ and executes the same sharded decode step SPMD. CPU backend with 4
 virtual devices per process → an 8-device global mesh, per the repo's
 multi-chip testing convention.
 
-Prints ONE JSON line; ``--write-artifact`` also records it to
-MULTIHOST_DEMO_r05.json for the round artifact.
+Prints ONE JSON line.
 
-Usage: python tools/demo_multihost.py [--write-artifact]
+Usage: python tools/demo_multihost.py
 """
 
 from __future__ import annotations
@@ -184,9 +183,6 @@ def main() -> None:
             "workers": results,
         }
         print(json.dumps(artifact))
-        if "--write-artifact" in sys.argv:
-            with open(os.path.join(REPO, "MULTIHOST_DEMO_r05.json"), "w") as f:
-                json.dump(artifact, f, indent=1)
         sys.exit(0 if artifact["ok"] else 1)
     finally:
         broker.terminate()

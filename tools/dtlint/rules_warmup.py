@@ -17,8 +17,8 @@ agree when their arity sets intersect (the recorder keys executables by
 ``(kind,) + tuple(key)``, so kind+arity is the static shape of the key
 space; the element *values* are runtime rungs the bench still covers).
 
-``static_warmup_report()`` exports the same enumeration for bench.py, which
-cross-checks it against the recorder's dynamically observed executable keys
+``static_warmup_report()`` exports the same enumeration for the tests, which
+cross-check it against the recorder's dynamically observed executable keys
 — the static and dynamic views of the 0-compile invariant must agree.
 """
 
@@ -199,13 +199,13 @@ def warm001(index: ProjectIndex) -> List[Finding]:
 
 
 def static_warmup_report(root: str) -> dict:
-    """Bench-facing export: the statically enumerated warmup key space.
+    """Test-facing export: the statically enumerated warmup key space.
 
     ``{"warmed": {kind: [arities]}, "serving": {kind: [arities]}}`` —
-    bench.py asserts the flight recorder's dynamically compiled executable
-    kinds/arities are a subset of the static ``warmed`` set, closing the
-    loop between this rule and the runtime 0-compile gate. Pure ast, no
-    JAX import.
+    ``tests/test_dtlint.py`` holds ``serving`` inside ``warmed``, and
+    ``tests/test_decode_paths.py`` a warmed scheduler's compiled
+    kinds/arities inside ``warmed``, closing the loop between this rule
+    and the runtime 0-compile gate. Pure ast, no JAX import.
     """
     index = ProjectIndex(LintConfig(root=root))
     warmed, serving = enumerate_warmup(index)

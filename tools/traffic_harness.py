@@ -31,8 +31,7 @@ observer over a real HTTP /metrics, AutoscaleController — runs the
 harness against it, optionally arms a chaos scenario (runtime/faults.py)
 the moment the first scale event lands, and reports SLO-attainment +
 goodput curves per window plus the controller's convergence vs the
-capacity oracle. This is the standing ``autoscale`` bench section and the
-CI gate.
+capacity oracle (``tests/test_autoscale.py`` holds it to them).
 
 CLI::
 
@@ -51,7 +50,7 @@ import random
 import sys
 import time
 from dataclasses import asdict, dataclass, field
-from typing import Any, Callable, Dict, List, Optional
+from typing import Any, Dict, List, Optional
 
 # Run as a file from a bare checkout: the package sits one directory up.
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
@@ -153,7 +152,6 @@ class Outcome:
     isl: int
     osl: int
     ttft_s: Optional[float] = None
-    e2e_s: Optional[float] = None
     finish: Optional[str] = None
     error: Optional[str] = None
     tokens: int = 0
@@ -216,7 +214,6 @@ class DisaggPath:
                 if data.get("finish_reason"):
                     finish = data["finish_reason"]
                     break
-            out.e2e_s = time.monotonic() - t0
             out.finish = finish
             out.tokens = len(got)
             expected = list(range(len(tokens), len(tokens) + osl))
@@ -225,7 +222,6 @@ class DisaggPath:
             )
         except Exception as e:  # noqa: BLE001 — the harness counts, never masks
             out.error = f"{type(e).__name__}: {e}"
-            out.e2e_s = time.monotonic() - t0
         return out
 
 
@@ -267,8 +263,7 @@ class TrafficHarness:
         return self.outcomes
 
     # --- aggregation -------------------------------------------------------
-    def windows(self, window_s: float = 2.0, slo_ttft_ms: float = 0.0,
-                slo_e2e_ms: float = 0.0) -> List[dict]:
+    def windows(self, window_s: float = 2.0, slo_ttft_ms: float = 0.0) -> List[dict]:
         """SLO-attainment and goodput curves across the run, per window."""
         if not self.outcomes:
             return []
@@ -287,8 +282,7 @@ class TrafficHarness:
 
             attained = [
                 o for o in done
-                if (not slo_ttft_ms or (o.ttft_s or 0.0) * 1000.0 <= slo_ttft_ms)
-                and (not slo_e2e_ms or (o.e2e_s or 0.0) * 1000.0 <= slo_e2e_ms)
+                if not slo_ttft_ms or (o.ttft_s or 0.0) * 1000.0 <= slo_ttft_ms
             ]
             wins.append({
                 "t": lo,
@@ -575,322 +569,6 @@ async def run_autoscale_bench(cfg: Optional[AutoscaleBenchConfig] = None) -> dic
         if server is not None:
             await server.stop()
         await drt.shutdown()
-
-
-# --- elastic prefill/decode bench ---------------------------------------------
-class ColocatedPath:
-    """Single-leg request path: prompt AND decode on one worker (round-robin
-    over the pool) — the pure co-located extreme of the elastic ladder."""
-
-    def __init__(self, engines: List[Any], *, request_timeout_ms: float = 0.0):
-        self.engines = list(engines)
-        self.request_timeout_ms = request_timeout_ms
-        self._rr = 0
-
-    def _req(self, tokens: List[int], max_tokens: int, **extra: Any) -> dict:
-        stop: Dict[str, Any] = {"max_tokens": max_tokens}
-        if self.request_timeout_ms:
-            stop["deadline_ms"] = self.request_timeout_ms
-        return {
-            "token_ids": list(tokens),
-            "sampling_options": {"temperature": 0.0},
-            "stop_conditions": stop,
-            **extra,
-        }
-
-    async def request(self, tokens: List[int], osl: int, t: float) -> Outcome:
-        from dynamo_tpu.runtime.engine import Context
-
-        eng = self.engines[self._rr % len(self.engines)]
-        self._rr += 1
-        return await _single_leg(eng, self._req(tokens, osl), tokens, osl, t)
-
-
-async def _single_leg(engine, req: dict, tokens: List[int], osl: int, t: float) -> Outcome:
-    """Run one co-located request (prefill + decode on ``engine``) and score
-    it exactly like DisaggPath does: TTFT = first token, the position-rule
-    stream must match the expected positions bit-for-bit."""
-    from dynamo_tpu.runtime.engine import Context
-
-    out = Outcome(t=t, isl=len(tokens), osl=osl)
-    t0 = time.monotonic()
-    got: List[int] = []
-    finish = None
-    try:
-        async for item in engine.generate(req, Context()):
-            data = item.data if hasattr(item, "data") else item
-            if not isinstance(data, dict):
-                continue
-            if data.get("token_ids") and out.ttft_s is None:
-                out.ttft_s = time.monotonic() - t0
-            got.extend(data.get("token_ids") or ())
-            if data.get("finish_reason"):
-                finish = data["finish_reason"]
-                break
-        out.e2e_s = time.monotonic() - t0
-        out.finish = finish
-        out.tokens = len(got)
-        expected = list(range(len(tokens), len(tokens) + osl))
-        out.token_exact = got == expected[: len(got)] and (
-            finish != "length" or len(got) == osl
-        )
-    except Exception as e:  # noqa: BLE001 — the harness counts, never masks
-        out.error = f"{type(e).__name__}: {e}"
-        out.e2e_s = time.monotonic() - t0
-    return out
-
-
-class ElasticPath(DisaggPath):
-    """The elastic ladder over two mixed-capable workers: two-leg disagg by
-    default (clean decode steps), degrading a request to a co-located single
-    leg on whichever worker has slack when its preferred leg's worker is
-    saturated — DEGRADE instead of queue. Saturation is judged from the
-    worker's own scheduler state (waiting + running vs slots), the same
-    signal the disagg handler's pool_load_probe scrapes."""
-
-    def __init__(
-        self,
-        prefill_engine,
-        decode_engine,
-        *,
-        prefill_saturated: Callable[[], bool],
-        decode_saturated: Callable[[], bool],
-        note_degrade: Optional[Callable[[str, str], None]] = None,
-        request_timeout_ms: float = 0.0,
-    ):
-        super().__init__(prefill_engine, decode_engine, request_timeout_ms=request_timeout_ms)
-        self.prefill_saturated = prefill_saturated
-        self.decode_saturated = decode_saturated
-        # (direction, target_worker) — target is who absorbs the degraded leg.
-        self.note_degrade = note_degrade or (lambda d, tgt: None)
-        self.degrades_to_decode = 0  # prefill pool saturated → co-locate on decode worker
-        self.degrades_to_prefill = 0  # decode pool saturated → co-locate on prefill worker
-
-    async def request(self, tokens: List[int], osl: int, t: float) -> Outcome:
-        if self.prefill_saturated():
-            self.degrades_to_decode += 1
-            self.note_degrade("disagg_to_colocated", "decode")
-            return await _single_leg(
-                self.decode_engine, self._req(tokens, osl), tokens, osl, t
-            )
-        if self.decode_saturated():
-            self.degrades_to_prefill += 1
-            self.note_degrade("disagg_to_colocated", "prefill")
-            return await _single_leg(
-                self.prefill_engine, self._req(tokens, osl), tokens, osl, t
-            )
-        return await super().request(tokens, osl, t)
-
-
-@dataclass
-class ElasticBenchConfig:
-    """Degrade-vs-queue: one shifting ISL/OSL mix offered to three fleets of
-    IDENTICAL hardware (same worker count, same MockEngineArgs) that differ
-    only in topology policy — pure disagg (static split, queue on
-    saturation), pure co-located (mixed everywhere, constant interference),
-    elastic (disagg + capacity dial + degradation ladder)."""
-
-    pattern: TrafficPattern = field(default_factory=lambda: TrafficPattern(
-        kind="ramp", duration_s=16.0, base_rate=4.0, peak_rate=4.0,
-        # The mix flip: starts prefill-heavy (long prompts, short answers),
-        # ends decode-heavy — prefill and decode demand cross mid-run.
-        isl=224, isl_end=48, osl=6, osl_end=40,
-        prefix_ratio=0.0, seed=0,
-    ))
-    slo_ttft_ms: float = 600.0
-    slo_e2e_ms: float = 4000.0
-    dial_interval_s: float = 1.0
-    # Queue depth (waiting + running beyond slots) at which the elastic path
-    # degrades instead of queueing.
-    saturation_depth: int = 3
-    settle_s: float = 3.0
-
-    def worker_args(self):
-        from dynamo_tpu.llm.mocker import MockEngineArgs
-
-        # Mixed-capable: meaningful prefill cost (compute-bound prompts) AND
-        # meaningful decode cost (bandwidth-bound steps), so the dial's
-        # budget split moves real queues in both directions.
-        return MockEngineArgs(
-            prefill_base_ms=1.0, prefill_per_token_us=1500.0,
-            itl_base_ms=30.0, itl_per_seq_ms=1.0,
-            max_batch=4, max_prefill_chunk=256,
-            num_blocks=768, token_rule="position",
-            slo_ttft_ms=self.slo_ttft_ms, slo_tpot_ms=None,
-        )
-
-
-def _prefill_saturation_probe(engine, budget_ms: float) -> Callable[[], bool]:
-    """Saturated = the pending prefill work already queued (tokens not yet
-    computed, priced by the worker's own timing model) would push a new
-    arrival's TTFT past ``budget_ms`` — the cost-model form of the disagg
-    handler's pool_load_probe."""
-
-    def probe() -> bool:
-        pend = sum(
-            max(s.prefill_span - s.computed, 0)
-            for s in engine.waiting + engine.running
-        )
-        return engine.args.prefill_ms(pend) > budget_ms
-
-    return probe
-
-
-def _decode_saturation_probe(engine, depth: int) -> Callable[[], bool]:
-    """Saturated = every decode slot is taken AND a queue is forming."""
-
-    def probe() -> bool:
-        backlog = len(engine.waiting) + len(engine.running)
-        return backlog >= engine.args.max_batch + depth
-
-    return probe
-
-
-async def _run_elastic_scenario(cfg: ElasticBenchConfig, mode: str) -> dict:
-    """One fleet, one mode, the shared pattern. Returns windows + totals."""
-    from dynamo_tpu.llm.mocker import MockTpuEngine
-    from dynamo_tpu.planner.controller import (
-        AutoscaleController,
-        ControllerConfig,
-        MockerCapacityModel,
-    )
-    from dynamo_tpu.planner.planner_core import ObservedLoad
-
-    args_a, args_b = cfg.worker_args(), cfg.worker_args()
-    a, b = MockTpuEngine(args_a), MockTpuEngine(args_b)
-    dial_timeline: List[dict] = []
-    stop_dial = asyncio.Event()
-    dial_task: Optional[asyncio.Task] = None
-
-    if mode == "disagg":
-        path: Any = DisaggPath(a, b)
-    elif mode == "colocated":
-        path = ColocatedPath([a, b])
-    elif mode == "elastic":
-        path = ElasticPath(
-            a, b,
-            prefill_saturated=_prefill_saturation_probe(a, cfg.slo_ttft_ms * 0.4),
-            decode_saturated=_decode_saturation_probe(b, cfg.saturation_depth),
-            note_degrade=lambda d, tgt: (b if tgt == "decode" else a).note_degrade(d),
-        )
-        controller = AutoscaleController(
-            ControllerConfig(
-                dial_deadband=0.02, dial_min_interval_s=cfg.dial_interval_s * 0.5,
-            ),
-            MockerCapacityModel(args_a, utilization=0.8),
-        )
-
-        async def actuate() -> None:
-            # The planner ratio actuator driven by the offered curve: the
-            # same decide_dial the AutoscaleLoop runs, fed the true mix
-            # (the observer's job in the full-plane autoscale bench).
-            start = time.monotonic()
-            while not stop_dial.is_set():
-                await asyncio.sleep(cfg.dial_interval_s)
-                t_rel = time.monotonic() - start
-                off = cfg.pattern.offered(min(t_rel, cfg.pattern.duration_s))
-                load = ObservedLoad(
-                    request_rate=off.rate, avg_isl=float(off.isl), avg_osl=float(off.osl)
-                )
-                d = controller.decide_dial(load, time.monotonic())
-                if d is not None:
-                    # Role-aware actuation: the dial only SHRINKS a budget
-                    # (both sides clamp at the configured base), so each
-                    # worker is dialed toward its role and never below the
-                    # configured identity on the axis it serves.
-                    applied_a = a.set_capacity_dial(max(d.fraction, 0.5))
-                    applied_b = b.set_capacity_dial(min(d.fraction, 0.5))
-                    dial_timeline.append({
-                        "t": round(t_rel, 2), "fraction": round(d.fraction, 3),
-                        "prefill_worker": applied_a, "decode_worker": applied_b,
-                    })
-
-        dial_task = asyncio.create_task(actuate())
-    else:
-        raise ValueError(f"unknown elastic bench mode {mode!r}")
-
-    harness = TrafficHarness(path, cfg.pattern)
-    try:
-        await harness.run()
-        await asyncio.sleep(cfg.settle_s)
-    finally:
-        stop_dial.set()
-        if dial_task is not None:
-            dial_task.cancel()
-            try:
-                await dial_task
-            except asyncio.CancelledError:
-                pass
-        for eng in (a, b):
-            stop = getattr(eng, "stop", None)
-            if stop is not None:
-                try:
-                    await stop()
-                except Exception:  # noqa: BLE001
-                    pass
-
-    windows = harness.windows(
-        window_s=2.0, slo_ttft_ms=cfg.slo_ttft_ms, slo_e2e_ms=cfg.slo_e2e_ms
-    )
-    totals = harness.totals()
-    done = [o for o in harness.outcomes if o.completed]
-    attained = sum(w["slo_attained"] for w in windows)
-    goodput_tok = sum(
-        o.tokens for o in done
-        if (o.ttft_s or 0.0) * 1000.0 <= cfg.slo_ttft_ms
-        and (o.e2e_s or 0.0) * 1000.0 <= cfg.slo_e2e_ms
-    )
-    out = {
-        "mode": mode,
-        "windows": windows,
-        "totals": totals,
-        "slo_attainment": round(attained / len(done), 4) if done else 0.0,
-        "goodput_tok_total": goodput_tok,
-        "stats": {
-            "a": {k: v for k, v in a.stats_handler().items()
-                  if k.startswith(("elastic_", "degrade_"))},
-            "b": {k: v for k, v in b.stats_handler().items()
-                  if k.startswith(("elastic_", "degrade_"))},
-        },
-    }
-    if mode == "elastic":
-        out["dial_timeline"] = dial_timeline
-        out["degrades"] = {
-            "to_decode_worker": path.degrades_to_decode,
-            "to_prefill_worker": path.degrades_to_prefill,
-        }
-    return out
-
-
-async def run_elastic_bench(cfg: Optional[ElasticBenchConfig] = None) -> dict:
-    """The ``elastic`` bench section: degrade-vs-queue TTFT/goodput curves
-    under a shifting ISL/OSL mix. CI asserts the elastic fleet's SLO
-    attainment AND goodput strictly dominate both static extremes, with
-    zero token loss in every mode."""
-    cfg = cfg or ElasticBenchConfig()
-    scenarios: Dict[str, dict] = {}
-    for mode in ("disagg", "colocated", "elastic"):
-        scenarios[mode] = await _run_elastic_scenario(cfg, mode)
-    el, dis, col = scenarios["elastic"], scenarios["disagg"], scenarios["colocated"]
-    return {
-        "pattern": asdict(cfg.pattern),
-        "slo": {"ttft_ms": cfg.slo_ttft_ms, "e2e_ms": cfg.slo_e2e_ms},
-        "scenarios": scenarios,
-        "summary": {
-            "slo_attainment": {m: scenarios[m]["slo_attainment"] for m in scenarios},
-            "goodput_tok_total": {m: scenarios[m]["goodput_tok_total"] for m in scenarios},
-            "token_loss": {m: scenarios[m]["totals"]["token_loss"] for m in scenarios},
-            "errors": {m: scenarios[m]["totals"]["errors"] for m in scenarios},
-            "degrades": el.get("degrades"),
-            "dial_moves": len(el.get("dial_timeline") or ()),
-            "elastic_dominates": (
-                el["slo_attainment"] > dis["slo_attainment"]
-                and el["slo_attainment"] > col["slo_attainment"]
-                and el["goodput_tok_total"] > dis["goodput_tok_total"]
-                and el["goodput_tok_total"] > col["goodput_tok_total"]
-            ),
-        },
-    }
 
 
 def main() -> None:
