@@ -10,7 +10,7 @@ from __future__ import annotations
 
 import dataclasses
 from dataclasses import dataclass
-from typing import Optional, Tuple
+from typing import NamedTuple, Optional, Tuple
 
 
 @dataclass(frozen=True)
@@ -206,6 +206,42 @@ class ModelConfig:
     # Residual merge with four learned vectors a sublayer:
     # x <- (gx * x + bx) + (gf * f + bf).
     residual_merge: bool = False
+    # "mla_full" and "mla_window" layers (``models/latent.py``): multi-head
+    # latent attention behind low-rank queries (``q_lora_rank``), with a
+    # sigmoid gate a head (``attention_gate``) and, where ``mla_lora_rescale``,
+    # both normed latents times sqrt(hidden_size / rank). A full layer reads
+    # ``num_heads``, ``kv_lora_rank``, ``qk_nope_head_dim``, ``qk_rope_head_dim``,
+    # ``v_head_dim`` and ``rope_theta`` and keeps one pool row a token (latent
+    # and rotated key on the ``k`` side, the indexer's key on the ``v`` side);
+    # its learned indexer (``index_n_heads`` heads of ``index_head_dim`` lanes)
+    # picks the ``index_topk`` cached rows a query attends. A window layer
+    # reads the ``swa_*`` sizes, attends the last ``sliding_window`` positions
+    # (the token itself included) and keeps them as a ring in the sequence's
+    # slot: no pool rows.
+    q_lora_rank: int = 0
+    mla_lora_rescale: bool = False
+    attention_gate: bool = False
+    index_n_heads: int = 0
+    index_head_dim: int = 0
+    index_topk: int = 0
+    sliding_window: int = 0
+    swa_num_heads: int = 0
+    swa_q_lora_rank: int = 0
+    swa_kv_lora_rank: int = 0
+    swa_qk_nope_head_dim: int = 0
+    swa_qk_rope_head_dim: int = 0
+    swa_v_head_dim: int = 0
+    swa_rope_theta: float = 0.0
+    # Router kind "sigmoid": scores are sigmoids (float32), the choice is the
+    # top-k of score + a stored correction bias, the weights are the chosen
+    # scores, normalised to sum 1 where ``norm_topk_prob``, times
+    # ``routed_scaling_factor``.
+    norm_topk_prob: bool = False
+    routed_scaling_factor: float = 1.0
+    # The first ``first_k_dense`` layers have a dense SwiGLU of
+    # ``dense_intermediate_size`` in place of the experts (latent kinds only).
+    first_k_dense: int = 0
+    dense_intermediate_size: int = 0
 
     def __post_init__(self):
         if self.attention_impl not in ("auto", "gather", "paged", "megakernel"):
@@ -263,21 +299,28 @@ class ModelConfig:
             or self.router_kind != "linear"
             or self.moe_skip_choice
             or self.residual_merge
+            or self.first_k_dense
+            or self.sliding_window
+            or self.index_topk
+            or self.attention_gate
         ):
             raise ValueError(
                 "a share of the experts, a shared expert, use_rope=False, attention_scale, the multipliers, "
-                "rope_fraction, a router kind, a skip choice and the residual merge "
+                "rope_fraction, a router kind, a skip choice, the residual merge, a dense first layer, a sliding "
+                "window, an indexer and a gate on the heads "
                 "are read by the layer-group step programs only: state layer_types"
             )
 
     def _check_hybrid(self) -> None:
         """What ``models/hybrid.py`` serves, and what it refuses by name."""
-        if len(self.layer_types) != self.num_layers or set(self.layer_types) - {"attention", "mamba", "cca"}:
+        kinds = {"attention", "mamba", "cca"} | set(LATENT_KINDS)
+        if len(self.layer_types) != self.num_layers or set(self.layer_types) - kinds:
             raise ValueError(
-                f"layer_types names 'attention', 'mamba' or 'cca' for each of num_layers={self.num_layers} layers, "
+                f"layer_types names one of {sorted(kinds)} for each of num_layers={self.num_layers} layers, "
                 f"got {self.layer_types!r}"
             )
         cca = "cca" in self.layer_types
+        latent = self.is_latent
         refused = {
             "architecture other than 'llama'": self.architecture != "llama",
             "attention_kind 'eva'": self.is_eva,
@@ -293,7 +336,19 @@ class ModelConfig:
                 not self.use_rope or self.attention_scale or self.num_experts_held
             ),
             "rope_fraction, router_kind 'zaya', a skip choice or the residual merge without 'cca' layers": not cca and (
-                self.rope_fraction != 1.0 or self.router_kind != "linear" or self.moe_skip_choice or self.residual_merge
+                self.rope_fraction != 1.0 or self.router_kind == "zaya" or self.moe_skip_choice or self.residual_merge
+            ),
+            "'mla_full' / 'mla_window' layers beside layers of another kind": latent and bool(
+                set(self.layer_types) - set(LATENT_KINDS)
+            ),
+            "'mla_full' / 'mla_window' layers with use_rope=False, an attention_scale or a multiplier off 1": latent and (
+                not self.use_rope or self.attention_scale
+                or (self.embedding_multiplier, self.residual_multiplier, self.logits_scaling) != (1.0, 1.0, 1.0)
+            ),
+            "router_kind 'sigmoid', a dense first layer, a sliding window, an indexer or a gate on the heads "
+            "without 'mla_full' / 'mla_window' layers": not latent and bool(
+                self.router_kind == "sigmoid" or self.first_k_dense or self.sliding_window or self.index_topk
+                or self.attention_gate
             ),
         }
         for what, hit in refused.items():
@@ -318,8 +373,10 @@ class ModelConfig:
                     "a 'cca' layer needs num_kv_heads dividing num_heads, an even num_kv_heads (half the value heads "
                     "are the shifted ones) and an even number of rotating lanes"
                 )
-        if self.router_kind not in ("linear", "zaya"):
-            raise ValueError(f"router_kind must be linear|zaya, got {self.router_kind!r}")
+        if latent:
+            self._check_latent()
+        if self.router_kind not in ("linear", "zaya", "sigmoid"):
+            raise ValueError(f"router_kind must be linear|zaya|sigmoid, got {self.router_kind!r}")
         if self.router_kind == "zaya" and (self.router_hidden_size <= 0 or self.num_experts_per_tok != 1 or not self.num_experts):
             raise ValueError("router_kind 'zaya' needs router_hidden_size > 0, experts, and num_experts_per_tok = 1")
         if self.moe_skip_choice and self.router_kind != "zaya":
@@ -330,6 +387,23 @@ class ModelConfig:
                 f"experts [{self.first_expert_held}, {self.first_expert_held + held}) are not among "
                 f"the router's {self.num_experts}"
             )
+
+    def _check_latent(self) -> None:
+        """Sizes the latent kinds need (``models/latent.py``)."""
+        for kind in set(self.layer_types):
+            z = self.latent_sizes(kind)
+            if min(z) <= 0 or z.rope % 2:
+                raise ValueError(f"a {kind!r} layer needs its heads, ranks, head sizes and rope_theta > 0 (rope lanes even), got {z}")
+        if "mla_full" in self.layer_types and (
+            min(self.index_n_heads, self.index_head_dim, self.index_topk) <= 0 or self.index_head_dim % 4
+        ):
+            raise ValueError("an 'mla_full' layer needs index_n_heads, index_head_dim (a multiple of 4) and index_topk > 0")
+        if "mla_window" in self.layer_types and self.sliding_window < 1:
+            raise ValueError("an 'mla_window' layer needs sliding_window >= 1 (the token itself counts)")
+        if not 0 <= self.first_k_dense <= self.num_layers or (self.first_k_dense and self.dense_intermediate_size <= 0):
+            raise ValueError("first_k_dense layers need dense_intermediate_size > 0")
+        if self.first_k_dense < self.num_layers and not self.num_experts:
+            raise ValueError("the layers past first_k_dense are expert layers: state num_experts")
 
     @property
     def q_size(self) -> int:
@@ -376,7 +450,38 @@ class ModelConfig:
         """Layers the paged pool holds rows for."""
         if not self.layer_types:
             return self.num_layers
-        return self.layer_types.count("attention") + self.num_cca_layers
+        return self.layer_types.count("attention") + self.num_cca_layers + self.layer_types.count("mla_full")
+
+    @property
+    def is_latent(self) -> bool:
+        """A stack of "mla_full" / "mla_window" layers (``models/latent.py``)."""
+        return bool(self.layer_types) and self.layer_types[0] in LATENT_KINDS
+
+    @property
+    def num_window_layers(self) -> int:
+        """Layers that keep a ring of ``sliding_window`` rows in a sequence's slot."""
+        return self.layer_types.count("mla_window")
+
+    def latent_sizes(self, kind: str) -> "LatentSizes":
+        """The sizes of one latent kind's attention."""
+        if kind == "mla_full":
+            return LatentSizes(self.num_heads, self.qk_nope_head_dim, self.qk_rope_head_dim, self.v_head_dim,
+                               self.q_lora_rank, self.kv_lora_rank, float(self.rope_theta))
+        return LatentSizes(self.swa_num_heads, self.swa_qk_nope_head_dim, self.swa_qk_rope_head_dim, self.swa_v_head_dim,
+                           self.swa_q_lora_rank, self.swa_kv_lora_rank, float(self.swa_rope_theta))
+
+    @property
+    def latent_groups(self) -> Tuple[Tuple[str, bool, int], ...]:
+        """``layer_types`` as ordered runs of one kind AND one FFN:
+        ((kind, dense FFN?, count), ...): one scan a run."""
+        groups: list = []
+        for l, kind in enumerate(self.layer_types):
+            dense = l < self.first_k_dense
+            if groups and groups[-1][:2] == [kind, dense]:
+                groups[-1][2] += 1
+            else:
+                groups.append([kind, dense, 1])
+        return tuple((k, d, n) for k, d, n in groups)
 
     @property
     def num_mamba_layers(self) -> int:
@@ -434,6 +539,32 @@ class ModelConfig:
 
     def replace(self, **kwargs) -> "ModelConfig":
         return dataclasses.replace(self, **kwargs)
+
+
+class LatentSizes(NamedTuple):
+    """One latent kind's attention: heads, a head's nope / rope / value lanes,
+    the queries' and the keys' ranks, the rope base. A cached row is
+    ``kv_rank + rope`` lanes."""
+
+    heads: int
+    nope: int
+    rope: int
+    value: int
+    q_rank: int
+    kv_rank: int
+    theta: float
+
+    @property
+    def row(self) -> int:
+        return self.kv_rank + self.rope
+
+    @property
+    def scale(self) -> float:
+        """The multiplier of a score: one over the root of a head's query lanes."""
+        return (self.nope + self.rope) ** -0.5
+
+
+LATENT_KINDS = ("mla_full", "mla_window")
 
 
 PRESETS = {
@@ -574,6 +705,49 @@ PRESETS = {
         router_hidden_size=16,
         moe_skip_choice=True,
         residual_merge=True,
+    ),
+    # Tiny config of the two latent kinds for unit tests: one full layer with a
+    # dense FFN, one full, three window layers; the indexer picks 8 rows, a
+    # ring holds 5; 16 experts top-2 by sigmoid scores of which 4 are held.
+    "tiny-dots3": ModelConfig(
+        name="tiny-dots3",
+        vocab_size=256,
+        hidden_size=64,
+        num_layers=5,
+        num_heads=4,
+        num_kv_heads=1,
+        head_dim=24,
+        intermediate_size=32,
+        max_seq_len=256,
+        block_size=8,
+        rope_theta=80000.0,
+        num_experts=16,
+        num_experts_per_tok=2,
+        num_experts_held=4,
+        shared_intermediate_size=32,
+        layer_types=("mla_full", "mla_full", "mla_window", "mla_window", "mla_window"),
+        q_lora_rank=32,
+        kv_lora_rank=32,
+        qk_nope_head_dim=16,
+        qk_rope_head_dim=8,
+        v_head_dim=16,
+        mla_lora_rescale=True,
+        attention_gate=True,
+        index_n_heads=4,
+        index_head_dim=16,
+        index_topk=8,
+        sliding_window=5,
+        swa_num_heads=2,
+        swa_q_lora_rank=32,
+        swa_kv_lora_rank=48,
+        swa_qk_nope_head_dim=24,
+        swa_qk_rope_head_dim=8,
+        swa_v_head_dim=16,
+        swa_rope_theta=5000.0,
+        router_kind="sigmoid",
+        norm_topk_prob=True,
+        first_k_dense=1,
+        dense_intermediate_size=96,
     ),
     # Tiny MLA config (DeepSeek-style latent attention) for unit tests.
     "tiny-mla": ModelConfig(

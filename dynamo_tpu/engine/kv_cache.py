@@ -75,7 +75,19 @@ class SlotKv(NamedTuple):
     ``[L_cca, S, cca_slot_lanes]`` (the first convolution's last input, the
     second's, and the last token's projection for the shifted value heads,
     side by side on the lanes), and ``k`` carries an array of no elements
-    ``[L_cca, S, 0]`` beside ``slot_of``."""
+    ``[L_cca, S, 0]`` beside ``slot_of``.
+
+    A stack of latent layers ("mla_full" / "mla_window", ``models/latent.py``)
+    has a pool row a token in its full layers only, and its width is the
+    kind's, not ``KVH*HD``: the ``k`` pool holds ``[latent ; rotated key]``
+    (``kv_lora_rank + qk_rope_head_dim`` lanes, and zeros up to whole tiles of
+    128) and the ``v`` pool the
+    indexer's key (``index_head_dim`` lanes), both ``[L_full, N, BS, lanes]``.
+    A window layer holds no blocks: its last ``sliding_window`` rows are a
+    ring in the sequence's slot, ``v`` slots ``[L_window, S, sliding_window,
+    swa_kv_lora_rank + swa_qk_rope_head_dim]`` (position ``t`` lies in row ``t
+    mod sliding_window``), and ``k`` carries ``[L_window, S, 0]`` beside
+    ``slot_of``."""
 
     pool: Any  # jax.Array — [L_a, N, BS, KVH*HD]
     slots: jax.Array
@@ -208,6 +220,8 @@ class KvCacheArrays:
         beside the pool, scratch slot 0 included: ``SlotKv``)."""
         if sharding is not None:
             config.refuse_for_layer_types("a sharded cache (a mesh)")
+        if config.is_latent:
+            return cls._create_latent(config, num_blocks, dtype, num_slots)
         if config.architecture == "mla":
             # MLA stores one shared latent row per token (kv_lora_rank +
             # rope dim) in ``k``; ``v`` is a placeholder (values decompress
@@ -243,6 +257,25 @@ class KvCacheArrays:
             k = SlotKv(k, state, jnp.zeros((num_blocks,), jnp.int32))
             v = SlotKv(v, columns)
         return cls(k=k, v=v, kv_heads=kv_heads)
+
+
+    @classmethod
+    def _create_latent(cls, config: ModelConfig, num_blocks: int, dtype, num_slots: int) -> "KvCacheArrays":
+        """The cache of a stack of latent layers (``SlotKv``)."""
+        if config.kv_cache_dtype == "int8":
+            config.refuse_for_layer_types("kv_cache_dtype 'int8'")
+        if num_slots < 2:
+            raise ValueError("a hybrid model's cache needs the scratch slot and at least one more (num_slots >= 2)")
+        c, Lf, Lw = config, config.num_attention_layers, config.num_window_layers
+        rows = (Lf, num_blocks, c.block_size)
+        ring = c.latent_sizes("mla_window").row if Lw else 0
+        # Whole 128-lane tiles: a buffer whose last axis is not gets its TOKENS on the lanes from the runtime, and every
+        # step program then re-lays the whole pool on entry and on exit (5.8 ms each way at 1.8 GB: PERF.md section 6, PR 50).
+        k = SlotKv(jnp.zeros((*rows, -(-c.latent_sizes("mla_full").row // 128) * 128 if Lf else 0), dtype),
+                   jnp.zeros((Lw, num_slots, 0), dtype), jnp.zeros((num_blocks,), jnp.int32))
+        v = SlotKv(jnp.zeros((*rows, c.index_head_dim if Lf else 0), dtype),
+                   jnp.zeros((Lw, num_slots, c.sliding_window, ring), dtype))
+        return cls(k=k, v=v, kv_heads=1)
 
 
 class OutOfBlocksError(Exception):

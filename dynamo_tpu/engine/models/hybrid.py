@@ -50,6 +50,30 @@ attention path are those of a GQA layer of ``num_heads``/``num_kv_heads``
 heads. The ZAYA router's state is a second carry of the layer scan beside
 ``h``; it is float32 from the down-projection on.
 
+The latent kinds "mla_full" and "mla_window" (``latent.py`` has their mixers
+and says what each caches) go through ``_drive_latent``: a stack of them alone
+(``ModelConfig.is_latent``), its runs those of one kind AND one FFN
+(``ModelConfig.latent_groups``: the first ``first_k_dense`` layers have a dense
+SwiGLU, the others the expert FFN behind ``_sigmoid_route``). A full layer
+writes the step's rows into the pool before it attends, so the pools ride the
+layer scans and a multi-step window's loop with the rings, written in place;
+the counts gain ``indexed_rows`` and ``index_ctx`` (``LATENT_AUX_KEYS``).
+
+Two drivers, and why they stay two. ``_drive`` hands its mixers an ``attend``
+closure over a pool that no layer of the step writes (the step's new rows
+come back from the scan and ``_write`` scatters them once, after the stack; a
+window keeps them in a small carry): the pool is an operand of the scan and is
+never copied. A latent layer's index keys and latent row must be IN the pool
+before the layer attends (a query may choose its own row, and a chunk's rows
+choose among the chunk's), so ``_drive_latent`` carries the pool through every
+scan and the pool's layer axis is the scan's. Giving ``_drive`` that carry would
+make every attention and cca layer of the other families scan over the whole
+pool for nothing. What the two share is shared by calls: the expert FFN
+(``_ffn``), embedding and head, and the multi-step window (``_window``:
+sampling, the window's tokens and logits, the counts' sums) around each
+driver's one step. Each driver names its counts beside it (``AUX_KEYS``,
+``LATENT_AUX_KEYS``); ``_window`` takes the names from its caller.
+
 Not built for this kind, and refused by name
 (``ModelConfig.refuse_for_layer_types``): prefix-block reuse,
 KV export/injection and the KVBM tiers, speculative verification and rollback,
@@ -67,7 +91,7 @@ from jax import lax
 
 from dynamo_tpu.engine.config import ModelConfig
 from dynamo_tpu.engine.kv_cache import SlotKv, layer_flat, ragged_scatter_targets
-from dynamo_tpu.engine.models import llama
+from dynamo_tpu.engine.models import latent, llama
 from dynamo_tpu.engine.models.llama import (  # noqa: F401 — the scheduler reads the resolvers off its model module
     Params,
     _attend_piece,
@@ -104,6 +128,8 @@ def init_params(config: ModelConfig, key: jax.Array, dtype=jnp.bfloat16) -> Para
     taps stacked, the earlier token's on top. The ZAYA router's tensors past
     its down-projection, and the keys' temperatures, are float32."""
     c = config
+    if c.is_latent:
+        return _init_latent(c, key, dtype)
     D, L, La, Lm = c.hidden_size, c.num_layers, c.num_attention_layers, c.num_mamba_layers
     ks = iter(jax.random.split(key, 24))
 
@@ -182,6 +208,43 @@ def init_params(config: ModelConfig, key: jax.Array, dtype=jnp.bfloat16) -> Para
         }
     if not c.tie_word_embeddings:
         params["lm_head"] = dense((D, c.vocab_size), scale=0.02)
+    return params
+
+
+def _init_latent(c: ModelConfig, key: jax.Array, dtype) -> Params:
+    """Random weights (testing) of a stack of latent layers: ``mla_full`` and
+    ``mla_window`` the mixers of each kind (``latent.init_mixer``), ``dense``
+    the FFN of the first ``first_k_dense`` layers, ``layers`` the expert FFN of
+    the others (``router_bias``, the stored correction, float32)."""
+    D, Ld, F, E = c.hidden_size, c.first_k_dense, c.intermediate_size, c.experts_held
+    Le = c.num_layers - Ld
+    ks = iter(jax.random.split(key, 24))
+
+    def dense(shape, scale=None):
+        scale = shape[-2] ** -0.5 if scale is None else scale
+        return (jax.random.normal(next(ks), shape, dtype=jnp.float32) * scale).astype(dtype)
+
+    def gain(shape):
+        return (1.0 + 0.1 * jax.random.normal(next(ks), shape, dtype=jnp.float32)).astype(dtype)
+
+    params: Params = {"embed": dense((c.vocab_size, D), 0.02), "final_norm": gain((D,))}
+    for kind in latent.LATENT_KINDS:
+        if kind in c.layer_types:
+            params[kind] = latent.init_mixer(c, kind, c.layer_types.count(kind), next(ks), dtype)
+    if Ld:
+        Fd = c.dense_intermediate_size
+        params["dense"] = {"mlp_norm": gain((Ld, D)), "w_gate": dense((Ld, D, Fd)), "w_up": dense((Ld, D, Fd)),
+                           "w_down": dense((Ld, Fd, D))}
+    if Le:
+        layers = {"mlp_norm": gain((Le, D)), "router": dense((Le, D, c.num_experts), 4.0 * D ** -0.5),
+                  "router_bias": 0.02 * jax.random.normal(next(ks), (Le, c.num_experts), dtype=jnp.float32),
+                  "w_gate": dense((Le, E, D, F)), "w_up": dense((Le, E, D, F)), "w_down": dense((Le, E, F, D))}
+        if c.shared_intermediate_size:
+            Fs = c.shared_intermediate_size
+            layers.update(shared_gate=dense((Le, D, Fs)), shared_up=dense((Le, D, Fs)), shared_down=dense((Le, Fs, D)))
+        params["layers"] = layers
+    if not c.tie_word_embeddings:
+        params["lm_head"] = dense((D, c.vocab_size), 0.02)
     return params
 
 
@@ -543,13 +606,7 @@ def _mamba_mixer(c: ModelConfig, lp, lm, x, ssm, conv, slots, chunk, wdtype):
 # --- the cca mixer -----------------------------------------------------------
 
 
-def _wide(eq: str, a: jax.Array, b: jax.Array) -> jax.Array:
-    """``einsum(eq, a, b)`` with a float32 result of compute-dtype operands: on a
-    TPU the product leaves the MXU wide; elsewhere (XLA:CPU has no bf16 x bf16
-    = f32 dot) the operands are widened first."""
-    if llama._on_tpu():
-        return jnp.einsum(eq, a, b, preferred_element_type=jnp.float32)
-    return jnp.einsum(eq, a.astype(jnp.float32), b.astype(jnp.float32))
+_wide = latent.wide  # einsum with a float32 result of compute-dtype operands
 
 
 def _unit(x: jax.Array) -> jax.Array:
@@ -644,6 +701,21 @@ def _zaya_route(c: ModelConfig, lp, x: jax.Array, s: jax.Array):
         return jnp.take_along_axis(p, ids, axis=-1), ids, r
 
 
+def _sigmoid_route(c: ModelConfig, x: jax.Array, lp):
+    """The sigmoid router on the expert layer's input ``x [R, D]``: scores
+    ``sigmoid(x W_r)`` in float32 at full precision (a top-k boundary turns on
+    rounding), the choice the top-k of score + the stored correction bias, the
+    weights the chosen scores (summing to 1 where ``norm_topk_prob``) times
+    ``routed_scaling_factor``. Returns ``(weights [R, K], ids [R, K])``."""
+    with jax.named_scope("sigmoid_router"):
+        s = jax.nn.sigmoid(jnp.dot(x.astype(jnp.float32), lp["router"].astype(jnp.float32), precision=_HI))
+        _, ids = lax.top_k(s + lp["router_bias"], c.num_experts_per_tok)
+        w = jnp.take_along_axis(s, ids, axis=-1)
+        if c.norm_topk_prob:
+            w = w / jnp.sum(w, axis=-1, keepdims=True)
+        return w * c.routed_scaling_factor, ids.astype(jnp.int32)
+
+
 # --- the stack ---------------------------------------------------------------
 
 
@@ -681,9 +753,11 @@ def _ffn(c: ModelConfig, scanned, experts, h, l, valid, wdtype, stats):
         if c.router_kind == "zaya":
             weights, ids, r = _zaya_route(c, lp, _norm(c, h, lp["mlp_norm"], jnp.float32), stats[3])
             route = lambda *_: (weights, ids)  # noqa: E731 - routed already: the state had to come out
+        elif c.router_kind == "sigmoid":
+            route = functools.partial(_sigmoid_route, c)
         out, held, visited = _moe_held(x, lp, c, valid, experts, l, route=route)
         counts = (held, visited)
-        if route:
+        if c.router_kind == "zaya":
             counts, state = counts + (jnp.sum(valid & (ids[:, 0] == c.num_experts)).astype(jnp.int32),), (r,)
     else:
         out = (jax.nn.silu(x @ lp["w_gate"]) * (x @ lp["w_up"])) @ lp["w_down"]
@@ -752,6 +826,86 @@ def _drive(c: ModelConfig, params: Params, h, ssm, conv, attend, positions, slot
     return h, ssm, conv, jnp.concatenate(k_rows), jnp.concatenate(v_rows), dict(zip(AUX_KEYS, stats[:3]))
 
 
+# --- the latent kinds' stack ---------------------------------------------------
+
+LATENT_AUX_KEYS = ("held_assignments", "experts_visited", "indexed_rows", "index_ctx")  # the step log's names of a latent stack's counts
+
+
+def _drive_latent(c: ModelConfig, params: Params, h, k_cache: SlotKv, v_cache: SlotKv, positions, blocks, offs, rows, chunk,
+                  valid, wdtype):
+    """``h`` through a stack of latent layers (``latent.py``), run by run
+    (``ModelConfig.latent_groups``: one kind, one FFN), each run one scan.
+    ``blocks``/``offs`` ``[R]`` are where every row's pool rows go (a full
+    layer writes them before it attends); ``chunk = (T, table [W], valid_len)``
+    is the wide row, if any, and ``rows = (tables [B, W], active [B])`` the
+    length-1 rows after it. The pools and the rings are carried through the
+    scans and written in place. Returns ``(h, k_cache, v_cache, aux)``:
+    ``aux`` the expert layer's counts and, of the length-1 rows' full layers,
+    the rows chosen and the rows scored."""
+    Ld = c.first_k_dense
+    scanned, experts = _split_expert_stacks(c, params["layers"]) if c.num_layers > Ld else (None, None)
+    k_flat, i_flat = layer_flat(k_cache.pool), layer_flat(v_cache.pool)
+    rings = layer_flat(v_cache.slots)
+    N, S = k_cache.pool.shape[1], v_cache.slots.shape[1]
+    T = chunk[0] if chunk is not None else 0
+    if chunk is not None:
+        chunk_slot = _slots_of(k_cache, chunk[1])
+    if rows is not None:
+        row_slots = jnp.where(rows[1], _slots_of(k_cache, rows[0]), 0)
+
+    def ffn(dense: bool, h, l, stats):
+        if not dense:
+            return _ffn(c, scanned, experts, h, l - Ld, valid, wdtype, stats)
+        lp = _at(params["dense"], l)
+        x = _norm(c, h, lp["mlp_norm"], wdtype)
+        with jax.named_scope("dense_ffn"):
+            return h + (jax.nn.silu(x @ lp["w_gate"]) * (x @ lp["w_up"])) @ lp["w_down"], stats
+
+    def layer(kind: str, dense: bool):
+        z, full = c.latent_sizes(kind), kind == "mla_full"
+
+        def body(carry, idx):
+            h, (k_flat, i_flat, rings), stats, seen = carry
+            l, lk = idx  # the layer, and its place among the layers of its kind
+            lp = _at(params[kind], lk)
+            x = _norm(c, h, lp["attn_norm"], wdtype)
+            q, row, c_q = latent.project(c, z, lp, x, positions)
+            if full:  # the step's rows go into the pool first: a chunk's own rows and a decode row's own are cached rows
+                k_flat = k_flat.at[lk * N + blocks, offs].set(latent.to_lanes(row, k_flat.shape[-1]).astype(k_flat.dtype))
+                i_flat = i_flat.at[lk * N + blocks, offs].set(latent.index_key(c, lp, x, positions).astype(i_flat.dtype))
+            lats = []
+            if chunk is not None and full:
+                lats.append(latent.full_chunk(c, z, lp, q[:T], c_q[:T], x[:T], positions[:T], chunk[1] + lk * N, k_flat, i_flat))
+            elif chunk is not None:
+                lat, rings = latent.window_chunk(z, q[:T], row[:T], positions[:T], lk * S + chunk_slot, chunk[2], rings)
+                lats.append(lat)
+            if rows is not None and full:
+                lat, chosen, scored = latent.full_rows(
+                    c, z, lp, q[T:], c_q[T:], x[T:], positions[T:], rows[0] + lk * N, rows[1], k_flat, i_flat)
+                lats.append(lat)
+                seen = (seen[0] + chosen.astype(jnp.int32), seen[1] + scored.astype(jnp.int32))
+            elif rows is not None:
+                lat, rings = latent.window_rows(z, q[T:], row[T:], positions[T:], lk * S + row_slots, rows[1], rings)
+                lats.append(lat)
+            h = h + latent.output(c, z, lp, x, jnp.concatenate(lats) if len(lats) > 1 else lats[0])
+            h, stats = ffn(dense, h, l, stats)
+            return (h, (k_flat, i_flat, rings), stats, seen), None
+
+        return body
+
+    carry = (h, (k_flat, i_flat, rings), (jnp.int32(0), jnp.int32(0)), (jnp.int32(0), jnp.int32(0)))
+    l0, at = 0, {"mla_full": 0, "mla_window": 0}
+    for kind, dense, count in c.latent_groups:
+        layer_ids = jnp.arange(l0, l0 + count, dtype=jnp.int32)
+        carry, _ = lax.scan(layer(kind, dense), carry, (layer_ids, layer_ids - l0 + at[kind]))
+        at[kind] += count
+        l0 += count
+    h, (k_flat, i_flat, rings), stats, seen = carry
+    return (h, k_cache._replace(pool=k_flat.reshape(k_cache.pool.shape)),
+            v_cache._replace(pool=i_flat.reshape(v_cache.pool.shape), slots=rings.reshape(v_cache.slots.shape)),
+            dict(zip(LATENT_AUX_KEYS, stats + seen)))
+
+
 def _slots_of(k_cache: SlotKv, tables: jax.Array) -> jax.Array:
     """The slot of each row: that of the sequence whose table begins with the
     row's first block (``open_slot``); a table of zeros gives scratch slot 0."""
@@ -796,6 +950,10 @@ def prefill(
     positions = cache_len + jnp.arange(T, dtype=jnp.int32)
     valid_q = jnp.arange(T, dtype=jnp.int32) < valid_len
     blocks, offs = ragged_scatter_targets(block_table, positions, valid_q, c.block_size)
+    if c.is_latent:
+        h, k_new, v_new, aux = _drive_latent(
+            c, params, h, k_cache, v_cache, positions, blocks, offs, None, (T, block_table, valid_len), valid_q, wdtype)
+        return _logits(c, params, h if all_logits else h[jnp.maximum(valid_len - 1, 0)], wdtype), k_new, v_new, aux
     attend = _chunk_attention(c, k_cache.pool, v_cache.pool, block_table, cache_len, valid_len, T, use_flash, has_prefix)
     chunk = (T, _slots_of(k_cache, block_table), valid_len)
     h, ssm, conv, k_rows, v_rows, aux = _drive(
@@ -820,6 +978,10 @@ def decode(
     c = config
     h, wdtype = _embed(c, params, tokens)
     blocks, offs, _ = decode_targets(positions, block_tables, active, c.block_size)
+    if c.is_latent:
+        h, k_new, v_new, aux = _drive_latent(
+            c, params, h, k_cache, v_cache, positions, blocks, offs, (block_tables, active), None, active, wdtype)
+        return _logits(c, params, h, wdtype), k_new, v_new, aux
     attend = _rows_attention(c, k_cache.pool, v_cache.pool, block_tables, positions, active)
     slots = jnp.where(active, _slots_of(k_cache, block_tables), 0)
     h, ssm, conv, k_rows, v_rows, aux = _drive(
@@ -854,6 +1016,15 @@ def mixed_step(
     p_positions = p_cache_len + jnp.arange(S, dtype=jnp.int32)
     p_valid_q = jnp.arange(S, dtype=jnp.int32) < p_valid
     h, wdtype = _embed(c, params, jnp.concatenate([p_tokens, d_tokens]))
+    if c.is_latent:
+        p_blocks, p_offs = ragged_scatter_targets(p_table, p_positions, p_valid_q, c.block_size)
+        d_blocks, d_offs, _ = decode_targets(d_positions, d_tables, d_active, c.block_size)
+        h, k_new, v_new, aux = _drive_latent(
+            c, params, h, k_cache, v_cache, jnp.concatenate([p_positions, d_positions]),
+            jnp.concatenate([p_blocks, d_blocks]), jnp.concatenate([p_offs, d_offs]),
+            (d_tables, d_active), (S, p_table, p_valid), jnp.concatenate([p_valid_q, d_active]), wdtype)
+        rows = jnp.concatenate([h[jnp.maximum(p_valid - 1, 0)][None], h[S:]], axis=0)
+        return _logits(c, params, rows, wdtype), k_new, v_new, aux
     p_attend = _chunk_attention(c, k_cache.pool, v_cache.pool, p_table, p_cache_len, p_valid, S, use_flash, has_prefix)
     d_attend = _rows_attention(c, k_cache.pool, v_cache.pool, d_tables, d_positions, d_active)
 
@@ -898,37 +1069,27 @@ def decode_multi(
     v_cache, aux)``. The pool is read-only for the window (its rows ride a
     small carry and one scatter writes them at the end); the slot arrays are
     carried through the loop and advanced in place at every step."""
-    from dynamo_tpu.engine.sampling import sample_batch
-
     c = config
+    if c.is_latent:
+        return _latent_window(params, c, k_cache, v_cache, tokens, positions, block_tables, active, temps, top_ks, top_ps,
+                              rng_key, num_steps, return_logits)
     B, La, KVH, HD, bs = tokens.shape[0], c.num_attention_layers, c.num_kv_heads, c.head_dim, c.block_size
     wdtype = params["embed"].dtype
     slots = jnp.where(active, _slots_of(k_cache, block_tables), 0)
 
-    def body(i, carry):
-        toks, ssm, conv, k_win, v_win, out, lg_out, key, counts = carry
+    def step(i, toks, state):
+        ssm, conv, k_win, v_win = state
         h, _ = _embed(c, params, toks)
         attend = _rows_attention(c, k_cache.pool, v_cache.pool, block_tables, positions, active, window=num_steps, step=i)
         h, ssm, conv, k_rows, v_rows, aux = _drive(
             c, params, h, ssm, conv, attend, positions + i, slots, None, active, wdtype, window=(k_win, v_win)
         )
-        k_win, v_win = k_win.at[:, i].set(k_rows), v_win.at[:, i].set(v_rows)
-        logits = _logits(c, params, h, wdtype)
-        key, sub = jax.random.split(key)
-        nxt = sample_batch(logits, temps, top_ks, top_ps, sub).astype(jnp.int32)
-        if return_logits:
-            lg_out = lg_out.at[i].set(logits)
-        return (nxt, ssm, conv, k_win, v_win, out.at[i].set(nxt), lg_out, key,
-                tuple(n + aux[name] for n, name in zip(counts, AUX_KEYS)))
+        return h, (ssm, conv, k_win.at[:, i].set(k_rows), v_win.at[:, i].set(v_rows)), aux
 
     win0 = jnp.zeros((La, num_steps, B, KVH, HD), dtype=wdtype)
-    V = params["embed"].shape[0]
-    lg0 = jnp.zeros((num_steps if return_logits else 1, B, V if return_logits else 1), jnp.float32)
-    _, ssm, conv, k_win, v_win, out, lg_steps, _, counts = lax.fori_loop(
-        0, num_steps, body,
-        (tokens, k_cache.slots, v_cache.slots, win0, win0, jnp.zeros((num_steps, B), jnp.int32), lg0, rng_key,
-         _stats0(c, B)[:3]),
-    )
+    (ssm, conv, k_win, v_win), out, lg_steps, aux = _window(
+        c, params, step, (k_cache.slots, v_cache.slots, win0, win0), tokens, temps, top_ks, top_ps, rng_key, num_steps,
+        return_logits, AUX_KEYS, _stats0(c, B)[:3])
     # One scatter for the whole window: row (la, j, b) -> position_b + j.
     steps_i = jnp.arange(num_steps, dtype=jnp.int32)
     live = jnp.broadcast_to(active[None, :], (num_steps, B))
@@ -938,7 +1099,54 @@ def decode_multi(
         k_cache, v_cache, ssm, conv, k_win.reshape(La, num_steps * B, KVH, HD), v_win.reshape(La, num_steps * B, KVH, HD),
         blocks.reshape(-1), (rows % bs).reshape(-1),
     )
-    aux = dict(zip(AUX_KEYS, counts))
+    if return_logits:
+        return out, lg_steps, k_new, v_new, aux
+    return out, k_new, v_new, aux
+
+
+def _window(c, params, step, state0, tokens, temps, top_ks, top_ps, rng_key, num_steps: int, return_logits: bool, aux_keys, counts0):
+    """The loop of a multi-step window, for both drivers: ``step(i, tokens,
+    state) -> (h, state, aux)`` is one decode step of the stack on the state the
+    loop carries; the head, the sampler, the window's tokens (and logits) and
+    the sums of the step log's counts are here. Returns ``(state, tokens_out
+    [num_steps, B], logits, aux)``."""
+    from dynamo_tpu.engine.sampling import sample_batch
+
+    B, V = tokens.shape[0], params["embed"].shape[0]
+    wdtype = params["embed"].dtype
+
+    def body(i, carry):
+        toks, *state, out, lg_out, key, counts = carry
+        h, state, aux = step(i, toks, tuple(state))
+        logits = _logits(c, params, h, wdtype)
+        key, sub = jax.random.split(key)
+        nxt = sample_batch(logits, temps, top_ks, top_ps, sub).astype(jnp.int32)
+        if return_logits:
+            lg_out = lg_out.at[i].set(logits)
+        return (nxt, *state, out.at[i].set(nxt), lg_out, key, tuple(n + aux[name] for n, name in zip(counts, aux_keys)))
+
+    lg0 = jnp.zeros((num_steps if return_logits else 1, B, V if return_logits else 1), jnp.float32)
+    _, *state, out, lg_steps, _, counts = lax.fori_loop(
+        0, num_steps, body, (tokens, *state0, jnp.zeros((num_steps, B), jnp.int32), lg0, rng_key, tuple(counts0)))
+    return tuple(state), out, lg_steps, dict(zip(aux_keys, counts))
+
+
+def _latent_window(params, c, k_cache, v_cache, tokens, positions, block_tables, active, temps, top_ks, top_ps, rng_key,
+                   num_steps: int, return_logits: bool):
+    """``decode_multi`` of a stack of latent layers: every step is a ``decode``
+    step (its rows written into the pool and the rings before it attends), so
+    the whole cache is the state ``_window`` carries, written in place."""
+    wdtype = params["embed"].dtype
+
+    def step(i, toks, state):
+        h, _ = _embed(c, params, toks)
+        blocks, offs, _ = decode_targets(positions + i, block_tables, active, c.block_size)
+        h, k, v, aux = _drive_latent(c, params, h, *state, positions + i, blocks, offs, (block_tables, active), None, active, wdtype)
+        return h, (k, v), aux
+
+    (k_new, v_new), out, lg_steps, aux = _window(
+        c, params, step, (k_cache, v_cache), tokens, temps, top_ks, top_ps, rng_key, num_steps, return_logits,
+        LATENT_AUX_KEYS, (jnp.int32(0),) * len(LATENT_AUX_KEYS))
     if return_logits:
         return out, lg_steps, k_new, v_new, aux
     return out, k_new, v_new, aux

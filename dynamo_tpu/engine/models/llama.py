@@ -607,6 +607,8 @@ def resolve_attention_impl(c: ModelConfig, k_cache) -> str:
       serves that mesh (``warn_attention_impl_degrade`` says so once).
     """
     impl = c.attention_impl
+    if c.is_latent:
+        return "gather"  # the latent kinds attend through XLA's gather of the table (models/latent.py): no kernel takes their rows
     if impl == "auto":
         impl = "megakernel" if _on_tpu() else "gather"
     if impl != "gather" and kernel_shards(c.num_kv_heads) == 0:
@@ -625,6 +627,8 @@ def resolve_prefill_impl(c: ModelConfig) -> str:
     the Pallas flash kernel on TPU; a ``tp`` mesh whose KV heads do not
     divide takes the XLA path (see ``resolve_attention_impl``)."""
     impl = c.prefill_impl
+    if c.is_latent:
+        return "xla"
     if impl == "auto":
         impl = "flash" if _on_tpu() else "xla"
     if impl == "flash" and kernel_shards(c.num_kv_heads) == 0:

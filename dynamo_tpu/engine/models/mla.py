@@ -19,6 +19,18 @@ TPU-first design:
 Cache layout: k_cache [L, N, BS, R] with R = kv_lora_rank +
 qk_rope_head_dim — one "head" of width R in the pool's merged-lane layout
 (``KvCacheArrays``); v_cache is unused (shape [L, 1, 1, 1]).
+
+Which latent path the scheduler serves. This module is the OLDER, whole-stack
+family (``ModelConfig.architecture == "mla"``): every layer alike, full-rank
+queries, ``prefill`` / ``decode`` / ``decode_multi`` through the XLA gather
+and none of the scheduler's fast paths (no mixed steps, no slots); it is held
+by ``tests/test_mla.py`` on the CPU and no benchmark cell runs it. The latent
+layers the benchmark serves are the layer-group kinds "mla_full" and
+"mla_window" of ``ModelConfig.layer_types`` (``models/latent.py``, driven by
+``models/hybrid.py``): low-rank queries, a gate a head, a learned indexer or a
+sliding window, through ``mixed_step`` and the slot life-cycle. The absorbed
+products are written once, there: this module's rows go through
+``latent.absorb`` and ``latent.attend``.
 """
 
 from __future__ import annotations
@@ -31,6 +43,7 @@ from jax import lax
 
 from dynamo_tpu.engine.config import ModelConfig
 from dynamo_tpu.engine.kv_cache import layer_flat
+from dynamo_tpu.engine.models.latent import absorb, attend
 from dynamo_tpu.engine.models.llama import _gather_kv, _scatter_kv, _mlp, _split_expert_stacks, apply_rope, rms_norm
 
 Params = Dict[str, jax.Array]
@@ -93,8 +106,7 @@ def _project_q(x: jax.Array, lp, c: ModelConfig, positions: jax.Array) -> Tuple[
     q = (x @ lp["wq"]).reshape(T, c.num_heads, qk)
     q_nope = q[..., : c.qk_nope_head_dim]
     q_rope = apply_rope(q[..., c.qk_nope_head_dim :], positions, c.rope_theta)
-    q_eff = jnp.einsum("thn,hnr->thr", q_nope, lp["w_uk"])  # absorb W_uk
-    return q_eff, q_rope
+    return absorb(q_nope, lp["w_uk"]), q_rope
 
 
 def _latent_kv(x: jax.Array, lp, c: ModelConfig, positions: jax.Array) -> jax.Array:
@@ -112,16 +124,10 @@ def _attend_latent(
     lp,
     c: ModelConfig,
 ) -> jax.Array:
-    """→ [T, H * v_head_dim]."""
-    r = c.kv_lora_rank
-    c_kv, k_rope = latent[:, :r], latent[:, r:]
+    """→ [T, H * v_head_dim]. The absorbed product is ``latent.attend``'s:
+    one set of rows for all queries, or (``latent [T, S, R]``) a set a query."""
     scale = (c.qk_nope_head_dim + c.qk_rope_head_dim) ** -0.5
-    scores = (
-        jnp.einsum("thr,sr->ths", q_eff, c_kv) + jnp.einsum("the,se->ths", q_rope, k_rope)
-    ).astype(jnp.float32) * scale
-    scores = jnp.where(mask[:, None, :], scores, -1e30)
-    probs = jax.nn.softmax(scores, axis=-1).astype(q_eff.dtype)
-    attn_lat = jnp.einsum("ths,sr->thr", probs, c_kv)  # weighted latent sum
+    attn_lat = attend(jnp.concatenate([q_eff, q_rope], axis=-1), latent, mask, c.kv_lora_rank, scale)  # weighted latent sum
     out = jnp.einsum("thr,hrv->thv", attn_lat, lp["w_uv"])  # decompress once
     return out.reshape(q_eff.shape[0], c.num_heads * c.v_head_dim)
 

@@ -455,8 +455,9 @@ class Scheduler:
             logger.info("model %r: prefix-block reuse is not built for it, prefix caching is off", model_config.name)
             self.sc.enable_prefix_caching = False
         # What a slot holds names its gauges and step-log counts: recurrent state
-        # ("ssm") or, for a stack of cca layers, the convolutions' columns ("cca").
-        self._slot_kind = "cca" if model_config.num_cca_layers else "ssm"
+        # ("ssm"), for a stack of cca layers the convolutions' columns ("cca"), for
+        # a stack of latent layers the window layers' rings ("window").
+        self._slot_kind = "window" if model_config.is_latent else "cca" if model_config.num_cca_layers else "ssm"
         self.moe_skipped_rows_total = 0  # rows x layers that drew the ZAYA router's skip choice
         self.slots: Optional[SlotAllocator] = None
         if self._hybrid:
@@ -1089,7 +1090,7 @@ class Scheduler:
                 "cached": a.num_cached,
                 "active": a.num_active,
                 "usage": round(a.usage(), 6),
-                **{k: v for k, v in self.kv_gauges().items() if k == "kv_fragmentation" or k.startswith(("eva_", "ssm_", "cca_", "moe_skipped_"))},
+                **{k: v for k, v in self.kv_gauges().items() if k == "kv_fragmentation" or k.startswith(("eva_", "ssm_", "cca_", "window_", "moe_skipped_"))},
             },
             "digests": self.telemetry.summary(),
             "slo": self.slo.to_stats(),
@@ -1291,6 +1292,9 @@ class Scheduler:
                 # Slots the dispatch advances (its decode rows' and its chunk's), and slots held.
                 span.set(**{f"{self._slot_kind}_rows": len(batch) + (1 if kind == "mixed" else 0),
                             f"{self._slot_kind}_slots": self.slots.in_use})
+                if self.mc.is_latent:
+                    # Blocks live sequences hold: the full layers' rows alone, the rings hold none.
+                    span.set(pool_blocks=self.sc.num_blocks - 1 - self.allocator.num_free)
             if kind in ("decode", "decode_multi", "mixed") and self._attn_impl == "megakernel":
                 # The decode rows' attention launch (megakernel.build_work): the pages under
                 # the rows' current tokens, the steps it takes a layer (one a group of
@@ -2369,7 +2373,7 @@ class Scheduler:
             # Smallest table width serving can pair with this chunk bucket:
             # the shortest prompt that maps here (prev_bucket+1 tokens),
             # bucketed by _prefill_table's rung rule (16 floor).
-            min_w = max(16, width_bucket((prev_bucket + 1 + bs - 1) // bs, self.max_blocks_per_seq))
+            min_w = self._prompt_width((prev_bucket + 1 + bs - 1) // bs)
             # Wave-admission width floor for this chunk bucket: _admit_wave
             # buckets by the wave's longest block table (rung floor 4, NOT
             # _prefill_table's 16) — the shortest fresh prompt chunking
@@ -2457,7 +2461,7 @@ class Scheduler:
         ):
             # (An eva table shrinks at every roll and regrows, so a prompt's
             # table takes every width on its way: warm them all.)
-            p_ws = [max(16, width_bucket(1, self.max_blocks_per_seq))]
+            p_ws = [self._prompt_width(1)]
             if self._eva:
                 p_ws = sorted({max(16, w) for w in widths} | set(p_ws))
             # Where has_prefix changes the program (_hp_static) a prompt's
@@ -3231,10 +3235,20 @@ class Scheduler:
         1B on v5e before this). Rung widths (see width_rungs) bound the
         executable count at 2·log2(max_blocks) variants per prefill
         bucket. A host array: a mixed step packs it with its other operands."""
-        w = max(16, width_bucket(len(seq.block_ids), self.max_blocks_per_seq))
+        w = self._prompt_width(len(seq.block_ids))
         table = np.zeros((w,), dtype=np.int32)
         table[: len(seq.block_ids)] = seq.block_ids
         return table
+
+    def _prompt_width(self, blocks: int) -> int:
+        """Width of the table a prompt of ``blocks`` blocks is prefilled under:
+        its rung, 16 at least. A stack of latent layers walks a chunk's pages
+        up to its last row (``latent.full_chunk``: a loop with a traced bound),
+        so a wider table costs it nothing and every prompt takes the widest:
+        one executable a chunk bucket, none built for a rare long prompt."""
+        if self.mc.is_latent:
+            return self.max_blocks_per_seq
+        return max(16, width_bucket(blocks, self.max_blocks_per_seq))
 
     def _ensure_block_capacity(self, seq: Sequence) -> None:
         """Grow the block table if the *next* token would overflow it.

@@ -75,6 +75,8 @@ GAUGE_KEYS = (
     "ssm_slots_total", "ssm_slots_in_use",
     # A stack of cca layers: a slot holds the convolutions' last columns.
     "cca_slots_total", "cca_slots_in_use",
+    # A stack of latent layers: a slot holds the window layers' rings.
+    "window_slots_total", "window_slots_in_use",
 )
 
 # Fleet-level digest families the aggregator re-exports (merged across
@@ -97,6 +99,7 @@ COUNTER_KEYS = (
     # (each is a whole recompute of the sequence's recurrent state).
     "ssm_slot_allocs_total", "ssm_preempt_recomputes_total",
     "cca_slot_allocs_total", "cca_preempt_recomputes_total",
+    "window_slot_allocs_total", "window_preempt_recomputes_total",
     # rows x layers that drew the ZAYA router's skip choice
     "moe_skipped_rows_total",
     "moe_dropped_total", "moe_assignments_total",
