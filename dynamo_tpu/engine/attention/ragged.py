@@ -1,5 +1,11 @@
-"""Ragged prefill-chunk attention: the shared chunk-over-[prefix ; chunk]
-piece behind both phase-separated prefill and MIXED prefill+decode steps.
+"""Ragged prefill-chunk attention over a GATHERED prefix: the chunk-over-
+[prefix ; chunk] piece of phase-separated prefill and of MIXED
+prefill+decode steps where no Pallas kernel serves the pool
+(``llama.resolve_attention_impl`` says ``"gather"``: ``auto`` off the TPU, a
+mesh whose KV heads do not divide, an int8 pool under ``paged``). Wherever
+one does (``"megakernel"``, ``"paged"``) a chunk walks the megakernel's tiles
+instead (``llama.chunk_walks_tiles``, PERF.md section 6 PR 52) and nothing
+here runs.
 
 A ragged batch row is a ``(start, len)`` run of tokens over the paged KV
 cache: ``start`` (= ``cache_len``) tokens are already materialized behind a
@@ -13,11 +19,13 @@ Two backends, numerically interchangeable:
 - **XLA** (default off-TPU): one masked softmax over the concatenated
   ``[prefix ; chunk]`` keys — the width-bucketed gather bounds the prefix
   extent, the mask covers fresh and continuation chunks alike.
-- **Pallas flash** (opt-in fast path, ``ModelConfig.prefill_impl``): the
-  chunk's causal self-attention runs in the flash kernel (prefill.py —
-  scores never leave VMEM) and the cached-prefix piece is an online-softmax
-  partial merged outside the kernel; fresh chunks (``has_prefix=False``)
-  statically skip the prefix piece altogether.
+- **Pallas flash** (``ModelConfig.prefill_impl`` ``"flash"``, or ``auto`` on
+  a TPU, with ``attention_impl="gather"``: chip_smoke.py's parity phase and
+  an operator's explicit choice reach it): the chunk's causal
+  self-attention runs in the flash kernel (prefill.py — scores never leave
+  VMEM) and the cached-prefix piece is an online-softmax partial of float32
+  scores in HBM, merged outside the kernel; fresh chunks
+  (``has_prefix=False``) statically skip the prefix piece altogether.
 """
 
 from __future__ import annotations

@@ -85,13 +85,17 @@ class ModelConfig:
     #   lane group of whole KV heads. prefill launches it once a layer,
     #   mixed_step twice (the chunk, then the decode rows: disjoint outputs,
     #   no merge). A sched.step entry of a chunk-carrying dispatch names the
-    #   path as traced: chunk_attn = tile<TQ> | paged | gather.
-    # - "paged": the r5 per-piece Pallas paged flash-decode kernel
-    #   (attention/decode.py) — correct (interpret-mode parity tests) but
-    #   NEVER auto-selected (a configuration may set it: evabyte-d16's
-    #   4096-lane pages, PERF.md section 6 PR 28 and PR 31). No int8
-    #   path — int8 caches degrade to gather with a logged warning
-    #   (llama.resolve_attention_impl).
+    #   path as traced: chunk_attn = tile<TQ> | gather.
+    # - "paged": the length-1 decode rows' prefix through the r5 per-piece
+    #   Pallas paged flash-decode kernel (attention/decode.py), merged with
+    #   the current token in-register — correct (interpret-mode parity
+    #   tests) but NEVER auto-selected (a configuration may set it:
+    #   evabyte-d16's 4096-lane pages, PERF.md section 6 PR 28 and PR 31).
+    #   A prefill chunk beside them walks the megakernel's tiles, as under
+    #   "megakernel" (llama.chunk_walks_tiles, PERF.md section 6 PR 52): the
+    #   two differ in the decode rows' kernel alone. No int8 path — int8
+    #   caches degrade to gather with a logged warning
+    #   (llama.resolve_attention_impl). Honoured off the TPU (interpreted).
     # - "auto": "megakernel" on TPU, "gather" elsewhere (interpreted
     #   Pallas is test-only). The gather's read + packed-copy write +
     #   attend re-read is 3× the true KV bytes; the megakernel streams

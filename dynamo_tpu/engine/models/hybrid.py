@@ -105,6 +105,7 @@ from dynamo_tpu.engine.models.llama import (  # noqa: F401 — the scheduler rea
     _split_expert_stacks,
     _use_megakernel,
     chunk_attn_path,
+    chunk_walks_tiles,
     decode_targets,
     resolve_attention_impl,
     resolve_prefill_impl,
@@ -351,8 +352,8 @@ def _chunk_attention(c: ModelConfig, k_pool, v_pool, table, prefix_rows, valid_l
     N, bs, kvh, hd = k_pool.shape[1], c.block_size, c.num_kv_heads, c.head_dim
     ctx = table.shape[0] * bs
     k_flat, v_flat = layer_flat(k_pool), layer_flat(v_pool)
-    use_mega = _use_megakernel(c, k_pool)
-    if use_mega:
+    walks_tiles = chunk_walks_tiles(c, k_pool)
+    if walks_tiles:
         from dynamo_tpu.engine.attention.megakernel import build_meta
 
         t_iq = jnp.arange(T, dtype=jnp.int32)
@@ -363,7 +364,7 @@ def _chunk_attention(c: ModelConfig, k_pool, v_pool, table, prefix_rows, valid_l
 
     def attend(q, k, v, la):
         table_l = table + la * N
-        if use_mega:
+        if walks_tiles:
             out = _mega_attend_rows(c, q, k, v, k_flat, v_flat, table_l[None, :], meta)
             return out.astype(q.dtype).reshape(T, c.q_size)
         from dynamo_tpu.engine.attention.ragged import ragged_chunk_attention

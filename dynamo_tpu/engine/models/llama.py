@@ -672,19 +672,33 @@ def warn_attention_impl_degrade(c: ModelConfig, k_cache) -> None:
 
 
 def _use_paged_decode(c: ModelConfig, k_cache) -> bool:
-    """The r5 per-piece Pallas paged kernel (attention/decode.py) — still
-    explicit opt-in only; superseded by the ragged megakernel for the
-    fused path. int8 caches degrade to gather (resolve_attention_impl)."""
+    """The length-1 rows' prefix through the r5 per-piece Pallas paged kernel
+    (attention/decode.py) — still explicit opt-in only; superseded by the
+    ragged megakernel for the fused path. int8 caches degrade to gather
+    (resolve_attention_impl). A chunk beside them walks tiles
+    (``chunk_walks_tiles``)."""
     return resolve_attention_impl(c, k_cache) == "paged"
 
 
 def _use_megakernel(c: ModelConfig, k_cache) -> bool:
-    """Ragged paged-attention megakernel (attention/megakernel.py): one
-    kernel serves every row of the step — decode rows a query a grid row,
-    a prefill chunk by tiles of its queries (a launch a layer each; a
-    mixed step has both) — with no gathered prefix copy and pl.when-skipped
-    dead slots. Auto-selected on TPU."""
+    """The length-1 rows through the ragged paged-attention megakernel
+    (attention/megakernel.py): a launch a layer over the list of their live
+    pages, no gathered prefix copy, no step for a dead row or slot.
+    Auto-selected on TPU."""
     return resolve_attention_impl(c, k_cache) == "megakernel"
+
+
+def chunk_walks_tiles(c: ModelConfig, k_cache) -> bool:
+    """THE rule for a wide row (a prefill chunk, in ``prefill``,
+    ``mixed_step`` and hybrid.py's attention mixer): it meets its paged prefix
+    and its own keys in one launch a layer of the megakernel's tile walk
+    (``_mega_attend_rows`` without ``work``) wherever a Pallas kernel serves
+    the pool at all — ``resolve_attention_impl`` says ``"megakernel"`` or
+    ``"paged"``, which differ in the length-1 rows' kernel alone. Only
+    ``"gather"`` (``auto`` off the TPU, a mesh whose KV heads do not divide,
+    an int8 pool under ``paged``, a latent kind) takes attention/ragged.py's
+    gathered prefix."""
+    return resolve_attention_impl(c, k_cache) != "gather"
 
 
 def _chunk_tile(c: ModelConfig, k_cache, num_queries: int, dtype) -> int:
@@ -703,9 +717,8 @@ def _chunk_tile(c: ModelConfig, k_cache, num_queries: int, dtype) -> int:
 def chunk_attn_path(c: ModelConfig, k_cache, num_queries: int, dtype) -> str:
     """How a chunk of ``num_queries`` meets its keys in ``prefill`` and
     ``mixed_step`` as they trace now: ``tile<TQ>`` (the ragged megakernel's
-    (tile, page) walk), ``paged`` or ``gather``. For the step log."""
-    impl = resolve_attention_impl(c, k_cache)
-    return f"tile{_chunk_tile(c, k_cache, num_queries, dtype)}" if impl == "megakernel" else impl
+    (tile, page) walk, ``chunk_walks_tiles``) or ``gather``. For the step log."""
+    return f"tile{_chunk_tile(c, k_cache, num_queries, dtype)}" if chunk_walks_tiles(c, k_cache) else "gather"
 
 
 def rows_pages_per_step(c: ModelConfig, k_cache, num_slots: int) -> int:
@@ -836,12 +849,14 @@ def prefill(
     v_cache) — or ([T, V] logits with ``all_logits=True``, the target-model
     verification pass for speculative decoding; spec_decode.py).
 
-    With ``use_flash`` the chunk's causal self-attention runs in the Pallas
-    flash kernel (attention/prefill.py — scores never leave VMEM) and the
-    cached-prefix piece (absent for fresh prefills: ``has_prefix=False``)
-    is an online-softmax partial merged outside the kernel. The XLA path
-    (use_flash=False) materializes the full [T, ctx+T] mask — CPU meshes /
-    debugging."""
+    Wherever a kernel serves the pool the chunk walks the megakernel's tiles
+    (``chunk_walks_tiles``) and neither ``use_flash`` nor ``has_prefix`` is
+    read. Under the gather, with ``use_flash`` the chunk's causal
+    self-attention runs in the Pallas flash kernel (attention/prefill.py —
+    scores never leave VMEM) and the cached-prefix piece (absent for fresh
+    prefills: ``has_prefix=False``) is an online-softmax partial merged
+    outside the kernel; the XLA path (use_flash=False) materializes the full
+    [T, ctx+T] mask — CPU meshes / debugging."""
     c = config
     bs = c.block_size
     T = tokens.shape[0]
@@ -887,12 +902,13 @@ def prefill(
     k_flat = layer_flat(k_cache)
     v_flat = layer_flat(v_cache)
 
-    use_mega = _use_megakernel(c, k_cache)
-    if use_mega:
+    walks_tiles = chunk_walks_tiles(c, k_cache)
+    if walks_tiles:
         # The prefill chunk is one wide megakernel row, walked by tiles of
         # its queries: causal fresh chunk + paged prefix in ONE launch per
         # layer — no gathered prefix copy, pad queries (and fresh prefills'
-        # empty prefix) skipped dead in-kernel.
+        # empty prefix) skipped dead in-kernel. ``use_flash`` and
+        # ``has_prefix`` are not read.
         from dynamo_tpu.engine.attention.megakernel import build_meta
 
         t_iq = jnp.arange(T, dtype=jnp.int32)
@@ -914,37 +930,29 @@ def prefill(
         k = project_heads(x, lp["wk"], c.num_kv_heads, positions, c.rope_theta)
         v = (x @ lp["wv"]).reshape(T, c.num_kv_heads, c.head_dim)
 
-        if use_mega:
+        if walks_tiles:
             attn = _mega_attend_rows(
                 c, q, k, v, k_flat, v_flat,
                 (block_table + l * N)[None, :], mega_meta,
             ).astype(wdtype)
-            h = h + attn.reshape(T, c.q_size) @ lp["wo"]
-            x = _norm(c, h, lp["mlp_norm"], wdtype)
-            if moe_stats:
-                mlp_out, drops = _mlp(x, lp, c, valid=valid_q, stats=True, experts=experts, layer=l)
-                h = h + mlp_out
-                return h, (k, v, drops)
-            h = h + _mlp(x, lp, c, valid=valid_q, experts=experts, layer=l)
-            return h, (k, v)
-
-        # Ragged chunk attention over [cached prefix ; chunk] — shared with
-        # the mixed prefill+decode step (attention/ragged.py). The prefix
-        # gather is bounded by the caller's width-bucketed table — the true
-        # prefix extent, not max_seq_len; flash fresh chunks skip it.
-        from dynamo_tpu.engine.attention.ragged import ragged_chunk_attention
-
-        if use_flash and not has_prefix:
-            k_ctx = v_ctx = None
         else:
-            table_l = block_table + l * N
-            k_ctx = _gather_kv(k_flat, table_l, wdtype).reshape(ctx, kvh, c.head_dim)
-            v_ctx = _gather_kv(v_flat, table_l, wdtype).reshape(ctx, kvh, c.head_dim)
-        attn = ragged_chunk_attention(
-            q, k, v, k_ctx, v_ctx, valid_len, prefix_rows,
-            num_kv_heads=kvh, use_flash=use_flash, has_prefix=has_prefix,
-            interpret=interp,
-        )
+            # No kernel serves the pool: ragged chunk attention over
+            # [gathered prefix ; chunk] — shared with the mixed step
+            # (attention/ragged.py). The gather is bounded by the caller's
+            # width-bucketed table; flash fresh chunks skip it.
+            from dynamo_tpu.engine.attention.ragged import ragged_chunk_attention
+
+            if use_flash and not has_prefix:
+                k_ctx = v_ctx = None
+            else:
+                table_l = block_table + l * N
+                k_ctx = _gather_kv(k_flat, table_l, wdtype).reshape(ctx, kvh, c.head_dim)
+                v_ctx = _gather_kv(v_flat, table_l, wdtype).reshape(ctx, kvh, c.head_dim)
+            attn = ragged_chunk_attention(
+                q, k, v, k_ctx, v_ctx, valid_len, prefix_rows,
+                num_kv_heads=kvh, use_flash=use_flash, has_prefix=has_prefix,
+                interpret=interp,
+            )
         h = h + attn.reshape(T, c.q_size) @ lp["wo"]
 
         x = _norm(c, h, lp["mlp_norm"], wdtype)
@@ -1523,11 +1531,13 @@ def mixed_step(
     rows]``. Projections, MLP, and the final fused KV scatter run over the
     whole ragged batch (decode matmuls alone leave the MXU idle — the chunk
     tokens ride the same dispatch instead of stalling behind it), while
-    attention splits into the two shapes it actually has: the ragged chunk
-    piece (attention/ragged.py — width-bucketed prefix gather + causal
-    chunk, flash kernel opt-in) and the decode rows' two-piece online-
-    softmax (cached prefix + current token in-register), identical math to
-    ``prefill`` and ``decode`` respectively."""
+    attention splits into the two shapes it actually has: the chunk (the
+    megakernel's tile walk wherever a kernel serves the pool,
+    ``chunk_walks_tiles``; else attention/ragged.py — width-bucketed prefix
+    gather + causal chunk, flash kernel opt-in) and the decode rows (the
+    kernel their impl names, or the two-piece online-softmax of gathered
+    prefix + current token in-register), identical math to ``prefill`` and
+    ``decode`` respectively."""
     c = config
     bs = c.block_size
     S = p_tokens.shape[0]
@@ -1555,16 +1565,19 @@ def mixed_step(
     d_tgt_blocks, d_tgt_offs, d_mask = decode_targets(d_rows, d_tables, d_active, bs)
     use_paged = _use_paged_decode(c, k_cache)
     use_mega = _use_megakernel(c, k_cache)
+    walks_tiles = chunk_walks_tiles(c, k_cache)
     d_prefix_lens = jnp.minimum(d_rows, ctx_d).astype(jnp.int32)
-    if use_mega:
-        # Megakernel packing: the mixed step's attention is the two shapes
-        # it has, each a launch per layer of the one kernel — the chunk, a
-        # wide row walked by tiles of its queries over its own table and
-        # its own fresh keys, and the B length-1 decode rows, whose launch
-        # walks the list of their live pages (build_work): an inactive lane
-        # or a padded table slot is no step of it. The chunk's padded slots
-        # hold the scratch page and are skipped (pl.when) along with its
-        # bucket's dead queries.
+    # The mixed step's attention is the two shapes it has, each a launch per
+    # layer where a kernel serves the pool. The chunk, a wide row walked by
+    # tiles of its queries over its own table and its own fresh keys
+    # (chunk_walks_tiles): its padded slots hold the scratch page and are
+    # skipped (pl.when) along with its bucket's dead queries and a fresh
+    # chunk's empty prefix, so ``use_flash`` and ``has_prefix`` are not read.
+    # And the B length-1 decode rows, through the kernel their impl names:
+    # the megakernel's launch over the list of their live pages (build_work:
+    # an inactive lane or a padded table slot is no step of it), or the paged
+    # kernel's prefix partials merged with the current token in-register.
+    if walks_tiles:
         from dynamo_tpu.engine.attention.megakernel import build_meta
 
         s_iq = jnp.arange(S, dtype=jnp.int32)
@@ -1573,6 +1586,7 @@ def mixed_step(
             jnp.zeros((S,), jnp.int32), jnp.full((S,), p_prefix_rows, jnp.int32),
             jnp.zeros((S,), jnp.int32), s_iq + 1, s_iq < p_valid,
         )
+    if use_mega:
         d_meta = build_meta(d_iq, d_prefix_lens, d_iq, d_iq + 1, d_active)
         d_work = _mega_rows_work(c, k_cache, d_prefix_lens, d_active, d_tables.shape[1])
 
@@ -1588,54 +1602,49 @@ def mixed_step(
         k = project_heads(x, lp["wk"], kvh, positions_all, c.rope_theta)
         v = (x @ lp["wv"]).reshape(S + B, kvh, hd)
 
-        if use_mega:
-            # Each piece's fresh keys are its own rows of the projection.
+        # Chunk piece [S, H, hd]; each piece's fresh keys are its own rows of
+        # the projection.
+        if walks_tiles:
             attn_p = _mega_attend_rows(
                 c, q[:S], k[:S], v[:S], k_flat, v_flat, (p_table + l * N)[None, :], p_meta,
             )
-            attn_d = _mega_attend_rows(c, q[S:], k[S:], v[S:], k_flat, v_flat, d_tables + l * N, d_meta, d_work)
-            attn = jnp.concatenate([attn_p, attn_d]).astype(wdtype).reshape(S + B, c.q_size)
-            h = h + attn @ lp["wo"]
-            x = _norm(c, h, lp["mlp_norm"], wdtype)
-            if moe_stats:
-                mlp_out, drops = _mlp(x, lp, c, valid=valid_all, stats=True, experts=experts, layer=l)
-                return h + mlp_out, (k, v, drops)
-            h = h + _mlp(x, lp, c, valid=valid_all, experts=experts, layer=l)
-            return h, (k, v)
-
-        # Chunk piece: [cached prefix ; chunk] — prefill's exact math.
-        if use_flash and not has_prefix:
-            kp_ctx = vp_ctx = None
         else:
-            table_pl = p_table + l * N
-            kp_ctx = _gather_kv(k_flat, table_pl, wdtype).reshape(ctx_p, kvh, hd)
-            vp_ctx = _gather_kv(v_flat, table_pl, wdtype).reshape(ctx_p, kvh, hd)
-        attn_p = ragged_chunk_attention(
-            q[:S], k[:S], v[:S], kp_ctx, vp_ctx, p_valid, p_prefix_rows,
-            num_kv_heads=kvh, use_flash=use_flash, has_prefix=has_prefix,
-            interpret=interp,
-        )
-
-        # Decode rows: cached prefix + current token in-register — the
-        # decode_layer_scan two-piece merge.
-        qg_d = q[S:].reshape(B, kvh, G, hd)
-        if use_paged:
-            m1, l1, acc1 = _paged_prefix_partials(
-                c, q[S:], k_flat, v_flat, d_tables + l * N, d_prefix_lens
+            # [gathered prefix ; chunk] — prefill's exact math where no
+            # kernel serves the pool.
+            if use_flash and not has_prefix:
+                kp_ctx = vp_ctx = None
+            else:
+                table_pl = p_table + l * N
+                kp_ctx = _gather_kv(k_flat, table_pl, wdtype).reshape(ctx_p, kvh, hd)
+                vp_ctx = _gather_kv(v_flat, table_pl, wdtype).reshape(ctx_p, kvh, hd)
+            attn_p = ragged_chunk_attention(
+                q[:S], k[:S], v[:S], kp_ctx, vp_ctx, p_valid, p_prefix_rows,
+                num_kv_heads=kvh, use_flash=use_flash, has_prefix=has_prefix,
+                interpret=interp,
             )
-        else:
-            tables_dl = d_tables + l * N
-            kd_ctx = _gather_kv(k_flat, tables_dl, wdtype).reshape(B, ctx_d, kvh, hd)
-            vd_ctx = _gather_kv(v_flat, tables_dl, wdtype).reshape(B, ctx_d, kvh, hd)
-            m1, l1, acc1 = _attend_piece(qg_d, kd_ctx, vd_ctx, d_mask, scale)
-        m2, l2, acc2 = _attend_piece(
-            qg_d, k[S:, None], v[S:, None], jnp.ones((B, 1), dtype=bool), scale
-        )
-        attn_d = _merge_pieces(m1, l1, acc1, m2, l2, acc2).astype(wdtype)
 
-        attn = jnp.concatenate(
-            [attn_p.reshape(S, c.q_size), attn_d.reshape(B, c.q_size)], axis=0
-        )
+        # Decode rows [B, H, hd].
+        if use_mega:
+            attn_d = _mega_attend_rows(c, q[S:], k[S:], v[S:], k_flat, v_flat, d_tables + l * N, d_meta, d_work)
+        else:
+            # Cached prefix + current token in-register — the
+            # decode_layer_scan two-piece merge.
+            qg_d = q[S:].reshape(B, kvh, G, hd)
+            if use_paged:
+                m1, l1, acc1 = _paged_prefix_partials(
+                    c, q[S:], k_flat, v_flat, d_tables + l * N, d_prefix_lens
+                )
+            else:
+                tables_dl = d_tables + l * N
+                kd_ctx = _gather_kv(k_flat, tables_dl, wdtype).reshape(B, ctx_d, kvh, hd)
+                vd_ctx = _gather_kv(v_flat, tables_dl, wdtype).reshape(B, ctx_d, kvh, hd)
+                m1, l1, acc1 = _attend_piece(qg_d, kd_ctx, vd_ctx, d_mask, scale)
+            m2, l2, acc2 = _attend_piece(
+                qg_d, k[S:, None], v[S:, None], jnp.ones((B, 1), dtype=bool), scale
+            )
+            attn_d = _merge_pieces(m1, l1, acc1, m2, l2, acc2).astype(wdtype).reshape(B, c.num_heads, hd)
+
+        attn = jnp.concatenate([attn_p, attn_d]).astype(wdtype).reshape(S + B, c.q_size)
         h = h + attn @ lp["wo"]
         x = _norm(c, h, lp["mlp_norm"], wdtype)
         if moe_stats:

@@ -595,12 +595,14 @@ class Scheduler:
         # ``has_prefix`` is a STATIC argument of the prefill and mixed-step
         # programs only where it changes them: on the flash path's own
         # chunk attention, which skips the prefix piece of a fresh chunk.
-        # Under the megakernel (one ragged launch serves every row) the
-        # step programs never read it, and a static argument would key two
-        # byte-identical executables — the second one re-traced, lowered
-        # and fetched from the persistent cache in the middle of traffic
-        # (0.05-0.6 s of a stalled step thread per key, PERF.md §6 PR 26).
-        self._hp_static = self._use_flash_prefill and self._attn_impl != "megakernel"
+        # That path is taken only where no kernel serves the pool
+        # (llama.chunk_walks_tiles): under "megakernel" and "paged" a chunk
+        # walks tiles, the step programs never read it, and a static
+        # argument would key two byte-identical executables — the second one
+        # re-traced, lowered and fetched from the persistent cache in the
+        # middle of traffic (0.05-0.6 s of a stalled step thread per key,
+        # PERF.md §6 PR 26).
+        self._hp_static = self._use_flash_prefill and not model.chunk_walks_tiles(model_config, pool_of(self.cache.k))
         # Capacity-dispatch MoE exports drop counters (wide-EP observability;
         # ref: SURVEY.md §2e / trtllm_utils.py:37-39 wide-EP surface).
         self._moe_stats = (
@@ -661,7 +663,7 @@ class Scheduler:
 
             self._prefill_jit = self._jit(prefill, donate_argnums=(1, 2), static_argnums=(5,))
         else:
-            # No ``hp`` here: the XLA path's masks and the megakernel's ragged
+            # No ``hp`` here: the XLA path's masks and the tile walk's ragged
             # rows cover prefix and fresh prefills alike (a static argument
             # would compile two byte-identical executables per bucket, a
             # traced one would be an upload of its own).
@@ -1325,9 +1327,10 @@ class Scheduler:
     def _chunk_attn(self, bucket: int) -> str:
         """How a chunk of ``bucket`` queries meets its keys in ``prefill`` and
         ``mixed_step`` as this engine traced them: ``tile<TQ>`` (the ragged
-        megakernel's walk by tiles of TQ queries), ``paged`` or ``gather``."""
-        if self._attn_impl != "megakernel":
-            return self._attn_impl
+        megakernel's walk by tiles of TQ queries, wherever a kernel serves
+        the pool: llama.chunk_walks_tiles) or ``gather``."""
+        if self._attn_impl == "gather":
+            return "gather"
         if bucket not in self._chunk_attn_paths:  # 0.8 ms to work out: once a bucket, not once a dispatch
             self._chunk_attn_paths[bucket] = self._model.chunk_attn_path(self.mc, pool_of(self.cache.k), bucket, self.dtype)
         return self._chunk_attn_paths[bucket]
@@ -1624,7 +1627,7 @@ class Scheduler:
         — shared by _mixed_step and warmup so both compile the same thing.
         ``hp`` follows the prefill convention (``_hp_static``): static on
         the flash path's own chunk attention (the kernel skips the prefix
-        piece), a traced no-op on XLA and under the megakernel."""
+        piece), not an argument on XLA and under the tile walk."""
         if key not in self._mixed_jits:
             model = self._model
             stats_kw = {"moe_stats": True} if self._moe_stats else {}

@@ -99,6 +99,60 @@ def test_step_programs_agree_with_the_reference_across_rolls(params, block_size,
         assert r["fault_control"]["fails"]  # the tables rolled, the roll program never ran
 
 
+# kind of rows -> (configuration, where a chunk with a prefix starts): a causal row's prefix ends inside a page (21 rows
+# of pages of 16); an eva row's is the summaries of one or of three rolled windows (a block each) and the first 8 rows
+# of its own window. A fresh chunk starts at 0 whatever the kind, so three summary blocks bring no fresh case of their own.
+CHUNK_ROWS = {"causal": ("tiny", 21), "eva-1-summary-block": ("tiny-eva", W + 8), "eva-3-summary-blocks": ("tiny-eva", 3 * W + 8)}
+CHUNK_CASES = [(rows, prefix, valid, pad) for rows in CHUNK_ROWS for prefix in (False, True) for valid in (16, 9) for pad in (0, 3)
+               if prefix or rows != "eva-3-summary-blocks"]
+
+
+@pytest.mark.parametrize("rows,prefix,valid,pad", CHUNK_CASES, ids=[
+    f"{rows}-{'prefix' if prefix else 'fresh'}-{valid}-of-16-{'padded-table' if pad else 'exact-table'}" for rows, prefix, valid, pad in CHUNK_CASES])
+def test_a_chunk_walks_tiles_wherever_a_kernel_serves_the_pool(rows, prefix, valid, pad):
+    """``attention_impl="paged"`` (honoured off the TPU, its kernels interpreted):
+    a chunk in ``prefill`` and in ``mixed_step`` takes the megakernel's tile walk
+    (``llama.chunk_walks_tiles``) and the decode rows beside it the paged kernel,
+    and both give the logits and the pool rows of the ``gather`` path, within
+    tests/test_megakernel.py's tolerances: over a pool of random rows, a chunk
+    that fills its bucket of 16 or 9 of it, a table as wide as its rows or with
+    three padded slots, a live decode row and a dead lane."""
+    from dynamo_tpu.engine.kv_cache import KvCacheArrays
+
+    preset, start = CHUNK_ROWS[rows]
+    base = get_config(preset)
+    weights = llama.init_params(base, jax.random.PRNGKey(0), dtype=jnp.float32)
+    start = start if prefix else 0
+    bs, rng = base.block_size, np.random.default_rng(valid + pad + start)
+    blocks = -(-(int(cache_rows(base, start)) + 16) // bs)
+    ids = rng.permutation(np.arange(1, 32))
+    table = jnp.asarray(np.r_[ids[:blocks], np.zeros(pad, np.int64)].astype(np.int32))
+    d_tables = jnp.asarray(np.stack([np.r_[ids[blocks : blocks + 2], 0], np.zeros(3, np.int64)]).astype(np.int32))
+    chunk = np.zeros(16, np.int32)
+    chunk[:valid] = rng.integers(1, 255, size=valid)
+    pool = KvCacheArrays.create(base, num_blocks=32, dtype=jnp.float32)
+    k0, v0 = (jax.random.normal(jax.random.PRNGKey(i), pool.k.shape, jnp.float32) for i in (1, 2))
+
+    def run(impl):
+        c = base.replace(attention_impl=impl)
+        assert llama.chunk_walks_tiles(c, k0) == (impl == "paged")
+
+        def both(p, k, v):
+            lg, k1, v1 = llama.prefill(p, c, k, v, jnp.asarray(chunk), jnp.int32(valid), jnp.int32(start), table)
+            mixed = llama.mixed_step(p, c, k, v, jnp.asarray(chunk), jnp.int32(valid), jnp.int32(start), table,
+                                     jnp.asarray([7, 0], jnp.int32), jnp.asarray([bs + 3, 0], jnp.int32), d_tables, jnp.asarray([True, False]))
+            return lg, k1, v1, mixed[0][:2], mixed[1], mixed[2]  # logits: the chunk's row and the live decode row's
+
+        return jax.jit(both)(weights, k0, v0)
+
+    for name, want, got in zip(("prefill logits", "prefill k", "prefill v", "mixed logits", "mixed k", "mixed v"), run("gather"), run("paged")):
+        want, got = np.asarray(want), np.asarray(got)
+        if name[-1] in "kv":  # pool rows but the scratch block's: dead rows sink there, and nothing reads it
+            np.testing.assert_allclose(got[:, 1:], want[:, 1:], atol=2e-5, err_msg=name)
+        else:
+            np.testing.assert_allclose(got, want, atol=2e-4, err_msg=name)
+
+
 def test_the_window_kernels_steps_past_a_boundary_write_nothing(params):
     """decode_multi: a row that reaches its window boundary inside the window
     keeps the completed window's rows as they are (the roll reads them next)."""

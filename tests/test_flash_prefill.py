@@ -120,12 +120,14 @@ def test_scheduler_flash_prefill_e2e():
     assert run("flash") == run("xla")
 
 
-@pytest.mark.parametrize("attention_impl,static", [("megakernel", False), ("gather", True)])
+@pytest.mark.parametrize("attention_impl,static", [("megakernel", False), ("paged", False), ("gather", True)])
 def test_has_prefix_keys_an_executable_only_where_it_changes_the_program(attention_impl, static):
     """``has_prefix`` is a static argument of the prefill and mixed-step
-    programs on the flash path's own chunk attention; under the megakernel
-    the programs never read it, so a chunk with a cached prefix reuses the
-    executable the fresh chunk traced: nothing is built mid-traffic."""
+    programs on the flash path's own chunk attention, which only the gather
+    reaches; where a kernel serves the pool (``megakernel``, ``paged``) a
+    chunk walks tiles and the programs never read it, so a chunk with a cached
+    prefix reuses the executable the fresh chunk traced: nothing is built
+    mid-traffic."""
     from dynamo_tpu.engine.sampling import SamplingParams
     from dynamo_tpu.engine.scheduler import Scheduler, SchedulerConfig, StopConditions
 

@@ -154,12 +154,14 @@ def test_flight_records_and_recent_steps_keep_their_shape():
     assert abs(time.monotonic() - fr.last_step_ts) < 1.0  # the stall watchdog's clock
 
 
-@pytest.mark.parametrize("impl,path", [("gather", "gather"), ("paged", "paged"), ("megakernel", "tile32")])
+@pytest.mark.parametrize("impl,path", [("gather", "gather"), ("paged", "tile32"), ("megakernel", "tile32")])
 def test_a_chunk_carrying_step_names_the_chunks_attention_path(impl, path):
     """``sched.step`` of a dispatch that carried a prefill chunk says how the
     chunk met its keys in the program as traced — a bare chunk, a mixed step,
     and a chunk that follows a decode dispatch in its iteration — and
-    ``recent_steps`` of /debug/state shows it."""
+    ``recent_steps`` of /debug/state shows it. Wherever a kernel serves the
+    pool the chunk walks tiles (``llama.chunk_walks_tiles``): ``paged`` names
+    the decode rows' kernel, not the chunk's."""
     sched = Scheduler(CFG.replace(attention_impl=impl), PARAMS,
                       SchedulerConfig(num_blocks=128, max_running=8, prefill_buckets=[32], decode_buckets=[4],
                                       num_scheduler_steps=1, enable_prefix_caching=False), dtype=jnp.float32)

@@ -22,6 +22,7 @@ PROBES = {
     "tools/ssm_step_bench.py --tiny --parts moe": {"ragged_dot", "gmm"},
     "tools/ssm_step_bench.py --tiny --parts moe --shipped-only": {"ragged_dot", "gmm"},
     "tools/attn_chunk_bench.py --tiny": CHUNK_FORMS,
+    "tools/attn_chunk_bench.py --tiny --widths 6": CHUNK_FORMS,
     "tools/attn_chunk_bench.py --tiny --study rows": {"rows", "rows_walk"},
     "tools/attn_chunk_bench.py --tiny --study rows --heads 8 --pages-per-step 1 2 4": {"rows", "rows_walk"},
 }

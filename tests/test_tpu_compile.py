@@ -73,6 +73,14 @@ def on_tpu(monkeypatch):
     monkeypatch.setattr(llama, "_on_tpu", lambda: True)
 
 
+def _cell_file(cell):
+    """``benchmark/configs/<cell>.json`` as the harness reads it."""
+    import json
+
+    with open(os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "benchmark", "configs", cell + ".json")) as f:
+        return json.load(f)
+
+
 def _sds(shape, dtype, sharding):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
@@ -296,8 +304,13 @@ def _eva_compiled(sh, program):
 @pytest.mark.parametrize("program", ["mixed_step", "decode_multi_w8", "eva_roll"])
 def test_eva_step_programs_compile_and_hold_no_copy_of_the_pool(one_chip, on_tpu, program):
     """``attention_impl="paged"``, as the benchmark's configuration sets it for
-    4096-lane pages: the mixed step holds the flash kernel for the chunk (its
-    prefix fetched once) and the paged kernel for the decode rows. No program
+    4096-lane pages: the mixed step holds the megakernel's tile walk for the
+    chunk (``llama.chunk_walks_tiles``: every live page of its prefix fetched
+    once for all 256 queries, its own keys the same launch's fresh piece) and
+    the paged kernel for the decode rows: no scores of the chunk against its
+    table in HBM (until PR 52 XLA wrote them as ``bf16[32,256,2560]``, 42 MB a
+    layer, and read them three times, in float32 inside its fusions) and no
+    gathered copy of the table (``bf16[20,128,4096]``). No program
     may hold a temporary of the pool's size: XLA:TPU lowers a gather of whole
     4096-lane blocks by slicing the pool in halves (PERF.md section 6, PR 28),
     so one sequence's table is read by dynamic slices (``llama._GATHER_MAX_LANES``)."""
@@ -305,9 +318,44 @@ def test_eva_step_programs_compile_and_hold_no_copy_of_the_pool(one_chip, on_tpu
     text = compiled.as_text()
     assert ("tpu_custom_call" in text) == (program != "eva_roll")
     if program == "mixed_step":
-        assert "paged_decode_partials" in text and "flash" in text
+        assert "ragged_paged_attention" in text and "paged_decode_partials" in text and "flash_chunk_attention" not in text
+        heads, chunk, width = EVA.num_heads, 256, 20
+        assert not re.search(rf"\[{heads},{chunk},(1,)?{width * EVA.block_size}\]", text)
+        assert f"bf16[{width},{EVA.block_size},{EVA.kv_size}]" not in text
     pool = EVA.num_layers * EVA_BLOCKS * EVA.block_size * EVA.kv_size * 2
     assert compiled.memory_analysis().temp_size_in_bytes < pool // 2
+
+
+# cell's configuration file -> (attention_impl as resolved on a TPU, query heads, the pool's lanes, a page's rows, the chunk,
+# how a chunk meets its keys). The five cells that PR 52's rule bypasses read what they read before it.
+CELL_CHUNKS = {
+    "mistral-7b-w8": ("megakernel", 32, 1024, 128, 256, "tile256"),
+    "mixtral-8x7b-d3": ("megakernel", 32, 1024, 128, 256, "tile256"),
+    "evabyte-d16": ("paged", 32, 4096, 128, 256, "tile256"),  # "paged" until PR 52: gather + flash + an XLA prefix piece
+    "granite-4.0-h-small-d10-e36": ("megakernel", 32, 1024, 128, 256, "tile256"),
+    "zaya1-8b-d20": ("megakernel", 8, 256, 128, 256, "tile256"),
+    "dots3-note-prev-d5-e32": ("gather", 128, 192, 1024, 512, "gather"),  # latent: resolved before the rule is read
+}
+
+
+@pytest.mark.parametrize("cell", list(CELL_CHUNKS))
+def test_the_cells_chunks_take_the_path_their_shapes_and_their_impl_give(on_tpu, cell):
+    """``llama.chunk_walks_tiles`` and ``megakernel.chunk_tile`` at the six
+    cells' own configurations, as a TPU resolves them: a chunk walks tiles
+    of 256 wherever a kernel serves the pool (``megakernel`` or ``paged``),
+    and the decode rows keep the kernel the impl names."""
+    from benchmark import families
+    from dynamo_tpu.engine.models import get_module
+
+    cfg = _cell_file(cell)
+    mc = families.load(cfg["family"]).model_config(cfg, cell)
+    chunk = cfg["scheduler"]["max_prefill_chunk"]
+    pool = jax.ShapeDtypeStruct((mc.num_layers, 8, mc.block_size, mc.kv_size), BF16)
+    impl, heads, lanes, page, want_chunk, path = CELL_CHUNKS[cell]
+    assert (llama.resolve_attention_impl(mc, pool), mc.num_heads, mc.kv_size, mc.block_size, chunk) == (impl, heads, lanes, page, want_chunk)
+    assert llama.chunk_walks_tiles(mc, pool) == (impl != "gather") == path.startswith("tile")
+    assert (llama._use_megakernel(mc, pool), llama._use_paged_decode(mc, pool)) == (impl == "megakernel", impl == "paged")
+    assert get_module(mc).chunk_attn_path(mc, pool, chunk, BF16) == path
 
 
 # --- MoE at Mixtral-8x7B's widths, 3 layers: the expert stacks are read where they lie -----------
@@ -421,15 +469,11 @@ def _granite_jit(sh, program):
     ``granite-4.0-h-small-d10-e36`` as configured (65 slots, 1,025 blocks, 64
     rows). ``check-``: as the output check calls it (``granite_hybrid.program_logits``:
     the same pool and slots, its bucket and table width, logits returned)."""
-    import json
-
     from benchmark import families
     from dynamo_tpu.engine.kv_cache import KvCacheArrays
     from dynamo_tpu.engine.models import hybrid
 
-    with open(os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "benchmark", "configs",
-                           "granite-4.0-h-small-d10-e36.json")) as f:
-        cfg = json.load(f)
+    cfg = _cell_file("granite-4.0-h-small-d10-e36")
     fam = families.load("granite_hybrid")
     mc = fam.model_config(cfg, "granite")
     sc = cfg["scheduler"]
@@ -496,15 +540,11 @@ def _zaya_jit(sh, program):
     ``zaya1-8b-d20`` as configured (65 slots, 1,025 blocks, 64 rows, tables of
     24). ``check-``: as the output check calls it (``zaya.program_logits``: its
     bucket and table width, every position's logits)."""
-    import json
-
     from benchmark import families
     from dynamo_tpu.engine.kv_cache import KvCacheArrays
     from dynamo_tpu.engine.models import hybrid
 
-    with open(os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "benchmark", "configs",
-                           "zaya1-8b-d20.json")) as f:
-        cfg = json.load(f)
+    cfg = _cell_file("zaya1-8b-d20")
     fam = families.load("zaya")
     mc = fam.model_config(cfg, "zaya")
     sc = cfg["scheduler"]

@@ -3,8 +3,11 @@
 31): a mixed step's, a chunk of 256 queries over a paged prefix and its own
 keys, beside 32 decode rows of 12 pages, at two pool widths — ``KVH*HD`` 1,024
 lanes (Mistral-7B: 32/8 heads of 128) and 4,096 (EvaByte: 32/32 heads of 128).
-``--study rows`` (PERF.md §6, PR 38): the decode rows' launch alone, by how
-many of a bucket's rows are live and how much of their table they fill.
+The chunk's table is 16 slots wide or each of ``--widths`` (EvaByte's prompt
+tables are 16 and 20: ``--lanes 4096 --widths 16 20 --prefix 256 1152 2432``,
+PERF.md §6, PR 52). ``--study rows`` (PERF.md §6, PR 38): the decode rows'
+launch alone, by how many of a bucket's rows are live and how much of their
+table they fill.
 
 Forms, each a ``lax.scan`` over L layers of a layer-flat pool, timed whole and
 divided by L (the queries of layer l+1 follow from layer l's output, so nothing hoists):
@@ -17,11 +20,23 @@ divided by L (the queries of layer l+1 follow from layer l's output, so nothing 
 - ``tile<T>fold``: the same with every KV head folded block-diagonally into
                    one group, as a length-1 row's are.
 - ``chunk<T>`` / ``rows``: the two launches of ``tile<T>`` apart.
-- ``paged``:       the chunk on ``attention_impl="paged"``'s path (the prefix
-                   fetched through ``llama._gather_kv``, flash kernel for the
-                   chunk, XLA score product for the prefix); no decode rows.
-- ``pagedrows``:   the decode rows on that path (``paged_decode_partials``
-                   merged with the current token's piece).
+- ``paged``:       the chunk where no kernel serves the pool and
+                   ``prefill_impl`` is ``flash`` (``attention_impl="paged"``'s
+                   own chunk path until PR 52: the prefix fetched through
+                   ``llama._gather_kv``, flash kernel for the chunk, XLA score
+                   product for the prefix); no decode rows.
+- ``pagedrows``:   the decode rows on ``attention_impl="paged"``'s path
+                   (``paged_decode_partials`` merged with the current token's
+                   piece).
+
+Which forms a cell's mixed step is since PR 52 (``llama.chunk_walks_tiles``: a
+chunk walks tiles wherever a kernel serves the pool): ``mistral-7b-w8.chat``
+and ``mixtral-8x7b-d3.chat-sat`` ``chunk256`` + ``rows`` at 1,024 lanes;
+``evabyte-d16.doc-bytes`` ``chunk256`` + ``pagedrows`` at 4,096 lanes
+(``paged`` + ``pagedrows`` before it); ``granite-4.0-h-small-d10-e36.chat-many``
+and ``zaya1-8b-d20.reason`` a tile walk and ``rows`` through ``hybrid.py``'s
+mixers at their own lanes (1,024 and 256); ``dots3-note-prev-d5-e32.long-notes``
+none of them (its latent attention is XLA's, ``models/latent.py``).
 
 The rows study takes shapes ``live:pages:bucket:width`` (live rows, full pages
 each, the batch bucket, the table's width) and windows (the fresh keys a row
@@ -233,6 +248,8 @@ def main():
     ap.add_argument("--iters", type=int, default=10)
     ap.add_argument("--lanes", type=int, nargs="*", default=[1024, 4096])
     ap.add_argument("--prefix", type=int, nargs="*", default=[0, 512, 1408])
+    ap.add_argument("--widths", type=int, nargs="*", default=[],
+                    help="the chunk study at each of these widths of the chunk's table (slots), not at 16 alone")
     ap.add_argument("--tiles", type=int, nargs="*", default=[32, 64, 128, 256])
     ap.add_argument("--fold-tiles", type=int, nargs="*", default=[16, 32])
     ap.add_argument("--study", choices=["chunk", "rows"], default="chunk")
@@ -261,7 +278,9 @@ def main():
         log.write(line + "\n")
         log.flush()
 
-    say(device=str(jax.devices()[0].device_kind), S=S, B=B, W=W, L=L, H=H, HD=HD, BS=BS, dtype=str(jnp.dtype(dtype)))
+    widths = a.widths or [W]
+    say(device=str(jax.devices()[0].device_kind), S=S, B=B, W=widths if a.study == "chunk" else W, L=L, H=H, HD=HD, BS=BS,
+        dtype=str(jnp.dtype(dtype)))
     if a.study == "rows":
         return rows_study(a, say, lanes=a.lanes[0], N=N, L=L, H=H, HD=HD, BS=BS, dtype=dtype, on_tpu=on_tpu)
     lane_fold = mk.lane_fold
@@ -273,8 +292,8 @@ def main():
         v = jax.random.normal(keys[2], (S + B, KVH, HD), dtype)
         kp = jax.random.normal(keys[3], (L * N, BS, lanes), dtype)
         vp = jax.random.normal(keys[4], (L * N, BS, lanes), dtype)
-        d_prefix = min(12, W) * BS - BS // 2
-        for prefix in a.prefix:
+        for W, prefix in ((w, p) for w in widths for p in a.prefix if p <= w * BS):
+            d_prefix = min(12, W) * BS - BS // 2
             forms = [("walk", 1, False), ("rows", 1, False), ("paged", 1, False), ("pagedrows", 1, False)]
             forms += [(f, t, False) for t in a.tiles for f in ("tile", "chunk")]
             forms += [("tile", t, True) for t in a.fold_tiles]
@@ -288,7 +307,7 @@ def main():
                                d_prefix=d_prefix, interpret=not on_tpu)
                     us, out = measure(fn, (q, k, v, kp, vp), a.iters)
                 except Exception as e:  # a tile the compiler refuses is a reading too
-                    say(lanes=lanes, prefix=prefix, form=name, error=f"{type(e).__name__}: {str(e)[:300]}")
+                    say(lanes=lanes, width=W, prefix=prefix, form=name, error=f"{type(e).__name__}: {str(e)[:300]}")
                     continue
                 finally:
                     mk.lane_fold = lane_fold
@@ -296,7 +315,7 @@ def main():
                 if form == "walk":
                     ref = out
                 err = float(jnp.max(jnp.abs(ref[lo:hi] - out[lo:hi])) / (jnp.max(jnp.abs(ref[lo:hi])) + 1e-9))
-                say(lanes=lanes, prefix=prefix, form=name, us_per_layer=us / L, max_rel_diff_vs_walk=err)
+                say(lanes=lanes, width=W, prefix=prefix, form=name, us_per_layer=us / L, max_rel_diff_vs_walk=err)
 
 
 if __name__ == "__main__":
